@@ -215,14 +215,6 @@ fusion-gate:
 fusion-demo:
 	JAX_PLATFORMS=cpu python scripts/fusion_demo.py --out fusion_demo
 
-# regenerate every artifact-quoted doc figure from the committed round
-# snapshot / fail when the docs drift from it (CI runs docs-check)
-docs-sync:
-	python scripts/docs_sync.py
-
-docs-check:
-	python scripts/docs_sync.py --check
-
 # guided end-to-end walkthroughs (the reference's notebooks role):
 # canary shift, 8-member ensemble, epsilon-greedy feedback, SSE streaming
 demos:

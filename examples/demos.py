@@ -14,9 +14,10 @@ benchmark_simple_model.ipynb), as runnable scripts:
 
     python examples/demos.py [canary|ensemble|mab|stream|all] [--tpu]
 
-Engines run on host CPU by default (SELDON_FORCE_CPU=1) so every scenario
-works anywhere — including boxes whose accelerator admits one process —
-and several engines can coexist; pass --tpu to put them on the real chip.
+Engines run on host CPU by default (JAX_PLATFORMS=cpu): every scenario
+starts SEVERAL engine processes on one host, and a chip belongs to one
+process at a time — nothing here assigns chips to processes yet.  --tpu
+leaves the platform to JAX and only makes sense for a one-engine scenario.
 Exits non-zero on any failed assertion; `make demos` runs all four.
 """
 
@@ -99,7 +100,7 @@ class Stack:
             json.dump(deployment, f)
         env = dict(os.environ)
         if FORCE_CPU:
-            env["SELDON_FORCE_CPU"] = "1"
+            env["JAX_PLATFORMS"] = "cpu"
         env.update(env_extra or {})
         cmd = [sys.executable, "-m", "seldon_core_tpu.runtime.engine_main",
                "--file", path, "--host", "127.0.0.1",

@@ -146,7 +146,7 @@ def main() -> int:
     dep_path = os.path.join(tmp, "deployment.json")
     with open(dep_path, "w") as f:
         json.dump(deployment, f)
-    env = dict(os.environ, SELDON_FORCE_CPU="1")
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     proc = subprocess.Popen(
         [sys.executable, "-m", "seldon_core_tpu.runtime.engine_main",
          "--file", dep_path, "--host", "127.0.0.1",

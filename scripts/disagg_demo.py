@@ -76,7 +76,7 @@ class Replica:
             "w+", suffix=".log", delete=False)
         env = dict(os.environ)
         env.update({
-            "SELDON_FORCE_CPU": "1", "JAX_PLATFORMS": "cpu",
+            "JAX_PLATFORMS": "cpu",
             "ENGINE_HTTP_IMPL": "fast", "ENGINE_GRPC_IMPL": "fast",
             "ENGINE_MAX_BATCH": "32", "ENGINE_BATCH_WAIT_MS": "0.5",
         })

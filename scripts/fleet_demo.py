@@ -37,7 +37,6 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
-os.environ.setdefault("SELDON_FORCE_CPU", "1")
 os.environ["SELDON_TPU_TRACE"] = "1"
 
 from seldon_core_tpu.gateway.apife import ApiGateway, DeploymentStore  # noqa: E402

@@ -92,14 +92,13 @@ async def run_demo(out_dir: str, n_requests: int) -> dict:
 
 def print_table(doc: dict) -> None:
     dev = doc["device"]
-    print(
-        "device: %s (%s)  peak %.0f TFLOP/s bf16, %.0f GB/s HBM%s"
-        % (
-            dev["device_kind"] or "?", dev["platform"] or "?",
-            dev["peak_bf16_tflops"], dev["peak_hbm_gbs"],
-            " [assumed]" if dev["peak_assumed"] else "",
-        )
-    )
+    if dev["peak_bf16_tflops"] is None:
+        peak = "no peak in utils/chips.py (MFU/roofline columns empty)"
+    else:
+        peak = "peak %.0f TFLOP/s bf16, %.0f GB/s HBM" % (
+            dev["peak_bf16_tflops"], dev["peak_hbm_gbs"])
+    print("device: %s (%s)  %s"
+          % (dev["device_kind"] or "?", dev["platform"] or "?", peak))
     cols = ("executable", "calls", "p50_ms", "p99_ms", "compile_s",
             "gflops", "mfu", "pred/meas", "bound")
     print(("%-28s %6s %8s %8s %9s %8s %10s %9s %9s") % cols)
@@ -112,7 +111,7 @@ def print_table(doc: dict) -> None:
             "-" if r.get("mfu") is None else "%.2e" % r["mfu"],
             "-" if r.get("predicted_vs_measured") is None
             else "%.3g" % r["predicted_vs_measured"],
-            r.get("bound", "-"),
+            r.get("bound") or "-",
         ))
     for h in doc.get("hbm", []):
         if h.get("memory_stats", "x") is None:

@@ -34,13 +34,12 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from seldon_core_tpu.utils.fence import fetch_sync
 
 
 from jax.experimental import pallas as pl
 
 
-def _relay_floor():
+def _dispatch_floor():
     f = jax.jit(lambda x: x * 2.0)
     x = jnp.zeros((1, 8), jnp.float32)
     np.asarray(f(x))
@@ -86,7 +85,7 @@ def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=64)
     args = ap.parse_args()
-    relay_s = _relay_floor()
+    floor_s = _dispatch_floor()
 
     B, KV, C, hd = 256, 4, 64, 64
     buf0 = jnp.zeros((B, KV, C, hd), jnp.bfloat16)
@@ -104,14 +103,14 @@ def main():
                 step, (buf, jnp.zeros((), jnp.float32)),
                 jnp.arange(args.steps))
             return buf, acc
-        fetch_sync(prog(buf0, q))
+        jax.block_until_ready(prog(buf0, q))
         raws = []
         for _ in range(2):
             t0 = time.perf_counter()
-            fetch_sync(prog(buf0, q))
+            jax.block_until_ready(prog(buf0, q))
             raws.append(time.perf_counter() - t0)
         raw = min(raws)
-        return max(raw - relay_s, 0.05 * raw) / args.steps * 1e6
+        return max(raw - floor_s, 0.05 * raw) / args.steps * 1e6
 
     def read_of(buf, q, t):
         # a data-dependent read over the buffer prefix (like attention)

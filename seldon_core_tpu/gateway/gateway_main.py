@@ -274,6 +274,11 @@ def main(argv=None) -> None:
     )
     parser.add_argument("--host", default="0.0.0.0")
     args = parser.parse_args(argv)
+    # a chip belongs to one process at a time and the gateway never needs
+    # one: pin this process to the host platform so nothing it touches
+    # lazily (the /prometheus scrape's HBM watermarks, the quality
+    # summarizer) can open the device an engine on this host holds
+    os.environ["JAX_PLATFORMS"] = "cpu"
     asyncio.run(serve(args.spec_dir, args.host))
 
 

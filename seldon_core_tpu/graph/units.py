@@ -94,6 +94,12 @@ class Unit:
     class_names: Optional[list] = None
     #: static meta tags merged into every response this unit touches
     static_tags: Optional[dict] = None
+    #: Pallas kernels this unit's predict path takes, decided at
+    #: construction (static under jit); the engine names them on its
+    #: ``engine up:`` line so the path in use is stated, never inferred.
+    #: Kernels chosen per call from the shapes (flash attention) are not
+    #: listed here.
+    kernels: Tuple[str, ...] = ()
 
     def init_state(self, rng) -> Any:
         return None

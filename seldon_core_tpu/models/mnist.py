@@ -116,16 +116,23 @@ class MnistClassifier(Unit):
         self.depth = int(depth)
         self.seed = int(seed)
         self.dtype = jnp.dtype(dtype)
-        # kernel-path decision is made HERE (static under jit): "auto" probes
-        # the backend once; "never" forces the XLA path; "interpret" runs the
-        # kernel in interpreter mode (CPU tests of the kernel itself)
+        # kernel-path decision is made HERE (static under jit): "auto"
+        # means the kernel on a TPU backend and XLA elsewhere; "never"
+        # forces the XLA path; "interpret" runs the kernel in interpreter
+        # mode (CPU tests of the kernel itself)
         self.use_pallas = str(use_pallas)
-        if self.use_pallas == "auto":
-            from seldon_core_tpu.ops.fused_mlp import pallas_supported
+        if self.use_pallas not in ("auto", "never", "interpret"):
+            raise ValueError(
+                f"use_pallas={use_pallas!r} not supported "
+                "(auto | never | interpret)")
+        from seldon_core_tpu.ops.fused_mlp import pallas_supported
 
-            self._pallas = pallas_supported()
-        else:
-            self._pallas = self.use_pallas == "interpret"
+        self._pallas = self.use_pallas == "interpret" or (
+            self.use_pallas == "auto" and pallas_supported())
+
+    @property
+    def kernels(self):
+        return ("fused_mlp",) if self._pallas else ()
 
     def init_state(self, rng):
         if rng is None:
@@ -138,14 +145,18 @@ class MnistClassifier(Unit):
     def predict(self, state, X):
         X = X.reshape(X.shape[0], -1)
         if self._pallas:
-            from seldon_core_tpu.ops.fused_mlp import fused_mlp_softmax
+            from seldon_core_tpu.ops.fused_mlp import (
+                fused_mlp_fits,
+                fused_mlp_softmax,
+            )
 
-            try:
+            # the kernel's one size constraint, tested up front: an MLP
+            # too large to sit in VMEM takes XLA; anything the kernel
+            # raises past this point is an error, not a lane change
+            if fused_mlp_fits(state):
                 return fused_mlp_softmax(
                     state, X, interpret=self.use_pallas == "interpret"
                 )
-            except ValueError:
-                pass  # shape/VMEM constraints — XLA path below
         return jax.nn.softmax(mlp_apply(state, X), axis=-1)
 
 
@@ -156,6 +167,8 @@ class QuantizedMnistClassifier(MnistClassifier):
     convert+scale into the dot's weight read, so weights stream at int8
     size — ops/quant.py records the measured trade-offs).  Activations
     are never quantized; argmax-stable for classifier heads."""
+
+    kernels = ()  # serves through dequant_matmul (XLA), never the kernel
 
     def init_state(self, rng):
         from seldon_core_tpu.ops.quant import quantize_mlp_params

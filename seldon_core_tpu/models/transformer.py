@@ -281,42 +281,40 @@ def _attention(q, k, v, mesh: Optional[Mesh], causal: bool,
     when the mesh shards S.
 
     ``use_flash`` opts the single-chip path into the Pallas flash kernel
-    (differentiable — custom flash VJP); constraint violations fall back
-    to the plain XLA path silently.  Grouped K/V (KV < H) takes the GQA
-    formulation; the ring path requires full MHA heads."""
+    (differentiable — custom flash VJP) for the shapes the kernel
+    documents (``flash_applies``: S % 128, hd <= 256); other shapes take
+    the XLA formulation.  The choice is made from the shapes BEFORE the
+    call, so an error out of the kernel surfaces instead of changing
+    path.  Grouped K/V (KV < H) takes the GQA formulation; the ring path
+    requires full MHA heads."""
+    from seldon_core_tpu.ops.flash_attention import (
+        flash_applies,
+        flash_attention,
+    )
+
     # auto mode only takes the kernel where it measures faster than XLA's
     # fused attention (thresholds above; grouped K/V wins from much
     # shorter S); "force" overrides (explicit opt-in / the benchmarking
-    # arm)
-    auto_min = (FLASH_AUTO_MIN_S_GQA if k.shape[1] != q.shape[1]
-                else FLASH_AUTO_MIN_S)
-    flash_eligible = use_flash == "force" or (
-        use_flash and q.shape[2] >= auto_min
+    # arm).  Single-chip only: pallas_call is not auto-partitionable
+    # under GSPMD, so any multi-device mesh (tp/dp/sp) keeps XLA
+    grouped = k.shape[1] != q.shape[1]
+    auto_min = FLASH_AUTO_MIN_S_GQA if grouped else FLASH_AUTO_MIN_S
+    take_flash = (
+        (use_flash == "force" or (use_flash and q.shape[2] >= auto_min))
+        and (mesh is None or mesh.size == 1)
+        and flash_applies(q.shape, k.shape)
     )
-    if k.shape[1] != q.shape[1]:
-        if mesh is not None and "sp" in mesh.axis_names and mesh.shape["sp"] > 1:
-            raise ValueError(
-                "sequence-parallel ring attention requires "
-                "n_kv_heads == n_heads"
-            )
-        if flash_eligible and (mesh is None or mesh.size == 1):
-            # the flash kernel is GQA-native (grouped K/V block indexing)
-            from seldon_core_tpu.ops.flash_attention import flash_attention
-
-            try:
-                return flash_attention(q, k, v, causal=causal)
-            except ValueError:
-                pass  # shape constraints unmet -> grouped XLA path
+    if grouped and mesh is not None and "sp" in mesh.axis_names \
+            and mesh.shape["sp"] > 1:
+        raise ValueError(
+            "sequence-parallel ring attention requires "
+            "n_kv_heads == n_heads"
+        )
+    if take_flash:
+        # the flash kernel is GQA-native (grouped K/V block indexing)
+        return flash_attention(q, k, v, causal=causal)
+    if grouped:
         return gqa_attention(q, k, v, causal)
-    if flash_eligible and (mesh is None or mesh.size == 1):
-        # single-chip only: pallas_call is not auto-partitionable under
-        # GSPMD, so any multi-device mesh (tp/dp/sp) keeps the XLA path
-        from seldon_core_tpu.ops.flash_attention import flash_attention
-
-        try:
-            return flash_attention(q, k, v, causal=causal)
-        except ValueError:
-            pass  # shape constraints unmet -> XLA path below
     if mesh is not None and "sp" in mesh.axis_names and mesh.shape["sp"] > 1:
         specs = P(
             "dp" if "dp" in mesh.axis_names else None,
@@ -548,16 +546,18 @@ FLASH_AUTO_MIN_S_GQA = 512
 def resolve_flash(attention: str, mesh: Optional[Mesh]):
     """Deployment-parameter attention mode -> static flash decision.
 
-    ``auto``  — Pallas flash kernel when the runtime supports it, the
-                mesh is single-chip (pallas_call is not auto-partitionable
-                under GSPMD), AND the sequence is long enough to win —
-                checked per call in ``_attention``: grouped K/V (GQA)
-                from ``FLASH_AUTO_MIN_S_GQA`` (512) up, MHA from
+    ``auto``  — Pallas flash kernel on a single-chip TPU backend
+                (pallas_call is not auto-partitionable under GSPMD, and
+                the kernels are Mosaic-TPU kernels) AND where the
+                sequence is long enough to win — checked per call in
+                ``_attention``: grouped K/V (GQA) from
+                ``FLASH_AUTO_MIN_S_GQA`` (512) up, MHA from
                 ``FLASH_AUTO_MIN_S`` (4096) up;  returns True/False;
-    ``flash`` — force the kernel at ANY length (returns ``"force"``, the
-                benchmarking arm / explicit opt-in); a runtime without
-                Pallas support or a multi-chip mesh still falls back to
-                XLA (degrade, don't crash-loop the pod);
+    ``flash`` — force the kernel at ANY length the kernel accepts
+                (returns ``"force"``, the benchmarking arm / explicit
+                opt-in); on a backend or mesh that cannot run it this
+                RAISES — an explicit request is never quietly swapped
+                for XLA;
     ``xla``   — force the plain XLA attention (the control arm)."""
     if attention == "xla":
         return False
@@ -570,7 +570,14 @@ def resolve_flash(attention: str, mesh: Optional[Mesh]):
 
     supported = pallas_supported() and not multi
     if attention == "flash":
-        return "force" if supported else False
+        if not supported:
+            raise ValueError(
+                "attention='flash' needs a single-chip TPU backend "
+                f"(backend={jax.default_backend()!r}, mesh size="
+                f"{mesh.size if mesh is not None else 1}); use "
+                "attention='auto' or 'xla'"
+            )
+        return "force"
     return supported
 
 

@@ -14,7 +14,8 @@ stats live in one native [bq, 128] lane tile (values broadcast across the
 applied by global-position masking.
 
 ``flash_attention`` raises ValueError when its constraints don't hold
-(S % 128, head dim <= 256); callers fall back to the XLA path.
+(S % 128, head dim <= 256); callers test ``flash_applies`` first and take
+the XLA path for shapes outside them.
 
 Training: the op carries a custom VJP (flash-attention backward — recompute
 p from the saved per-row log-sum-exp, never materialise [S, S] in HBM).
@@ -41,7 +42,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
-__all__ = ["flash_attention"]
+__all__ = ["flash_applies", "flash_attention"]
 
 _BLOCK = 128
 _NEG_INF = -1e30
@@ -115,22 +116,39 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref,
         )
 
 
-def _validate(q, k, v):
-    if k.shape != v.shape:
-        raise ValueError(f"k/v shapes differ: {k.shape} {v.shape}")
-    if q.ndim != 4 or k.ndim != 4:
-        raise ValueError(f"expected [B, H, S, D], got {q.shape} {k.shape}")
-    B, H, S, D = q.shape
-    KV = k.shape[1]
-    if k.shape[0] != B or k.shape[2] != S or k.shape[3] != D:
-        raise ValueError(f"q/k shapes differ: {q.shape} {k.shape}")
+def _constraint_error(q_shape, k_shape, v_shape):
+    """The kernel's documented shape constraints as one message, or None
+    when they all hold."""
+    if tuple(k_shape) != tuple(v_shape):
+        return f"k/v shapes differ: {k_shape} {v_shape}"
+    if len(q_shape) != 4 or len(k_shape) != 4:
+        return f"expected [B, H, S, D], got {q_shape} {k_shape}"
+    B, H, S, D = q_shape
+    KV = k_shape[1]
+    if k_shape[0] != B or k_shape[2] != S or k_shape[3] != D:
+        return f"q/k shapes differ: {q_shape} {k_shape}"
     if KV == 0 or H % KV != 0:
-        raise ValueError(f"query heads {H} not a multiple of kv heads {KV}")
+        return f"query heads {H} not a multiple of kv heads {KV}"
     if S % _BLOCK != 0:
-        raise ValueError(f"seq len {S} not divisible by {_BLOCK}")
+        return f"seq len {S} not divisible by {_BLOCK}"
     if D > 256:
-        raise ValueError(f"head dim {D} > 256")
-    return B, H, S, D
+        return f"head dim {D} > 256"
+    return None
+
+
+def flash_applies(q_shape, k_shape) -> bool:
+    """True when ``flash_attention`` accepts these q / k(=v) shapes —
+    the test callers make BEFORE choosing the kernel (models/
+    transformer.py ``_attention``), so a ValueError out of the kernel is
+    always an error and never a lane change."""
+    return _constraint_error(q_shape, k_shape, k_shape) is None
+
+
+def _validate(q, k, v):
+    err = _constraint_error(q.shape, k.shape, v.shape)
+    if err is not None:
+        raise ValueError(err)
+    return q.shape
 
 
 def _fwd_impl(q, k, v, causal: bool, interpret: bool):
@@ -383,8 +401,8 @@ def flash_attention(
     query attention) -> [B, H, S, D] attention output.
 
     Differentiable (custom flash VJP; the GQA backward group-sums the
-    repeated-head dK/dV).  Constraints (ValueError otherwise, caller falls
-    back to XLA): S divisible by 128, D <= 256, H a multiple of KV."""
+    repeated-head dK/dV).  Constraints (ValueError otherwise; see
+    ``flash_applies``): S divisible by 128, D <= 256, H a multiple of KV."""
     out, _ = _fwd_impl(q, k, v, causal, interpret)
     return out
 

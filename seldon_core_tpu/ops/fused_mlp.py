@@ -12,15 +12,20 @@ VMEM, and runs matmul -> bias -> relu -> ... -> softmax per batch tile with
 zero HBM traffic for intermediates.  Weights are bf16 (MXU-native), the
 final logits and softmax accumulate in f32.
 
-Weight VMEM budget: all layers must fit (~16 MB/core); serving MLPs
-(784x512x512x10 bf16 ~= 1.3 MB) are far under it.  ``fused_mlp_softmax``
-checks the budget and shape constraints and raises ``ValueError`` when the
-kernel doesn't apply — callers fall back to the XLA path
-(models/mnist.py mlp_apply).
+VMEM budget: every layer's weights must be resident (~16 MB/core), and
+Pallas double-buffers every blocked operand — the weights too, even
+though their block index never changes — so the budget counts each
+input and the output tile TWICE plus the f32 intermediates once.
+Serving MLPs (784x512x512x10 bf16 ~= 1.3 MB, 2.6 MB buffered) are far
+under it.  ``fused_mlp_fits`` is that test; callers ask it before calling
+(models/mnist.py) and take the XLA path for an MLP too large for VMEM.
+``fused_mlp_softmax`` itself raises ``ValueError`` on any violated
+constraint.
 
-``pallas_supported()`` probes the runtime once (compiles a trivial kernel);
-serving code uses it to pick the kernel path at unit-construction time, so
-the decision is static under jit.
+``pallas_supported()`` is a rule, not a probe: these are Mosaic-TPU
+kernels, so they are taken on a TPU backend and nowhere else.  A kernel
+that does not lower on the TPU raises where it is compiled — it is never
+swapped for the XLA path behind the caller's back.
 """
 
 from __future__ import annotations
@@ -31,9 +36,10 @@ from typing import Any, Dict
 import jax
 import jax.numpy as jnp
 
-__all__ = ["fused_mlp_softmax", "pallas_supported"]
+__all__ = ["fused_mlp_fits", "fused_mlp_softmax", "pallas_supported"]
 
 _VMEM_BUDGET_BYTES = 12 * 1024 * 1024  # leave headroom under ~16 MB/core
+_BLOCK_B = 128  # batch-tile rows
 
 
 def _layer_params(params: Dict[str, Any]):
@@ -61,19 +67,39 @@ def _mlp_kernel(*refs, n_layers: int):
     out_ref[:] = e / jnp.sum(e, axis=-1, keepdims=True)
 
 
+def _vmem_bytes(layers, block_b: int) -> int:
+    """VMEM the kernel needs for one batch tile: blocked operands (x tile,
+    every weight and bias, the output tile) are double-buffered by the
+    Pallas pipeline; the widest f32 intermediate is counted once."""
+    in_dim = layers[0][0].shape[0]
+    out_dim = layers[-1][0].shape[1]
+    weight_bytes = sum(w.size * w.dtype.itemsize + b.size * b.dtype.itemsize
+                       for w, b in layers)
+    x_tile = 4 * block_b * in_dim
+    out_tile = 4 * block_b * out_dim
+    act = 4 * block_b * max(w.shape[1] for w, _ in layers)
+    return 2 * (weight_bytes + x_tile + out_tile) + act
+
+
+def fused_mlp_fits(params: Dict[str, Any], block_b: int = _BLOCK_B) -> bool:
+    """The kernel's one size constraint, for callers to test BEFORE
+    calling: all layers resident in VMEM under the budget."""
+    layers = _layer_params(params)
+    return bool(layers) and _vmem_bytes(layers, block_b) <= _VMEM_BUDGET_BYTES
+
+
 def fused_mlp_softmax(
     params: Dict[str, Any],
     x: jax.Array,
     *,
-    block_b: int = 128,
+    block_b: int = _BLOCK_B,
     interpret: bool = False,
 ) -> jax.Array:
     """softmax(mlp(x)) fused in one Pallas kernel.
 
     params: flat dict {w0,b0,...,wL,bL} (models/mnist.py mlp_init layout);
     x: [B, in_dim] float array.  Returns [B, out_dim] float32 probabilities.
-    Raises ValueError when the kernel's constraints don't hold (caller falls
-    back to XLA)."""
+    Raises ValueError when the kernel's constraints don't hold."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -86,13 +112,10 @@ def fused_mlp_softmax(
     out_dim = layers[-1][0].shape[1]
     if x.shape[1] != in_dim:
         raise ValueError(f"x dim {x.shape[1]} != w0 in_dim {in_dim}")
-    weight_bytes = sum(w.size * w.dtype.itemsize + b.size * b.dtype.itemsize
-                       for w, b in layers)
-    # x tile + widest activation tile, f32
-    act_bytes = 4 * block_b * (in_dim + max(w.shape[1] for w, _ in layers))
-    if weight_bytes + act_bytes > _VMEM_BUDGET_BYTES:
+    need = _vmem_bytes(layers, block_b)
+    if need > _VMEM_BUDGET_BYTES:
         raise ValueError(
-            f"fused MLP needs ~{(weight_bytes + act_bytes) >> 20} MiB VMEM "
+            f"fused MLP needs ~{need >> 20} MiB VMEM "
             f"(budget {_VMEM_BUDGET_BYTES >> 20} MiB)"
         )
 
@@ -101,7 +124,7 @@ def fused_mlp_softmax(
     grid = (pl.cdiv(B, block_b),)
 
     # x is tiled over the batch grid; weights/biases are whole-array blocks
-    # (the same VMEM-resident block every step — Mosaic hoists the copies)
+    # (the same block index every step, so they are fetched once)
     in_specs = [
         pl.BlockSpec((block_b, in_dim), lambda i: (i, 0),
                      memory_space=pltpu.VMEM)
@@ -127,45 +150,7 @@ def fused_mlp_softmax(
     return fn(*flat_inputs)
 
 
-_PALLAS_PROBE: "bool | None" = None
-
-
 def pallas_supported() -> bool:
-    """True when the default backend compiles+runs a trivial Pallas TPU
-    kernel.  Probed once per process — but NEVER probed (or cached) inside
-    a jit trace, where the float() readback would raise and pin a spurious
-    False for the whole process; under a trace we answer from the backend
-    platform instead."""
-    global _PALLAS_PROBE
-    if _PALLAS_PROBE is not None:
-        return _PALLAS_PROBE
-    try:
-        from jax._src.core import trace_state_clean
-    except ImportError:  # pragma: no cover - private-API drift
-        trace_state_clean = None
-    if trace_state_clean is not None and not trace_state_clean():
-        import jax
-
-        return jax.default_backend() == "tpu"  # uncached best answer
-    _PALLAS_PROBE = _pallas_probe()
-    return _PALLAS_PROBE
-
-
-def _pallas_probe() -> bool:
-    try:
-        from jax.experimental import pallas as pl
-        from jax.experimental.pallas import tpu as pltpu
-
-        def k(i_ref, o_ref):
-            o_ref[:] = i_ref[:] * 2.0
-
-        x = jnp.ones((8, 128), jnp.float32)
-        y = pl.pallas_call(
-            k,
-            out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
-            in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM)],
-            out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
-        )(x)
-        return bool(abs(float(y[0, 0]) - 2.0) < 1e-6)
-    except Exception:  # noqa: BLE001 - any lowering/runtime failure => no
-        return False
+    """True on a TPU backend: the kernels in ops/ are Mosaic-TPU kernels.
+    Static under jit (the backend cannot change inside a trace)."""
+    return jax.default_backend() == "tpu"

@@ -28,27 +28,8 @@ __all__ = ["MeshSpec", "build_mesh", "local_device_count", "shard_batch",
            "shard_map"]
 
 
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma=None):
-    """``jax.shard_map`` across jax versions — the one compat seam every
-    shard_map call site in the repo routes through.
-
-    jax >= 0.5 promotes shard_map to ``jax.shard_map`` (and renames the
-    replication check to ``check_vma``); 0.4.x only ships
-    ``jax.experimental.shard_map.shard_map`` with the old ``check_rep``
-    spelling.  Callers use the NEW names; this resolver translates when it
-    has to fall back."""
-    native = getattr(jax, "shard_map", None)
-    if native is not None:
-        kw = {} if check_vma is None else {"check_vma": check_vma}
-        return native(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw
-        )
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    kw = {} if check_vma is None else {"check_rep": check_vma}
-    return _shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw
-    )
+#: every shard_map call site in the repo routes through this name
+shard_map = jax.shard_map
 
 
 def local_device_count() -> int:

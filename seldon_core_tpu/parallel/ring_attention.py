@@ -69,13 +69,7 @@ def ring_attention(
     Call INSIDE shard_map/pjit with q/k/v local blocks of shape
     [B, H, S_local, D].  Returns the local output block [B, H, S_local, D].
     """
-    # jax.lax.axis_size is a >=0.5 addition; psum(1) over the axis is the
-    # 0.4.x-safe spelling of the same quantity (static under shard_map)
-    n_blocks = (
-        jax.lax.axis_size(axis_name)
-        if hasattr(jax.lax, "axis_size")
-        else jax.lax.psum(1, axis_name)
-    )
+    n_blocks = jax.lax.axis_size(axis_name)
     my_idx = jax.lax.axis_index(axis_name)
     s_local = q.shape[2]
     q_offset = my_idx * s_local

@@ -134,8 +134,12 @@ async def serve(deployment: SeldonDeploymentSpec, predictor_name=None,
     #   native (C++ HTTP termination + batching, runtime/nativeplane.py)
     #   fast   (asyncio.Protocol, runtime/httpfast.py)
     #   aiohttp (full framework app, runtime/rest.py)
-    # ENGINE_HTTP_IMPL picks explicitly; the default tries native and falls
-    # back per-lane (ineligible graph, missing toolchain)
+    # ENGINE_HTTP_IMPL picks explicitly.  Under the default the lane is
+    # chosen from the GRAPH by a stated rule (native_ineligible_reason):
+    # an ineligible graph — a generator, whose streaming and GenLane
+    # scheduler live on the fast lane; a stateful or tag-emitting graph —
+    # is announced on the fast lane; on an eligible graph a plane that
+    # fails to build, load or bind is FATAL, never a quiet lane change
     http_impl = os.environ.get("ENGINE_HTTP_IMPL", "native").strip().lower()
     if http_impl not in ("native", "fast", "aiohttp"):
         # never boot with NO data plane: unknown names get the most
@@ -146,10 +150,8 @@ async def serve(deployment: SeldonDeploymentSpec, predictor_name=None,
     # gRPC lane selection: native (C++ HTTP/2 in the same plane), fast
     # (runtime/grpcfast.py asyncio lane), aio (stock grpc.aio server).
     # Default rides the native plane when the HTTP lane does.
-    grpc_impl = os.environ.get(
-        "ENGINE_GRPC_IMPL", "native" if http_impl == "native" else "fast"
-    ).strip().lower()
-    if grpc_impl not in ("native", "fast", "aio"):
+    grpc_impl = os.environ.get("ENGINE_GRPC_IMPL", "").strip().lower()
+    if grpc_impl not in ("", "native", "fast", "aio"):
         print(f"unknown ENGINE_GRPC_IMPL={grpc_impl!r}; serving fast lane",
               flush=True)
         grpc_impl = "fast"
@@ -157,30 +159,31 @@ async def serve(deployment: SeldonDeploymentSpec, predictor_name=None,
     fast_server = None
     runner = None
     if http_impl == "native":
-        try:
-            from seldon_core_tpu.runtime.nativeplane import serve_native
+        from seldon_core_tpu.runtime.nativeplane import (
+            native_ineligible_reason,
+            serve_native,
+        )
 
+        reason = native_ineligible_reason(engine)
+        if reason is not None:
+            print(f"http lane: fast — {reason}", flush=True)
+            http_impl = "fast"
+        else:
             # the C++ listener binds a single address; 0.0.0.0 maps to ANY
             native_plane = await serve_native(
                 engine, host if host != "0.0.0.0" else "", rest_port,
-                grpc_port=grpc_port if grpc_impl == "native" else None,
+                grpc_port=(grpc_port if grpc_impl in ("", "native")
+                           else None),
             )
-        except (RuntimeError, OSError) as e:
-            print(f"native data plane unavailable ({e}); "
-                  f"serving the Python fast lane", flush=True)
-            http_impl = "fast"
     if http_impl == "fast":
         from seldon_core_tpu.runtime.httpfast import serve_fast
 
         fast_server = await serve_fast(engine, host, rest_port)
     elif http_impl == "aiohttp":
         runner = await serve_app(make_engine_app(engine), host, rest_port)
-    if grpc_impl == "native" and (
-        native_plane is None or native_plane.grpc_port is None
-    ):
-        print("native gRPC lane unavailable (no native plane); "
-              "serving the Python fast lane", flush=True)
-        grpc_impl = "fast"
+    if grpc_impl in ("", "native"):
+        # the native gRPC lane exists only inside the native plane
+        grpc_impl = "native" if native_plane is not None else "fast"
     if grpc_impl == "native":
         async def grpc_stop():
             pass  # stopped with the shared native plane below
@@ -226,9 +229,18 @@ async def serve(deployment: SeldonDeploymentSpec, predictor_name=None,
 
         http_uds_server = FastHttpServer(engine)
         await http_uds_server.start_uds(http_uds_path)
+    kernels = sorted({
+        k for u in (engine.compiled.units.values()
+                    if engine.compiled is not None else ())
+        for k in u.kernels
+    })
     print(
         f"engine up: predictor={engine.predictor.name} mode={engine.mode} "
-        f"rest=:{rest_port} grpc=:{grpc_port}"
+        f"rest=:{rest_port} grpc=:{grpc_port} "
+        # the lanes and kernels actually serving — bench.py and
+        # chip_smoke.py read these instead of inferring the path
+        f"http={http_impl} grpc-lane={grpc_impl} "
+        f"kernels={','.join(kernels) or 'none'}"
         + (f" uds={uds_path}" if uds_server is not None else "")
         + (f" http-uds={http_uds_path}"
            if http_uds_server is not None else "")
@@ -382,17 +394,13 @@ def main(argv=None) -> None:
              "cross-host KV-handoff receiver (env ENGINE_RELAY_TCP_PORT)",
     )
     args = parser.parse_args(argv)
-    if os.environ.get("SELDON_FORCE_CPU") == "1":
-        # host-CPU serving for control-plane demos/tests: several engines
-        # can then coexist on a box whose accelerator admits one process
-        # (JAX_PLATFORMS env is not honored by every plugin backend; the
-        # config call before first backend use is)
-        import jax
+    from seldon_core_tpu.runtime.compilecache import (
+        compile_cache_dir,
+        enable_compile_cache,
+    )
 
-        jax.config.update("jax_platforms", "cpu")
-    from seldon_core_tpu.runtime.compilecache import enable_compile_cache
-
-    enable_compile_cache()
+    if enable_compile_cache():
+        print(f"compile cache: {compile_cache_dir()}", flush=True)
     deployment = load_deployment_from_env(args.file)
     node = args.node or os.environ.get("ENGINE_GRAPH_NODE", "").strip()
     if node:

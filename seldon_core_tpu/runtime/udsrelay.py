@@ -1,8 +1,8 @@
 """Zero-copy UDS relay lane — co-located gateway<->engine dispatch.
 
-Every bench round has had ``relay_floor_ms`` bounded by the TCP loopback
-hop's fixed costs: connection bookkeeping, HTTP head composition, header
-re-parse, chunked-body state machines.  When gateway and engine share a
+The gateway->engine hop over TCP loopback pays fixed costs per request:
+connection bookkeeping, HTTP head composition, header re-parse,
+chunked-body state machines.  When gateway and engine share a
 host none of that buys anything, so this lane replaces it with the
 cheapest framing that still multiplexes methods:
 

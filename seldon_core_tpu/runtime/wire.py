@@ -1,9 +1,7 @@
 """Binary tensor wire contract — the zero-copy ingress lane.
 
-Eight bench rounds pinned ``relay_floor_ms`` at ~100–128 ms and REST
-throughput at ~38–41k qps/host-core while ``span_framework_p50_ms`` sat
-at ~1.7: the end-to-end floor is the WIRE FORMAT, not the framework.  A
-JSON predict burns the payload four times before the device sees it —
+For a payload-heavy predict the host cost is the WIRE FORMAT, not the
+framework.  A JSON predict burns the payload four times before the device sees it —
 socket bytes -> str decode -> json parse -> list -> numpy — and four
 more on the way out.  The reference shipped an experimental flatbuffers
 contract (``fbs/prediction.fbs``) for exactly this reason; this module

@@ -7,14 +7,16 @@ numbers — extracting the table here is what guarantees bench MFU and
 serving MFU can never disagree about what "peak" means.
 
 Values are public spec-sheet figures; matching is by substring of
-``device.device_kind`` (e.g. "TPU v5 lite").  Unknown device kinds (CPU
-backend, future chips) fall back to a conservative default flagged
-``assumed`` so downstream figures are labelled honest rather than wrong.
+``device.device_kind`` (e.g. "TPU v5 lite").  A device kind that is not
+in the table (CPU backend, a chip nobody has added) HAS NO PEAK: the
+lookups return ``None`` and every figure normalized against a peak (MFU,
+roofline share) is then absent/``null`` — never computed against some
+other chip's numbers.
 """
 
 from __future__ import annotations
 
-from typing import Tuple
+from typing import Optional
 
 __all__ = [
     "PEAK_BF16_TFLOPS",
@@ -45,25 +47,21 @@ PEAK_HBM_GBS = (
     ("v3", 900.0), ("v2", 700.0),
 )
 
-#: conservative defaults (v5e-class) used when the device kind matches no
-#: table row — flagged assumed by the lookup helpers
-_DEFAULT_TFLOPS = 197.0
-_DEFAULT_HBM_GBS = 819.0
-
-
-def _lookup(table, device_kind: str, default: float) -> Tuple[float, bool]:
+def _lookup(table, device_kind: str) -> Optional[float]:
     dk = (device_kind or "").lower()
     for frag, peak in table:
         if frag in dk:
-            return peak, False
-    return default, True  # conservative default, flagged as assumed
+            return peak
+    return None
 
 
-def chip_peak_tflops(device_kind: str) -> Tuple[float, bool]:
-    """(peak dense bf16 TFLOP/s, assumed?) for a device kind string."""
-    return _lookup(PEAK_BF16_TFLOPS, device_kind, _DEFAULT_TFLOPS)
+def chip_peak_tflops(device_kind: str) -> Optional[float]:
+    """Peak dense bf16 TFLOP/s for a device kind string; None when the
+    kind is not in the table."""
+    return _lookup(PEAK_BF16_TFLOPS, device_kind)
 
 
-def chip_peak_hbm_gbs(device_kind: str) -> Tuple[float, bool]:
-    """(peak HBM GB/s, assumed?) for a device kind string."""
-    return _lookup(PEAK_HBM_GBS, device_kind, _DEFAULT_HBM_GBS)
+def chip_peak_hbm_gbs(device_kind: str) -> Optional[float]:
+    """Peak HBM GB/s for a device kind string; None when the kind is not
+    in the table."""
+    return _lookup(PEAK_HBM_GBS, device_kind)

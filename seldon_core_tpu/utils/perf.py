@@ -21,7 +21,7 @@ cost features XLA already computes for free:
     compute-bound vs memory-bound by which peak binds first,
     overhead-bound when measured time exceeds the roofline prediction by
     ``SELDON_TPU_PERF_OVERHEAD_X`` (the dispatch is dominated by
-    host/relay overhead, not device work).
+    host overhead, not device work).
   * **Anomalies**: ``seldon_tpu_perf_anomaly_total{kind}`` fires when a
     dispatch drifts past ``SELDON_TPU_PERF_ANOMALY_FACTOR`` x its own
     executable's rolling p50 (``kind="slow_dispatch"``) or its rolling
@@ -207,28 +207,22 @@ class PerfObservatory:
     # -- device peaks ------------------------------------------------------
 
     def peaks(self) -> Dict[str, Any]:
-        """Device identity + advertised peaks (lazy; cached).  Tolerates a
-        missing/unimportable jax backend — figures then normalize against
-        the conservative assumed defaults."""
+        """Device identity + advertised peaks of this process's backend
+        (lazy; cached).  A ``device_kind`` that is not in the chip table
+        (utils/chips.py) has NO peak: both peak fields are ``None`` and
+        every figure normalized against them is absent from /perf
+        rather than computed against another chip's numbers."""
         if self._peaks is not None:
             return self._peaks
-        device_kind, platform = "", ""
-        try:
-            import jax
+        import jax
 
-            dev = jax.devices()[0]
-            device_kind = str(getattr(dev, "device_kind", dev))
-            platform = str(getattr(dev, "platform", ""))
-        except Exception:  # noqa: BLE001 - no backend: assumed peaks
-            pass
-        tflops, tflops_assumed = chip_peak_tflops(device_kind)
-        hbm_gbs, hbm_assumed = chip_peak_hbm_gbs(device_kind)
+        dev = jax.devices()[0]
+        device_kind = str(getattr(dev, "device_kind", dev))
         self._peaks = {
             "device_kind": device_kind,
-            "platform": platform,
-            "peak_bf16_tflops": tflops,
-            "peak_hbm_gbs": hbm_gbs,
-            "peak_assumed": bool(tflops_assumed or hbm_assumed),
+            "platform": str(getattr(dev, "platform", "")),
+            "peak_bf16_tflops": chip_peak_tflops(device_kind),
+            "peak_hbm_gbs": chip_peak_hbm_gbs(device_kind),
         }
         return self._peaks
 
@@ -269,14 +263,13 @@ class PerfObservatory:
             if compile_s is not None:
                 ent.compile_s = float(compile_s)
         if compile_s is not None:
-            # when the jax.monitoring DURATION listener is live it already
-            # observed this backend compile — recording here too would
-            # double-count every AOT compile in seldon_tpu_compile_seconds
-            # (older jax builds lack that listener; then this is the only
-            # source)
+            # when the jax.monitoring listener is live (compile cache
+            # enabled in this process) it already observed this backend
+            # compile — recording here too would double-count every AOT
+            # compile in seldon_tpu_compile_seconds
             from seldon_core_tpu.utils import telemetry as _telemetry
 
-            if not _telemetry._compile_duration_listener_installed:
+            if not _telemetry._compile_listener_installed:
                 RECORDER.record_compile_seconds(compile_s)
 
     def note_phases(self, key: str, phases: Dict[str, float]) -> None:
@@ -322,19 +315,24 @@ class PerfObservatory:
         if cost:
             flops = cost.get("flops", 0.0)
             nbytes = cost.get("bytes_accessed", 0.0)
-            peak_flops_s = peaks["peak_bf16_tflops"] * 1e12
-            peak_bytes_s = peaks["peak_hbm_gbs"] * 1e9
-            t_compute = flops / peak_flops_s if flops else 0.0
-            t_memory = nbytes / peak_bytes_s if nbytes else 0.0
-            predicted_s = max(t_compute, t_memory)
             if flops:
                 derived["flops"] = flops
                 derived["achieved_tflops"] = flops / seconds / 1e12
-                derived["mfu"] = flops / seconds / peak_flops_s
             if nbytes:
                 derived["achieved_gbs"] = nbytes / seconds / 1e9
                 if flops:
                     derived["arithmetic_intensity"] = flops / nbytes
+            # everything below normalizes against the chip's peaks; a
+            # device kind with no table row gets none of it
+            predicted_s = 0.0
+            if peaks["peak_bf16_tflops"] and peaks["peak_hbm_gbs"]:
+                peak_flops_s = peaks["peak_bf16_tflops"] * 1e12
+                peak_bytes_s = peaks["peak_hbm_gbs"] * 1e9
+                t_compute = flops / peak_flops_s if flops else 0.0
+                t_memory = nbytes / peak_bytes_s if nbytes else 0.0
+                predicted_s = max(t_compute, t_memory)
+                if flops:
+                    derived["mfu"] = flops / seconds / peak_flops_s
             if predicted_s > 0:
                 slowdown = seconds / predicted_s
                 derived["predicted_s"] = predicted_s
@@ -410,6 +408,8 @@ class PerfObservatory:
             return None
         cost = ent.cost
         peaks = self.peaks()
+        if not (peaks["peak_bf16_tflops"] and peaks["peak_hbm_gbs"]):
+            return None  # no peak for this device kind: no roofline prior
         t_compute = cost.get("flops", 0.0) / (
             peaks["peak_bf16_tflops"] * 1e12
         )
@@ -547,11 +547,16 @@ class PerfObservatory:
             for k in ("mfu", "achieved_tflops", "achieved_gbs",
                       "predicted_vs_measured"):
                 if k in last:
-                    # significant figures, not decimal places: CPU-backend
-                    # MFU is legitimately ~1e-8 and must not round to 0
+                    # significant figures, not decimal places: a tiny
+                    # dispatch's MFU is legitimately ~1e-8 and must not
+                    # round to 0
                     row[k] = float("%.4g" % float(last[k]))
             if "bound" in last:
                 row["bound"] = last["bound"]
+            if cost and not self.peaks()["peak_bf16_tflops"]:
+                # a device kind with no peak: the peak-normalized fields
+                # are stated as null, not left for a reader to assume
+                row.update(mfu=None, predicted_vs_measured=None, bound=None)
         return row
 
     def document(self) -> Dict[str, Any]:

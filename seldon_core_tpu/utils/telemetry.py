@@ -2217,12 +2217,10 @@ class AuditLog:
 # Compile-cache event listener
 # ---------------------------------------------------------------------------
 
+#: True once the jax.monitoring listeners (cache hit/miss counts AND
+#: backend-compile durations) are registered; until then the AOT compile
+#: capture (utils/perf.py) records durations itself
 _compile_listener_installed = False
-#: set only when the jax.monitoring DURATION listener registered — older
-#: jax builds have the count-event API but not the duration one, and the
-#: AOT compile capture (utils/perf.py) must keep recording durations
-#: itself in that case
-_compile_duration_listener_installed = False
 
 
 def install_compile_cache_listener() -> bool:
@@ -2237,7 +2235,7 @@ def install_compile_cache_listener() -> bool:
     substring, everything else ignored.  Degrades cleanly (returns False,
     nothing registered) when jax.monitoring is absent.  Idempotent;
     returns True when listeners are registered."""
-    global _compile_listener_installed, _compile_duration_listener_installed
+    global _compile_listener_installed
     if _compile_listener_installed:
         return True
     try:
@@ -2256,14 +2254,7 @@ def install_compile_cache_listener() -> bool:
                 RECORDER.record_compile_seconds(float(duration_secs))
 
         _mon.register_event_listener(_on_event)
-        # older jax builds may lack the duration-listener API; the count
-        # listener alone is still worth keeping
-        register_duration = getattr(
-            _mon, "register_event_duration_secs_listener", None
-        )
-        if register_duration is not None:
-            register_duration(_on_duration)
-            _compile_duration_listener_installed = True
+        _mon.register_event_duration_secs_listener(_on_duration)
         _compile_listener_installed = True
         return True
     except Exception:

@@ -63,6 +63,7 @@ def test_pad_bucket_and_branch_key():
     assert branch_key("r", 0, None) == "branch:r/0[1]"
 
 
+@pytest.mark.usefixtures("v5e_peaks")
 def test_seed_prior_is_overhead_adjusted_roofline():
     """Before any measurement the prediction is the perf observatory's
     overhead-adjusted roofline — hand-computed from the cost features."""

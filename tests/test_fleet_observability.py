@@ -158,9 +158,13 @@ def test_federated_trace_of_disagg_generation_over_real_relay():
         # the critical path crosses all three legs...
         cp_names = {c["name"] for c in doc["critical_path"]}
         assert {"kv_handoff", "decode"} <= cp_names
-        # ...and its segments sum exactly to the root duration
+        # ...and its segments sum exactly to the root duration (each
+        # figure in the document is rounded to 3 decimals, so "exactly"
+        # is within half a unit of the last place per term)
         total = sum(c["self_ms"] for c in doc["critical_path"])
-        assert total == pytest.approx(doc["root_duration_ms"], rel=1e-6)
+        assert total == pytest.approx(
+            doc["root_duration_ms"],
+            abs=0.0005 * (len(doc["critical_path"]) + 1))
         assert doc["phases"]["total_ms"] == pytest.approx(
             doc["root_duration_ms"], abs=0.01)
         assert doc["phases"]["decode_ms"] > 0

@@ -108,6 +108,37 @@ def test_fast_lane_parity_with_python_path(plane_engine):
     asyncio.run(run())
 
 
+def test_binary_wire_frame_answers_over_the_native_plane(plane_engine):
+    """An application/x-seldon-tensor request on the DEFAULT lane rides
+    the misc bridge: its framed response parts must be joined into the
+    one buffer the C++ side sends (it used to raise in the completion
+    callback and the client hung)."""
+    from seldon_core_tpu.runtime import wire
+
+    async def run():
+        plane = await _serve(plane_engine)
+        try:
+            x = np.asarray([[0.25], [0.5]], np.float32)
+            status, raw = await asyncio.wait_for(_post(
+                "127.0.0.1", plane.port, "/api/v0.1/predictions",
+                wire.join_parts(wire.encode_frame(x)),
+                ctype=wire.WIRE_CONTENT_TYPE,
+            ), 30)
+            assert status == 200
+            y_bin = np.asarray(wire.decode_frame(raw).array)
+            _, y_json = await _post(
+                "127.0.0.1", plane.port, "/api/v0.1/predictions",
+                json.dumps({"data": {"ndarray": x.tolist()}}))
+            np.testing.assert_array_equal(
+                y_bin.astype(np.float32),
+                np.asarray(json.loads(y_json)["data"]["ndarray"],
+                           np.float32))
+        finally:
+            await plane.stop()
+
+    asyncio.run(run())
+
+
 def test_tensor_kind_meta_echo_and_multirow(plane_engine):
     async def run():
         plane = await _serve(plane_engine)

@@ -69,6 +69,7 @@ def drive(engine, rows, width, n=12):
 # ---------------------------------------------------------------------------
 
 
+@pytest.mark.usefixtures("v5e_peaks")
 def test_cost_features_captured_on_compiled_model():
     """A served matmul graph lands in the observatory with non-zero FLOPs
     (bounded below by the analytic 2*M*K*N), bytes accessed, a measured
@@ -93,6 +94,36 @@ def test_cost_features_captured_on_compiled_model():
     assert row["latency_ms"]["p50"] > 0
 
 
+def test_unknown_device_kind_has_no_peak_and_perf_prints_null():
+    """A device kind with no row in utils/chips.py has NO peak: /perf
+    states null for the peak-normalized fields instead of quoting some
+    other chip's MFU (this CPU backend used to report a v5e's)."""
+    from seldon_core_tpu.utils.chips import (
+        chip_peak_hbm_gbs,
+        chip_peak_tflops,
+    )
+
+    assert chip_peak_tflops("TPU v5 lite") == 197.0
+    assert chip_peak_hbm_gbs("TPU v5 lite") == 819.0
+    assert chip_peak_tflops("cpu") is None
+    assert chip_peak_hbm_gbs("TPU v9x") is None
+    OBSERVATORY.reset()
+    engine = EngineService(matmul_deployment())
+    drive(engine, 4, PureMatmulUnit.K, n=3)
+    doc = json.loads(json.dumps(engine.perf_document()))
+    dev = doc["device"]
+    assert dev["platform"] == "cpu"
+    assert dev["peak_bf16_tflops"] is None and dev["peak_hbm_gbs"] is None
+    assert "peak_assumed" not in dev
+    row = next(r for r in doc["executables"] if "4x" in r["executable"])
+    assert row["flops"] > 0 and row["achieved_tflops"] > 0
+    assert row["mfu"] is None
+    assert row["predicted_vs_measured"] is None and row["bound"] is None
+    # no peak -> no roofline prior: the autopilot waits for measurements
+    assert OBSERVATORY.seed_predicted_s(row["executable"]) is None
+
+
+@pytest.mark.usefixtures("v5e_peaks")
 def test_mfu_math_against_hand_computed_flops():
     """observe_dispatch derives exactly flops/seconds/peak — checked with
     a hand-computed matmul FLOP count against the observatory's own
@@ -182,6 +213,7 @@ def test_anomaly_counter_fires_on_injected_slow_dispatch():
     assert got == before.get("slow_dispatch", 0) + 1
 
 
+@pytest.mark.usefixtures("v5e_peaks")
 def test_ratio_drift_anomaly():
     """With cost features present, drift is judged on measured/predicted —
     a dispatch whose ratio blows past its own rolling baseline fires
@@ -281,6 +313,7 @@ def test_prometheus_exposition_refreshes_hbm_gauges(monkeypatch):
 # ---------------------------------------------------------------------------
 
 
+@pytest.mark.usefixtures("v5e_peaks")
 def test_perf_endpoint_and_exemplars_aiohttp_lane():
     from aiohttp.test_utils import TestClient, TestServer
 
@@ -343,6 +376,7 @@ def test_perf_endpoint_and_exemplars_aiohttp_lane():
     asyncio.run(run())
 
 
+@pytest.mark.usefixtures("v5e_peaks")
 def test_perf_endpoint_and_exemplars_fast_lane():
     import aiohttp
 

@@ -162,10 +162,12 @@ def test_attention_parameter_modes():
     from seldon_core_tpu.models.transformer import TransformerLM, resolve_flash
 
     assert resolve_flash("xla", None) is False
-    assert isinstance(resolve_flash("auto", None), bool)
-    # 'flash' prefers the kernel but still degrades on unsupported
-    # runtimes instead of crash-looping the pod
-    assert resolve_flash("flash", None) == resolve_flash("auto", None)
+    # the kernels are Mosaic-TPU kernels: auto means XLA on this CPU
+    # backend, and an explicit 'flash' RAISES rather than quietly
+    # serving XLA under the name the operator asked for
+    assert resolve_flash("auto", None) is False
+    with pytest.raises(ValueError, match="single-chip TPU"):
+        resolve_flash("flash", None)
     with pytest.raises(ValueError):
         TransformerLM(attention="nope")
     with pytest.raises(ValueError):

@@ -115,14 +115,21 @@ _EXAMPLE_FEATURES = {
     "stub_deployment.json": 1,  # the reference's max-throughput stub graph
     "generator_tp_deployment.json": 5,  # tp=4 mesh-sharded LM generator
     "generator_ep_deployment.json": 5,  # ep=4 MoE expert-parallel generator
-    "generator_int8_deployment.json": 4,  # int8 + GQA + flash opt-ins
+    "generator_int8_deployment.json": 4,  # int8 weights/KV + GQA opt-ins
     "speculative_deployment.json": 5,  # draft/verify generation opt-in
     # shared-prefix KV cache + eos stop handling opt-ins
     "generator_prefix_deployment.json": 4,
+    # the full-width 165.7M-parameter LM chip_smoke.py serves on the chip;
+    # too heavy for the CPU tier-1 budget, so it runs under -m slow here
+    "lm_d1024_deployment.json": 5,
 }
 
 
-@pytest.mark.parametrize("fname", sorted(_EXAMPLE_FEATURES))
+@pytest.mark.parametrize("fname", [
+    pytest.param(f, marks=pytest.mark.slow)
+    if f == "lm_d1024_deployment.json" else f
+    for f in sorted(_EXAMPLE_FEATURES)
+])
 def test_every_example_deployment_serves(fname):
     path = EXAMPLES / fname
     assert path.exists(), f"example listed but missing: {fname}"

@@ -396,12 +396,46 @@ def test_engine_audits_abandoned_stream():
     assert stream[0]["ttft_ms"] > 0
 
 
-def test_compile_cache_boot_outcome_recorded(monkeypatch, tmp_path):
-    from seldon_core_tpu.runtime.compilecache import enable_compile_cache
+@pytest.fixture
+def _restore_jax_cache_dir():
+    before = jax.config.jax_compilation_cache_dir
+    yield before
+    jax.config.update("jax_compilation_cache_dir", before)
 
-    monkeypatch.setenv("SELDON_COMPILE_CACHE_DIR", str(tmp_path / "xla"))
-    assert enable_compile_cache() is True
+
+def test_compile_cache_placed_by_env_sets_no_dir_in_code(
+        monkeypatch, tmp_path, _restore_jax_cache_dir):
+    """JAX_COMPILATION_CACHE_DIR set: JAX owns the placement — the
+    program configures no directory of its own (and creates none)."""
+    from seldon_core_tpu.runtime import compilecache
+
+    placed = str(tmp_path / "xla")
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", placed)
+    monkeypatch.setattr(compilecache, "_DEFAULT_DIR",
+                        str(tmp_path / "never"))
+    assert compilecache.compile_cache_dir() == placed
+    assert compilecache.enable_compile_cache() is True
+    assert jax.config.jax_compilation_cache_dir == _restore_jax_cache_dir
+    assert not (tmp_path / "never").exists()
     assert RECORDER.snapshot()["compile_cache_events"].get("enabled") == 1
     monkeypatch.setenv("SELDON_COMPILE_CACHE", "0")
-    assert enable_compile_cache() is False
+    assert compilecache.enable_compile_cache() is False
     assert RECORDER.snapshot()["compile_cache_events"].get("disabled") == 1
+
+
+def test_compile_cache_default_is_fixed_dir_inside_checkout(
+        monkeypatch, _restore_jax_cache_dir):
+    """Unset: one fixed git-ignored directory next to the package —
+    never ~, a temp name, a pid or a time (the path keys the cache)."""
+    import os
+
+    from seldon_core_tpu.runtime import compilecache
+
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    want = os.path.join(repo, ".xla_cache")
+    assert compilecache.compile_cache_dir() == want
+    assert compilecache.enable_compile_cache() is True
+    assert jax.config.jax_compilation_cache_dir == want
+    with open(os.path.join(repo, ".gitignore")) as f:
+        assert ".xla_cache/" in f.read().split()
