@@ -1,0 +1,132 @@
+"""lib/roofline.py against hand-worked bytes and FLOPs for both
+configurations, the peaks table, and the bucket arithmetic."""
+
+import bench_paths  # noqa: F401
+import pytest
+from bench_paths import REPO
+from lib import buckets, roofline
+from lib.manifest import Manifest
+from lib.peaks import peaks_for
+
+MAN = Manifest(REPO)
+
+
+# a second set of published sizes for the arithmetic (StarCoder2-7B's,
+# 20 of 32 layers: PERF.md section 7 keeps its cell for a later PR)
+SEVEN_B_L20 = dict(
+    hidden_size=4608, intermediate_size=18432, num_hidden_layers=20,
+    num_attention_heads=36, num_key_value_heads=4, vocab_size=49152)
+
+
+def config(name):
+    if name == "starcoder2-7b-l20":
+        return SEVEN_B_L20
+    return MAN.config(name)
+
+
+# worked by hand from the published sizes (bf16)
+HAND = {
+    "starcoder2-3b": dict(
+        layer=3072 * (3072 + 2 * 2 * 128) + 3072 * 3072 + 2 * 3072 * 12288,
+        layer_value=95_944_704, embed=49152 * 3072,
+        matmul=30 * 95_944_704 + 150_994_944, kv_pos=30_720),
+    "starcoder2-7b-l20": dict(
+        layer=4608 * (4608 + 2 * 4 * 128) + 4608 * 4608 + 2 * 4608 * 18432,
+        layer_value=217_055_232, embed=49152 * 4608,
+        matmul=20 * 217_055_232 + 226_492_416, kv_pos=40_960),
+}
+
+
+@pytest.mark.parametrize("name", sorted(HAND))
+def test_sizes_match_the_hand_worked_numbers(name):
+    s = roofline.sizes(config(name))
+    h = HAND[name]
+    assert h["layer"] == h["layer_value"]
+    assert s["layer_params"] == h["layer_value"]
+    assert s["matmul_params"] == h["matmul"]
+    assert s["weight_bytes"] == 2 * h["matmul"]
+    assert s["kv_bytes_per_position"] == h["kv_pos"]
+    assert s["hd"] == 128
+
+
+@pytest.mark.parametrize("name,rows,live", [
+    ("starcoder2-3b", 48, 48 * 400), ("starcoder2-7b-l20", 64, 64 * 350)])
+def test_decode_step_needs_weights_once_and_live_kv_once(name, rows, live):
+    cfg = config(name)
+    h = HAND[name]
+    need = roofline.decode_step(cfg, rows, live)
+    assert need["bytes"] == 2 * h["matmul"] + h["kv_pos"] * (live + rows)
+    heads, layers = cfg["num_attention_heads"], cfg["num_hidden_layers"]
+    assert need["flops"] == (2 * h["matmul"] * rows
+                             + 4 * layers * heads * 128 * live)
+    least = roofline.least_seconds(need, peaks_for("TPU v5 lite"))
+    assert least["bound"] == "memory"
+    assert least["seconds"] == pytest.approx(need["bytes"] / 819e9)
+    # 3B: 6.06 GB of weights alone are 7.4 ms at 819 GB/s
+    if name == "starcoder2-3b":
+        assert 7.4e-3 < least["seconds"] < 8.3e-3
+
+
+@pytest.mark.parametrize("name", sorted(HAND))
+def test_prefill_is_compute_bound_on_long_chunks_and_counts_real_tokens(
+        name):
+    cfg = config(name)
+    h = HAND[name]
+    tokens, calls = 4 * 256, 1
+    attended = 4 * sum(range(1, 257))
+    need = roofline.prefill(cfg, calls, tokens, attended)
+    assert need["bytes"] == 2 * h["matmul"] + 2 * h["kv_pos"] * tokens
+    body = h["matmul"] - h["embed"]
+    assert need["flops"] == pytest.approx(
+        2 * body * tokens + 2 * h["embed"] * calls
+        + 4 * cfg["num_hidden_layers"] * cfg["num_attention_heads"] * 128
+        * attended)
+    assert roofline.least_seconds(
+        need, peaks_for("TPU v5 lite"))["bound"] == "compute"
+    one = roofline.prefill(cfg, 1, 64, sum(range(1, 65)))
+    assert roofline.least_seconds(
+        one, peaks_for("TPU v5 lite"))["bound"] == "memory"
+
+
+def test_an_unknown_device_kind_is_an_error_not_a_default():
+    assert peaks_for("TPU v5 lite")["bf16_flops"] == 197e12
+    assert peaks_for("TPU v5 lite")["hbm_bytes_per_s"] == 819e9
+    with pytest.raises(KeyError):
+        peaks_for("cpu")
+
+
+@pytest.mark.parametrize("n,want", [(0, 1), (1, 1), (2, 2), (3, 4), (5, 8),
+                                    (64, 64), (65, 128)])
+def test_pow2_is_the_schedulers(n, want):
+    from seldon_core_tpu.runtime.genserver import _pow2
+
+    assert buckets.pow2(n) == want == _pow2(n)
+
+
+@pytest.mark.parametrize("cell", [w["name"] for w in MAN.doc["workloads"]])
+def test_the_ladder_takes_every_reachable_bucket_once(cell):
+    doc = MAN.cell(cell)
+    dep = MAN.deployment(doc, MAN.config(doc["config"]))
+    cp = buckets.caps(MAN.mix(doc["mix"]))
+    want_pre, want_dec = buckets.reachable(dep, cp)
+    got_pre, got_dec = set(), set()
+    for length, max_new in buckets.ladder_rows(dep, cp):
+        assert cp["min_prompt"] <= length <= cp["max_prompt"]
+        pre, dec = buckets.touched(length, max_new, dep)
+        got_pre |= pre
+        got_dec |= dec
+    assert want_pre <= got_pre and want_dec <= got_dec
+    # brute force over every request the mix can draw
+    seen_pre, seen_dec = set(), set()
+    for p in range(cp["min_prompt"], cp["max_prompt"] + 1, 7):
+        o = min(cp["max_out"], cp["max_positions"] - p)
+        pre, dec = buckets.touched(p, o, dep)
+        seen_pre |= pre
+        seen_dec |= dec
+    assert seen_pre <= want_pre and seen_dec <= want_dec
+    progs = buckets.programs(dep, cp)
+    rows = buckets.row_buckets(dep["slots"])
+    assert rows[-1] == dep["slots"] and rows[0] == 1
+    assert len(progs["prefill"]) == len(rows) * len(want_pre)
+    assert len(progs["decode"]) == len(rows) * len(want_dec)
+    assert max(want_dec) * dep["block_size"] <= 4096
