@@ -1108,24 +1108,35 @@ def _paged_block(lp, x, pool_layer, tables, start, valid, cfg: LMConfig):
     B, W, D = x.shape
     hd = cfg.d_model // cfg.n_heads
     kv_h = cfg.kv_heads
-    h = _rmsnorm(x, lp["ln1"])
-    qkv = lm_matmul(lp, "wqkv", h, out_dtype=x.dtype)
-    q, k, v = jnp.split(qkv, [D, D + kv_h * hd], axis=-1)
-    q = _heads(q, B, W, cfg.n_heads, hd)
-    k = _heads(k, B, W, kv_h, hd)
-    v = _heads(v, B, W, kv_h, hd)
+    # the stages below are jax.named_scope's: op metadata only (same
+    # programs, same numerics), read back from a profile window's device
+    # ops by bench/lib/trace_scopes.py — keep the names stable
+    with jax.named_scope("qkv"):
+        h = _rmsnorm(x, lp["ln1"])
+        qkv = lm_matmul(lp, "wqkv", h, out_dtype=x.dtype)
+        q, k, v = jnp.split(qkv, [D, D + kv_h * hd], axis=-1)
+        q = _heads(q, B, W, cfg.n_heads, hd)
+        k = _heads(k, B, W, kv_h, hd)
+        v = _heads(v, B, W, kv_h, hd)
     positions = start[:, None] + jnp.arange(W)[None, :]  # [B, W] per-row
     if cfg.rope:
-        q = apply_rope(q, positions, cfg.rope_base)
-        k = apply_rope(k, positions, cfg.rope_base)
-    pool_layer = _paged_write(pool_layer, tables, positions, valid, k, v)
-    view = _paged_view(pool_layer, tables)
-    a = _attend_paged(q, view, start)
-    a = a.transpose(0, 2, 1, 3).reshape(B, W, D)
-    x = x + lm_matmul(lp, "wo", a, out_dtype=x.dtype)
-    h = _rmsnorm(x, lp["ln2"])
-    y, _lb = _ffn(lp, h, cfg, mesh=None)
-    return x + y, pool_layer
+        with jax.named_scope("rope"):
+            q = apply_rope(q, positions, cfg.rope_base)
+            k = apply_rope(k, positions, cfg.rope_base)
+    with jax.named_scope("kv_write"):
+        pool_layer = _paged_write(pool_layer, tables, positions, valid, k, v)
+    with jax.named_scope("kv_gather"):
+        view = _paged_view(pool_layer, tables)
+    with jax.named_scope("attn"):
+        a = _attend_paged(q, view, start)
+        a = a.transpose(0, 2, 1, 3).reshape(B, W, D)
+    with jax.named_scope("wo"):
+        x = x + lm_matmul(lp, "wo", a, out_dtype=x.dtype)
+    with jax.named_scope("ffn"):
+        h = _rmsnorm(x, lp["ln2"])
+        y, _lb = _ffn(lp, h, cfg, mesh=None)
+        x = x + y
+    return x, pool_layer
 
 
 def paged_forward(params, tokens, pool, tables, start, width,
@@ -1143,19 +1154,21 @@ def paged_forward(params, tokens, pool, tables, start, width,
     for every position (the verify pass scores all of them)."""
     B, W = tokens.shape
     valid = jnp.arange(W)[None, :] < width[:, None]  # [B, W]
-    x = params["embed"][tokens]
+    with jax.named_scope("embed"):
+        x = params["embed"][tokens]
     for i in range(cfg.n_layers):
         x, pool[f"l{i}"] = _paged_block(
             params[f"l{i}"], x, pool[f"l{i}"], tables, start, valid, cfg
         )
-    if last_only:
-        idx = jnp.clip(width - 1, 0, W - 1)
-        x = jnp.take_along_axis(
-            x, jnp.broadcast_to(idx[:, None, None], (B, 1, x.shape[2])),
-            axis=1,
-        )  # [B, 1, D] — before the (positionwise) norm: same numerics
-    x = _rmsnorm(x, params["ln_f"])
-    logits = (x @ params["embed"].T).astype(jnp.float32)
+    with jax.named_scope("unembed"):
+        if last_only:
+            idx = jnp.clip(width - 1, 0, W - 1)
+            x = jnp.take_along_axis(
+                x, jnp.broadcast_to(idx[:, None, None], (B, 1, x.shape[2])),
+                axis=1,
+            )  # [B, 1, D] — before the (positionwise) norm: same numerics
+        x = _rmsnorm(x, params["ln_f"])
+        logits = (x @ params["embed"].T).astype(jnp.float32)
     return (logits[:, 0, :] if last_only else logits), pool
 
 
@@ -1177,29 +1190,32 @@ def paged_decode_round(params, pool, tables, token, n_valid, active,
 
     def step(carry, _):
         pool, token, n_valid, seen_eos, keys = carry
-        x = params["embed"][token][:, None, :]
+        with jax.named_scope("embed"):
+            x = params["embed"][token][:, None, :]
         for i in range(cfg.n_layers):
             x, pool[f"l{i}"] = _paged_block(
                 params[f"l{i}"], x, pool[f"l{i}"], tables, n_valid,
                 active[:, None], cfg,
             )
-        x = _rmsnorm(x, params["ln_f"])
-        logits = (x[:, 0, :] @ params["embed"].T).astype(jnp.float32)
-        if temperature <= 0.0:
-            nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        else:
-            split = jax.vmap(jax.random.split)(keys)  # [B, 2] keys
-            keys = split[:, 0]
-            nxt = jax.vmap(
-                lambda lg, kk: sample_token(
-                    lg[None, :], kk, temperature, top_k, top_p
-                )[0]
-            )(logits, split[:, 1])
-        if eos_token >= 0:
-            nxt = jnp.where(seen_eos, jnp.int32(eos_token), nxt)
-            seen_eos = seen_eos | (nxt == eos_token)
-        nxt = jnp.where(active, nxt, 0)
-        n_valid = n_valid + active.astype(jnp.int32)
+        with jax.named_scope("unembed"):
+            x = _rmsnorm(x, params["ln_f"])
+            logits = (x[:, 0, :] @ params["embed"].T).astype(jnp.float32)
+        with jax.named_scope("sample"):
+            if temperature <= 0.0:
+                nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            else:
+                split = jax.vmap(jax.random.split)(keys)  # [B, 2] keys
+                keys = split[:, 0]
+                nxt = jax.vmap(
+                    lambda lg, kk: sample_token(
+                        lg[None, :], kk, temperature, top_k, top_p
+                    )[0]
+                )(logits, split[:, 1])
+            if eos_token >= 0:
+                nxt = jnp.where(seen_eos, jnp.int32(eos_token), nxt)
+                seen_eos = seen_eos | (nxt == eos_token)
+            nxt = jnp.where(active, nxt, 0)
+            n_valid = n_valid + active.astype(jnp.int32)
         return (pool, nxt, n_valid, seen_eos, keys), nxt
 
     (pool, token, n_valid, seen_eos, keys), toks = jax.lax.scan(

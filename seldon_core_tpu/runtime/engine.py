@@ -726,18 +726,25 @@ class EngineService:
             raise SeldonMessageError("streaming needs a numeric prompt")
         return text, chunk
 
-    async def generate_stream(self, raw, chunk: int = 8):
+    async def generate_stream(self, raw, chunk: int = 8,
+                              t_recv: Optional[float] = None):
         """Incremental generation: yields SSE-able JSON strings —
         ``{"tokens": [[...]], "done": false}`` per chunk, then a terminal
         ``{"done": true, "meta": {...}}``.  Beyond-reference surface (the
         reference predates sequence models); greedy streams concatenate to
         exactly the ``predict_json`` output.
 
+        ``t_recv`` is the HTTP lane's ``perf_counter`` at handler entry
+        (this call's own entry when the lane gives none): the origin of
+        the request stages ``/genperf`` reports under ``requests``.
+
         Streams bypass the batcher (a stream holds the device for its
         chunk dispatches; concurrent streams interleave at chunk
         granularity) and never write unit state back."""
         import json as _json
 
+        if t_recv is None:
+            t_recv = time.perf_counter()
         if not self.can_stream():
             raise SeldonMessageError(
                 "graph does not support streaming generation "
@@ -766,11 +773,13 @@ class EngineService:
             rows = rows.reshape(1, -1)
         puid = msg.meta.puid or new_puid()
         loop = asyncio.get_running_loop()
+        req = None
         if self.genserver is not None:
             # continuous lane: the stream joins the in-flight decode
             # batch at the next scheduler step (chunked prefill first),
             # instead of holding the device for a private generate()
-            gen = self.genserver.stream(rows, chunk=chunk, max_new=max_new)
+            req, gen = self.genserver.open_stream(
+                rows, chunk=chunk, max_new=max_new)
         else:
             name, unit = next(iter(self.compiled.units.items()))
             state = self.compiled.states[name]
@@ -805,7 +814,18 @@ class EngineService:
                             # ttft/decode-rate families are recorded ONCE, by
                             # stream_chunks itself — recording here too would
                             # double-count every stream
-                            ttft_s = time.perf_counter() - t0
+                            t_out = time.perf_counter()
+                            ttft_s = t_out - t0
+                            if req is not None and req.t_first is not None:
+                                # the lane's two request stages, at the
+                                # instant the chunk goes to the writer
+                                from seldon_core_tpu.utils.genperf import (
+                                    GENPERF,
+                                )
+
+                                GENPERF.observe_stream_first(
+                                    req.t_submit - t_recv,
+                                    t_out - req.t_first, t_out - t_recv)
                         tokens += int(arr.shape[0] * arr.shape[1])
                         yield _json.dumps({
                             "tokens": arr.astype(float).tolist(),

@@ -80,6 +80,37 @@ def _env_int(name: str, default: int) -> int:
         return default
 
 
+class _Phase:
+    """One scheduler phase on both clocks: a ``jax.profiler.TraceAnnotation``
+    (always on — an inactive TraceMe costs well under a microsecond — so a
+    ``POST /profile/start`` window shows the phase on the profiler's clock
+    beside the device ops) and, with ``into``, its ``perf_counter`` seconds
+    added to ``into[key]`` for ``/genperf``.  One helper opens both, so the
+    two can never mean different intervals.  ``args`` ride the annotation
+    (read back from the trace by bench/lib/trace_scopes.py)."""
+
+    __slots__ = ("_ann", "_into", "_key", "_t0")
+
+    def __init__(self, name: str, into: Optional[Dict[str, float]] = None,
+                 key: str = "", **args: int):
+        import jax.profiler
+
+        self._ann = jax.profiler.TraceAnnotation(name, **args)
+        self._into = into
+        self._key = key
+
+    def __enter__(self) -> "_Phase":
+        self._ann.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        if self._into is not None:
+            self._into[self._key] = (self._into.get(self._key, 0.0)
+                                     + time.perf_counter() - self._t0)
+        self._ann.__exit__(*exc)
+
+
 def _pow2(n: int) -> int:
     return 1 << max(n - 1, 0).bit_length() if n > 1 else 1
 
@@ -282,9 +313,15 @@ class GenRequest:
         self.queue: "queue.Queue" = queue.Queue()
         self.delivered = 0              # stream tokens handed out per row
         self.cancelled = False
+        # the scheduler's stamps of a stream's time to first token
+        # (/genperf ``requests``), perf_counter only: submitted, first
+        # sequence admitted, first chunk put on ``queue``.  The lane takes
+        # the two around them (handler entry, chunk handed to its writer)
+        # itself, in engine.generate_stream
         self.t_submit = time.perf_counter()
+        self.t_admit: Optional[float] = None
+        self.t_first: Optional[float] = None
         self.ttft_recorded = False
-        self.admit_recorded = False
         # the submitting request's trace context + QoS identity, captured
         # on the CALLER's thread (contextvars don't cross into the
         # scheduler thread): per-sequence prefill/decode spans parent
@@ -453,6 +490,10 @@ class GenServer:
         self._tick_kv_pos = 0                # cache positions streamed
         self._tick_kv_blocks = 0             # blocks the tables covered
         self._tick_kv_ages: List[tuple] = []  # (n_blocks, age_s) freed
+        #: streamed requests' scheduler-side stages, per tick: submit ->
+        #: admit and admit -> first chunk queued (/genperf ``requests``)
+        self._tick_req_queue_s: List[float] = []
+        self._tick_req_prefill_s: List[float] = []
         # cost-ledger scratch (utils/costledger.py): per-phase tenant
         # splits of the tick's padded capacity + KV-block-seconds freed
         # this tick.  None when the ledger kill switch is off — the
@@ -495,6 +536,14 @@ class GenServer:
         """Streaming generation: a plain generator of ``[B, <=chunk]``
         int32 arrays whose concatenation equals the unary output —
         the stream_tokens contract, served by the scheduler."""
+        return self.open_stream(rows, chunk, max_new, tier)[1]
+
+    def open_stream(self, rows, chunk: int = 8,
+                    max_new: Optional[int] = None,
+                    tier: Optional[str] = None):
+        """``stream`` for a lane that times its own side of the request:
+        returns ``(request, chunks)``; the request carries the scheduler's
+        stamps (``t_submit``, ``t_admit``, ``t_first``) back."""
         req = self._enqueue(rows, chunk=max(1, int(chunk)),
                             max_new=max_new, tier=tier)
 
@@ -513,7 +562,7 @@ class GenServer:
                     with self._wake:
                         self._wake.notify_all()
 
-        return _iter()
+        return req, _iter()
 
     def _enqueue(self, rows, chunk, max_new,
                  tier: Optional[str] = None) -> GenRequest:
@@ -832,20 +881,22 @@ class GenServer:
                        and not self._waiting and not self._prefilling
                        and not self._active and not self._remote_arrivals
                        and not self._handoff_done):
-                    if self._imports:
-                        # an in-flight remote import holds reserved
-                        # blocks: wake periodically so the TTL reaper
-                        # can reclaim a torn handoff even when no other
-                        # work arrives
-                        self._wake.wait(1.0)
-                        break
-                    self._wake.wait()
+                    with _Phase("GenServer._run/wait"):
+                        if self._imports:
+                            # an in-flight remote import holds reserved
+                            # blocks: wake periodically so the TTL reaper
+                            # can reclaim a torn handoff even when no
+                            # other work arrives
+                            self._wake.wait(1.0)
+                            break
+                        self._wake.wait()
                 if self._stopped:
                     break
                 while self._arrivals:
                     self._waiting.append(self._arrivals.popleft())
             try:
-                progress = self._tick()
+                with _Phase("GenServer._tick"):
+                    progress = self._tick()
             except Exception as e:  # noqa: BLE001 - fail loudly per request
                 logger.exception("genserver tick failed")
                 # a silently-erroring scheduler must be visible beyond
@@ -863,7 +914,7 @@ class GenServer:
             if not progress:
                 # queued work that cannot run yet (pool dry, waiting on a
                 # retirement that cannot come this tick): don't spin hot
-                with self._wake:
+                with self._wake, _Phase("GenServer._run/wait"):
                     self._wake.wait(0.005)
         self._fail_all(RuntimeError("generation scheduler stopped"))
 
@@ -926,36 +977,32 @@ class GenServer:
         self._tick_kv_attr = []
         self._ensure_device()
         self._drop_cancelled()
-        ta = time.perf_counter()
-        admitted = self._admit()
-        admitted += self._import_admit()
-        handed_back = self._drain_handoff_done()
-        self._reap_stale_imports()
-        phases = {"admit": time.perf_counter() - ta}
+        phases: Dict[str, float] = {}
+        with _Phase("GenServer._admit", phases, "admit"):
+            admitted = self._admit()
+            admitted += self._import_admit()
+            handed_back = self._drain_handoff_done()
+            self._reap_stale_imports()
         kind = None
         tokens = 0
         if self._prefilling:
             kind = "prefill"
-            tp = time.perf_counter()
-            tokens = self._prefill_tick()
-            phases["prefill"] = time.perf_counter() - tp
+            with _Phase("GenServer._prefill_tick", phases, "prefill"):
+                tokens = self._prefill_tick()
         # a first token can finish a sequence (eos / max_new == 1): retire
         # BEFORE the round so it neither wastes a slot nor a dispatch
-        tr = time.perf_counter()
-        retired = self._retire_finished()
-        phases["retire"] = time.perf_counter() - tr
+        with _Phase("GenServer._retire", phases, "retire"):
+            retired = self._retire_finished()
         if self._active:
             if kind is None:
                 kind = "spec" if self.spec else "decode"
             else:
                 kind = "mixed"
-            td = time.perf_counter()
-            tokens += (self._spec_round() if self.spec
-                       else self._decode_round())
-            phases["decode"] = time.perf_counter() - td
-        tr = time.perf_counter()
-        retired += self._retire_finished()
-        phases["retire"] += time.perf_counter() - tr
+            with _Phase("GenServer._decode_round", phases, "decode"):
+                tokens += (self._spec_round() if self.spec
+                           else self._decode_round())
+        with _Phase("GenServer._retire", phases, "retire"):
+            retired += self._retire_finished()
         # idle spins count explicitly: a hot-spinning scheduler must
         # read as a bubble on /genperf, not as silence in steps_total
         self.steps_total[kind or "idle"] = (
@@ -980,6 +1027,12 @@ class GenServer:
         if bubble_s > 0.0:
             detail["bubble_s"] = bubble_s
             detail["bubble_cause"] = bubble_cause
+        if self._tick_req_queue_s:
+            detail["req_queue_s"] = tuple(self._tick_req_queue_s)
+            self._tick_req_queue_s.clear()
+        if self._tick_req_prefill_s:
+            detail["req_prefill_s"] = tuple(self._tick_req_prefill_s)
+            self._tick_req_prefill_s.clear()
         if self._tick_attr is not None:
             # cost-ledger payload: per-phase tenant splits of the padded
             # capacity, KV-block-seconds freed this tick, deployment
@@ -1001,8 +1054,9 @@ class GenServer:
                 },
                 "kv": tuple(self._tick_kv_attr),
             }
-        self._publish(admitted, retired, kind or "idle", tokens, wall,
-                      detail=detail)
+        with _Phase("GenServer._publish"):
+            self._publish(admitted, retired, kind or "idle", tokens, wall,
+                          detail=detail)
         progress = (kind is not None or admitted > 0 or retired > 0
                     or handed_back > 0)
         # the bubble ledger: stamp this tick's end and decide what the
@@ -1208,12 +1262,14 @@ class GenServer:
             self.admitted_total += 1
             admitted += 1
             RECORDER.record_gen_admitted()
-            if not seq.request.admit_recorded:
+            req = seq.request
+            if req.t_admit is None:
                 # admission wait is this lane's queue wait — same family
                 # the MicroBatcher feeds, so /stats reads unchanged
-                seq.request.admit_recorded = True
-                RECORDER.observe_queue_wait(
-                    time.perf_counter() - seq.request.t_submit)
+                req.t_admit = time.perf_counter()
+                RECORDER.observe_queue_wait(req.t_admit - req.t_submit)
+                if req.chunk is not None:
+                    self._tick_req_queue_s.append(req.t_admit - req.t_submit)
         return admitted
 
     def _table(self, seq: _Sequence, nblk: int, draft: bool = False
@@ -1242,152 +1298,164 @@ class GenServer:
         )
 
         t0 = time.perf_counter()
-        # brownout stage >= 2: drop to the floor grain (the guaranteed
-        # interleave) so in-flight decode stalls minimally; the adaptive
-        # probe pauses rather than learning from degraded-mode walls
-        floored = BROWNOUT.gen_chunk_floor()
-        C = self.prefill_chunk if floored else self._chunk_eff
-        # capacity pass first: eviction inside it may requeue OTHER
-        # prefilling sequences, so the batch is built only afterwards
-        for seq in list(self._prefilling):
-            if seq not in self._prefilling:
-                continue  # preempted by an earlier row's eviction
-            w = min(C, len(seq.prompt) - seq.prefill_pos)
-            upto = self._prefix_len + seq.prefill_pos + w
-            ok = self._ensure_capacity(seq, upto)
-            if ok and self.spec:
-                # draft pool sized like the target pool; best effort
-                self._ensure_capacity(
-                    seq, seq.prefill_pos + w, draft=True)
-            if not ok:
-                # cannot even hold this chunk: re-queue and wait.
-                # _admit OVERWRITES seq.blocks on re-admission (and
-                # resets prefill_pos — recompute-on-readmit), so the
-                # blocks held so far must go back to the pool now
-                self._prefilling.remove(seq)
-                self._release_blocks(seq)
-                if not self._active and not self._prefilling:
-                    # alone and still failing: no retirement can ever
-                    # free more — the prompt simply exceeds the pool.
-                    # Requeueing would livelock (admit -> prefill ->
-                    # requeue at full device utilization, forever)
-                    self._finish_error(seq, RuntimeError(
-                        f"KV pool ({self.num_blocks} blocks of "
-                        f"{self.block_size}) too small for prompt "
-                        f"length {len(seq.prompt)} (grow "
-                        "SELDON_TPU_GEN_POOL_BLOCKS)"))
-                    continue
-                self._waiting.appendleft(seq)
-                seq.state = _Sequence.WAITING
-        batch = list(self._prefilling)
-        if not batch:
-            return 0
-        B = _pow2(len(batch))
-        toks = np.zeros((B, C), np.int32)
-        start = np.zeros((B,), np.int32)
-        width = np.zeros((B,), np.int32)
-        widths = []
-        for i, seq in enumerate(batch):
-            lo = seq.prefill_pos
-            w = min(C, len(seq.prompt) - lo)
-            toks[i, :w] = seq.prompt[lo:lo + w]
-            start[i] = self._prefix_len + lo
-            width[i] = w
-            widths.append(w)
-        nblk = _pow2(max(
-            self._blocks_needed(int(start[i]) + widths[i])
-            for i in range(len(batch))
-        ))
-        tables = np.zeros((B, nblk), np.int32)
-        for i, seq in enumerate(batch):
-            tables[i] = self._table(seq, nblk)
-        OBSERVATORY.note_padding(len(batch), B)
-        self._tick_rows += B
-        self._tick_real_rows += len(batch)
-        # cost attribution: real units are this chunk's REAL prompt
-        # tokens per sequence; the dispatched capacity is B x C (pad
-        # rows and pad columns both burn the same device program)
-        self._attr_note("prefill", B * C, [
-            (s.request.tenant, s.request.tier, int(widths[i]), 0, 0)
-            for i, s in enumerate(batch)
-        ])
-        self._tick_kv_blocks += sum(
-            self._blocks_needed(int(start[i]) + widths[i])
-            for i in range(len(batch)))
-        td = time.perf_counter()
-        logits, self._pool = paged_forward_jit(
-            self.params, jnp.asarray(toks), self._pool,
-            jnp.asarray(tables), jnp.asarray(start), jnp.asarray(width),
-            cfg=self.cfg, last_only=True,
-        )
-        if self.spec:
-            d_nblk = _pow2(max(
-                self._blocks_needed(seq.prefill_pos + widths[i])
-                for i, seq in enumerate(batch)
-            ))
-            d_tables = np.zeros((B, d_nblk), np.int32)
-            d_start = np.zeros((B,), np.int32)
+        with _Phase("GenServer._prefill_tick/build"):
+            # brownout stage >= 2: drop to the floor grain (the guaranteed
+            # interleave) so in-flight decode stalls minimally; the adaptive
+            # probe pauses rather than learning from degraded-mode walls
+            floored = BROWNOUT.gen_chunk_floor()
+            C = self.prefill_chunk if floored else self._chunk_eff
+            # capacity pass first: eviction inside it may requeue OTHER
+            # prefilling sequences, so the batch is built only afterwards
+            for seq in list(self._prefilling):
+                if seq not in self._prefilling:
+                    continue  # preempted by an earlier row's eviction
+                w = min(C, len(seq.prompt) - seq.prefill_pos)
+                upto = self._prefix_len + seq.prefill_pos + w
+                ok = self._ensure_capacity(seq, upto)
+                if ok and self.spec:
+                    # draft pool sized like the target pool; best effort
+                    self._ensure_capacity(
+                        seq, seq.prefill_pos + w, draft=True)
+                if not ok:
+                    # cannot even hold this chunk: re-queue and wait.
+                    # _admit OVERWRITES seq.blocks on re-admission (and
+                    # resets prefill_pos — recompute-on-readmit), so the
+                    # blocks held so far must go back to the pool now
+                    self._prefilling.remove(seq)
+                    self._release_blocks(seq)
+                    if not self._active and not self._prefilling:
+                        # alone and still failing: no retirement can ever
+                        # free more — the prompt simply exceeds the pool.
+                        # Requeueing would livelock (admit -> prefill ->
+                        # requeue at full device utilization, forever)
+                        self._finish_error(seq, RuntimeError(
+                            f"KV pool ({self.num_blocks} blocks of "
+                            f"{self.block_size}) too small for prompt "
+                            f"length {len(seq.prompt)} (grow "
+                            "SELDON_TPU_GEN_POOL_BLOCKS)"))
+                        continue
+                    self._waiting.appendleft(seq)
+                    seq.state = _Sequence.WAITING
+            batch = list(self._prefilling)
+            if not batch:
+                return 0
+            B = _pow2(len(batch))
+            toks = np.zeros((B, C), np.int32)
+            start = np.zeros((B,), np.int32)
+            width = np.zeros((B,), np.int32)
+            widths = []
             for i, seq in enumerate(batch):
-                d_tables[i] = self._table(seq, d_nblk, draft=True)
-                d_start[i] = seq.prefill_pos
-            _, self._draft_pool = paged_forward_jit(
-                self.draft_params, jnp.asarray(toks), self._draft_pool,
-                jnp.asarray(d_tables), jnp.asarray(d_start),
-                jnp.asarray(width), cfg=self.draft_cfg, last_only=True,
+                lo = seq.prefill_pos
+                w = min(C, len(seq.prompt) - lo)
+                toks[i, :w] = seq.prompt[lo:lo + w]
+                start[i] = self._prefix_len + lo
+                width[i] = w
+                widths.append(w)
+            nblk = _pow2(max(
+                self._blocks_needed(int(start[i]) + widths[i])
+                for i in range(len(batch))
+            ))
+            tables = np.zeros((B, nblk), np.int32)
+            for i, seq in enumerate(batch):
+                tables[i] = self._table(seq, nblk)
+            OBSERVATORY.note_padding(len(batch), B)
+            self._tick_rows += B
+            self._tick_real_rows += len(batch)
+            # cost attribution: real units are this chunk's REAL prompt
+            # tokens per sequence; the dispatched capacity is B x C (pad
+            # rows and pad columns both burn the same device program)
+            self._attr_note("prefill", B * C, [
+                (s.request.tenant, s.request.tier, int(widths[i]), 0, 0)
+                for i, s in enumerate(batch)
+            ])
+            self._tick_kv_blocks += sum(
+                self._blocks_needed(int(start[i]) + widths[i])
+                for i in range(len(batch)))
+        # dispatch -> block_until_ready returns: the fenced "device"
+        # seconds of /genperf and the annotation a trace reduction sets
+        # the module event against (how much of the fence is not device)
+        with _Phase("GenServer._prefill_tick/device", self._dev_s, "prefill",
+                    rows=B, real_rows=len(batch), nblk=nblk,
+                    tokens=sum(widths),
+                    kv_positions=int(start.sum()) + sum(widths)):
+            logits, self._pool = paged_forward_jit(
+                self.params, jnp.asarray(toks), self._pool,
+                jnp.asarray(tables), jnp.asarray(start), jnp.asarray(width),
+                cfg=self.cfg, last_only=True,
             )
-        # flight recorder: fence the dispatched step.  The greedy path
-        # host-syncs these logits a few lines down anyway — this only
-        # MOVES the sync so device wall is attributable to the phase
-        jax.block_until_ready(logits)
-        self._dev_s["prefill"] = (
-            self._dev_s.get("prefill", 0.0) + time.perf_counter() - td)
-        logits_host = None
+            if self.spec:
+                d_nblk = _pow2(max(
+                    self._blocks_needed(seq.prefill_pos + widths[i])
+                    for i, seq in enumerate(batch)
+                ))
+                d_tables = np.zeros((B, d_nblk), np.int32)
+                d_start = np.zeros((B,), np.int32)
+                for i, seq in enumerate(batch):
+                    d_tables[i] = self._table(seq, d_nblk, draft=True)
+                    d_start[i] = seq.prefill_pos
+                _, self._draft_pool = paged_forward_jit(
+                    self.draft_params, jnp.asarray(toks), self._draft_pool,
+                    jnp.asarray(d_tables), jnp.asarray(d_start),
+                    jnp.asarray(width), cfg=self.draft_cfg, last_only=True,
+                )
+            # flight recorder: fence the dispatched step.  The greedy path
+            # host-syncs these logits a few lines down anyway — this only
+            # MOVES the sync so device wall is attributable to the phase
+            jax.block_until_ready(logits)
+        with _Phase("GenServer._prefill_tick/readback"):
+            # greedy first tokens are picked on the host: fetched once,
+            # and only when a row's prompt ends in this chunk
+            logits_host = None
+            if self.temperature <= 0.0 and any(
+                    seq.pending is None
+                    and seq.prefill_pos + widths[i] >= len(seq.prompt)
+                    for i, seq in enumerate(batch)):
+                logits_host = np.asarray(logits)
         emitted = 0
-        for i, seq in enumerate(batch):
-            seq.prefill_pos += widths[i]
-            self._seq_event(seq, "prefill_chunk", pos=seq.prefill_pos,
-                            width=int(widths[i]))
-            seq.n_valid = int(start[i]) + widths[i]
-            if seq.prefill_pos < len(seq.prompt):
-                continue
-            # prompt fully consumed: sample (or restore) the first token
-            self._prefilling.remove(seq)
-            # the per-sequence prefill span (admission -> prompt fully
-            # cached): the "prefill dispatch" leg of a federated trace's
-            # critical path.  One record per sequence, trace-gated — the
-            # per-step hot-path budget is untouched when tracing is off
-            self._record_seq_span(seq, "prefill", "prefill")
-            if seq.pending is None:
-                if self.temperature > 0.0:
-                    key = jax.random.wrap_key_data(
-                        jnp.asarray(seq.key_data))
-                    k0, key = jax.random.split(key)
-                    seq.key_data = np.asarray(jax.random.key_data(key))
-                    first = int(sample_token(
-                        logits[i:i + 1], k0, self.temperature,
-                        self.top_k, self.top_p,
-                    )[0])
+        with _Phase("GenServer._prefill_tick/emit"):
+            for i, seq in enumerate(batch):
+                seq.prefill_pos += widths[i]
+                self._seq_event(seq, "prefill_chunk", pos=seq.prefill_pos,
+                                width=int(widths[i]))
+                seq.n_valid = int(start[i]) + widths[i]
+                if seq.prefill_pos < len(seq.prompt):
+                    continue
+                # prompt fully consumed: sample (or restore) the first token
+                self._prefilling.remove(seq)
+                # the per-sequence prefill span (admission -> prompt fully
+                # cached): the "prefill dispatch" leg of a federated trace's
+                # critical path.  One record per sequence, trace-gated — the
+                # per-step hot-path budget is untouched when tracing is off
+                self._record_seq_span(seq, "prefill", "prefill")
+                if seq.pending is None:
+                    if self.temperature > 0.0:
+                        key = jax.random.wrap_key_data(
+                            jnp.asarray(seq.key_data))
+                        k0, key = jax.random.split(key)
+                        seq.key_data = np.asarray(jax.random.key_data(key))
+                        first = int(sample_token(
+                            logits[i:i + 1], k0, self.temperature,
+                            self.top_k, self.top_p,
+                        )[0])
+                    else:
+                        first = int(np.argmax(logits_host[i]))
+                    seq.pending = first
+                    self._emit_tokens(seq, [first])
+                    emitted += 1
+                    # one completed prefill = one request for the ledger's
+                    # per-request usage normalization; the first served token
+                    self._attr_note("prefill", 0, [
+                        (seq.request.tenant, seq.request.tier, 0, 1, 1)])
+                if self.role == "prefill":
+                    if seq.done:
+                        # the first token already finished the sequence
+                        # (max_new==1 / immediate eos): nothing to hand off
+                        self._retire(seq, seq.retire_reason or "length")
+                    else:
+                        self._handoff_out(seq)
                 else:
-                    if logits_host is None:
-                        logits_host = np.asarray(logits)
-                    first = int(np.argmax(logits_host[i]))
-                seq.pending = first
-                self._emit_tokens(seq, [first])
-                emitted += 1
-                # one completed prefill = one request for the ledger's
-                # per-request usage normalization; the first served token
-                self._attr_note("prefill", 0, [
-                    (seq.request.tenant, seq.request.tier, 0, 1, 1)])
-            if self.role == "prefill":
-                if seq.done:
-                    # the first token already finished the sequence
-                    # (max_new==1 / immediate eos): nothing to hand off
-                    self._retire(seq, seq.retire_reason or "length")
-                else:
-                    self._handoff_out(seq)
-            else:
-                seq.state = _Sequence.RUNNING
-                self._active.append(seq)
+                    seq.state = _Sequence.RUNNING
+                    self._active.append(seq)
         if max(widths) == C and not floored:
             # only adapt on SATURATED ticks: short prompts never use a
             # wider executable, so probing one would compile it for
@@ -1428,94 +1496,102 @@ class GenServer:
 
         from seldon_core_tpu.models.generate import paged_decode_round_jit
 
-        batch = sorted(self._active, key=lambda s: s.sid)
-        for seq in batch:
-            if seq not in self._active:
-                continue  # preempted by an earlier row's eviction
-            if not self._ensure_capacity(seq, seq.n_valid + self.span):
-                # pool exhausted even after eviction: this sequence is
-                # alone and cannot fit — surface a typed failure
-                self._active.remove(seq)
-                self._finish_error(seq, RuntimeError(
-                    "KV pool too small for sequence length "
-                    f"{seq.n_valid + self.span} (grow "
-                    "SELDON_TPU_GEN_POOL_BLOCKS)"))
+        with _Phase("GenServer._decode_round/capacity"):
+            batch = sorted(self._active, key=lambda s: s.sid)
+            for seq in batch:
+                if seq not in self._active:
+                    continue  # preempted by an earlier row's eviction
+                if not self._ensure_capacity(seq, seq.n_valid + self.span):
+                    # pool exhausted even after eviction: this sequence is
+                    # alone and cannot fit — surface a typed failure
+                    self._active.remove(seq)
+                    self._finish_error(seq, RuntimeError(
+                        "KV pool too small for sequence length "
+                        f"{seq.n_valid + self.span} (grow "
+                        "SELDON_TPU_GEN_POOL_BLOCKS)"))
+                    return 0
+        with _Phase("GenServer._decode_round/build"):
+            batch = sorted(self._active, key=lambda s: s.sid)
+            if not batch:
                 return 0
-        batch = sorted(self._active, key=lambda s: s.sid)
-        if not batch:
-            return 0
-        B = _pow2(len(batch))
-        nblk = _pow2(max(
-            self._blocks_needed(s.n_valid + self.span) for s in batch))
-        tables = np.zeros((B, nblk), np.int32)
-        token = np.zeros((B,), np.int32)
-        n_valid = np.zeros((B,), np.int32)
-        active = np.zeros((B,), bool)
-        seen = np.zeros((B,), bool)
-        for i, s in enumerate(batch):
-            tables[i] = self._table(s, nblk)
-            token[i] = s.pending
-            n_valid[i] = s.n_valid
-            active[i] = True
-            seen[i] = (self.eos_token >= 0
-                       and self.eos_token in s.emitted)
-        if self.temperature > 0.0:
-            kd = np.stack([
-                s.key_data if s.key_data is not None
-                else np.zeros_like(batch[0].key_data)
-                for s in batch
-            ] + [np.zeros_like(batch[0].key_data)] * (B - len(batch)))
-            keys = jax.random.wrap_key_data(jnp.asarray(kd))
-        else:
-            keys = jnp.zeros((B,), jnp.uint32)
-        OBSERVATORY.note_padding(len(batch), B)
-        self._tick_rows += B
-        self._tick_real_rows += len(batch)
-        # cost attribution: one real unit per LIVE sequence, capacity B
-        # (the pow-2 row padding is the decode round's whole pad tax)
-        self._attr_note("decode", B, [
-            (s.request.tenant, s.request.tier, 1, 0, 0) for s in batch])
-        self._tick_kv_blocks += sum(
-            self._blocks_needed(s.n_valid + self.span) for s in batch)
-        # cache positions the round streams (served HBM-BW accounting):
-        # each of the span steps attends over ~n_valid + step positions
-        self._tick_kv_pos += sum(
-            self.span * (s.n_valid + self.span // 2) for s in batch)
-        self._tick_dev_steps += self.span
-        td = time.perf_counter()
-        toks, self._pool, _tok, _nv, _seen, keys_out = (
-            paged_decode_round_jit(
-                self.params, self._pool, jnp.asarray(tables),
-                jnp.asarray(token), jnp.asarray(n_valid),
-                jnp.asarray(active), jnp.asarray(seen), keys,
-                self.cfg, span=self.span, temperature=self.temperature,
-                top_k=self.top_k, top_p=self.top_p,
-                eos_token=self.eos_token,
-            )
-        )
-        # fence = the sync np.asarray was about to pay anyway, moved
-        # here so decode device wall lands in its own phase
-        jax.block_until_ready(toks)
-        self._dev_s["decode"] = (
-            self._dev_s.get("decode", 0.0) + time.perf_counter() - td)
-        toks = np.asarray(toks)  # the per-round host sync
-        if self.temperature > 0.0:
-            kd_out = np.asarray(jax.random.key_data(keys_out))
-        emitted = 0
-        for i, s in enumerate(batch):
+            B = _pow2(len(batch))
+            nblk = _pow2(max(
+                self._blocks_needed(s.n_valid + self.span) for s in batch))
+            tables = np.zeros((B, nblk), np.int32)
+            token = np.zeros((B,), np.int32)
+            n_valid = np.zeros((B,), np.int32)
+            active = np.zeros((B,), bool)
+            seen = np.zeros((B,), bool)
+            for i, s in enumerate(batch):
+                tables[i] = self._table(s, nblk)
+                token[i] = s.pending
+                n_valid[i] = s.n_valid
+                active[i] = True
+                seen[i] = (self.eos_token >= 0
+                           and self.eos_token in s.emitted)
             if self.temperature > 0.0:
-                s.key_data = kd_out[i]
-            remaining = s.max_new - len(s.emitted)
-            take = min(self.span, remaining)
-            s.n_valid += self.span
-            s.pending = int(toks[i, -1])
-            self._emit_tokens(s, [int(t) for t in toks[i, :take]])
-            self._seq_event(s, "decode_round", n_valid=s.n_valid,
-                            take=take)
-            emitted += take
-            if take > 0:
-                self._attr_note("decode", 0, [
-                    (s.request.tenant, s.request.tier, 0, 0, take)])
+                kd = np.stack([
+                    s.key_data if s.key_data is not None
+                    else np.zeros_like(batch[0].key_data)
+                    for s in batch
+                ] + [np.zeros_like(batch[0].key_data)] * (B - len(batch)))
+                keys = jax.random.wrap_key_data(jnp.asarray(kd))
+            else:
+                keys = jnp.zeros((B,), jnp.uint32)
+            OBSERVATORY.note_padding(len(batch), B)
+            self._tick_rows += B
+            self._tick_real_rows += len(batch)
+            # cost attribution: one real unit per LIVE sequence, capacity B
+            # (the pow-2 row padding is the decode round's whole pad tax)
+            self._attr_note("decode", B, [
+                (s.request.tenant, s.request.tier, 1, 0, 0) for s in batch])
+            self._tick_kv_blocks += sum(
+                self._blocks_needed(s.n_valid + self.span) for s in batch)
+            # cache positions the round streams (served HBM-BW accounting):
+            # each of the span steps attends over ~n_valid + step positions
+            kv_positions = sum(
+                self.span * (s.n_valid + self.span // 2) for s in batch)
+            self._tick_kv_pos += kv_positions
+            self._tick_dev_steps += self.span
+        # dispatch -> block_until_ready returns: /genperf's fenced decode
+        # seconds, and the annotation the trace sets paged_decode_round's
+        # module event against (decode_fence_slack_ms)
+        with _Phase("GenServer._decode_round/device", self._dev_s, "decode",
+                    rows=B, real_rows=len(batch), nblk=nblk,
+                    kv_positions=kv_positions):
+            toks, self._pool, _tok, _nv, _seen, keys_out = (
+                paged_decode_round_jit(
+                    self.params, self._pool, jnp.asarray(tables),
+                    jnp.asarray(token), jnp.asarray(n_valid),
+                    jnp.asarray(active), jnp.asarray(seen), keys,
+                    self.cfg, span=self.span, temperature=self.temperature,
+                    top_k=self.top_k, top_p=self.top_p,
+                    eos_token=self.eos_token,
+                )
+            )
+            # fence = the sync np.asarray was about to pay anyway, moved
+            # here so decode device wall lands in its own phase
+            jax.block_until_ready(toks)
+        with _Phase("GenServer._decode_round/readback"):
+            toks = np.asarray(toks)  # the per-round host sync
+            if self.temperature > 0.0:
+                kd_out = np.asarray(jax.random.key_data(keys_out))
+        emitted = 0
+        with _Phase("GenServer._decode_round/emit"):
+            for i, s in enumerate(batch):
+                if self.temperature > 0.0:
+                    s.key_data = kd_out[i]
+                remaining = s.max_new - len(s.emitted)
+                take = min(self.span, remaining)
+                s.n_valid += self.span
+                s.pending = int(toks[i, -1])
+                self._emit_tokens(s, [int(t) for t in toks[i, :take]])
+                self._seq_event(s, "decode_round", n_valid=s.n_valid,
+                                take=take)
+                emitted += take
+                if take > 0:
+                    self._attr_note("decode", 0, [
+                        (s.request.tenant, s.request.tier, 0, 0, take)])
         return emitted
 
     def _spec_round(self) -> int:
@@ -1948,6 +2024,13 @@ class GenServer:
                     [s.emitted[req.delivered:req.delivered + n]
                      for s in req.seqs], np.int32)
                 req.delivered += n
+                if req.t_first is None:
+                    # stamped BEFORE the put: the consumer may wake, and
+                    # take its own stamp, before put() returns
+                    req.t_first = time.perf_counter()
+                    if req.t_admit is not None:
+                        self._tick_req_prefill_s.append(
+                            req.t_first - req.t_admit)
                 req.queue.put(arr)
         if all(s.done for s in req.seqs):
             out = np.asarray([s.emitted for s in req.seqs], np.int32)

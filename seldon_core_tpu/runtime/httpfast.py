@@ -47,6 +47,7 @@ aiohttp, curl, the gateway's pooled client) sends Content-Length.
 from __future__ import annotations
 
 import asyncio
+import time
 from typing import Awaitable, Callable, Dict, Optional, Tuple
 from urllib.parse import parse_qs
 
@@ -220,6 +221,7 @@ class _EngineRoutes:
         """SSE token streaming (beyond-reference: the reference predates
         sequence models).  Payload = a SeldonMessage with the prompt plus
         an optional top-level ``chunk`` (tokens per event)."""
+        t_recv = time.perf_counter()  # /genperf requests.stage_s.lane_in
         try:  # every problem surfaces as a plain 400 BEFORE streaming
             text, chunk = self.engine.prepare_stream_request(
                 _payload_text(body, ctype)
@@ -228,7 +230,7 @@ class _EngineRoutes:
             return 400, SeldonMessage.failure(str(e)).to_json().encode(), _JSON
         return StreamResult(
             200, "text/event-stream",
-            self.engine.generate_stream(text, chunk=chunk),
+            self.engine.generate_stream(text, chunk=chunk, t_recv=t_recv),
         )
 
     async def _feedback(self, body, ctype, query) -> Result:
@@ -423,6 +425,10 @@ class _EngineRoutes:
 
         from seldon_core_tpu.utils.tracing import profile_window_stop
 
+        # ON the loop, although it blocks every stream for the seconds the
+        # trace takes to write: from an executor thread, beside a live
+        # loop, the same stop took three times as long (30-34 s against
+        # 9-13 s, chip runs, PERF.md section 6, PR 24)
         return 200, _json.dumps(profile_window_stop()).encode(), _JSON
 
     async def _profile(self, body, ctype, query) -> Result:

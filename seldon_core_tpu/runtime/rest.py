@@ -346,6 +346,7 @@ def make_engine_app(engine: EngineService) -> web.Application:
     async def profile_stop(_):
         from seldon_core_tpu.utils.tracing import profile_window_stop
 
+        # on the loop, as on the fast lane (httpfast._profile_stop says why)
         return web.json_response(profile_window_stop())
 
     async def profile_get(_):

@@ -32,7 +32,15 @@ page"):
   * an idle-poll duty cycle (idle tick wall / scheduler wall) so a
     hot-spinning scheduler reads as a bubble, not as silence;
   * a KV-block age histogram (block residency at release) for pool
-    sizing.
+    sizing;
+  * the ``requests`` block — per STREAMED request, the four stages of its
+    time to first token on ``perf_counter`` only (``lane_in`` recv ->
+    submit, ``queue`` submit -> admit, ``prefill`` admit -> first chunk on
+    the request's queue, ``lane_out`` that chunk -> handed to the lane's
+    writer), as CUMULATIVE sums and counts plus a fixed-edge histogram of
+    recv -> writer, so a window delta of two documents reads them.  The
+    scheduler's two stages ride the tick record; the lane's two are folded
+    by ``engine.generate_stream`` where it takes its ``ttft_s``.
 
 The host+device+bubble ledger accounts for scheduler wall BY
 CONSTRUCTION: per-tick host time is defined as tick wall minus fenced
@@ -48,6 +56,7 @@ way this module sees zero observations.
 
 from __future__ import annotations
 
+import bisect
 import threading
 from typing import Any, Dict, Optional, Tuple
 
@@ -62,6 +71,14 @@ BUBBLE_CAUSES = ("host", "admission_stall", "pool_exhaustion", "idle")
 #: per-tick phase vocabulary (labels on seldon_tpu_gen_step_seconds)
 TICK_PHASES = ("admit", "prefill", "decode", "retire", "host_other")
 
+#: the stages a streamed request's time to first token splits into
+REQUEST_STAGES = ("lane_in", "queue", "prefill", "lane_out")
+
+#: fixed log-spaced edges of the TTFT histogram, 1 ms ... 60 s at a ratio
+#: of 60000 ** (1 / 79) = 1.1494 (<= 1.15): fixed, so two documents of one
+#: process always subtract bucket by bucket
+TTFT_EDGES_MS = tuple(round(60000.0 ** (i / 79.0), 4) for i in range(80))
+
 
 class GenPerf:
     """Process-global per-tick generation-lane accounting.  All observe
@@ -70,6 +87,9 @@ class GenPerf:
 
     def __init__(self) -> None:
         self._lock = threading.Lock()
+        self._zero()
+
+    def _zero(self) -> None:
         self.ticks: Dict[str, int] = {}             # kind -> count
         self.tick_wall: Dict[str, Reservoir] = {}   # kind -> wall seconds
         #: host/device seconds by (kind, phase); "host_other" is the
@@ -94,6 +114,15 @@ class GenPerf:
         self.kv_block_age = Reservoir(1024)   # seconds held at release
         self.kv_blocks_released = 0
         self.tick_errors = 0
+        # streamed requests, cumulative (the ``requests`` block)
+        self.req_streams = 0         # first chunk handed to the writer
+        self.req_admitted = 0        # first sequence admitted
+        self.req_first_tokens = 0    # first chunk put on the queue
+        self.req_ttft_s = 0.0        # recv -> handed to the writer
+        self.req_stage_s = dict.fromkeys(REQUEST_STAGES, 0.0)
+        #: counts[i] holds edges[i-1] <= ttft < edges[i]; the first bucket
+        #: is everything under 1 ms, the last everything from 60 s up
+        self.req_ttft_hist = [0] * (len(TTFT_EDGES_MS) + 1)
 
     # -- feeding (spine drainer only) ------------------------------------
 
@@ -151,8 +180,32 @@ class GenPerf:
             for n_blocks, age_s in kv_ages:
                 self.kv_blocks_released += int(n_blocks)
                 self.kv_block_age.observe(float(age_s))
+            # streamed requests' scheduler-side stages, one value per
+            # request that was admitted / got its first chunk in this tick
+            waits = detail.get("req_queue_s")
+            if waits:
+                self.req_admitted += len(waits)
+                self.req_stage_s["queue"] += float(sum(waits))
+            waits = detail.get("req_prefill_s")
+            if waits:
+                self.req_first_tokens += len(waits)
+                self.req_stage_s["prefill"] += float(sum(waits))
         # reservoirs take their own lock; observe outside ours
         res.observe(wall)
+
+    def observe_stream_first(self, lane_in_s: float, lane_out_s: float,
+                             ttft_s: float) -> None:
+        """Fold the HTTP lane's side of one stream's first chunk
+        (``engine.generate_stream``, once per stream, off the scheduler
+        thread): recv -> submit, first chunk queued -> handed to the
+        writer, and the whole recv -> writer time."""
+        with self._lock:
+            self.req_streams += 1
+            self.req_ttft_s += ttft_s
+            self.req_stage_s["lane_in"] += lane_in_s
+            self.req_stage_s["lane_out"] += lane_out_s
+            self.req_ttft_hist[
+                bisect.bisect_right(TTFT_EDGES_MS, ttft_s * 1e3)] += 1
 
     def observe_tick_error(self) -> None:
         with self._lock:
@@ -176,6 +229,9 @@ class GenPerf:
             "decode_device_s": round(dev_s, 4),
             "real_tokens": tokens,
             "device_steps": steps,
+            # live cache positions the single-token steps attended over,
+            # summed: the program's own count for a roofline reader
+            "kv_positions": kv_pos,
             "served_decode_mfu_pct": None,
             "served_decode_hbm_bw_util_pct": None,
             "served_decode_tok_s_device": (
@@ -281,6 +337,19 @@ class GenPerf:
                     "block_age_s": self.kv_block_age.snapshot(),
                 },
                 "tick_errors_total": self.tick_errors,
+                # cumulative, never rounded: a reader takes the delta of
+                # two documents (docs/operations.md "reading /genperf")
+                "requests": {
+                    "streams": self.req_streams,
+                    "admitted": self.req_admitted,
+                    "first_tokens": self.req_first_tokens,
+                    "ttft_s": self.req_ttft_s,
+                    "stage_s": dict(self.req_stage_s),
+                    "ttft_ms_hist": {
+                        "edges_ms": list(TTFT_EDGES_MS),
+                        "counts": list(self.req_ttft_hist),
+                    },
+                },
             }
         doc["served_decode"] = self.served_decode()
         return doc
@@ -296,27 +365,7 @@ class GenPerf:
     def reset(self) -> None:
         """Fresh state — tests only."""
         with self._lock:
-            self.ticks = {}
-            self.tick_wall = {}
-            self.phase_host_s = {}
-            self.phase_device_s = {}
-            self.wall_s = 0.0
-            self.host_s = 0.0
-            self.device_s = 0.0
-            self.bubble_s = {}
-            self.bubble_ticks = {}
-            self.idle_ticks = 0
-            self.idle_wall_s = 0.0
-            self.rows = 0
-            self.real_rows = 0
-            self.kv_blocks_touched = 0
-            self.decode_device_s = 0.0
-            self.decode_tokens = 0
-            self.decode_steps = 0
-            self.decode_kv_positions = 0
-            self.kv_block_age = Reservoir(1024)
-            self.kv_blocks_released = 0
-            self.tick_errors = 0
+            self._zero()
 
 
 GENPERF = GenPerf()

@@ -27,11 +27,13 @@ module promotes it to a *causal* tracer:
     trace root; the decision propagates in the traceparent flags byte, so
     tracing can stay on under production load.  ``sample=0`` records
     nothing anywhere in the tree.
-  * ``device_profile`` wraps ``jax.profiler`` tracing for XLA/TPU-level
-    timelines (the compiled graph is ONE XLA program, so intra-graph
-    timing lives in the device profile, not host spans).  Re-entrancy
-    safe: a nested/concurrent profile request becomes a span event, not
-    a ``jax.profiler`` exception.
+  * ``profile_window_start`` / ``profile_window_stop`` (``POST
+    /profile/start|stop``) open a bounded ``jax.profiler`` window for
+    XLA/TPU-level timelines (a compiled graph is ONE XLA program, so
+    intra-graph timing lives in the device profile, not host spans); the
+    generation scheduler's phases (runtime/genserver.py ``_Phase``) and
+    the paged programs' stages (models/generate.py ``jax.named_scope``)
+    are written into that same trace.  Overlapping windows are refused.
 
 Tracing is off by default (``SELDON_TPU_TRACE=1`` or ``TRACER.enable()``);
 disabled spans cost one attribute load and return a shared null context.
@@ -76,7 +78,6 @@ __all__ = [
     "export_document",
     "span_from_json_dict",
     "partial_markers",
-    "device_profile",
     "profile_window_start",
     "profile_window_stop",
     "profile_window_status",
@@ -910,54 +911,19 @@ def export_document(
 
 
 # ---------------------------------------------------------------------------
-# Device profiling
-# ---------------------------------------------------------------------------
-
-_PROFILE_LOCK = threading.Lock()
-
-
-@contextmanager
-def device_profile(logdir: str):
-    """Capture a jax.profiler trace (XLA op timeline, TPU utilisation) for
-    the enclosed block; view with TensorBoard/xprof.  This is the
-    device-level complement to host spans: inside one compiled graph the
-    per-op timing only exists here.
-
-    Re-entrancy safe: ``jax.profiler.start_trace`` raises when a trace is
-    already active, so a nested or concurrent profile request records a
-    ``device_profile_skipped`` span event (or a zero-length span when no
-    span is open) and the block runs unprofiled."""
-    import jax
-
-    if not _PROFILE_LOCK.acquire(blocking=False):
-        if not TRACER.event(
-            "device_profile_skipped", logdir=str(logdir),
-            reason="profiler already active",
-        ):
-            TRACER.record_span(
-                "device_profile_skipped", kind="profile",
-                start_s=time.time(), duration_ms=0.0,
-                ctx=current_trace_context(), logdir=str(logdir),
-            )
-        yield
-        return
-    try:
-        jax.profiler.start_trace(logdir)
-        try:
-            yield
-        finally:
-            jax.profiler.stop_trace()
-    finally:
-        _PROFILE_LOCK.release()
-
-
-# ---------------------------------------------------------------------------
 # Coordinated profiling windows (fleet observability)
 # ---------------------------------------------------------------------------
 
+#: held for a window's whole lifetime, stop included: one profiler session
+#: per process
+_PROFILE_LOCK = threading.Lock()
+#: serialises stops: a stop answers only when no stop is in flight
+_STOP_LOCK = threading.Lock()
+
+
 class ProfileBusyError(RuntimeError):
-    """A profile window (or a ``device_profile`` block) is already
-    active in this process — overlapping windows are refused, never
+    """A profile window is already active in this process (or its stop
+    is still writing the trace) — overlapping windows are refused, never
     queued: the second window's data would be attributed to the first."""
 
 
@@ -984,13 +950,18 @@ def profile_window_start(logdir: str, duration_s: float = 0.0,
     (gateway/fleet.py fans one ``POST /profile/start`` out to every
     replica so the mesh is captured simultaneously).
 
-    Holds the module profile lock for the window's lifetime, so a
-    concurrent ``device_profile`` block degrades to a span event exactly
-    as it does against any active profiler session.  The window closes
-    on ``profile_window_stop()`` or automatically after ``duration_s``
-    (clamped to ``SELDON_TPU_PROFILE_MAX_S``).  Raises
-    :class:`ProfileBusyError` when a window/profile is already active —
-    overlapping windows are refused by contract."""
+    Holds the module profile lock for the window's lifetime.  The window
+    closes on ``profile_window_stop()`` or automatically after
+    ``duration_s`` (clamped to ``SELDON_TPU_PROFILE_MAX_S``).  Raises
+    :class:`ProfileBusyError` when a window is already active —
+    overlapping windows are refused by contract.
+
+    The profiler's Python tracer is OFF: the scheduler writes its own
+    phases into the trace (``TraceAnnotation``, host tracer level kept),
+    which is what a reduction attributes device-idle gaps to; an event
+    per Python call added two points of idle share to the window it was
+    measuring and made the stop no shorter (chip runs, PERF.md section
+    6, PR 24)."""
     import jax
 
     duration_s = float(duration_s or 0.0)
@@ -999,11 +970,13 @@ def profile_window_start(logdir: str, duration_s: float = 0.0,
         duration_s = max_s
     if not _PROFILE_LOCK.acquire(blocking=False):
         raise ProfileBusyError(
-            "a profile window or device_profile block is already active "
-            "in this process — stop it before opening another")
+            "a profile window is already active in this process — stop "
+            "it before opening another")
     try:
         os.makedirs(logdir, exist_ok=True)
-        jax.profiler.start_trace(logdir)
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        jax.profiler.start_trace(logdir, profiler_options=options)
     except BaseException:
         _PROFILE_LOCK.release()
         raise
@@ -1025,36 +998,39 @@ def profile_window_start(logdir: str, duration_s: float = 0.0,
 
 
 def profile_window_stop() -> Dict[str, Any]:
-    """Close the active window (idempotent — the auto-stop timer and an
-    explicit stop may race; whichever runs second is a no-op).  Returns
-    the finished window's manifest entry, or the LAST one when no window
-    is active."""
+    """Close the active window and return its manifest entry, or the LAST
+    one when no window is active.  Idempotent, and serialised: the
+    auto-stop timer and an explicit stop may race, and whichever comes
+    second WAITS for the first instead of answering at once — so "stop
+    answered" always means "the trace is on disk and a start is
+    accepted" (writing the trace takes seconds on a chip)."""
     import jax
 
-    with _WINDOW_STATE_LOCK:
-        if not _WINDOW["active"]:
-            return {"active": False, "last": _WINDOW["last"]}
-        timer = _WINDOW.pop("timer", None)
-        if timer is not None:
-            timer.cancel()
-        _WINDOW["timer"] = None
-        _WINDOW["active"] = False
-        entry = {
-            "window": _WINDOW["window"],
-            "artifact": _WINDOW["logdir"],
-            "started_s": _WINDOW["started_s"],
-            "duration_s": round(time.time() - _WINDOW["started_s"], 3),
-        }
-        _WINDOW["last"] = entry
-    try:
-        jax.profiler.stop_trace()
-    except Exception as e:  # noqa: BLE001 - backend already stopped
-        entry = dict(entry, error=f"{type(e).__name__}: {e}")
+    with _STOP_LOCK:
         with _WINDOW_STATE_LOCK:
+            if not _WINDOW["active"]:
+                return {"active": False, "last": _WINDOW["last"]}
+            timer = _WINDOW.pop("timer", None)
+            if timer is not None:
+                timer.cancel()
+            _WINDOW["timer"] = None
+            _WINDOW["active"] = False
+            entry = {
+                "window": _WINDOW["window"],
+                "artifact": _WINDOW["logdir"],
+                "started_s": _WINDOW["started_s"],
+                "duration_s": round(time.time() - _WINDOW["started_s"], 3),
+            }
             _WINDOW["last"] = entry
-    finally:
-        _PROFILE_LOCK.release()
-    return {"active": False, "last": entry}
+        try:
+            jax.profiler.stop_trace()
+        except Exception as e:  # noqa: BLE001 - backend already stopped
+            entry = dict(entry, error=f"{type(e).__name__}: {e}")
+            with _WINDOW_STATE_LOCK:
+                _WINDOW["last"] = entry
+        finally:
+            _PROFILE_LOCK.release()
+        return {"active": False, "last": entry}
 
 
 def profile_window_start_request(body: dict) -> Dict[str, Any]:

@@ -11,6 +11,7 @@ duration; ``/trace/export`` validates as Chrome trace-event JSON.
 
 import asyncio
 import json
+import time
 
 import numpy as np
 import pytest
@@ -662,59 +663,83 @@ def test_gateway_feedback_adopts_traceparent():
 
 
 # ---------------------------------------------------------------------------
-# Device profile re-entrancy
+# Profile windows (no real profiler session: jax.profiler is a stand-in)
 # ---------------------------------------------------------------------------
 
 
-def test_device_profile_reentrancy_is_noop_not_error(monkeypatch, tmp_path):
+class _FakeProfiler:
+    """start/stop counters; ``stop_trace`` takes ``stop_s`` like the real
+    one takes seconds to write a chip trace."""
+
+    def __init__(self, stop_s=0.0):
+        import jax
+
+        self.ProfileOptions = jax.profiler.ProfileOptions
+        self.stop_s = stop_s
+        self.started, self.stopped, self.options = 0, 0, []
+
+    def start_trace(self, logdir, profiler_options=None):
+        assert self.started == self.stopped, "profiler already active"
+        self.started += 1
+        self.options.append(profiler_options)
+
+    def stop_trace(self):
+        time.sleep(self.stop_s)
+        self.stopped += 1
+
+
+def test_profile_window_keeps_the_host_tracer_and_drops_the_python_one(
+        monkeypatch, tmp_path):
+    import jax
+
     import seldon_core_tpu.utils.tracing as tracing
 
-    calls = {"start": 0, "stop": 0}
+    fake = _FakeProfiler()
+    monkeypatch.setattr(jax, "profiler", fake)
+    res = tracing.profile_window_start(str(tmp_path / "w"), 30.0)
+    try:
+        assert res["active"] is True
+        with pytest.raises(tracing.ProfileBusyError):
+            tracing.profile_window_start(str(tmp_path / "w2"), 1.0)
+    finally:
+        out = tracing.profile_window_stop()
+    assert out["last"]["artifact"].endswith("w")
+    (opts,) = fake.options
+    # the scheduler's own TraceAnnotations need the host tracer; the
+    # Python tracer (an event per Python call) is what made a stop slow
+    assert opts.python_tracer_level == 0 and opts.host_tracer_level >= 1
+    assert (fake.started, fake.stopped) == (1, 1)
 
-    class _FakeProfiler:
-        @staticmethod
-        def start_trace(logdir):
-            if calls["start"] > calls["stop"]:
-                raise RuntimeError("profiler already active")
-            calls["start"] += 1
 
-        @staticmethod
-        def stop_trace():
-            calls["stop"] += 1
+def test_profile_window_stop_answers_only_when_no_stop_is_in_flight(
+        monkeypatch, tmp_path):
+    """The auto-stop timer (or a first stop) may still be writing the
+    trace when a second stop arrives: the second one WAITS, so that its
+    answer means "the trace is on disk and a start is accepted" — it used
+    to answer at once while the profile lock was still held, and the next
+    start was refused with 409."""
+    import threading
 
     import jax
 
-    monkeypatch.setattr(jax, "profiler", _FakeProfiler)
-    TRACER.enable()
-    with TRACER.span("prof-puid", "work", kind="request"):
-        with tracing.device_profile(str(tmp_path)):
-            # nested: must not raise, must not call start_trace again
-            with tracing.device_profile(str(tmp_path)):
-                pass
-    assert calls == {"start": 1, "stop": 1}
-    (span,) = TRACER.trace("prof-puid")
-    assert any(e["name"] == "device_profile_skipped" for e in span.events)
-
-
-def test_device_profile_skip_without_open_span_records_span(monkeypatch, tmp_path):
     import seldon_core_tpu.utils.tracing as tracing
 
-    class _FakeProfiler:
-        @staticmethod
-        def start_trace(logdir):
-            pass
-
-        @staticmethod
-        def stop_trace():
-            pass
-
-    import jax
-
-    monkeypatch.setattr(jax, "profiler", _FakeProfiler)
-    TRACER.enable()
-    with tracing.device_profile(str(tmp_path)):
-        with tracing.device_profile(str(tmp_path)):
-            pass
-    assert any(
-        s.name == "device_profile_skipped" for s in TRACER.recent(10)
-    )
+    fake = _FakeProfiler(stop_s=0.4)
+    monkeypatch.setattr(jax, "profiler", fake)
+    tracing.profile_window_start(str(tmp_path / "w"), 30.0)
+    first = threading.Thread(target=tracing.profile_window_stop)
+    first.start()
+    deadline = time.monotonic() + 5
+    while tracing.profile_window_status()["active"] \
+            and time.monotonic() < deadline:
+        time.sleep(0.005)            # the first stop is now inside stop_trace
+    assert fake.stopped == 0
+    out = tracing.profile_window_stop()          # the second stop
+    assert fake.stopped == 1, "the second stop answered before the first"
+    assert out["active"] is False and out["last"]["artifact"].endswith("w")
+    # and a start is accepted at once
+    again = tracing.profile_window_start(str(tmp_path / "w3"), 30.0)
+    assert again["active"] is True
+    tracing.profile_window_stop()
+    first.join(timeout=5)
+    assert (fake.started, fake.stopped) == (2, 2)

@@ -610,8 +610,23 @@ def test_scrape_tick_gauges_publish_dead_lease_staleness(monkeypatch):
 # ---------------------------------------------------------------------------
 
 
+@pytest.fixture
+def no_window_left():
+    """For the tests that open a REAL profiler window (the CPU one): none
+    is left behind, however the test ends.  ``profile_window_stop`` waits
+    for a stop in flight (the auto-stop timer's), so after it the trace
+    is written and the process lock is free for the next test."""
+    from seldon_core_tpu.utils import tracing
+
+    yield
+    tracing.profile_window_stop()
+    assert tracing.profile_window_status()["active"] is False
+    assert not tracing._PROFILE_LOCK.locked()
+
+
 def test_profile_window_coordinated_and_overlap_refused(tmp_path,
-                                                        monkeypatch):
+                                                        monkeypatch,
+                                                        no_window_left):
     monkeypatch.setenv("SELDON_TPU_PROFILE_DIR", str(tmp_path))
     spec = _iris_spec()
     e1 = EngineService(spec)
@@ -656,7 +671,7 @@ def test_profile_window_coordinated_and_overlap_refused(tmp_path,
         asyncio.run(e1.close())
 
 
-def test_profile_window_auto_stops_at_duration(tmp_path):
+def test_profile_window_auto_stops_at_duration(tmp_path, no_window_left):
     import time
 
     from seldon_core_tpu.utils.tracing import (
@@ -685,7 +700,7 @@ def test_profile_window_auto_stops_at_duration(tmp_path):
 # ---------------------------------------------------------------------------
 
 
-def test_gateway_http_routes_serve_fleet_surfaces():
+def test_gateway_http_routes_serve_fleet_surfaces(no_window_left):
     from aiohttp.test_utils import TestClient, TestServer
 
     from seldon_core_tpu.gateway.apife import make_gateway_app
@@ -733,7 +748,7 @@ def test_gateway_http_routes_serve_fleet_surfaces():
         asyncio.run(e1.close())
 
 
-def test_engine_profile_routes_contract():
+def test_engine_profile_routes_contract(no_window_left):
     from aiohttp.test_utils import TestClient, TestServer
 
     from seldon_core_tpu.runtime.rest import make_engine_app

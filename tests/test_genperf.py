@@ -84,6 +84,27 @@ def deployment():
     )
 
 
+def gen_deployment():
+    return SeldonDeploymentSpec.from_json_dict({
+        "spec": {"name": "genperf-gen", "predictors": [{
+            "name": "p",
+            "graph": {"name": "g", "type": "MODEL"},
+            "components": [{
+                "name": "g", "runtime": "inprocess",
+                "class_path": "TransformerGenerator",
+                "parameters": [
+                    {"name": k, "value": v, "type": t} for k, v, t in (
+                        ("vocab", "48", "INT"), ("d_model", "32", "INT"),
+                        ("n_heads", "4", "INT"), ("n_layers", "2", "INT"),
+                        ("d_ff", "64", "INT"),
+                        ("max_new_tokens", "9", "INT"),
+                        ("temperature", "0.0", "FLOAT"),
+                        ("dtype", "float32", "STRING"))],
+            }],
+        }]}
+    })
+
+
 # -- bubble-ledger arithmetic (hand-timed fake clock) ------------------------
 
 
@@ -162,6 +183,35 @@ def test_served_decode_null_guard_without_cost_features(monkeypatch):
     assert served["served_decode_hbm_bw_util_pct"] is None
     assert served["real_tokens"] == 8
     assert served["served_decode_tok_s_device"] == round(8 / 0.004, 1)
+
+
+def test_requests_block_folds_both_sides_and_publishes_kv_positions():
+    """The scheduler's stages ride the tick record, the lane's are folded
+    once per stream; the served-decode block carries the program's own
+    count of live cache positions."""
+    GENPERF.observe_tick("mixed", {
+        "wall_s": 0.02, "device_s": 0.015,
+        "device_phases": {"decode": 0.01}, "steps": 8, "tokens": 16,
+        "kv_positions": 3200,
+        "req_queue_s": (0.001, 0.003), "req_prefill_s": (0.150,)})
+    GENPERF.observe_stream_first(0.0005, 0.0015, 0.1560)
+    GENPERF.observe_stream_first(0.0005, 0.0015, 70.0)
+    doc = GENPERF.document()
+    req = doc["requests"]
+    assert (req["streams"], req["admitted"], req["first_tokens"]) == (2, 2, 1)
+    assert req["stage_s"] == {
+        "lane_in": pytest.approx(0.001), "queue": pytest.approx(0.004),
+        "prefill": pytest.approx(0.150), "lane_out": pytest.approx(0.003)}
+    assert req["ttft_s"] == pytest.approx(70.156)
+    edges, counts = (req["ttft_ms_hist"]["edges_ms"],
+                     req["ttft_ms_hist"]["counts"])
+    assert edges[0] == 1.0 and edges[-1] == 60000.0
+    assert max(b / a for a, b in zip(edges, edges[1:])) <= 1.15
+    assert len(counts) == len(edges) + 1 and sum(counts) == 2
+    assert counts[-1] == 1          # 70 s: the overflow bucket
+    i = counts.index(1)
+    assert edges[i - 1] <= 156.0 < edges[i]
+    assert doc["served_decode"]["kv_positions"] == 3200
 
 
 def test_tick_error_counter_and_family():
@@ -370,6 +420,188 @@ def test_genperf_endpoint_on_both_lanes():
             await server.stop()
 
     asyncio.run(run_fast())
+
+
+async def _stream(engine, prompt, t_recv=None):
+    raw = json.dumps({"data": {"ndarray": [prompt]}})
+    frames = [json.loads(f) async for f in engine.generate_stream(
+        raw, chunk=3, t_recv=t_recv)]
+    assert frames[-1]["done"] is True
+    return frames
+
+
+def _requests_settled(engine, streams):
+    """The ``requests`` block once the tick that queued the last first
+    chunk has published its record (a stream's lane side is folded
+    mid-tick, the scheduler's side at the tick's end)."""
+    deadline = time.monotonic() + 10
+    while True:
+        req = engine.genperf_document()["requests"]
+        if (req["first_tokens"] >= streams and req["admitted"] >= streams) \
+                or time.monotonic() > deadline:
+            return req
+        time.sleep(0.01)
+
+
+@pytest.mark.parametrize("lane_gives_recv", [True, False])
+def test_request_stages_sum_to_ttft_for_every_stream(lane_gives_recv):
+    """Per streamed request the four stages (recv -> submit -> admit ->
+    first chunk queued -> handed to the writer) sum to its recv -> writer
+    time, because they are differences of five stamps on one clock: shown
+    stream by stream as deltas of the cumulative block."""
+    engine = EngineService(gen_deployment())
+    try:
+        before = _requests_settled(engine, 0)
+        assert before["streams"] == 0 and before["ttft_s"] == 0.0
+        for n in range(1, 4):
+            t_recv = time.perf_counter() - 0.005 if lane_gives_recv else None
+            asyncio.run(_stream(engine, [1.0 + n] * (3 + n), t_recv))
+            after = _requests_settled(engine, n)
+            assert (after["streams"], after["admitted"],
+                    after["first_tokens"]) == (n, n, n)
+            stages = {k: after["stage_s"][k] - before["stage_s"][k]
+                      for k in after["stage_s"]}
+            assert set(stages) == {"lane_in", "queue", "prefill", "lane_out"}
+            assert all(v >= 0.0 for v in stages.values()), stages
+            assert sum(stages.values()) == pytest.approx(
+                after["ttft_s"] - before["ttft_s"], abs=1e-9)
+            if lane_gives_recv:     # the lane's 5 ms are in lane_in
+                assert stages["lane_in"] >= 0.005
+            assert sum(after["ttft_ms_hist"]["counts"]) == n
+            before = after
+    finally:
+        asyncio.run(engine.close())
+
+
+def test_requests_block_is_monotone_across_two_gets_on_the_fast_lane():
+    """GET /genperf twice around two SSE streams on the lane the benchmark
+    drives: every number of ``requests`` is cumulative, so the second
+    document is nowhere below the first and a window delta is meaningful;
+    a unary request is no stream and leaves the block alone."""
+    from seldon_core_tpu.runtime.httpfast import serve_fast
+
+    engine = EngineService(gen_deployment())
+
+    def flat(req):
+        return ([req["streams"], req["admitted"], req["first_tokens"],
+                 req["ttft_s"]] + [req["stage_s"][k] for k in sorted(
+                     req["stage_s"])] + req["ttft_ms_hist"]["counts"])
+
+    async def run():
+        import aiohttp
+
+        server = await serve_fast(engine, "127.0.0.1", 0)
+        base = f"http://127.0.0.1:{server.port}"
+        docs = []
+        try:
+            async with aiohttp.ClientSession() as sess:
+                for round_ in range(2):
+                    body = {"data": {"ndarray": [[2.0, 3.0, 4.0 + round_]]},
+                            "chunk": 3}
+                    async with sess.post(
+                            base + "/api/v0.1/generate/stream",
+                            json=body) as r:
+                        assert r.status == 200
+                        assert b'"done": true' in await r.read()
+                    async with sess.post(
+                            base + "/api/v0.1/predictions",
+                            json={"data": {"ndarray": [[5.0, 6.0]]}}) as r:
+                        assert r.status == 200
+                    await asyncio.get_running_loop().run_in_executor(
+                        None, _requests_settled, engine, round_ + 1)
+                    async with sess.get(base + "/genperf") as r:
+                        docs.append((await r.json())["requests"])
+        finally:
+            await server.stop()
+        return docs
+
+    try:
+        first, second = asyncio.run(run())
+    finally:
+        asyncio.run(engine.close())
+    assert first["streams"] == 1 and second["streams"] == 2
+    assert second["admitted"] == 2 and second["first_tokens"] == 2
+    assert all(b >= a for a, b in zip(flat(first), flat(second)))
+    assert first["ttft_ms_hist"]["edges_ms"] == \
+        second["ttft_ms_hist"]["edges_ms"]
+    assert second["stage_s"]["lane_in"] > 0.0   # the lane stamped t_recv
+
+
+# -- scheduler phases on the profiler's clock ---------------------------------
+
+PHASE_NAMES = {
+    "GenServer._tick", "GenServer._admit", "GenServer._retire",
+    "GenServer._publish", "GenServer._run/wait",
+    "GenServer._prefill_tick", "GenServer._prefill_tick/build",
+    "GenServer._prefill_tick/device", "GenServer._prefill_tick/readback",
+    "GenServer._prefill_tick/emit",
+    "GenServer._decode_round", "GenServer._decode_round/capacity",
+    "GenServer._decode_round/build", "GenServer._decode_round/device",
+    "GenServer._decode_round/readback", "GenServer._decode_round/emit",
+}
+
+
+def test_scheduler_opens_every_phase_annotation_properly_nested(
+        params, monkeypatch):
+    """With jax.profiler.TraceAnnotation replaced by a recorder (no
+    profiler session), one tiny scheduler run opens every phase name the
+    trace reductions look for, as a well-formed stack on the scheduler
+    thread, each sub-phase inside its function inside ``_tick``, and the
+    ``/device`` phases carry what rode the dispatch."""
+    import threading
+
+    log = []          # (thread id, "B"/"E", name, args)
+
+    class Recorder:
+        def __init__(self, name, **args):
+            self.name, self.args = name, args
+
+        def __enter__(self):
+            log.append((threading.get_ident(), "B", self.name, self.args))
+            return self
+
+        def __exit__(self, *exc):
+            log.append((threading.get_ident(), "E", self.name, self.args))
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Recorder)
+    srv = _server(params)
+    try:
+        reqs = [srv.submit(np.full((1, 6), i + 1.0)) for i in range(3)]
+        chunks = list(srv.stream(np.full((1, 5), 7.0), chunk=3))
+        for r in reqs:
+            r.future.result(timeout=30)
+        _settle(srv)
+        time.sleep(0.05)        # the scheduler parks in _run/wait
+    finally:
+        srv.stop()
+    assert sum(c.shape[1] for c in chunks) == 10
+    assert len({t for t, *_ in log}) == 1, "phases off the scheduler thread"
+    stack, parents, device_args = [], {}, {}
+    for _, kind, name, args in log:
+        if kind == "B":
+            parents.setdefault(name, set()).add(stack[-1] if stack else None)
+            stack.append(name)
+            if name.endswith("/device"):
+                device_args.setdefault(name, []).append(args)
+        else:
+            assert stack and stack.pop() == name, f"{name} closed out of turn"
+    # stop() can catch the scheduler parked: at most the wait is left open
+    assert stack in ([], ["GenServer._run/wait"])
+    assert set(parents) == PHASE_NAMES
+    assert parents["GenServer._tick"] == {None}
+    assert parents["GenServer._run/wait"] == {None}
+    for name, ups in parents.items():
+        if "/" in name and name != "GenServer._run/wait":
+            assert ups == {name.split("/")[0]}, (name, ups)
+        elif name not in ("GenServer._tick", "GenServer._run/wait"):
+            assert ups == {"GenServer._tick"}, (name, ups)
+    for name, calls in device_args.items():
+        for args in calls:
+            assert {"rows", "real_rows", "nblk", "kv_positions"} <= set(args)
+            assert 1 <= args["real_rows"] <= args["rows"]
+            assert args["nblk"] >= 1 and args["kv_positions"] > 0
+    assert any(a["real_rows"] > 1
+               for a in device_args["GenServer._decode_round/device"])
 
 
 def test_new_metric_families_registered():

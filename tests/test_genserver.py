@@ -592,3 +592,49 @@ def test_gen_metric_families_exported():
         text = RECORDER.exposition().decode()
         assert "seldon_tpu_gen_kv_blocks" in text
         assert 'state="high_water"' in text
+
+
+# -- program stages as named scopes -------------------------------------------
+
+BLOCK_SCOPES = ("qkv", "rope", "kv_write", "kv_gather", "attn", "wo", "ffn")
+
+
+def _lowered_paged(program: str, params):
+    from seldon_core_tpu.models.generate import (
+        init_block_pool,
+        paged_decode_round_jit,
+        paged_forward_jit,
+    )
+
+    B, nblk = 2, 2
+    pool = init_block_pool(CFG, 8, 4)
+    tables = jnp.zeros((B, nblk), jnp.int32)
+    if program == "paged_forward":
+        return paged_forward_jit.lower(
+            params, jnp.zeros((B, 4), jnp.int32), pool, tables,
+            jnp.zeros((B,), jnp.int32), jnp.full((B,), 4, jnp.int32),
+            cfg=CFG, last_only=True)
+    return paged_decode_round_jit.lower(
+        params, pool, tables, jnp.zeros((B,), jnp.int32),
+        jnp.full((B,), 3, jnp.int32), jnp.ones((B,), bool),
+        jnp.zeros((B,), bool), jnp.zeros((B,), jnp.uint32), CFG, span=3,
+        temperature=0.0, top_k=0, top_p=1.0, eos_token=-1)
+
+
+@pytest.mark.parametrize("program,scopes", [
+    ("paged_forward", ("embed",) + BLOCK_SCOPES + ("unembed",)),
+    ("paged_decode_round",
+     ("embed",) + BLOCK_SCOPES + ("unembed", "sample")),
+])
+def test_paged_programs_name_every_stage(program, scopes, params):
+    """Each stage of the paged programs is a jax.named_scope, so a device
+    op's metadata says which stage it belongs to (a profile window's ops
+    are otherwise all ``fusion.N``); bench/lib/trace_scopes.py reads them,
+    so the names are part of that metric's definition."""
+    import re
+
+    text = _lowered_paged(program, params).as_text(debug_info=True)
+    named = set(re.findall(r'loc\("(?:[^"]*/)?(\w+)/\w', text))
+    assert set(scopes) <= named, set(scopes) - named
+    if program == "paged_forward":      # prefill picks no token
+        assert "sample" not in named
