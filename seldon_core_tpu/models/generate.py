@@ -87,7 +87,8 @@ def _eager(x) -> bool:
 __all__ = ["init_cache", "init_chunk", "prefill", "decode_step",
            "generate", "stream_chunks", "sample_token", "mask_after_eos",
            "build_prefix_main",
-           "init_block_pool", "paged_forward", "paged_decode_round",
+           "init_block_pool", "decode_inplace", "paged_forward",
+           "paged_decode_round",
            "paged_spec_round", "paged_write_prefix_blocks",
            "paged_write_prefix_tail",
            "TransformerGenerator"]
@@ -96,9 +97,8 @@ __all__ = ["init_cache", "init_chunk", "prefill", "decode_step",
 def init_cache(cfg: LMConfig, batch: int, max_len: int) -> Dict[str, Any]:
     # K/V stored at the GROUPED head count (cfg.kv_heads): with GQA the
     # cache — the HBM stream every decode step pays for — shrinks by
-    # n_heads/n_kv_heads.  NOT rounded up to the flash-decode block: that
-    # kernel is unwired (measured slower, see ops/flash_decode.py), and
-    # padding would bill every decode step for masked slots.
+    # n_heads/n_kv_heads.  Allocated at EXACTLY max_len: padding would
+    # bill every decode step for masked slots.
     # kv_quant="int8" stores int8 values + per-token-per-head f32 scales
     # ([B, KV, L] — ~6% size overhead at hd=64), halving the stream.
     hd = cfg.d_model // cfg.n_heads
@@ -370,12 +370,10 @@ def _attend_cached(q, cache_layer, n_valid):
     """q [B,H,1,hd] against the (possibly grouped, possibly int8) cache
     layer {k, v, k_s?, v_s?}; positions >= n_valid (scalar) masked.
 
-    Deliberately the grouped-XLA formulation: the fused Pallas
-    flash-decode kernel (ops/flash_decode.py) was measured SLOWER here —
-    a (B*KV, L/128) grid serializes tiny per-step dots where XLA runs
-    the whole batch as a few large batched dots (see that module's
-    docstring for numbers).  Keep the dots batched; revisit only with a
-    batch-blocked kernel design."""
+    Deliberately the grouped-XLA formulation: over a DENSE cache XLA runs
+    the whole batch as a few large batched dots, and a (B*KV, L/128)
+    kernel grid that serialized tiny per-step dots measured 1.6-2.3x
+    slower.  (The PAGED pool is another matter: see _paged_block.)"""
     s = _grouped_qk(q, cache_layer["k"], cache_layer.get("k_s"))
     valid = jnp.arange(cache_layer["k"].shape[2]) < n_valid  # [L]
     s = jnp.where(valid[None, None, None, None, :], s, -1e30)
@@ -993,13 +991,19 @@ def stream_chunks(params, prompt, cfg: LMConfig, max_new_tokens: int,
 #                          verify + greedy acceptance (speculative decoding
 #                          on the serving path)
 #
-# Reads GATHER the row's blocks into a position-ordered dense view
-# (pool[tables] — the pure-XLA formulation of paged attention; a Pallas
-# block-table kernel is future work, and the repo's flash-decode precedent
-# says measure before fusing).  Writes SCATTER fresh K/V at
-# (table[pos // bs], pos % bs) — the vLLM reshape_and_cache shape.  Block 0
-# is a reserved SCRATCH block: masked rows and pad positions write there,
-# so inactive slots never need a branch.
+# Reads come in two formulations of one contract (attention of each row over
+# its own blocks, positions <= the query's): the GATHER path builds a
+# position-ordered dense view (pool[tables], pure XLA: _paged_view +
+# _attend_paged) and serves every width, dtype, backend and mesh; the
+# IN-PLACE path (ops/paged_attention.py, a Pallas TPU kernel) reads a row's
+# blocks where they lie and serves the width-1 decode step where
+# ops.paged_attention.inplace_supported says so.  The gather path is the
+# reference the kernel is tested against.  Writes SCATTER fresh K/V at
+# (table[pos // bs], pos % bs) — the vLLM reshape_and_cache shape — and the
+# pool's layout is the one XLA's TPU scatter wants (a token's KV x hd
+# window minor-most): any other makes XLA re-lay the whole pool around
+# every write.  Block 0 is a reserved SCRATCH block: masked rows and pad
+# positions write there, so inactive slots never need a branch.
 
 
 def init_block_pool(cfg: LMConfig, num_blocks: int, block_size: int
@@ -1099,10 +1103,16 @@ def _attend_paged(q, view, start):
     return _grouped_pv(p, view["v"], q.shape, q.dtype, view.get("v_s"))
 
 
-def _paged_block(lp, x, pool_layer, tables, start, valid, cfg: LMConfig):
+def _paged_block(lp, x, pool_layer, tables, start, valid, cfg: LMConfig,
+                 plan=None, interpret: bool = False):
     """One decoder block over the paged pool: K/V written at per-row
     positions start[b] + i (scratch-routed where ``valid`` is False),
-    attention over each row's own blocks.  x [B, W, D]."""
+    attention over each row's own blocks.  x [B, W, D].
+
+    ``plan`` (ops.paged_attention.decode_plan, width 1 only) selects the
+    in-place formulation: attention reads the row's blocks from the pool
+    where they lie.  None takes the gather path."""
+    from seldon_core_tpu.ops.paged_attention import paged_decode_attention
     from seldon_core_tpu.ops.quant import lm_matmul
 
     B, W, D = x.shape
@@ -1125,10 +1135,16 @@ def _paged_block(lp, x, pool_layer, tables, start, valid, cfg: LMConfig):
             k = apply_rope(k, positions, cfg.rope_base)
     with jax.named_scope("kv_write"):
         pool_layer = _paged_write(pool_layer, tables, positions, valid, k, v)
-    with jax.named_scope("kv_gather"):
-        view = _paged_view(pool_layer, tables)
+    if plan is None:
+        with jax.named_scope("kv_gather"):
+            view = _paged_view(pool_layer, tables)
     with jax.named_scope("attn"):
-        a = _attend_paged(q, view, start)
+        if plan is None:
+            a = _attend_paged(q, view, start)
+        else:
+            a = paged_decode_attention(
+                q, pool_layer["k"], pool_layer["v"], tables, *plan,
+                interpret=interpret)
         a = a.transpose(0, 2, 1, 3).reshape(B, W, D)
     with jax.named_scope("wo"):
         x = x + lm_matmul(lp, "wo", a, out_dtype=x.dtype)
@@ -1172,12 +1188,32 @@ def paged_forward(params, tokens, pool, tables, start, width,
     return (logits[:, 0, :] if last_only else logits), pool
 
 
+def decode_inplace(pool, mesh=None) -> bool:
+    """Whether a decode round over ``pool`` attends in place (the Pallas
+    kernel) or through the gather path: ops.paged_attention
+    .inplace_supported over what is observable here — the backend, the
+    pool's dtype and shapes, and the caller's mesh."""
+    from seldon_core_tpu.ops.paged_attention import inplace_supported
+
+    k = pool["l0"]["k"]
+    return inplace_supported(
+        width=1, backend=jax.default_backend(), pool_dtype=k.dtype,
+        mesh=mesh, block_size=k.shape[1], kv_heads=k.shape[2],
+        head_dim=k.shape[3])
+
+
 def paged_decode_round(params, pool, tables, token, n_valid, active,
                        seen_eos, keys, cfg: LMConfig, *, span: int,
                        temperature: float, top_k: int, top_p: float,
-                       eos_token: int):
+                       eos_token: int, inplace=None):
     """``span`` cached decode steps for the whole in-flight batch as ONE
     lax.scan — the scheduler's unit of work between admission points.
+
+    ``inplace``: None decides by ``decode_inplace(pool)`` (a caller that
+    shards the pool over a mesh passes its own answer: a traced program
+    cannot see shardings); True / False force the kernel / the gather
+    path; "interpret" runs the kernel in Pallas interpret mode (tests on
+    the CPU).
 
     token [B] pending tokens; n_valid [B] per-row cache length; active [B]
     masks empty slots (their writes go to scratch, their samples are
@@ -1187,15 +1223,23 @@ def paged_decode_round(params, pool, tables, token, n_valid, active,
     keys [B] per-ROW PRNG keys (sampled decoding must not couple co-batched
     requests the way a shared batch key does).  Returns
     (toks [B, span], pool', token', n_valid', seen_eos', keys')."""
+    from seldon_core_tpu.ops.paged_attention import decode_plan
+
+    if inplace is None:
+        inplace = decode_inplace(pool)
+    capacity = tables.shape[1] * pool["l0"]["k"].shape[1]
 
     def step(carry, _):
         pool, token, n_valid, seen_eos, keys = carry
+        # the kernel's scalar operands: once a step, shared by the layers
+        plan = decode_plan(n_valid, active, capacity) if inplace else None
         with jax.named_scope("embed"):
             x = params["embed"][token][:, None, :]
         for i in range(cfg.n_layers):
             x, pool[f"l{i}"] = _paged_block(
                 params[f"l{i}"], x, pool[f"l{i}"], tables, n_valid,
-                active[:, None], cfg,
+                active[:, None], cfg, plan=plan,
+                interpret=inplace == "interpret",
             )
         with jax.named_scope("unembed"):
             x = _rmsnorm(x, params["ln_f"])
@@ -1348,7 +1392,7 @@ paged_forward_jit = jax.jit(
 paged_decode_round_jit = jax.jit(
     paged_decode_round,
     static_argnames=("cfg", "span", "temperature", "top_k", "top_p",
-                     "eos_token"),
+                     "eos_token", "inplace"),
     donate_argnums=(1,),
 )
 paged_spec_round_jit = jax.jit(

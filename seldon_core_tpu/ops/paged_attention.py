@@ -1,0 +1,270 @@
+"""Paged decode attention over the KV block pool IN PLACE — a Pallas TPU
+kernel for the continuous-batching decode step (models/generate.py
+``_paged_block`` at query width 1).
+
+The XLA formulation of paged attention (``_paged_view`` + ``_attend_paged``)
+gathers every row's blocks into a dense ``[B, KV, nblk*bs, hd]`` copy sized
+by the PADDED row count times the LONGEST row's padded table, for every
+layer of every step.  This kernel reads each row's K/V blocks from the pool
+where they lie, through the block table, and stops at the row's length:
+
+  * one invocation for the whole batch (no grid: the work is latency-bound,
+    a grid step per row x head x block would cost more than the gather);
+  * the LIVE rows only (``order[:count]``, compacted by ``decode_plan``), each
+    walked in chunks of ``blocks_per_chunk`` blocks; a chunk's blocks are
+    DMA'd HBM -> VMEM one descriptor each, all KV heads of a block in one
+    descriptor, the next chunk (of this row or of the next live row) in
+    flight while the current one is computed;
+  * a block past a row's length costs no DMA and no compute; an inactive row
+    costs nothing and yields zeros;
+  * the pool keeps the layout XLA's scatter wants, ``[N, bs, KV, hd]``: a
+    block arrives in VMEM with its KV heads interleaved position by
+    position, so the kernel views the buffer as rows of 32-bit words
+    ``[bs * KV * itemsize / 4, hd]`` (a no-op on the bytes), takes a head's
+    rows by a strided load and, for a 16-bit pool, the head's half of each
+    word by a shift or a mask (``_head_rows``);
+  * online softmax in float32 across a row's chunks; scores accumulate in
+    float32 from pool-dtype operands and ``p`` is cast to the model dtype
+    before ``p @ V`` — the roundings of ``_grouped_qk`` / ``_grouped_pv``.
+
+Why not a pool laid out for the kernel (``[N, KV, bs, hd]``, a head's block
+one plain tile): XLA's TPU scatter re-lays such a pool to ``[N, bs, KV, hd]``
+around every ``_paged_write`` — two whole-pool copies per layer (PERF.md §6,
+PR 25).
+
+Which formulation serves is decided by ONE pure function,
+``inplace_supported``: the kernel for width 1 on a TPU backend with a float
+pool, no mesh (a Mosaic call does not partition under GSPMD) and shapes the
+kernel tiles; the gather path otherwise.  The gather path is also the
+reference the kernel is tested against (tests/test_paged_attention.py).
+"""
+
+from __future__ import annotations
+
+import functools
+from typing import Any, Optional
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+__all__ = ["blocks_per_chunk", "decode_plan", "inplace_supported",
+           "paged_decode_attention"]
+
+_CHUNK_POSITIONS = 512          # K/V positions one compute step covers
+_BUFFER_BYTES = 8 * 1024 * 1024  # both K and V chunks, double-buffered
+_MASK = -1e30                   # generate._attend_paged's mask value
+
+
+def blocks_per_chunk(block_size: int, kv_heads: int, head_dim: int,
+                     itemsize: int, nblk: int) -> int:
+    """Blocks one compute step covers: as many as reach
+    ``_CHUNK_POSITIONS`` positions, at most the table's width, halved until
+    the four chunk buffers (K and V, two slots) fit ``_BUFFER_BYTES``.
+    0 = even one block does not fit."""
+    block_bytes = 4 * kv_heads * block_size * head_dim * itemsize
+    c = max(1, min(_CHUNK_POSITIONS // block_size, nblk))
+    while c > 1 and c * block_bytes > _BUFFER_BYTES:
+        c //= 2
+    return c if c * block_bytes <= _BUFFER_BYTES else 0
+
+
+def inplace_supported(*, width: int, backend: str, pool_dtype: Any,
+                      mesh: Optional[Any], block_size: int, kv_heads: int,
+                      head_dim: int) -> bool:
+    """True where decode attention runs over the pool in place (this
+    module's kernel), False where it takes the gather path.  Decided from
+    what the caller can observe, never from a model's name or a switch."""
+    if width != 1 or backend != "tpu" or mesh is not None:
+        return False
+    dt = jnp.dtype(pool_dtype)
+    if not jnp.issubdtype(dt, jnp.floating):
+        return False
+    # the 32-bit view: a position's KV heads are one memory tile of
+    # 1, 2, 4 or 8 rows of 128 words, and a block is whole (8, 128) tiles
+    rows = kv_heads * dt.itemsize / 4
+    if (dt.itemsize not in (2, 4) or rows not in (1, 2, 4, 8)
+            or head_dim % 128 or block_size * rows % 8):
+        return False
+    return blocks_per_chunk(block_size, kv_heads, head_dim,
+                            dt.itemsize, 1) > 0
+
+
+def decode_plan(n_valid, active, capacity: int):
+    """The kernel's scalar operands for one decode step, shared by every
+    layer: ``lengths`` [B] (``n_valid + 1`` — the row's own fresh K/V is
+    already in the pool — 0 for an inactive row, at most the table's
+    ``capacity``), ``order`` [B] (the live rows' indices first) and
+    ``count`` [1] (how many are live)."""
+    B = n_valid.shape[0]
+    lengths = jnp.where(active, jnp.minimum(n_valid + 1, capacity), 0)
+    lengths = lengths.astype(jnp.int32)
+    seen = jnp.cumsum(lengths > 0)  # live rows up to and including b
+    # the r-th live row's index = rows that come before it
+    order = jnp.sum(seen[None, :] <= jnp.arange(B)[:, None], axis=1)
+    order = jnp.minimum(order, B - 1).astype(jnp.int32)
+    return lengths, order, seen[-1:].astype(jnp.int32)
+
+
+def _head_rows(buf, h: int, dtype):
+    """Head ``h``'s ``[T, hd]`` of a chunk buffer ``[C, bs, KV, hd]``.
+
+    The buffer's bytes, position by position, are the KV heads' rows one
+    after another; as 32-bit words that is ``KV * itemsize / 4`` rows per
+    position.  A 32-bit pool: head h is every KV-th row from h.  A 16-bit
+    pool: heads 2i and 2i+1 share a row, the even head in the low halves of
+    its words — moved up (or masked in place) a half becomes the float32
+    with the same value, and the cast back is exact."""
+    C, bs, KV, hd = buf.shape
+    itemsize = jnp.dtype(dtype).itemsize
+    R = KV * itemsize // 4  # 32-bit rows a position
+    if itemsize == 4:
+        return buf.reshape(C * bs * R, hd)[pl.ds(h, C * bs, stride=R), :]
+    w = buf.bitcast(jnp.uint32).reshape(C * bs * R, hd)[
+        pl.ds(h // 2, C * bs, stride=R), :]
+    w = (w & jnp.uint32(0xFFFF0000)) if h % 2 else (w << 16)
+    return pltpu.bitcast(w, jnp.float32).astype(dtype)
+
+
+def _kernel(len_ref, order_ref, count_ref, tbl_ref, q_ref, k_hbm, v_hbm,
+            o_ref, kbuf, vbuf, sem, m_scr, l_scr, acc_scr, *, nblk: int,
+            p_dtype):
+    B, KV, g, hd = q_ref.shape
+    _, C, bs, _, _ = kbuf.shape
+    T = C * bs
+    scale = jnp.float32(1.0 / (hd ** 0.5))
+
+    o_ref[...] = jnp.zeros_like(o_ref)
+    if C > 1:
+        # a chunk's blocks past the row's length are not fetched: whatever
+        # VMEM held there meets p == 0, which must not be 0 * NaN
+        vbuf[...] = jnp.zeros_like(vbuf)
+
+    def each_copy(b, j, slot, fn):
+        """``fn`` over the DMA descriptors of chunk ``j`` of row ``b``:
+        one for K and one for V per block that holds a live position."""
+        n = len_ref[b]
+        for c in range(C):
+            i = j * C + c
+
+            @pl.when(i * bs < n)
+            def _():
+                blk = tbl_ref[b * nblk + i]
+                fn(pltpu.make_async_copy(
+                    k_hbm.at[blk], kbuf.at[slot, c], sem.at[0, slot]))
+                fn(pltpu.make_async_copy(
+                    v_hbm.at[blk], vbuf.at[slot, c], sem.at[1, slot]))
+
+    def start(b, j, slot):
+        each_copy(b, j, slot, lambda dma: dma.start())
+
+    def wait(b, j, slot):
+        each_copy(b, j, slot, lambda dma: dma.wait())
+
+    count = count_ref[0]
+
+    @pl.when(count > 0)
+    def _():
+        start(order_ref[0], 0, 0)
+
+    def row(r, t):
+        b = order_ref[r]
+        n = len_ref[b]
+        chunks = (n + T - 1) // T
+        next_b = order_ref[jnp.minimum(r + 1, B - 1)]
+        m_scr[...] = jnp.full_like(m_scr, -jnp.inf)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
+
+        def chunk(j, t):
+            slot = t % 2
+            last = j + 1 >= chunks
+
+            @pl.when(jnp.logical_not(last))
+            def _():
+                start(b, j + 1, 1 - slot)
+
+            @pl.when(jnp.logical_and(last, r + 1 < count))
+            def _():
+                start(next_b, 0, 1 - slot)
+
+            wait(b, j, slot)
+            live = (j * T + jax.lax.broadcasted_iota(jnp.int32, (g, T), 1)
+                    < n)
+            for h in range(KV):
+                k = _head_rows(kbuf.at[slot], h, kbuf.dtype)
+                v = _head_rows(vbuf.at[slot], h, vbuf.dtype)
+                s = jax.lax.dot_general(
+                    q_ref[b, h].astype(k.dtype), k,
+                    (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32) * scale
+                s = jnp.where(live, s, _MASK)
+                m_prev = m_scr[h]
+                m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+                alpha = jnp.exp(m_prev - m_new)
+                p = jnp.exp(s - m_new)
+                l_scr[h] = alpha * l_scr[h] + p.sum(axis=-1, keepdims=True)
+                acc_scr[h] = alpha * acc_scr[h] + jnp.dot(
+                    p.astype(p_dtype).astype(v.dtype), v,
+                    preferred_element_type=jnp.float32)
+                m_scr[h] = m_new
+            return t + 1
+
+        t = jax.lax.fori_loop(0, chunks, chunk, t)
+        o_ref[b] = acc_scr[...] / l_scr[...]
+        return t
+
+    jax.lax.fori_loop(0, count, row, jnp.int32(0))
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def paged_decode_attention(q, k_pool, v_pool, tables, lengths, order, count,
+                           *, interpret: bool = False):
+    """Width-1 attention of every live row over its own blocks.
+
+    q [B, H, 1, hd] in the model dtype; k_pool / v_pool
+    ``[N, bs, KV, hd]``; tables [B, nblk] int32; ``lengths, order, count``
+    from ``decode_plan``.  Returns [B, H, 1, hd] in q's dtype; an inactive
+    row's output is zeros.  Jitted so that a program's layers share one
+    trace and one lowering of the kernel."""
+    B, H, _, hd = q.shape
+    _, bs, KV, _ = k_pool.shape
+    nblk = tables.shape[1]
+    g = H // KV
+    gp = -(-g // 8) * 8  # float32 sublane tile
+    C = blocks_per_chunk(bs, KV, hd, k_pool.dtype.itemsize, nblk)
+    if C == 0:
+        raise ValueError(
+            f"one block of {KV} x {bs} x {hd} {k_pool.dtype} does not fit "
+            "the kernel's chunk buffers; take the gather path "
+            "(inplace_supported)")
+    # float32 in and out: the model dtype's values exactly, in (8, 128)
+    # tiles whatever the group size; cast back to the pool dtype in VMEM
+    qg = q.reshape(B, KV, g, hd).astype(jnp.float32)
+    if gp != g:
+        qg = jnp.pad(qg, ((0, 0), (0, 0), (0, gp - g), (0, 0)))
+    out = pl.pallas_call(
+        functools.partial(_kernel, nblk=nblk, p_dtype=q.dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            grid=(1,),
+            in_specs=[
+                pl.BlockSpec(memory_space=pltpu.VMEM),
+                pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec(memory_space=pl.ANY),
+            ],
+            out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
+            scratch_shapes=[
+                pltpu.VMEM((2, C, bs, KV, hd), k_pool.dtype),
+                pltpu.VMEM((2, C, bs, KV, hd), v_pool.dtype),
+                pltpu.SemaphoreType.DMA((2, 2)),
+                pltpu.VMEM((KV, gp, 1), jnp.float32),
+                pltpu.VMEM((KV, gp, 1), jnp.float32),
+                pltpu.VMEM((KV, gp, hd), jnp.float32),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((B, KV, gp, hd), jnp.float32),
+        interpret=interpret,
+    )(lengths, order, count, tables.reshape(-1), qg, k_pool, v_pool)
+    return out[:, :, :g].astype(q.dtype).reshape(B, H, 1, hd)

@@ -433,6 +433,7 @@ class GenServer:
         self._thread: Optional[threading.Thread] = None
         self._stopped = False
         self._pool = None
+        self._inplace = False  # decode attends over the pool in place
         self._device_ready = False
         self._device_init_lock = threading.Lock()
         self._draft_pool = None
@@ -487,6 +488,7 @@ class GenServer:
         self._tick_rows = 0                  # padded rows dispatched
         self._tick_real_rows = 0             # real rows dispatched
         self._tick_dev_steps = 0             # single-token device steps
+        self._tick_inplace_steps = 0         # ... that attended in place
         self._tick_kv_pos = 0                # cache positions streamed
         self._tick_kv_blocks = 0             # blocks the tables covered
         self._tick_kv_ages: List[tuple] = []  # (n_blocks, age_s) freed
@@ -798,6 +800,7 @@ class GenServer:
         # local tick ran (the init lock makes that safe — pool MUTATION
         # stays scheduler-thread-only afterwards)
         from seldon_core_tpu.models.generate import (
+            decode_inplace,
             init_block_pool,
             paged_write_prefix_blocks_jit,
         )
@@ -814,6 +817,9 @@ class GenServer:
             from seldon_core_tpu.runtime.servingmesh import shard_gen_pool
 
             self._pool = shard_gen_pool(self.mesh, self._pool)
+        # the Pallas kernel or the gather path: decided here, once, because
+        # only the scheduler sees the mesh its pool is sharded over
+        self._inplace = decode_inplace(self._pool, self.mesh)
         if self.spec:
             self._draft_pool = init_block_pool(
                 self.draft_cfg, self.num_blocks, self.block_size)
@@ -973,6 +979,7 @@ class GenServer:
         self._dev_s = {}
         self._tick_rows = self._tick_real_rows = 0
         self._tick_dev_steps = self._tick_kv_pos = self._tick_kv_blocks = 0
+        self._tick_inplace_steps = 0
         self._tick_attr = {} if costledger_enabled() else None
         self._tick_kv_attr = []
         self._ensure_device()
@@ -1020,6 +1027,7 @@ class GenServer:
             "real_rows": self._tick_real_rows,
             "tokens": tokens,
             "steps": self._tick_dev_steps,
+            "inplace_steps": self._tick_inplace_steps,
             "kv_positions": self._tick_kv_pos,
             "kv_blocks": self._tick_kv_blocks,
             "kv_ages": tuple(ages),
@@ -1553,12 +1561,14 @@ class GenServer:
                 self.span * (s.n_valid + self.span // 2) for s in batch)
             self._tick_kv_pos += kv_positions
             self._tick_dev_steps += self.span
+            if self._inplace:
+                self._tick_inplace_steps += self.span
         # dispatch -> block_until_ready returns: /genperf's fenced decode
         # seconds, and the annotation the trace sets paged_decode_round's
         # module event against (decode_fence_slack_ms)
         with _Phase("GenServer._decode_round/device", self._dev_s, "decode",
                     rows=B, real_rows=len(batch), nblk=nblk,
-                    kv_positions=kv_positions):
+                    kv_positions=kv_positions, inplace=int(self._inplace)):
             toks, self._pool, _tok, _nv, _seen, keys_out = (
                 paged_decode_round_jit(
                     self.params, self._pool, jnp.asarray(tables),
@@ -1566,7 +1576,7 @@ class GenServer:
                     jnp.asarray(active), jnp.asarray(seen), keys,
                     self.cfg, span=self.span, temperature=self.temperature,
                     top_k=self.top_k, top_p=self.top_p,
-                    eos_token=self.eos_token,
+                    eos_token=self.eos_token, inplace=self._inplace,
                 )
             )
             # fence = the sync np.asarray was about to pay anyway, moved

@@ -110,6 +110,7 @@ class GenPerf:
         self.decode_device_s = 0.0
         self.decode_tokens = 0       # REAL tokens emitted by decode ticks
         self.decode_steps = 0        # single-token device steps run
+        self.decode_inplace_steps = 0  # ... that attended in place
         self.decode_kv_positions = 0  # cache positions streamed per step
         self.kv_block_age = Reservoir(1024)   # seconds held at release
         self.kv_blocks_released = 0
@@ -175,6 +176,8 @@ class GenPerf:
                     dev_phases.get("decode", 0.0))
                 self.decode_tokens += int(detail.get("tokens", 0) or 0)
                 self.decode_steps += int(detail.get("steps", 0) or 0)
+                self.decode_inplace_steps += int(
+                    detail.get("inplace_steps", 0) or 0)
                 self.decode_kv_positions += int(
                     detail.get("kv_positions", 0) or 0)
             for n_blocks, age_s in kv_ages:
@@ -224,11 +227,15 @@ class GenPerf:
             dev_s = self.decode_device_s
             tokens = self.decode_tokens
             steps = self.decode_steps
+            inplace_steps = self.decode_inplace_steps
             kv_pos = self.decode_kv_positions
         out: Dict[str, Any] = {
             "decode_device_s": round(dev_s, 4),
             "real_tokens": tokens,
             "device_steps": steps,
+            # ... of which attended over the block pool in place (the
+            # Pallas kernel, ops/paged_attention.py), not a gathered view
+            "inplace_steps": inplace_steps,
             # live cache positions the single-token steps attended over,
             # summed: the program's own count for a roofline reader
             "kv_positions": kv_pos,

@@ -214,6 +214,23 @@ def test_requests_block_folds_both_sides_and_publishes_kv_positions():
     assert doc["served_decode"]["kv_positions"] == 3200
 
 
+def test_served_decode_counts_the_steps_that_attended_in_place():
+    """``inplace_steps`` rides the tick record beside ``steps``: 0 while
+    decode takes the gather path, equal to ``device_steps`` when every
+    round's tick record says in-place (bench/layer_metrics/
+    decode_inplace_share.json divides the two)."""
+    tick = {"wall_s": 0.02, "device_s": 0.01, "tokens": 16, "steps": 8,
+            "device_phases": {"decode": 0.01}, "phases": {"decode": 0.01}}
+    GENPERF.observe_tick("decode", tick)
+    served = GENPERF.document()["served_decode"]
+    assert (served["device_steps"], served["inplace_steps"]) == (8, 0)
+    GENPERF.reset()
+    for _ in range(3):
+        GENPERF.observe_tick("mixed", {**tick, "inplace_steps": 8})
+    served = GENPERF.document()["served_decode"]
+    assert served["inplace_steps"] == served["device_steps"] == 24
+
+
 def test_tick_error_counter_and_family():
     assert "seldon_tpu_gen_tick_errors_total" in TPU_METRIC_FAMILIES
     before = RECORDER.gen_tick_errors
@@ -249,6 +266,9 @@ def test_scheduler_run_accounts_for_wall(params):
     # the scheduler registered analytic decode-step costs at device init
     assert OBSERVATORY.cost_features("gen_decode_step") is not None
     assert doc["served_decode"]["real_tokens"] > 0
+    # on the CPU decode attends through the gather path
+    assert doc["served_decode"]["device_steps"] > 0
+    assert doc["served_decode"]["inplace_steps"] == 0
 
 
 def test_idle_ticks_accounted(params):

@@ -1,0 +1,277 @@
+"""The in-place paged decode attention kernel (ops/paged_attention.py)
+against the gather path it replaces at width 1 (generate._paged_view +
+_attend_paged): Pallas interpret mode on the CPU, the choosing function, the
+decode round with the in-place path forced, and the kernel compiled for a
+described v5e at the benchmark cell's shapes."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from seldon_core_tpu.models.generate import (
+    _attend_paged,
+    _paged_view,
+    decode_inplace,
+    init_block_pool,
+    paged_decode_round_jit,
+    paged_forward_jit,
+)
+from seldon_core_tpu.models.transformer import LMConfig, lm_init
+from seldon_core_tpu.ops.paged_attention import (
+    blocks_per_chunk,
+    decode_plan,
+    inplace_supported,
+    paged_decode_attention,
+)
+
+KV, HD = 2, 128
+
+
+def _case(bs, g, dtype, n_valid, active, nblk, seed=0):
+    """Random q and pools, and tables of scrambled, non-contiguous block
+    ids; an inactive row's table is all zeros (the scheduler's padding)."""
+    rng = np.random.default_rng(seed)
+    B, H = len(n_valid), KV * g
+    N = B * nblk + 3
+    q = jnp.asarray(rng.normal(size=(B, H, 1, HD)), dtype)
+    pool = {name: jnp.asarray(rng.normal(size=(N, bs, KV, HD)), dtype)
+            for name in ("k", "v")}
+    ids = rng.permutation(np.arange(1, N))[:B * nblk].reshape(B, nblk)
+    tables = np.where(np.asarray(active)[:, None], ids, 0).astype(np.int32)
+    return (q, pool, jnp.asarray(tables), jnp.asarray(n_valid, jnp.int32),
+            jnp.asarray(active))
+
+
+def _both(q, pool, tables, n_valid, active):
+    want = _attend_paged(q, _paged_view(pool, tables), n_valid)
+    capacity = tables.shape[1] * pool["k"].shape[1]
+    got = paged_decode_attention(
+        q, pool["k"], pool["v"], tables,
+        *decode_plan(n_valid, active, capacity), interpret=True)
+    return np.asarray(got, np.float32), np.asarray(want, np.float32)
+
+
+def _tol(dtype):
+    # bf16: one rounding of p (2^-9) and one of the output, |v| <~ 4
+    return 3e-2 if dtype == jnp.bfloat16 else 2e-5
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("g", [1, 4, 12])
+@pytest.mark.parametrize("bs", [16, 256])
+def test_kernel_matches_gather_path_on_ragged_rows(bs, g, dtype):
+    """Lengths 1, exactly a block, a block + 1, mid-table and the full
+    table; one inactive row between live ones."""
+    nblk = 4
+    lengths = [1, bs, bs + 1, 0, 2 * bs + bs // 2, nblk * bs]
+    active = [n > 0 for n in lengths]
+    n_valid = [max(n - 1, 0) for n in lengths]  # the kernel reads n_valid + 1
+    got, want = _both(*_case(bs, g, dtype, n_valid, active, nblk))
+    live = np.asarray(active)
+    np.testing.assert_allclose(got[live], want[live], atol=_tol(dtype),
+                               rtol=0)
+    assert np.isfinite(got).all()
+    assert (got[~live] == 0).all()  # zeros, never NaN
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("bs", [16, 256])
+def test_kernel_ignores_table_entries_past_a_rows_length(bs, dtype):
+    """A table padded past every row's need: the entries past a row's
+    length point at blocks full of NaN, which the kernel must never read
+    (the gather path is given the same rows over clean blocks)."""
+    nblk = 8
+    n_valid = [0, bs - 2, bs + 3, 2 * bs - 1]
+    active = [True] * 4
+    q, pool, tables, nv, act = _case(bs, 4, dtype, n_valid, active, nblk,
+                                     seed=1)
+    _, want = _both(q, pool, tables, nv, act)
+    need = np.asarray(n_valid) // bs + 1
+    past = np.asarray(tables)[np.arange(nblk)[None, :] >= need[:, None]]
+    poisoned = {name: arr.at[past].set(jnp.nan) for name, arr in pool.items()}
+    got, _ = _both(q, poisoned, tables, nv, act)
+    np.testing.assert_allclose(got, want, atol=_tol(dtype), rtol=0)
+
+
+def test_all_rows_inactive_gives_zeros():
+    q, pool, tables, nv, act = _case(16, 4, jnp.float32, [0, 0], [False] * 2,
+                                     2)
+    got, _ = _both(q, pool, tables, nv, act)
+    assert (got == 0).all()
+
+
+def test_decode_plan_compacts_live_rows():
+    lengths, order, count = decode_plan(
+        jnp.asarray([5, 0, 31, 7], jnp.int32),
+        jnp.asarray([True, False, True, True]), 32)
+    assert lengths.tolist() == [6, 0, 32, 8]  # n_valid + 1, capped
+    assert order.tolist()[:3] == [0, 2, 3] and count.tolist() == [3]
+
+
+@pytest.mark.parametrize("kw,want", [
+    ({}, True),
+    ({"width": 2}, False),                    # prefill chunk, verify pass
+    ({"pool_dtype": jnp.int8}, False),        # quantized pool
+    ({"mesh": object()}, False),              # GSPMD cannot partition it
+    ({"backend": "cpu"}, False),
+    ({"pool_dtype": jnp.float32}, True),
+    ({"block_size": 16}, True),
+    ({"head_dim": 64}, False),                # not a 128-lane row
+    ({"kv_heads": 6}, False),                 # not a memory tile
+    ({"kv_heads": 1}, False),                 # half a word a position
+    ({"kv_heads": 1, "pool_dtype": jnp.float32}, True),
+], ids=["cell", "wide", "int8", "mesh", "cpu", "f32", "bs16", "hd64", "kv6",
+        "kv1-bf16", "kv1-f32"])
+def test_inplace_supported_chooses_by_what_it_can_observe(kw, want):
+    base = dict(width=1, backend="tpu", pool_dtype=jnp.bfloat16, mesh=None,
+                block_size=256, kv_heads=2, head_dim=128)
+    assert inplace_supported(**{**base, **kw}) is want
+
+
+def test_chunks_cover_512_positions_within_the_tables_width():
+    assert blocks_per_chunk(256, 2, 128, 2, 4) == 2
+    assert blocks_per_chunk(16, 2, 128, 2, 8) == 8      # the table's width
+    assert blocks_per_chunk(16, 2, 128, 2, 64) == 32
+    assert blocks_per_chunk(4096, 8, 128, 4, 4) == 0    # one block > budget
+
+
+CFG = LMConfig(vocab=64, d_model=64, n_heads=4, n_kv_heads=2, n_layers=2,
+               d_ff=128, dtype=jnp.float32)
+
+
+def test_decode_round_in_place_emits_the_gather_paths_tokens():
+    """Three rounds of paged_decode_round with the in-place path forced
+    through the function's own argument (interpret mode): the same greedy
+    tokens and the same pool as the gather path."""
+    params = lm_init(jax.random.key(0), CFG)
+    bs, nblk, span, B = 4, 8, 3, 4
+    rng = np.random.default_rng(5)
+    lens = [3, 6, 9]                       # row 3 is an empty slot
+    toks = np.zeros((B, 12), np.int32)
+    for i, n in enumerate(lens):
+        toks[i, :n] = rng.integers(0, CFG.vocab, n)
+    tables = np.zeros((B, nblk), np.int32)
+    ids = rng.permutation(np.arange(1, 1 + 3 * nblk))
+    for i in range(3):
+        tables[i] = ids[i * nblk:(i + 1) * nblk]
+    width = np.asarray(lens + [0], np.int32)
+    active = jnp.asarray(width > 0)
+
+    def run(inplace):
+        pool = init_block_pool(CFG, 1 + 3 * nblk, bs)
+        logits, pool = paged_forward_jit(
+            params, jnp.asarray(toks), pool, jnp.asarray(tables),
+            jnp.zeros((B,), jnp.int32), jnp.asarray(width), cfg=CFG)
+        token = jnp.argmax(logits, -1).astype(jnp.int32)
+        n_valid, seen = jnp.asarray(width), jnp.zeros((B,), bool)
+        keys, out = jnp.zeros((B,), jnp.uint32), []
+        for _ in range(3):
+            t, pool, token, n_valid, seen, keys = paged_decode_round_jit(
+                params, pool, jnp.asarray(tables), token, n_valid, active,
+                seen, keys, CFG, span=span, temperature=0.0, top_k=0,
+                top_p=0.0, eos_token=-1, inplace=inplace)
+            out.append(np.asarray(t))
+        return np.concatenate(out, axis=1), pool
+
+    toks_gather, pool_gather = run(False)
+    toks_inplace, pool_inplace = run("interpret")
+    np.testing.assert_array_equal(toks_inplace, toks_gather)
+    live = np.asarray(tables[:3]).reshape(-1)     # not the scratch block
+    for li in pool_gather:
+        for name in ("k", "v"):
+            np.testing.assert_allclose(
+                np.asarray(pool_inplace[li][name])[live],
+                np.asarray(pool_gather[li][name])[live], atol=1e-5, rtol=0)
+
+
+def test_the_cpu_takes_the_gather_path():
+    pool = init_block_pool(CFG, 4, 16)
+    assert decode_inplace(pool) is False
+
+
+def test_init_cache_exact_length():
+    """Dense caches allocate EXACTLY the requested length: padding would
+    bill every decode step for masked slots."""
+    from seldon_core_tpu.models.generate import init_cache
+
+    cfg = LMConfig(vocab=64, d_model=64, n_heads=4, n_layers=1, d_ff=128)
+    c = init_cache(cfg, batch=2, max_len=130)
+    assert c["l0"]["k"].shape[2] == 130
+
+
+@pytest.mark.slow  # heavyweight equivalence check: full-suite/CI-shard coverage; excluded from the tier-1 time budget
+def test_generate_matches_teacher_forced_lm_apply():
+    """Greedy generate over the dense cache equals teacher forcing through
+    lm_apply, which has no cache at all."""
+    from seldon_core_tpu.models.generate import generate
+    from seldon_core_tpu.models.transformer import lm_apply
+
+    cfg = LMConfig(vocab=64, d_model=64, n_heads=4, n_layers=2, d_ff=128,
+                   dtype=jnp.float32)
+    params = lm_init(jax.random.key(0), cfg)
+    prompt = jnp.asarray(
+        np.random.default_rng(2).integers(0, 64, size=(2, 7)), jnp.int32
+    )
+    toks = np.asarray(generate(params, prompt, cfg, max_new_tokens=5))
+    full = np.asarray(prompt)
+    for i in range(5):
+        logits = np.asarray(lm_apply(params, jnp.asarray(full), cfg))
+        nxt = logits[:, -1, :].argmax(-1)
+        np.testing.assert_array_equal(nxt, toks[:, i])
+        full = np.concatenate([full, nxt[:, None].astype(np.int32)], axis=1)
+
+
+# -- compiled for the chip, without the chip ---------------------------------
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.mark.parametrize("B,H,kv,bs,nblk,dtype", [
+    (16, 24, 2, 256, 4, jnp.bfloat16),   # the benchmark cell's decode shape
+    (32, 24, 2, 256, 8, jnp.bfloat16),
+    (8, 8, 4, 16, 16, jnp.bfloat16),     # the program's default block size
+    (8, 8, 2, 16, 4, jnp.float32),
+], ids=["cell-16x4", "cell-32x8", "bs16-kv4", "f32"])
+def test_kernel_compiles_for_a_described_v5e(one_chip, B, H, kv, bs, nblk,
+                                             dtype):
+    """Mosaic accepts the kernel at real widths: the 32-bit view of the
+    interleaved block, the strided head loads and the chunk buffers'
+    VMEM.  A compile, not a run: it says nothing about results or time."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    def s(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    assert inplace_supported(width=1, backend="tpu", pool_dtype=dtype,
+                             mesh=None, block_size=bs, kv_heads=kv,
+                             head_dim=HD)
+    N = 64
+    # a compile for a described chip is written to the persistent cache
+    # but can never be read back here: keep it out
+    cache_was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        compiled = paged_decode_attention.lower(
+            s((B, H, 1, HD), dtype), s((N, bs, kv, HD), dtype),
+            s((N, bs, kv, HD), dtype), s((B, nblk), jnp.int32),
+            s((B,), jnp.int32), s((B,), jnp.int32), s((1,), jnp.int32),
+        ).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache_was)
+        compilation_cache.reset_cache()
+    assert "tpu_custom_call" in compiled.as_text()
