@@ -1,7 +1,10 @@
-"""The plain reference: the block the configuration files DECLARE, in
-straightforward ``jax.numpy`` and float32 under
+"""The plain reference of the ``dense_gelu`` block: what a configuration
+file that names ``"arch": "dense_gelu"`` DECLARES, in straightforward
+``jax.numpy`` and float32 under
 ``jax.default_matmul_precision("highest")`` — no cache, no paging, no
-batching tricks, no code of the program under test.
+batching tricks, no code of the program under test.  Sizes come from the
+configuration file (its published keys), never from the program's own
+``cfg``.
 
 The declared block ("<model>'s sizes through the repo's block"):
 token embedding -> N x [ pre-RMSNorm (no bias, eps 1e-6) -> fused bias-free
@@ -78,11 +81,13 @@ def head(embed, ln_f, x):
         return x @ embed.astype(jnp.float32).T
 
 
-def forward(params, tokens, *, n_layers: int, n_heads: int, n_kv: int,
-            theta: float):
-    """tokens [B, S] int32 -> logits [B, S, V] float32."""
+def forward(params, tokens, config: dict):
+    """tokens [B, S] int32 -> logits [B, S, V] float32, at the sizes
+    ``config`` (a configuration file's document) publishes."""
     x = params["embed"][tokens].astype(jnp.float32)
-    for i in range(n_layers):
-        x = layer(params[f"l{i}"], x, n_heads=n_heads, n_kv=n_kv,
-                  theta=float(theta))
+    for i in range(config["num_hidden_layers"]):
+        x = layer(params[f"l{i}"], x,
+                  n_heads=config["num_attention_heads"],
+                  n_kv=config["num_key_value_heads"],
+                  theta=float(config["rope_theta"]))
     return head(params["embed"], params["ln_f"], x)

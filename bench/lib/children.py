@@ -3,7 +3,8 @@ JSON object as its last stdout line.
 
   numerics    the program's ``paged_forward`` prefill followed by
               ``paged_decode_round`` at the configuration's full widths
-              against lib/reference.py, on a seeded sample.
+              against the plain reference of the block the configuration
+              names (archs/<arch>/reference.py), on a seeded sample.
 
     python bench/lib/children.py numerics <spec.json>
 """
@@ -34,20 +35,18 @@ def _setup(spec: dict):
     return jax, device
 
 
-def _unit(spec: dict):
-    """The program's own generator unit, built from the same parameters the
-    deployment file carries — so ``cfg`` is the object the engine jits with."""
-    from seldon_core_tpu.models.generate import TransformerGenerator
+def build_unit(unit: dict):
+    """The program's own generator unit, built the way the engine builds
+    it from a deployment file (``graph/units.py``): the class the
+    ``class_path`` resolves to, with the typed parameter list as its
+    keywords — so ``cfg`` is the object the engine jits with, and nothing
+    the configuration passes can be dropped on the way."""
+    from seldon_core_tpu.graph.spec import Parameter, params_to_kwargs
+    from seldon_core_tpu.graph.units import resolve_unit_class
 
-    p = {d["name"]: d["value"] for d in spec["parameters"]}
-    return TransformerGenerator(
-        vocab=int(p["vocab"]), d_model=int(p["d_model"]),
-        n_heads=int(p["n_heads"]), n_kv_heads=int(p["n_kv_heads"]),
-        n_layers=int(p["n_layers"]), d_ff=int(p["d_ff"]),
-        rope_base=float(p["rope_base"]), seed=int(p["seed"]),
-        max_new_tokens=int(p["max_new_tokens"]),
-        temperature=float(p["temperature"]), eos_token=int(p["eos_token"]),
-        dtype=p["dtype"])
+    cls = resolve_unit_class(unit["class_path"])
+    return cls(**params_to_kwargs(
+        [Parameter.from_json_dict(d) for d in unit["parameters"]]))
 
 
 def numerics(spec: dict) -> dict:
@@ -55,24 +54,25 @@ def numerics(spec: dict) -> dict:
     import jax.numpy as jnp
     import numpy as np
 
-    from lib import reference
+    from lib.manifest import arch_module
     from seldon_core_tpu.models.generate import (
         init_block_pool,
         paged_decode_round_jit,
         paged_forward_jit,
     )
 
-    unit = _unit(spec)
+    reference = arch_module(spec["bench_dir"], spec["config"], "reference")
+    unit = build_unit(spec["unit"])
     cfg, dep = unit.cfg, spec["deployment"]
     params = unit.init_state(None)["params"]
     pool = init_block_pool(cfg, dep["pool_blocks"], dep["block_size"])
-    bs, span, C = dep["block_size"], dep["span"], dep["prefill_chunk"]
+    span, C = dep["span"], dep["prefill_chunk"]
     lens = spec["sample_lens"]           # e.g. [40, 33]: one program (B, C, 4)
     B = len(lens)
     rng = np.random.default_rng(spec["sample_seed"])
     toks = np.zeros((B, C), np.int32)
     for i, n in enumerate(lens):
-        toks[i, :n] = rng.integers(0, cfg.vocab, n)
+        toks[i, :n] = rng.integers(0, spec["config"]["vocab_size"], n)
     nblk = spec["sample_blocks"]
     tables = np.zeros((B, nblk), np.int32)
     for i in range(B):
@@ -93,8 +93,6 @@ def numerics(spec: dict) -> dict:
     sys_toks = np.asarray(out)            # [B, span]
     del pool
 
-    kw = dict(n_layers=cfg.n_layers, n_heads=cfg.n_heads,
-              n_kv=cfg.kv_heads, theta=cfg.rope_base)
     worst_prefill = 0.0
     worst_margin = 0.0
     rms = []
@@ -105,7 +103,7 @@ def numerics(spec: dict) -> dict:
         # positions n .. n+span-1 the logits each decode step chose from
         seq = np.concatenate([toks[i, :n], first[i:i + 1], sys_toks[i]])
         ref = np.asarray(reference.forward(
-            params, jnp.asarray(seq[None, :-1]), **kw))[0]
+            params, jnp.asarray(seq[None, :-1]), spec["config"]))[0]
         rms.append(float(np.sqrt(np.mean(ref[n - 1] ** 2))))
         worst_prefill = max(worst_prefill,
                             float(np.abs(ref[n - 1] - sys_logits[i]).max()))

@@ -43,21 +43,39 @@ def free_port() -> int:
         return s.getsockname()[1]
 
 
-def deployment_doc(config: dict, dep: dict, seed: int, max_new: int) -> dict:
-    """The SeldonDeployment a run boots: the configuration's sizes as the
-    TransformerGenerator's parameters, weights from ``seed``."""
-    params = {
-        "vocab": config["vocab_size"], "d_model": config["hidden_size"],
-        "n_heads": config["num_attention_heads"],
-        "n_kv_heads": config["num_key_value_heads"],
-        "n_layers": config["num_hidden_layers"],
-        "d_ff": config["intermediate_size"],
-        "rope_base": config["rope_theta"],
+_PARAM_TYPES = {int: "INT", float: "FLOAT", str: "STRING", bool: "BOOL"}
+
+
+def unit_spec(config: dict, dep: dict, seed: int, max_new: int) -> dict:
+    """The generator component a run serves: the configuration's ``unit``
+    section — ``class_path`` and ``parameters``, a map from the unit's
+    keyword to ``{"from": "<published key of the same file>"}`` or a
+    literal — and after it only what a run owns: the answer's length cap,
+    the seed of the weights, and sampling and dtype from the deployment."""
+    unit = config["unit"]
+    params = {}
+    for keyword, value in unit["parameters"].items():
+        if isinstance(value, dict):
+            if list(value) != ["from"] or value["from"] not in config:
+                raise KeyError(
+                    f"config {config.get('name')!r}: unit parameter "
+                    f"{keyword!r} names {value!r}, not a key of the file")
+            value = config[value["from"]]
+        params[keyword] = value
+    params.update({
         "max_new_tokens": max_new, "seed": int(seed) % (2 ** 31 - 1),
         "temperature": dep["temperature"], "eos_token": dep["eos_token"],
-        "dtype": dep["dtype"],
-    }
-    types = {int: "INT", float: "FLOAT", str: "STRING"}
+        "dtype": dep["dtype"]})
+    return {
+        "class_path": unit["class_path"],
+        "parameters": [
+            {"name": k, "value": str(v), "type": _PARAM_TYPES[type(v)]}
+            for k, v in params.items()]}
+
+
+def deployment_doc(config: dict, dep: dict, seed: int, max_new: int) -> dict:
+    """The SeldonDeployment a run boots: one in-process generator, the
+    unit the configuration names, weights from ``seed``."""
     return {
         "apiVersion": "machinelearning.seldon.io/v1alpha2",
         "kind": "SeldonDeployment",
@@ -66,10 +84,7 @@ def deployment_doc(config: dict, dep: dict, seed: int, max_new: int) -> dict:
             "name": "main", "replicas": 1,
             "components": [{
                 "name": "gen", "runtime": "inprocess",
-                "class_path": "TransformerGenerator",
-                "parameters": [
-                    {"name": k, "value": str(v), "type": types[type(v)]}
-                    for k, v in params.items()],
+                **unit_spec(config, dep, seed, max_new),
             }],
             "graph": {"name": "gen", "type": "MODEL", "children": []},
         }]},

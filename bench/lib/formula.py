@@ -37,6 +37,20 @@ def delta(before: dict, after: dict, path: str) -> Optional[float]:
     return a - (b or 0.0)
 
 
+def deltas(before: dict, after: dict) -> dict:
+    """Every number of ``after`` less the same number of ``before`` (0
+    where ``before`` lacks it), nested as ``after`` is; what is no number
+    is left out."""
+    out = {}
+    for key, a in after.items():
+        b = before.get(key) if isinstance(before, dict) else None
+        if isinstance(a, dict):
+            out[key] = deltas(b if isinstance(b, dict) else {}, a)
+        elif isinstance(a, (int, float)) and not isinstance(a, bool):
+            out[key] = a - (b if isinstance(b, (int, float)) else 0)
+    return out
+
+
 def _sum(terms, before, after, harness) -> Optional[float]:
     total, found = 0.0, False
     for t in terms:

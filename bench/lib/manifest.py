@@ -2,11 +2,12 @@
 
 A root is a directory that holds ``BENCHMARK.json`` and ``bench/``; the
 default is the checkout run.py lives in.  Tests point it at a temporary
-copy to show that cells, configurations, mixes and layer metrics are added
-by adding files."""
+copy to show that cells, configurations, architectures, mixes and layer
+metrics are added by adding files."""
 
 from __future__ import annotations
 
+import importlib.util
 import json
 import os
 import re
@@ -28,6 +29,32 @@ def _load(path: str) -> dict:
         raise ManifestError(f"no such file: {path}")
     with open(path) as f:
         return json.load(f)
+
+
+_ARCH_MODULES: dict = {}     # file path -> module
+
+
+def arch_module(bench: str, config: dict, module: str):
+    """``<bench>/archs/<config["arch"]>/<module>.py`` as a module: the
+    plain reference (``reference``) or the bytes and FLOPs a program needs
+    (``needs``) of the block a configuration declares.  A configuration
+    without the key, or a key without its file, is an error: there is no
+    default block."""
+    arch = config.get("arch")
+    if not arch or not NAME_RE.match(str(arch)):
+        raise ManifestError(
+            f"config {config.get('name')!r} names no 'arch' "
+            f"(a directory of {os.path.join(bench, 'archs')})")
+    path = os.path.join(bench, "archs", arch, module + ".py")
+    if not os.path.isfile(path):
+        raise ManifestError(f"arch {arch!r}: no such file: {path}")
+    if path not in _ARCH_MODULES:
+        spec = importlib.util.spec_from_file_location(
+            f"bench_arch_{module}_{len(_ARCH_MODULES)}", path)
+        mod = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(mod)
+        _ARCH_MODULES[path] = mod
+    return _ARCH_MODULES[path]
 
 
 class Manifest:

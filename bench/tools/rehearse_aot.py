@@ -3,7 +3,11 @@
 DESCRIBED v5e chip (no chip attached) and print ``memory_analysis()`` and
 the compile seconds.  This is how ``pool_blocks`` in bench/configs/*.json
 was sized; nothing here runs on a device and nothing it prints is a device
-metric.
+metric.  The unit is built as the numerics child builds it
+(lib/children.py ``build_unit``, from the configuration's ``unit``
+section), and the decode round is lowered with ``inplace=True``: the
+program the chip runs, where ``JAX_PLATFORMS=cpu`` alone would pick the
+gather path.
 
     JAX_PLATFORMS=cpu python bench/tools/rehearse_aot.py \
         --config starcoder2-3b --pool-blocks 12000 \
@@ -24,6 +28,7 @@ os.environ.setdefault("JAX_PLATFORMS", "cpu")
 BENCH = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 REPO = os.path.dirname(BENCH)
 sys.path.insert(0, REPO)
+sys.path.insert(0, BENCH)
 
 
 def main() -> None:
@@ -41,21 +46,20 @@ def main() -> None:
     from jax.experimental import topologies
     from jax.sharding import SingleDeviceSharding
 
+    from lib.children import build_unit
+    from lib.engine import unit_spec
     from seldon_core_tpu.models.generate import (
+        init_block_pool,
         paged_decode_round_jit,
         paged_forward_jit,
     )
-    from seldon_core_tpu.models.transformer import LMConfig, lm_init
 
     jax.config.update("jax_enable_compilation_cache", False)
     with open(os.path.join(BENCH, "configs", args.config + ".json")) as f:
         doc = json.load(f)
-    m, dep = doc, doc["deployment"]
-    cfg = LMConfig(
-        vocab=m["vocab_size"], d_model=m["hidden_size"],
-        n_heads=m["num_attention_heads"], n_layers=m["num_hidden_layers"],
-        d_ff=m["intermediate_size"], n_kv_heads=m["num_key_value_heads"],
-        dtype=jnp.bfloat16, rope=True, rope_base=float(m["rope_theta"]))
+    dep = doc["deployment"]
+    unit = build_unit(unit_spec(doc, dep, 0, 1))
+    cfg = unit.cfg
     topo = topologies.get_topology_desc(platform="tpu",
                                         topology_name="v5e:2x2")
     one = SingleDeviceSharding(topo.devices[0])
@@ -66,17 +70,18 @@ def main() -> None:
             tree)
 
     params = on_chip(jax.eval_shape(
-        lambda: lm_init(jax.random.key(0), cfg)))
+        lambda: unit.init_state(None)["params"]))
     n_param = sum(x.size for x in jax.tree.leaves(params))
-    hd = cfg.d_model // cfg.n_heads
-    bs = int(dep["block_size"])
-    layer = {
-        name: jax.ShapeDtypeStruct(
-            (args.pool_blocks, bs, cfg.kv_heads, hd), jnp.bfloat16,
-            sharding=one)
-        for name in ("k", "v")}
-    pool = {f"l{i}": dict(layer) for i in range(cfg.n_layers)}
-    pool_bytes = sum(x.size * 2 for x in jax.tree.leaves(pool))
+    # on the CPU backend init_block_pool stores a bf16 pool as float32
+    # (XLA:CPU has no bf16 scatter); the chip keeps the unit's dtype
+    pool = on_chip(jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(
+            s.shape, cfg.dtype if s.ndim == 4 and s.dtype == jnp.float32
+            else s.dtype),
+        jax.eval_shape(lambda: init_block_pool(
+            cfg, args.pool_blocks, int(dep["block_size"])))))
+    pool_bytes = sum(x.size * x.dtype.itemsize
+                     for x in jax.tree.leaves(pool))
     print(json.dumps({"config": args.config, "params": n_param,
                       "param_bytes": 2 * n_param,
                       "pool_blocks": args.pool_blocks,
@@ -111,7 +116,8 @@ def main() -> None:
             params, pool, arr((B, nblk), jnp.int32), arr((B,), jnp.int32),
             arr((B,), jnp.int32), arr((B,), jnp.bool_), arr((B,), jnp.bool_),
             arr((B,), jnp.uint32), cfg, span=int(dep["span"]),
-            temperature=0.0, top_k=0, top_p=0.0, eos_token=-1))
+            temperature=0.0, top_k=0, top_p=0.0, eos_token=-1,
+            inplace=True))
 
 
 if __name__ == "__main__":

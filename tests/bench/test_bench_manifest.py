@@ -10,7 +10,13 @@ import bench_paths
 import pytest
 from bench_paths import BENCH, REPO, load
 from lib import buckets
-from lib.manifest import NAME_RE, UNIT_RE, Manifest, ManifestError
+from lib.manifest import (
+    NAME_RE,
+    UNIT_RE,
+    Manifest,
+    ManifestError,
+    arch_module,
+)
 
 MAN = Manifest(REPO)
 CELLS = [w["name"] for w in MAN.doc["workloads"]]
@@ -39,10 +45,27 @@ def test_manifest_has_exactly_the_contract_keys():
 
 @pytest.mark.parametrize("kind,names", [
     ("cells", CELLS), ("configs", CONFIGS), ("layer_metrics", PER_LAYER),
-    ("traffic", sorted({w["traffic"] for w in MAN.doc["workloads"]})),
 ])
 def test_every_entry_has_its_file_and_every_file_its_entry(kind, names):
     assert listed(kind) == sorted(names)
+
+
+def test_every_cells_mix_has_its_file_and_every_mix_file_its_cell():
+    """A mix is named by cells, not by the manifest.  A file no cell names
+    fails, unless it says itself which cell it waits for
+    (``awaiting_cell``): ``complete`` was measured in PR 26 and its cell
+    left out (PERF.md section 7)."""
+    used = {w["traffic"] for w in MAN.doc["workloads"]}
+    waiting = {name for name in listed("traffic")
+               if MAN.mix(name).get("awaiting_cell")}
+    assert waiting == {"complete"}
+    assert sorted(used | waiting) == listed("traffic")
+    for name in listed("traffic"):
+        mix = MAN.mix(name)
+        assert mix["name"] == name and NAME_RE.match(name)
+        cp = buckets.caps(mix)
+        assert 0 < cp["min_prompt"] <= cp["max_prompt"]
+        assert cp["max_positions"] + 8 <= 4096
 
 
 def check_cell(man, cell):
@@ -77,6 +100,16 @@ def test_config_file_declares_source_cuts_and_departures(config):
     assert doc["reduced"] == entry["reduced"]
     assert not set(doc["reduced"]) & set(WIDTH_KEYS)   # no width is cut
     assert "sizes through the repo's block" in doc["described_as"]
+    # the block is the configuration's to name: its reference and needs are
+    # files of bench/archs/<arch>/, its unit and the unit's keywords data
+    for module in ("reference", "needs"):
+        assert os.path.isfile(os.path.join(
+            BENCH, "archs", doc["arch"], module + ".py"))
+    assert doc["unit"]["class_path"]
+    for keyword, value in doc["unit"]["parameters"].items():
+        assert NAME_RE.match(keyword)
+        if isinstance(value, dict):
+            assert list(value) == ["from"] and value["from"] in doc
     assert doc["departures"] and doc["assumed"] and doc["hbm"]
     assert doc["hidden_size"] // doc["num_attention_heads"] == 128
     for key in ("block_size", "span", "slots", "pool_blocks",
@@ -139,15 +172,9 @@ def test_names_and_units_stay_within_the_allowed_characters():
 def test_a_cell_config_mix_and_layer_metric_are_added_by_adding_files(
         tmp_path):
     root = bench_paths.copy_root(tmp_path)
-    before = {}
-    for d, _, files in os.walk(root):
-        for f in files:
-            if f != "BENCHMARK.json":
-                p = os.path.join(d, f)
-                before[p] = open(p, "rb").read()
+    before = bench_paths.snapshot(root)
     cell = bench_paths.add_tiny_cell(root)
-    for p, raw in before.items():
-        assert open(p, "rb").read() == raw, f"{p} was edited"
+    bench_paths.assert_untouched(before)
     man = Manifest(root)
     # the copy, with its added entries, still agrees file by file
     for w in man.doc["workloads"]:
@@ -183,6 +210,92 @@ def test_a_cell_config_mix_and_layer_metric_are_added_by_adding_files(
         "genperf_after": {"ticks": {"prefill": 5, "mixed": 4}},
         "harness": {}})
     assert value == 7.0
+
+
+def test_an_architecture_is_added_by_adding_files(tmp_path):
+    """A configuration of ANOTHER block — its reference, its needs, its
+    unit and that unit's keywords — comes as files and manifest entries:
+    no file that was there is edited, the deployment document carries the
+    new parameters verbatim, and the roofline reader takes the new needs."""
+    from lib.engine import deployment_doc
+    from lib.peaks import peaks_for
+    from readers import trace
+
+    root = bench_paths.copy_root(tmp_path)
+    before = bench_paths.snapshot(root)
+    bench_paths.add_tiny_cell(root)
+    cell = bench_paths.add_tiny_arch(root)
+    bench_paths.assert_untouched(before)
+    man = Manifest(root)
+    for w in man.doc["workloads"]:
+        check_cell(man, w["name"])
+    assert sorted(os.listdir(os.path.join(root, "bench", "archs"))) == [
+        "dense_gelu", "tinyarch"]
+    doc = man.cell(cell)
+    cfg = man.config(doc["config"])
+    dep = man.deployment(doc, cfg)
+    # the unit section reaches the SeldonDeployment as it stands: class,
+    # keywords in the file's order, typed; then what a run owns
+    comp = deployment_doc(cfg, dep, 5, 24)["spec"]["predictors"][0][
+        "components"][0]
+    assert comp["class_path"] == "a_test.units:GatedGenerator"
+    got = [(p["name"], p["value"], p["type"]) for p in comp["parameters"]]
+    assert got == [
+        ("vocab", "512", "INT"), ("d_model", "128", "INT"),
+        ("n_heads", "4", "INT"), ("n_kv_heads", "2", "INT"),
+        ("n_layers", "2", "INT"), ("d_ff", "512", "INT"),
+        ("rope_base", "10000.0", "FLOAT"), ("norm_eps", "1e-05", "FLOAT"),
+        ("tie_head", "False", "BOOL"), ("ffn", "gated_silu", "STRING"),
+        ("n_experts", "0", "INT"),
+        ("max_new_tokens", "24", "INT"), ("seed", "5", "INT"),
+        ("temperature", "0.0", "FLOAT"), ("eos_token", "-1", "INT"),
+        ("dtype", "bfloat16", "STRING")]
+    # needs.py of the new directory: three FFN matrices, worked by hand
+    needs = arch_module(man.bench, cfg, "needs")
+    layer = 128 * (128 + 2 * 2 * 32) + 128 * 128 + 3 * 128 * 512
+    assert needs.sizes(cfg)["layer_params"] == layer == 245_760
+    assert needs is not arch_module(man.bench, man.config("starcoder2-3b"), "needs")
+    ctx = {
+        "bench_dir": man.bench, "config": cfg, "deployment": dep,
+        "device": {"kind": "TPU v5 lite"},
+        "trace": {"programs": {
+            "decode": {"seconds": 0.004, "calls": 5},
+            "prefill": {"seconds": 0.002, "calls": 3}}},
+        "traced": {"decode_rows_mean": 3.0,
+                   "decode_live_positions_mean": 120.0,
+                   "prefill_tokens": 90.0, "prefill_attended": 2000.0},
+        "genperf_before": {"served_prefill": {"tokens": 40}},
+        "genperf_after": {"served_prefill": {"tokens": 100}},
+    }
+    weight_bytes = 2 * (2 * layer + 512 * 128)
+    kv_pos = 2 * 2 * 2 * 32 * 2
+    bw = peaks_for("TPU v5 lite")["hbm_bytes_per_s"]
+    decode = trace.read(man.layer_metric("decode_roofline"), ctx)
+    steps = 5 * dep["span"]
+    assert decode == pytest.approx(
+        100.0 * (weight_bytes + kv_pos * 123.0) / bw * steps / 0.004)
+    # ... and the counters it is handed are the window's deltas (60 prompt
+    # tokens counted by the program, not the harness's 90)
+    share = trace.read(man.layer_metric("prefill_roofline"), ctx)
+    assert share == pytest.approx(
+        100.0 * (3 * weight_bytes + 2 * kv_pos * 60.0) / bw / 0.002)
+    assert ctx["bounds"] == {"decode_roofline": "memory",
+                             "prefill_roofline": "memory"}
+    # the same trace under the dense block's needs reads something else
+    dense = {**ctx, "config": {**cfg, "arch": "dense_gelu"}}
+    assert trace.read(man.layer_metric("decode_roofline"), dense) < decode
+
+
+@pytest.mark.parametrize("config,module,says", [
+    ({"name": "c"}, "needs", "names no 'arch'"),
+    ({"name": "c", "arch": "../lib"}, "needs", "names no 'arch'"),
+    ({"name": "c", "arch": "nowhere"}, "needs", "no such file"),
+    ({"name": "c", "arch": "dense_gelu"}, "kernels", "no such file"),
+])
+def test_a_configuration_whose_arch_has_no_files_is_refused(
+        config, module, says):
+    with pytest.raises(ManifestError, match=says):
+        arch_module(MAN.bench, config, module)
 
 
 def test_a_cell_file_that_disagrees_with_the_manifest_is_refused(tmp_path):

@@ -1,30 +1,40 @@
-"""The plain reference (bench/lib/reference.py) against the program at a
+"""The plain reference (bench/archs/dense_gelu/reference.py) against the program at a
 tiny size on the CPU: the dense forward, and prefill followed by a decode
 round through the paged pool."""
 
-import bench_paths  # noqa: F401
+import bench_paths
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from lib import reference
+from lib.manifest import arch_module
 
+reference = arch_module(bench_paths.BENCH, {"arch": "dense_gelu"},
+                        "reference")
+
+# configuration documents: the published keys the reference reads
 SIZES = [
-    dict(vocab=97, d_model=64, n_heads=4, n_kv_heads=2, n_layers=2, d_ff=160),
-    dict(vocab=61, d_model=96, n_heads=6, n_kv_heads=2, n_layers=3, d_ff=128),
+    dict(vocab_size=97, hidden_size=64, num_attention_heads=4,
+         num_key_value_heads=2, num_hidden_layers=2, intermediate_size=160,
+         rope_theta=999999.44),
+    dict(vocab_size=61, hidden_size=96, num_attention_heads=6,
+         num_key_value_heads=2, num_hidden_layers=3, intermediate_size=128,
+         rope_theta=999999.44),
 ]
 
 
 def build(sz, dtype=jnp.float32):
+    """The program's own config and weights at a configuration's sizes;
+    the reference gets the configuration document, never ``cfg``."""
     from seldon_core_tpu.models.transformer import LMConfig, lm_init
 
-    cfg = LMConfig(dtype=dtype, rope=True, rope_base=999999.44, **sz)
+    cfg = LMConfig(
+        dtype=dtype, rope=True, rope_base=sz["rope_theta"],
+        vocab=sz["vocab_size"], d_model=sz["hidden_size"],
+        n_heads=sz["num_attention_heads"],
+        n_kv_heads=sz["num_key_value_heads"],
+        n_layers=sz["num_hidden_layers"], d_ff=sz["intermediate_size"])
     return cfg, lm_init(jax.random.key(3), cfg)
-
-
-def ref_kwargs(cfg):
-    return dict(n_layers=cfg.n_layers, n_heads=cfg.n_heads,
-                n_kv=cfg.kv_heads, theta=cfg.rope_base)
 
 
 @pytest.mark.parametrize("sz", SIZES, ids=["gqa2", "gqa3"])
@@ -35,7 +45,7 @@ def test_reference_equals_the_programs_dense_forward(sz):
     toks = jax.random.randint(jax.random.key(1), (2, 19), 0, cfg.vocab)
     with jax.default_matmul_precision("highest"):
         want = np.asarray(lm_apply(params, toks, cfg))
-    got = np.asarray(reference.forward(params, toks, **ref_kwargs(cfg)))
+    got = np.asarray(reference.forward(params, toks, sz))
     # float32 against float32: only the order of additions differs
     assert np.abs(got - want).max() < 2e-4 * max(1.0, np.abs(want).max())
 
@@ -70,7 +80,7 @@ def test_paged_prefill_then_decode_round_agree_with_the_reference(sz):
     for i, n in enumerate(lens):
         seq = np.concatenate([toks[i, :n], first[i:i + 1], out[i]])
         ref = np.asarray(reference.forward(
-            params, jnp.asarray(seq[None, :-1]), **ref_kwargs(cfg)))[0]
+            params, jnp.asarray(seq[None, :-1]), sz))[0]
         assert np.abs(ref[n - 1] - np.asarray(logits)[i]).max() < 1e-3
         for j in range(span):     # each step chose (nearly) the best logit
             assert ref[n + j].max() - ref[n + j][out[i, j]] < 1e-3
@@ -82,7 +92,7 @@ def test_a_lower_precision_than_bf16_would_fail_the_chip_tolerance():
     inside it, weights rounded to 4 mantissa bits do not."""
     cfg, params = build(SIZES[0])
     toks = jax.random.randint(jax.random.key(2), (2, 24), 0, cfg.vocab)
-    want = np.asarray(reference.forward(params, toks, **ref_kwargs(cfg)))
+    want = np.asarray(reference.forward(params, toks, SIZES[0]))
     rms = float(np.sqrt(np.mean(want ** 2)))
 
     def rounded(bits):
@@ -95,7 +105,7 @@ def test_a_lower_precision_than_bf16_would_fail_the_chip_tolerance():
         return jax.tree.map(f, params)
 
     def err(p):
-        got = np.asarray(reference.forward(p, toks, **ref_kwargs(cfg)))
+        got = np.asarray(reference.forward(p, toks, SIZES[0]))
         return float(np.abs(got - want).max())
 
     assert err(rounded(8)) < 0.1 * rms     # bf16 keeps 8 bits

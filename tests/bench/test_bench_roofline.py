@@ -1,14 +1,16 @@
-"""lib/roofline.py against hand-worked bytes and FLOPs for both
-configurations, the peaks table, and the bucket arithmetic."""
+"""archs/dense_gelu/needs.py and lib/roofline.py against hand-worked bytes
+and FLOPs for two sets of published sizes, the peaks table, and the bucket
+arithmetic."""
 
 import bench_paths  # noqa: F401
 import pytest
 from bench_paths import REPO
 from lib import buckets, roofline
-from lib.manifest import Manifest
+from lib.manifest import Manifest, arch_module
 from lib.peaks import peaks_for
 
 MAN = Manifest(REPO)
+needs = arch_module(MAN.bench, {"name": "a test", "arch": "dense_gelu"}, "needs")
 
 
 # a second set of published sizes for the arithmetic (StarCoder2-7B's,
@@ -39,7 +41,7 @@ HAND = {
 
 @pytest.mark.parametrize("name", sorted(HAND))
 def test_sizes_match_the_hand_worked_numbers(name):
-    s = roofline.sizes(config(name))
+    s = needs.sizes(config(name))
     h = HAND[name]
     assert h["layer"] == h["layer_value"]
     assert s["layer_params"] == h["layer_value"]
@@ -54,7 +56,7 @@ def test_sizes_match_the_hand_worked_numbers(name):
 def test_decode_step_needs_weights_once_and_live_kv_once(name, rows, live):
     cfg = config(name)
     h = HAND[name]
-    need = roofline.decode_step(cfg, rows, live)
+    need = needs.decode_step(cfg, rows, live, {})
     assert need["bytes"] == 2 * h["matmul"] + h["kv_pos"] * (live + rows)
     heads, layers = cfg["num_attention_heads"], cfg["num_hidden_layers"]
     assert need["flops"] == (2 * h["matmul"] * rows
@@ -74,7 +76,7 @@ def test_prefill_is_compute_bound_on_long_chunks_and_counts_real_tokens(
     h = HAND[name]
     tokens, calls = 4 * 256, 1
     attended = 4 * sum(range(1, 257))
-    need = roofline.prefill(cfg, calls, tokens, attended)
+    need = needs.prefill(cfg, calls, tokens, attended, {})
     assert need["bytes"] == 2 * h["matmul"] + 2 * h["kv_pos"] * tokens
     body = h["matmul"] - h["embed"]
     assert need["flops"] == pytest.approx(
@@ -83,7 +85,7 @@ def test_prefill_is_compute_bound_on_long_chunks_and_counts_real_tokens(
         * attended)
     assert roofline.least_seconds(
         need, peaks_for("TPU v5 lite"))["bound"] == "compute"
-    one = roofline.prefill(cfg, 1, 64, sum(range(1, 65)))
+    one = needs.prefill(cfg, 1, 64, sum(range(1, 65)), {})
     assert roofline.least_seconds(
         one, peaks_for("TPU v5 lite"))["bound"] == "memory"
 
