@@ -1,19 +1,29 @@
 """The child that owns the chip once the engine has gone.  It prints ONE
 JSON object as its last stdout line.
 
-  numerics    the program's ``paged_forward`` prefill followed by
-              ``paged_decode_round`` at the configuration's full widths
-              against the plain reference of the block the configuration
-              names (archs/<arch>/reference.py), on a seeded sample.
+  numerics    a batch the deployment really runs (lib/sample.py: as many
+              rows as it has slots, at the lengths of the window's own
+              schedule) through the program's ``paged_forward``, chunk by
+              chunk as the scheduler prefills, then one
+              ``paged_decode_round`` over all rows — against the plain
+              reference of the block the configuration names
+              (archs/<arch>/reference.py), judged row by row
+              (lib/verdict.py).
+  limits      the readings a limit is set from, in one process: over the
+              spec's seeds the program's per-row numbers, and the
+              control's — the reference on weights rounded to fp8 e4m3,
+              one scale a matrix, in the program's place.
 
-    python bench/lib/children.py numerics <spec.json>
+    python bench/lib/children.py numerics|limits <spec.json>
 """
 
 from __future__ import annotations
 
+import functools
 import json
 import os
 import sys
+import time
 
 BENCH = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -32,7 +42,7 @@ def _setup(spec: dict):
     if device["platform"] not in spec["platforms"]:
         raise SystemExit(
             f"device is {device}, the run needs one of {spec['platforms']}")
-    return jax, device
+    return device
 
 
 def build_unit(unit: dict):
@@ -49,83 +59,261 @@ def build_unit(unit: dict):
         [Parameter.from_json_dict(d) for d in unit["parameters"]]))
 
 
-def numerics(spec: dict) -> dict:
-    jax, device = _setup(spec)
+def sample_tokens(lens: list, vocab: int, seed: int) -> list:
+    import numpy as np
+
+    rng = np.random.default_rng(seed)
+    return [rng.integers(0, vocab, n).astype(np.int32) for n in lens]
+
+
+def run_program(unit, params, dep: dict, prompts: list) -> dict:
+    """The timed programs over the judged rows, as the scheduler drives
+    them (runtime/genserver.py ``_prefill_tick``, ``_decode_round``): one
+    ``paged_forward`` a chunk over the rows still prefilling, ``start``
+    advancing per row, rows and tables padded to powers of two — the
+    shapes the cell's ladder loads; then one decode round of ``span``
+    steps over all rows.  Returns each row's logits from the call that
+    consumed its last prompt token, its first token and the round's."""
     import jax.numpy as jnp
     import numpy as np
 
-    from lib.manifest import arch_module
+    from lib.buckets import blocks, pow2
     from seldon_core_tpu.models.generate import (
         init_block_pool,
         paged_decode_round_jit,
         paged_forward_jit,
     )
 
-    reference = arch_module(spec["bench_dir"], spec["config"], "reference")
-    unit = build_unit(spec["unit"])
-    cfg, dep = unit.cfg, spec["deployment"]
-    params = unit.init_state(None)["params"]
-    pool = init_block_pool(cfg, dep["pool_blocks"], dep["block_size"])
-    span, C = dep["span"], dep["prefill_chunk"]
-    lens = spec["sample_lens"]           # e.g. [40, 33]: one program (B, C, 4)
-    B = len(lens)
-    rng = np.random.default_rng(spec["sample_seed"])
-    toks = np.zeros((B, C), np.int32)
-    for i, n in enumerate(lens):
-        toks[i, :n] = rng.integers(0, spec["config"]["vocab_size"], n)
-    nblk = spec["sample_blocks"]
-    tables = np.zeros((B, nblk), np.int32)
-    for i in range(B):
-        tables[i] = 1 + i * nblk + np.arange(nblk)
-    width = np.asarray(lens, np.int32)
-    logits, pool = paged_forward_jit(
-        params, jnp.asarray(toks), pool, jnp.asarray(tables),
-        jnp.zeros((B,), jnp.int32), jnp.asarray(width), cfg=cfg,
-        last_only=True)
-    sys_logits = np.asarray(logits)
+    cfg = unit.cfg
+    span, C, bs = dep["span"], dep["prefill_chunk"], dep["block_size"]
+    lens = [len(p) for p in prompts]
+    R = len(lens)
+    own, nxt = [], 1                     # disjoint blocks; 0 is scratch
+    for n in lens:
+        k = blocks(n + span, bs)
+        own.append(np.arange(nxt, nxt + k, dtype=np.int32))
+        nxt += k
+    if nxt > dep["pool_blocks"]:
+        raise SystemExit(f"the judged rows need {nxt} blocks, the pool "
+                         f"has {dep['pool_blocks']}")
+
+    def tables(rows: list, upto: list, B: int):
+        nblk = pow2(max(blocks(u, bs) for u in upto))
+        t = np.zeros((B, nblk), np.int32)
+        for i, r in enumerate(rows):
+            t[i, :min(len(own[r]), nblk)] = own[r][:nblk]
+        return jnp.asarray(t)
+
+    pool = init_block_pool(cfg, dep["pool_blocks"], bs)
+    sys_logits = [None] * R
+    pos = [0] * R
+    while True:
+        batch = [r for r in range(R) if pos[r] < lens[r]]
+        if not batch:
+            break
+        B = pow2(len(batch))
+        toks = np.zeros((B, C), np.int32)
+        start = np.zeros((B,), np.int32)
+        width = np.zeros((B,), np.int32)
+        for i, r in enumerate(batch):
+            w = min(C, lens[r] - pos[r])
+            toks[i, :w] = prompts[r][pos[r]:pos[r] + w]
+            start[i], width[i] = pos[r], w
+        logits, pool = paged_forward_jit(
+            params, jnp.asarray(toks), pool,
+            tables(batch, [int(s + w) for s, w in zip(start, width)], B),
+            jnp.asarray(start), jnp.asarray(width), cfg=cfg, last_only=True)
+        host = None
+        for i, r in enumerate(batch):
+            pos[r] += int(width[i])
+            if pos[r] == lens[r]:
+                host = np.asarray(logits) if host is None else host
+                sys_logits[r] = host[i]
+    sys_logits = np.stack(sys_logits)
     first = sys_logits.argmax(-1).astype(np.int32)
+    B = pow2(R)
+
+    def padded(values, dtype):
+        out = np.zeros((B,), dtype)
+        out[:R] = values
+        return jnp.asarray(out)
+
     out, pool, *_ = paged_decode_round_jit(
-        params, pool, jnp.asarray(tables), jnp.asarray(first),
-        jnp.asarray(width), jnp.ones((B,), bool), jnp.zeros((B,), bool),
+        params, pool, tables(list(range(R)), [n + span for n in lens], B),
+        padded(first, np.int32), padded(lens, np.int32),
+        padded(True, bool), jnp.zeros((B,), bool),
         jnp.zeros((B,), jnp.uint32), cfg, span=span,
         temperature=unit.temperature, top_k=unit.top_k, top_p=unit.top_p,
         eos_token=unit.eos_token)
-    sys_toks = np.asarray(out)            # [B, span]
+    sys_toks = np.asarray(out)[:R]            # [R, span]
     del pool
+    return {"logits": sys_logits, "first": first, "tokens": sys_toks}
 
-    worst_prefill = 0.0
-    worst_margin = 0.0
-    rms = []
-    for i, n in enumerate(lens):
-        # the row's prompt, its first token and the round's tokens, through
-        # the reference in ONE full causal pass (teacher-forced on the
-        # system's own tokens): position n-1 gives the prefill logits,
-        # positions n .. n+span-1 the logits each decode step chose from
-        seq = np.concatenate([toks[i, :n], first[i:i + 1], sys_toks[i]])
-        ref = np.asarray(reference.forward(
-            params, jnp.asarray(seq[None, :-1]), spec["config"]))[0]
-        rms.append(float(np.sqrt(np.mean(ref[n - 1] ** 2))))
-        worst_prefill = max(worst_prefill,
-                            float(np.abs(ref[n - 1] - sys_logits[i]).max()))
-        for j in range(span):
-            row = ref[n + j]
-            worst_margin = max(worst_margin,
-                               float(row.max() - row[sys_toks[i, j]]))
-    ref_rms = float(np.mean(rms))
-    tol = spec["tolerance_rms"] * ref_rms
+
+def run_reference(forward, params, config: dict, prompts: list,
+                  first, tokens):
+    """The reference's logits at the judged positions, [R, 1 + span, V]:
+    each row's prompt, first token and round through ONE full causal pass
+    (teacher-forced on the program's own tokens) — position n-1 gives the
+    prefill logits, n .. n+span-1 the logits each decode step chose from.
+    Rows go in groups right-padded to the group's longest
+    (lib/sample.py); only the judged positions come back, and never more
+    than one group's [rows, S, V] is held."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    from lib.sample import reference_groups
+
+    seqs = [np.concatenate([p, first[i:i + 1], tokens[i, :-1]])
+            for i, p in enumerate(prompts)]
+    span = tokens.shape[1]
+    out = np.zeros((len(seqs), 1 + span, config["vocab_size"]), np.float32)
+    for rows in reference_groups([len(s) for s in seqs],
+                                 config["vocab_size"]):
+        toks = np.zeros((len(rows), len(seqs[rows[0]])), np.int32)
+        at = np.zeros((len(rows), 1 + span), np.int32)
+        for i, r in enumerate(rows):
+            toks[i, :len(seqs[r])] = seqs[r]
+            at[i] = len(prompts[r]) - 1 + np.arange(1 + span)
+        logits = forward(params, jnp.asarray(toks), config)
+        out[rows] = np.asarray(
+            logits[jnp.arange(len(rows))[:, None], jnp.asarray(at)])
+        del logits
+    return out
+
+
+def by_row(ref, logits, chosen) -> dict:
+    """Per row: ``prefill_err`` = max |Δ| of the logits after its last
+    prompt token; ``decode_margin`` = the worst, over the round's steps,
+    of the reference's best logit minus its logit of the token chosen;
+    ``rms`` of the reference's prefill logits."""
+    import numpy as np
+
+    step = ref[:, 1:]                                   # [R, span, V]
+    took = np.take_along_axis(step, chosen[..., None], -1)[..., 0]
     return {
-        "device": device, "ref_logit_rms": ref_rms,
-        "prefill_max_abs_err": worst_prefill,
-        "decode_max_margin": worst_margin, "tolerance": tol,
-        "ok": bool(worst_prefill <= tol and worst_margin <= 2 * tol),
-        "memory_peak_bytes": max(
-            (d.memory_stats() or {}).get("peak_bytes_in_use", 0)
-            for d in jax.devices()),
+        "prefill_err": np.abs(ref[:, 0] - logits).max(-1).tolist(),
+        "decode_margin": (step.max(-1) - took).max(-1).tolist(),
+        "rms": np.sqrt((ref[:, 0] ** 2).mean(-1)).tolist()}
+
+
+def fp8_rounded(params: dict) -> dict:
+    """The control's weights: every matrix rounded to fp8 e4m3 (the
+    precision below bf16) with ONE scale a matrix, a power of two so that
+    the rounded values are exact in the weights' own dtype.  ``params``
+    is emptied as it goes, so both trees never lie on the chip whole."""
+    import jax
+    import jax.numpy as jnp
+
+    # two programs: inside one, XLA's TPU compiler may drop a narrowing
+    # conversion that is widened again at once (excess precision allowed),
+    # and the control would be the program's own weights (my chip run,
+    # PR 27: every row read 0.0)
+    @jax.jit
+    def narrow(a):
+        w = a.astype(jnp.float32)
+        top = jnp.maximum(jnp.abs(w).max(), jnp.finfo(jnp.float32).tiny)
+        scale = jnp.exp2(jnp.ceil(jnp.log2(top / 448.0)))
+        return (w / scale).astype(jnp.float8_e4m3fn), scale
+
+    @functools.partial(jax.jit, static_argnames=("dtype",))
+    def widen(q, scale, dtype):
+        return (q.astype(jnp.float32) * scale).astype(dtype)
+
+    def rounded(a):
+        return widen(*narrow(a), dtype=a.dtype)
+
+    out = {}
+    for key in list(params):
+        out[key] = jax.tree.map(
+            lambda a: rounded(a) if a.ndim >= 2 else a, params.pop(key))
+    return out
+
+
+def _peak() -> int:
+    import jax
+
+    return max((d.memory_stats() or {}).get("peak_bytes_in_use", 0)
+               for d in jax.devices())
+
+
+def compare(spec: dict, unit, params, token_seed: int) -> dict:
+    """The judged batch through the program, then through the reference:
+    every row's numbers and the verdict over them."""
+    from lib.manifest import arch_module
+    from lib.verdict import judge
+
+    config = spec["config"]
+    forward = arch_module(spec["bench_dir"], config, "reference").forward
+    prompts = sample_tokens(spec["sample"]["lens"], config["vocab_size"],
+                            token_seed)
+    t0 = time.monotonic()
+    prog = run_program(unit, params, spec["deployment"], prompts)
+    # the program's peak: the reference comes after it and is not counted
+    peak = _peak()
+    t1 = time.monotonic()
+    ref = run_reference(forward, params, config, prompts, prog["first"],
+                        prog["tokens"])
+    rows = by_row(ref, prog["logits"], prog["tokens"])
+    rms = sum(rows["rms"]) / len(rows["rms"])
+    return {"forward": forward, "prompts": prompts, "prog": prog, "ref": ref,
+            "rows": rows, "rms": rms, "memory_peak_bytes": peak,
+            "verdict": judge(rows["prefill_err"], rows["decode_margin"],
+                             config["numerics"], rms),
+            "seconds": {"program": t1 - t0,
+                        "reference": time.monotonic() - t1}}
+
+
+def numerics(spec: dict, device: dict) -> dict:
+    unit = build_unit(spec["unit"])
+    c = compare(spec, unit, unit.init_state(None)["params"],
+                spec["sample_seed"])
+    v, sample = c["verdict"], spec["sample"]
+    return {
+        "device": device, "ref_logit_rms": c["rms"], "ok": v["ok"],
+        "prefill_max_abs_err": v["prefill"]["max"],
+        "decode_max_margin": v["decode"]["max"],
+        "tolerance": v["tolerance"], "verdict": v,
+        "rows_offered": sample["offered"], "chunks": sample["chunks"],
+        "lens": sample["lens"], "by_row": c["rows"],
+        "memory_peak_bytes": c["memory_peak_bytes"], "seconds": c["seconds"],
     }
+
+
+def limits(spec: dict, device: dict) -> dict:
+    """Program and control over ``spec["seeds"]``, each seed its own
+    weights and token ids, every judged row's numbers."""
+    from lib.verdict import judge
+
+    config = spec["config"]
+    seeds = []
+    for seed, unit_doc in zip(spec["seeds"], spec["units"]):
+        unit = build_unit(unit_doc)
+        params = unit.init_state(None)["params"]
+        c = compare(spec, unit, params, seed % 9973)
+        entry = {"seed": seed, "ref_logit_rms": c["rms"],
+                 "program": c["rows"], "program_verdict": c["verdict"]}
+        if seed in spec["control_seeds"]:
+            ctl = run_reference(c["forward"], fp8_rounded(params), config,
+                                c["prompts"], c["prog"]["first"],
+                                c["prog"]["tokens"])
+            rows = by_row(c["ref"], ctl[:, 0], ctl[:, 1:].argmax(-1))
+            if not max(rows["prefill_err"]) > 0.0:
+                raise SystemExit("the control reads what the reference "
+                                 "reads: its weights were not rounded")
+            entry.update(control=rows, control_verdict=judge(
+                rows["prefill_err"], rows["decode_margin"],
+                config["numerics"], c["rms"]))
+        del params, c
+        seeds.append(entry)
+        sys.stderr.write(f"seed {seed} done\n")
+    return {"device": device, "lens": spec["sample"]["lens"],
+            "chunks": spec["sample"]["chunks"], "seeds": seeds}
 
 
 if __name__ == "__main__":
     with open(sys.argv[2]) as f:
         _spec = json.load(f)
-    _result = {"numerics": numerics}[sys.argv[1]](_spec)
+    _result = {"numerics": numerics, "limits": limits}[sys.argv[1]](
+        _spec, _setup(_spec))
     print(json.dumps(_result), flush=True)

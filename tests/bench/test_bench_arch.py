@@ -10,7 +10,7 @@ import os
 import bench_paths
 import pytest
 from bench_paths import BENCH, REPO
-from lib import buckets
+from lib import buckets, sample
 from lib.engine import EngineFailure, deployment_doc, run_child, unit_spec
 from lib.formula import deltas
 from lib.manifest import Manifest
@@ -122,17 +122,17 @@ def arch_root(tmp_path_factory):
     return root
 
 
+# four slots, chunks of 32: rows of one, two and three chunks
+TINY_PROMPTS = [9, 31, 50, 64, 90]
+
+
 def numerics(root, tmp_path, arch, cfg=None):
     cfg, dep = tiny(arch) if cfg is None else (cfg, cfg["deployment"])
-    lens = [31, 24]
     spec = {
         "repo": REPO, "platforms": ["cpu"],
         "bench_dir": os.path.join(root, "bench"), "config": cfg,
         "unit": unit_spec(cfg, dep, 2 ** 31 + 9, 24), "deployment": dep,
-        "sample_lens": lens, "sample_seed": 17,
-        "sample_blocks": buckets.pow2(buckets.blocks(
-            max(lens) + dep["span"], dep["block_size"])),
-        "tolerance_rms": cfg["numerics"]["tolerance_rms"]}
+        "sample": sample.plan(TINY_PROMPTS, dep, 120), "sample_seed": 17}
     path = str(tmp_path / f"numerics_{arch}.json")
     bench_paths.dump(path, spec)
     return run_child(
@@ -148,6 +148,12 @@ def test_numerics_child_on_the_cpu_is_ok_against_its_own_block(
     assert num["device"]["platform"] == "cpu"
     assert 0.0 < num["prefill_max_abs_err"] < 0.5 * num["tolerance"]
     assert num["decode_max_margin"] <= 2 * num["tolerance"]
+    v = num["verdict"]
+    assert (v["rows"], num["rows_offered"], num["chunks"]) == (4, 4, [1, 3])
+    assert num["lens"] == [9, 31, 64, 90]
+    assert v["prefill"]["over"] == v["decode"]["over"] == 0
+    assert v["prefill"]["allowed"] == v["decode"]["allowed"] == 0.0
+    assert len(num["by_row"]["prefill_err"]) == 4
 
 
 def test_numerics_child_is_not_ok_against_a_reference_of_another_block(
@@ -155,6 +161,7 @@ def test_numerics_child_is_not_ok_against_a_reference_of_another_block(
     num = numerics(arch_root, tmp_path, "dense_relu")
     assert num["ok"] is False, num
     assert num["prefill_max_abs_err"] > 2 * num["tolerance"]
+    assert num["verdict"]["prefill"]["share"] == 1.0      # every row
 
 
 def test_numerics_child_fails_loudly_where_the_reference_wants_other_weights(

@@ -2,12 +2,16 @@
 tiny size on the CPU: the dense forward, and prefill followed by a decode
 round through the paged pool."""
 
+from types import SimpleNamespace
+
 import bench_paths
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from lib import children, sample
 from lib.manifest import arch_module
+from lib.verdict import judge
 
 reference = arch_module(bench_paths.BENCH, {"arch": "dense_gelu"},
                         "reference")
@@ -50,63 +54,88 @@ def test_reference_equals_the_programs_dense_forward(sz):
     assert np.abs(got - want).max() < 2e-4 * max(1.0, np.abs(want).max())
 
 
+# the judged batch of a tiny deployment: four slots, chunks of 16, blocks
+# of 16: rows of one, two and three chunks (lib/sample.py)
+DEP = dict(slots=4, span=4, block_size=16, prefill_chunk=16, pool_blocks=16)
+PLAN = sample.plan([5, 13, 21, 30, 41], DEP, 64)
+
+
+def unit_of(cfg):
+    return SimpleNamespace(cfg=cfg, temperature=0.0, top_k=0, top_p=0.0,
+                           eos_token=-1)
+
+
 @pytest.mark.parametrize("sz", SIZES, ids=["gqa2", "gqa3"])
 def test_paged_prefill_then_decode_round_agree_with_the_reference(sz):
-    from seldon_core_tpu.models.generate import (
-        init_block_pool,
-        paged_decode_round_jit,
-        paged_forward_jit,
-    )
-
+    """The numerics child's own loop (chunk by chunk, then one decode
+    round over all rows) against the reference in float32: only the order
+    of additions differs, in every row, whatever its number of chunks."""
     cfg, params = build(sz)
-    lens, C, nblk, span = [13, 9], 16, 2, 4
-    rng = np.random.default_rng(0)
-    toks = np.zeros((2, C), np.int32)
-    for i, n in enumerate(lens):
-        toks[i, :n] = rng.integers(0, cfg.vocab, n)
-    tables = np.asarray([[1, 2], [3, 4]], np.int32)
-    pool = init_block_pool(cfg, 8, 16)
-    logits, pool = paged_forward_jit(
-        params, jnp.asarray(toks), pool, jnp.asarray(tables),
-        jnp.zeros((2,), jnp.int32), jnp.asarray(lens, jnp.int32), cfg=cfg,
-        last_only=True)
-    first = np.asarray(logits).argmax(-1).astype(np.int32)
-    out, *_ = paged_decode_round_jit(
-        params, pool, jnp.asarray(tables), jnp.asarray(first),
-        jnp.asarray(lens, jnp.int32), jnp.ones((2,), bool),
-        jnp.zeros((2,), bool), jnp.zeros((2,), jnp.uint32), cfg, span=span,
-        temperature=0.0, top_k=0, top_p=0.0, eos_token=-1)
-    out = np.asarray(out)
-    for i, n in enumerate(lens):
-        seq = np.concatenate([toks[i, :n], first[i:i + 1], out[i]])
-        ref = np.asarray(reference.forward(
-            params, jnp.asarray(seq[None, :-1]), sz))[0]
-        assert np.abs(ref[n - 1] - np.asarray(logits)[i]).max() < 1e-3
-        for j in range(span):     # each step chose (nearly) the best logit
-            assert ref[n + j].max() - ref[n + j][out[i, j]] < 1e-3
+    assert PLAN["lens"] == [5, 13, 30, 41] and PLAN["chunks"] == [1, 3]
+    prompts = children.sample_tokens(PLAN["lens"], cfg.vocab, 0)
+    prog = children.run_program(unit_of(cfg), params, DEP, prompts)
+    ref = children.run_reference(reference.forward, params, sz, prompts,
+                                 prog["first"], prog["tokens"])
+    assert ref.shape == (4, 1 + DEP["span"], cfg.vocab)
+    rows = children.by_row(ref, prog["logits"], prog["tokens"])
+    assert max(rows["prefill_err"]) < 1e-3
+    assert max(rows["decode_margin"]) < 1e-3   # each step chose the best
+    # the grouped, right-padded passes give what one pass a row gives
+    for i, p in enumerate(prompts):
+        seq = np.concatenate([p, prog["first"][i:i + 1],
+                              prog["tokens"][i, :-1]])
+        alone = np.asarray(reference.forward(
+            params, jnp.asarray(seq[None]), sz))[0]
+        assert np.abs(alone[len(p) - 1:] - ref[i]).max() < 1e-4
 
 
-def test_a_lower_precision_than_bf16_would_fail_the_chip_tolerance():
+@pytest.mark.parametrize("bits, ok, share", [(8, True, 0.0), (3, False, 1.0)],
+                         ids=["bf16-keeps-8-bits", "fp8-e4m3-keeps-3"])
+def test_a_lower_precision_than_bf16_would_fail_the_chip_tolerance(
+        bits, ok, share):
     """The chip tolerance is 0.1 x the logits' rms (configuration files,
-    ``numerics.tolerance_rms``).  At a tiny size: bf16 weights stay well
-    inside it, weights rounded to 4 mantissa bits do not."""
+    ``numerics.tolerance_rms``), held by every judged row.  At a tiny
+    size, the reference on rounded weights in the program's place (the
+    control, as tools/limits.py reads it on the chip): bf16's 8 bits
+    stay well inside it in every row, 4 significant bits are over it in
+    every row."""
     cfg, params = build(SIZES[0])
-    toks = jax.random.randint(jax.random.key(2), (2, 24), 0, cfg.vocab)
-    want = np.asarray(reference.forward(params, toks, SIZES[0]))
-    rms = float(np.sqrt(np.mean(want ** 2)))
+    prompts = children.sample_tokens(PLAN["lens"], cfg.vocab, 2)
+    first = np.zeros((4,), np.int32)
+    tokens = np.ones((4, DEP["span"]), np.int32)
 
-    def rounded(bits):
-        def f(a):
-            if a.ndim < 2:
-                return a
-            m, e = np.frexp(np.asarray(a, np.float32))
-            return jnp.asarray(np.ldexp(np.round(m * 2 ** bits) / 2 ** bits,
-                                        e), jnp.float32)
-        return jax.tree.map(f, params)
+    def rounded(a):
+        if a.ndim < 2:
+            return a
+        m, e = np.frexp(np.asarray(a, np.float32))
+        return jnp.asarray(np.ldexp(np.round(m * 2 ** bits) / 2 ** bits, e),
+                           jnp.float32)
 
-    def err(p):
-        got = np.asarray(reference.forward(p, toks, SIZES[0]))
-        return float(np.abs(got - want).max())
+    ref = children.run_reference(reference.forward, params, SIZES[0],
+                                 prompts, first, tokens)
+    got = children.run_reference(reference.forward,
+                                 jax.tree.map(rounded, params), SIZES[0],
+                                 prompts, first, tokens)
+    rows = children.by_row(ref, got[:, 0], got[:, 1:].argmax(-1))
+    v = judge(rows["prefill_err"], rows["decode_margin"],
+              {"tolerance_rms": 0.1}, float(np.mean(rows["rms"])))
+    assert v["ok"] is ok and v["prefill"]["share"] == share, v
 
-    assert err(rounded(8)) < 0.1 * rms     # bf16 keeps 8 bits
-    assert err(rounded(3)) > 0.1 * rms     # fp8-e4m3 keeps 3
+
+def test_the_fp8_control_rounds_every_matrix_to_e4m3_with_one_scale():
+    """``children.fp8_rounded``: 4 significant bits, nothing over 448
+    scales, vectors untouched, the input tree emptied as it goes."""
+    _, params = build(SIZES[0], jnp.bfloat16)
+    want = {k: jax.tree.map(np.asarray, v) for k, v in params.items()}
+    out = children.fp8_rounded(params)
+    assert params == {} and sorted(out) == sorted(want)
+    for key in ("wqkv", "w2"):
+        a = np.asarray(out["l0"][key], np.float32)
+        w = np.asarray(want["l0"][key], np.float32)
+        assert a.dtype == w.dtype and a.shape == w.shape
+        m, _ = np.frexp(a)
+        assert np.array_equal(m * 16, np.round(m * 16))   # 4 bits
+        big = np.abs(w) > np.abs(w).max() / 16
+        assert np.abs(a - w)[big].max() <= np.abs(w[big]).max() / 16
+        assert 0 < np.abs(a - w).max()
+    assert np.array_equal(np.asarray(out["l0"]["ln1"]), want["l0"]["ln1"])
