@@ -115,6 +115,38 @@ def _pow2(n: int) -> int:
     return 1 << max(n - 1, 0).bit_length() if n > 1 else 1
 
 
+# The most int32 entries (rows x width) a decode round's block table is
+# widened to where the in-place kernel serves.  The flattened table is the
+# kernel's scalar-prefetch operand, copied to scalar memory once a layer a
+# step, and the budget bounds that copy.  Measured on one TPU v5e (PERF.md
+# section 6, PR 28: 30 layers, 32 padded rows of which 8 live, block 256):
+# device time of a decode round at widths 8 / 32 / 128 / 512 blocks
+# 66.479 / 66.481 / 66.482 / 66.492 ms, attention's share of it
+# 6.42 / 6.44 / 6.53 / 6.86% -- 4 Ki entries (16 KiB, width 128 at 32
+# rows) cost 0.005% of a step, so the first budget tried stands.
+_DECODE_TABLE_ENTRIES = 4096
+
+
+def _decode_table_width(inplace, rows: int, need: int, row_max: int) -> int:
+    """Width of a decode round's block table: ``rows`` padded rows, the
+    longest live row needing ``need`` blocks, a row never holding more than
+    ``row_max``.
+
+    On the gather path the width sizes the gathered ``[B, KV, nblk*bs, hd]``
+    copy and stays as narrow as a power of two allows.  Where the in-place
+    kernel serves (ops/paged_attention.py) an entry past a row's length
+    costs no DMA and no compute, and every width is one more compiled
+    program to trace and load: the table gets one width per row count, the
+    widest power of two a row can fill that keeps the table within
+    ``_DECODE_TABLE_ENTRIES``, and grows by powers of two only for a row
+    that outgrows it."""
+    width = _pow2(need)
+    cap = min(row_max, _DECODE_TABLE_ENTRIES // rows)
+    if inplace and cap >= 1:
+        width = max(width, 1 << (cap.bit_length() - 1))
+    return width
+
+
 class BlockAllocator:
     """Host-side free-list allocator over the device block pool.
 
@@ -476,6 +508,10 @@ class GenServer:
         self.steps_total: Dict[str, int] = {}
         self.tokens_emitted_total = 0
         self.tick_errors_total = 0
+        # the distinct shapes dispatched since boot — each one a compiled
+        # program a fresh process traces and loads: (rows, chunk, nblk) of
+        # prefill, (rows, nblk) of decode.  /stats reports their counts
+        self._programs: Dict[str, set] = {"prefill": set(), "decode": set()}
         # flight-recorder scratch (utils/genperf.py): the bubble ledger
         # stamps the END of every tick and classifies the gap before the
         # NEXT one by how this one ended; the per-tick accumulators are
@@ -732,6 +768,7 @@ class GenServer:
             "steps_total": dict(self.steps_total),
             "tokens_emitted_total": self.tokens_emitted_total,
             "tick_errors_total": self.tick_errors_total,
+            "programs": {k: len(v) for k, v in self._programs.items()},
             "sequence_ledger": ledger,
         }
         if self.spec:
@@ -1363,6 +1400,7 @@ class GenServer:
                 self._blocks_needed(int(start[i]) + widths[i])
                 for i in range(len(batch))
             ))
+            self._programs["prefill"].add((B, C, nblk))
             tables = np.zeros((B, nblk), np.int32)
             for i, seq in enumerate(batch):
                 tables[i] = self._table(seq, nblk)
@@ -1523,8 +1561,12 @@ class GenServer:
             if not batch:
                 return 0
             B = _pow2(len(batch))
-            nblk = _pow2(max(
-                self._blocks_needed(s.n_valid + self.span) for s in batch))
+            nblk = _decode_table_width(
+                self._inplace, B,
+                max(self._blocks_needed(s.n_valid + self.span)
+                    for s in batch),
+                self._allocator.capacity)
+            self._programs["decode"].add((B, nblk))
             tables = np.zeros((B, nblk), np.int32)
             token = np.zeros((B,), np.int32)
             n_valid = np.zeros((B,), np.int32)
@@ -1568,7 +1610,8 @@ class GenServer:
         # module event against (decode_fence_slack_ms)
         with _Phase("GenServer._decode_round/device", self._dev_s, "decode",
                     rows=B, real_rows=len(batch), nblk=nblk,
-                    kv_positions=kv_positions, inplace=int(self._inplace)):
+                    kv_positions=kv_positions,
+                    inplace=int(bool(self._inplace))):
             toks, self._pool, _tok, _nv, _seen, keys_out = (
                 paged_decode_round_jit(
                     self.params, self._pool, jnp.asarray(tables),

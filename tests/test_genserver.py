@@ -16,7 +16,13 @@ import pytest
 
 from seldon_core_tpu.models.generate import generate, init_cache, prefill
 from seldon_core_tpu.models.transformer import LMConfig, lm_init
-from seldon_core_tpu.runtime.genserver import BlockAllocator, GenServer
+from seldon_core_tpu.runtime.genserver import (
+    _DECODE_TABLE_ENTRIES,
+    BlockAllocator,
+    GenServer,
+    _decode_table_width,
+    _pow2,
+)
 
 CFG = LMConfig(vocab=48, d_model=32, n_heads=4, n_layers=2, d_ff=64,
                dtype=jnp.float32)
@@ -206,6 +212,65 @@ def test_scheduler_speculative_rounds():
         assert srv.snapshot()["steps_total"].get("spec", 0) > 0
     finally:
         srv.stop()
+
+
+# -- one decode program per row count -----------------------------------------
+
+
+@pytest.mark.parametrize("row_max", [7, 63, 255, 1023])
+@pytest.mark.parametrize("rows", [1, 2, 4, 8, 16, 32, 64, 8192])
+def test_decode_table_width_follows_which_attention_serves(rows, row_max):
+    """Gather path: today's power of two of the longest row's blocks.  In
+    place: one floor width a row count — never wider than a row can fill,
+    the table within its budget of entries — and powers of two above it."""
+    floor = _decode_table_width(True, rows, 1, row_max)
+    for need in range(1, 65):
+        assert _decode_table_width(False, rows, need, row_max) == _pow2(need)
+        got = _decode_table_width(True, rows, need, row_max)
+        assert got == _decode_table_width("interpret", rows, need, row_max)
+        if need <= floor:
+            assert got == floor
+        else:
+            assert got == _pow2(need)
+    if floor > 1:  # the floor applied
+        assert floor == _pow2(floor)
+        assert floor <= row_max and 2 * floor > min(
+            row_max, _DECODE_TABLE_ENTRIES // rows)
+        assert rows * floor <= _DECODE_TABLE_ENTRIES
+
+
+def test_in_place_a_decode_round_has_one_program_per_row_count(
+        params, monkeypatch):
+    """Prompts whose rows cross three block boundaries, at two row counts.
+    The CPU's gather path dispatches a decode shape per (rows, power-of-two
+    width) as ever; with the in-place kernel serving (interpret mode) it is
+    one per row count, and the tokens are the same."""
+    from seldon_core_tpu.models import generate as gen_mod
+
+    prompts = np.random.default_rng(21).integers(0, 48, size=(3, 3))
+
+    def serve():
+        srv = _server(params, max_new_tokens=14)
+        try:
+            # block 4, span 3: tables of 2, 3, 4 and 5 blocks
+            got = [srv.submit(p.astype(float)).future.result(timeout=240)
+                   for p in (prompts[:2], prompts[2:])]
+            return (np.concatenate(got), set(srv._programs["decode"]),
+                    _settle(srv)["programs"])
+        finally:
+            srv.stop()
+
+    toks, shapes, counts = serve()
+    assert shapes == {(b, w) for b in (1, 2) for w in (2, 4, 8)}
+    assert counts == {"prefill": 2, "decode": 6}
+    monkeypatch.setattr(gen_mod, "decode_inplace",
+                        lambda pool, mesh=None: "interpret")
+    toks_inplace, shapes, counts = serve()
+    assert shapes == {(1, 32), (2, 32)}    # 63 blocks a row can hold
+    assert counts == {"prefill": 2, "decode": 2}
+    np.testing.assert_array_equal(toks_inplace, toks)
+    np.testing.assert_array_equal(toks, np.asarray(generate(
+        params, jnp.asarray(prompts, jnp.int32), CFG, max_new_tokens=14)))
 
 
 # -- admission / retirement / exhaustion -------------------------------------

@@ -142,10 +142,10 @@ CFG = LMConfig(vocab=64, d_model=64, n_heads=4, n_kv_heads=2, n_layers=2,
                d_ff=128, dtype=jnp.float32)
 
 
-def test_decode_round_in_place_emits_the_gather_paths_tokens():
-    """Three rounds of paged_decode_round with the in-place path forced
-    through the function's own argument (interpret mode): the same greedy
-    tokens and the same pool as the gather path."""
+def _three_rounds(inplace, width):
+    """Ragged rows prefilled, then three rounds of paged_decode_round over
+    tables ``width`` wide (a row owns 8 blocks; the rest is the scheduler's
+    zero padding): the tokens, the pool, and the live block ids."""
     params = lm_init(jax.random.key(0), CFG)
     bs, nblk, span, B = 4, 8, 3, 4
     rng = np.random.default_rng(5)
@@ -153,38 +153,56 @@ def test_decode_round_in_place_emits_the_gather_paths_tokens():
     toks = np.zeros((B, 12), np.int32)
     for i, n in enumerate(lens):
         toks[i, :n] = rng.integers(0, CFG.vocab, n)
-    tables = np.zeros((B, nblk), np.int32)
+    tables = np.zeros((B, width), np.int32)
     ids = rng.permutation(np.arange(1, 1 + 3 * nblk))
     for i in range(3):
-        tables[i] = ids[i * nblk:(i + 1) * nblk]
-    width = np.asarray(lens + [0], np.int32)
-    active = jnp.asarray(width > 0)
+        tables[i, :nblk] = ids[i * nblk:(i + 1) * nblk]
+    lengths = np.asarray(lens + [0], np.int32)
+    active = jnp.asarray(lengths > 0)
+    pool = init_block_pool(CFG, 1 + 3 * nblk, bs)
+    logits, pool = paged_forward_jit(
+        params, jnp.asarray(toks), pool, jnp.asarray(tables[:, :nblk]),
+        jnp.zeros((B,), jnp.int32), jnp.asarray(lengths), cfg=CFG)
+    token = jnp.argmax(logits, -1).astype(jnp.int32)
+    n_valid, seen = jnp.asarray(lengths), jnp.zeros((B,), bool)
+    keys, out = jnp.zeros((B,), jnp.uint32), []
+    for _ in range(3):
+        t, pool, token, n_valid, seen, keys = paged_decode_round_jit(
+            params, pool, jnp.asarray(tables), token, n_valid, active,
+            seen, keys, CFG, span=span, temperature=0.0, top_k=0,
+            top_p=0.0, eos_token=-1, inplace=inplace)
+        out.append(np.asarray(t))
+    return np.concatenate(out, axis=1), pool, tables[:3, :nblk].reshape(-1)
 
-    def run(inplace):
-        pool = init_block_pool(CFG, 1 + 3 * nblk, bs)
-        logits, pool = paged_forward_jit(
-            params, jnp.asarray(toks), pool, jnp.asarray(tables),
-            jnp.zeros((B,), jnp.int32), jnp.asarray(width), cfg=CFG)
-        token = jnp.argmax(logits, -1).astype(jnp.int32)
-        n_valid, seen = jnp.asarray(width), jnp.zeros((B,), bool)
-        keys, out = jnp.zeros((B,), jnp.uint32), []
-        for _ in range(3):
-            t, pool, token, n_valid, seen, keys = paged_decode_round_jit(
-                params, pool, jnp.asarray(tables), token, n_valid, active,
-                seen, keys, CFG, span=span, temperature=0.0, top_k=0,
-                top_p=0.0, eos_token=-1, inplace=inplace)
-            out.append(np.asarray(t))
-        return np.concatenate(out, axis=1), pool
 
-    toks_gather, pool_gather = run(False)
-    toks_inplace, pool_inplace = run("interpret")
-    np.testing.assert_array_equal(toks_inplace, toks_gather)
-    live = np.asarray(tables[:3]).reshape(-1)     # not the scratch block
-    for li in pool_gather:
+def _assert_same_round(got, want):
+    (toks, pool, live), (toks_want, pool_want, _) = got, want
+    np.testing.assert_array_equal(toks, toks_want)
+    for li in pool_want:                   # live: not the scratch block
         for name in ("k", "v"):
             np.testing.assert_allclose(
-                np.asarray(pool_inplace[li][name])[live],
-                np.asarray(pool_gather[li][name])[live], atol=1e-5, rtol=0)
+                np.asarray(pool[li][name])[live],
+                np.asarray(pool_want[li][name])[live], atol=1e-5, rtol=0)
+
+
+def test_decode_round_in_place_emits_the_gather_paths_tokens():
+    """Three rounds of paged_decode_round with the in-place path forced
+    through the function's own argument (interpret mode): the same greedy
+    tokens and the same pool as the gather path."""
+    _assert_same_round(_three_rounds("interpret", 8), _three_rounds(False, 8))
+
+
+def test_decode_round_in_place_is_blind_to_the_tables_padding():
+    """The scheduler's one width a row count (genserver._decode_table_width)
+    zero-pads the table far past what any row owns; in place that changes
+    neither a token nor a live block of the pool."""
+    from seldon_core_tpu.runtime.genserver import _decode_table_width
+
+    width = _decode_table_width("interpret", 4, 3, 24)
+    assert width == 16                     # the floor, not _pow2(3)
+    wide = _three_rounds("interpret", width)
+    _assert_same_round(wide, _three_rounds("interpret", 8))
+    _assert_same_round(wide, _three_rounds(False, 8))
 
 
 def test_the_cpu_takes_the_gather_path():
@@ -243,9 +261,10 @@ def one_chip():
 @pytest.mark.parametrize("B,H,kv,bs,nblk,dtype", [
     (16, 24, 2, 256, 4, jnp.bfloat16),   # the benchmark cell's decode shape
     (32, 24, 2, 256, 8, jnp.bfloat16),
+    (32, 24, 2, 256, 128, jnp.bfloat16),  # its one width since PR 28
     (8, 8, 4, 16, 16, jnp.bfloat16),     # the program's default block size
     (8, 8, 2, 16, 4, jnp.float32),
-], ids=["cell-16x4", "cell-32x8", "bs16-kv4", "f32"])
+], ids=["cell-16x4", "cell-32x8", "cell-32x128-floor", "bs16-kv4", "f32"])
 def test_kernel_compiles_for_a_described_v5e(one_chip, B, H, kv, bs, nblk,
                                              dtype):
     """Mosaic accepts the kernel at real widths: the 32-bit view of the
