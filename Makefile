@@ -126,60 +126,6 @@ postmortem-demo:
 corpus-demo:
 	JAX_PLATFORMS=cpu python scripts/corpus_demo.py --out corpus_demo
 
-bench:
-	python bench.py
-
-# telemetry overhead budget gate: the span probe with EVERY observatory
-# enabled must keep span_framework_p50_ms within
-# SELDON_TPU_OVERHEAD_BUDGET_MS (default 1.0).  Fails loudly on breach;
-# prove it gates with SELDON_TPU_TELEMETRY_TEST_DELAY_MS=2.
-# CPU-friendly — no TPU required (docs/operations.md runbook).
-# Relative A/B mode: when the absolute budget is breached, the baseline
-# ref (OVERHEAD_BASELINE, default HEAD — set it to origin/main when the
-# working tree IS HEAD, or empty for the pure absolute gate) is measured
-# in a clean worktree ON THE SAME BOX and the gate fails only if this
-# tree exceeds SELDON_TPU_OVERHEAD_REL_TOLERANCE (1.25x) of it — slow
-# containers read as "parity", regressions still go red.
-OVERHEAD_BASELINE ?= HEAD
-overhead-gate:
-	JAX_PLATFORMS=cpu python bench.py --overhead-gate $(if $(OVERHEAD_BASELINE),--overhead-gate-baseline $(OVERHEAD_BASELINE),)
-
-# continuous-batching TTFT gate: the concurrent-stream probe (staggered
-# arrivals into an already-decoding batch) must keep TTFT p50 within
-# SELDON_TPU_TTFT_BUDGET_MS (default 400).  A scheduler change that lets
-# prefill block co-batched decode — the r05 regression (305 -> 2012 ms)
-# — turns this lane red.  CPU-friendly (docs/operations.md runbook).
-ttft-gate:
-	JAX_PLATFORMS=cpu python bench.py --ttft-gate --smoke
-
-# multi-tenant fairness gate: a victim tenant's p99 under a 10x-share
-# hog must stay within SELDON_TPU_FAIRNESS_BOUND (default 1.5) x its
-# solo baseline with zero victim failures — the runtime/qos.py token
-# bucket + weighted-fair-queue admission contract, best-of-3.
-# CPU-friendly (docs/operations.md "Surviving overload" runbook).
-fairness-gate:
-	JAX_PLATFORMS=cpu python bench.py --fairness-gate
-
-# binary wire contract gate: JSON vs application/x-seldon-tensor over
-# the same socket/engine (bench.py --wire-gate, best-of-3).  Fails when
-# the binary-lane floor exceeds SELDON_TPU_WIRE_FLOOR_REL (default
-# 0.6) x the JSON floor AND bytes-copied-per-request dropped < 4x (the
-# host-bound-container escape hatch; SELDON_TPU_WIRE_GATE_STRICT=1
-# disables it).  CPU-friendly (docs/benchmarking.md "binary wire A/B").
-wire-gate:
-	JAX_PLATFORMS=cpu python bench.py --wire-gate --smoke
-
-# served-decode flight-recorder gate: drives the REAL continuous-
-# batching scheduler at saturation (bench.py --decode-gate, best-of-3)
-# and holds the bubble ledger to SELDON_TPU_DECODE_BUBBLE_MAX (default
-# 0.25) and served/kernel decode throughput to
-# SELDON_TPU_SERVED_DECODE_REL (default 0.25), with a >=95% ledger-
-# integrity floor and a host-bound escape hatch
-# (SELDON_TPU_DECODE_GATE_STRICT=1 disables it).  CPU-friendly
-# (docs/benchmarking.md "served decode MFU").
-decode-gate:
-	JAX_PLATFORMS=cpu python bench.py --decode-gate --smoke
-
 # decode flight-recorder demo: saturated genserver run that prints the
 # per-tick timeline (kind, host/device split, bubbles by cause) and the
 # bubble-ledger breakdown, checks host+device+bubble accounts for >=95%
@@ -196,16 +142,6 @@ decode-demo:
 # external-api.md "binary tensor wire contract")
 wire-demo:
 	JAX_PLATFORMS=cpu python scripts/wire_demo.py --out wire_demo
-
-# whole-graph fusion gate: the fused dispatch path (graph/fuse.py) must
-# stay bit-identical to the interpreter on the probe graphs AND keep the
-# fused 4-node-chain p50 <= SELDON_TPU_FUSION_REL (default 0.7) x the
-# interpreted p50, best-of-3.  Escape hatch for host-core-bound runners:
-# relax SELDON_TPU_FUSION_REL toward 1.0 — equivalence and the
-# graph_hops_eliminated N->1 accounting still gate.  CPU-friendly
-# (docs/operations.md "The fused graph path").
-fusion-gate:
-	JAX_PLATFORMS=cpu python bench.py --fusion-gate --smoke
 
 # whole-graph fusion demo: fused-vs-interpreter equivalence on a served
 # graph, the fusion plan off /stats, the /perf per-node phase
@@ -248,4 +184,4 @@ release-dryrun:
 	  { echo "usage: make release-dryrun VERSION=X.Y.Z"; exit 2; }
 	python release/release.py --version $(VERSION)
 
-.PHONY: proto native test chaos trace-demo perf-demo quality-demo scale-demo autopilot-demo canary-demo overload-demo disagg-demo fleet-demo corpus-demo cost-demo postmortem-demo bench overhead-gate ttft-gate fairness-gate wire-gate wire-demo decode-gate decode-demo fusion-gate fusion-demo demos train-demo stack bundle images publish release-dryrun
+.PHONY: proto native test chaos trace-demo perf-demo quality-demo scale-demo autopilot-demo canary-demo overload-demo disagg-demo fleet-demo corpus-demo cost-demo postmortem-demo wire-demo decode-demo fusion-demo demos train-demo stack bundle images publish release-dryrun

@@ -1,7 +1,7 @@
 // loadgen — closed-loop load generator for the engine's socketed data planes.
 //
 // The reference's benchmark methodology drives the engine with locust workers
-// on THREE dedicated client nodes (docs/benchmarking.md:20-36); on this
+// on THREE dedicated client nodes (its docs/benchmarking.md:20-36); on this
 // single-core host the client and server timeshare one CPU, so a Python
 // client would charge its own per-request cost against the server's budget.
 // This native client plays the role of the reference's dedicated loadtest

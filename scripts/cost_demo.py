@@ -33,10 +33,7 @@ Artifacts:
                         per-arm numbers and pass/fail per assertion
 
 Run via ``make cost-demo``; CI uploads the artifact from a non-blocking
-lane, mirroring ``overload-demo`` / ``scale-demo``.  bench.py's
-``cost_attribution_phase`` runs this script and lifts
-``cost_attributed_fraction`` /
-``cost_per_1k_tok_interactive_vs_offline_x`` into the compact doc."""
+lane, mirroring ``overload-demo`` / ``scale-demo``."""
 
 from __future__ import annotations
 

@@ -34,8 +34,8 @@ Artifacts:
                        coalesce counters, kill-switch check
 
 Run via ``make wire-demo``; CI uploads the artifact from a non-blocking
-lane, mirroring ``scale-demo`` / ``perf-demo``.  The BLOCKING fence is
-``make wire-gate`` (bench.py --wire-gate)."""
+lane, mirroring ``scale-demo`` / ``perf-demo``.  The blocking contracts
+are tests/test_wire.py."""
 
 from __future__ import annotations
 

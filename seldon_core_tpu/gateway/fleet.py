@@ -35,7 +35,7 @@ single pane over the sheet of workers:
 
 Everything here is READ-PATH-ONLY: assembly, merging and outlier math
 run at query time (or on the existing scrape tick), never on the
-request hot path — ``make overhead-gate`` must not move.
+request hot path.
 ``SELDON_TPU_FLEET=0`` kills federation: the gateway answers every
 surface from local data only, bit-for-bit the PR-12 behaviour.
 """

@@ -1,39 +1,29 @@
 """Speculative decoding — draft/verify generation, exact under greedy.
 
-A small draft LM proposes ``k`` tokens with its own KV cache; the target LM
+A small draft LM proposes ``k`` tokens with its own KV pool; the target LM
 scores all ``k+1`` positions in ONE forward (one MXU pass instead of k+1
 sequential decode steps); the longest prefix where the draft matched the
 target's argmax is accepted plus one corrected token.  Greedy acceptance is
 exact in exact arithmetic: the output equals vanilla greedy decoding of the
 target token-for-token (pinned bit-exact by the f32 tests).  In low
-precision an argmax near-tie can flip between the S=1 and S=k+1 segment
+precision an argmax near-tie can flip between the width-1 and width-(k+1)
 forwards (different reduction orders), so bf16 outputs may diverge at tie
 positions — same-quality tokens, not errors.  The target runs
 ~(accepted+1)x fewer sequential passes; acceptance rate tracks how well
 the draft approximates the target (an unrelated random draft accepts ~0).
 
-TPU shape: the whole loop is one SHARED batched ``lax.while_loop`` under
-jit — every round, ALL rows draft k tokens (batched one-token forwards),
-ALL rows verify in one (k+1)-wide target pass, and acceptance is a masked
-per-row reduction.  There is no per-row program and no vmap-lifted
-while_loop: rows at different sequence lengths share every MXU pass.
-
-The layout trick that makes the shared loop scatter-free: cache slots are
-ROUND-ALIGNED.  Round r writes its k+1 candidate K/V at slots
-``S + r*(k+1)..`` — the SAME offset for every row — so cache writes are
-ordinary ``dynamic_update_slice`` ops, never per-row scatters (the old
-vmapped design's per-row offsets lowered each cache write to a scatter).
-Rejected candidates leave holes; a per-row VALIDITY BITMAP masks them out
-of every later attention (additive -1e30), and RoPE rotates by per-row
-LOGICAL positions (apply_rope takes [B, S] position arrays), so the math
-over the valid set is exactly vanilla greedy decoding of the target.
-Memory trades for regularity: caches are sized S + (max_new-1)*(k+1)
-worst-case instead of S + max_new.
-
-Rows that finish early keep riding the loop with their validity updates
-masked off (gained = 0), and outputs are written round-aligned
-([B, rounds, k+1] + per-row gained counts), compacted once at the end —
-the only scatter in the program.
+The round itself is ``models/generate.py paged_spec_round`` — the program
+the scheduler's speculative mode dispatches (runtime/genserver.py
+``_spec_round``).  This module is the static lane's driver for it: two
+private pools (target and draft, identity tables), both prompts prefilled
+with ``paged_forward``, then one SHARED batched ``lax.while_loop`` over
+rounds under jit — every round ALL rows draft, ALL rows verify, and
+acceptance is a masked per-row reduction; rows at different sequence
+lengths share every MXU pass.  Rejected candidates' K/V are stale slots
+past a row's ``n_valid`` that the next round overwrites before anything
+attends them, so a row needs ``S + max_new + k + 1`` positions, not a slot
+per candidate.  Rows that finish early keep riding the loop inactive
+(their writes go to the scratch block, ``gained`` = 0).
 """
 
 from __future__ import annotations
@@ -45,85 +35,14 @@ import jax.numpy as jnp
 
 from seldon_core_tpu.graph.units import Unit, register_unit
 from seldon_core_tpu.models.generate import (
-    _grouped_pv,
-    _grouped_qk,
-    _heads,
-    init_cache,
+    paged_forward_jit,
+    paged_spec_round,
+    private_pool,
     sanitize_prompt,
-    segment_forward,
 )
-from seldon_core_tpu.models.transformer import (
-    LMConfig,
-    _ffn,
-    _rmsnorm,
-    apply_rope,
-    lm_init,
-)
+from seldon_core_tpu.models.transformer import LMConfig, lm_init
 
 __all__ = ["speculative_generate", "SpeculativeGenerator"]
-
-
-def _forward_seg(params, tokens, cache, off, pos0, valid, cfg: LMConfig):
-    """Bitmap-masked segment forward for the shared round loop.
-
-    tokens [B, W] at per-row logical positions pos0[:, None] + arange(W);
-    K/V written at cache slots off..off+W-1 (``off`` is round-uniform —
-    a regular dus, never a scatter).  Attention allows, per row, the
-    ``valid`` [B, L] bitmap slots plus in-segment causal slots (slot
-    off+j visible to query i iff j <= i).  Returns
-    (logits [B, W, vocab] f32, cache').
-
-    NOTE: this deliberately re-states the per-layer forward that
-    generate.py's _block_cached implements for prefix-valid caches —
-    the bitmap mask and per-row positions cut across every one of that
-    function's masking modes.  The two MUST evolve together (new quant
-    modes, attention changes); the float-only guard in
-    speculative_generate is the current honest gap."""
-    from seldon_core_tpu.ops.quant import lm_matmul
-
-    B, W = tokens.shape
-    D = cfg.d_model
-    hd = D // cfg.n_heads
-    kv_h = cfg.kv_heads
-    L = cache["l0"]["k"].shape[2]
-    lidx = jnp.arange(L)
-    seg = (lidx >= off) & (lidx < off + W)              # [L]
-    incause = (lidx - off)[None, :] <= jnp.arange(W)[:, None]  # [W, L]
-    allowed = jnp.where(seg[None, None, :], incause[None, :, :],
-                        valid[:, None, :])              # [B, W, L]
-    mask_add = jnp.where(allowed, 0.0, -1e30).astype(jnp.float32)
-    positions = pos0[:, None] + jnp.arange(W)[None, :]  # [B, W]
-    x = params["embed"][tokens]                         # [B, W, D]
-    for i in range(cfg.n_layers):
-        lp = params[f"l{i}"]
-        cl = cache[f"l{i}"]
-        h = _rmsnorm(x, lp["ln1"])
-        qkv = lm_matmul(lp, "wqkv", h, out_dtype=x.dtype)
-        q, k, v = jnp.split(qkv, [D, D + kv_h * hd], axis=-1)
-        q = _heads(q, B, W, cfg.n_heads, hd)
-        k = _heads(k, B, W, kv_h, hd)
-        v = _heads(v, B, W, kv_h, hd)
-        if cfg.rope:
-            q = apply_rope(q, positions, cfg.rope_base)
-            k = apply_rope(k, positions, cfg.rope_base)
-        cl = {
-            "k": jax.lax.dynamic_update_slice(
-                cl["k"], k.astype(cl["k"].dtype), (0, 0, off, 0)),
-            "v": jax.lax.dynamic_update_slice(
-                cl["v"], v.astype(cl["v"].dtype), (0, 0, off, 0)),
-        }
-        s = _grouped_qk(q, cl["k"])                     # [B,KV,g,W,L]
-        s = s + mask_add[:, None, None, :, :]
-        p = jax.nn.softmax(s, axis=-1)
-        a = _grouped_pv(p, cl["v"], q.shape, q.dtype)
-        a = a.transpose(0, 2, 1, 3).reshape(B, W, D)
-        x = x + lm_matmul(lp, "wo", a, out_dtype=x.dtype)
-        h2 = _rmsnorm(x, lp["ln2"])
-        y, _lb = _ffn(lp, h2, cfg, mesh=None)
-        x = x + y
-        cache[f"l{i}"] = cl
-    x = _rmsnorm(x, params["ln_f"])
-    return (x @ params["embed"].T).astype(jnp.float32), cache
 
 
 def speculative_generate(
@@ -141,21 +60,15 @@ def speculative_generate(
     per target pass, vs exactly 1 for vanilla decoding).
 
     Greedy only; per-row output equals vanilla greedy decoding of the
-    target over its confirmed prefix.  One SHARED batched round loop —
-    see the module docstring for the round-aligned/bitmap design.
+    target over its confirmed prefix.  One SHARED batched round loop over
+    ``paged_spec_round`` — see the module docstring.
 
-    CACHE SIZING: round-aligned slots make both caches worst-case sized
-    ``Lmax = S + R*(k+1)`` where ``R = max_new_tokens - 1`` — about
-    (k+1)x the S + max_new a vanilla decode allocates (5x at k=4).
-    ``max_rounds > 0`` caps R by an EXPECTED-ACCEPTANCE bound: a draft
-    that tracks the target at mean acceptance ``a`` finishes in about
-    ``max_new / (a*k + 1)`` rounds, so e.g. ``max_rounds =
-    ceil(max_new / (0.5*k + 1)) + slack`` cuts the cache to that many
-    rounds' worth.  The cap trades worst-case completeness for memory:
-    rows still decoding when rounds run out get zero-padded tails
-    (``rounds`` returned == cap for such rows — observable), so pick the
-    cap from measured acceptance, not hope.  0 (default) keeps the exact
-    worst-case sizing.
+    ``max_rounds > 0`` bounds the loop (it sizes nothing): a draft that
+    tracks the target at mean acceptance ``a`` finishes in about
+    ``max_new / (a*k + 1)`` rounds.  Rows still decoding when the rounds
+    run out get zero-padded tails (``rounds`` returned == the bound for
+    such rows — observable), so pick the bound from measured acceptance,
+    not hope.  0 (default) allows the worst case, one token a round.
 
     Telemetry: eager calls record the per-request mean acceptance ratio
     into the flight recorder (seldon_tpu_speculative_accept_ratio);
@@ -169,109 +82,54 @@ def speculative_generate(
     R = max(max_new_tokens - 1, 1)  # worst case: 1 token gained per round
     if max_rounds > 0:
         R = min(R, int(max_rounds))
-    Lmax = S + R * W
-    t_cache = init_cache(target_cfg, B, Lmax)
-    d_cache = init_cache(draft_cfg, B, Lmax)
+    # a live row starts a round at n_valid <= S + max_new - 2 and writes W
+    # positions from there
+    t_pool, tables = private_pool(target_cfg, B, S + max_new_tokens + W)
+    d_pool, _ = private_pool(draft_cfg, B, S + max_new_tokens + W)
 
     # prefill both models on the prompt; last-position argmax = first token
-    t_logits, t_cache = segment_forward(
-        target_params, prompt, t_cache, 0, target_cfg, segment=False)
-    _d_logits, d_cache = segment_forward(
-        draft_params, prompt, d_cache, 0, draft_cfg, segment=False)
-    first = jnp.argmax(t_logits[:, -1, :], axis=-1).astype(jnp.int32)  # [B]
+    start = jnp.zeros((B,), jnp.int32)
+    width = jnp.full((B,), S, jnp.int32)
+    t_logits, t_pool = paged_forward_jit(
+        target_params, prompt, t_pool, tables, start, width,
+        cfg=target_cfg, last_only=True)
+    _, d_pool = paged_forward_jit(
+        draft_params, prompt, d_pool, tables, start, width,
+        cfg=draft_cfg, last_only=True)
+    first = jnp.argmax(t_logits, axis=-1).astype(jnp.int32)  # [B]
     if max_new_tokens == 1:
         return first[:, None], jnp.zeros((B,), jnp.int32)
-
-    valid0 = jnp.broadcast_to(jnp.arange(Lmax) < S, (B, Lmax))
-    toks_rounds = jnp.zeros((B, R, W), jnp.int32)
-    gained_rounds = jnp.zeros((B, R), jnp.int32)
 
     def cond(c):
         r, n = c[0], c[1]
         return (r < R) & jnp.any(n < max_new_tokens)
 
     def body(c):
-        (r, n, last, toks_rounds, gained_rounds, rounds_used,
-         t_cache, d_cache, t_valid, d_valid) = c
-        off = S + r * W
-        P = S + n - 1  # logical position of `last`, per row [B]
-
-        # -- every row drafts k tokens: k+1 batched one-token forwards.
-        # The extra step writes the LAST proposal's KV so a fully-
-        # accepted round leaves no cache hole.  Earlier in-round slots
-        # become visible through the provisional bitmap ``dv``.
-        def draft_step(carry, i):
-            tok, d_cache, dv = carry
-            logits, d_cache = _forward_seg(
-                draft_params, tok[:, None], d_cache, off + i, P + i,
-                dv, draft_cfg)
-            nxt = jnp.argmax(logits[:, -1, :], axis=-1).astype(jnp.int32)
-            dv = jax.lax.dynamic_update_slice(
-                dv, jnp.ones((B, 1), bool), (0, off + i))
-            return (nxt, d_cache, dv), tok
-
-        (_, d_cache, _), seg_toks = jax.lax.scan(
-            draft_step, (last, d_cache, d_valid), jnp.arange(W))
-        # seg_toks[i] is the token FED at step i: [last, d1..dk]
-        seg_toks = seg_toks.T  # [B, W]
-        draft_toks = seg_toks[:, 1:]  # [B, k]
-
-        # -- one (k+1)-wide target pass verifies every row ----------------
-        t_logits, t_cache = _forward_seg(
-            target_params, seg_toks, t_cache, off, P, t_valid, target_cfg)
-        t_argmax = jnp.argmax(t_logits, axis=-1).astype(jnp.int32)  # [B,W]
-
-        # greedy acceptance: longest prefix where draft == target argmax
-        match = draft_toks == t_argmax[:, :k]  # [B, k]
-        a = jnp.argmin(
-            jnp.concatenate([match, jnp.zeros((B, 1), bool)], axis=1),
-            axis=1,
-        )  # [B] first False; k if all matched
-        corrected = jnp.take_along_axis(t_argmax, a[:, None], axis=1)[:, 0]
-        padded = jnp.concatenate(
-            [draft_toks, jnp.zeros((B, 1), jnp.int32)], axis=1)  # [B, W]
-        new_toks = jnp.where(
-            jnp.arange(W)[None, :] < a[:, None], padded, corrected[:, None])
+        r, n, pending, out, rounds_used, t_pool, d_pool = c
         active = n < max_new_tokens
-        gained = jnp.where(active, a + 1, 0)
+        # ``pending`` is each row's last emitted token, not yet in a pool:
+        # the pools hold the prompt and the n - 1 tokens before it
+        new_toks, gained, corrected, t_pool, d_pool = paged_spec_round(
+            target_params, draft_params, t_pool, d_pool, tables, tables,
+            pending, S + n - 1, active, target_cfg, draft_cfg, k=k)
+        # row b emits new_toks[b, :gained[b]] at out[b, n[b]:]; zeros past
+        # them, which the next round overwrites (or the final cut drops)
+        emit = jnp.where(jnp.arange(W)[None, :] < gained[:, None],
+                         new_toks, 0)
+        out = jax.vmap(
+            lambda o, t, i: jax.lax.dynamic_update_slice(o, t, (i,))
+        )(out, emit, n)
+        pending = jnp.where(active, corrected, pending)
+        return (r + 1, n + gained, pending, out,
+                rounds_used + active.astype(jnp.int32), t_pool, d_pool)
 
-        toks_rounds = jax.lax.dynamic_update_slice(
-            toks_rounds, new_toks[:, None, :], (0, r, 0))
-        gained_rounds = jax.lax.dynamic_update_slice(
-            gained_rounds, gained[:, None], (0, r))
-        # confirmed slots this round: off+0 (last) .. off+a — `last` was
-        # materialised here for the first time (the corrected token is
-        # never forwarded in the round it is emitted), so slot 0 is the
-        # ONLY copy and stays valid; rejected tails stay holes
-        vmask = ((jnp.arange(W)[None, :] <= a[:, None])
-                 & active[:, None])  # [B, W]
-        t_valid = jax.lax.dynamic_update_slice(t_valid, vmask, (0, off))
-        d_valid = jax.lax.dynamic_update_slice(d_valid, vmask, (0, off))
-        last = jnp.where(active, corrected, last)
-        return (r + 1, n + gained, last, toks_rounds, gained_rounds,
-                rounds_used + active.astype(jnp.int32),
-                t_cache, d_cache, t_valid, d_valid)
-
-    (r, n, last, toks_rounds, gained_rounds, rounds_used,
-     *_rest) = jax.lax.while_loop(
+    # a row's last live round starts at n <= max_new - 1 and writes W slots
+    out = jnp.zeros((B, max_new_tokens + W), jnp.int32).at[:, 0].set(first)
+    (_, n, _, out, rounds_used, _, _) = jax.lax.while_loop(
         cond, body,
-        (jnp.int32(0), jnp.ones((B,), jnp.int32), first, toks_rounds,
-         gained_rounds, jnp.zeros((B,), jnp.int32), t_cache, d_cache,
-         valid0, valid0),
+        (jnp.int32(0), jnp.ones((B,), jnp.int32), first, out,
+         jnp.zeros((B,), jnp.int32), t_pool, d_pool),
     )
-
-    # compact the round-aligned tokens into dense rows — the program's
-    # ONE scatter, run once after the loop
-    flat = toks_rounds.reshape(B, R * W)
-    keep = (jnp.arange(W)[None, None, :]
-            < gained_rounds[:, :, None]).reshape(B, R * W)
-    dest = jnp.cumsum(keep, axis=1)  # kept token j -> output index 1..
-    pad = max_new_tokens + W  # clipped rows' overflow lands past the end
-    dest = jnp.where(keep, jnp.minimum(dest, pad), pad)
-    out = jnp.zeros((B, pad + 1), jnp.int32)
-    out = out.at[:, 0].set(first)
-    out = out.at[jnp.arange(B)[:, None], dest].set(
-        jnp.where(keep, flat, 0))
     toks_out = out[:, :max_new_tokens]
     if not isinstance(rounds_used, jax.core.Tracer):
         # eager execution: per-request acceptance telemetry.  gained
@@ -297,23 +155,14 @@ class SpeculativeGenerator(Unit):
     """Serving unit: speculative draft/verify generation over the standard
     data plane.  Target and draft dimensions are graph parameters (draft_*
     defaults to a quarter-size model).  Concurrent callers coalesce into
-    ONE shared batched round loop (round-aligned cache slots + per-row
-    validity bitmaps — see speculative_generate); per-row outputs equal
-    the single-row outputs, so coalescing never changes an answer.
+    ONE shared batched round loop (speculative_generate); per-row outputs
+    equal the single-row outputs, so coalescing never changes an answer.
 
-    MEMORY: round-aligned cache slots size BOTH the target and draft KV
-    caches at ``Lmax = S + (max_new_tokens - 1) * (k + 1)`` — worst case
-    one gained token per verify round, ~(k+1)x the ``S + max_new`` a
-    vanilla decode allocates (5x at k=4).  Deployments sized before this
-    layout (round 4 and earlier) can OOM on the same graph parameters;
-    either lower ``max_new_tokens``/``k`` or set ``max_rounds`` to an
-    expected-acceptance bound.  Example: ``max_new_tokens=256, k=4`` is
-    worst-case Lmax = S + 1275 slots/row/model; a draft measured at ~50%
-    acceptance finishes in ~256/(0.5*4+1) = 86 rounds, so
-    ``max_rounds=110`` (bound + ~25% slack) cuts that to S + 550 while
-    leaving headroom.  Rows that exhaust the capped rounds get
-    zero-padded tails — watch seldon_tpu_speculative_accept_ratio and
-    resize when the measured acceptance drifts below the bound."""
+    ``max_rounds`` bounds that loop: e.g. a draft measured at ~50%
+    acceptance finishes ``max_new_tokens=256, k=4`` in ~256/(0.5*4+1) = 86
+    rounds, so ``max_rounds=110`` leaves ~25% slack.  Rows that exhaust the
+    bound get zero-padded tails — watch seldon_tpu_speculative_accept_ratio
+    and raise it when the measured acceptance drifts below the estimate."""
 
     pure = True
     # per-row outputs are independent of co-batched rows (pinned by
@@ -367,9 +216,8 @@ class SpeculativeGenerator(Unit):
     def continuous_spec(self, state):
         """Scheduler contract for the continuous-batching lane
         (runtime/genserver.py): the draft params/config put the scheduler
-        in SPECULATIVE mode — per-step draft/verify rounds over paged
-        pools, so the 2.42x trained-draft win composes with continuous
-        admission instead of living only in the isolated bench arm.
+        in SPECULATIVE mode — the same paged_spec_round, over the
+        scheduler's pools and composed with continuous admission.
         Greedy/float-KV only, matching speculative_generate's guards."""
         return {
             "params": state["target"],

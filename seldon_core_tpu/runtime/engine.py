@@ -1580,7 +1580,7 @@ class EngineService:
     async def predict_proto(self, req):
         """Proto-to-proto predict — the gRPC hot path (the reference's
         faster wire: its published gRPC throughput is 2.3x its REST,
-        docs/benchmarking.md:44,58).  Tensor-kind requests with a bare meta
+        the reference's docs/benchmarking.md:44,58).  Tensor-kind requests with a bare meta
         skip the SeldonMessage object layer entirely: packed values ->
         batched dispatch -> packed response.  Everything else goes through
         the object path with identical semantics."""

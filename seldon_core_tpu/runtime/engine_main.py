@@ -237,8 +237,8 @@ async def serve(deployment: SeldonDeploymentSpec, predictor_name=None,
     print(
         f"engine up: predictor={engine.predictor.name} mode={engine.mode} "
         f"rest=:{rest_port} grpc=:{grpc_port} "
-        # the lanes and kernels actually serving — bench.py and
-        # chip_smoke.py read these instead of inferring the path
+        # the lanes and kernels actually serving — chip_smoke.py and
+        # bench/ read these instead of inferring the path
         f"http={http_impl} grpc-lane={grpc_impl} "
         f"kernels={','.join(kernels) or 'none'}"
         + (f" uds={uds_path}" if uds_server is not None else "")

@@ -1,20 +1,23 @@
 """Continuous-batching generation server — paged KV blocks, per-step
 admission, chunked prefill.
 
-The static serving path runs ``generate()`` once per request: a request's
-batch owns the device for its whole lifetime, a long prefill stalls every
-co-batched decode (BENCH_r05: stream TTFT 2012 ms while the isolated
-decode arm does 75k tok/s), and the int8-KV / shared-prefix / speculative
-wins only exist in bench arms because nothing on the serving path
-composes them.  This module is the scheduler shape production TPU serving
-stacks use instead (Orca/vLLM-style):
+One KV layout and three device programs serve all generation
+(``models/generate.py``: the block pool, ``paged_forward``,
+``paged_decode_round``, ``paged_spec_round``).  The STATIC lane
+(``generate()``) gives each request a private pool: the request's batch
+owns the device for its whole lifetime and a long prefill stalls every
+co-batched decode.  This module is the CONTINUOUS lane over the same
+programs — the scheduler shape production TPU serving stacks use
+(Orca/vLLM-style) — and differs from the static lane only in who owns the
+pool and the tables:
 
   * **Paged KV pool** — one process-wide per-layer block pool
     (``models/generate.py init_block_pool``: ``[num_blocks, block_size,
     KV, hd]``); sequences hold block tables, the :class:`BlockAllocator`
     does alloc/free/eviction (preempt-youngest recompute) and occupancy
-    accounting.  Shared prefixes are written once and PINNED: every
-    sequence's table references the same physical blocks.
+    accounting.  A shared prefix is computed once, at boot, into PINNED
+    blocks: every sequence's table references the same physical blocks
+    (a partly filled boundary block is copied per sequence, pool to pool).
   * **Per-step admission** — each scheduler iteration admits newly
     arrived sequences into the in-flight decode batch, runs one decode
     ROUND (``span`` single-token steps as one ``lax.scan`` — one device
@@ -27,14 +30,17 @@ stacks use instead (Orca/vLLM-style):
     prefill.
   * **Composition** — int8 KV pools, shared-prefix block reuse, and
     speculative draft/verify rounds (``paged_spec_round``) all run
-    through the same admission/retirement machinery, so their bench-arm
-    wins apply to actual served traffic.
+    through the same admission/retirement machinery.
 
 Greedy scheduler output is token-identical to one-shot ``generate()``
 (tests/test_genserver.py pins it); sampled decoding uses per-SEQUENCE
 PRNG keys, so co-batched requests cannot couple through a shared batch
-key (a deliberate improvement over the static path's batch-coupled
-sampling — same quality, decoupled streams).
+key (the static lane keys each row of a request the same way).
+
+What the scheduler cannot serve stays on the static lane: an MoE
+generator (capacity routing couples co-batched rows —
+``continuous_spec`` returns None) and a generator inside a graph of
+several units (runtime/engine.py).
 
 Tuning knobs (docs/operations.md "tuning the generation scheduler"):
 ``SELDON_TPU_GEN_BLOCK_SIZE`` (16), ``SELDON_TPU_GEN_POOL_BLOCKS``
@@ -42,8 +48,8 @@ Tuning knobs (docs/operations.md "tuning the generation scheduler"):
 ``SELDON_TPU_GEN_PREFILL_CHUNK`` (128, the interleave floor),
 ``SELDON_TPU_GEN_PREFILL_CHUNK_MAX`` (512, the adaptive-chunk
 ceiling).  Kill switch:
-``SELDON_TPU_GEN_CONTINUOUS=0`` restores the static per-request path
-(runtime/engine.py).
+``SELDON_TPU_GEN_CONTINUOUS=0`` serves every generator on the static
+lane (runtime/engine.py).
 """
 
 from __future__ import annotations
@@ -388,7 +394,7 @@ class GenServer:
         top_p: float = 0.0,
         eos_token: int = -1,
         max_new_tokens: int = 32,
-        prefix_cache=None,
+        prefix_ids=None,
         draft_params=None,
         draft_cfg=None,
         spec_k: int = 4,
@@ -409,7 +415,7 @@ class GenServer:
         self.top_p = float(top_p)
         self.eos_token = int(eos_token)
         self.max_new_tokens = int(max_new_tokens)
-        self.prefix_cache = prefix_cache
+        self.prefix_ids = prefix_ids  # int32 [P] shared-prefix token ids
         self.draft_params = draft_params
         self.draft_cfg = draft_cfg
         self.spec = draft_params is not None
@@ -417,7 +423,7 @@ class GenServer:
         self.seed = int(seed)
         if self.spec and (self.temperature > 0.0
                           or cfg.kv_quant == "int8"
-                          or prefix_cache is not None):
+                          or prefix_ids is not None):
             # mirror speculative_generate's guards: greedy, float KV
             raise ValueError(
                 "speculative continuous mode is greedy/float-KV only")
@@ -472,6 +478,7 @@ class GenServer:
         self._allocator: Optional[BlockAllocator] = None
         self._draft_allocator: Optional[BlockAllocator] = None
         self._prefix_blocks: List[int] = []     # shared full blocks
+        self._prefix_tail: Optional[int] = None  # pinned boundary block
         self._prefix_len = 0
         self._seq_counter = 0
         self._admit_counter = 0
@@ -839,7 +846,7 @@ class GenServer:
         from seldon_core_tpu.models.generate import (
             decode_inplace,
             init_block_pool,
-            paged_write_prefix_blocks_jit,
+            paged_forward_jit,
         )
 
         self._pool = init_block_pool(
@@ -862,31 +869,38 @@ class GenServer:
                 self.draft_cfg, self.num_blocks, self.block_size)
             self._draft_allocator = BlockAllocator(self.num_blocks)
         self._register_decode_costs()
-        if self.prefix_cache is not None:
-            P = int(self.prefix_cache["l0"]["k"].shape[2])
+        if self.prefix_ids is not None:
+            # the shared prefix is computed ONCE, here, into pinned blocks:
+            # one row whose table is those blocks.  Its full blocks are
+            # shared by table reference; a partly filled boundary block is
+            # copied into each row's first private block at admission
+            import jax.numpy as jnp
+
+            ids = np.asarray(self.prefix_ids, np.int32)[None, :]
+            P = ids.shape[1]
+            blocks = self._allocator.alloc(self._blocks_needed(P))
+            if blocks is None:
+                raise RuntimeError(
+                    f"KV pool ({self.num_blocks} blocks) smaller than "
+                    f"the shared prefix ({self._blocks_needed(P)} blocks)")
+            _, self._pool = paged_forward_jit(
+                self.params, jnp.asarray(ids), self._pool,
+                jnp.asarray([blocks], jnp.int32), jnp.zeros((1,), jnp.int32),
+                jnp.full((1,), P, jnp.int32), cfg=self.cfg, last_only=True)
+            self._allocator.pin(blocks)
             self._prefix_len = P
             full = P // self.block_size
-            if full:
-                blocks = self._allocator.alloc(full)
-                if blocks is None:
-                    raise RuntimeError(
-                        f"KV pool ({self.num_blocks} blocks) smaller than "
-                        f"the shared prefix ({full} blocks)")
-                self._pool = paged_write_prefix_blocks_jit(
-                    self._pool, self.prefix_cache, tuple(blocks),
-                    cfg=self.cfg)
-                self._allocator.pin(blocks)
-                self._prefix_blocks = blocks
+            self._prefix_blocks = blocks[:full]
+            self._prefix_tail = blocks[full] if P % self.block_size else None
 
     def _register_decode_costs(self) -> None:
         """Analytic per-token cost features for the SERVED decode lane,
         registered once at device init under ``gen_decode_step`` — the
         read side is ``OBSERVATORY.cost_features`` in utils/genperf.py,
         which prices served decode MFU / HBM-BW utilization against
-        REAL tokens.  Same arithmetic as bench.py's kernel decode arm
-        (matmul weights at serving dtype, two KV tensors per position
-        plus int8 scales), so served-vs-kernel ratios compare like with
-        like.  Never raises: accounting must not block serving."""
+        REAL tokens (matmul weights at serving dtype, two KV tensors per
+        position plus int8 scales).  Never raises: accounting must not
+        block serving."""
         try:
             cfg = self.cfg
             d, L = cfg.d_model, cfg.n_layers
@@ -900,8 +914,7 @@ class GenServer:
             kvb = 1 if kv_int8 else 2
             OBSERVATORY.record_compile("gen_decode_step", {
                 # matmul FLOPs per generated token (attention's
-                # position-dependent term excluded — documented in
-                # docs/benchmarking.md's served-MFU methodology)
+                # position-dependent term excluded)
                 "flops": float(2 * (L * per_layer + d * v)),
                 # HBM bytes ONE device step streams regardless of batch:
                 # every matmul'd weight once, the bf16 unembed once
@@ -1283,18 +1296,17 @@ class GenServer:
                 seq.draft_blocks = (
                     self._draft_allocator.alloc(d_need) or [])
             # shared-prefix tail: the partially-filled boundary block is
-            # private — copy the tail K/V into this sequence's first block
-            p0 = len(self._prefix_blocks) * self.block_size
-            if self._prefix_len > p0 and seq.blocks:
+            # private — copy the pinned one into this sequence's first block
+            if self._prefix_tail is not None and seq.blocks:
                 import jax.numpy as jnp
 
                 from seldon_core_tpu.models.generate import (
-                    paged_write_prefix_tail_jit,
+                    paged_copy_block_jit,
                 )
 
-                self._pool = paged_write_prefix_tail_jit(
-                    self._pool, self.prefix_cache,
-                    jnp.int32(seq.blocks[0]), cfg=self.cfg, p0=p0)
+                self._pool = paged_copy_block_jit(
+                    self._pool, jnp.int32(self._prefix_tail),
+                    jnp.int32(seq.blocks[0]))
             seq.n_valid = self._prefix_len
             seq.state = _Sequence.PREFILL
             seq.prefill_pos = 0

@@ -3,7 +3,7 @@
 The stock Python gRPC runtime (grpc.aio) costs ~370us of CPU per unary RPC
 across client+server on this class of host — an echo benchmark tops out
 near 2.6k calls/s/core before any model work.  The reference's engine
-serves 28k gRPC predictions/s (docs/benchmarking.md:58) on a 16-core JVM;
+serves 28k gRPC predictions/s (its docs/benchmarking.md:58) on a 16-core JVM;
 matching that per-core on a single shared core needs the per-RPC path to
 be tens of microseconds, so — exactly as with HTTP/1.1 (runtime/
 httpfast.py) — the framework terminates the protocol itself:
@@ -23,7 +23,7 @@ the stock grpc.aio server (runtime/grpc_server.py), which remains the
 full-surface lane.
 
 Reference parity: engine grpc/SeldonGrpcServer.java:34-62 (service
-surface), docs/benchmarking.md:48-64 (the gRPC numbers this lane chases).
+surface), its docs/benchmarking.md:48-64 (the gRPC numbers this lane chases).
 """
 
 from __future__ import annotations

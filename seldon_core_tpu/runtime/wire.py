@@ -62,9 +62,8 @@ back to JSON, restoring the pre-wire path bit-for-bit.
 
 Copy accounting: every host-side byte copy the codec (or a lane feeding
 it) makes is recorded via :func:`account_copy` into
-``seldon_tpu_wire_bytes_copied_total`` — the bench's
-``bytes_copied_per_request`` arm prices this lane against JSON with
-measured numbers, not vibes (docs/benchmarking.md).
+``seldon_tpu_wire_bytes_copied_total``, so the lane is priced against
+JSON in bytes copied per request (tests/test_wire.py pins the ratio).
 """
 
 from __future__ import annotations

@@ -267,7 +267,7 @@ async def drive_tenant(
 ):
     """Fire ``n`` predicts at an in-process gateway AS one tenant —
     the overload-fairness harness (tests/test_chaos.py hog/victim arms,
-    scripts/overload_demo.py, ``bench.py --fairness-gate``).
+    scripts/overload_demo.py).
 
     Returns ``(latencies_s, outcomes)``: per-request wall seconds and
     the response status code (200 for SUCCESS).  ``concurrency`` > 1

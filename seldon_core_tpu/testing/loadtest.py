@@ -3,7 +3,7 @@ predict_rest_locust.py, helm-charts/seldon-core-loadtesting).
 
 K closed-loop clients fire contract-generated requests at a REST or gRPC
 endpoint for a fixed duration; reports qps + latency percentiles as one JSON
-line (the shape ``docs/benchmarking.md`` tabulates)::
+line (the shape the reference's ``docs/benchmarking.md`` tabulates)::
 
     python -m seldon_core_tpu.testing.loadtest contract.json 127.0.0.1 8000 \
         --clients 64 --duration 10 [--api grpc] [--batch-size 1]
@@ -27,7 +27,7 @@ __all__ = ["run_load", "run_load_native", "main"]
 
 # ---------------------------------------------------------------------------
 # Native load generator (native/loadgen.cpp) — plays the role of the
-# reference's DEDICATED loadtest nodes (docs/benchmarking.md drives the
+# reference's DEDICATED loadtest nodes (its docs/benchmarking.md drives the
 # engine from 3 separate locust machines).  On this single-core host a
 # Python client would charge its own per-request cost to the same CPU the
 # server runs on; the native client costs ~2 us/request, so the measured
@@ -204,7 +204,7 @@ async def run_load(
         # locust FastHttpUser analogue: raw keepalive HTTP/1.1 connections,
         # one per client, minimal parsing — the aiohttp client costs ~3x as
         # much CPU per request, which matters when clients and server share
-        # cores (docs/benchmarking.md methodology note)
+        # cores
         body = payload_msg.to_json().encode()
         auth = f"Authorization: Bearer {token}\r\n" if token else ""
         request = (

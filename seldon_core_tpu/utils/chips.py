@@ -1,10 +1,9 @@
-"""Chip spec table — advertised per-chip peaks, shared by bench and runtime.
+"""Chip spec table — advertised per-chip peaks.
 
-One table, two consumers: ``bench.py`` normalizes its measured MFU against
-these peaks, and the runtime performance observatory (``utils/perf.py``)
-normalizes live per-dispatch MFU/roofline figures against the SAME
-numbers — extracting the table here is what guarantees bench MFU and
-serving MFU can never disagree about what "peak" means.
+The runtime performance observatory (``utils/perf.py``) normalizes live
+per-dispatch MFU/roofline figures against these numbers (the chip
+benchmark keeps its own, ``bench/lib/peaks.py``: it imports nothing of
+the program).
 
 Values are public spec-sheet figures; matching is by substring of
 ``device.device_kind`` (e.g. "TPU v5 lite").  A device kind that is not

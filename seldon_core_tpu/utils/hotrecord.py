@@ -39,9 +39,7 @@ the stack learned to see itself.  This module inverts the flow:
     ``GET /overhead`` decomposes framework time per subsystem
     (tracer/perf/quality/recorder/ring) from the records themselves,
     ``seldon_tpu_framework_overhead_ms{subsystem}`` feeds the
-    ``SeldonTPUTelemetryOverhead`` alert, and ``bench.py
-    --overhead-gate`` (``make overhead-gate``) fails when
-    ``span_framework_p50_ms`` with every observatory enabled exceeds
+    ``SeldonTPUTelemetryOverhead`` alert against
     ``SELDON_TPU_OVERHEAD_BUDGET_MS`` (default 1.0).
 
 Kill switches compose independently: ``SELDON_TPU_TELEMETRY=0`` silences
@@ -298,8 +296,8 @@ class TelemetrySpine:
             else _env_float("SELDON_TPU_TELEMETRY_DRAIN_MS", 50.0) / 1e3
         )
         self.budget_ms = _env_float("SELDON_TPU_OVERHEAD_BUDGET_MS", 1.0)
-        #: gate-validation hook: sleep this long inside every ring write
-        #: so `make overhead-gate` can be proven to fail on breach
+        #: validation hook: sleep this long inside every ring write, so
+        #: the budget's breach can be provoked (tests/test_telemetry_spine.py)
         self.test_delay_s = (
             _env_float("SELDON_TPU_TELEMETRY_TEST_DELAY_MS", 0.0) / 1e3
         )
@@ -1008,8 +1006,7 @@ class TelemetrySpine:
 
     def framework_p50_ms(self) -> Optional[float]:
         """Per-request framework overhead estimate from the folded
-        records: request-hop p50 minus dispatch-hop p50 (the same
-        subtraction bench.py's ``span_framework_p50_ms`` makes).  None
+        records: request-hop p50 minus dispatch-hop p50.  None
         until both hops have samples — request hops need tracing on."""
         req = self.hop_ms["request"].snapshot()
         disp = self.hop_ms["dispatch"].snapshot()

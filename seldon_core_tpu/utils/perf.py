@@ -16,8 +16,8 @@ cost features XLA already computes for free:
     Compile wall time is recorded per executable alongside.
   * **Dispatch time**: measured wall time combines with the static
     features into achieved TFLOP/s, achieved GB/s, MFU against the
-    device-kind-matched advertised peak (utils/chips.py — the SAME table
-    bench.py normalizes against), and a roofline classification:
+    device-kind-matched advertised peak (utils/chips.py), and a roofline
+    classification:
     compute-bound vs memory-bound by which peak binds first,
     overhead-bound when measured time exceeds the roofline prediction by
     ``SELDON_TPU_PERF_OVERHEAD_X`` (the dispatch is dominated by

@@ -16,8 +16,7 @@ on it).  This module is the ledger those consumers were missing:
     the perf observatory's static cost features (FLOPs / bytes / rows)
     and the measured wall.  The write rides the fold, never the
     dispatch path: with the telemetry kill switches off there are no
-    ring writes, no folds, and therefore zero corpus I/O (the
-    overhead-gate's corpus-on arm pins the budget with writes on).
+    ring writes, no folds, and therefore zero corpus I/O.
   * **Size-bounded segments + compacted sketches.**  Rows append to
     ``corpus-<seq>.jsonl``; when a segment passes
     ``SELDON_TPU_CORPUS_SEGMENT_BYTES`` it rotates: the in-memory

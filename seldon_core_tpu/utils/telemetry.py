@@ -871,8 +871,7 @@ class FlightRecorder:
             self._p_wire_bytes_copied = Counter(
                 "seldon_tpu_wire_bytes_copied_total",
                 "Host-side bytes copied by the binary wire codec and "
-                "the lanes feeding it (the bytes_copied_per_request "
-                "bench axis — docs/benchmarking.md)",
+                "the lanes feeding it (runtime/wire.py account_copy)",
                 registry=self.registry)
             self._p_wire_coalesced = Counter(
                 "seldon_tpu_wire_coalesced_total",

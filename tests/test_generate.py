@@ -43,86 +43,92 @@ def test_cached_generation_matches_naive():
     np.testing.assert_array_equal(got, ref)
 
 
-def test_chunked_generation_merge_path_matches(monkeypatch):
-    """Force the chunk-merge path (GEN_CHUNK_CAP smaller than max_new):
-    tokens must match the single-chunk result exactly — merging relocates
-    K/V between tiers without changing the attended set."""
+def test_static_lane_runs_the_paged_programs(monkeypatch):
+    """generate() and stream_chunks() dispatch paged_forward and
+    paged_decode_round — the scheduler's programs over a private pool —
+    and the module holds no second decoder block or cache layout."""
     import seldon_core_tpu.models.generate as gen_mod
+    from seldon_core_tpu.models.generate import stream_chunks
 
+    blocks = [n for n in vars(gen_mod)
+              if n.startswith("_") and "block" in n]
+    assert blocks == ["_paged_block"], blocks
+    for gone in ("segment_forward", "decode_step", "prefill"):
+        assert not hasattr(gen_mod, gone), gone
+    calls = {"forward": 0, "round": []}
+    fwd, rnd = gen_mod.paged_forward_jit, gen_mod.paged_decode_round_jit
+
+    def forward(*a, **kw):
+        calls["forward"] += 1
+        return fwd(*a, **kw)
+
+    def round_(*a, **kw):
+        calls["round"].append(kw["span"])
+        return rnd(*a, **kw)
+
+    monkeypatch.setattr(gen_mod, "paged_forward_jit", forward)
+    monkeypatch.setattr(gen_mod, "paged_decode_round_jit", round_)
     params = lm_init(jax.random.key(5), CFG)
     prompt = jnp.asarray(
         np.random.default_rng(7).integers(0, 48, size=(2, 6)), jnp.int32
     )
     ref = np.asarray(generate(params, prompt, CFG, max_new_tokens=13))
-    monkeypatch.setattr(gen_mod, "GEN_CHUNK_CAP", 4)
-    got = np.asarray(generate(params, prompt, CFG, max_new_tokens=13))
-    np.testing.assert_array_equal(got, ref)
-
-
-def test_stream_merge_path_matches_generate(monkeypatch):
-    """Streams that outgrow STREAM_CHUNK_CAP merge mid-stream; the
-    concatenated tokens still equal generate()'s."""
-    import seldon_core_tpu.models.generate as gen_mod
-    from seldon_core_tpu.models.generate import stream_chunks
-
-    monkeypatch.setattr(gen_mod, "STREAM_CHUNK_CAP", 5)
-    params = lm_init(jax.random.key(6), CFG)
-    prompt = jnp.asarray(
-        np.random.default_rng(8).integers(0, 48, size=(2, 6)), jnp.int32
-    )
-    ref = np.asarray(generate(params, prompt, CFG, max_new_tokens=14))
+    assert calls == {"forward": 1, "round": [12]}  # one prefill, one round
+    np.testing.assert_array_equal(ref, _naive_greedy(params, prompt, 13))
     chunks = [np.asarray(c) for c in stream_chunks(
-        params, prompt, CFG, max_new_tokens=14, chunk=3
-    )]
+        params, prompt, CFG, max_new_tokens=13, chunk=5)]
+    # prefill token + 4, then 5, then the 3-token tail
+    assert calls == {"forward": 2, "round": [12, 4, 5, 3]}
     np.testing.assert_array_equal(np.concatenate(chunks, axis=1), ref)
 
 
-def test_stream_chunk_larger_than_cap_clamped(monkeypatch):
-    """A requested chunk bigger than STREAM_CHUNK_CAP must be clamped,
-    not dus'd past the buffer (which would silently corrupt K/V)."""
-    import seldon_core_tpu.models.generate as gen_mod
+def test_stream_largest_client_chunk_matches_generate():
+    """The engine lets a client ask for chunks of up to 256 tokens: one
+    round of 255 steps after the prefill token, then the tail — equal to
+    generate() (long and short generations are the same code)."""
     from seldon_core_tpu.models.generate import stream_chunks
 
-    monkeypatch.setattr(gen_mod, "STREAM_CHUNK_CAP", 4)
     params = lm_init(jax.random.key(9), CFG)
     prompt = jnp.asarray(
         np.random.default_rng(10).integers(0, 48, size=(1, 5)), jnp.int32
     )
-    ref = np.asarray(generate(params, prompt, CFG, max_new_tokens=11))
+    ref = np.asarray(generate(params, prompt, CFG, max_new_tokens=260))
     chunks = [np.asarray(c) for c in stream_chunks(
-        params, prompt, CFG, max_new_tokens=11, chunk=9  # > cap
-    )]
+        params, prompt, CFG, max_new_tokens=260, chunk=256)]
+    assert [c.shape[1] for c in chunks] == [256, 4]
     np.testing.assert_array_equal(np.concatenate(chunks, axis=1), ref)
 
 
 def test_int8_kv_attention_close_to_float():
-    """Int8 cached attention vs the float formulation: per-token absmax
-    rounding bounds the relative error at a few percent."""
-    from seldon_core_tpu.models.generate import _attend_cached, _quantize_kv
+    """Int8 attention over a paged view vs the float formulation:
+    per-token absmax rounding bounds the relative error at a few percent."""
+    from seldon_core_tpu.models.generate import _attend_paged, _quantize_kv
 
     rng = np.random.default_rng(3)
     B, KV, g, hd, L = 2, 2, 4, 64, 96
     q = jnp.asarray(rng.normal(size=(B, KV * g, 1, hd)), jnp.float32)
     k = jnp.asarray(rng.normal(size=(B, KV, L, hd)), jnp.float32)
     v = jnp.asarray(rng.normal(size=(B, KV, L, hd)), jnp.float32)
-    want = np.asarray(_attend_cached(q, {"k": k, "v": v}, 80))
+    start = jnp.full((B,), 79, jnp.int32)  # the query sees 80 positions
+    want = np.asarray(_attend_paged(q, {"k": k, "v": v}, start))
     k_q, k_s = _quantize_kv(k)
     v_q, v_s = _quantize_kv(v)
-    got = np.asarray(_attend_cached(
-        q, {"k": k_q, "v": v_q, "k_s": k_s, "v_s": v_s}, 80
+    got = np.asarray(_attend_paged(
+        q, {"k": k_q, "v": v_q, "k_s": k_s, "v_s": v_s}, start
     ))
     rel = np.abs(got - want).max() / np.abs(want).max()
     assert rel < 0.03, f"int8 KV attention rel err {rel:.4f}"
 
 
 def test_int8_kv_generate_wiring_and_logit_fidelity():
-    """kv_quant='int8' end to end: prefill stays exact (attends the
-    pre-quantization k/v), decode logits track the float path closely,
-    and generate() runs the full scan with the quantized cache."""
+    """kv_quant='int8' end to end: the pool stores int8 values + scale
+    planes, prefill and decode logits track the float pool's closely
+    (only KV rounding separates them), and generate() runs the whole
+    round over the quantized pool."""
     import dataclasses
 
     from seldon_core_tpu.models.generate import (
-        decode_step, init_cache, prefill,
+        paged_forward_jit, private_pool,
     )
 
     cfg_q = dataclasses.replace(CFG, kv_quant="int8")
@@ -132,17 +138,20 @@ def test_int8_kv_generate_wiring_and_logit_fidelity():
     )
     outs = {}
     for name, cfg in (("f32", CFG), ("int8", cfg_q)):
-        cache = init_cache(cfg, 2, 16)
-        logits, cache = prefill(params, prompt, cache, cfg)
+        pool, tables = private_pool(cfg, 2, 16)
+        assert ("k_s" in pool["l0"]) == (name == "int8")
+        logits, pool = paged_forward_jit(
+            params, prompt, pool, tables, jnp.zeros((2,), jnp.int32),
+            jnp.full((2,), 9, jnp.int32), cfg=cfg)
         first = jnp.argmax(logits, -1).astype(jnp.int32)
-        step_logits, _ = decode_step(params, first, cache, 9, cfg)
+        step_logits, _ = paged_forward_jit(
+            params, first[:, None], pool, tables,
+            jnp.full((2,), 9, jnp.int32), jnp.ones((2,), jnp.int32),
+            cfg=cfg)
         outs[name] = (np.asarray(logits), np.asarray(step_logits))
-    # prefill logits are EXACT (same float attention path)
-    np.testing.assert_array_equal(outs["f32"][0], outs["int8"][0])
-    # decode logits: only KV rounding error separates them
-    np.testing.assert_allclose(
-        outs["int8"][1], outs["f32"][1], rtol=0.1, atol=0.05
-    )
+    assert pool["l0"]["k"].dtype == jnp.int8
+    for f, q in zip(outs["f32"], outs["int8"]):
+        np.testing.assert_allclose(q, f, rtol=0.1, atol=0.05)
     toks = np.asarray(generate(params, prompt, cfg_q, max_new_tokens=8))
     assert toks.shape == (2, 8)
     assert (toks >= 0).all() and (toks < CFG.vocab).all()
@@ -202,6 +211,52 @@ def test_generator_unit_serves_through_engine():
     assert toks.shape == (2, 6)
     assert np.isfinite(toks).all()
     assert ((0 <= toks) & (toks < 48)).all()
+
+
+def test_generator_inside_a_two_unit_graph_serves_compiled():
+    """The scheduler takes single-unit graphs only: behind a TRANSFORMER
+    the generator serves through the compiled graph, i.e. generate() is
+    traced under the graph's jit — the static lane's reason to exist."""
+    from seldon_core_tpu.messages import SeldonMessage
+    from seldon_core_tpu.models.generate import sanitize_prompt
+    from seldon_core_tpu.models.tabular import MeanTransformer
+
+    spec = SeldonDeploymentSpec.from_json_dict({
+        "spec": {"name": "gen2", "predictors": [{
+            "name": "p",
+            "graph": {"name": "t", "type": "TRANSFORMER",
+                      "children": [{"name": "g", "type": "MODEL"}]},
+            "components": [
+                {"name": "t", "runtime": "inprocess",
+                 "class_path": "MeanTransformer"},
+                {"name": "g", "runtime": "inprocess",
+                 "class_path": "TransformerGenerator",
+                 "parameters": [
+                     {"name": "vocab", "value": "48", "type": "INT"},
+                     {"name": "d_model", "value": "32", "type": "INT"},
+                     {"name": "n_layers", "value": "2", "type": "INT"},
+                     {"name": "d_ff", "value": "64", "type": "INT"},
+                     {"name": "max_new_tokens", "value": "6", "type": "INT"},
+                     {"name": "dtype", "value": "float32",
+                      "type": "STRING"},
+                 ]},
+            ],
+        }]}
+    })
+    engine = EngineService(spec)
+    assert engine.genserver is None and engine.compiled is not None
+    X = np.asarray([[3.0, 9.0, 1.0, 40.0, 7.0], [2.0, 2.0, 30.0, 5.0, 40.0]])
+    msg = SeldonMessage.from_json(json.dumps({"data": {"ndarray": X.tolist()}}))
+    got = np.asarray(asyncio.run(engine.predict(msg)).data.array)
+    unit, state = engine.compiled.units["g"], engine.compiled.states["g"]
+    tokens = sanitize_prompt(
+        MeanTransformer().transform_input(None, jnp.asarray(X)), 48)
+    assert np.asarray(tokens).sum() == 2  # min-max scaled: the 40s -> 1
+    ref = np.asarray(generate(state["params"], tokens, unit.cfg,
+                              max_new_tokens=6))
+    np.testing.assert_array_equal(got, ref)
+    np.testing.assert_array_equal(
+        ref, _naive_greedy(state["params"], tokens, 6))
 
 
 def test_single_token_generation():
@@ -323,75 +378,37 @@ def test_mask_after_eos_and_generate_eos_contract():
     assert (chunks[-1] == eos).all()
 
 
-def test_prefix_cache_equals_full_prefill():
-    """A shared-prefix KV cache built once at B=1 must reproduce the
-    full-prompt generation EXACTLY (f32 greedy) for batched suffixes,
-    through both generate() and stream_chunks()."""
-    from seldon_core_tpu.models.generate import (
-        init_cache, prefill, stream_chunks,
-    )
+def _prefix_unit(prefix_tokens="", **kw):
+    return TransformerGenerator(
+        vocab=48, d_model=32, n_heads=4, n_layers=2, d_ff=64,
+        max_new_tokens=10, dtype="float32", prefix_tokens=prefix_tokens,
+        **kw)
 
-    params = lm_init(jax.random.key(3), CFG)
+
+def test_prefix_cache_equals_full_prefill():
+    """A shared prefix on the static lane is its ids in front of every
+    row: the unit must reproduce the full-prompt generation EXACTLY (f32
+    greedy) for batched suffixes, through both predict() and
+    stream_tokens()."""
     rng = np.random.default_rng(11)
     prefix_ids = rng.integers(0, 48, size=(6,)).tolist()
-    sufs = jnp.asarray(rng.integers(0, 48, size=(3, 5)), jnp.int32)
+    sufs = jnp.asarray(rng.integers(0, 48, size=(3, 5)), jnp.float32)
+    unit = _prefix_unit(",".join(map(str, prefix_ids)))
+    state = unit.init_state(None)
     full = jnp.concatenate(
         [jnp.broadcast_to(jnp.asarray(prefix_ids, jnp.int32), (3, 6)),
-         sufs], axis=1)
-    ref = np.asarray(generate(params, full, CFG, max_new_tokens=10))
-
-    pc = init_cache(CFG, 1, len(prefix_ids))
-    _, pc = prefill(params, jnp.asarray([prefix_ids], jnp.int32), pc, CFG)
-    got = np.asarray(generate(
-        params, sufs, CFG, max_new_tokens=10, prefix=pc))
-    np.testing.assert_array_equal(got, ref)
-
-    chunks = [np.asarray(c) for c in stream_chunks(
-        params, sufs, CFG, max_new_tokens=10, chunk=4, prefix=pc)]
+         sufs.astype(jnp.int32)], axis=1)
+    ref = np.asarray(generate(state["params"], full, unit.cfg,
+                              max_new_tokens=10))
+    np.testing.assert_array_equal(
+        np.asarray(jax.jit(unit.predict)(state, sufs)), ref)
+    chunks = [np.asarray(c) for c in unit.stream_tokens(state, sufs, 4)]
     np.testing.assert_array_equal(np.concatenate(chunks, axis=1), ref)
 
 
-def _prefix_chunked_roundtrip(cfg, monkeypatch, cap=4, max_new=13):
-    """Shared body: chunked (max_new > GEN_CHUNK_CAP) prefix-path equality
-    — the zero-pad-to-main_len + merge_chunk-into-padded-region lane,
-    including int8 k_s/v_s scale buffers when cfg quantizes the cache."""
-    from seldon_core_tpu.models.generate import init_cache, prefill
-    import seldon_core_tpu.models.generate as gen_mod
-
-    params = lm_init(jax.random.key(3), cfg)
-    rng = np.random.default_rng(21)
-    prefix_ids = rng.integers(0, 48, size=(6,)).tolist()
-    sufs = jnp.asarray(rng.integers(0, 48, size=(2, 5)), jnp.int32)
-
-    pc = init_cache(cfg, 1, len(prefix_ids))
-    _, pc = prefill(params, jnp.asarray([prefix_ids], jnp.int32), pc, cfg)
-    # single-chunk reference FIRST (no cap patch): same prefix cache, so
-    # any mismatch isolates the chunked merge path itself
-    ref = np.asarray(generate(
-        params, sufs, cfg, max_new_tokens=max_new, prefix=pc))
-    monkeypatch.setattr(gen_mod, "GEN_CHUNK_CAP", cap)
-    got = np.asarray(generate(
-        params, sufs, cfg, max_new_tokens=max_new, prefix=pc))
-    np.testing.assert_array_equal(got, ref)
-
-
-def test_prefix_cache_chunked_merge_matches(monkeypatch):
-    """Float cache: prefix + chunked decode (merge_chunk into the padded
-    main region) must equal the single-chunk prefix result exactly."""
-    _prefix_chunked_roundtrip(CFG, monkeypatch)
-
-
-def test_prefix_cache_chunked_merge_matches_int8(monkeypatch):
-    """int8 KV cache variant: the padded main carries k_s/v_s scale
-    buffers that merge_chunk must relocate alongside the quantized K/V."""
-    cfg = LMConfig(vocab=48, d_model=32, n_heads=4, n_layers=2, d_ff=64,
-                   dtype=jnp.float32, kv_quant="int8")
-    _prefix_chunked_roundtrip(cfg, monkeypatch)
-
-
 def test_prefix_cache_unit_serves():
-    """prefix_tokens as a deployment parameter: the unit builds the
-    prefix cache once in init_state and every predict equals the
+    """prefix_tokens as a deployment parameter: unit state carries the
+    prefix's token ids (no KV of its own) and every predict equals the
     no-prefix unit fed the concatenated prompt."""
     plain = TransformerGenerator(
         vocab=48, d_model=32, n_heads=4, n_layers=2, d_ff=64,
@@ -400,7 +417,8 @@ def test_prefix_cache_unit_serves():
         vocab=48, d_model=32, n_heads=4, n_layers=2, d_ff=64,
         max_new_tokens=6, dtype="float32", prefix_tokens="4, 9, 2")
     sp, s2 = plain.init_state(None), pref.init_state(None)
-    assert "prefix_cache" in s2
+    assert "prefix_cache" not in s2
+    np.testing.assert_array_equal(np.asarray(s2["prefix_ids"]), [4, 9, 2])
     suf = jnp.asarray([[7, 8, 20, 1]], jnp.float32)
     full = jnp.asarray([[4, 9, 2, 7, 8, 20, 1]], jnp.float32)
     np.testing.assert_array_equal(
@@ -414,7 +432,7 @@ def test_prefix_cache_unit_serves():
 
 def test_sampled_state_writeback_preserves_prefix_cache():
     """temperature>0 writes state back (request counter); the write-back
-    must carry EVERY state key — dropping prefix_cache silently turned
+    must carry EVERY state key — dropping the prefix would silently turn
     every later request prefix-less."""
     unit = TransformerGenerator(
         vocab=48, d_model=32, n_heads=4, n_layers=2, d_ff=64,
@@ -422,28 +440,24 @@ def test_sampled_state_writeback_preserves_prefix_cache():
         prefix_tokens="4,9,2")
     state = unit.init_state(None)
     y, aux = unit.predict(state, jnp.asarray([[7, 8]], jnp.float32))
-    assert "prefix_cache" in aux.state
+    np.testing.assert_array_equal(
+        np.asarray(aux.state["prefix_ids"]), [4, 9, 2])
     y2 = unit.predict(aux.state, jnp.asarray([[7, 8]], jnp.float32))[0]
     assert np.asarray(y2).shape == (1, 4)
 
 
 def test_prefix_cache_with_int8_kv_serves():
-    """prefix caching composes with kv_quant='int8' (scales broadcast
-    and concatenate like K/V): outputs are valid tokens — exactness is
-    deliberately NOT claimed here (the prefix reads back quantized; see
-    generate()'s docstring)."""
-    import dataclasses
-
-    from seldon_core_tpu.models.generate import init_cache, prefill
-
-    cfg_q = dataclasses.replace(CFG, kv_quant="int8")
-    params = lm_init(jax.random.key(3), CFG)
-    prefix_ids = [4, 9, 2, 30]
-    pc = init_cache(cfg_q, 1, len(prefix_ids))
-    _, pc = prefill(params, jnp.asarray([prefix_ids], jnp.int32), pc,
-                    cfg_q)
-    sufs = jnp.asarray([[7, 8, 20], [1, 2, 3]], jnp.int32)
-    got = np.asarray(generate(params, sufs, cfg_q, max_new_tokens=6,
-                              prefix=pc))
-    assert got.shape == (2, 6)
+    """A shared prefix composes with kv_quant='int8': the prefix's K/V
+    are quantized into the pool like every other position, so the unit's
+    tokens equal the concatenated prompt's over the same int8 pool."""
+    unit = _prefix_unit("4,9,2,30", kv_quant="int8")
+    state = unit.init_state(None)
+    sufs = jnp.asarray([[7, 8, 20], [1, 2, 3]], jnp.float32)
+    got = np.asarray(unit.predict(state, sufs))
+    assert got.shape == (2, 10)
     assert (got >= 0).all() and (got < CFG.vocab).all()
+    full = jnp.concatenate(
+        [jnp.broadcast_to(jnp.asarray([4, 9, 2, 30], jnp.int32), (2, 4)),
+         sufs.astype(jnp.int32)], axis=1)
+    np.testing.assert_array_equal(got, np.asarray(generate(
+        state["params"], full, unit.cfg, max_new_tokens=10)))

@@ -14,7 +14,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from seldon_core_tpu.models.generate import generate, init_cache, prefill
+from seldon_core_tpu.models.generate import generate
 from seldon_core_tpu.models.transformer import LMConfig, lm_init
 from seldon_core_tpu.runtime.genserver import (
     _DECODE_TABLE_ENTRIES,
@@ -93,14 +93,29 @@ def test_allocator_pinned_blocks_never_freed():
 # -- the defining equivalence ------------------------------------------------
 
 
-def test_scheduler_tokens_identical_to_generate(params):
+@pytest.mark.parametrize("variant", ["mha", "gqa", "int8", "prefix"])
+def test_scheduler_tokens_identical_to_generate(variant):
     """Chunked prefill (prompt 7 through chunk-4 pieces) + paged decode
     rounds must reproduce one-shot generate() token-for-token (greedy,
-    f32) — including across co-scheduled requests."""
+    f32) — including across co-scheduled requests.  Both lanes run the
+    same programs over the same layout, so this holds for grouped KV
+    heads, for an int8 pool (every position is read back quantized in
+    both) and for a shared prefix (pinned blocks vs the ids in front)."""
+    import dataclasses
+
+    cfg = {"gqa": dataclasses.replace(CFG, n_kv_heads=2),
+           "int8": dataclasses.replace(CFG, kv_quant="int8")}.get(
+               variant, CFG)
+    params = lm_init(jax.random.key(3), cfg)
     prompts = np.random.default_rng(0).integers(0, 48, size=(3, 7))
-    ref = np.asarray(generate(params, jnp.asarray(prompts, jnp.int32),
-                              CFG, max_new_tokens=10))
-    srv = _server(params)
+    prefix = [5, 40, 17, 2, 33] if variant == "prefix" else []
+    full = np.concatenate(
+        [np.broadcast_to(np.asarray(prefix, int), (3, len(prefix))),
+         prompts], axis=1)
+    ref = np.asarray(generate(params, jnp.asarray(full, jnp.int32),
+                              cfg, max_new_tokens=10))
+    srv = _server(params, cfg=cfg,
+                  prefix_ids=np.asarray(prefix, np.int32) if prefix else None)
     try:
         # two requests in flight at once: rows co-batch in the decode
         # round, outputs stay per-row identical
@@ -164,27 +179,67 @@ def test_scheduler_int8_kv_pool(params):
         srv.stop()
 
 
-def test_scheduler_prefix_cache_shared_blocks(params):
-    """A shared B=1 prefix cache: full blocks written once and pinned,
-    per-sequence tail copy, outputs equal full-prompt generate()."""
+@pytest.mark.parametrize("prefix_len", [6, 8])
+def test_scheduler_prefix_cache_shared_blocks(prefix_len):
+    """A shared prefix lives in the pool only: unit state carries its
+    token ids, the scheduler computes its K/V once into pinned blocks
+    (full blocks shared by table reference, a partly filled boundary
+    block copied per sequence), and outputs equal full-prompt
+    generate().  Block size 4: length 6 leaves a 2-token tail, length 8
+    is whole blocks."""
+    from seldon_core_tpu.models.generate import (
+        TransformerGenerator, _paged_view,
+    )
+
     rng = np.random.default_rng(11)
-    prefix_ids = rng.integers(0, 48, size=(6,)).tolist()
+    prefix_ids = rng.integers(0, 48, size=(prefix_len,)).tolist()
     sufs = rng.integers(0, 48, size=(3, 5))
+    unit = TransformerGenerator(
+        vocab=48, d_model=32, n_heads=4, n_layers=2, d_ff=64,
+        max_new_tokens=10, dtype="float32",
+        prefix_tokens=",".join(map(str, prefix_ids)))
+    state = unit.init_state(None)
+    # ids only: nothing in unit state has a KV layout
+    assert sorted(state) == ["params", "prefix_ids", "requests"]
+    np.testing.assert_array_equal(np.asarray(state["prefix_ids"]),
+                                  prefix_ids)
     full = np.concatenate(
-        [np.broadcast_to(np.asarray(prefix_ids), (3, 6)), sufs], axis=1)
-    ref = np.asarray(generate(params, jnp.asarray(full, jnp.int32), CFG,
-                              max_new_tokens=10))
-    pc = init_cache(CFG, 1, len(prefix_ids))
-    _, pc = prefill(params, jnp.asarray([prefix_ids], jnp.int32), pc, CFG)
-    srv = _server(params, prefix_cache=pc)
+        [np.broadcast_to(np.asarray(prefix_ids), (3, prefix_len)), sufs],
+        axis=1)
+    ref = np.asarray(generate(state["params"], jnp.asarray(full, jnp.int32),
+                              unit.cfg, max_new_tokens=10))
+    srv = GenServer(**unit.continuous_spec(state), block_size=4,
+                    num_blocks=64, slots=8, span=3, prefill_chunk=4)
     try:
         got = srv.submit(sufs.astype(float)).future.result(timeout=180)
         np.testing.assert_array_equal(got, ref)
         snap = _settle(srv)
-        # prefix len 6, block 4: one full block pinned + shared, the
-        # 2-token tail copied per-sequence into private blocks
-        assert snap["kv_blocks"]["pinned"] == 1
-        assert snap["kv_blocks"]["used"] == 1  # only the pinned block stays
+        # every block the prefix touches is pinned and stays resident;
+        # the sequences' private blocks went back at retirement
+        pinned = -(-prefix_len // 4)
+        assert snap["kv_blocks"]["pinned"] == pinned
+        assert snap["kv_blocks"]["used"] == pinned
+        assert len(srv._prefix_blocks) == prefix_len // 4
+        assert (srv._prefix_tail is None) == (prefix_len % 4 == 0)
+        # the pinned blocks hold the prefix: its K equals what a private
+        # pool gets from the same ids (generate()'s row 0, positions < P)
+        from seldon_core_tpu.models.generate import (
+            paged_forward_jit, private_pool,
+        )
+
+        pool, tables = private_pool(unit.cfg, 1, prefix_len)
+        _, pool = paged_forward_jit(
+            state["params"], jnp.asarray([prefix_ids], jnp.int32), pool,
+            tables, jnp.zeros((1,), jnp.int32),
+            jnp.full((1,), prefix_len, jnp.int32), cfg=unit.cfg)
+        want = _paged_view(pool["l1"], tables)["k"][:, :, :prefix_len]
+        blocks = srv._prefix_blocks + (
+            [] if srv._prefix_tail is None else [srv._prefix_tail])
+        have = _paged_view(
+            srv._pool["l1"], jnp.asarray([blocks], jnp.int32)
+        )["k"][:, :, :prefix_len]
+        np.testing.assert_allclose(np.asarray(have), np.asarray(want),
+                                   rtol=1e-6, atol=1e-6)
     finally:
         srv.stop()
 

@@ -10,7 +10,7 @@ import pytest
 from seldon_core_tpu.models.generate import (
     TransformerGenerator,
     generate,
-    init_cache,
+    init_block_pool,
 )
 from seldon_core_tpu.models.transformer import (
     LMConfig,
@@ -48,10 +48,11 @@ def test_gqa_cache_shrinks_by_group_factor():
     cfg_mha = LMConfig(vocab=64, d_model=64, n_heads=8, n_layers=2, d_ff=128)
     cfg_gqa = LMConfig(vocab=64, d_model=64, n_heads=8, n_layers=2, d_ff=128,
                        n_kv_heads=2)
-    c_mha = init_cache(cfg_mha, batch=4, max_len=32)
-    c_gqa = init_cache(cfg_gqa, batch=4, max_len=32)
-    assert c_mha["l0"]["k"].shape == (4, 8, 32, 8)
-    assert c_gqa["l0"]["k"].shape == (4, 2, 32, 8)
+    # the pool's KV axis: [num_blocks, block_size, KV, hd]
+    c_mha = init_block_pool(cfg_mha, num_blocks=9, block_size=16)
+    c_gqa = init_block_pool(cfg_gqa, num_blocks=9, block_size=16)
+    assert c_mha["l0"]["k"].shape == (9, 16, 8, 8)
+    assert c_gqa["l0"]["k"].shape == (9, 16, 2, 8)
     # wqkv output shrinks too: q (64) + k/v (2 heads x 8 dim each)
     p = lm_init(jax.random.key(0), cfg_gqa)
     assert p["l0"]["wqkv"].shape == (64, 64 + 2 * 2 * 8)

@@ -86,6 +86,32 @@ def test_moe_generation():
     assert ((0 <= y) & (y < 48)).all()
 
 
+def test_moe_generation_matches_naive_reforward():
+    """The input only the static lane serves (continuous_spec is None for
+    MoE): generate() over its private pool equals recomputing the whole
+    forward every step.  Two experts, both taken by every token, so expert
+    capacity (sized from the tokens in a pass) never drops one: a cached
+    step of B tokens and a re-forward of B x S are then the same function
+    of the sequence — with drops they are not, whatever the cache."""
+    import dataclasses
+
+    from seldon_core_tpu.models.generate import TransformerGenerator
+
+    assert TransformerGenerator(moe_every=2).continuous_spec({}) is None
+    cfg = dataclasses.replace(CFG, n_experts=2)
+    params = lm_init(jax.random.key(4), cfg)
+    prompt = _tokens(4, 2, 5)
+    got = np.asarray(jax.jit(
+        lambda p, t: generate(p, t, cfg, max_new_tokens=6))(params, prompt))
+    tokens, want = prompt, []
+    for _ in range(6):
+        nxt = jnp.argmax(lm_apply(params, tokens, cfg)[:, -1, :],
+                         axis=-1).astype(jnp.int32)
+        want.append(nxt)
+        tokens = jnp.concatenate([tokens, nxt[:, None]], axis=1)
+    np.testing.assert_array_equal(got, np.asarray(jnp.stack(want, axis=1)))
+
+
 def test_moe_generator_unit_serves():
     """MoE generation reachable from a deployment config, incl. NaN-proof
     prompt handling."""

@@ -210,19 +210,23 @@ def test_the_cpu_takes_the_gather_path():
     assert decode_inplace(pool) is False
 
 
-def test_init_cache_exact_length():
-    """Dense caches allocate EXACTLY the requested length: padding would
-    bill every decode step for masked slots."""
-    from seldon_core_tpu.models.generate import init_cache
+def test_private_pool_exact_length():
+    """The static lane's private pool holds exactly 1 + B * ceil(total/bs)
+    blocks — the scratch block and each row's own, in order — and no
+    more: every block past a row's length is attended under a mask."""
+    from seldon_core_tpu.models.generate import BLOCK_SIZE, private_pool
 
     cfg = LMConfig(vocab=64, d_model=64, n_heads=4, n_layers=1, d_ff=128)
-    c = init_cache(cfg, batch=2, max_len=130)
-    assert c["l0"]["k"].shape[2] == 130
+    pool, tables = private_pool(cfg, 2, 130)
+    n = -(-130 // BLOCK_SIZE)
+    assert pool["l0"]["k"].shape[:2] == (1 + 2 * n, BLOCK_SIZE)
+    np.testing.assert_array_equal(
+        np.asarray(tables), 1 + np.arange(2 * n).reshape(2, n))
 
 
 @pytest.mark.slow  # heavyweight equivalence check: full-suite/CI-shard coverage; excluded from the tier-1 time budget
 def test_generate_matches_teacher_forced_lm_apply():
-    """Greedy generate over the dense cache equals teacher forcing through
+    """Greedy generate over its private pool equals teacher forcing through
     lm_apply, which has no cache at all."""
     from seldon_core_tpu.models.generate import generate
     from seldon_core_tpu.models.transformer import lm_apply

@@ -31,6 +31,43 @@ def test_speculative_equals_vanilla_greedy():
     assert 1 <= int(rounds[0]) <= 24
 
 
+def test_speculative_static_lane_runs_the_paged_round(monkeypatch):
+    """speculative_generate drives paged_spec_round — the program the
+    scheduler's speculative mode dispatches — over two private pools, and
+    still equals vanilla greedy for every row of a batch at different
+    acceptance; a bound on the rounds leaves zero-padded tails."""
+    import seldon_core_tpu.models.speculative as spec_mod
+
+    traced = []
+    inner = spec_mod.paged_spec_round
+
+    def spy(*a, **kw):
+        traced.append(kw["k"])
+        return inner(*a, **kw)
+
+    monkeypatch.setattr(spec_mod, "paged_spec_round", spy)
+    tp = lm_init(jax.random.key(0), TARGET)
+    dp = lm_init(jax.random.key(1), DRAFT)
+    prompts = jnp.asarray(
+        np.random.default_rng(3).integers(0, 48, size=(3, 6)), jnp.int32
+    )
+    ref = np.asarray(generate(tp, prompts, TARGET, max_new_tokens=12))
+    got, rounds = speculative_generate(tp, dp, prompts, TARGET, DRAFT,
+                                       max_new_tokens=12, k=3)
+    assert traced == [3]  # traced once, into the while_loop's body
+    np.testing.assert_array_equal(np.asarray(got), ref)
+    assert ((1 <= np.asarray(rounds)) & (np.asarray(rounds) <= 11)).all()
+    capped, r2 = speculative_generate(tp, dp, prompts, TARGET, DRAFT,
+                                      max_new_tokens=12, k=3, max_rounds=2)
+    capped = np.asarray(capped)
+    assert (np.asarray(r2) == 2).all()
+    for b in range(3):
+        n = int(np.flatnonzero(capped[b] != ref[b])[0]) \
+            if (capped[b] != ref[b]).any() else 12
+        assert 3 <= n  # first token + two rounds of at least one each
+        assert (capped[b, n:] == 0).all() or n == 12
+
+
 def test_speculative_self_draft_max_acceptance():
     """Draft == target: every proposal matches, so rounds ~ max_new/(k+1)."""
     tp = lm_init(jax.random.key(2), TARGET)

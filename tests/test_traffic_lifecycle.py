@@ -61,7 +61,10 @@ def _predictor(name, seed, replicas, annotations=None, node=None):
 
 
 def _spec(name="life-dep", shadow=True, sample="1.0", extra_ann=None,
-          cand_seed=1):
+          cand_seed=1, node=None):
+    """``node`` names both predictors' unit: a unit's init key is derived
+    from its NAME as well as its seed (graph/interpreter.py unit_rngs), so
+    "the same weights" needs both to agree."""
     ann = {"seldon.io/shadow-sample": sample,
            "seldon.io/shadow-budget-per-s": "10000"}
     ann.update(extra_ann or {})
@@ -70,10 +73,11 @@ def _spec(name="life-dep", shadow=True, sample="1.0", extra_ann=None,
             "name": name, "oauth_key": "k", "oauth_secret": "s",
             "annotations": ann,
             "predictors": [
-                _predictor("main", 0, 3),
+                _predictor("main", 0, 3, node=node),
                 _predictor(
                     "cand", cand_seed, 1,
                     {"seldon.io/shadow": "true"} if shadow else None,
+                    node=node,
                 ),
             ],
         }
@@ -127,7 +131,9 @@ def test_shadow_config_from_spec_and_weight_zero_registration():
 
 def test_shadow_mirrors_and_diffs_live_traffic():
     async def run():
-        spec = _spec(cand_seed=0)  # identical candidate: zero divergence
+        # identical candidate — same unit name, same seed, so the same
+        # trained weights: zero divergence
+        spec = _spec(cand_seed=0, node="clf")
         gw, store, engines, token = await _gateway(spec)
         rng = np.random.default_rng(0)
         for _ in range(20):

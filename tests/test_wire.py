@@ -301,6 +301,50 @@ async def _http_round(port, body, ctype, reader=None, writer=None,
     return status, ct, resp, reader, writer
 
 
+def test_httpfast_binary_copies_a_quarter_of_json_at_most():
+    """The binary lane's reason to exist, as a count and not a timing: over
+    the fast HTTP lane a [16, 784] float32 predict copies, by the codec's own
+    accounting, at most a quarter of the bytes the JSON lane must (socket
+    bytes -> bytes -> str, values materialized as f64, and back — a LOWER
+    bound from the measured body sizes)."""
+    from seldon_core_tpu.runtime.httpfast import serve_fast
+
+    rows, feats, n = 16, 784, 10
+
+    async def run():
+        eng = EngineService(sigmoid_spec(n_features=feats), max_batch=64,
+                            max_wait_ms=0.5)
+        srv = await serve_fast(eng, "127.0.0.1", 0)
+        r = w = None
+        try:
+            x = np.random.default_rng(7).normal(
+                size=(rows, feats)).astype(np.float32)
+            jreq = json_payload(x.astype(np.float64)).encode()
+            st, _ct, jresp, r, w = await _http_round(
+                srv.port, jreq, "application/json")
+            assert st == 200
+            good = frame_bytes(x)
+            st, _ct, _b, r, w = await _http_round(  # warm the lane
+                srv.port, good, wire.WIRE_CONTENT_TYPE, r, w)
+            assert st == 200
+            before = RECORDER.snapshot()["wire"]["bytes_copied"]
+            for _ in range(n):
+                st, _ct, _b, r, w = await _http_round(
+                    srv.port, good, wire.WIRE_CONTENT_TYPE, r, w)
+                assert st == 200
+            copied = (RECORDER.snapshot()["wire"]["bytes_copied"]
+                      - before) / n
+            json_copied = 2 * len(jreq) + 8 * rows * feats + 2 * len(jresp)
+            assert 0 < copied <= json_copied / 4, (copied, json_copied)
+        finally:
+            if w is not None:
+                w.close()
+            await srv.stop()
+            await eng.close()
+
+    asyncio.run(run())
+
+
 def test_httpfast_binary_parity_then_typed_errors_keep_serving():
     from seldon_core_tpu.runtime.httpfast import serve_fast
 
