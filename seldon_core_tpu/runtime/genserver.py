@@ -24,6 +24,12 @@ pool and the tables:
     program, one host sync), retires finished rows (the device-side
     after-eos latch composing with the ``mask_after_eos`` output
     contract), and hands tokens to the per-request streams.
+  * **One round ahead** — the iteration dispatches round k+1 before it
+    reads round k back: which rows ride a round is arithmetic, and what
+    a round hands the next (pending token, after-eos latch, sampling
+    key) stays on the device in a per-slot carry, so the device goes
+    from round to round without waiting for the host (``GenServer
+    ._tick``, ``_depth``, ``_carry_ops``).
   * **Chunked prefill** — prompts are consumed ``prefill_chunk`` tokens
     at a time, interleaved between decode rounds, so a 512-token prompt
     stalls in-flight streams for at most one chunk instead of a full
@@ -55,6 +61,7 @@ lane (runtime/engine.py).
 from __future__ import annotations
 
 import concurrent.futures
+import functools
 import logging
 import os
 import queue
@@ -93,7 +100,10 @@ class _Phase:
     beside the device ops) and, with ``into``, its ``perf_counter`` seconds
     added to ``into[key]`` for ``/genperf``.  One helper opens both, so the
     two can never mean different intervals.  ``args`` ride the annotation
-    (read back from the trace by bench/lib/trace_scopes.py)."""
+    (read back from the trace by bench/lib/trace_scopes.py).  Device
+    seconds are not booked here: a program's are taken where its completion
+    is observed (``GenServer._await``, utils/genperf.py
+    ``booked_device_s``)."""
 
     __slots__ = ("_ann", "_into", "_key", "_t0")
 
@@ -151,6 +161,117 @@ def _decode_table_width(inplace, rows: int, need: int, row_max: int) -> int:
     if inplace and cap >= 1:
         width = max(width, 1 << (cap.bit_length() - 1))
     return width
+
+
+# Of how many decode rounds the scheduler runs one the synchronous way, to
+# read a fenced round's slack again (``GenServer._depth``).  The slack --
+# dispatch -> the module starting, the module ending -> ``block_until_ready``
+# returning -- is what the wake-up before a round's end aims at (``_pace``):
+# round k+1 has to be dispatched that long before round k is seen to end, and
+# the host cannot see it while it keeps a round ahead.  It moves with the
+# machine's moment (PERF.md section 6, PR 24: 8.5-11 ms after a cold start,
+# 3.2 ms warm), so it is read again; a fenced tick costs two hand-overs, so
+# one round in 19 keeps that under 1% of the rounds' time whatever a round
+# lasts.
+_FENCE_EVERY = 19
+
+# A round's device seconds are the least of this many booked readings
+# (``GenServer._await``): a reading can only be too long -- the host came
+# late to a completion -- so the least of a few is what the sleep before
+# the next round cannot move.
+_ROUND_READINGS = 8
+
+
+@functools.lru_cache(maxsize=None)
+def _carry_ops():
+    """The tiny jitted programs over the scheduler's per-slot carry -- what
+    one decode round hands the next, kept on the device so that a round
+    can be dispatched before the one ahead of it was read back.
+
+    ``carry``: ``{"tok": int32 [S], "seen": bool [S]}`` and, for sampled
+    decoding, ``"keys": uint32 [S, K]`` key data; S = slots + 1, the last
+    entry scratch for padded rows.  Every shape but the batch's row count
+    is fixed, so each program compiles once a row count
+    (``GenServer._init_device`` loads them all).
+
+    * ``take(carry, idx)`` -> a round's ``token``, ``seen_eos`` and
+      ``keys`` inputs: a gather by the batch's slot indices.
+    * ``put(carry, idx, tok, seen, keys)`` -> ``(carry', key data)``: the
+      round's ``token'`` / ``seen_eos'`` / ``keys'`` scattered back (keys
+      typed as the program returns them, or raw key data from the host).
+    * ``first(carry, logits, idx, held, held_tok, key_data)`` ->
+      ``(carry', first tokens [B], key data')``: the first token of every
+      row whose prompt ended in this chunk, picked on the device --
+      argmax, or ``sample_token`` under the first half of the row's split
+      key as the host used to -- and scattered into the carry.  ``held``
+      marks rows readmitted after a preemption, whose pending token
+      ``held_tok`` (and key) the host restores instead of sampling."""
+    import jax
+    import jax.numpy as jnp
+
+    from seldon_core_tpu.models.generate import sample_token
+
+    def take(carry, idx):
+        keys = carry.get("keys")
+        return (carry["tok"][idx], carry["seen"][idx],
+                None if keys is None
+                else jax.random.wrap_key_data(keys[idx]))
+
+    def put(carry, idx, tok, seen, keys):
+        out = {"tok": carry["tok"].at[idx].set(tok),
+               "seen": carry["seen"].at[idx].set(seen)}
+        if keys is not None and jnp.issubdtype(keys.dtype,
+                                               jax.dtypes.prng_key):
+            keys = jax.random.key_data(keys)
+        if "keys" in carry:
+            out["keys"] = carry["keys"].at[idx].set(keys)
+        return out, keys
+
+    def first(carry, logits, idx, held, held_tok, key_data, *,
+              temperature, top_k, top_p, eos_token):
+        if temperature > 0.0:
+            split = jax.vmap(jax.random.split)(
+                jax.random.wrap_key_data(key_data))
+            tok = jax.vmap(lambda lg, kk: sample_token(
+                lg[None, :], kk, temperature, top_k, top_p)[0])(
+                    logits, split[:, 0])
+            key_data = jnp.where(held[:, None], key_data,
+                                 jax.random.key_data(split[:, 1]))
+        else:
+            tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        tok = jnp.where(held, held_tok, tok)
+        seen = (tok == eos_token) if eos_token >= 0 else jnp.zeros_like(held)
+        carry, key_data = put(carry, idx, tok, seen, key_data)
+        return carry, tok, key_data
+
+    return (jax.jit(take), jax.jit(put, donate_argnums=(0,)),
+            jax.jit(first, donate_argnums=(0,), static_argnames=(
+                "temperature", "top_k", "top_p", "eos_token")))
+
+
+class _Flight:
+    """One dispatched device program whose results the host has not read:
+    what ``_await`` waits on, what the collect step reads back, and the
+    rows it will credit -- decided by arithmetic at dispatch, because the
+    next program may already be queued behind this one."""
+
+    __slots__ = ("kind", "rows", "ready", "out", "keys", "t_dispatch",
+                 "t_done", "size", "chunk", "host_s", "attr")
+
+    def __init__(self, kind: str, size: int, rows: list, ready, out, keys,
+                 t_dispatch: float, attr: tuple):
+        self.kind = kind              # "decode" | "prefill"
+        self.size = size              # padded row count
+        self.rows = rows              # decode: (seq, take); prefill:
+        #                               (seq, row index, fresh first token)
+        self.ready = ready            # the output whose readiness is waited
+        self.out = out                # tokens to read back (None: nothing)
+        self.keys = keys              # sampled: the rows' new key data
+        self.t_dispatch = t_dispatch
+        self.attr = attr              # cost ledger: (padded units, rows)
+        self.t_done: Optional[float] = None   # observed completion
+        self.chunk = 0                # prefill: the saturated chunk width
+        self.host_s = 0.0             # prefill: build + dispatch wall
 
 
 class BlockAllocator:
@@ -258,7 +379,7 @@ class _Sequence:
         "sid", "request", "row", "prompt", "prompt0", "max_new", "state",
         "n_valid", "blocks", "draft_blocks", "pending", "prefill_pos",
         "emitted", "done", "key_data", "admit_order", "retire_reason",
-        "t_start", "events",
+        "t_start", "events", "slot", "inflight",
     )
     WAITING, PREFILL, RUNNING, DONE = range(4)
 
@@ -274,7 +395,12 @@ class _Sequence:
         self.n_valid = 0                # cache positions written (global)
         self.blocks: List[int] = []     # PRIVATE blocks only
         self.draft_blocks: List[int] = []   # speculative mode
-        self.pending: Optional[int] = None  # sampled, not yet in cache
+        #: sampled, not yet in cache.  The host's copy: current whenever
+        #: nothing of this row is in flight (``inflight == 0``); the round
+        #: itself reads the device's, in the carry at ``slot``
+        self.pending: Optional[int] = None
+        self.slot = -1                  # index into the device-side carry
+        self.inflight = 0               # tokens dispatched, not yet read
         self.prefill_pos = 0            # prompt tokens consumed
         self.emitted: List[int] = []
         self.done = False
@@ -472,6 +598,28 @@ class GenServer:
         self._stopped = False
         self._pool = None
         self._inplace = False  # decode attends over the pool in place
+        # what one decode round hands the next, on the device (_carry_ops):
+        # a sequence holds a slot of it from admission to retirement
+        self._carry = None
+        self._slot_free: deque = deque(range(self.slots))
+        self._zero_keys: Dict[int, Any] = {}   # greedy: one a row count
+        #: dispatched programs whose results are unread, in device order.
+        #: Between ticks it holds at most the one decode round the tick
+        #: keeps ahead of its own readback (_depth)
+        self._unread: deque = deque()
+        # the server's own observations the wake-up before a round's end
+        # rests on (_pace): the last observed completion and whether the
+        # host was waiting when it came, a round's booked device seconds by
+        # row count (the last few: the estimate is their least), a fenced
+        # round's slack and how many rounds ago it was read, and the one
+        # closed-loop term, the guard
+        self._last_done = 0.0
+        self._last_done_seen = False
+        self._round_s: Dict[int, deque] = {}
+        self._slack_s = 0.0
+        self._guard_s = 0.0
+        self._since_fence = _FENCE_EVERY
+        self._paced: Optional[_Flight] = None
         self._device_ready = False
         self._device_init_lock = threading.Lock()
         self._draft_pool = None
@@ -527,11 +675,15 @@ class GenServer:
         self._last_tick_end = 0.0
         self._bubble_cause = "idle"
         self._pool_dry = False               # _admit broke on a dry pool
-        self._dev_s: Dict[str, float] = {}   # phase -> fenced device s
+        self._dev_s: Dict[str, float] = {}   # phase -> booked device s
         self._tick_rows = 0                  # padded rows dispatched
         self._tick_real_rows = 0             # real rows dispatched
         self._tick_dev_steps = 0             # single-token device steps
         self._tick_inplace_steps = 0         # ... that attended in place
+        self._tick_ahead_steps = 0           # ... dispatched ahead of a read
+        self._tick_tokens = 0                # tokens emitted
+        self._tick_retired = 0               # sequences retired
+        self._phases: Dict[str, float] = {}  # phase -> host wall s
         self._tick_kv_pos = 0                # cache positions streamed
         self._tick_kv_blocks = 0             # blocks the tables covered
         self._tick_kv_ages: List[tuple] = []  # (n_blocks, age_s) freed
@@ -776,6 +928,15 @@ class GenServer:
             "tokens_emitted_total": self.tokens_emitted_total,
             "tick_errors_total": self.tick_errors_total,
             "programs": {k: len(v) for k, v in self._programs.items()},
+            # what the wake-up before a round's end rests on (_pace): a
+            # round's device ms by row count, the guard it wakes ahead by,
+            # the fenced round's slack the guard aims at
+            "pace": {
+                "round_ms": {str(b): round(min(v) * 1e3, 3)
+                             for b, v in sorted(self._round_s.items())},
+                "guard_ms": round(self._guard_s * 1e3, 3),
+                "slack_ms": round(self._slack_s * 1e3, 3),
+            },
             "sequence_ledger": ledger,
         }
         if self.spec:
@@ -869,6 +1030,7 @@ class GenServer:
                 self.draft_cfg, self.num_blocks, self.block_size)
             self._draft_allocator = BlockAllocator(self.num_blocks)
         self._register_decode_costs()
+        self._init_carry()
         if self.prefix_ids is not None:
             # the shared prefix is computed ONCE, here, into pinned blocks:
             # one row whose table is those blocks.  Its full blocks are
@@ -892,6 +1054,72 @@ class GenServer:
             full = P // self.block_size
             self._prefix_blocks = blocks[:full]
             self._prefix_tail = blocks[full] if P % self.block_size else None
+
+    def _new_carry(self):
+        """A zeroed carry on the device (``_carry_ops``).  Under a mesh it is
+        replicated over it, so that what the helpers return can enter the
+        sharded programs beside the parameters and the pool."""
+        import jax
+
+        width = self.slots + 1          # the last entry is scratch
+        carry = {"tok": np.zeros((width,), np.int32),
+                 "seen": np.zeros((width,), bool)}
+        if self.temperature > 0.0:
+            carry["keys"] = np.zeros((width, self._key_width), np.uint32)
+        if self.mesh is None:
+            return jax.device_put(carry)
+        from jax.sharding import NamedSharding, PartitionSpec
+
+        return jax.device_put(
+            carry, NamedSharding(self.mesh, PartitionSpec()))
+
+    def _init_carry(self) -> None:
+        """The carry, and every helper over it compiled for every row count
+        a batch can have: they are keyed by nothing else, so none of them
+        can compile once traffic runs, whatever it drives.  The row counts
+        compile side by side (each on a carry of its own: ``put`` and
+        ``first`` donate theirs): a dozen and a half tiny programs, none
+        slow enough for the persistent cache to keep, are a second of a
+        boot instead of five."""
+        import jax
+
+        take, put, first = _carry_ops()
+        self._key_width = (
+            int(np.asarray(jax.random.key_data(
+                jax.random.key(self.seed))).shape[-1])
+            if self.temperature > 0.0 else 0)
+
+        def load(rows: int) -> None:
+            carry = self._new_carry()
+            idx = np.full((rows,), self.slots, np.int32)   # scratch only
+            tok, seen, keys = take(carry, idx)
+            carry, key_data = put(carry, idx, tok, seen, keys)
+            if key_data is not None:
+                # raw key data from the host: an imported row's (decode role)
+                key_data = np.asarray(key_data)
+                carry, _ = put(carry, idx, tok, seen, key_data)
+            first(carry, np.zeros((rows, self.cfg.vocab), np.float32), idx,
+                  np.zeros((rows,), bool), np.zeros((rows,), np.int32),
+                  key_data, temperature=self.temperature, top_k=self.top_k,
+                  top_p=self.top_p, eos_token=self.eos_token)
+
+        counts = [1 << i for i in range(_pow2(self.slots).bit_length())]
+        with concurrent.futures.ThreadPoolExecutor(len(counts)) as pool:
+            list(pool.map(load, counts))
+        self._zero_keys = {
+            rows: jax.device_put(np.zeros((rows,), np.uint32))
+            for rows in counts}
+        self._carry = self._new_carry()
+
+    def _first(self, logits, idx, held, held_tok, key_data):
+        """First tokens picked on the device into the carry (``_carry_ops``
+        ``first``); returns the ``[B]`` tokens and the rows' new key data,
+        both still on the device."""
+        self._carry, tok, key_data = _carry_ops()[2](
+            self._carry, logits, idx, held, held_tok, key_data,
+            temperature=self.temperature, top_k=self.top_k,
+            top_p=self.top_p, eos_token=self.eos_token)
+        return tok, key_data
 
     def _register_decode_costs(self) -> None:
         """Analytic per-token cost features for the SERVED decode lane,
@@ -936,7 +1164,11 @@ class GenServer:
                 while (not self._stopped and not self._arrivals
                        and not self._waiting and not self._prefilling
                        and not self._active and not self._remote_arrivals
-                       and not self._handoff_done):
+                       and not self._handoff_done
+                       # a round queued behind its rows' last one (an eos
+                       # found a round late) is read before the loop parks:
+                       # its completion is no later arrival's to find
+                       and not self._unread):
                     with _Phase("GenServer._run/wait"):
                         if self._imports:
                             # an in-flight remote import holds reserved
@@ -948,8 +1180,6 @@ class GenServer:
                         self._wake.wait()
                 if self._stopped:
                     break
-                while self._arrivals:
-                    self._waiting.append(self._arrivals.popleft())
             try:
                 with _Phase("GenServer._tick"):
                     progress = self._tick()
@@ -979,6 +1209,10 @@ class GenServer:
             committed = list(self._remote_arrivals)
             seqs = (list(self._waiting) + list(self._prefilling)
                     + list(self._active) + list(self._arrivals)
+                    # rows of a program dispatched and not read: some left
+                    # the lists by arithmetic when it was dispatched (a
+                    # finished prompt awaiting hand-off; see _Flight)
+                    + [row[0] for fl in self._unread for row in fl.rows]
                     + [imp.seq for imp in committed]
                     # sequences whose handoff is at the coordinator (or
                     # already completed into _handoff_done): they live in
@@ -991,6 +1225,7 @@ class GenServer:
             self._handoff_seqs.clear()
             self._handoff_done.clear()
             self._prefilling, self._active = [], []
+            self._unread.clear()
             imports = list(self._imports.values())
             self._imports.clear()
         for imp in imports + committed:
@@ -1001,6 +1236,7 @@ class GenServer:
                 self._allocator.release_reserved(imp.blocks)
         for seq in seqs:
             self._release_blocks(seq)
+            seq.inflight = 0
             req = seq.request
             if not req.future.done():
                 req.future.set_exception(exc)
@@ -1009,18 +1245,44 @@ class GenServer:
             # future bounded-queue change must BLOCK here rather than
             # silently drop the shutdown error a consumer is waiting on
             req.queue.put(exc)
+        if self._carry is not None and not self._stopped:
+            # whatever failed may have left the carry's chain of donated
+            # buffers broken: the next request starts from a fresh one
+            try:
+                self._carry = self._new_carry()
+            except Exception:  # noqa: BLE001 - the requests are failed already
+                logger.exception("could not rebuild the decode carry")
 
     # -- the scheduler step ----------------------------------------------
 
     def _tick(self) -> bool:
         """One scheduler iteration: admit, one prefill chunk, one decode
-        round, retire, account.  Exactly one fused telemetry record per
-        step (utils/hotrecord.py HOP_GEN_STEP) — enriched with the
-        flight-recorder decomposition: per-phase host walls, the fenced
-        device walls the phase methods accumulated, and the inter-tick
-        bubble classified by how the PREVIOUS tick ended.  Returns False
-        when no work could run (the loop then backs off instead of
-        spinning)."""
+        round, retire, account.
+
+        **The order.**  The tick dispatches the next device work before it
+        reads back the last.  Entered with round k on the device (left
+        there by the tick before), it sleeps until that round is about to
+        end (``_pace``), admits, builds and dispatches this tick's prefill
+        chunk and round k+1 -- which rows ride it is arithmetic, the
+        tokens they carry stay on the device (``_carry_ops``) -- and only
+        then waits for round k's tokens, reads them back, emits, retires
+        and publishes, under cover of the device running what it was just
+        given; a first token is read and delivered as soon as its chunk is
+        done.  The synchronous order -- every program fenced and read
+        before the next is built -- is the same code at depth 0
+        (``_depth``): the wait placed before the dispatch.  The tick
+        drains to it wherever a decision needs tokens the host has not
+        seen: speculative rounds, a prefill replica's hand-off, a cancel
+        or a preemption that touches a row in flight, a dry pool,
+        ``stop()``, a device error.
+
+        Exactly one fused telemetry record per step (utils/hotrecord.py
+        HOP_GEN_STEP) -- enriched with the flight-recorder decomposition:
+        per-phase host walls, the device seconds booked for every program
+        whose completion this tick observed (``_await``), and the
+        inter-tick bubble classified by how the PREVIOUS tick ended.
+        Returns False when no work could run (the loop then backs off
+        instead of spinning)."""
         t0 = time.perf_counter()
         bubble_s = (max(t0 - self._last_tick_end, 0.0)
                     if self._last_tick_end > 0.0 else 0.0)
@@ -1029,37 +1291,73 @@ class GenServer:
         self._dev_s = {}
         self._tick_rows = self._tick_real_rows = 0
         self._tick_dev_steps = self._tick_kv_pos = self._tick_kv_blocks = 0
-        self._tick_inplace_steps = 0
+        self._tick_inplace_steps = self._tick_ahead_steps = 0
         self._tick_attr = {} if costledger_enabled() else None
         self._tick_kv_attr = []
+        self._tick_tokens = self._tick_retired = 0
+        self._paced = None
         self._ensure_device()
+        phases = self._phases = {}
+        depth = self._depth()
+        decoded = bool(self._unread)    # a round's tokens come back below
+        if self._unread and depth:
+            with _Phase("GenServer._decode_round", phases, "decode"):
+                self._pace()
+            if self._stopped:
+                return True
+        elif self._unread:
+            # the synchronous order: the wait comes before everything else,
+            # admission included, so that a request arriving while the round
+            # in flight ends still gets into this tick's chunk
+            self._drain()
+        with self._wake:
+            while self._arrivals:
+                self._waiting.append(self._arrivals.popleft())
         self._drop_cancelled()
-        phases: Dict[str, float] = {}
         with _Phase("GenServer._admit", phases, "admit"):
             admitted = self._admit()
             admitted += self._import_admit()
             handed_back = self._drain_handoff_done()
             self._reap_stale_imports()
-        kind = None
-        tokens = 0
+        prefilled = False
         if self._prefilling:
-            kind = "prefill"
             with _Phase("GenServer._prefill_tick", phases, "prefill"):
-                tokens = self._prefill_tick()
-        # a first token can finish a sequence (eos / max_new == 1): retire
-        # BEFORE the round so it neither wastes a slot nor a dispatch
-        with _Phase("GenServer._retire", phases, "retire"):
-            retired = self._retire_finished()
-        if self._active:
-            if kind is None:
-                kind = "spec" if self.spec else "decode"
-            else:
-                kind = "mixed"
+                fl = self._prefill_tick(fenced=depth == 0)
+                if fl is not None:
+                    prefilled = True
+                    if depth == 0:
+                        self._prefill_collect(fl)
+        if depth == 0:
+            # a first token can finish a sequence (eos / max_new == 1):
+            # retire BEFORE the round so it neither wastes a slot nor a
+            # dispatch.  Ahead of the readback the same rows are left out
+            # by arithmetic, or ride one round as padding (an eos)
+            with _Phase("GenServer._retire", phases, "retire"):
+                self._retire_finished()
+        ahead = None
+        if self._decodable():
+            decoded = True
             with _Phase("GenServer._decode_round", phases, "decode"):
-                tokens += (self._spec_round() if self.spec
-                           else self._decode_round())
+                if self.spec:
+                    self._spec_round()
+                else:
+                    fl = self._decode_round(fenced=depth == 0)
+                    if fl is not None and depth == 0:
+                        self._decode_collect(fl)
+                    else:
+                        ahead = fl
+        # read what the device has finished or is finishing, in its order:
+        # everything but the round just put behind it
+        while self._unread and self._unread[0] is not ahead:
+            self._collect(self._unread[0])
         with _Phase("GenServer._retire", phases, "retire"):
-            retired += self._retire_finished()
+            self._retire_finished()
+        tokens, retired = self._tick_tokens, self._tick_retired
+        kind = None
+        if prefilled:
+            kind = "mixed" if decoded else "prefill"
+        elif decoded:
+            kind = "spec" if self.spec else "decode"
         # idle spins count explicitly: a hot-spinning scheduler must
         # read as a bubble on /genperf, not as silence in steps_total
         self.steps_total[kind or "idle"] = (
@@ -1078,6 +1376,7 @@ class GenServer:
             "tokens": tokens,
             "steps": self._tick_dev_steps,
             "inplace_steps": self._tick_inplace_steps,
+            "ahead_steps": self._tick_ahead_steps,
             "kv_positions": self._tick_kv_pos,
             "kv_blocks": self._tick_kv_blocks,
             "kv_ages": tuple(ages),
@@ -1134,11 +1433,149 @@ class GenServer:
             self._bubble_cause = "idle"
         return progress
 
+    def _depth(self) -> int:
+        """How many decode rounds the tick leaves on the device's queue
+        when it returns: 1 keeps round k+1 queued behind round k before
+        k's tokens are read; 0 is the synchronous order, every program
+        fenced and read where it is dispatched.  It follows what the
+        server observes in its own state and nothing else: a speculative
+        round's verify step decides the next draft and a prefill replica
+        hands a finished prompt off, so both need every token on the host
+        (always 0); and the wake-up guard aims at a fenced round's slack
+        (``_pace``), which only a depth-0 tick can read -- one round in
+        ``_FENCE_EVERY`` is run so."""
+        if self.spec or self.role == "prefill":
+            return 0
+        return int(self._since_fence < _FENCE_EVERY - 1)
+
+    def _pace(self) -> None:
+        """With a round on the device: sleep until it is about to end, so
+        that admission decides as late as the synchronous order did (a
+        request arriving during round k still gets into this tick's
+        prefill) and the device still finds round k+1 queued when k ends.
+        Woken at ``round start + its device seconds at this row count -
+        guard``.  The start is the later of the round's dispatch and the
+        completion seen before it.  The device seconds are the least of
+        the last few readings ``_await`` booked for such a round, each
+        taken between two completions the host was waiting for: a reading
+        can only come out too long, so the least is what this sleep
+        cannot move.  The guard is the one closed-loop term: ``_await``
+        moves it by how long the wait for round k lasts once round k+1 is
+        out, which should be a fenced round's slack (``_decode_round``)
+        -- shorter and round k+1 reached the queue too late, longer and
+        admission closed earlier than it had to.  Waking too late is the
+        synchronous order's gap; too early admits that much early."""
+        fl = self._unread[-1]
+        readings = self._round_s.get(fl.size)
+        if not readings:
+            return          # nothing observed yet at this row count
+        wake = (max(fl.t_dispatch, self._last_done) + min(readings)
+                - self._guard_s)
+        self._paced = fl
+        with _Phase("GenServer._decode_round/wait"), self._wake:
+            while not self._stopped:
+                left = wake - time.perf_counter()
+                if left <= 0:
+                    break
+                self._wake.wait(left)
+
+    def _decodable(self) -> List[_Sequence]:
+        """The rows the next decode round carries, by arithmetic alone: a
+        row whose tokens read so far and in flight reach ``max_new`` has
+        left; a row whose prompt ended in this tick's chunk has joined."""
+        return sorted(
+            (s for s in self._active
+             if not s.done and len(s.emitted) + s.inflight < s.max_new),
+            key=lambda s: s.sid)
+
+    def _await(self, fl: _Flight, name: str) -> None:
+        """Observe ``fl``'s completion and book its device seconds: the
+        observed completion minus the later of its dispatch and the
+        previous program's observed completion (utils/genperf.py
+        ``booked_device_s``).  A fenced program was waited for where it
+        was dispatched, under ``<name>/device``; the wait for one that
+        ran queued behind another is ``<name>/wait``, so that a trace
+        reader pairing ``/device`` with the module starting inside it
+        never reads the wrong round.  Here too the wake-up before a
+        round's end gets its observations (``_pace``): a reading of a
+        round's device seconds, and the wait the guard is steered by."""
+        import jax
+
+        from seldon_core_tpu.utils.genperf import booked_device_s
+
+        seen = fl.t_done is not None    # fenced: waited for at dispatch
+        if fl.t_done is None:
+            # a completion the host was not waiting for when it came is an
+            # upper bound, no reading: the program may have ended any time
+            # since its dispatch
+            seen = not fl.ready.is_ready()
+            t_wait = time.perf_counter()
+            with _Phase(name + "/wait"):
+                jax.block_until_ready(fl.ready)
+            fl.t_done = time.perf_counter()
+            if fl is self._paced and self._unread[-1] is not fl:
+                # the round this tick paced itself against, with the next
+                # one out: had that reached the queue in time, this wait
+                # lasts about a fenced round's slack.  The guard follows
+                # the difference, up faster than down (too late idles the
+                # device, too early only admits early), within half a round
+                err = self._slack_s - (fl.t_done - t_wait)
+                self._guard_s = max(0.0, min(
+                    0.5 * min(self._round_s[fl.size]),
+                    self._guard_s + (0.5 if err > 0 else 0.2) * err))
+        queued = self._last_done > fl.t_dispatch
+        booked = booked_device_s(fl.t_dispatch, fl.t_done, self._last_done)
+        readings = self._round_s.get(fl.size) if fl.kind == "decode" else None
+        if not seen and readings:
+            # found finished: it ran no longer than a fenced round reads
+            booked = min(booked, min(readings) + self._slack_s)
+        if fl.kind == "decode" and queued and seen and self._last_done_seen:
+            # it started when the program before it ended and the host was
+            # waiting at both ends: what was booked is the round's own
+            # device time, no launch and no late look in it
+            if readings is None:
+                readings = self._round_s[fl.size] = deque(
+                    maxlen=_ROUND_READINGS)
+            readings.append(booked)
+        self._last_done, self._last_done_seen = fl.t_done, seen
+        self._dev_s[fl.kind] = self._dev_s.get(fl.kind, 0.0) + booked
+        self._attr_note(fl.kind, *fl.attr)
+        if fl.chunk:
+            self._adapt_chunk(fl.chunk, fl.host_s + booked)
+        self._unread.remove(fl)
+
+    def _collect(self, fl: _Flight) -> None:
+        """Read one dispatched program back under its function's phase."""
+        if fl.kind == "decode":
+            with _Phase("GenServer._decode_round", self._phases, "decode"):
+                self._decode_collect(fl)
+        else:
+            with _Phase("GenServer._prefill_tick", self._phases, "prefill"):
+                self._prefill_collect(fl)
+
+    def _drain(self) -> None:
+        """Down to depth 0 from wherever the tick stands: read everything
+        dispatched, retire what finished.  After it the host's copy of
+        every row (``emitted``, ``pending``, ``key_data``) is current, so
+        a preemption may rebuild a prompt from it and a cancel may drop
+        the row."""
+        while self._unread:
+            self._collect(self._unread[0])
+        with _Phase("GenServer._retire", self._phases, "retire"):
+            self._retire_finished()
+
     def _drop_cancelled(self) -> None:
-        for coll in (self._waiting, self._prefilling, self._active):
-            for seq in [s for s in coll if s.request.cancelled]:
-                coll.remove(seq)
-                self._retire(seq, "cancelled")
+        gone = [s for coll in (self._waiting, self._prefilling, self._active)
+                for s in coll if s.request.cancelled]
+        if any(s.inflight for s in gone):
+            # a row of a program not read yet: read it first, so that the
+            # row leaves with its books closed (it may have finished there)
+            self._drain()
+        for seq in gone:
+            for coll in (self._waiting, self._prefilling, self._active):
+                if seq in coll:
+                    coll.remove(seq)
+                    self._retire(seq, "cancelled")
 
     def _blocks_needed(self, upto: int) -> int:
         return -(-upto // self.block_size)  # ceil
@@ -1154,6 +1591,14 @@ class GenServer:
         if need <= 0:
             return True
         while not alloc.can_alloc(need):
+            if self._unread:
+                # a dry pool: read everything in flight first -- a row
+                # that finished frees blocks without an eviction, and
+                # _preempt rebuilds a victim's prompt from its ``emitted``
+                self._drain()
+                if seq.state == _Sequence.DONE:
+                    return True     # it finished in what was just read
+                continue
             victim = self._pick_victim(exclude=seq)
             if victim is None:
                 return False
@@ -1198,6 +1643,7 @@ class GenServer:
             seq.pending = seq.emitted[-1]
         seq.prefill_pos = 0
         seq.n_valid = 0
+        seq.inflight = 0
         seq.state = _Sequence.WAITING
         self._waiting.appendleft(seq)
         self.preempted_total += 1
@@ -1240,6 +1686,12 @@ class GenServer:
                     ))
             self._allocator.free(seq.blocks)
         seq.blocks = []
+        if seq.slot >= 0:
+            # a row still riding a queued round as padding writes this
+            # slot once more; the carry's own chain of programs orders
+            # that before whatever the slot's next holder puts there
+            self._slot_free.append(seq.slot)
+            seq.slot = -1
         if self._draft_allocator is not None and seq.draft_blocks:
             self._draft_allocator.free(seq.draft_blocks)
         seq.draft_blocks = []
@@ -1266,7 +1718,7 @@ class GenServer:
         that one fails with a typed error instead of deadlocking the
         queue."""
         admitted = 0
-        while self._waiting and (
+        while self._waiting and self._slot_free and (
             len(self._active) + len(self._prefilling) < self.slots
         ):
             idx = self._next_waiting_index()
@@ -1279,6 +1731,11 @@ class GenServer:
             if (not self._allocator.can_alloc(need)
                     or (self.spec
                         and not self._draft_allocator.can_alloc(d_need))):
+                if self._unread:
+                    # a dry pool with a round in flight: what it finishes
+                    # may free the blocks, so read it before giving up
+                    self._drain()
+                    continue
                 if not self._active and not self._prefilling:
                     # nothing will ever retire to free blocks: the pool
                     # is smaller than one request's first chunk
@@ -1309,6 +1766,7 @@ class GenServer:
                     jnp.int32(seq.blocks[0]))
             seq.n_valid = self._prefix_len
             seq.state = _Sequence.PREFILL
+            seq.slot = self._slot_free.popleft()
             seq.prefill_pos = 0
             seq.t_start = time.time()
             self._seq_event(seq, "admit", blocks=len(seq.blocks),
@@ -1339,20 +1797,24 @@ class GenServer:
 
     # -- prefill ----------------------------------------------------------
 
-    def _prefill_tick(self) -> int:
-        """Consume one chunk of EVERY prefilling sequence's prompt as a
+    def _prefill_tick(self, fenced: bool) -> Optional[_Flight]:
+        """Dispatch one chunk of EVERY prefilling sequence's prompt as a
         single batched device program — the interleave grain that keeps a
         long prompt from stalling in-flight decode for more than ~one
         chunk's worth of time, without serializing one dispatch per
         prompt (16 co-arriving 512-token prompts at chunk 128 are 4
-        batched ticks, not 64 sequential ones)."""
-        import jax
-        import jax.numpy as jnp
+        batched ticks, not 64 sequential ones).
 
-        from seldon_core_tpu.models.generate import (
-            paged_forward_jit,
-            sample_token,
-        )
+        A row whose prompt ends in this chunk gets its first token on the
+        device (``_carry_ops`` ``first``: only a ``[B]`` int32 comes
+        back, never the ``[B, vocab]`` logits) and joins ``_active`` here,
+        by arithmetic, so the decode round of the SAME tick carries it.
+        ``fenced`` (depth 0) waits for the program under ``…/device``;
+        otherwise it is left on the device's queue.  Either way
+        ``_prefill_collect`` reads it back."""
+        import jax
+
+        from seldon_core_tpu.models.generate import paged_forward_jit
 
         t0 = time.perf_counter()
         with _Phase("GenServer._prefill_tick/build"):
@@ -1395,7 +1857,7 @@ class GenServer:
                     seq.state = _Sequence.WAITING
             batch = list(self._prefilling)
             if not batch:
-                return 0
+                return None
             B = _pow2(len(batch))
             toks = np.zeros((B, C), np.int32)
             start = np.zeros((B,), np.int32)
@@ -1416,29 +1878,49 @@ class GenServer:
             tables = np.zeros((B, nblk), np.int32)
             for i, seq in enumerate(batch):
                 tables[i] = self._table(seq, nblk)
+            # the rows whose prompt ends here: their first token is picked
+            # into the carry, or restored there when the host holds it (a
+            # row readmitted after a preemption is never re-sampled)
+            ending = [(seq, i, seq.pending is None)
+                      for i, seq in enumerate(batch)
+                      if seq.prefill_pos + widths[i] >= len(seq.prompt)]
+            if ending:
+                idx = np.full((B,), self.slots, np.int32)
+                held = np.zeros((B,), bool)
+                held_tok = np.zeros((B,), np.int32)
+                key_data = (np.zeros((B, self._key_width), np.uint32)
+                            if self.temperature > 0.0 else None)
+                for seq, i, fresh in ending:
+                    idx[i] = seq.slot
+                    if not fresh:
+                        held[i], held_tok[i] = True, seq.pending
+                    if key_data is not None:
+                        key_data[i] = seq.key_data
             OBSERVATORY.note_padding(len(batch), B)
             self._tick_rows += B
             self._tick_real_rows += len(batch)
             # cost attribution: real units are this chunk's REAL prompt
             # tokens per sequence; the dispatched capacity is B x C (pad
-            # rows and pad columns both burn the same device program)
-            self._attr_note("prefill", B * C, [
+            # rows and pad columns both burn the same device program).
+            # Noted by the tick that books the program's device seconds
+            attr = (B * C, [
                 (s.request.tenant, s.request.tier, int(widths[i]), 0, 0)
                 for i, s in enumerate(batch)
             ])
             self._tick_kv_blocks += sum(
                 self._blocks_needed(int(start[i]) + widths[i])
                 for i in range(len(batch)))
-        # dispatch -> block_until_ready returns: the fenced "device"
-        # seconds of /genperf and the annotation a trace reduction sets
-        # the module event against (how much of the fence is not device)
-        with _Phase("GenServer._prefill_tick/device", self._dev_s, "prefill",
-                    rows=B, real_rows=len(batch), nblk=nblk,
-                    tokens=sum(widths),
-                    kv_positions=int(start.sum()) + sum(widths)):
+        # fenced (depth 0): dispatch -> ready with nothing queued ahead, the
+        # annotation a trace reduction sets the module event against (how
+        # much of the fence is not device time).  Otherwise the dispatch is
+        # one more piece of building, behind the round still running
+        with (_Phase("GenServer._prefill_tick/device", rows=B,
+                     real_rows=len(batch), nblk=nblk, tokens=sum(widths),
+                     kv_positions=int(start.sum()) + sum(widths))
+              if fenced else _Phase("GenServer._prefill_tick/build")):
+            t_dispatch = time.perf_counter()
             logits, self._pool = paged_forward_jit(
-                self.params, jnp.asarray(toks), self._pool,
-                jnp.asarray(tables), jnp.asarray(start), jnp.asarray(width),
+                self.params, toks, self._pool, tables, start, width,
                 cfg=self.cfg, last_only=True,
             )
             if self.spec:
@@ -1452,54 +1934,68 @@ class GenServer:
                     d_tables[i] = self._table(seq, d_nblk, draft=True)
                     d_start[i] = seq.prefill_pos
                 _, self._draft_pool = paged_forward_jit(
-                    self.draft_params, jnp.asarray(toks), self._draft_pool,
-                    jnp.asarray(d_tables), jnp.asarray(d_start),
-                    jnp.asarray(width), cfg=self.draft_cfg, last_only=True,
+                    self.draft_params, toks, self._draft_pool, d_tables,
+                    d_start, width, cfg=self.draft_cfg, last_only=True,
                 )
-            # flight recorder: fence the dispatched step.  The greedy path
-            # host-syncs these logits a few lines down anyway — this only
-            # MOVES the sync so device wall is attributable to the phase
-            jax.block_until_ready(logits)
+            first = keys = None
+            if ending:
+                first, keys = self._first(logits, idx, held, held_tok,
+                                          key_data)
+                first.copy_to_host_async()
+            fl = _Flight("prefill", B, ending, logits, first, keys,
+                         t_dispatch, attr)
+            self._unread.append(fl)
+            if fenced:
+                jax.block_until_ready(fl.ready)
+                fl.t_done = time.perf_counter()
+        # what the chunk will have done, by arithmetic: the host does not
+        # wait for it to know
+        for i, seq in enumerate(batch):
+            seq.prefill_pos += widths[i]
+            self._seq_event(seq, "prefill_chunk", pos=seq.prefill_pos,
+                            width=int(widths[i]))
+            seq.n_valid = int(start[i]) + widths[i]
+        for seq, _, fresh in ending:
+            self._prefilling.remove(seq)
+            seq.inflight = int(fresh)
+            if self.role != "prefill":
+                seq.state = _Sequence.RUNNING
+                self._active.append(seq)
+        if max(widths) == C and not floored:
+            # only adapt on SATURATED ticks: short prompts never use a
+            # wider executable, so probing one would compile it for
+            # nothing (and the wall of an unsaturated tick says nothing
+            # about width-C compute anyway)
+            fl.chunk = C
+        fl.host_s = time.perf_counter() - t0 - (
+            fl.t_done - fl.t_dispatch if fenced else 0.0)
+        return fl
+
+    def _prefill_collect(self, fl: _Flight) -> None:
+        """Wait for a dispatched chunk, read its first tokens back (a
+        ``[B]`` int32, and only when a prompt ended in it), deliver them,
+        and hand finished prompts off on a prefill replica."""
+        self._await(fl, "GenServer._prefill_tick")
+        first = key_data = None
         with _Phase("GenServer._prefill_tick/readback"):
-            # greedy first tokens are picked on the host: fetched once,
-            # and only when a row's prompt ends in this chunk
-            logits_host = None
-            if self.temperature <= 0.0 and any(
-                    seq.pending is None
-                    and seq.prefill_pos + widths[i] >= len(seq.prompt)
-                    for i, seq in enumerate(batch)):
-                logits_host = np.asarray(logits)
-        emitted = 0
+            if fl.out is not None:
+                first = np.asarray(fl.out)
+                if fl.keys is not None:
+                    key_data = np.asarray(fl.keys)
         with _Phase("GenServer._prefill_tick/emit"):
-            for i, seq in enumerate(batch):
-                seq.prefill_pos += widths[i]
-                self._seq_event(seq, "prefill_chunk", pos=seq.prefill_pos,
-                                width=int(widths[i]))
-                seq.n_valid = int(start[i]) + widths[i]
-                if seq.prefill_pos < len(seq.prompt):
-                    continue
-                # prompt fully consumed: sample (or restore) the first token
-                self._prefilling.remove(seq)
+            for seq, i, fresh in fl.rows:
                 # the per-sequence prefill span (admission -> prompt fully
                 # cached): the "prefill dispatch" leg of a federated trace's
                 # critical path.  One record per sequence, trace-gated — the
                 # per-step hot-path budget is untouched when tracing is off
                 self._record_seq_span(seq, "prefill", "prefill")
-                if seq.pending is None:
-                    if self.temperature > 0.0:
-                        key = jax.random.wrap_key_data(
-                            jnp.asarray(seq.key_data))
-                        k0, key = jax.random.split(key)
-                        seq.key_data = np.asarray(jax.random.key_data(key))
-                        first = int(sample_token(
-                            logits[i:i + 1], k0, self.temperature,
-                            self.top_k, self.top_p,
-                        )[0])
-                    else:
-                        first = int(np.argmax(logits_host[i]))
-                    seq.pending = first
-                    self._emit_tokens(seq, [first])
-                    emitted += 1
+                if key_data is not None:
+                    seq.key_data = key_data[i]
+                if fresh:
+                    seq.inflight -= 1
+                    seq.pending = int(first[i])
+                    self._emit_tokens(seq, [seq.pending])
+                    self._tick_tokens += 1
                     # one completed prefill = one request for the ledger's
                     # per-request usage normalization; the first served token
                     self._attr_note("prefill", 0, [
@@ -1511,16 +2007,6 @@ class GenServer:
                         self._retire(seq, seq.retire_reason or "length")
                     else:
                         self._handoff_out(seq)
-                else:
-                    seq.state = _Sequence.RUNNING
-                    self._active.append(seq)
-        if max(widths) == C and not floored:
-            # only adapt on SATURATED ticks: short prompts never use a
-            # wider executable, so probing one would compile it for
-            # nothing (and the wall of an unsaturated tick says nothing
-            # about width-C compute anyway)
-            self._adapt_chunk(C, time.perf_counter() - t0)
-        return emitted
 
     def _adapt_chunk(self, C: int, wall_s: float) -> None:
         """Probe the effective prefill chunk upward while ticks stay
@@ -1545,20 +2031,30 @@ class GenServer:
 
     # -- decode -----------------------------------------------------------
 
-    def _decode_round(self) -> int:
-        """One ``span``-step decode round for every RUNNING sequence as a
-        single device program; the only host sync is the token readback
-        the streams need anyway."""
+    def _decode_round(self, fenced: bool) -> Optional[_Flight]:
+        """Dispatch one ``span``-step decode round for every decodable
+        sequence as a single device program.
+
+        The host uploads what it knows by arithmetic (block tables,
+        ``n_valid``, which rows are live); the values that depend on the
+        round before -- pending token, after-eos latch, sampling key --
+        are gathered from the carry on the device and scattered back
+        (``_carry_ops``), so this round can be queued while the one
+        before it is still running and unread.  Each row's share of the
+        round's tokens (``take``) is fixed here.  A row that sampled eos
+        in a round not read yet rides this one as padding: the latch is
+        set on the device, ``_emit_tokens`` drops what follows a stop,
+        and its blocks go back a round later.  ``fenced`` (depth 0) waits
+        for the program under ``…/device``; ``_decode_collect`` is the
+        token readback the streams need, now or a tick later."""
         import jax
-        import jax.numpy as jnp
 
         from seldon_core_tpu.models.generate import paged_decode_round_jit
 
         with _Phase("GenServer._decode_round/capacity"):
-            batch = sorted(self._active, key=lambda s: s.sid)
-            for seq in batch:
-                if seq not in self._active:
-                    continue  # preempted by an earlier row's eviction
+            for seq in self._decodable():
+                if seq not in self._active or seq.done:
+                    continue  # preempted, or finished in a drain, above
                 if not self._ensure_capacity(seq, seq.n_valid + self.span):
                     # pool exhausted even after eviction: this sequence is
                     # alone and cannot fit — surface a typed failure
@@ -1567,11 +2063,12 @@ class GenServer:
                         "KV pool too small for sequence length "
                         f"{seq.n_valid + self.span} (grow "
                         "SELDON_TPU_GEN_POOL_BLOCKS)"))
-                    return 0
+                    return None
+
         with _Phase("GenServer._decode_round/build"):
-            batch = sorted(self._active, key=lambda s: s.sid)
+            batch = self._decodable()
             if not batch:
-                return 0
+                return None
             B = _pow2(len(batch))
             nblk = _decode_table_width(
                 self._inplace, B,
@@ -1580,32 +2077,24 @@ class GenServer:
                 self._allocator.capacity)
             self._programs["decode"].add((B, nblk))
             tables = np.zeros((B, nblk), np.int32)
-            token = np.zeros((B,), np.int32)
             n_valid = np.zeros((B,), np.int32)
             active = np.zeros((B,), bool)
-            seen = np.zeros((B,), bool)
+            idx = np.full((B,), self.slots, np.int32)   # pads: scratch
+            rows = []
             for i, s in enumerate(batch):
                 tables[i] = self._table(s, nblk)
-                token[i] = s.pending
                 n_valid[i] = s.n_valid
                 active[i] = True
-                seen[i] = (self.eos_token >= 0
-                           and self.eos_token in s.emitted)
-            if self.temperature > 0.0:
-                kd = np.stack([
-                    s.key_data if s.key_data is not None
-                    else np.zeros_like(batch[0].key_data)
-                    for s in batch
-                ] + [np.zeros_like(batch[0].key_data)] * (B - len(batch)))
-                keys = jax.random.wrap_key_data(jnp.asarray(kd))
-            else:
-                keys = jnp.zeros((B,), jnp.uint32)
+                idx[i] = s.slot
+                rows.append((s, min(
+                    self.span, s.max_new - len(s.emitted) - s.inflight)))
             OBSERVATORY.note_padding(len(batch), B)
             self._tick_rows += B
             self._tick_real_rows += len(batch)
             # cost attribution: one real unit per LIVE sequence, capacity B
-            # (the pow-2 row padding is the decode round's whole pad tax)
-            self._attr_note("decode", B, [
+            # (the pow-2 row padding is the decode round's whole pad tax);
+            # noted by the tick that books the round's device seconds
+            attr = (B, [
                 (s.request.tenant, s.request.tier, 1, 0, 0) for s in batch])
             self._tick_kv_blocks += sum(
                 self._blocks_needed(s.n_valid + self.span) for s in batch)
@@ -1617,47 +2106,83 @@ class GenServer:
             self._tick_dev_steps += self.span
             if self._inplace:
                 self._tick_inplace_steps += self.span
-        # dispatch -> block_until_ready returns: /genperf's fenced decode
-        # seconds, and the annotation the trace sets paged_decode_round's
-        # module event against (decode_fence_slack_ms)
-        with _Phase("GenServer._decode_round/device", self._dev_s, "decode",
-                    rows=B, real_rows=len(batch), nblk=nblk,
-                    kv_positions=kv_positions,
-                    inplace=int(bool(self._inplace))):
-            toks, self._pool, _tok, _nv, _seen, keys_out = (
+            if self._unread:
+                # queued behind a program whose results are still unread:
+                # the device goes from that one to this without the host
+                self._tick_ahead_steps += self.span
+        # fenced (depth 0): dispatch -> ready with nothing queued ahead, the
+        # annotation the trace sets paged_decode_round's module event against
+        # (decode_fence_slack_ms) and the guard's second half.  Otherwise
+        # the dispatch is one more piece of building
+        with (_Phase("GenServer._decode_round/device", rows=B,
+                     real_rows=len(batch), nblk=nblk,
+                     kv_positions=kv_positions,
+                     inplace=int(bool(self._inplace)))
+              if fenced else _Phase("GenServer._decode_round/build")):
+            t_dispatch = time.perf_counter()
+            take, put, _ = _carry_ops()
+            token, seen, keys = take(self._carry, idx)
+            toks, self._pool, token, _nv, seen, keys = (
                 paged_decode_round_jit(
-                    self.params, self._pool, jnp.asarray(tables),
-                    jnp.asarray(token), jnp.asarray(n_valid),
-                    jnp.asarray(active), jnp.asarray(seen), keys,
+                    self.params, self._pool, tables, token, n_valid,
+                    active, seen,
+                    self._zero_keys[B] if keys is None else keys,
                     self.cfg, span=self.span, temperature=self.temperature,
                     top_k=self.top_k, top_p=self.top_p,
                     eos_token=self.eos_token, inplace=self._inplace,
                 )
             )
-            # fence = the sync np.asarray was about to pay anyway, moved
-            # here so decode device wall lands in its own phase
-            jax.block_until_ready(toks)
+            self._carry, key_data = put(
+                self._carry, idx, token, seen,
+                keys if self.temperature > 0.0 else None)
+            toks.copy_to_host_async()
+            fl = _Flight("decode", B, rows, toks, toks, key_data, t_dispatch,
+                         attr)
+            self._unread.append(fl)
+            if fenced:
+                jax.block_until_ready(fl.ready)
+                fl.t_done = time.perf_counter()
+        self._since_fence += 1
+        if fenced:
+            self._since_fence = 0
+            readings = self._round_s.get(B)
+            if readings:
+                # what the wake-up guard aims at: dispatch -> ready less the
+                # round's own device seconds.  A moving average; one stalled
+                # round moves it no further than a reading of twice itself
+                slack = max(fl.t_done - t_dispatch - min(readings), 0.0)
+                old = self._slack_s
+                self._slack_s = (slack if old == 0.0
+                                 else 0.5 * (old + min(slack, 2.0 * old)))
+        for s, share in rows:
+            s.inflight += share
+            s.n_valid += self.span
+        return fl
+
+    def _decode_collect(self, fl: _Flight) -> None:
+        """Wait for a dispatched round and read its tokens back -- the one
+        host sync a round needs -- then emit each row's share."""
+        self._await(fl, "GenServer._decode_round")
+        key_data = None
         with _Phase("GenServer._decode_round/readback"):
-            toks = np.asarray(toks)  # the per-round host sync
-            if self.temperature > 0.0:
-                kd_out = np.asarray(jax.random.key_data(keys_out))
-        emitted = 0
+            toks = np.asarray(fl.out)
+            if fl.keys is not None:
+                key_data = np.asarray(fl.keys)
         with _Phase("GenServer._decode_round/emit"):
-            for i, s in enumerate(batch):
-                if self.temperature > 0.0:
-                    s.key_data = kd_out[i]
-                remaining = s.max_new - len(s.emitted)
-                take = min(self.span, remaining)
-                s.n_valid += self.span
+            for i, (s, take) in enumerate(fl.rows):
+                s.inflight -= take
                 s.pending = int(toks[i, -1])
+                if key_data is not None:
+                    s.key_data = key_data[i]
+                if s.done:
+                    continue    # stopped a round ago: this one was padding
                 self._emit_tokens(s, [int(t) for t in toks[i, :take]])
                 self._seq_event(s, "decode_round", n_valid=s.n_valid,
                                 take=take)
-                emitted += take
+                self._tick_tokens += take
                 if take > 0:
                     self._attr_note("decode", 0, [
                         (s.request.tenant, s.request.tier, 0, 0, take)])
-        return emitted
 
     def _spec_round(self) -> int:
         """One speculative draft/verify round for every RUNNING sequence
@@ -1723,8 +2248,9 @@ class GenServer:
             )
         )
         jax.block_until_ready(new_toks)
+        self._last_done = time.perf_counter()
         self._dev_s["decode"] = (
-            self._dev_s.get("decode", 0.0) + time.perf_counter() - td)
+            self._dev_s.get("decode", 0.0) + self._last_done - td)
         new_toks = np.asarray(new_toks)
         gained = np.asarray(gained)
         corrected = np.asarray(corrected)
@@ -1747,6 +2273,7 @@ class GenServer:
             accept_rounds += 1
         if accept_rounds:
             RECORDER.observe_accept_ratio(accept_sum / accept_rounds)
+        self._tick_tokens += emitted
         return emitted
 
     # -- disaggregated handoff: prefill side ------------------------------
@@ -2006,13 +2533,18 @@ class GenServer:
         from seldon_core_tpu.runtime import kvstream
 
         n = 0
-        while self._remote_arrivals:
+        joined = []
+        # a row needs a slot of the carry: an import beyond them waits for
+        # a retirement, as a local request waits for a free slot
+        while self._remote_arrivals and self._slot_free:
             imp = self._remote_arrivals.popleft()
             self._pool = kvstream.scatter_staged(
                 self._pool, imp.blocks, imp.staged)
             self._allocator.commit_reserved(imp.blocks)
             seq = imp.seq
             seq.blocks = list(imp.blocks)
+            seq.slot = self._slot_free.popleft()
+            joined.append(seq)
             seq.state = _Sequence.RUNNING
             seq.t_start = time.time()
             self._seq_event(seq, "admit", blocks=len(seq.blocks),
@@ -2025,6 +2557,23 @@ class GenServer:
             RECORDER.record_gen_admitted()
             RECORDER.record_kv_handoff("imported")
             n += 1
+        if joined:
+            # what the prefill side carried over -- pending token, after-eos
+            # latch, sampling key -- goes into the carry, where a local
+            # row's first token would have put it
+            rows = _pow2(len(joined))
+            idx = np.full((rows,), self.slots, np.int32)
+            tok = np.zeros((rows,), np.int32)
+            seen = np.zeros((rows,), bool)
+            key_data = (np.zeros((rows, self._key_width), np.uint32)
+                        if self.temperature > 0.0 else None)
+            for i, seq in enumerate(joined):
+                idx[i], tok[i] = seq.slot, seq.pending
+                seen[i] = self.eos_token >= 0 and self.eos_token in seq.emitted
+                if key_data is not None and seq.key_data is not None:
+                    key_data[i] = seq.key_data
+            self._carry, _ = _carry_ops()[1](
+                self._carry, idx, tok, seen, key_data)
         return n
 
     def _reap_stale_imports(self) -> None:
@@ -2110,13 +2659,11 @@ class GenServer:
             if req.chunk is not None:
                 req.queue.put(None)
 
-    def _retire_finished(self) -> int:
-        retired = 0
+    def _retire_finished(self) -> None:
         for seq in [s for s in self._active if s.done]:
             self._active.remove(seq)
             self._retire(seq, seq.retire_reason or "length")
-            retired += 1
-        return retired
+            self._tick_retired += 1
 
     def _record_seq_span(self, seq: _Sequence, name: str,
                          method: str) -> None:
@@ -2200,7 +2747,8 @@ class GenServer:
 
         if not TRACER.enabled:
             return
-        for s in list(self._active) + list(self._prefilling):
+        for s in (list(self._active) + list(self._prefilling)
+                  + [row[0] for fl in self._unread for row in fl.rows]):
             ctx = getattr(s.request, "trace_ctx", None)
             if ctx is not None and ctx.sampled:
                 TRACER.record_span(

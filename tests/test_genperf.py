@@ -231,6 +231,62 @@ def test_served_decode_counts_the_steps_that_attended_in_place():
     assert served["inplace_steps"] == served["device_steps"] == 24
 
 
+@pytest.mark.parametrize("depth", [0, 1])
+def test_ahead_steps_fold_into_served_decode(depth):
+    """``ahead_steps`` rides the tick record beside ``inplace_steps``: 0 at
+    depth 0, ``span`` a round where the round was dispatched behind a
+    program still unread; it is in the document from boot."""
+    assert GENPERF.document()["served_decode"]["ahead_steps"] == 0
+    span, rounds = 8, 5
+    for _ in range(rounds):
+        GENPERF.observe_tick("decode", {
+            "wall_s": 0.07, "device_s": 0.065,
+            "device_phases": {"decode": 0.065}, "steps": span,
+            "inplace_steps": span, "ahead_steps": span * depth,
+            "tokens": 5 * span})
+    # prefill and idle ticks dispatch no round: nothing of theirs counts
+    GENPERF.observe_tick("prefill", {"wall_s": 0.02, "device_s": 0.014,
+                                     "ahead_steps": span})
+    served = GENPERF.document()["served_decode"]
+    assert served["device_steps"] == span * rounds
+    assert served["inplace_steps"] == span * rounds
+    assert served["ahead_steps"] == span * rounds * depth
+
+
+def test_device_seconds_of_queued_programs_are_booked_once():
+    """Round k dispatched at 0.000 and seen done at 0.065; the chunk and
+    round k+1 dispatched at 0.060 and 0.061, while k ran, and seen done at
+    0.079 and 0.144.  Each is booked from the later of its own dispatch and
+    the completion seen before it, so the three sum to the stretch the
+    device was seen busy -- not to 0.065 + 0.019 + 0.083 -- and a fenced
+    program reads its whole dispatch -> ready interval, as before."""
+    from seldon_core_tpu.utils.genperf import booked_device_s
+
+    k = booked_device_s(0.000, 0.065, prev_done=0.0)
+    chunk = booked_device_s(0.060, 0.079, prev_done=0.065)
+    k1 = booked_device_s(0.061, 0.144, prev_done=0.079)
+    assert (k, chunk, k1) == pytest.approx((0.065, 0.014, 0.065))
+    assert k + chunk + k1 == pytest.approx(0.144)
+    # fenced: dispatched after everything before it was seen to end
+    assert booked_device_s(0.200, 0.268, prev_done=0.144) == \
+        pytest.approx(0.068)
+    # the records of the two ticks that observed them fold to the same sum
+    GENPERF.observe_tick("decode", {
+        "wall_s": 0.066, "device_s": k, "device_phases": {"decode": k},
+        "steps": 8, "ahead_steps": 0})
+    GENPERF.observe_tick("mixed", {
+        "wall_s": 0.079, "device_s": chunk + k1,
+        "device_phases": {"prefill": chunk, "decode": k1},
+        "phases": {"prefill": 0.016, "decode": 0.062},
+        "steps": 8, "ahead_steps": 8})
+    doc = GENPERF.document()
+    assert doc["served_decode"]["decode_device_s"] == pytest.approx(
+        0.130, abs=1e-4)
+    assert doc["accounting"]["device_s"] == pytest.approx(0.144, abs=1e-4)
+    assert doc["phases"]["device_s"]["mixed/prefill"] == pytest.approx(
+        0.014, abs=1e-4)
+
+
 def test_tick_error_counter_and_family():
     assert "seldon_tpu_gen_tick_errors_total" in TPU_METRIC_FAMILIES
     before = RECORDER.gen_tick_errors
@@ -561,14 +617,30 @@ PHASE_NAMES = {
 }
 
 
+#: the waits for a program that ran queued behind another: never a
+#: ``/device``, which a trace reader pairs with the module starting inside
+WAIT_NAMES = {"GenServer._decode_round/wait", "GenServer._prefill_tick/wait"}
+
+
+@pytest.mark.parametrize("depth", [0, 1])
 def test_scheduler_opens_every_phase_annotation_properly_nested(
-        params, monkeypatch):
+        params, monkeypatch, depth):
     """With jax.profiler.TraceAnnotation replaced by a recorder (no
     profiler session), one tiny scheduler run opens every phase name the
     trace reductions look for, as a well-formed stack on the scheduler
     thread, each sub-phase inside its function inside ``_tick``, and the
-    ``/device`` phases carry what rode the dispatch."""
+    ``/device`` phases carry what rode the dispatch.  Held at depth 0 the
+    names are the synchronous order's; a round ahead, the waits for
+    queued programs are ``/wait`` and ``/device`` stays the fenced
+    rounds' (the first, then one in ``_FENCE_EVERY``)."""
     import threading
+
+    from seldon_core_tpu.runtime import genserver as gs
+
+    if depth == 0:
+        monkeypatch.setattr(gs.GenServer, "_depth", lambda self: 0)
+    else:
+        monkeypatch.setattr(gs, "_FENCE_EVERY", 10 ** 9)
 
     log = []          # (thread id, "B"/"E", name, args)
 
@@ -607,7 +679,11 @@ def test_scheduler_opens_every_phase_annotation_properly_nested(
             assert stack and stack.pop() == name, f"{name} closed out of turn"
     # stop() can catch the scheduler parked: at most the wait is left open
     assert stack in ([], ["GenServer._run/wait"])
-    assert set(parents) == PHASE_NAMES
+    if depth == 0:
+        assert set(parents) == PHASE_NAMES
+    else:
+        assert PHASE_NAMES <= set(parents) <= PHASE_NAMES | WAIT_NAMES
+        assert "GenServer._decode_round/wait" in parents
     assert parents["GenServer._tick"] == {None}
     assert parents["GenServer._run/wait"] == {None}
     for name, ups in parents.items():
@@ -620,8 +696,9 @@ def test_scheduler_opens_every_phase_annotation_properly_nested(
             assert {"rows", "real_rows", "nblk", "kv_positions"} <= set(args)
             assert 1 <= args["real_rows"] <= args["rows"]
             assert args["nblk"] >= 1 and args["kv_positions"] > 0
-    assert any(a["real_rows"] > 1
-               for a in device_args["GenServer._decode_round/device"])
+    if depth == 0:
+        assert any(a["real_rows"] > 1
+                   for a in device_args["GenServer._decode_round/device"])
 
 
 def test_new_metric_families_registered():

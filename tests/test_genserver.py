@@ -758,3 +758,489 @@ def test_paged_programs_name_every_stage(program, scopes, params):
     assert set(scopes) <= named, set(scopes) - named
     if program == "paged_forward":      # prefill picks no token
         assert "sample" not in named
+
+
+# -- one decode round ahead of its own readback ------------------------------
+
+
+def _ref(params, prompt, max_new, **kw):
+    return np.asarray(generate(params, jnp.asarray(prompt, jnp.int32), CFG,
+                               max_new_tokens=max_new, **kw))
+
+
+def _eos_case(params):
+    """A prompt whose greedy output brings a NEW token in the middle of its
+    second decode round (span 3: index 0 is the first token, 1-3 round one,
+    4-6 round two), to serve as eos: the round after it is already queued
+    when the host finds the stop."""
+    for seed in range(200):
+        prompt = np.random.default_rng(seed).integers(0, 48, size=(1, 7))
+        base = _ref(params, prompt, 10)[0]
+        for j in (4, 5):
+            if base[j] not in base[:j]:
+                return prompt, int(base[j])
+    raise AssertionError("no prompt with a fresh token mid-round")
+
+
+def _drain_stream(it, limit=None):
+    chunks = []
+    for c in it:
+        chunks.append(np.asarray(c))
+        if limit is not None and len(chunks) >= limit:
+            break
+    return chunks
+
+
+def _ahead_scenario(name, params):
+    """One scenario against one server; returns what a client saw: the
+    chunks of every stream in order and every unary answer."""
+    rng = np.random.default_rng(31)
+    seen = {"streams": [], "unary": [], "snap": None}
+    if name == "ragged":
+        # max_new 10 is no multiple of span 3: the last round is cut
+        prompts = rng.integers(0, 48, size=(2, 5))
+        srv = _server(params)
+        try:
+            seen["streams"].append(_drain_stream(
+                srv.stream(prompts.astype(float), chunk=4)))
+            seen["snap"] = _settle(srv)
+        finally:
+            srv.stop()
+        np.testing.assert_array_equal(
+            np.concatenate(seen["streams"][0], axis=1),
+            _ref(params, prompts, 10))
+    elif name == "join_leave":
+        # prompts of 1-4 chunks and answers of 2-5 rounds: rows finish
+        # prefilling and reach max_new in the same ticks others decode in
+        lens, news = (3, 9, 14, 5), (4, 11, 7, 13)
+        prompts = [rng.integers(0, 48, size=(1, n)) for n in lens]
+        srv = _server(params, max_new_tokens=16)
+        try:
+            reqs = [srv.submit(p.astype(float), max_new=m)
+                    for p, m in zip(prompts[:2], news[:2])]
+            its = [srv.stream(p.astype(float), chunk=2, max_new=m)
+                   for p, m in zip(prompts[2:], news[2:])]
+            seen["streams"] = [_drain_stream(it) for it in its]
+            seen["unary"] = [r.future.result(timeout=180) for r in reqs]
+            seen["snap"] = _settle(srv)
+        finally:
+            srv.stop()
+        got = seen["unary"] + [np.concatenate(c, axis=1)
+                               for c in seen["streams"]]
+        for g, p, m in zip(got, prompts, news):
+            np.testing.assert_array_equal(g, _ref(params, p, m))
+    elif name == "eos":
+        prompt, eos = _eos_case(params)
+        other = rng.integers(0, 48, size=(1, 6))
+        srv = _server(params, eos_token=eos)
+        try:
+            it = srv.stream(prompt.astype(float), chunk=1)
+            req = srv.submit(other.astype(float))
+            seen["streams"].append(_drain_stream(it))
+            seen["unary"].append(req.future.result(timeout=180))
+            seen["snap"] = _settle(srv)
+        finally:
+            srv.stop()
+        want = _ref(params, prompt, 10, eos_token=eos)
+        got = np.concatenate(seen["streams"][0], axis=1)
+        # nothing of the round that rode as padding reached the stream
+        np.testing.assert_array_equal(got, want)
+        stop = int(np.argmax(want[0] == eos))
+        assert (got[0, stop:] == eos).all() and stop in (4, 5)
+        np.testing.assert_array_equal(
+            seen["unary"][0], _ref(params, other, 10, eos_token=eos))
+        n_eos = 1 + int(eos in seen["unary"][0])
+        assert seen["snap"]["retired_total"].get("eos", 0) == n_eos
+        assert seen["snap"]["retired_total"].get("length", 0) == 2 - n_eos
+    elif name == "sampled":
+        prompts = rng.integers(0, 48, size=(3, 6))
+        srv = _server(params, temperature=1.0, top_k=8, seed=11,
+                      max_new_tokens=11)
+        try:
+            reqs = [srv.submit(prompts[i:i + 1].astype(float))
+                    for i in range(2)]
+            seen["streams"].append(_drain_stream(
+                srv.stream(prompts[2:].astype(float), chunk=3)))
+            seen["unary"] = [r.future.result(timeout=180) for r in reqs]
+            seen["snap"] = _settle(srv)
+        finally:
+            srv.stop()
+        for t in seen["unary"]:
+            assert t.shape == (1, 11) and (t >= 0).all() and (t < 48).all()
+    elif name == "preempt":
+        prompts = rng.integers(0, 48, size=(2, 4))
+        srv = _server(params, block_size=2, num_blocks=9, span=4,
+                      prefill_chunk=4, max_new_tokens=8)
+        try:
+            reqs = [srv.submit(prompts[i:i + 1].astype(float))
+                    for i in range(2)]
+            seen["unary"] = [r.future.result(timeout=180) for r in reqs]
+            seen["snap"] = _settle(srv)
+        finally:
+            srv.stop()
+        np.testing.assert_array_equal(
+            np.concatenate(seen["unary"]), _ref(params, prompts, 8))
+        assert seen["snap"]["preempted_total"] >= 1
+    elif name == "cancel":
+        # long enough that the client is never slower than the answer
+        prompts = rng.integers(0, 48, size=(2, 5))
+        srv = _server(params, max_new_tokens=400, span=2, num_blocks=256)
+        try:
+            it = srv.stream(prompts[:1].astype(float), chunk=2)
+            req = srv.submit(prompts[1:].astype(float), max_new=30)
+            seen["streams"].append(_drain_stream(it, limit=3))
+            it.close()      # its next round is on the device already
+            seen["unary"].append(req.future.result(timeout=180))
+            deadline = time.monotonic() + 10
+            while time.monotonic() < deadline and not srv.snapshot()[
+                    "retired_total"].get("cancelled", 0):
+                time.sleep(0.01)
+            seen["snap"] = _settle(srv)
+        finally:
+            srv.stop()
+        assert seen["snap"]["retired_total"].get("cancelled", 0) == 1
+        np.testing.assert_array_equal(
+            np.concatenate(seen["streams"][0], axis=1),
+            _ref(params, prompts[:1], 400)[:, :6])
+        np.testing.assert_array_equal(
+            seen["unary"][0], _ref(params, prompts[1:], 30))
+    elif name == "stop":
+        prompt = rng.integers(0, 48, size=(1, 5))
+        srv = _server(params, max_new_tokens=400, span=2, num_blocks=256)
+        it = srv.stream(prompt.astype(float), chunk=2)
+        chunks = _drain_stream(it, limit=2)
+        srv.stop()          # with a round on the device
+        with pytest.raises(RuntimeError, match="stopped"):
+            chunks += _drain_stream(it)
+        got = np.concatenate(chunks, axis=1)
+        assert got.shape[1] < 400
+        np.testing.assert_array_equal(
+            got, _ref(params, prompt, 400)[:, :got.shape[1]])
+        seen["streams"].append(chunks[:2])
+        seen["snap"] = srv.snapshot()
+    if name != "stop":
+        assert seen["snap"]["kv_blocks"]["used"] == 0
+        assert len(srv._slot_free) == srv.slots and not srv._unread
+    return seen
+
+
+@pytest.mark.parametrize("name", ["ragged", "join_leave", "eos", "sampled",
+                                  "preempt", "cancel", "stop"])
+def test_a_round_ahead_changes_no_token_and_no_chunk(name, params,
+                                                     monkeypatch):
+    """The tick that keeps a decode round ahead of its own readback and the
+    same server held at depth 0 (its own ``_depth`` decision patched, no
+    variable) hand a client identical tokens in identical chunks."""
+    from seldon_core_tpu.runtime import genserver as gs
+    from seldon_core_tpu.utils.genperf import GENPERF
+    from seldon_core_tpu.utils.hotrecord import SPINE
+
+    def served():
+        SPINE.drain()
+        GENPERF.reset()
+        seen = _ahead_scenario(name, params)
+        SPINE.drain()
+        return seen, GENPERF.document()["served_decode"]
+
+    # only the first round fenced: everything after it runs a round ahead
+    monkeypatch.setattr(gs, "_FENCE_EVERY", 10 ** 9)
+    ahead, counted = served()
+    assert 0 < counted["ahead_steps"] <= counted["device_steps"]
+    monkeypatch.setattr(gs.GenServer, "_depth", lambda self: 0)
+    fenced, counted = served()
+    assert counted["ahead_steps"] == 0 < counted["device_steps"]
+    assert len(ahead["streams"]) == len(fenced["streams"])
+    for a, b in zip(ahead["streams"], fenced["streams"]):
+        assert [c.shape for c in a] == [c.shape for c in b]
+        for ca, cb in zip(a, b):
+            np.testing.assert_array_equal(ca, cb)
+    for a, b in zip(ahead["unary"], fenced["unary"]):
+        np.testing.assert_array_equal(a, b)
+
+
+def test_device_error_at_the_delayed_readback_fails_that_programs_requests(
+        params, monkeypatch):
+    """A device error now surfaces a tick late, where the round is read
+    back.  It fails the requests that rode the failed program -- one of
+    them with every token it will ever get in flight, so riding no later
+    round -- and no request that finished before or came after; the tick
+    error is counted and stamped into the failing request's trace, and the
+    server serves on."""
+    from seldon_core_tpu.runtime import genserver as gs
+    from seldon_core_tpu.utils.tracing import (
+        TRACER, TraceContext, new_span_id, new_trace_id, trace_scope)
+
+    monkeypatch.setattr(gs, "_FENCE_EVERY", 10 ** 9)
+    monkeypatch.setattr(TRACER, "enabled", True)
+    TRACER.clear()
+    ctx = TraceContext(trace_id=new_trace_id(), span_id=new_span_id(),
+                       sampled=True, puid="p-late-error")
+    prompts = np.random.default_rng(17).integers(0, 48, size=(4, 5))
+    srv = _server(params, max_new_tokens=40)
+    arm = {"on": False, "rows": None}
+    wait = gs.GenServer._await
+
+    def late(self, fl, name):
+        if arm["on"] and fl.kind == "decode" and fl.t_done is None:
+            arm["on"] = False
+            arm["rows"] = [s.request for s, _ in fl.rows]
+            raise RuntimeError("injected device error at the readback")
+        return wait(self, fl, name)
+
+    monkeypatch.setattr(gs.GenServer, "_await", late)
+    try:
+        done = srv.submit(prompts[:1].astype(float), max_new=6)
+        np.testing.assert_array_equal(
+            done.future.result(timeout=180), _ref(params, prompts[:1], 6))
+        _settle(srv)
+        arm["on"] = True    # the next round waited for a tick late
+        with trace_scope(ctx):
+            long_ = srv.submit(prompts[1:2].astype(float))
+        # four tokens: the first and one round, its last -- so the round
+        # in flight when the error comes is the one it leaves by
+        short = srv.submit(prompts[2:3].astype(float), max_new=4)
+        for req in (long_, short):
+            with pytest.raises(RuntimeError, match="injected device error"):
+                req.future.result(timeout=180)
+        assert {id(r) for r in arm["rows"]} <= {id(long_), id(short)}
+        assert id(long_) in {id(r) for r in arm["rows"]}
+        snap = _settle(srv)
+        assert snap["tick_errors_total"] == 1
+        assert snap["kv_blocks"]["used"] == 0
+        assert len(srv._slot_free) == srv.slots and not srv._unread
+        assert [s.name for s in TRACER.by_trace(ctx.trace_id)
+                if s.name == "gen_tick_error"] == ["gen_tick_error"]
+        after = srv.submit(prompts[3:].astype(float), max_new=9)
+        np.testing.assert_array_equal(
+            after.future.result(timeout=180), _ref(params, prompts[3:], 9))
+        assert done.future.result().shape == (1, 6)
+    finally:
+        srv.stop()
+        TRACER.clear()
+
+
+def test_an_eos_on_the_last_row_leaves_no_round_unread_over_an_idle_spell(
+        params):
+    """The last active row samples eos in round k with round k+1 already
+    queued for it as padding.  The scheduler reads that round before it
+    parks, so the idle spell that follows is neither booked as device time
+    nor learned as the length of a round, and the next request's rounds
+    are paced like any other."""
+    from seldon_core_tpu.utils.genperf import GENPERF
+    from seldon_core_tpu.utils.hotrecord import SPINE
+
+    def device_s():
+        SPINE.drain()
+        return GENPERF.document()["served_decode"]["decode_device_s"]
+
+    prompt, eos = _eos_case(params)
+    SPINE.drain()
+    GENPERF.reset()
+    srv = _server(params, eos_token=eos)
+    try:
+        want = _ref(params, prompt, 10, eos_token=eos)
+        np.testing.assert_array_equal(
+            srv.submit(prompt.astype(float)).future.result(timeout=180), want)
+        snap = _settle(srv)
+        assert snap["retired_total"] == {"eos": 1}
+        deadline = time.monotonic() + 5
+        while srv._unread and time.monotonic() < deadline:
+            time.sleep(0.005)
+        assert not srv._unread          # read before the loop parked
+        before = device_s()
+        time.sleep(1.0)                 # the idle spell
+        t0 = time.perf_counter()
+        np.testing.assert_array_equal(
+            srv.submit(prompt.astype(float)).future.result(timeout=180), want)
+        served = time.perf_counter() - t0
+        snap = _settle(srv)
+        # nothing of the idle second in the books or in the estimate
+        assert device_s() - before <= served
+        assert all(ms < 1e3 * served
+                   for ms in snap["pace"]["round_ms"].values())
+        assert snap["kv_blocks"]["used"] == 0 and not srv._unread
+    finally:
+        srv.stop()
+
+
+class _Program:
+    """Stands for a dispatched program's output: ready ``after`` seconds."""
+
+    def __init__(self, after):
+        self.at = time.perf_counter() + after
+
+    def is_ready(self):
+        return time.perf_counter() >= self.at
+
+    def block_until_ready(self):
+        time.sleep(max(self.at - time.perf_counter(), 0.0))
+        return self
+
+
+def test_a_rounds_device_seconds_come_only_from_completions_waited_for(
+        params):
+    """What the wake-up before a round's end rests on (``_await``): a
+    round's device seconds are read only between two completions the host
+    was waiting for, their estimate is the least of the last few, and a
+    round found finished -- after a host stall, an idle spell -- adds no
+    reading and is booked no longer than a fenced round lasts."""
+    from seldon_core_tpu.runtime import genserver as gs
+
+    srv = _server(params)       # never started: the test is its scheduler
+    srv._dev_s = {}
+
+    def flight(after, queued=True):
+        fl = gs._Flight("decode", 4, [], _Program(after), None, None,
+                        time.perf_counter(), (4, []))
+        if not queued:
+            srv._last_done = 0.0
+        srv._unread.append(fl)
+        return fl
+
+    def book(fl):
+        srv._dev_s = {}
+        srv._await(fl, "GenServer._decode_round")
+        return srv._dev_s["decode"]
+
+    assert 0.05 <= book(flight(0.05, queued=False)) < 0.5
+    assert 4 not in srv._round_s            # it was not queued: a launch in it
+    srv._last_done = time.perf_counter()    # as if it ended just now
+    for after in (0.12, 0.05, 0.15):
+        fl = flight(after)
+        srv._last_done = fl.t_dispatch + 1e-6   # ended as this one went out
+        book(fl)
+    assert len(srv._round_s[4]) == 3
+    assert 0.05 <= min(srv._round_s[4]) < 0.1
+    # found finished after half a second away: no reading, and the books
+    # hold a round's seconds and the fence's slack, not the absence
+    srv._slack_s = 0.003
+    fl = flight(0.0)
+    srv._last_done = fl.t_dispatch + 1e-6
+    time.sleep(0.5)
+    assert book(fl) <= min(srv._round_s[4]) + 0.003
+    assert len(srv._round_s[4]) == 3
+    # and neither does the round after it: its start was not seen
+    fl = flight(0.05)
+    srv._last_done = fl.t_dispatch + 1e-6
+    book(fl)
+    assert len(srv._round_s[4]) == 3
+    fl = flight(0.05)
+    srv._last_done = fl.t_dispatch + 1e-6
+    book(fl)
+    assert len(srv._round_s[4]) == 4 and not srv._unread
+    assert srv.snapshot()["pace"]["round_ms"]["4"] == round(
+        min(srv._round_s[4]) * 1e3, 3)
+
+
+def test_a_cancel_that_lands_after_the_ticks_look_still_closes_the_books(
+        params):
+    """``_drop_cancelled`` itself reads what is in flight before it retires
+    a row of it, whenever the cancel landed: the row leaves with nothing in
+    flight, its slot and blocks go back once, and the row beside it in the
+    same round gets every token."""
+    prompts = np.random.default_rng(5).integers(0, 48, size=(2, 5))
+    srv = _server(params, max_new_tokens=40)
+    srv._ensure_thread = lambda: None       # the test is its scheduler
+    gone = srv.submit(prompts[:1].astype(float))
+    kept = srv.submit(prompts[1:].astype(float), max_new=12)
+    for _ in range(50):
+        srv._tick()
+        if srv._unread and all(s.inflight for s in srv._active):
+            break
+    assert len(srv._active) == 2 and srv._unread
+    gone.cancelled = True                   # between the look and the drop
+    srv._drop_cancelled()
+    assert not srv._unread and len(srv._active) == 1
+    assert srv.retired_total == {"cancelled": 1}
+    assert all(s.inflight == 0 for s in srv._active)
+    for _ in range(50):
+        if kept.future.done():
+            break
+        srv._tick()
+    np.testing.assert_array_equal(
+        kept.future.result(timeout=0), _ref(params, prompts[1:], 12))
+    srv._tick()
+    assert srv.snapshot()["kv_blocks"]["used"] == 0
+    assert len(srv._slot_free) == srv.slots and not srv._unread
+
+
+def test_a_synchronous_tick_waits_before_it_admits(params, monkeypatch):
+    """Entered with a round in flight, a depth-0 tick waits for it before
+    it looks at the arrivals: a request that came while that round was
+    ending is admitted and prefilled in this very tick, as in the order
+    that keeps a round ahead (``_pace`` sleeps before the same look)."""
+    from seldon_core_tpu.runtime import genserver as gs
+
+    prompts = np.random.default_rng(9).integers(0, 48, size=(2, 4))
+    monkeypatch.setattr(gs, "_FENCE_EVERY", 10 ** 9)
+    srv = _server(params, max_new_tokens=30)
+    srv._ensure_thread = lambda: None       # the test is its scheduler
+    first = srv.submit(prompts[:1].astype(float))
+    for _ in range(50):
+        srv._tick()
+        if srv._unread:
+            break
+    assert srv._unread
+    late, drain = [], srv._drain
+
+    def arrives_meanwhile():
+        late.append(srv.submit(prompts[1:].astype(float), max_new=7))
+        drain()
+
+    monkeypatch.setattr(srv, "_drain", arrives_meanwhile)
+    monkeypatch.setattr(srv, "_depth", lambda: 0)
+    srv._tick()
+    assert len(late) == 1 and late[0].t_admit is not None
+    assert not srv._waiting and not srv._arrivals and not srv._unread
+    monkeypatch.setattr(srv, "_drain", drain)
+    for _ in range(50):
+        if first.future.done() and late[0].future.done():
+            break
+        srv._tick()
+    np.testing.assert_array_equal(
+        first.future.result(timeout=0), _ref(params, prompts[:1], 30))
+    np.testing.assert_array_equal(
+        late[0].future.result(timeout=0), _ref(params, prompts[1:], 7))
+
+
+def test_the_wake_up_guard_follows_the_wait_for_the_round_paced_against(
+        params):
+    """``_pace`` sleeps until the round in flight is about to end -- its
+    start, its device seconds at this row count, less the guard -- and the
+    guard is one closed loop on how long the wait for that round lasts once
+    the next program is out: found finished (too late) it grows by half the
+    missing slack, waited for too long (too early) it shrinks by a fifth of
+    the excess, and never leaves [0, half a round]."""
+    from collections import deque
+
+    from seldon_core_tpu.runtime import genserver as gs
+
+    srv = _server(params)       # never started: the test is its scheduler
+    srv._dev_s = {}
+    srv._round_s[4] = deque([0.2], maxlen=8)
+    srv._slack_s = 0.04         # long against what a loaded host adds
+
+    def tick(guard):
+        srv._guard_s = guard
+        srv._last_done = 0.0
+        k = gs._Flight("decode", 4, [], _Program(0.2), None, None,
+                       time.perf_counter(), (4, []))
+        srv._unread.append(k)
+        srv._pace()
+        woke = time.perf_counter() - k.t_dispatch
+        srv._unread.append(gs._Flight(          # round k+1 goes out
+            "decode", 4, [], _Program(0.2), None, None,
+            time.perf_counter(), (4, [])))
+        srv._await(k, "GenServer._decode_round")
+        srv._unread.clear()
+        return woke
+
+    assert tick(0.0) >= 0.2                     # woken at its end: too late
+    assert 0.012 <= srv._guard_s <= 0.02        # half the slack it missed
+    assert 0.09 <= tick(0.1) < 0.19             # 100 ms early: too early
+    assert 0.08 < srv._guard_s < 0.095          # a fifth of 60 ms back
+    srv._slack_s = 10.0                         # whatever the loop is told,
+    tick(0.05)
+    assert srv._guard_s == 0.1                  # half a round bounds it
+    assert srv.snapshot()["pace"] == {
+        "round_ms": {"4": 200.0}, "guard_ms": 100.0, "slack_ms": 10000.0}

@@ -208,6 +208,43 @@ def _export_for(gs, prompt):
     return captured
 
 
+def test_a_handed_off_prompt_holds_no_slot_of_the_prefill_replica():
+    """A prefill replica never decodes, so a finished prompt needs no entry
+    of the decode carry once its first token is picked: the slot goes back
+    with the blocks at the hand-off, and outstanding hand-offs -- however
+    many, however slow the decode side -- never stall admission."""
+    held = []
+
+    class Hold:
+        def submit(self, export, done_cb):
+            held.append((export, done_cb))
+
+        def close(self):
+            pass
+
+        def snapshot(self):
+            return {}
+
+        def chain_estimate_s(self):
+            return None
+
+    prefill = _genserver(role="prefill", coordinator=Hold(), slots=2)
+    try:
+        reqs = [prefill.submit(_PROMPT + i) for i in range(5)]
+        deadline = time.monotonic() + 60
+        while len(held) < 5 and time.monotonic() < deadline:
+            time.sleep(0.01)
+        assert len(held) == 5       # all five exported, none handed back
+        _wait_blocks_freed(prefill)
+        assert len(prefill._slot_free) == 2
+        for export, done in held:
+            done(np.arange(16))
+        for req in reqs:
+            assert np.asarray(req.future.result(timeout=60)).shape == (1, 16)
+    finally:
+        prefill.stop()
+
+
 def test_torn_handoff_reclaims_all_blocks():
     decode = _genserver(role="decode")
     prefill = _genserver(role="prefill")
