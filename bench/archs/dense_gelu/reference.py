@@ -75,19 +75,37 @@ def layer(lp, x, *, n_heads: int, n_kv: int, theta: float):
 
 
 @jax.jit
-def head(embed, ln_f, x):
+def head(embed, ln_f, x, at):
+    """The final norm and the tied unembedding at the positions ``at``
+    [B, A] of x [B, S, D] only: nothing of [B, S, V] is ever held."""
     with jax.default_matmul_precision("highest"):
+        x = jnp.take_along_axis(x, at[..., None], axis=1)
         x = _rmsnorm(x, ln_f.astype(jnp.float32))
         return x @ embed.astype(jnp.float32).T
 
 
-def forward(params, tokens, config: dict):
-    """tokens [B, S] int32 -> logits [B, S, V] float32, at the sizes
-    ``config`` (a configuration file's document) publishes."""
+def forward(params, tokens, config: dict, at):
+    """tokens [B, S] int32, at [B, A] int32 -> the logits after the
+    positions ``at`` of each row, [B, A, V] float32, at the sizes ``config``
+    (a configuration file's document) publishes."""
     x = params["embed"][tokens].astype(jnp.float32)
     for i in range(config["num_hidden_layers"]):
         x = layer(params[f"l{i}"], x,
                   n_heads=config["num_attention_heads"],
                   n_kv=config["num_key_value_heads"],
                   theta=float(config["rope_theta"]))
-    return head(params["embed"], params["ln_f"], x)
+    return head(params["embed"], params["ln_f"], x, at)
+
+
+def row_bytes(config: dict, S: int, judged: int) -> int:
+    """What one row of ``S`` positions holds at its fullest, inside
+    ``layer`` (float32): the scores and their softmax [H, S, S]; the
+    stream, its norm, q and the repeated k and v, the attention's output
+    [S, D] each; qkv; the FFN's hidden [S, F] before and after the GELU —
+    and the ``judged`` positions' logits.  lib/sample.py sizes a group of
+    rows by it."""
+    D, H = config["hidden_size"], config["num_attention_heads"]
+    hd = D // H
+    F, V = config["intermediate_size"], config["vocab_size"]
+    qkv = D + 2 * config["num_key_value_heads"] * hd
+    return 4 * (2 * H * S * S + S * (6 * D + qkv + 2 * F) + judged * V)

@@ -36,6 +36,12 @@ def _setup(spec: dict):
     from seldon_core_tpu.runtime.compilecache import enable_compile_cache
 
     enable_compile_cache()
+    # keep the child's one-off programs too (a gather, a cast, the head at
+    # each group's shape): each compiles in under the second below which
+    # JAX keeps none, so every run compiled them anew — the reference's
+    # first pass over the judged batch 7.14 s, 2.27 s once they are loaded,
+    # 1.44 s of it arithmetic (my chip run, PR 31, call P31x)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     dev = jax.devices()
     device = {"platform": dev[0].platform, "kind": dev[0].device_kind,
               "count": len(dev)}
@@ -150,15 +156,16 @@ def run_program(unit, params, dep: dict, prompts: list) -> dict:
     return {"logits": sys_logits, "first": first, "tokens": sys_toks}
 
 
-def run_reference(forward, params, config: dict, prompts: list,
-                  first, tokens):
+def run_reference(reference, params, config: dict, prompts: list,
+                  first, tokens, quantum: int):
     """The reference's logits at the judged positions, [R, 1 + span, V]:
     each row's prompt, first token and round through ONE full causal pass
     (teacher-forced on the program's own tokens) — position n-1 gives the
     prefill logits, n .. n+span-1 the logits each decode step chose from.
-    Rows go in groups right-padded to the group's longest
-    (lib/sample.py); only the judged positions come back, and never more
-    than one group's [rows, S, V] is held."""
+    ``reference`` is the architecture's module: its ``forward`` is handed
+    the judged positions (``at``) and unembeds those alone, its
+    ``row_bytes`` says what a row holds, by which lib/sample.py groups the
+    rows; a group is right-padded to a multiple of ``quantum``."""
     import jax.numpy as jnp
     import numpy as np
 
@@ -168,17 +175,18 @@ def run_reference(forward, params, config: dict, prompts: list,
             for i, p in enumerate(prompts)]
     span = tokens.shape[1]
     out = np.zeros((len(seqs), 1 + span, config["vocab_size"]), np.float32)
-    for rows in reference_groups([len(s) for s in seqs],
-                                 config["vocab_size"]):
-        toks = np.zeros((len(rows), len(seqs[rows[0]])), np.int32)
+    groups = reference_groups(
+        [len(s) for s in seqs],
+        lambda length: reference.row_bytes(config, length, 1 + span),
+        quantum)
+    for length, rows in groups:
+        toks = np.zeros((len(rows), length), np.int32)
         at = np.zeros((len(rows), 1 + span), np.int32)
         for i, r in enumerate(rows):
             toks[i, :len(seqs[r])] = seqs[r]
             at[i] = len(prompts[r]) - 1 + np.arange(1 + span)
-        logits = forward(params, jnp.asarray(toks), config)
-        out[rows] = np.asarray(
-            logits[jnp.arange(len(rows))[:, None], jnp.asarray(at)])
-        del logits
+        out[rows] = np.asarray(reference.forward(
+            params, jnp.asarray(toks), config, jnp.asarray(at)))
     return out
 
 
@@ -244,7 +252,7 @@ def compare(spec: dict, unit, params, token_seed: int) -> dict:
     from lib.verdict import judge
 
     config = spec["config"]
-    forward = arch_module(spec["bench_dir"], config, "reference").forward
+    reference = arch_module(spec["bench_dir"], config, "reference")
     prompts = sample_tokens(spec["sample"]["lens"], config["vocab_size"],
                             token_seed)
     t0 = time.monotonic()
@@ -252,12 +260,12 @@ def compare(spec: dict, unit, params, token_seed: int) -> dict:
     # the program's peak: the reference comes after it and is not counted
     peak = _peak()
     t1 = time.monotonic()
-    ref = run_reference(forward, params, config, prompts, prog["first"],
-                        prog["tokens"])
+    ref = run_reference(reference, params, config, prompts, prog["first"],
+                        prog["tokens"], spec["deployment"]["block_size"])
     rows = by_row(ref, prog["logits"], prog["tokens"])
     rms = sum(rows["rms"]) / len(rows["rms"])
-    return {"forward": forward, "prompts": prompts, "prog": prog, "ref": ref,
-            "rows": rows, "rms": rms, "memory_peak_bytes": peak,
+    return {"reference": reference, "prompts": prompts, "prog": prog,
+            "ref": ref, "rows": rows, "rms": rms, "memory_peak_bytes": peak,
             "verdict": judge(rows["prefill_err"], rows["decode_margin"],
                              config["numerics"], rms),
             "seconds": {"program": t1 - t0,
@@ -294,9 +302,10 @@ def limits(spec: dict, device: dict) -> dict:
         entry = {"seed": seed, "ref_logit_rms": c["rms"],
                  "program": c["rows"], "program_verdict": c["verdict"]}
         if seed in spec["control_seeds"]:
-            ctl = run_reference(c["forward"], fp8_rounded(params), config,
+            ctl = run_reference(c["reference"], fp8_rounded(params), config,
                                 c["prompts"], c["prog"]["first"],
-                                c["prog"]["tokens"])
+                                c["prog"]["tokens"],
+                                spec["deployment"]["block_size"])
             rows = by_row(c["ref"], ctl[:, 0], ctl[:, 1:].argmax(-1))
             if not max(rows["prefill_err"]) > 0.0:
                 raise SystemExit("the control reads what the reference "

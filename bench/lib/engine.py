@@ -14,6 +14,8 @@ import subprocess
 import sys
 import time
 
+from .manifest import ManifestError
+
 
 class EngineFailure(Exception):
     pass
@@ -61,6 +63,12 @@ def unit_spec(config: dict, dep: dict, seed: int, max_new: int) -> dict:
                     f"config {config.get('name')!r}: unit parameter "
                     f"{keyword!r} names {value!r}, not a key of the file")
             value = config[value["from"]]
+        if type(value) not in _PARAM_TYPES:
+            raise ManifestError(
+                f"config {config.get('name')!r}: unit parameter {keyword!r} "
+                f"is a {type(value).__name__}; a deployment document "
+                "carries INT, FLOAT, STRING and BOOL only (bench/README.md: "
+                "a unit derives a list from the published scalars)")
         params[keyword] = value
     params.update({
         "max_new_tokens": max_new, "seed": int(seed) % (2 ** 31 - 1),

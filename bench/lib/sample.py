@@ -12,14 +12,14 @@ four chunks is judged on a row of four chunks, whatever the seed."""
 
 from __future__ import annotations
 
-from typing import List, Sequence
+from typing import Callable, List, Sequence, Tuple
 
 from . import buckets
 
 MAX_ROWS = 32
-# the most one group of reference logits [rows, S, V] (float32) may take
+# the most one group of rows may hold inside the reference, by the
+# architecture's own account of a row (archs/<arch>/reference.py row_bytes)
 GROUP_BYTES = 2 << 30
-LONGEST = 4
 
 
 def pick(prompts: Sequence[int], rows: int) -> List[int]:
@@ -56,22 +56,24 @@ def plan(prompts: Sequence[int], dep: dict, max_positions: int) -> dict:
             "chunks": [min(chunks), max(chunks)]}
 
 
-def reference_groups(totals: Sequence[int], vocab: int) -> List[List[int]]:
-    """Row indices in groups the reference takes one at a time, each
-    right-padded to its longest row (a causal pass is unchanged before the
-    pad), neighbours in length together.  Every group is one more shape
-    the reference compiles (12-20 s apiece, cold), so there are as few as
-    the two rules leave: the longest ``1 / LONGEST`` of the rows go apart
-    (padding everything to them would multiply the work), and no group's
-    logits [rows, S, V] in float32 pass ``GROUP_BYTES`` (one row alone
-    may)."""
-    order = sorted(range(len(totals)), key=lambda i: totals[i], reverse=True)
-    most = -(-len(order) // LONGEST)
+def reference_groups(totals: Sequence[int], row_bytes: Callable[[int], int],
+                     quantum: int) -> List[Tuple[int, List[int]]]:
+    """``(length, row indices)`` groups the reference takes one at a time,
+    the longest first.  A group's rows are right-padded to ``length``, the
+    next multiple of ``quantum`` (the deployment's block size) at or over
+    its longest row: a causal pass is unchanged before the pad, and rows of
+    nearby lengths share a compiled shape, whatever the schedule's exact
+    lengths.  Rows of one length go together as far as ``GROUP_BYTES``
+    holds them by ``row_bytes(length)`` — what a row of the reference holds
+    at that length, asked of the architecture, since a wide vocabulary, a
+    long row's scores or an expert layer's hidden each dominate somewhere;
+    one row alone may pass it."""
+    by_length: dict = {}
+    for i in sorted(range(len(totals)), key=lambda i: -totals[i]):
+        by_length.setdefault(-(-totals[i] // quantum) * quantum, []).append(i)
     out = []
-    while order:
-        fit = GROUP_BYTES // (totals[order[0]] * vocab * 4)
-        k = max(1, min(most, fit))
-        out.append(order[:k])
-        order = order[k:]
-        most = len(order)
+    for length in sorted(by_length, reverse=True):
+        rows = by_length[length]
+        fit = max(1, GROUP_BYTES // row_bytes(length))
+        out += [(length, rows[k:k + fit]) for k in range(0, len(rows), fit)]
     return out

@@ -51,6 +51,18 @@ UNSCOPED = "unscoped"
 UNATTRIBUTED = "unattributed"
 
 
+def scope_share(prog: dict, scopes) -> Optional[float]:
+    """100 x the device seconds of one program (an entry of the
+    reduction's ``programs``) under the named ``scopes`` over the program's
+    device seconds.  None where the program did not run or wrote no named
+    scope at all (a program from before the scopes): never 0 for that."""
+    by = prog.get("by_scope_s") or {}
+    if not prog.get("module_s", 0.0) > 0 or not any(
+            v > 0 for k, v in by.items() if k != UNSCOPED):
+        return None
+    return 100.0 * sum(by.get(k, 0.0) for k in scopes) / prog["module_s"]
+
+
 def leaf_segments(annotations: list) -> List[Tuple[float, float, str]]:
     """Nested ``(name, start, end)`` annotations of one thread ->
     non-overlapping ``(start, end, name)`` segments, each named by the
@@ -237,14 +249,10 @@ def reduce_scopes(planes: list) -> dict:
         prog["calls"] /= n
         prog["by_scope_s"] = {k: v / n for k, v in sorted(
             prog["by_scope_s"].items(), key=lambda kv: -kv[1])}
-        known = sum(v for k, v in prog["by_scope_s"].items()
-                    if k != UNSCOPED)
-        if prog["module_s"] > 0 and known > 0:
-            kv = sum(prog["by_scope_s"].get(k, 0.0) for k in KV_SCOPES)
-            prog["kv_share"] = 100.0 * kv / prog["module_s"]
-            prog["unscoped_share"] = 100.0 * prog["by_scope_s"].get(
-                UNSCOPED, 0.0) / prog["module_s"]
-            out[key + "_kv_share"] = prog["kv_share"]
+        kv = scope_share(prog, KV_SCOPES)
+        if kv is not None:
+            prog["kv_share"] = out[key + "_kv_share"] = kv
+            prog["unscoped_share"] = scope_share(prog, (UNSCOPED,))
         prog["fence"] = fences.get(key)
         prog["device_args"] = _mean_args(
             [ev for ev in annotations if ev[0] == FENCES[key]])

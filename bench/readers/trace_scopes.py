@@ -3,6 +3,12 @@
 inside a fenced decode dispatch, a program's device time by stage.
 
     {"value": "<key of the reduction>"}
+    {"program": "decode" | "prefill", "scopes": ["<jax.named_scope>", ...]}
+
+The second is the share of one program's device time under the named
+scopes, 100 x sum of ``programs.<program>.by_scope_s[scope]`` over its
+``module_s``: a block that names its own stages (``experts``, ``router``)
+asks for their share with a data file.
 
 run.py hands a reader no path to the trace, so this one finds the
 window's ``*.xplane.pb`` itself: the newest under ``bench/out/<cell
@@ -25,6 +31,7 @@ import subprocess
 
 from lib.engine import EngineFailure, run_child
 from lib.formula import delta
+from lib.trace_scopes import scope_share
 
 BENCH_DIR = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 _REDUCED: dict = {}          # trace path -> reduction
@@ -78,5 +85,10 @@ def read(metric: dict, ctx: dict):
     path = newest_trace(ctx["cell"]["name"])
     if path is None:
         return None
-    value = reduction(path, ctx).get(metric["formula"]["value"])
+    red, formula = reduction(path, ctx), metric["formula"]
+    if "scopes" in formula:
+        return scope_share(
+            (red.get("programs") or {}).get(formula["program"]) or {},
+            formula["scopes"])
+    value = red.get(formula["value"])
     return float(value) if isinstance(value, (int, float)) else None

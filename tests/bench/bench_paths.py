@@ -66,7 +66,7 @@ def add_tiny_cell(root: str) -> str:
     ADDING files and manifest entries only; returns the cell's name."""
     bench = os.path.join(root, "bench")
     cfg = load(os.path.join(bench, "configs", "starcoder2-3b.json"))
-    cfg.update(TINY_CONFIG, name="tiny")
+    cfg.update(TINY_CONFIG, name="tiny", source="a test")
     cfg["deployment"] = {**cfg["deployment"], **TINY_DEPLOYMENT}
     dump(os.path.join(bench, "configs", "tiny.json"), cfg)
     mix = load(os.path.join(bench, "traffic", "codegen.json"))
@@ -108,7 +108,8 @@ def add_tiny_cell(root: str) -> str:
 
 TINYARCH_REFERENCE = '''"""A test's block, not the repo's: pre-RMSNorm (eps from the configuration),
 bias-free separate Q/K/V, half-split RoPE, GQA, a GATED SiLU FFN of three
-matrices and an UNTIED head (``lm_head``)."""
+matrices and an UNTIED head (``lm_head``).  A head is ``head_dim`` wide
+where the configuration says so, else hidden // heads."""
 
 import math
 
@@ -129,14 +130,18 @@ def _rope(x, theta):
     return jnp.concatenate([a * cos - b * sin, b * cos + a * sin], -1)
 
 
-def forward(params, tokens, config):
+def _hd(config):
+    return config.get("head_dim", config["hidden_size"]
+                      // config["num_attention_heads"])
+
+
+def forward(params, tokens, config, at):
     H, KV = config["num_attention_heads"], config["num_key_value_heads"]
-    eps, theta = config["rms_norm_eps"], config["rope_theta"]
+    eps, theta, hd = config["rms_norm_eps"], config["rope_theta"], _hd(config)
     with jax.default_matmul_precision("highest"):
         p = jax.tree.map(lambda a: a.astype(jnp.float32), params)
         x = p["embed"][tokens]
         B, S, D = x.shape
-        hd = D // H
         for i in range(config["num_hidden_layers"]):
             lp = p[f"l{i}"]
             h = _norm(x, lp["ln1"], eps)
@@ -147,11 +152,19 @@ def forward(params, tokens, config):
             s = jnp.einsum("bqhd,bkhd->bhqk", q, k) / math.sqrt(hd)
             s = jnp.where(jnp.tril(jnp.ones((S, S), bool)), s, -jnp.inf)
             a = jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, -1), v)
-            x = x + a.reshape(B, S, D) @ lp["wo"]
+            x = x + a.reshape(B, S, H * hd) @ lp["wo"]
             h = _norm(x, lp["ln2"], eps)
             x = x + (jax.nn.silu(h @ lp["w_gate"]) * (h @ lp["w_up"])
                      ) @ lp["w_down"]
+        x = jnp.take_along_axis(x, at[..., None], axis=1)
         return _norm(x, p["ln_f"], eps) @ p["lm_head"]
+
+
+def row_bytes(config, S, judged):
+    D, F = config["hidden_size"], config["intermediate_size"]
+    H = config["num_attention_heads"]
+    return 4 * (2 * H * S * S + S * (4 * D + 4 * H * _hd(config) + 3 * F)
+                + judged * config["vocab_size"])
 '''
 
 TINYARCH_NEEDS = '''"""Bytes and FLOPs of the test's block: THREE FFN matrices a layer, an
@@ -165,7 +178,8 @@ def sizes(config):
     kv = config["num_key_value_heads"] * hd
     L, V = config["num_hidden_layers"], config["vocab_size"]
     layer = D * (D + 2 * kv) + D * D + 3 * D * F
-    return {"layer_params": layer, "matmul_params": L * layer + V * D,
+    return {"hd": hd, "layer_params": layer,
+            "matmul_params": L * layer + V * D,
             "weight_bytes": 2 * (L * layer + V * D),
             "kv_bytes_per_position": L * 2 * kv * 2,
             "attn_flops_per_position": 4 * L * D}
@@ -226,6 +240,10 @@ def add_tiny_arch(root: str) -> str:
         "unit": TINYARCH_UNIT, **TINY_CONFIG,
         "rope_theta": 10000.0, "rms_norm_eps": 1e-05,
         "tie_word_embeddings": False, "reduced": [],
+        # the block as its "source" has it, every size given
+        "departures": [], "assumed": [],
+        "positions_limit": {"value": 512, "why": "a test's rotary table"},
+        "hbm": {"total": "a test's: kilobytes"},
         "deployment": {**base["deployment"], **TINY_DEPLOYMENT},
         "numerics": base["numerics"],
     }
@@ -249,3 +267,284 @@ def add_tiny_arch(root: str) -> str:
             m["workloads"].append(cell)
     dump(os.path.join(root, "BENCHMARK.json"), man)
     return cell
+
+
+def check_ladder(man, cell):
+    """The warm-up ladder of a cell takes every program its lengths can
+    reach, and no row reaches past its configuration's own limit."""
+    from lib import buckets
+
+    doc = man.cell(cell)
+    config = man.config(doc["config"])
+    dep = man.deployment(doc, config)
+    cp = buckets.caps(man.mix(doc["mix"]))
+    want_pre, want_dec = buckets.reachable(dep, cp)
+    got_pre, got_dec = set(), set()
+    for length, max_new in buckets.ladder_rows(dep, cp):
+        assert cp["min_prompt"] <= length <= cp["max_prompt"]
+        pre, dec = buckets.touched(length, max_new, dep)
+        got_pre |= pre
+        got_dec |= dec
+    assert want_pre <= got_pre and want_dec <= got_dec
+    # brute force over every request the mix can draw
+    seen_pre, seen_dec = set(), set()
+    for p in range(cp["min_prompt"], cp["max_prompt"] + 1, 7):
+        o = min(cp["max_out"], cp["max_positions"] - p)
+        pre, dec = buckets.touched(p, o, dep)
+        seen_pre |= pre
+        seen_dec |= dec
+    assert seen_pre <= want_pre and seen_dec <= want_dec
+    progs = buckets.programs(dep, cp)
+    rows = buckets.row_buckets(dep["slots"])
+    assert rows[-1] == dep["slots"] and rows[0] == 1
+    assert len(progs["prefill"]) == len(rows) * len(want_pre)
+    assert len(progs["decode"]) == len(rows) * len(want_dec)
+    # no row reaches past what the cell's OWN configuration can hold
+    assert cp["max_positions"] + dep["span"] <= config["positions_limit"][
+        "value"]
+
+
+# -- the second toy architecture: a block that is NOT the repo's --------------
+
+TOYMOE_REFERENCE = '''"""A test's block, shaped like what a draw of public models brings: RMSNorm
+before each sub-layer; bias-free q, k, v of ``head_dim`` a head (NOT hidden
+// heads); GQA; ``layer_types`` from the file: rotary embedding and a
+window of ``sliding_window`` keys on the sliding layers, neither on the
+full ones; ``num_dense_layers`` leading gated-SiLU layers, then expert
+layers: sigmoid scores in float32 over the PUBLISHED number of experts,
+the top ``num_experts_per_tok`` of them, weights normalised over the chosen
+and times ``route_scale``, a shared expert beside them.  The file's
+``num_experts`` is this chip's share (``reduced``): the first that many
+are held, and a chosen expert that lives on another chip adds nothing, in
+the program and here alike.  Untied head."""
+
+import math
+
+import jax
+import jax.numpy as jnp
+
+
+def _norm(x, w, eps):
+    return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps) * w
+
+
+def _rope(x, theta):
+    half = x.shape[-1] // 2
+    ang = (jnp.arange(x.shape[1], dtype=jnp.float32)[:, None]
+           * theta ** (-jnp.arange(half, dtype=jnp.float32) / half))
+    cos, sin = jnp.cos(ang)[None, :, None], jnp.sin(ang)[None, :, None]
+    a, b = x[..., :half], x[..., half:]
+    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin], -1)
+
+
+def _gated(h, gate, up, down):
+    return (jax.nn.silu(h @ gate) * (h @ up)) @ down
+
+
+def _experts(h, lp, config):
+    held = config["num_experts"]
+    score = jax.nn.sigmoid(h @ lp["router"])            # every published one
+    top, idx = jax.lax.top_k(score, config["num_experts_per_tok"])
+    weight = top / top.sum(-1, keepdims=True) * config["route_scale"]
+    # every held expert on every token, then the chosen ones among them
+    every = jnp.einsum(
+        "bsef,efd->bsed",
+        jax.nn.silu(jnp.einsum("bsd,edf->bsef", h, lp["e_gate"]))
+        * jnp.einsum("bsd,edf->bsef", h, lp["e_up"]), lp["e_down"])
+    onehot = jax.nn.one_hot(idx, held)                  # 0 for one not held
+    return jnp.einsum("bsk,bske,bsed->bsd", weight, onehot, every)
+
+
+def forward(params, tokens, config, at):
+    H, KV = config["num_attention_heads"], config["num_key_value_heads"]
+    hd, eps = config["head_dim"], config["rms_norm_eps"]
+    with jax.default_matmul_precision("highest"):
+        p = jax.tree.map(lambda a: a.astype(jnp.float32), params)
+        x = p["embed"][tokens]
+        B, S, D = x.shape
+        ahead = jnp.arange(S)[:, None] - jnp.arange(S)[None, :]
+        for i, kind in enumerate(config["layer_types"]):
+            lp = p[f"l{i}"]
+            h = _norm(x, lp["ln1"], eps)
+            q = (h @ lp["wq"]).reshape(B, S, H, hd)
+            k = (h @ lp["wk"]).reshape(B, S, KV, hd)
+            v = (h @ lp["wv"]).reshape(B, S, KV, hd)
+            seen = ahead >= 0
+            if kind == "sliding_attention":
+                q, k = (_rope(t, config["rope_theta"]) for t in (q, k))
+                seen = seen & (ahead < config["sliding_window"])
+            k, v = (jnp.repeat(t, H // KV, axis=2) for t in (k, v))
+            s = jnp.einsum("bqhd,bkhd->bhqk", q, k) / math.sqrt(hd)
+            s = jnp.where(seen, s, -jnp.inf)
+            a = jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, -1), v)
+            x = x + a.reshape(B, S, H * hd) @ lp["wo"]
+            h = _norm(x, lp["ln2"], eps)
+            y = _gated(h, lp["w_gate"], lp["w_up"], lp["w_down"])
+            if i >= config["num_dense_layers"]:
+                y = y + _experts(h, lp, config)
+            x = x + y
+        x = jnp.take_along_axis(x, at[..., None], axis=1)
+        return _norm(x, p["ln_f"], eps) @ p["lm_head"]
+
+
+def row_bytes(config, S, judged):
+    """Scores and softmax, the stream's copies, and what dominates a routed
+    block: every held expert's hidden on every token."""
+    D, H, hd = (config["hidden_size"], config["num_attention_heads"],
+                config["head_dim"])
+    E, F = config["num_experts"], config["moe_intermediate_size"]
+    return 4 * (2 * H * S * S + S * (4 * D + 4 * H * hd + 3 * E * F + E * D)
+                + judged * config["vocab_size"])
+'''
+
+TOYMOE_NEEDS = '''"""Bytes and FLOPs of the test's routed block.  A step reads the experts
+it chose, not all of them: the program's count where it has one
+(``counters``), else the most ``rows`` tokens can choose."""
+
+
+def sizes(config):
+    D, hd = config["hidden_size"], config["head_dim"]
+    H, KV = config["num_attention_heads"], config["num_key_value_heads"]
+    L, dense = config["num_hidden_layers"], config["num_dense_layers"]
+    attn = D * hd * (H + 2 * KV) + H * hd * D
+    expert = 3 * D * config["moe_intermediate_size"]
+    return {"hd": hd, "attn_params": attn, "expert_params": expert,
+            "dense_params": dense * (
+                attn + 3 * D * config["intermediate_size"]),
+            "expert_layers": L - dense,
+            "head_params": config["vocab_size"] * D,
+            "kv_bytes_per_position": L * 2 * KV * hd * 2}
+
+
+def _step(config, rows, counters):
+    s = sizes(config)
+    chosen = rows * config["num_experts_per_tok"]
+    read = counters.get("served_decode", {}).get(
+        "experts_read_per_step", min(config["num_experts"], chosen))
+    per_layer = s["attn_params"] + s["expert_params"] * (1 + read)
+    return s, s["dense_params"] + s["expert_layers"] * per_layer + s[
+        "head_params"]
+
+
+def decode_step(config, rows, live_positions, counters):
+    s, params = _step(config, rows, counters)
+    return {"bytes": 2 * params
+            + s["kv_bytes_per_position"] * (live_positions + rows),
+            "flops": 2.0 * params * rows}
+
+
+def prefill(config, calls, tokens, attended_positions, counters):
+    s, params = _step(config, tokens / max(calls, 1), counters)
+    return {"bytes": calls * 2 * params
+            + 2 * s["kv_bytes_per_position"] * tokens,
+            "flops": 2.0 * params * tokens}
+'''
+
+TOYMOE_CONFIG = {
+    "name": "toymoe", "source": "a test", "arch": "toymoe",
+    "described_as": "a test's block as its source has it, one chip's share "
+                    "of its experts",
+    "unit": {
+        "class_path": "toy_moe:ToyMoEGenerator",
+        "parameters": {
+            "vocab": {"from": "vocab_size"},
+            "d_model": {"from": "hidden_size"},
+            "head_dim": {"from": "head_dim"},
+            "n_heads": {"from": "num_attention_heads"},
+            "n_kv_heads": {"from": "num_key_value_heads"},
+            "n_layers": {"from": "num_hidden_layers"},
+            "n_dense_layers": {"from": "num_dense_layers"},
+            "d_ff": {"from": "intermediate_size"},
+            "d_expert": {"from": "moe_intermediate_size"},
+            "n_experts": {"from": "num_experts"},
+            "router_width": 32,
+            "experts_per_tok": {"from": "num_experts_per_tok"},
+            "window": {"from": "sliding_window"},
+            "full_every": {"from": "global_attn_every_n_layers"},
+            "rope_base": {"from": "rope_theta"},
+            "norm_eps": {"from": "rms_norm_eps"},
+            "route_scale": {"from": "route_scale"},
+        },
+    },
+    # hidden // heads is 16: the head is 32 wide because the file says so
+    "hidden_size": 64, "head_dim": 32, "num_attention_heads": 4,
+    "num_key_value_heads": 2, "num_hidden_layers": 3, "num_dense_layers": 1,
+    "intermediate_size": 128, "moe_intermediate_size": 32,
+    "num_experts": 8, "num_experts_per_tok": 2, "num_shared_experts": 1,
+    "route_scale": 1.5, "vocab_size": 512, "tie_word_embeddings": False,
+    "sliding_window": 48, "global_attn_every_n_layers": 3,
+    # the list stays in the file for the reference; the unit derives it
+    "layer_types": ["sliding_attention", "sliding_attention",
+                    "full_attention"],
+    "rope_theta": 10000.0, "rms_norm_eps": 1e-05,
+    "max_position_embeddings": 128,
+    "positions_limit": {
+        "value": 128, "why": "max_position_embeddings; the window of 48 "
+                             "is implemented and every judged row of more "
+                             "than 48 positions crosses it"},
+    "reduced": ["num_experts"],
+    "published": {"num_experts": 32},
+    "shared_by_chips": 4,
+    "layer_divided": "each expert layer's 32 routed experts over 4 chips, "
+                     "8 here; attention, the shared expert and the router "
+                     "(all 32 scores, top 2) on every chip",
+    "assumed": [], "departures": [],
+    "hbm": {"total": "a test's: kilobytes"},
+    "numerics": {
+        "tolerance_rms": 0.1,
+        "discrete_share": {
+            "prefill": 0.25, "decode": 0.25,
+            "why": "a top-2 router: a score that falls the other way spoils "
+                   "a row wholly (tests/bench/test_bench_verdict.py)"}},
+}
+
+
+def add_toy_moe(root: str) -> str:
+    """Add ``bench/archs/toymoe/`` — a routed block with a head width, a
+    window, an untied head and a chip's share of its experts — its
+    configuration and a cell on ``add_tiny_cell``'s mix, by ADDING files
+    and manifest entries only.  Its program side is tests/bench/toy_moe.py.
+    Returns the cell's name."""
+    bench = os.path.join(root, "bench")
+    arch = os.path.join(bench, "archs", "toymoe")
+    os.makedirs(arch)
+    with open(os.path.join(arch, "reference.py"), "w") as f:
+        f.write(TOYMOE_REFERENCE)
+    with open(os.path.join(arch, "needs.py"), "w") as f:
+        f.write(TOYMOE_NEEDS)
+    base = load(os.path.join(bench, "configs", "starcoder2-3b.json"))
+    dump(os.path.join(bench, "configs", "toymoe.json"), {
+        **TOYMOE_CONFIG,
+        "deployment": {**base["deployment"], **TINY_DEPLOYMENT}})
+    cell = "toymoe.tinymix.r80"
+    why = ("tinymix on a routed block: rows of 9-80 positions, the longest "
+           "two past the window of 48")
+    dump(os.path.join(bench, "cells", cell + ".json"), {
+        "name": cell, "config": "toymoe", "mix": "tinymix", "chips": 1,
+        "arrivals": {"kind": "open", "rate": 8.0},
+        "drain_s": 2, "soak_s": 2, "trace_s": 1, "why": why})
+    man = load(os.path.join(root, "BENCHMARK.json"))
+    man["configs"].append({
+        "name": "toymoe", "source": "a test", "reduced": ["num_experts"],
+        "file": "bench/configs/toymoe.json",
+        "why": "a test's routed block, one chip's share of its experts"})
+    man["workloads"].append({
+        "name": cell, "config": "toymoe", "traffic": "tinymix", "chips": 1,
+        "why": why})
+    for m in man["end_to_end"] + man["per_layer"]:
+        if "workloads" in m:
+            m["workloads"].append(cell)
+    dump(os.path.join(root, "BENCHMARK.json"), man)
+    return cell
+
+
+def toy_root(tmp_path) -> str:
+    """A copy of the benchmark with the dense toys and ``toymoe`` added, no
+    file that was there edited."""
+    root = copy_root(tmp_path)
+    before = snapshot(root)
+    add_tiny_cell(root)
+    add_tiny_arch(root)
+    add_toy_moe(root)
+    assert_untouched(before)
+    return root

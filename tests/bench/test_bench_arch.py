@@ -13,7 +13,7 @@ from bench_paths import BENCH, REPO
 from lib import buckets, sample
 from lib.engine import EngineFailure, deployment_doc, run_child, unit_spec
 from lib.formula import deltas
-from lib.manifest import Manifest
+from lib.manifest import Manifest, ManifestError
 
 MAN = Manifest(REPO)
 CHILD = os.path.join(BENCH, "lib", "children.py")
@@ -58,6 +58,28 @@ def test_unit_parameters_are_published_keys_or_literals_and_nothing_else():
     del cfg["unit"]
     with pytest.raises(KeyError):       # no default unit
         unit_spec(cfg, dep, 1, 7)
+
+
+@pytest.mark.parametrize("value, type_name", [
+    (["sliding_attention", "full_attention"], "list"),
+    ({"from": "layer_types"}, "list"),
+    ({"from": "positions_limit"}, "dict"),
+    (None, "NoneType"),
+], ids=["a-literal-list", "a-published-list", "a-published-group", "null"])
+def test_a_unit_parameter_a_deployment_cannot_carry_is_refused_by_name(
+        value, type_name):
+    """A SeldonDeployment parameter is INT, FLOAT, STRING or BOOL.  A list a
+    unit needs (a layer pattern) is the unit's to derive from the published
+    scalars; handing it over is refused with the configuration, the keyword
+    and the type — it was a bare ``KeyError: <class 'list'>``."""
+    cfg, dep = tiny()
+    cfg["layer_types"] = ["sliding_attention", "full_attention"]
+    cfg["unit"] = {"class_path": "x:Y", "parameters": {
+        "vocab": {"from": "vocab_size"}, "pattern": value}}
+    with pytest.raises(ManifestError) as e:
+        unit_spec(cfg, dep, 1, 7)
+    for part in ("'tiny'", "'pattern'", type_name, "INT, FLOAT, STRING"):
+        assert part in str(e.value)
 
 
 def test_the_child_builds_the_unit_as_the_engine_does():
@@ -174,3 +196,81 @@ def test_numerics_child_fails_loudly_where_the_reference_wants_other_weights(
         numerics(arch_root, tmp_path, "tinyarch", gated)
     with pytest.raises(EngineFailure, match="no such file"):
         numerics(arch_root, tmp_path, "nowhere")
+
+
+# -- a block that is not the repo's: the numerics child on the toy MoE -------
+
+
+@pytest.fixture(scope="module")
+def moe_root(tmp_path_factory):
+    """The benchmark with ``toymoe`` added as files (bench_paths), and two
+    references to put in its place: ``tinyarch``, the DENSE toy (it reads
+    the same attention and shared-expert weights: no router, no window, no
+    rotary-free layers), and ``toymoe_nowindow``, its own with the window
+    taken out."""
+    root = bench_paths.toy_root(tmp_path_factory.mktemp("moe"))
+    window = ' & (ahead < config["sliding_window"])'
+    assert bench_paths.TOYMOE_REFERENCE.count(window) == 1
+    dst = os.path.join(root, "bench", "archs", "toymoe_nowindow")
+    os.makedirs(dst)
+    with open(os.path.join(dst, "reference.py"), "w") as f:
+        f.write(bench_paths.TOYMOE_REFERENCE.replace(window, ""))
+    return root
+
+
+def toy_numerics(root, monkeypatch, arch):
+    """lib/children.py ``numerics`` in this process, the test-local unit's
+    paged programs (tests/bench/toy_moe.py) in the place of the program's:
+    the unit is built from the deployment document as the engine builds
+    one, and the child drives chunked prefill and a decode round."""
+    import toy_moe
+    from lib import children
+    from seldon_core_tpu.models import generate
+
+    for name in ("init_block_pool", "paged_forward_jit",
+                 "paged_decode_round_jit"):
+        monkeypatch.setattr(generate, name, getattr(toy_moe, name))
+    man = Manifest(root)
+    cell = man.cell("toymoe.tinymix.r80")
+    cfg = man.config(cell["config"])
+    dep = man.deployment(cell, cfg)
+    caps = buckets.caps(man.mix(cell["mix"]))
+    spec = {
+        "repo": REPO, "platforms": ["cpu"], "bench_dir": man.bench,
+        "config": {**cfg, "arch": arch}, "deployment": dep,
+        "unit": unit_spec(cfg, dep, 2 ** 31 + 9, caps["max_out"]),
+        "sample": sample.plan(TINY_PROMPTS, dep, caps["max_positions"]),
+        "sample_seed": 17}
+    num = children.numerics(
+        spec, {"platform": "cpu", "kind": "cpu", "count": 1})
+    return num, cfg
+
+
+@pytest.mark.parametrize("arch, ok, rows_over", [
+    ("toymoe", True, 0), ("tinyarch", False, 4), ("toymoe_nowindow", False, 2),
+], ids=["its-own-block", "the-dense-toy-in-its-place", "no-window"])
+def test_numerics_child_judges_a_block_that_is_not_the_repos(
+        moe_root, monkeypatch, arch, ok, rows_over):
+    num, cfg = toy_numerics(moe_root, monkeypatch, arch)
+    v = num["verdict"]
+    assert num["ok"] is ok, v
+    # rows of 9, 31, 64 and 80 positions + a round: two within the window
+    # of 48, two past it, none past the configuration's own limit
+    assert num["lens"] == [9, 31, 64, 80] and num["chunks"] == [1, 3]
+    assert cfg["sliding_window"] < num["lens"][-2]
+    assert num["lens"][-1] + 8 <= cfg["positions_limit"]["value"]
+    assert v["prefill"]["over"] == rows_over
+    assert v["prefill"]["allowed"] == v["decode"]["allowed"] == 0.25
+    errs = num["by_row"]["prefill_err"]
+    if ok:
+        assert 0.0 < max(errs) < 0.01 * num["tolerance"]
+        assert num["decode_max_margin"] <= 0.01 * num["tolerance"]
+    elif rows_over == 2:
+        # only the rows that reach past the window see that it is gone
+        assert max(errs[:2]) < 0.01 * num["tolerance"] < min(errs[2:])
+    # the unit derived the pattern the file lists for the reference
+    from lib.children import build_unit
+
+    unit = build_unit(unit_spec(cfg, cfg["deployment"], 1, 8))
+    assert [unit.cfg.sliding(i) for i in range(3)] == [
+        t == "sliding_attention" for t in cfg["layer_types"]]

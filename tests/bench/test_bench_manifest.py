@@ -4,12 +4,14 @@ the allowed characters — and a cell, a configuration, a mix and a layer
 metric are each added by ADDING files (shown in a temporary copy)."""
 
 import importlib
+import inspect
 import os
 
 import bench_paths
 import pytest
 from bench_paths import BENCH, REPO, load
 from lib import buckets
+from lib.engine import unit_spec
 from lib.manifest import (
     NAME_RE,
     UNIT_RE,
@@ -23,8 +25,18 @@ CELLS = [w["name"] for w in MAN.doc["workloads"]]
 CONFIGS = [c["name"] for c in MAN.doc["configs"]]
 PER_LAYER = [m["name"] for m in MAN.doc["per_layer"]]
 E2E = [m["name"] for m in MAN.doc["end_to_end"]]
-WIDTH_KEYS = ("hidden_size", "intermediate_size", "num_attention_heads",
-              "num_key_value_heads", "vocab_size")
+# What section 4 of the model-configs guide never cuts: a width.  A key of
+# these, or one that ends as a width's name ends, may not be in ``reduced``.
+WIDTH_KEYS = ("hidden_size", "intermediate_size", "moe_intermediate_size",
+              "head_dim", "sliding_window", "num_experts_per_tok")
+WIDTH_ENDINGS = ("_dim", "_rank", "intermediate_size", "state_size")
+# ... and the counts that may be the chip's share of a stated deployment:
+# how many layers, heads, routed experts, rows of the vocabulary are held
+# here.  Each in ``reduced`` needs its published count beside it.
+LAYERS = "num_hidden_layers"
+VOCAB = "vocab_size"
+HEADS = ("num_attention_heads", "num_key_value_heads")
+MIN_EXPERTS, MIN_VOCAB_SHARE, MIN_LAYERS = 8, 8, 4
 
 
 def listed(kind):
@@ -65,7 +77,21 @@ def test_every_cells_mix_has_its_file_and_every_mix_file_its_cell():
         assert mix["name"] == name and NAME_RE.match(name)
         cp = buckets.caps(mix)
         assert 0 < cp["min_prompt"] <= cp["max_prompt"]
-        assert cp["max_positions"] + 8 <= 4096
+    # a mix belongs to no configuration, so it has no position cap of its
+    # own: a cell's check holds it to its configuration's, and a mix that
+    # waits for a cell to the configuration that cell names
+    for name in waiting:
+        awaited = MAN.mix(name)["awaiting_cell"].split(":")[0]
+        config, = [c for c in CONFIGS if awaited.startswith(c + ".")]
+        check_positions(MAN.config(config), MAN.config(config)["deployment"],
+                        MAN.mix(name))
+
+
+def check_positions(config, dep, mix):
+    """No row reaches past what the configuration says it can hold: the
+    longest the mix sends plus one decode round."""
+    assert (buckets.caps(mix)["max_positions"] + dep["span"]
+            <= config["positions_limit"]["value"])
 
 
 def check_cell(man, cell):
@@ -74,12 +100,11 @@ def check_cell(man, cell):
     assert doc["name"] == cell and doc["why"] == entry["why"]
     assert len(entry["why"]) <= 200
     assert entry["chips"] in (1, 4)
-    man.config(doc["config"])
+    config = man.config(doc["config"])
     mix = man.mix(doc["mix"])
     assert doc["arrivals"]["kind"] == "open"
     assert doc["arrivals"]["rate"] > 0 and doc["drain_s"] > 0
-    # no cell reaches past the window the block does not implement
-    assert buckets.caps(mix)["max_positions"] + 8 <= 4096
+    check_positions(config, man.deployment(doc, config), mix)
     # every cell reports setup_s, another end-to-end and a per-layer metric
     e2e = [m["name"] for m in man.metrics_for(cell, "end_to_end")]
     assert "setup_s" in e2e and len(e2e) >= 2
@@ -91,30 +116,86 @@ def test_cell_file_agrees_with_its_manifest_entry(cell):
     check_cell(MAN, cell)
 
 
-@pytest.mark.parametrize("config", CONFIGS)
-def test_config_file_declares_source_cuts_and_departures(config):
-    entry = next(c for c in MAN.doc["configs"] if c["name"] == config)
-    doc = MAN.config(config)
+def is_width(key):
+    return key in WIDTH_KEYS or key.endswith(WIDTH_ENDINGS)
+
+
+def is_expert_count(key):
+    return "experts" in key and not is_width(key)
+
+
+def check_reduced(doc):
+    """``reduced`` names no width, and a count (layers, heads, routed
+    experts, rows of the vocabulary) only as the chip's share of a
+    deployment the file states, within the guide's floors."""
+    reduced = doc["reduced"]
+    assert not [k for k in reduced if is_width(k)], "a width is never cut"
+    counts = [k for k in reduced if type(doc[k]) is int]
+    if not counts:
+        return
+    published = doc["published"]
+    for key in counts:
+        assert 0 < doc[key] < published[key], key
+    shared = [k for k in counts
+              if k in HEADS or k == VOCAB or is_expert_count(k)]
+    if shared:
+        # one number for every count of a layer: the chips that share it
+        chips = doc["shared_by_chips"]
+        assert chips > 1 and doc["layer_divided"]
+        for key in shared:
+            assert doc[key] * chips == published[key], key
+    for key in filter(is_expert_count, counts):
+        assert doc[key] >= MIN_EXPERTS, key
+    if VOCAB in counts:
+        assert doc[VOCAB] * MIN_VOCAB_SHARE >= published[VOCAB]
+    if LAYERS in counts:
+        pattern = doc["layer_pattern"]
+        after = doc[LAYERS] - pattern["leading_dense"]
+        assert after >= MIN_LAYERS and after % pattern["period"] == 0
+
+
+def check_config(man, config, doc=None):
+    """A configuration is held to what it DECLARES — its own head width,
+    its own position cap, its own cuts — not to one model's shape.  (``doc``
+    stands in for the file where a test breaks one rule at a time.)"""
+    entry = next(c for c in man.doc["configs"] if c["name"] == config)
+    doc = doc or man.config(config)
     assert doc["name"] == config and doc["source"] == entry["source"]
     assert entry["file"] == f"bench/configs/{config}.json"
     assert doc["reduced"] == entry["reduced"]
-    assert not set(doc["reduced"]) & set(WIDTH_KEYS)   # no width is cut
-    assert "sizes through the repo's block" in doc["described_as"]
+    check_reduced(doc)
+    # a block that is implemented as published has nothing to depart from
+    # and, with every size given, nothing to assume: the lists may be empty
+    assert isinstance(doc["departures"], list)
+    assert isinstance(doc["assumed"], list)
+    assert ("sizes through the repo's block" in doc["described_as"]) == bool(
+        doc["departures"])
     # the block is the configuration's to name: its reference and needs are
     # files of bench/archs/<arch>/, its unit and the unit's keywords data
-    for module in ("reference", "needs"):
-        assert os.path.isfile(os.path.join(
-            BENCH, "archs", doc["arch"], module + ".py"))
+    reference = arch_module(man.bench, doc, "reference")
+    assert list(inspect.signature(reference.forward).parameters) == [
+        "params", "tokens", "config", "at"]
+    assert reference.row_bytes(doc, 2 * doc["deployment"]["block_size"],
+                               1 + doc["deployment"]["span"]) > 0
     assert doc["unit"]["class_path"]
-    for keyword, value in doc["unit"]["parameters"].items():
-        assert NAME_RE.match(keyword)
-        if isinstance(value, dict):
-            assert list(value) == ["from"] and value["from"] in doc
-    assert doc["departures"] and doc["assumed"] and doc["hbm"]
-    assert doc["hidden_size"] // doc["num_attention_heads"] == 128
+    assert all(NAME_RE.match(k) for k in doc["unit"]["parameters"])
+    # every parameter a key of the file or a literal, and a scalar
+    unit_spec(doc, doc["deployment"], 1, 8)
+    assert doc["hbm"]
+    # the head width is the file's own key where it has one, and the
+    # architecture's arithmetic reads the same
+    hd = doc.get("head_dim", doc["hidden_size"] // doc["num_attention_heads"])
+    assert arch_module(man.bench, doc, "needs").sizes(doc)["hd"] == hd
+    limit = doc["positions_limit"]
+    assert limit["value"] > 0 and limit["why"]
     for key in ("block_size", "span", "slots", "pool_blocks",
                 "prefill_chunk"):
         assert doc["deployment"][key] > 0
+
+
+@pytest.mark.parametrize("config", CONFIGS)
+def test_config_file_declares_source_cuts_and_departures(config):
+    check_config(MAN, config)
 
 
 def check_layer_metric(man, name):
@@ -169,6 +250,17 @@ def test_names_and_units_stay_within_the_allowed_characters():
             assert all(NAME_RE.match(p) for p in rel.split(os.sep)), rel
 
 
+def check_copy(man):
+    """Every parametrised check of this directory, on a copy's entries."""
+    for w in man.doc["workloads"]:
+        check_cell(man, w["name"])
+        bench_paths.check_ladder(man, w["name"])
+    for c in man.doc["configs"]:
+        check_config(man, c["name"])
+    for m in man.doc["per_layer"]:
+        check_layer_metric(man, m["name"])
+
+
 def test_a_cell_config_mix_and_layer_metric_are_added_by_adding_files(
         tmp_path):
     root = bench_paths.copy_root(tmp_path)
@@ -177,10 +269,7 @@ def test_a_cell_config_mix_and_layer_metric_are_added_by_adding_files(
     bench_paths.assert_untouched(before)
     man = Manifest(root)
     # the copy, with its added entries, still agrees file by file
-    for w in man.doc["workloads"]:
-        check_cell(man, w["name"])
-    for m in man.doc["per_layer"]:
-        check_layer_metric(man, m["name"])
+    check_copy(man)
     for kind, names in (
             ("cells", [w["name"] for w in man.doc["workloads"]]),
             ("configs", [c["name"] for c in man.doc["configs"]]),
@@ -227,8 +316,7 @@ def test_an_architecture_is_added_by_adding_files(tmp_path):
     cell = bench_paths.add_tiny_arch(root)
     bench_paths.assert_untouched(before)
     man = Manifest(root)
-    for w in man.doc["workloads"]:
-        check_cell(man, w["name"])
+    check_copy(man)
     assert sorted(os.listdir(os.path.join(root, "bench", "archs"))) == [
         "dense_gelu", "tinyarch"]
     doc = man.cell(cell)
@@ -284,6 +372,163 @@ def test_an_architecture_is_added_by_adding_files(tmp_path):
     # the same trace under the dense block's needs reads something else
     dense = {**ctx, "config": {**cfg, "arch": "dense_gelu"}}
     assert trace.read(man.layer_metric("decode_roofline"), dense) < decode
+
+
+@pytest.fixture(scope="module")
+def moe_root(tmp_path_factory):
+    """The benchmark with three configurations added as files: the dense
+    toys and ``toymoe``, a block that is NOT a smaller StarCoder2."""
+    return bench_paths.toy_root(tmp_path_factory.mktemp("moe"))
+
+
+def test_a_block_that_is_not_the_repos_is_added_by_adding_files(moe_root):
+    """Head width apart from hidden // heads, a router over more experts
+    than are held, a window of its own shorter than a judged row, a chip's
+    share in ``reduced``, nothing departed from and nothing assumed: new
+    files and manifest entries only, and every check of this directory
+    holds on the copy.  (On the parent of PR 31 its case of the
+    configuration test failed ``hidden_size // num_attention_heads ==
+    128``, and so did the dense toy's, at 32.)"""
+    man = Manifest(moe_root)
+    check_copy(man)
+    assert sorted(os.listdir(os.path.join(man.bench, "archs"))) == [
+        "dense_gelu", "tinyarch", "toymoe"]
+    cfg = man.config("toymoe")
+    assert cfg["hidden_size"] // cfg["num_attention_heads"] == 16
+    assert cfg["head_dim"] == 32 != 128
+    assert cfg["reduced"] == ["num_experts"] and cfg["assumed"] == []
+    assert cfg["departures"] == [] and not cfg["tie_word_embeddings"]
+    cell = man.cell("toymoe.tinymix.r80")
+    longest = buckets.caps(man.mix(cell["mix"]))["max_positions"]
+    assert cfg["sliding_window"] < longest < cfg["positions_limit"]["value"]
+    # StarCoder2's 4,096 is nobody else's cap
+    assert cfg["positions_limit"]["value"] != man.config(
+        "starcoder2-3b")["positions_limit"]["value"]
+    # the deployment document carries scalars only, the list stays behind
+    from lib.engine import deployment_doc
+
+    dep = man.deployment(cell, cfg)
+    comp = deployment_doc(cfg, dep, 5, 24)["spec"]["predictors"][0][
+        "components"][0]
+    names = [p["name"] for p in comp["parameters"]]
+    assert "head_dim" in names and "full_every" in names
+    assert "layer_types" not in names
+    assert arch_module(man.bench, cfg, "needs").sizes(cfg)["hd"] == 32
+
+
+def broken(**changes):
+    def apply(doc):
+        for key, value in changes.items():
+            if value is None:
+                doc.pop(key)
+            else:
+                doc[key] = value
+    return apply
+
+
+def sliced_vocab(doc):
+    doc.update(vocab_size=32, reduced=["num_experts", "vocab_size"],
+               shared_by_chips=32, num_experts=1,
+               published={"num_experts": 32, "vocab_size": 1024})
+
+
+@pytest.mark.parametrize("config, change", [
+    ("toymoe", broken(published=None)),
+    ("toymoe", broken(published={})),
+    ("toymoe", broken(shared_by_chips=None)),
+    ("toymoe", broken(shared_by_chips=2)),
+    ("toymoe", broken(layer_divided="")),
+    ("toymoe", broken(num_experts=4, shared_by_chips=8)),
+    ("toymoe", sliced_vocab),
+    ("toymoe", broken(reduced=["num_experts", "head_dim"])),
+    ("toymoe", broken(reduced=["num_experts", "moe_intermediate_size"])),
+    ("toymoe", broken(reduced=["num_experts", "num_experts_per_tok"])),
+    ("toymoe", broken(reduced=["num_experts", "sliding_window"])),
+    ("toymoe", broken(reduced=["num_experts", "num_hidden_layers"],
+                      published={"num_experts": 32,
+                                 "num_hidden_layers": 32})),
+    ("toymoe", broken(reduced=["num_experts", "num_hidden_layers"],
+                      published={"num_experts": 32, "num_hidden_layers": 32},
+                      layer_pattern={"leading_dense": 1, "period": 4})),
+    ("toymoe", broken(departures=["no norm after a sub-layer"])),
+    ("toymoe", broken(described_as="a test's sizes through the repo's "
+                                   "block")),
+    ("toymoe", broken(assumed=None)),
+    ("toymoe", broken(departures=None)),
+    ("tiny", broken(head_dim=64)),
+    ("toymoe", broken(head_dim=None)),
+    ("toymoe", broken(positions_limit=None)),
+    ("toymoe", broken(positions_limit={"value": 128})),
+    ("toymoe", broken(layer_types=None, unit={
+        "class_path": "x:Y", "parameters": {"p": {"from": "published"}}})),
+    ("starcoder2-3b", broken(departures=[])),
+    ("starcoder2-3b", broken(positions_limit=None)),
+    ("starcoder2-3b", broken(reduced=["vocab_size"])),
+], ids=["no-published", "count-not-published", "no-deployment",
+        "chips-times-held-is-not-published", "not-said-how-divided",
+        "under-8-experts", "under-an-eighth-of-the-vocabulary",
+        "head_dim-cut", "expert-width-cut", "experts-per-token-cut",
+        "window-cut", "layers-cut-without-a-pattern",
+        "layers-cut-under-a-period", "departs-without-the-phrase",
+        "the-phrase-without-departing", "assumed-absent",
+        "departures-absent", "head_dim-is-not-the-archs",
+        "head_dim-left-to-hidden-over-heads", "no-positions_limit",
+        "positions_limit-without-its-reason", "a-group-for-a-unit-parameter",
+        "starcoder2-departs-and-says-so", "starcoder2-no-positions_limit",
+        "starcoder2-vocabulary-cut-unstated"])
+def test_a_configuration_that_breaks_one_rule_is_refused(moe_root, config,
+                                                         change):
+    man = Manifest(moe_root)
+    doc = man.config(config)
+    check_config(man, config, doc)          # sound as it stands
+    change(doc)
+    entry = next(c for c in man.doc["configs"] if c["name"] == config)
+    entry["reduced"] = doc["reduced"]       # the entry agrees: the RULE fails
+    with pytest.raises((AssertionError, KeyError, ManifestError)):
+        check_config(man, config, doc)
+
+
+def test_the_chips_share_may_be_layers_heads_experts_and_vocabulary(
+        moe_root):
+    """What section 4 calls the usual cut, stated in full, passes: a whole
+    period and four layers after the leading dense one, 8 of 32 experts,
+    a quarter of the vocabulary and of the heads over 4 chips."""
+    man = Manifest(moe_root)
+    doc = man.config("toymoe")
+    doc.update(
+        reduced=["num_hidden_layers", "num_experts", "vocab_size",
+                 "num_attention_heads", "num_key_value_heads",
+                 "layer_types"],
+        num_hidden_layers=7, layer_types=doc["layer_types"] * 2 + ["x"],
+        layer_pattern={"leading_dense": 1, "period": 3},
+        published={"num_hidden_layers": 31, "num_experts": 32,
+                   "vocab_size": 2048, "num_attention_heads": 16,
+                   "num_key_value_heads": 8})
+    check_reduced(doc)
+    doc["published"]["vocab_size"] = 8 * 512 + 4
+    with pytest.raises(AssertionError):
+        check_reduced(doc)
+
+
+@pytest.mark.parametrize("limit, span, ok", [
+    (96, 8, True), (95, 8, False), (96, 9, False), (4096, 8, True)])
+def test_a_cell_past_its_configurations_positions_limit_is_refused(
+        moe_root, limit, span, ok):
+    """``tinymix`` holds rows of 88 positions: with a round of ``span`` it
+    has to stay within the limit of the CELL'S configuration, whatever
+    another configuration's is."""
+    man = Manifest(moe_root)
+    cell = man.cell("toymoe.tinymix.r80")
+    cfg = man.config("toymoe")
+    cfg["positions_limit"]["value"] = limit
+    dep = {**man.deployment(cell, cfg), "span": span}
+    mix = man.mix(cell["mix"])
+    assert buckets.caps(mix)["max_positions"] == 88
+    if ok:
+        check_positions(cfg, dep, mix)
+    else:
+        with pytest.raises(AssertionError):
+            check_positions(cfg, dep, mix)
 
 
 @pytest.mark.parametrize("config,module,says", [

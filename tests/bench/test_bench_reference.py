@@ -41,6 +41,11 @@ def build(sz, dtype=jnp.float32):
     return cfg, lm_init(jax.random.key(3), cfg)
 
 
+def everywhere(toks):
+    """``at`` for every position of every row: the old contract's logits."""
+    return jnp.broadcast_to(jnp.arange(toks.shape[1]), toks.shape)
+
+
 @pytest.mark.parametrize("sz", SIZES, ids=["gqa2", "gqa3"])
 def test_reference_equals_the_programs_dense_forward(sz):
     from seldon_core_tpu.models.transformer import lm_apply
@@ -49,9 +54,30 @@ def test_reference_equals_the_programs_dense_forward(sz):
     toks = jax.random.randint(jax.random.key(1), (2, 19), 0, cfg.vocab)
     with jax.default_matmul_precision("highest"):
         want = np.asarray(lm_apply(params, toks, cfg))
-    got = np.asarray(reference.forward(params, toks, sz))
+    got = np.asarray(reference.forward(params, toks, sz, everywhere(toks)))
     # float32 against float32: only the order of additions differs
     assert np.abs(got - want).max() < 2e-4 * max(1.0, np.abs(want).max())
+
+
+@pytest.mark.parametrize("sz", SIZES, ids=["gqa2", "gqa3"])
+def test_the_judged_positions_logits_are_the_full_passs_gathered(sz):
+    """``forward(..., at)`` norms and unembeds the gathered positions only;
+    it gives exactly what gathering [B, S, V] afterwards gave (the old
+    contract), repeated and out-of-order positions included."""
+    cfg, params = build(sz)
+    toks = jax.random.randint(jax.random.key(2), (3, 23), 0, cfg.vocab)
+    at = jnp.asarray([[4, 5, 6, 7, 22], [0, 0, 21, 3, 2], [18, 19, 20, 21, 22]])
+    whole = np.asarray(reference.forward(params, toks, sz, everywhere(toks)))
+    got = np.asarray(reference.forward(params, toks, sz, at))
+    assert got.shape == (3, 5, cfg.vocab)
+    assert np.array_equal(got, np.take_along_axis(
+        whole, np.asarray(at)[..., None], axis=1))
+    # what a row holds is asked of the architecture: the judged logits,
+    # not [S, V], and the scores grow with the square of the length
+    assert (reference.row_bytes(sz, 64, 9) - reference.row_bytes(sz, 64, 1)
+            == 8 * 4 * cfg.vocab)
+    assert reference.row_bytes(sz, 4096, 9) > 3 * reference.row_bytes(
+        sz, 2048, 9)
 
 
 # the judged batch of a tiny deployment: four slots, chunks of 16, blocks
@@ -74,8 +100,9 @@ def test_paged_prefill_then_decode_round_agree_with_the_reference(sz):
     assert PLAN["lens"] == [5, 13, 30, 41] and PLAN["chunks"] == [1, 3]
     prompts = children.sample_tokens(PLAN["lens"], cfg.vocab, 0)
     prog = children.run_program(unit_of(cfg), params, DEP, prompts)
-    ref = children.run_reference(reference.forward, params, sz, prompts,
-                                 prog["first"], prog["tokens"])
+    ref = children.run_reference(reference, params, sz, prompts,
+                                 prog["first"], prog["tokens"],
+                                 DEP["block_size"])
     assert ref.shape == (4, 1 + DEP["span"], cfg.vocab)
     rows = children.by_row(ref, prog["logits"], prog["tokens"])
     assert max(rows["prefill_err"]) < 1e-3
@@ -85,7 +112,8 @@ def test_paged_prefill_then_decode_round_agree_with_the_reference(sz):
         seq = np.concatenate([p, prog["first"][i:i + 1],
                               prog["tokens"][i, :-1]])
         alone = np.asarray(reference.forward(
-            params, jnp.asarray(seq[None]), sz))[0]
+            params, jnp.asarray(seq[None]), sz,
+            everywhere(seq[None])))[0]
         assert np.abs(alone[len(p) - 1:] - ref[i]).max() < 1e-4
 
 
@@ -111,11 +139,11 @@ def test_a_lower_precision_than_bf16_would_fail_the_chip_tolerance(
         return jnp.asarray(np.ldexp(np.round(m * 2 ** bits) / 2 ** bits, e),
                            jnp.float32)
 
-    ref = children.run_reference(reference.forward, params, SIZES[0],
-                                 prompts, first, tokens)
-    got = children.run_reference(reference.forward,
-                                 jax.tree.map(rounded, params), SIZES[0],
-                                 prompts, first, tokens)
+    ref = children.run_reference(reference, params, SIZES[0], prompts,
+                                 first, tokens, DEP["block_size"])
+    got = children.run_reference(reference, jax.tree.map(rounded, params),
+                                 SIZES[0], prompts, first, tokens,
+                                 DEP["block_size"])
     rows = children.by_row(ref, got[:, 0], got[:, 1:].argmax(-1))
     v = judge(rows["prefill_err"], rows["decode_margin"],
               {"tolerance_rms": 0.1}, float(np.mean(rows["rms"])))

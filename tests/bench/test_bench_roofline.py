@@ -2,7 +2,7 @@
 and FLOPs for two sets of published sizes, the peaks table, and the bucket
 arithmetic."""
 
-import bench_paths  # noqa: F401
+import bench_paths
 import pytest
 from bench_paths import REPO
 from lib import buckets, roofline
@@ -107,28 +107,4 @@ def test_pow2_is_the_schedulers(n, want):
 
 @pytest.mark.parametrize("cell", [w["name"] for w in MAN.doc["workloads"]])
 def test_the_ladder_takes_every_reachable_bucket_once(cell):
-    doc = MAN.cell(cell)
-    dep = MAN.deployment(doc, MAN.config(doc["config"]))
-    cp = buckets.caps(MAN.mix(doc["mix"]))
-    want_pre, want_dec = buckets.reachable(dep, cp)
-    got_pre, got_dec = set(), set()
-    for length, max_new in buckets.ladder_rows(dep, cp):
-        assert cp["min_prompt"] <= length <= cp["max_prompt"]
-        pre, dec = buckets.touched(length, max_new, dep)
-        got_pre |= pre
-        got_dec |= dec
-    assert want_pre <= got_pre and want_dec <= got_dec
-    # brute force over every request the mix can draw
-    seen_pre, seen_dec = set(), set()
-    for p in range(cp["min_prompt"], cp["max_prompt"] + 1, 7):
-        o = min(cp["max_out"], cp["max_positions"] - p)
-        pre, dec = buckets.touched(p, o, dep)
-        seen_pre |= pre
-        seen_dec |= dec
-    assert seen_pre <= want_pre and seen_dec <= want_dec
-    progs = buckets.programs(dep, cp)
-    rows = buckets.row_buckets(dep["slots"])
-    assert rows[-1] == dep["slots"] and rows[0] == 1
-    assert len(progs["prefill"]) == len(rows) * len(want_pre)
-    assert len(progs["decode"]) == len(rows) * len(want_dec)
-    assert max(want_dec) * dep["block_size"] <= 4096
+    bench_paths.check_ladder(MAN, cell)

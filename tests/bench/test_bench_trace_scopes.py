@@ -226,6 +226,42 @@ def test_reduce_the_recorded_chip_trace():
     assert red["prefill_kv_share"] == pytest.approx(15.999058, rel=1e-6)
 
 
+@pytest.mark.parametrize("program, scopes, old_key", [
+    ("decode", ["kv_write", "kv_gather", "attn"], "decode_kv_share"),
+    ("prefill", ["kv_write", "kv_gather", "attn"], "prefill_kv_share"),
+    ("decode", ["ffn"], None), ("prefill", ["ffn", "unembed"], None),
+    ("decode", ["experts"], None), ("spec", ["attn"], None),
+])
+def test_a_scopes_share_of_a_program_is_data(monkeypatch, program, scopes,
+                                             old_key):
+    """``{"program", "scopes"}`` on the recorded chip trace: the two KV
+    shares read through it EQUAL their old keys; a stage the program names
+    reads its seconds over the module's; a scope no op carries reads 0 of a
+    program that has scopes, and a program that did not run reads nothing."""
+    red = ts.reduce_scopes(recorded_planes())
+    monkeypatch.setitem(reader._REDUCED, "a-trace", red)
+    monkeypatch.setattr(reader, "newest_trace", lambda name: "a-trace")
+    ctx = {"trace": {"busy_s": 1.0}, "cell": {"name": "cell.a"}}
+    got = reader.read(
+        {"formula": {"program": program, "scopes": scopes}}, ctx)
+    prog = red["programs"].get(program)
+    if prog is None:
+        assert got is None
+        return
+    if old_key:
+        assert got == red[old_key] == reader.read(
+            bench_paths.load(os.path.join(
+                bench_paths.BENCH, "layer_metrics", old_key + ".json")), ctx)
+    assert got == 100.0 * sum(
+        prog["by_scope_s"].get(k, 0.0) for k in scopes) / prog["module_s"]
+    assert (got == 0.0) == (scopes == ["experts"])
+    # a program from before the scopes existed: nothing, never 0
+    bare = {"module_s": 0.5, "calls": 3,
+            "by_scope_s": {ts.UNSCOPED: 0.5}}
+    assert ts.scope_share(bare, scopes) is None
+    assert ts.scope_share({"module_s": 0.0, "by_scope_s": {}}, scopes) is None
+
+
 def test_xplane_wire_reader_round_trips_a_hand_encoded_space(tmp_path):
     """lib/xplane.py on a file encoded here by hand: names, line
     timestamps, an event's own stats over its metadata's, a ref value, and

@@ -136,24 +136,52 @@ def test_pick_takes_even_ranks_with_both_ends(n, rows, want):
         assert got == want
 
 
-@pytest.mark.parametrize("vocab, most_rows_at_1033, n_groups", [
-    (49152, 8, 2), (200192, 2, 5)])
+def dense_row_bytes(config):
+    from lib.manifest import arch_module
+
+    reference = arch_module(BENCH, {"arch": "dense_gelu"}, "reference")
+    return lambda length: reference.row_bytes(config, length, 9)
+
+
+LONG_ROWS = [4088 - 128 * i for i in range(32)]        # 4,088 ... 120
+
+
+@pytest.mark.parametrize("vocab, totals, lengths, rows_of_the_longest", [
+    (49152, None, [1280, 768, 512, 256], 1),
+    (200192, None, [1280, 768, 512, 256], 1),
+    (200192, LONG_ROWS, None, 1),
+], ids=["codegen", "codegen-at-200k", "rows-to-4088-at-200k"])
 def test_reference_groups_hold_every_row_once_within_the_budget(
-        vocab, most_rows_at_1033, n_groups):
-    plan = cell_plan()[0]
-    totals = [n + 8 for n in plan["lens"]]
-    groups = sample.reference_groups(totals, vocab)
-    assert sorted(i for g in groups for i in g) == list(range(32))
-    assert len(groups) == n_groups
-    assert len(groups[0]) == most_rows_at_1033
-    for g in groups:
-        longest = max(totals[i] for i in g)
-        assert totals[g[0]] == longest
+        vocab, totals, lengths, rows_of_the_longest):
+    """Groups by padded length, sized by what the ARCHITECTURE says a row
+    holds: at a vocabulary of 200,192 a row of 4,088 positions no longer
+    makes every row a group of its own (its [S, V] logits were 3.27 GB, so
+    ``GROUP_BYTES // (S V 4)`` was 0 for every row over 1,340)."""
+    plan, _, dep, _, cfg = cell_plan()
+    totals = totals or [n + 8 for n in plan["lens"]]
+    row_bytes = dense_row_bytes({**cfg, "vocab_size": vocab})
+    groups = sample.reference_groups(totals, row_bytes, dep["block_size"])
+    assert sorted(i for _, g in groups for i in g) == list(range(32))
+    assert len(groups[0][1]) == rows_of_the_longest
+    if lengths:
+        assert [length for length, _ in groups] == lengths
+    assert max(len(g) for _, g in groups) > 1
+    # a shape the reference compiles is (rows, length): at most one a
+    # padded length and a half of what a shape a row came to
+    shapes = {(len(g), length) for length, g in groups}
+    assert len(shapes) <= len({length for length, _ in groups}) + 1
+    assert len(shapes) <= len(totals) // 2
+    for length, g in groups:
+        assert length % dep["block_size"] == 0
+        assert 0 <= length - max(totals[i] for i in g) < dep["block_size"]
         assert (len(g) == 1
-                or len(g) * longest * vocab * 4 <= sample.GROUP_BYTES)
+                or len(g) * row_bytes(length) <= sample.GROUP_BYTES)
     # neighbours in length together: no group reaches into the next
-    for a, b in zip(groups, groups[1:]):
+    for (_, a), (_, b) in zip(groups, groups[1:]):
         assert min(totals[i] for i in a) >= max(totals[i] for i in b)
+    # the judged positions' logits are 7 MB of what a row holds at a
+    # vocabulary of 200,192, where [S, V] was 3.27 GB at 4,088 positions
+    assert 9 * vocab * 4 < 0.02 * row_bytes(max(totals))
 
 
 # -- a toy block with a real top-k router -------------------------------------
