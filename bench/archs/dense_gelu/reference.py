@@ -84,10 +84,12 @@ def head(embed, ln_f, x, at):
         return x @ embed.astype(jnp.float32).T
 
 
-def forward(params, tokens, config: dict, at):
+def forward(params, tokens, config: dict, at, lengths=None):
     """tokens [B, S] int32, at [B, A] int32 -> the logits after the
     positions ``at`` of each row, [B, A, V] float32, at the sizes ``config``
-    (a configuration file's document) publishes."""
+    (a configuration file's document) publishes.  ``lengths`` [B], each
+    row's true length before the pad, is not read: under a causal mask no
+    judged position sees the pad."""
     x = params["embed"][tokens].astype(jnp.float32)
     for i in range(config["num_hidden_layers"]):
         x = layer(params[f"l{i}"], x,

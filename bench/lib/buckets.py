@@ -2,11 +2,19 @@
 takes each of them once.  Pure arithmetic on the scheduler's own rules
 (runtime/genserver.py): rows and block tables pad to powers of two, a
 prompt is consumed ``prefill_chunk`` tokens at a time, a decode round
-reserves ``span`` more positions.  It re-states those rules because the
-parent may not import the program (it must stay off JAX); it is held to
-the program by tests/bench/test_bench_readers.py (after this ladder a live
-engine compiles nothing under the mix's traffic) and, in every run, by the
-compile counters standing still through the soak and the window."""
+reserves ``span`` more positions.  Two counts of a round are the
+deployment's to state, with the program's where they are absent
+(``after_prefill``): ``prefill_emits``, the tokens a row has emitted when
+its prefill ends (1: the prefill's logits choose the first token; 0 where
+a prefill chooses none), and ``round_quantum``, the multiple of positions
+a row's first round starts on (1: right after the prompt; a generator
+that decodes whole blocks takes the prompt's remainder into its first
+block, and that round emits so many tokens fewer).  It re-states those
+rules because the parent may not import the program (it must stay off
+JAX); it is held to the program by tests/bench/test_bench_readers.py
+(after this ladder a live engine compiles nothing under the mix's traffic)
+and, in every run, by the compile counters standing still through the soak
+and the window."""
 
 from __future__ import annotations
 
@@ -30,6 +38,15 @@ def caps(mix: dict) -> dict:
             "max_out": o["max"], "max_positions": most}
 
 
+def after_prefill(prompt_len: int, dep: dict) -> Tuple[int, int]:
+    """(positions a row's first round starts after, tokens it has emitted
+    by then): the prompt and one token, unless the deployment states
+    ``round_quantum`` / ``prefill_emits``.  A round that starts before the
+    prompt's end emits the prompt's remainder again: so many fewer new."""
+    start = prompt_len - prompt_len % dep.get("round_quantum", 1)
+    return start, dep.get("prefill_emits", 1) - (prompt_len - start)
+
+
 def touched(prompt_len: int, max_new: int, dep: dict
             ) -> Tuple[Set[int], Set[int]]:
     """Block-table widths one row of ``prompt_len`` tokens generating
@@ -41,7 +58,7 @@ def touched(prompt_len: int, max_new: int, dep: dict
         w = min(C, prompt_len - pos)
         pre.add(pow2(blocks(pos + w, bs)))
         pos += w
-    n_valid, emitted = prompt_len, 1
+    n_valid, emitted = after_prefill(prompt_len, dep)
     while emitted < max_new:
         dec.add(pow2(blocks(n_valid + span, bs)))
         n_valid += span
@@ -54,9 +71,12 @@ def reachable(dep: dict, cp: dict) -> Tuple[Set[int], Set[int]]:
     bs, span = dep["block_size"], dep["span"]
     pre = {pow2(blocks(x, bs))
            for x in range(cp["min_prompt"], cp["max_prompt"] + 1)}
-    last = cp["max_positions"] - 2 + span
-    dec = {pow2(blocks(x, bs))
-           for x in range(cp["min_prompt"] + span, last + 1)}
+    # a round starts on a multiple of the quantum and is whole quanta long;
+    # the last one starts before the row's last token is out
+    quantum = dep.get("round_quantum", 1)
+    first = after_prefill(cp["min_prompt"], dep)[0] + span
+    last = cp["max_positions"] - 1 - dep.get("prefill_emits", 1) + span
+    dec = {pow2(blocks(x, bs)) for x in range(first, last + 1, quantum)}
     return pre, dec
 
 
@@ -75,10 +95,13 @@ def ladder_rows(dep: dict, cp: dict) -> List[Tuple[int, int]]:
     bs = dep["block_size"]
     want_pre, want_dec = reachable(dep, cp)
     cands = {cp["min_prompt"], cp["max_prompt"]}
+    quantum = dep.get("round_quantum", 1)
     for u in sorted(want_pre | want_dec):
         for L in ((u // 2) * bs + 1, (u // 2) * bs + 1 - dep["span"]):
-            if cp["min_prompt"] <= L <= cp["max_prompt"]:
-                cands.add(L)
+            # ... and the next length whose first round starts there
+            for L in (L, -(-L // quantum) * quantum):
+                if cp["min_prompt"] <= L <= cp["max_prompt"]:
+                    cands.add(L)
     chosen: List[Tuple[int, int]] = []
     left_pre, left_dec = set(want_pre), set(want_dec)
     while left_pre or left_dec:

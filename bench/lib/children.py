@@ -4,10 +4,12 @@ JSON object as its last stdout line.
   numerics    a batch the deployment really runs (lib/sample.py: as many
               rows as it has slots, at the lengths of the window's own
               schedule) through the program's ``paged_forward``, chunk by
-              chunk as the scheduler prefills, then one
-              ``paged_decode_round`` over all rows — against the plain
-              reference of the block the configuration names
-              (archs/<arch>/reference.py), judged row by row
+              chunk as the scheduler prefills, then one round over all
+              rows AS THE ARCHITECTURE DRIVES IT (archs/<arch>/drive.py;
+              where it brings none, ``one_token_a_step``) — against the
+              plain reference the configuration names
+              (archs/<arch>/reference.py), asked for the logits of every
+              judged EVENT of that round, judged row by row
               (lib/verdict.py).
   limits      the readings a limit is set from, in one process: over the
               spec's seeds the program's per-row numbers, and the
@@ -15,6 +17,15 @@ JSON object as its last stdout line.
               one scale a matrix, in the program's place.
 
     python bench/lib/children.py numerics|limits <spec.json>
+
+An event is one pass of the program over one row, as the round's driver
+reports it: ``{"row", "ids", "at", "chose"[, "prefill"]}`` — ``ids`` the
+context as that pass saw it (a mask id where one stood; its true length is
+``len(ids)``), ``at`` the positions whose logits the reference is asked
+for, ``chose`` the id the program put where each of them decides, or
+``NOT_JUDGED``.  One event a row carries ``"prefill": j``: ``at[j]`` is
+where the logits the prefill itself returned for the row are held against
+the reference's.
 """
 
 from __future__ import annotations
@@ -65,28 +76,38 @@ def build_unit(unit: dict):
         [Parameter.from_json_dict(d) for d in unit["parameters"]]))
 
 
-def sample_tokens(lens: list, vocab: int, seed: int) -> list:
+NOT_JUDGED = -1
+
+
+def sample_tokens(lens: list, vocab: int, seed: int, reserved=()) -> list:
+    """The judged rows' ids: uniform over the ids the configuration does
+    not reserve (lib/traffic.py ``skip_reserved``)."""
     import numpy as np
 
+    from lib.traffic import skip_reserved
+
     rng = np.random.default_rng(seed)
-    return [rng.integers(0, vocab, n).astype(np.int32) for n in lens]
+    return [skip_reserved(rng.integers(0, vocab - len(reserved), n),
+                          reserved).astype(np.int32) for n in lens]
 
 
-def run_program(unit, params, dep: dict, prompts: list) -> dict:
+def run_program(unit, params, dep: dict, prompts: list, drive=None) -> dict:
     """The timed programs over the judged rows, as the scheduler drives
     them (runtime/genserver.py ``_prefill_tick``, ``_decode_round``): one
     ``paged_forward`` a chunk over the rows still prefilling, ``start``
     advancing per row, rows and tables padded to powers of two — the
-    shapes the cell's ladder loads; then one decode round of ``span``
-    steps over all rows.  Returns each row's logits from the call that
-    consumed its last prompt token, its first token and the round's."""
+    shapes the cell's ladder loads; then one round of ``span`` positions
+    over all rows, driven by ``drive`` (the architecture's
+    archs/<arch>/drive.py; ``one_token_a_step`` where it brings none).
+    Returns each row's logits from the call that consumed its last prompt
+    token, and what the driver returns: the tokens the round emitted for
+    each row and its judged events."""
     import jax.numpy as jnp
     import numpy as np
 
     from lib.buckets import blocks, pow2
     from seldon_core_tpu.models.generate import (
         init_block_pool,
-        paged_decode_round_jit,
         paged_forward_jit,
     )
 
@@ -136,8 +157,40 @@ def run_program(unit, params, dep: dict, prompts: list) -> dict:
                 host = np.asarray(logits) if host is None else host
                 sys_logits[r] = host[i]
     sys_logits = np.stack(sys_logits)
-    first = sys_logits.argmax(-1).astype(np.int32)
-    B = pow2(R)
+    table = tables(list(range(R)), [n + span for n in lens], pow2(R))
+    return {"logits": sys_logits,
+            **(drive or one_token_a_step)(unit, params, pool, table, prompts,
+                                          sys_logits, dep)}
+
+
+def teacher_forced(prompts: list, first, tokens) -> list:
+    """One event a row for a round of single-token steps: the prompt, the
+    first token and the round's in ONE context — position n-1 is where the
+    prefill's logits stood, n .. n+span-1 where each step chose."""
+    import numpy as np
+
+    span = tokens.shape[1]
+    return [{"row": r, "ids": np.concatenate([p, first[r:r + 1],
+                                              tokens[r, :-1]]),
+             "at": len(p) - 1 + np.arange(1 + span),
+             "chose": np.concatenate([[NOT_JUDGED], tokens[r]]),
+             "prefill": 0} for r, p in enumerate(prompts)]
+
+
+def one_token_a_step(unit, params, pool, tables, prompts: list, logits,
+                     dep: dict) -> dict:
+    """The round of an architecture that brings no ``drive.py``: the first
+    token is the argmax of the prefill's logits, one
+    ``paged_decode_round`` of ``span`` single-token steps follows over all
+    rows (tables of the rows' prompt + span positions, padded as the
+    scheduler pads), and a row is one event, ``teacher_forced``."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    from seldon_core_tpu.models.generate import paged_decode_round_jit
+
+    R, B = len(prompts), tables.shape[0]
+    first = logits.argmax(-1).astype(np.int32)
 
     def padded(values, dtype):
         out = np.zeros((B,), dtype)
@@ -145,64 +198,103 @@ def run_program(unit, params, dep: dict, prompts: list) -> dict:
         return jnp.asarray(out)
 
     out, pool, *_ = paged_decode_round_jit(
-        params, pool, tables(list(range(R)), [n + span for n in lens], B),
-        padded(first, np.int32), padded(lens, np.int32),
+        params, pool, tables, padded(first, np.int32),
+        padded([len(p) for p in prompts], np.int32),
         padded(True, bool), jnp.zeros((B,), bool),
-        jnp.zeros((B,), jnp.uint32), cfg, span=span,
+        jnp.zeros((B,), jnp.uint32), unit.cfg, span=dep["span"],
         temperature=unit.temperature, top_k=unit.top_k, top_p=unit.top_p,
         eos_token=unit.eos_token)
-    sys_toks = np.asarray(out)[:R]            # [R, span]
-    del pool
-    return {"logits": sys_logits, "first": first, "tokens": sys_toks}
+    toks = np.asarray(out)[:R]            # [R, span]
+    return {"first": first, "tokens": toks,
+            "events": teacher_forced(prompts, first, toks)}
 
 
-def run_reference(reference, params, config: dict, prompts: list,
-                  first, tokens, quantum: int):
-    """The reference's logits at the judged positions, [R, 1 + span, V]:
-    each row's prompt, first token and round through ONE full causal pass
-    (teacher-forced on the program's own tokens) — position n-1 gives the
-    prefill logits, n .. n+span-1 the logits each decode step chose from.
-    ``reference`` is the architecture's module: its ``forward`` is handed
-    the judged positions (``at``) and unembeds those alone, its
-    ``row_bytes`` says what a row holds, by which lib/sample.py groups the
-    rows; a group is right-padded to a multiple of ``quantum``."""
+def run_reference(reference, params, config: dict, events: list,
+                  quantum: int):
+    """The reference's logits at every event's positions, [E, A, V] (A the
+    most positions an event asks for; an event's own come first).  Each
+    event's ``ids`` go through the reference as one row of its own, told
+    its true length: ``reference`` is the architecture's module, its
+    ``forward`` is handed the positions (``at``) and the lengths
+    (``lengths``: a mask that is not causal keeps the pad out by them) and
+    unembeds those positions alone, its ``row_bytes`` says what a row
+    holds, by which lib/sample.py groups the events; a group is
+    right-padded to a multiple of ``quantum``."""
     import jax.numpy as jnp
     import numpy as np
 
     from lib.sample import reference_groups
 
-    seqs = [np.concatenate([p, first[i:i + 1], tokens[i, :-1]])
-            for i, p in enumerate(prompts)]
-    span = tokens.shape[1]
-    out = np.zeros((len(seqs), 1 + span, config["vocab_size"]), np.float32)
+    judged = max(len(e["at"]) for e in events)
+    out = np.zeros((len(events), judged, config["vocab_size"]), np.float32)
     groups = reference_groups(
-        [len(s) for s in seqs],
-        lambda length: reference.row_bytes(config, length, 1 + span),
+        [len(e["ids"]) for e in events],
+        lambda length: reference.row_bytes(config, length, judged),
         quantum)
     for length, rows in groups:
         toks = np.zeros((len(rows), length), np.int32)
-        at = np.zeros((len(rows), 1 + span), np.int32)
-        for i, r in enumerate(rows):
-            toks[i, :len(seqs[r])] = seqs[r]
-            at[i] = len(prompts[r]) - 1 + np.arange(1 + span)
+        at = np.zeros((len(rows), judged), np.int32)
+        lengths = np.zeros((len(rows),), np.int32)
+        for i, k in enumerate(rows):
+            e = events[k]
+            lengths[i] = len(e["ids"])
+            toks[i, :lengths[i]] = e["ids"]
+            at[i, :len(e["at"])] = e["at"]
         out[rows] = np.asarray(reference.forward(
-            params, jnp.asarray(toks), config, jnp.asarray(at)))
+            params, jnp.asarray(toks), config, jnp.asarray(at),
+            jnp.asarray(lengths)))
     return out
 
 
-def by_row(ref, logits, chosen) -> dict:
-    """Per row: ``prefill_err`` = max |Δ| of the logits after its last
-    prompt token; ``decode_margin`` = the worst, over the round's steps,
-    of the reference's best logit minus its logit of the token chosen;
-    ``rms`` of the reference's prefill logits."""
+def prefill_rows(events: list, ref):
+    """[R, V]: of ``ref`` [E, A, V], each row's logits where its prefill's
+    own are held against the reference (the event that says ``prefill``)."""
     import numpy as np
 
-    step = ref[:, 1:]                                   # [R, span, V]
-    took = np.take_along_axis(step, chosen[..., None], -1)[..., 0]
+    found = {e["row"]: ref[k, e["prefill"]]
+             for k, e in enumerate(events) if "prefill" in e}
+    return np.stack([found[r] for r in range(len(found))])
+
+
+def without(logits, reserved):
+    """``logits`` with the ids no answer may hold out of the running."""
+    if not len(reserved):
+        return logits
+    logits = logits.copy()
+    logits[..., list(reserved)] = -float("inf")
+    return logits
+
+
+def by_row(events: list, ref, logits, chose=None, reserved=()) -> dict:
+    """Per row: ``prefill_err`` = max |Δ| of ``logits`` (what the prefill
+    returned for the row) against the reference where the row's event says
+    ``prefill``; ``decode_margin`` = the worst, over the row's judged
+    (event, position) pairs, of the reference's best logit among the ids an
+    answer may hold minus its logit of the id chosen; ``rms`` of the
+    reference's prefill logits.  ``chose`` [E, A] stands in for the events'
+    own ids where another's choices are judged at the same positions (the
+    control, lib ``limits``)."""
+    import numpy as np
+
+    want = prefill_rows(events, ref)
+    margin = [[] for _ in want]
+    for k, e in enumerate(events):
+        n = len(e["at"])
+        ids = np.asarray(e["chose"] if chose is None else chose[k][:n])
+        at = np.flatnonzero(np.asarray(e["chose"]) != NOT_JUDGED)
+        step = ref[k, at]                                    # [judged, V]
+        took = np.take_along_axis(step, ids[at][:, None], -1)[:, 0]
+        margin[e["row"]].extend(without(step, reserved).max(-1) - took)
     return {
-        "prefill_err": np.abs(ref[:, 0] - logits).max(-1).tolist(),
-        "decode_margin": (step.max(-1) - took).max(-1).tolist(),
-        "rms": np.sqrt((ref[:, 0] ** 2).mean(-1)).tolist()}
+        "prefill_err": np.abs(want - logits).max(-1).tolist(),
+        "decode_margin": [float(np.max(m)) for m in margin],
+        "rms": np.sqrt((want ** 2).mean(-1)).tolist()}
+
+
+def reserved_emitted(tokens, reserved) -> int:
+    """How many of the ids the round emitted no answer may hold: an exact
+    check, limit 0."""
+    return sum(int(t) in reserved for row in tokens for t in row)
 
 
 def fp8_rounded(params: dict) -> dict:
@@ -248,24 +340,28 @@ def _peak() -> int:
 def compare(spec: dict, unit, params, token_seed: int) -> dict:
     """The judged batch through the program, then through the reference:
     every row's numbers and the verdict over them."""
-    from lib.manifest import arch_module
+    from lib.manifest import arch_module, reserved_ids
     from lib.verdict import judge
 
     config = spec["config"]
     reference = arch_module(spec["bench_dir"], config, "reference")
+    driver = arch_module(spec["bench_dir"], config, "drive", optional=True)
+    reserved = reserved_ids(config)
     prompts = sample_tokens(spec["sample"]["lens"], config["vocab_size"],
-                            token_seed)
+                            token_seed, reserved)
     t0 = time.monotonic()
-    prog = run_program(unit, params, spec["deployment"], prompts)
+    prog = run_program(unit, params, spec["deployment"], prompts,
+                       driver and driver.drive)
     # the program's peak: the reference comes after it and is not counted
     peak = _peak()
     t1 = time.monotonic()
-    ref = run_reference(reference, params, config, prompts, prog["first"],
-                        prog["tokens"], spec["deployment"]["block_size"])
-    rows = by_row(ref, prog["logits"], prog["tokens"])
+    ref = run_reference(reference, params, config, prog["events"],
+                        spec["deployment"]["block_size"])
+    rows = by_row(prog["events"], ref, prog["logits"], reserved=reserved)
     rms = sum(rows["rms"]) / len(rows["rms"])
-    return {"reference": reference, "prompts": prompts, "prog": prog,
+    return {"reference": reference, "reserved": reserved, "prog": prog,
             "ref": ref, "rows": rows, "rms": rms, "memory_peak_bytes": peak,
+            "reserved_emitted": reserved_emitted(prog["tokens"], reserved),
             "verdict": judge(rows["prefill_err"], rows["decode_margin"],
                              config["numerics"], rms),
             "seconds": {"program": t1 - t0,
@@ -278,7 +374,9 @@ def numerics(spec: dict, device: dict) -> dict:
                 spec["sample_seed"])
     v, sample = c["verdict"], spec["sample"]
     return {
-        "device": device, "ref_logit_rms": c["rms"], "ok": v["ok"],
+        "device": device, "ref_logit_rms": c["rms"],
+        "ok": v["ok"] and not c["reserved_emitted"],
+        "reserved_emitted": c["reserved_emitted"],
         "prefill_max_abs_err": v["prefill"]["max"],
         "decode_max_margin": v["decode"]["max"],
         "tolerance": v["tolerance"], "verdict": v,
@@ -302,11 +400,11 @@ def limits(spec: dict, device: dict) -> dict:
         entry = {"seed": seed, "ref_logit_rms": c["rms"],
                  "program": c["rows"], "program_verdict": c["verdict"]}
         if seed in spec["control_seeds"]:
+            events = c["prog"]["events"]
             ctl = run_reference(c["reference"], fp8_rounded(params), config,
-                                c["prompts"], c["prog"]["first"],
-                                c["prog"]["tokens"],
-                                spec["deployment"]["block_size"])
-            rows = by_row(c["ref"], ctl[:, 0], ctl[:, 1:].argmax(-1))
+                                events, spec["deployment"]["block_size"])
+            rows = by_row(events, c["ref"], prefill_rows(events, ctl),
+                          without(ctl, c["reserved"]).argmax(-1))
             if not max(rows["prefill_err"]) > 0.0:
                 raise SystemExit("the control reads what the reference "
                                  "reads: its weights were not rounded")

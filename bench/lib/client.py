@@ -122,10 +122,12 @@ async def stream_once(port: int, body: bytes, now: Callable[[], float],
     return rec
 
 
-def finish_record(req, rec: dict, vocab: int, seconds: float) -> dict:
+def finish_record(req, rec: dict, vocab: int, seconds: float,
+                  reserved=()) -> dict:
     """One request's line of the run: times relative to the window, the
-    checks every answer gets (exactly ``out_len`` ids, all in range) and
-    its TTFT / TPOT.  A request that failed, was refused or had not
+    checks every answer gets (exactly ``out_len`` ids, all in range and
+    none that the configuration reserves: a mask id is never an answer)
+    and its TTFT / TPOT.  A request that failed, was refused or had not
     finished when the window ended at ``seconds`` is charged as if its
     missing chunks arrived at that instant: a first token it never got
     counts at the window's end, and its time per output token is the time
@@ -133,7 +135,8 @@ def finish_record(req, rec: dict, vocab: int, seconds: float) -> dict:
     over the tokens it did get.  So a tail over all requests SENT gets
     worse when requests fail, never better."""
     toks = rec["tokens"][0] if rec["tokens"] else []
-    in_range = all(float(t) == int(t) and 0 <= int(t) < vocab for t in toks)
+    in_range = all(float(t) == int(t) and 0 <= int(t) < vocab
+                   and int(t) not in reserved for t in toks)
     ok = (rec["status"] == 200 and rec["done"] and rec["error"] is None
           and len(toks) == req.out_len and in_range)
     out = {
@@ -160,7 +163,8 @@ def finish_record(req, rec: dict, vocab: int, seconds: float) -> dict:
 
 async def run_open_loop(port: int, requests: list, bodies: List[bytes],
                         vocab: int, t0: float, seconds: float,
-                        inflight_samples: Optional[list] = None) -> List[dict]:
+                        inflight_samples: Optional[list] = None,
+                        reserved=()) -> List[dict]:
     """Offer every request at ``t0 + due_s`` (``time.monotonic`` clock) and
     stop reading at ``t0 + seconds``: what has not finished by then has
     failed."""
@@ -180,7 +184,7 @@ async def run_open_loop(port: int, requests: list, bodies: List[bytes],
                                     max(seconds - now(), 0.05))
         finally:
             inflight[0] -= 1
-        records[i] = finish_record(req, rec, vocab, seconds)
+        records[i] = finish_record(req, rec, vocab, seconds, reserved)
 
     async def sampler():
         while True:

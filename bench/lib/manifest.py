@@ -34,12 +34,14 @@ def _load(path: str) -> dict:
 _ARCH_MODULES: dict = {}     # file path -> module
 
 
-def arch_module(bench: str, config: dict, module: str):
+def arch_module(bench: str, config: dict, module: str, optional=False):
     """``<bench>/archs/<config["arch"]>/<module>.py`` as a module: the
-    plain reference (``reference``) or the bytes and FLOPs a program needs
-    (``needs``) of the block a configuration declares.  A configuration
+    plain reference (``reference``), the bytes and FLOPs a program needs
+    (``needs``) or how a round is driven and what of it is judged
+    (``drive``) of the block a configuration declares.  A configuration
     without the key, or a key without its file, is an error: there is no
-    default block."""
+    default block — but for a file an architecture may leave out
+    (``optional``: ``drive``), where ``None`` comes back."""
     arch = config.get("arch")
     if not arch or not NAME_RE.match(str(arch)):
         raise ManifestError(
@@ -47,6 +49,8 @@ def arch_module(bench: str, config: dict, module: str):
             f"(a directory of {os.path.join(bench, 'archs')})")
     path = os.path.join(bench, "archs", arch, module + ".py")
     if not os.path.isfile(path):
+        if optional:
+            return None
         raise ManifestError(f"arch {arch!r}: no such file: {path}")
     if path not in _ARCH_MODULES:
         spec = importlib.util.spec_from_file_location(
@@ -55,6 +59,13 @@ def arch_module(bench: str, config: dict, module: str):
         spec.loader.exec_module(mod)
         _ARCH_MODULES[path] = mod
     return _ARCH_MODULES[path]
+
+
+def reserved_ids(config: dict) -> tuple:
+    """The ids a configuration keeps out of the traffic and out of every
+    answer (``reserved_ids``: ``[{"id", "why"}, ...]``, a mask id, a pad);
+    none where the key is absent."""
+    return tuple(r["id"] for r in config.get("reserved_ids", ()))
 
 
 class Manifest:

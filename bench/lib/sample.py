@@ -1,6 +1,7 @@
 """The batch the numerics child judges, planned off the chip: which rows,
 at which lengths, over which blocks, and how the plain reference takes
-them.  Pure arithmetic (the parent may not import JAX).
+the judged events of their round (lib/children.py says what an event is).
+Pure arithmetic (the parent may not import JAX).
 
 The rows are a batch the deployment really runs: as many as it has
 ``slots`` (at most ``MAX_ROWS``), their lengths the prompt lengths of the
@@ -59,15 +60,18 @@ def plan(prompts: Sequence[int], dep: dict, max_positions: int) -> dict:
 def reference_groups(totals: Sequence[int], row_bytes: Callable[[int], int],
                      quantum: int) -> List[Tuple[int, List[int]]]:
     """``(length, row indices)`` groups the reference takes one at a time,
-    the longest first.  A group's rows are right-padded to ``length``, the
-    next multiple of ``quantum`` (the deployment's block size) at or over
-    its longest row: a causal pass is unchanged before the pad, and rows of
-    nearby lengths share a compiled shape, whatever the schedule's exact
-    lengths.  Rows of one length go together as far as ``GROUP_BYTES``
-    holds them by ``row_bytes(length)`` — what a row of the reference holds
-    at that length, asked of the architecture, since a wide vocabulary, a
-    long row's scores or an expert layer's hidden each dominate somewhere;
-    one row alone may pass it."""
+    the longest first; a row is one judged event's context.  A group's rows
+    are right-padded to ``length``, the next multiple of ``quantum`` (the
+    deployment's block size) at or over its longest row, so rows of nearby
+    lengths share a compiled shape, whatever the schedule's exact lengths.
+    Whether the pad is harmless is the reference's to see to: it is told
+    each row's true length beside the positions asked for, and one whose
+    mask lets a position look ahead keeps the pad out by it.  Rows of one
+    length go together as far as ``GROUP_BYTES`` holds them by
+    ``row_bytes(length)`` — what a row of the reference holds at that
+    length, asked of the architecture, since a wide vocabulary, a long
+    row's scores or an expert layer's hidden each dominate somewhere; one
+    row alone may pass it."""
     by_length: dict = {}
     for i in sorted(range(len(totals)), key=lambda i: -totals[i]):
         by_length.setdefault(-(-totals[i] // quantum) * quantum, []).append(i)

@@ -1,6 +1,7 @@
 """The one traffic generator.  A mix is a data file of parameters
 (bench/traffic/<mix>.json); a schedule is a pure function of mix and
-arrival parameters, and the token ids a pure function of the seed.
+arrival parameters, and the token ids a pure function of the seed and
+of the ids the configuration reserves.
 
 The lengths and gaps are the stratified quantiles of the mix's
 distributions, in ONE order, and the order is BALANCED: the arrivals are
@@ -125,10 +126,23 @@ def open_loop(mix: dict, rate: float, seconds: float, drain_s: float
     return [Request(i, t, p, o, m) for i, (t, p, o, m) in enumerate(parts)]
 
 
-def prompt_tokens(seed: int, index: int, length: int, vocab: int
-                  ) -> List[int]:
-    """Token ids uniform in the vocabulary; no two requests share a prefix
+def skip_reserved(drawn: np.ndarray, reserved) -> np.ndarray:
+    """``drawn`` uniform in ``[0, vocab - len(reserved))`` moved onto the
+    ids that are not reserved, in order: uniform over them, and with
+    nothing reserved the draw as it stands."""
+    for r in sorted(reserved):
+        drawn = drawn + (drawn >= r)
+    return drawn
+
+
+def prompt_tokens(seed: int, index: int, length: int, vocab: int,
+                  reserved=()) -> List[int]:
+    """Token ids uniform over the vocabulary's ids that the configuration
+    does not reserve (``reserved_ids``: a mask id arriving in a prompt
+    would be a hole to fill, not a token); no two requests share a prefix
     beyond chance."""
     rng = np.random.default_rng([int(seed) & 0xFFFFFFFF, int(seed) >> 32,
                                  int(index)])
-    return rng.integers(0, vocab, size=length, dtype=np.int64).tolist()
+    return skip_reserved(
+        rng.integers(0, vocab - len(reserved), size=length, dtype=np.int64),
+        reserved).tolist()

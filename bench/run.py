@@ -45,7 +45,7 @@ from lib.engine import (  # noqa: E402
     run_child,
     unit_spec,
 )
-from lib.manifest import Manifest  # noqa: E402
+from lib.manifest import Manifest, reserved_ids  # noqa: E402
 
 PROBE_LEN, PROBE_NEW = 40, 16
 
@@ -78,6 +78,7 @@ class Run:
         self.dep = self.man.deployment(self.cell, self.config)
         self.caps = buckets.caps(self.mix)
         self.vocab = self.config["vocab_size"]
+        self.reserved = reserved_ids(self.config)
         self.out_dir = os.path.join(self.man.bench, "out")
         self.run_dir = os.path.join(
             self.out_dir, f"{self.cell_name}.{args.seed}.t{args.trace}")
@@ -174,7 +175,7 @@ class Run:
         for b in buckets.row_buckets(self.dep["slots"]):
             for length, max_new in rows:
                 body = client.rows_body(
-                    [traffic.prompt_tokens(7, n * 64 + r, length, self.vocab)
+                    [self.tokens(7, n * 64 + r, length)
                      for r in range(b)], max_new, self.dep["span"])
                 rec = await client.stream_once(
                     self.engine.port, body, time.monotonic, 900.0)
@@ -189,7 +190,7 @@ class Run:
 
     async def probe(self) -> list:
         body = client.stream_body(
-            traffic.prompt_tokens(11, 0, PROBE_LEN, self.vocab),
+            self.tokens(11, 0, PROBE_LEN),
             PROBE_NEW, self.dep["span"])
         rec = await client.stream_once(self.engine.port, body,
                                        time.monotonic, 300.0)
@@ -197,9 +198,13 @@ class Run:
             raise EngineFailure(f"probe request failed: {rec}")
         return rec["tokens"][0]
 
+    def tokens(self, seed: int, index: int, length: int) -> list:
+        return traffic.prompt_tokens(seed, index, length, self.vocab,
+                                     self.reserved)
+
     def bodies(self, requests: list, seed: int) -> list:
         return [client.stream_body(
-            traffic.prompt_tokens(seed, r.index, r.prompt_len, self.vocab),
+            self.tokens(seed, r.index, r.prompt_len),
             r.out_len, self.dep["span"]) for r in requests]
 
     def requests(self, seconds: float) -> list:
@@ -217,7 +222,7 @@ class Run:
     async def offer(self, plan: dict, seconds: float, samples=None) -> list:
         return await client.run_open_loop(
             self.engine.port, plan["requests"], plan["bodies"],
-            self.vocab, time.monotonic(), seconds, samples)
+            self.vocab, time.monotonic(), seconds, samples, self.reserved)
 
     async def soak(self) -> None:
         """The cell's own traffic from a warm-up seed until the compile
@@ -456,8 +461,12 @@ def main() -> int:
     except EngineFailure as e:
         sys.stderr.write(f"run failed: {e}\n")
         return 3
-    if not num["ok"]:
+    if not num["verdict"]["ok"]:
         run.notes.append(f"numerics child: {num['verdict']}")
+    if num["reserved_emitted"]:
+        run.notes.append(f"numerics child: the round emitted "
+                         f"{num['reserved_emitted']} ids the configuration "
+                         "reserves")
     if num["device"] != run.device:
         run.notes.append(f"numerics ran on {num['device']}, the engine on "
                          f"{run.device}")
@@ -468,7 +477,7 @@ def main() -> int:
                    and r["error"] is None]
     if bad_answers:
         run.notes.append(f"{len(bad_answers)} answers of the wrong length "
-                         "or with ids out of range")
+                         "or with ids out of range or reserved")
     # every number ``correct`` rests on, beside its limit: the benchmark's
     # contract asks every run to print them (read in the driver's run logs)
     pre, dec = num["verdict"]["prefill"], num["verdict"]["decode"]
@@ -484,7 +493,10 @@ def main() -> int:
         "compiles_in_window": [res["compiles"]["after"]["compiles"]
                                - res["compiles"]["before"]["compiles"], 0],
         "probe_moved": [res["probe_moved"], 0],
-        "answers_wrong": [len(bad_answers), 0]})
+        "answers_wrong": [len(bad_answers), 0],
+        # compared only where the configuration reserves an id
+        **({"reserved_emitted": [num["reserved_emitted"], 0]}
+           if run.reserved else {})})
     say(compared)
     device = {**run.device,
               "memory_peak_bytes": max(res["hbm_peak"],

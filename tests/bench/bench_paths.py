@@ -135,7 +135,7 @@ def _hd(config):
                       // config["num_attention_heads"])
 
 
-def forward(params, tokens, config, at):
+def forward(params, tokens, config, at, lengths):
     H, KV = config["num_attention_heads"], config["num_key_value_heads"]
     eps, theta, hd = config["rms_norm_eps"], config["rope_theta"], _hd(config)
     with jax.default_matmul_precision("highest"):
@@ -355,7 +355,7 @@ def _experts(h, lp, config):
     return jnp.einsum("bsk,bske,bsed->bsd", weight, onehot, every)
 
 
-def forward(params, tokens, config, at):
+def forward(params, tokens, config, at, lengths):
     H, KV = config["num_attention_heads"], config["num_key_value_heads"]
     hd, eps = config["head_dim"], config["rms_norm_eps"]
     with jax.default_matmul_precision("highest"):
@@ -538,13 +538,274 @@ def add_toy_moe(root: str) -> str:
     return cell
 
 
+# -- the third toy architecture: a round that is NOT one token a step ---------
+
+TOYBLOCKDIFF_REFERENCE = '''"""A test's generator by diffusion over blocks, as its "source" has it: the
+Qwen3 block at a toy's sizes — RMSNorm before each sub-layer, bias-free q,
+k, v of ``head_dim`` a head with an RMSNorm a head on q and k, rotary
+embedding, GQA, a gated-SiLU FFN, an untied head — under a BLOCK-CAUSAL
+mask: a position sees every key up to the end of its own block of
+``block_length``, the later ones of that block too.  No cache: one pass
+over a context as a pass of the program saw it (mask ids where they
+stood).  A row's last block may be short (a prompt that is no whole number
+of blocks), and then what follows it in a padded group is no key of the
+row: ``lengths`` keeps the pad out."""
+
+import math
+
+import jax
+import jax.numpy as jnp
+
+
+def _norm(x, w, eps):
+    return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps) * w
+
+
+def _rope(x, theta):
+    half = x.shape[-1] // 2
+    ang = (jnp.arange(x.shape[1], dtype=jnp.float32)[:, None]
+           * theta ** (-jnp.arange(half, dtype=jnp.float32) / half))
+    cos, sin = jnp.cos(ang)[None, :, None], jnp.sin(ang)[None, :, None]
+    a, b = x[..., :half], x[..., half:]
+    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin], -1)
+
+
+def forward(params, tokens, config, at, lengths):
+    H, KV = config["num_attention_heads"], config["num_key_value_heads"]
+    hd, eps, theta = (config["head_dim"], config["rms_norm_eps"],
+                      config["rope_theta"])
+    with jax.default_matmul_precision("highest"):
+        p = jax.tree.map(lambda a: a.astype(jnp.float32), params)
+        x = p["embed"][tokens]
+        B, S, D = x.shape
+        here = jnp.arange(S)
+        block_end = (here // config["block_length"] + 1) * config[
+            "block_length"]
+        seen = here[None, :] < block_end[:, None]                 # [S, S]
+        seen = seen[None] & (here[None, None, :] < lengths[:, None, None])
+        for i in range(config["num_hidden_layers"]):
+            lp = p[f"l{i}"]
+            h = _norm(x, lp["ln1"], eps)
+            q = _norm((h @ lp["wq"]).reshape(B, S, H, hd), lp["q_norm"], eps)
+            k = _norm((h @ lp["wk"]).reshape(B, S, KV, hd), lp["k_norm"], eps)
+            v = (h @ lp["wv"]).reshape(B, S, KV, hd)
+            q, k = _rope(q, theta), _rope(k, theta)
+            k, v = (jnp.repeat(t, H // KV, axis=2) for t in (k, v))
+            s = jnp.einsum("bqhd,bkhd->bhqk", q, k) / math.sqrt(hd)
+            s = jnp.where(seen[:, None], s, -jnp.inf)
+            a = jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, -1), v)
+            x = x + a.reshape(B, S, H * hd) @ lp["wo"]
+            h = _norm(x, lp["ln2"], eps)
+            x = x + (jax.nn.silu(h @ lp["w_gate"]) * (h @ lp["w_up"])
+                     ) @ lp["w_down"]
+        x = jnp.take_along_axis(x, at[..., None], axis=1)
+        return _norm(x, p["ln_f"], eps) @ p["lm_head"]
+
+
+def row_bytes(config, S, judged):
+    D, F = config["hidden_size"], config["intermediate_size"]
+    H, hd = config["num_attention_heads"], config["head_dim"]
+    return 4 * (2 * H * S * S + S * (4 * D + 4 * H * hd + 3 * F)
+                + judged * config["vocab_size"])
+'''
+
+TOYBLOCKDIFF_NEEDS = '''"""Bytes and FLOPs of a generator whose round is not ``span`` single-token
+steps.  The roofline reader multiplies ``decode_step`` by calls x ``span``
+(readers/trace.py), so ``decode_step`` is a ``span``-th of what ONE ROUND
+needs, and the round is written down here from the file's own sizes and
+``deployment``: ``span / block_length`` blocks, each ``denoising_steps``
+passes and one more that writes its K/V; a pass reads every weight and the
+rows' live K/V once and computes ``rows x block_length`` positions."""
+
+
+def sizes(config):
+    D, F, hd = (config["hidden_size"], config["intermediate_size"],
+                config["head_dim"])
+    H, KV = config["num_attention_heads"], config["num_key_value_heads"]
+    L, V = config["num_hidden_layers"], config["vocab_size"]
+    layer = D * hd * (H + 2 * KV) + H * hd * D + 3 * D * F
+    return {"hd": hd, "layer_params": layer,
+            "matmul_params": L * layer + V * D,
+            "weight_bytes": 2 * (L * layer + 2 * V * D),
+            "kv_bytes_per_position": L * 2 * KV * hd * 2,
+            "attn_flops_per_position": 4 * L * H * hd}
+
+
+def round_needs(config, rows, live_positions):
+    s, block = sizes(config), config["block_length"]
+    blocks = config["deployment"]["span"] // block
+    passes = config["denoising_steps"] + 1
+    a_pass = {
+        "bytes": s["weight_bytes"]
+        + s["kv_bytes_per_position"] * live_positions,
+        "flops": 2.0 * s["matmul_params"] * rows * block
+        + s["attn_flops_per_position"] * live_positions * block}
+    return {"bytes": blocks * (passes * a_pass["bytes"]
+                               + s["kv_bytes_per_position"] * rows * block),
+            "flops": blocks * passes * a_pass["flops"]}
+
+
+def decode_step(config, rows, live_positions, counters):
+    span = config["deployment"]["span"]
+    return {k: v / span
+            for k, v in round_needs(config, rows, live_positions).items()}
+
+
+def prefill(config, calls, tokens, attended_positions, counters):
+    s = sizes(config)
+    return {"bytes": calls * s["weight_bytes"]
+            + 2 * s["kv_bytes_per_position"] * tokens,
+            "flops": 2.0 * s["matmul_params"] * tokens
+            + s["attn_flops_per_position"] * attended_positions}
+'''
+
+TOYBLOCKDIFF_DRIVE = '''"""How a round of the test's block-diffusion generator is driven, and what
+of it the reference is asked (lib/children.py says what an event is).
+
+The prefill has run over each whole prompt, its last block short where the
+prompt is no whole number of blocks: that is the row's ``prefill`` event.
+The round is the program's own (``paged_decode_round``: in the tests the
+toy's in its place): ``span / block_length`` blocks a row, the first one
+begun by the prompt's remainder.  Every denoising pass that unmasked
+something in a row is an event: the context is the prompt's whole blocks,
+the blocks this round has finished and the block AS THAT PASS SAW IT (mask
+ids where they stood); the positions judged are the ones it unmasked, with
+the ids it put there.  The pass that writes a finished block's K/V chooses
+nothing and is no event: the next block's events see what it wrote."""
+
+import jax.numpy as jnp
+import numpy as np
+
+NOT_JUDGED = -1
+
+
+def drive(unit, params, pool, tables, prompts, logits, deployment):
+    from seldon_core_tpu.models.generate import paged_decode_round_jit
+
+    block = unit.cfg.block_length
+    R, B = len(prompts), tables.shape[0]
+    n_valid = np.zeros((B,), np.int32)
+    n_valid[:R] = [len(p) for p in prompts]
+    blocks, pool, passes = paged_decode_round_jit(
+        params, pool, tables, jnp.zeros((B,), jnp.int32),
+        jnp.asarray(n_valid), jnp.asarray(n_valid > 0),
+        jnp.zeros((B,), bool), jnp.zeros((B,), jnp.uint32), unit.cfg,
+        span=deployment["span"], temperature=unit.temperature,
+        top_k=unit.top_k, top_p=unit.top_p, eos_token=unit.eos_token)
+    blocks = np.asarray(blocks)
+    whole = [len(p) - len(p) % block for p in prompts]
+    events = [{"row": r, "ids": p, "at": np.asarray([len(p) - 1]),
+               "chose": np.asarray([NOT_JUDGED]), "prefill": 0}
+              for r, p in enumerate(prompts)]
+    for a_pass in passes:
+        done = a_pass["block"] * block
+        saw, picked, chose = (np.asarray(a_pass[k])
+                              for k in ("saw", "picked", "chose"))
+        for r, p in enumerate(prompts):
+            at = np.flatnonzero(picked[r])
+            if len(at):
+                events.append({
+                    "row": r, "chose": chose[r, at],
+                    "ids": np.concatenate([p[:whole[r]], blocks[r, :done],
+                                           saw[r]]),
+                    "at": whole[r] + done + at})
+    return {"tokens": [blocks[r, len(p) - whole[r]:]
+                       for r, p in enumerate(prompts)],
+            "events": events}
+'''
+
+TOYBLOCKDIFF_CONFIG = {
+    "name": "toyblockdiff", "source": "a test", "arch": "toyblockdiff",
+    "described_as": "a test's generator by diffusion over blocks, as its "
+                    "source has it",
+    "unit": {
+        "class_path": "toy_blockdiff:ToyBlockDiffGenerator",
+        "parameters": {
+            "vocab": {"from": "vocab_size"},
+            "d_model": {"from": "hidden_size"},
+            "head_dim": {"from": "head_dim"},
+            "n_heads": {"from": "num_attention_heads"},
+            "n_kv_heads": {"from": "num_key_value_heads"},
+            "n_layers": {"from": "num_hidden_layers"},
+            "d_ff": {"from": "intermediate_size"},
+            "rope_base": {"from": "rope_theta"},
+            "norm_eps": {"from": "rms_norm_eps"},
+            "block_length": {"from": "block_length"},
+            "denoising_steps": {"from": "denoising_steps"},
+            "mask_id": {"from": "mask_token_id"},
+        },
+    },
+    "hidden_size": 64, "head_dim": 16, "num_attention_heads": 4,
+    "num_key_value_heads": 2, "num_hidden_layers": 2,
+    "intermediate_size": 128, "vocab_size": 512,
+    "tie_word_embeddings": False, "rope_theta": 10000.0,
+    "rms_norm_eps": 1e-05, "max_position_embeddings": 128,
+    "block_length": 4, "denoising_steps": 4, "mask_token_id": 500,
+    # not the last id of the vocabulary: a range check alone lets it through
+    "reserved_ids": [{
+        "id": 500, "why": "mask_token_id: in a prompt it is a hole the "
+                          "program fills, in an answer a position it never "
+                          "filled"}],
+    "positions_limit": {"value": 128, "why": "max_position_embeddings"},
+    "reduced": [], "assumed": [], "departures": [],
+    "hbm": {"total": "a test's: kilobytes"},
+    "numerics": {"tolerance_rms": 0.1},
+}
+# what a round of this generator does to the ladder's arithmetic
+# (lib/buckets.py): a prefill emits nothing, and a row's first round starts
+# where its last whole block ends
+TOYBLOCKDIFF_ROUND = {"prefill_emits": 0, "round_quantum": 4}
+
+
+def add_toy_blockdiff(root: str) -> str:
+    """Add ``bench/archs/toyblockdiff/`` — reference, needs AND the driver
+    of a round that is passes over a block — its configuration with a
+    reserved mask id and a cell on ``add_tiny_cell``'s mix, by ADDING files
+    and manifest entries only.  Its program side is
+    tests/bench/toy_blockdiff.py.  Returns the cell's name."""
+    bench = os.path.join(root, "bench")
+    arch = os.path.join(bench, "archs", "toyblockdiff")
+    os.makedirs(arch)
+    for name, text in (("reference", TOYBLOCKDIFF_REFERENCE),
+                       ("needs", TOYBLOCKDIFF_NEEDS),
+                       ("drive", TOYBLOCKDIFF_DRIVE)):
+        with open(os.path.join(arch, name + ".py"), "w") as f:
+            f.write(text)
+    base = load(os.path.join(bench, "configs", "starcoder2-3b.json"))
+    dump(os.path.join(bench, "configs", "toyblockdiff.json"), {
+        **TOYBLOCKDIFF_CONFIG,
+        "deployment": {**base["deployment"], **TINY_DEPLOYMENT,
+                       **TOYBLOCKDIFF_ROUND}})
+    cell = "toyblockdiff.tinymix.r80"
+    why = ("tinymix on a generator by diffusion over blocks of 4: prompts of "
+           "8-64 tokens, most no whole number of blocks, two blocks a round")
+    dump(os.path.join(bench, "cells", cell + ".json"), {
+        "name": cell, "config": "toyblockdiff", "mix": "tinymix", "chips": 1,
+        "arrivals": {"kind": "open", "rate": 8.0},
+        "drain_s": 2, "soak_s": 2, "trace_s": 1, "why": why})
+    man = load(os.path.join(root, "BENCHMARK.json"))
+    man["configs"].append({
+        "name": "toyblockdiff", "source": "a test", "reduced": [],
+        "file": "bench/configs/toyblockdiff.json",
+        "why": "a test's generator whose round is passes over a block"})
+    man["workloads"].append({
+        "name": cell, "config": "toyblockdiff", "traffic": "tinymix",
+        "chips": 1, "why": why})
+    for m in man["end_to_end"] + man["per_layer"]:
+        if "workloads" in m:
+            m["workloads"].append(cell)
+    dump(os.path.join(root, "BENCHMARK.json"), man)
+    return cell
+
+
 def toy_root(tmp_path) -> str:
-    """A copy of the benchmark with the dense toys and ``toymoe`` added, no
-    file that was there edited."""
+    """A copy of the benchmark with the dense toys, ``toymoe`` and
+    ``toyblockdiff`` added, no file that was there edited."""
     root = copy_root(tmp_path)
     before = snapshot(root)
     add_tiny_cell(root)
     add_tiny_arch(root)
     add_toy_moe(root)
+    add_toy_blockdiff(root)
     assert_untouched(before)
     return root

@@ -274,3 +274,158 @@ def test_numerics_child_judges_a_block_that_is_not_the_repos(
     unit = build_unit(unit_spec(cfg, cfg["deployment"], 1, 8))
     assert [unit.cfg.sliding(i) for i in range(3)] == [
         t == "sliding_attention" for t in cfg["layer_types"]]
+
+
+# -- a round that is not one token a step: the numerics child on block diffusion
+
+
+LENGTHS_MASK = " & (here[None, None, :] < lengths[:, None, None])"
+
+
+@pytest.fixture(scope="module")
+def blockdiff_root(tmp_path_factory):
+    """The benchmark with ``toyblockdiff`` added as files (bench_paths), and
+    two architectures to put in its place: ``toyblockdiff_undriven``, its
+    reference WITHOUT the driver file (the child falls back to a row's one
+    teacher-forced pass), and ``toyblockdiff_untold``, driver and all, whose
+    reference does not use the lengths it is told."""
+    root = bench_paths.toy_root(tmp_path_factory.mktemp("blockdiff"))
+    assert bench_paths.TOYBLOCKDIFF_REFERENCE.count(LENGTHS_MASK) == 1
+    for arch, files in (
+            ("toyblockdiff_undriven",
+             {"reference": bench_paths.TOYBLOCKDIFF_REFERENCE}),
+            ("toyblockdiff_untold",
+             {"reference": bench_paths.TOYBLOCKDIFF_REFERENCE.replace(
+                 LENGTHS_MASK, ""),
+              "drive": bench_paths.TOYBLOCKDIFF_DRIVE})):
+        os.makedirs(os.path.join(root, "bench", "archs", arch))
+        for name, text in files.items():
+            with open(os.path.join(root, "bench", "archs", arch,
+                                   name + ".py"), "w") as f:
+                f.write(text)
+    return root
+
+
+def blockdiff_numerics(root, monkeypatch, steps, arch="toyblockdiff",
+                       fault=None):
+    """lib/children.py ``numerics`` in this process on ``toyblockdiff``, the
+    test-local generator's functions (tests/bench/toy_blockdiff.py) in the
+    place of the program's three, one of them broken where ``fault`` says."""
+    import numpy as np
+    import toy_blockdiff as toy
+    from lib import children
+    from seldon_core_tpu.models import generate
+
+    man = Manifest(root)
+    cell = man.cell("toyblockdiff.tinymix.r80")
+    cfg = {**man.config(cell["config"]), "denoising_steps": steps,
+           "arch": arch}
+    if fault == "kv-from-the-last-pass":
+        real, last = toy._forward, {}
+
+        def forward(params, tokens, pool, tables, start, width, cfg):
+            # the round's own calls are one block wide; the one over a
+            # block with no mask left is the pass that writes its K/V
+            if tokens.shape[1] == cfg.block_length:
+                if (np.asarray(tokens) != cfg.mask_id).all() and last:
+                    tokens = last["saw"]
+                else:
+                    last["saw"] = tokens
+            return real(params, tokens, pool, tables, start, width, cfg)
+
+        monkeypatch.setattr(toy, "_forward", forward)
+    elif fault == "causal-inside-the-block":
+        monkeypatch.setattr(toy, "_visible",
+                            lambda pos, kpos, block_length: kpos <= pos)
+    round_fn = toy.paged_decode_round_jit
+    if fault == "the-mask-id-streamed":
+        def round_fn(*a, **kw):
+            blocks, *rest = toy.paged_decode_round_jit(*a, **kw)
+            return (blocks.at[1, 6].set(cfg["mask_token_id"]), *rest)
+    monkeypatch.setattr(generate, "init_block_pool", toy.init_block_pool)
+    monkeypatch.setattr(generate, "paged_forward_jit", toy.paged_forward_jit)
+    monkeypatch.setattr(generate, "paged_decode_round_jit", round_fn)
+    dep = man.deployment(cell, cfg)
+    caps = buckets.caps(man.mix(cell["mix"]))
+    spec = {
+        "repo": REPO, "platforms": ["cpu"], "bench_dir": man.bench,
+        "config": cfg, "deployment": dep,
+        "unit": unit_spec(cfg, dep, 2 ** 31 + 9, caps["max_out"]),
+        "sample": sample.plan(TINY_PROMPTS, dep, caps["max_positions"]),
+        "sample_seed": 17}
+    num = children.numerics(
+        spec, {"platform": "cpu", "kind": "cpu", "count": 1})
+    return num, cfg
+
+
+@pytest.mark.parametrize("steps", [4, 2])
+def test_numerics_child_judges_a_round_that_is_passes_over_a_block(
+        blockdiff_root, monkeypatch, steps):
+    """Rows of 9, 31, 64 and 80 prompt tokens — a remainder of 1, 3, 0 and
+    0 seeds the first block — through chunked prefill under the
+    block-causal mask and one round of two blocks; every denoising pass
+    that unmasked something is an event of its own, and the reference
+    agrees with each to rounding."""
+    num, cfg = blockdiff_numerics(blockdiff_root, monkeypatch, steps)
+    v = num["verdict"]
+    assert num["ok"] is True, v
+    assert num["lens"] == [9, 31, 64, 80] and num["chunks"] == [1, 3]
+    assert [n % cfg["block_length"] for n in num["lens"]] == [1, 3, 0, 0]
+    assert v["prefill"]["allowed"] == v["decode"]["allowed"] == 0.0
+    assert 0.0 < max(num["by_row"]["prefill_err"]) < 0.01 * num["tolerance"]
+    assert num["decode_max_margin"] <= 0.01 * num["tolerance"]
+    assert num["reserved_emitted"] == 0
+
+
+@pytest.mark.parametrize("fault, steps, arch, by", [
+    ("kv-from-the-last-pass", 2, "toyblockdiff", "decode_margin"),
+    ("causal-inside-the-block", 4, "toyblockdiff", "prefill_err"),
+    ("causal-inside-the-block", 2, "toyblockdiff", "prefill_err"),
+    (None, 4, "toyblockdiff_untold", "prefill_err"),
+    (None, 4, "toyblockdiff_undriven", "decode_margin"),
+    (None, 2, "toyblockdiff_undriven", "decode_margin"),
+    ("the-mask-id-streamed", 4, "toyblockdiff", "reserved_emitted"),
+    ("the-mask-id-streamed", 2, "toyblockdiff", "reserved_emitted"),
+], ids=["kv-written-from-the-last-denoising-pass", "causal-inside-the-block-4",
+        "causal-inside-the-block-2", "the-reference-ignores-lengths",
+        "the-driver-file-taken-away-4", "the-driver-file-taken-away-2",
+        "the-mask-id-streamed-4", "the-mask-id-streamed-2"])
+def test_a_fault_of_a_round_over_blocks_comes_out_not_ok_by_its_own_number(
+        blockdiff_root, monkeypatch, fault, steps, arch, by):
+    num, cfg = blockdiff_numerics(blockdiff_root, monkeypatch, steps, arch,
+                                  fault)
+    v, rows = num["verdict"], num["by_row"]
+    assert num["ok"] is False
+    tol = num["tolerance"]
+    if fault == "kv-from-the-last-pass":
+        # block 1's K/V came from an input that still held masks: seen only
+        # where block 2 attends to it, in what block 2's passes chose.  The
+        # prefill, and a row whose first block had one position to fill
+        # (its last pass saw no mask: n = 31), read sound
+        assert by == "decode_margin" and v["prefill"]["over"] == 0
+        assert v["decode"]["over"] >= 1 and rows["decode_margin"][1] == 0.0
+        assert max(rows["decode_margin"]) > 2 * v["decode"]["limit"]
+    elif fault == "causal-inside-the-block":
+        # every prompt's K/V differs from its second layer on
+        assert by == "prefill_err" and v["prefill"]["over"] >= 3
+        assert max(rows["prefill_err"]) > 4 * tol
+    elif arch == "toyblockdiff_untold":
+        # a prompt that is no whole number of blocks ends in a short block,
+        # which under this mask looks AHEAD — into the pad, unless the
+        # reference keeps it out by the lengths it is told.  Whole blocks
+        # (64, 80) and every event of the round (contexts of whole blocks)
+        # never see it
+        assert by == "prefill_err" and v["decode"]["over"] == 0
+        assert rows["prefill_err"][0] > 4 * tol
+        assert max(rows["prefill_err"][2:]) < 0.01 * tol
+    elif arch == "toyblockdiff_undriven":
+        # the program is SOUND; what judges it is a row's one
+        # teacher-forced pass, which no pass of this generator ever ran:
+        # several rms in every row
+        assert by == "decode_margin" and v["decode"]["share"] == 1.0
+        assert min(rows["decode_margin"]) > 10 * v["decode"]["limit"]
+    else:
+        # one id altered where the round hands its blocks over: no logit
+        # moved, the exact check alone sees it (limit 0)
+        assert by == "reserved_emitted" and num["reserved_emitted"] == 1
+        assert v["ok"] is True and max(rows["decode_margin"]) <= 0.01 * tol

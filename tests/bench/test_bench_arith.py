@@ -48,13 +48,32 @@ def test_attainment_counts_requests_sent_and_failures_as_misses(
     assert got == (want if want is None else pytest.approx(want))
 
 
-def finished(req, t_first, t_last, n, status=200, done=True, error=None):
+def finished(req, t_first, t_last, n, status=200, done=True, error=None,
+             token=1, reserved=()):
     from lib.client import finish_record
 
     rec = {"status": status, "t_sent": req.due_s + 0.001,
            "t_first": t_first, "t_last": t_last, "done": done,
-           "error": error, "tokens": [[1] * n] if n else None}
-    return finish_record(req, rec, 512, 40.0)
+           "error": error, "tokens": [[1] * (n - 1) + [token]] if n else None}
+    return finish_record(req, rec, 512, 40.0, reserved)
+
+
+@pytest.mark.parametrize("token, reserved, ok", [
+    (500, (), True), (500, (500,), False), (499, (500,), True),
+    (511, (3, 511), False), (512, (), False), (-1, (), False),
+], ids=["nothing-reserved", "the-mask-id-streamed", "its-neighbour",
+        "the-last-id-reserved", "out-of-range", "negative"])
+def test_an_answer_that_holds_a_reserved_id_is_a_failed_request(
+        token, reserved, ok):
+    """The exact check every answer gets, limit 0: a finished stream of
+    the right length whose last id is one the configuration reserves is
+    not ok, and is charged at the window's end like any failure."""
+    from lib.traffic import Request
+
+    rec = finished(Request(0, 10.0, 64, 20, True), t_first=10.2,
+                   t_last=10.58, n=20, token=token, reserved=reserved)
+    assert rec["ok"] is ok and rec["status"] == 200 and rec["error"] is None
+    assert (rec["tpot_ms"] == pytest.approx(20.0)) is ok
 
 
 @pytest.mark.parametrize("case,ttft,tpot", [

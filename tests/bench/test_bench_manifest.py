@@ -18,6 +18,7 @@ from lib.manifest import (
     Manifest,
     ManifestError,
     arch_module,
+    reserved_ids,
 )
 
 MAN = Manifest(REPO)
@@ -154,6 +155,28 @@ def check_reduced(doc):
         assert after >= MIN_LAYERS and after % pattern["period"] == 0
 
 
+def check_reserved(doc):
+    """Ids a configuration keeps out of the traffic and of every answer:
+    each in range, none twice, each with its reason."""
+    entries = doc.get("reserved_ids", [])
+    assert all(type(r) is dict and set(r) == {"id", "why"} and r["why"]
+               for r in entries)
+    ids = list(reserved_ids(doc))
+    assert ids == [r["id"] for r in entries]
+    assert all(type(i) is int and 0 <= i < doc["vocab_size"] for i in ids)
+    assert len(set(ids)) == len(ids) < doc["vocab_size"]
+
+
+def check_round(dep):
+    """The two counts a deployment may state of a round that is not
+    single-token steps (lib/buckets.py): a round is whole quanta long and
+    a block of the pool holds whole quanta."""
+    assert dep.get("prefill_emits", 1) in (0, 1)
+    quantum = dep.get("round_quantum", 1)
+    assert type(quantum) is int and quantum >= 1
+    assert dep["span"] % quantum == 0 and dep["block_size"] % quantum == 0
+
+
 def check_config(man, config, doc=None):
     """A configuration is held to what it DECLARES — its own head width,
     its own position cap, its own cuts — not to one model's shape.  (``doc``
@@ -174,9 +197,18 @@ def check_config(man, config, doc=None):
     # files of bench/archs/<arch>/, its unit and the unit's keywords data
     reference = arch_module(man.bench, doc, "reference")
     assert list(inspect.signature(reference.forward).parameters) == [
-        "params", "tokens", "config", "at"]
+        "params", "tokens", "config", "at", "lengths"]
     assert reference.row_bytes(doc, 2 * doc["deployment"]["block_size"],
                                1 + doc["deployment"]["span"]) > 0
+    # how a round is driven is the architecture's to say, or nobody's: then
+    # it is single-token steps (lib/children.py one_token_a_step)
+    driver = arch_module(man.bench, doc, "drive", optional=True)
+    if driver is not None:
+        assert list(inspect.signature(driver.drive).parameters) == [
+            "unit", "params", "pool", "tables", "prompts", "logits",
+            "deployment"]
+    check_reserved(doc)
+    check_round(doc["deployment"])
     assert doc["unit"]["class_path"]
     assert all(NAME_RE.match(k) for k in doc["unit"]["parameters"])
     # every parameter a key of the file or a literal, and a scalar
@@ -376,9 +408,125 @@ def test_an_architecture_is_added_by_adding_files(tmp_path):
 
 @pytest.fixture(scope="module")
 def moe_root(tmp_path_factory):
-    """The benchmark with three configurations added as files: the dense
-    toys and ``toymoe``, a block that is NOT a smaller StarCoder2."""
-    return bench_paths.toy_root(tmp_path_factory.mktemp("moe"))
+    """The benchmark with four configurations added as files: the dense
+    toys, ``toymoe``, a block that is NOT a smaller StarCoder2, and
+    ``toyblockdiff``, a generator whose round is not one token a step —
+    and two architectures whose files break a rule: a reference that takes
+    no ``lengths``, a driver that takes another list of arguments."""
+    root = bench_paths.toy_root(tmp_path_factory.mktemp("moe"))
+    for arch, name, text in (
+            ("untold", "reference", bench_paths.TOYBLOCKDIFF_REFERENCE.replace(
+                "config, at, lengths):", "config, at):")),
+            ("undriven", "reference", bench_paths.TOYBLOCKDIFF_REFERENCE),
+            ("undriven", "drive", bench_paths.TOYBLOCKDIFF_DRIVE.replace(
+                "prompts, logits, deployment):", "prompts, deployment):"))):
+        os.makedirs(os.path.join(root, "bench", "archs", arch), exist_ok=True)
+        with open(os.path.join(root, "bench", "archs", arch, name + ".py"),
+                  "w") as f:
+            f.write(text)
+    for arch in ("untold", "undriven"):
+        with open(os.path.join(root, "bench", "archs", arch, "needs.py"),
+                  "w") as f:
+            f.write(bench_paths.TOYBLOCKDIFF_NEEDS)
+    return root
+
+
+def test_a_generator_whose_round_is_not_one_token_a_step_is_added_by_adding_files(
+        tmp_path):
+    """Diffusion over blocks: the reference, the needs AND the driver of a
+    round under ``bench/archs/toyblockdiff/``, a configuration with a
+    reserved mask id and the two counts of its round in ``deployment``, a
+    cell: new files and manifest entries only, every check of this
+    directory holds on the copy, and the ladder takes every width the
+    generator's OWN arithmetic reaches (with the program's counts it does
+    not: that is what the two keys are for)."""
+    root = bench_paths.copy_root(tmp_path)
+    before = bench_paths.snapshot(root)
+    bench_paths.add_tiny_cell(root)
+    cell = bench_paths.add_toy_blockdiff(root)
+    bench_paths.assert_untouched(before)
+    man = Manifest(root)
+    check_copy(man)
+    assert sorted(os.listdir(os.path.join(man.bench, "archs",
+                                          "toyblockdiff"))) == [
+        "drive.py", "needs.py", "reference.py"]
+    added = set(bench_paths.snapshot(root)) - set(before)
+    assert sorted(os.path.relpath(p, man.bench) for p in added
+                  if "toyblockdiff" in p) == [
+        "archs/toyblockdiff/drive.py", "archs/toyblockdiff/needs.py",
+        "archs/toyblockdiff/reference.py",
+        "cells/toyblockdiff.tinymix.r80.json", "configs/toyblockdiff.json"]
+    cfg = man.config("toyblockdiff")
+    assert reserved_ids(cfg) == (cfg["mask_token_id"],) == (500,)
+    assert reserved_ids(man.config("starcoder2-3b")) == ()
+    assert arch_module(man.bench, man.config("starcoder2-3b"), "drive",
+                       optional=True) is None
+    doc = man.cell(cell)
+    dep = man.deployment(doc, cfg)
+    cp = buckets.caps(man.mix(doc["mix"]))
+    L = cfg["block_length"]
+
+    def widths(prompt_len, max_new):
+        """The generator's own account (tests/bench/toy_blockdiff.py): a
+        row's first round starts where its last whole block ends and
+        emits the prompt's remainder again; the prefill emits nothing."""
+        dec, rem = set(), prompt_len % L
+        at, out = prompt_len - rem, -rem
+        while out < max_new:
+            at, out = at + dep["span"], out + dep["span"]
+            dec.add(buckets.pow2(buckets.blocks(at, dep["block_size"])))
+        return dec
+
+    reach = set()
+    for p in range(cp["min_prompt"], cp["max_prompt"] + 1):
+        for o in range(1, min(cp["max_out"], cp["max_positions"] - p) + 1):
+            assert buckets.touched(p, o, dep)[1] == widths(p, o), (p, o)
+            reach |= widths(p, o)
+    assert reach == buckets.reachable(dep, cp)[1] == {1, 2, 4, 8}
+    ladder = buckets.ladder_rows(dep, cp)
+    assert set().union(*(widths(*row) for row in ladder)) == reach
+    # under the program's counts the ladder misses two of them
+    old = {k: v for k, v in dep.items()
+           if k not in bench_paths.TOYBLOCKDIFF_ROUND}
+    assert set().union(*(widths(*row) for row in buckets.ladder_rows(
+        old, cp))) == {1, 4}
+
+
+def test_a_round_that_is_not_span_steps_states_its_needs_by_the_span_th(
+        moe_root):
+    """readers/trace.py multiplies ``decode_step`` by calls x span.  An
+    architecture whose round is passes over a block returns a ``span``-th
+    of a ROUND's bytes and FLOPs — here 2 blocks x (4 + 1) passes, each
+    all weights and the live K/V once — and the reader is as it was."""
+    from lib.peaks import peaks_for
+    from readers import trace
+
+    man = Manifest(moe_root)
+    cfg = man.config("toyblockdiff")
+    dep = man.deployment(man.cell("toyblockdiff.tinymix.r80"), cfg)
+    needs = arch_module(man.bench, cfg, "needs")
+    layer = 64 * 16 * (4 + 2 * 2) + 4 * 16 * 64 + 3 * 64 * 128
+    assert needs.sizes(cfg)["layer_params"] == layer == 36_864
+    weights = 2 * (2 * layer + 2 * 512 * 64)          # an untied head
+    kv_pos = 2 * 2 * 2 * 16 * 2
+    rows, live = 3.0, 120.0
+    a_round = 2 * (5 * (weights + kv_pos * live) + kv_pos * rows * 4)
+    need = needs.decode_step(cfg, rows, live, {})
+    assert need["bytes"] == pytest.approx(a_round / dep["span"])
+    assert need["flops"] == pytest.approx(2 * 5 * (
+        2.0 * (2 * layer + 512 * 64) * rows * 4
+        + 4 * 2 * 4 * 16 * live * 4) / dep["span"])
+    ctx = {"bench_dir": man.bench, "config": cfg, "deployment": dep,
+           "device": {"kind": "TPU v5 lite"},
+           "trace": {"programs": {"decode": {"seconds": 0.004, "calls": 5}}},
+           "traced": {"decode_rows_mean": rows,
+                      "decode_live_positions_mean": live}}
+    share = trace.read(man.layer_metric("decode_roofline"), ctx)
+    # five rounds' bytes over the traced seconds, whatever the span
+    bw = peaks_for("TPU v5 lite")["hbm_bytes_per_s"]
+    assert share == pytest.approx(100.0 * 5 * a_round / bw / 0.004)
+    steps2 = {**cfg, "denoising_steps": 2}
+    assert needs.decode_step(steps2, rows, live, {})["bytes"] < need["bytes"]
 
 
 def test_a_block_that_is_not_the_repos_is_added_by_adding_files(moe_root):
@@ -392,7 +540,8 @@ def test_a_block_that_is_not_the_repos_is_added_by_adding_files(moe_root):
     man = Manifest(moe_root)
     check_copy(man)
     assert sorted(os.listdir(os.path.join(man.bench, "archs"))) == [
-        "dense_gelu", "tinyarch", "toymoe"]
+        "dense_gelu", "tinyarch", "toyblockdiff", "toymoe",
+        "undriven", "untold"]       # the last two: the fixture's broken ones
     cfg = man.config("toymoe")
     assert cfg["hidden_size"] // cfg["num_attention_heads"] == 16
     assert cfg["head_dim"] == 32 != 128
@@ -423,6 +572,12 @@ def broken(**changes):
                 doc.pop(key)
             else:
                 doc[key] = value
+    return apply
+
+
+def in_deployment(**changes):
+    def apply(doc):
+        doc["deployment"] = {**doc["deployment"], **changes}
     return apply
 
 
@@ -464,6 +619,21 @@ def sliced_vocab(doc):
     ("starcoder2-3b", broken(departures=[])),
     ("starcoder2-3b", broken(positions_limit=None)),
     ("starcoder2-3b", broken(reduced=["vocab_size"])),
+    ("toyblockdiff", broken(reserved_ids=[{"id": 512, "why": "mask"}])),
+    ("toyblockdiff", broken(reserved_ids=[{"id": -1, "why": "mask"}])),
+    ("toyblockdiff", broken(reserved_ids=[{"id": 500, "why": "mask"},
+                                          {"id": 500, "why": "pad"}])),
+    ("toyblockdiff", broken(reserved_ids=[{"id": 500, "why": ""}])),
+    ("toyblockdiff", broken(reserved_ids=[{"id": 500}])),
+    ("toyblockdiff", broken(reserved_ids=[500])),
+    ("toyblockdiff", broken(reserved_ids=[{"id": 500.0, "why": "mask"}])),
+    ("starcoder2-3b", broken(reserved_ids=[{"id": 49152, "why": "mask"}])),
+    ("toyblockdiff", broken(arch="untold")),
+    ("toyblockdiff", broken(arch="undriven")),
+    ("toyblockdiff", in_deployment(round_quantum=3)),
+    ("toyblockdiff", in_deployment(round_quantum=0)),
+    ("toyblockdiff", in_deployment(round_quantum=32)),
+    ("toyblockdiff", in_deployment(prefill_emits=2)),
 ], ids=["no-published", "count-not-published", "no-deployment",
         "chips-times-held-is-not-published", "not-said-how-divided",
         "under-8-experts", "under-an-eighth-of-the-vocabulary",
@@ -475,7 +645,13 @@ def sliced_vocab(doc):
         "head_dim-left-to-hidden-over-heads", "no-positions_limit",
         "positions_limit-without-its-reason", "a-group-for-a-unit-parameter",
         "starcoder2-departs-and-says-so", "starcoder2-no-positions_limit",
-        "starcoder2-vocabulary-cut-unstated"])
+        "starcoder2-vocabulary-cut-unstated", "reserved-id-past-the-vocabulary",
+        "reserved-id-negative", "reserved-id-twice", "reserved-without-a-reason",
+        "reserved-without-the-key", "reserved-a-bare-number",
+        "reserved-id-not-an-integer", "starcoder2-reserved-id-out-of-range",
+        "reference-not-told-lengths", "driver-of-another-signature",
+        "round-quantum-splits-the-span", "round-quantum-zero",
+        "round-quantum-over-a-block", "prefill-emits-two"])
 def test_a_configuration_that_breaks_one_rule_is_refused(moe_root, config,
                                                          change):
     man = Manifest(moe_root)
@@ -553,3 +729,35 @@ def test_a_cell_file_that_disagrees_with_the_manifest_is_refused(tmp_path):
         Manifest(root).cell(CELLS[0])
     with pytest.raises(ManifestError):
         Manifest(root).cell("no-such-cell")
+
+
+def test_the_harness_names_no_unit_no_block_and_no_way_to_decode_but_the_default():
+    """``lib/``, ``readers/``, ``tools/`` and ``run.py`` learn a unit's
+    class and keywords from the configuration file, a block's formulas
+    from ``archs/<arch>/`` and how a round is driven from its ``drive.py``
+    — or drive the one default, a round of single-token steps."""
+    import re
+
+    unit = MAN.config(CONFIGS[0])["unit"]
+    words = [unit["class_path"], *unit["parameters"],
+             *(v["from"] for v in unit["parameters"].values()
+               if isinstance(v, dict)),
+             "diffusion", "denois", "block_length", "unmask", "speculative",
+             "draft", "bidirectional", "gelu", "silu", "rmsnorm"]
+    # the one size every answer is held to, under either name
+    words = [w for w in words if w not in ("vocab", "vocab_size")]
+    files = [os.path.join(BENCH, "run.py")]
+    for sub in ("lib", "readers", "tools"):
+        files += [os.path.join(BENCH, sub, f)
+                  for f in sorted(os.listdir(os.path.join(BENCH, sub)))
+                  if f.endswith(".py")]
+    assert len(files) > 25
+    hits = {}
+    for path in files:
+        with open(path) as f:
+            text = f.read()
+        found = [w for w in words
+                 if re.search(rf"(?<![A-Za-z_]){re.escape(w)}(?![a-z_])",
+                              text, re.IGNORECASE)]
+        hits[os.path.relpath(path, BENCH)] = found
+    assert {k: v for k, v in hits.items() if v} == {}

@@ -108,3 +108,40 @@ def test_pow2_is_the_schedulers(n, want):
 @pytest.mark.parametrize("cell", [w["name"] for w in MAN.doc["workloads"]])
 def test_the_ladder_takes_every_reachable_bucket_once(cell):
     bench_paths.check_ladder(MAN, cell)
+
+
+# what lib/buckets.py gave at the parent of PR 32 (29b9b78) under
+# starcoder2-3b's deployment, which states neither count of a round
+PARENT_BUCKETS = {
+    "codegen": {"reachable": ([1, 2, 4], [1, 2, 4, 8]), "programs": (18, 24),
+                "ladder": [(16, 2), (249, 2), (513, 2), (1017, 2)]},
+    "complete": {"reachable": ([1, 2, 4, 8, 16], [1, 2, 4, 8, 16]),
+                 "programs": (30, 30),
+                 "ladder": [(64, 2), (249, 2), (505, 2), (1017, 2),
+                            (2049, 2)]},
+}
+PARENT_TOUCHED = {(16, 16): ({1}, {1}), (300, 512): ({1, 2}, {2, 4}),
+                  (1024, 512): ({1, 2, 4}, {8}), (257, 2): ({1, 2}, {2})}
+
+
+@pytest.mark.parametrize("mix", sorted(PARENT_BUCKETS))
+def test_where_a_deployment_states_no_count_of_a_round_the_ladder_is_the_parents(
+        mix):
+    cfg = MAN.config("starcoder2-3b")
+    dep = MAN.deployment(MAN.cell("starcoder2-3b.codegen.r80"), cfg)
+    assert "prefill_emits" not in dep and "round_quantum" not in dep
+    cp = buckets.caps(MAN.mix(mix))
+    want = PARENT_BUCKETS[mix]
+    pre, dec = buckets.reachable(dep, cp)
+    assert (sorted(pre), sorted(dec)) == want["reachable"]
+    assert buckets.ladder_rows(dep, cp) == want["ladder"]
+    progs = buckets.programs(dep, cp)
+    assert (len(progs["prefill"]), len(progs["decode"])) == want["programs"]
+    for (p, o), sets in PARENT_TOUCHED.items():
+        assert buckets.touched(p, o, dep) == sets
+    # ... and stating the program's own counts changes nothing
+    same = {**dep, "prefill_emits": 1, "round_quantum": 1}
+    assert buckets.ladder_rows(same, cp) == want["ladder"]
+    assert buckets.after_prefill(300, dep) == (300, 1)
+    assert buckets.after_prefill(302, {"round_quantum": 4, "prefill_emits": 0}
+                                 ) == (300, -2)

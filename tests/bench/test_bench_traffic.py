@@ -69,6 +69,56 @@ def test_lengths_respect_the_mix_clips_and_position_cap(name):
         <= 1.15 * m["prompt_tokens"]["median"]
 
 
+# what the parent of PR 32 (29b9b78) drew for (seed, index 3, 12 ids of
+# 49,152) and, in the numerics child, for rows of 5 and 3 ids from
+# seed % 9973: with nothing reserved every seed's traffic is what it was
+PARENT_DREW = {
+    7: ([20357, 38170, 22512, 48638, 27385, 47248, 31695, 1555, 11404, 10650,
+         41895, 29340],
+        [[46443, 30724, 33628, 44099, 28424], [38126, 40975, 11069]]),
+    2 ** 31 + 5: ([35432, 31034, 7619, 997, 12130, 48314, 25157, 435, 29443,
+                   27334, 10233, 368],
+                  [[30610, 7523, 21458, 18975, 45905], [26737, 10151, 34943]]),
+    2147486311: ([37207, 29683, 31227, 2319, 29417, 39541, 24269, 6508,
+                  39110, 9012, 5960, 43444],
+                 [[17599, 22361, 23977, 12906, 12310], [44363, 8361, 32965]]),
+}
+
+
+@pytest.mark.parametrize("seed", sorted(PARENT_DREW))
+def test_with_nothing_reserved_both_draws_are_the_parents(seed):
+    from lib import children
+
+    prompt, rows = PARENT_DREW[seed]
+    assert traffic.prompt_tokens(seed, 3, 12, 49152) == prompt
+    assert traffic.prompt_tokens(seed, 3, 12, 49152, ()) == prompt
+    got = children.sample_tokens([5, 3], 49152, seed % 9973)
+    assert [a.tolist() for a in got] == rows
+    assert all(a.dtype == "int32" for a in got)
+
+
+@pytest.mark.parametrize("reserved", [(500,), (0, 511), (3, 4, 5, 200)],
+                         ids=["a-mask-id", "both-ends", "a-run-and-one"])
+def test_a_reserved_id_is_never_drawn_and_the_others_stay_uniform(reserved):
+    """10^5 ids of a vocabulary of 512, by the load generator and by the
+    numerics child: none reserved, every other id drawn, about as often."""
+    from lib import children
+
+    drawn = traffic.prompt_tokens(2 ** 31 + 9, 0, 100_000, 512, reserved)
+    rows = children.sample_tokens([60_000, 40_000], 512, 17, reserved)
+    for ids in (drawn, [int(t) for row in rows for t in row]):
+        assert len(ids) == 100_000
+        counts = [ids.count(t) for t in range(512)]
+        assert all(counts[r] == 0 for r in reserved)
+        rest = [c for t, c in enumerate(counts) if t not in reserved]
+        mean = 100_000 / len(rest)
+        assert 0.7 * mean < min(rest) and max(rest) < 1.3 * mean
+    # the draw is the unreserved one moved past the reserved ids, in order
+    free = traffic.prompt_tokens(2 ** 31 + 9, 0, 64, 512 - len(reserved))
+    allowed = [t for t in range(512) if t not in reserved]
+    assert drawn[:64] == [allowed[t] for t in free]
+
+
 def test_prompt_tokens_are_seeded_uniform_ids_in_range():
     a = traffic.prompt_tokens(2 ** 31 + 5, 3, 200, 49152)
     assert a == traffic.prompt_tokens(2 ** 31 + 5, 3, 200, 49152)
