@@ -16,10 +16,21 @@ Where the cache lives is decided from OUTSIDE the program:
 
 from __future__ import annotations
 
+import hashlib
+import json
 import logging
 import os
+from typing import Dict, Set
 
-__all__ = ["compile_cache_dir", "enable_compile_cache"]
+__all__ = [
+    "compile_cache_dir",
+    "enable_compile_cache",
+    "program_record_path",
+    "read_program_record",
+    "write_program_record",
+]
+
+logger = logging.getLogger(__name__)
 
 #: the in-checkout default: <checkout>/.xla_cache (the package's parent)
 _DEFAULT_DIR = os.path.join(
@@ -61,7 +72,7 @@ def enable_compile_cache() -> bool:
         try:
             os.makedirs(_DEFAULT_DIR, exist_ok=True)
         except OSError as e:
-            logging.getLogger(__name__).warning(
+            logger.warning(
                 "compile cache disabled (%s: %s) — every restart pays "
                 "full XLA compiles; set JAX_COMPILATION_CACHE_DIR to a "
                 "writable directory", type(e).__name__, e,
@@ -74,3 +85,84 @@ def enable_compile_cache() -> bool:
     install_compile_cache_listener()
     RECORDER.record_compile_cache("enabled")
     return True
+
+
+# ---------------------------------------------------------------------------
+# The program record: which shapes of the scheduler's paged programs a
+# deployment dispatched, kept in the cache directory so that the next boot
+# loads exactly those before its first request (runtime/genserver.py
+# ``_load_programs``).  A HINT that holds no executable: JAX's persistent
+# cache stays the only store of compiled code and its key the only thing
+# that decides whether code is current, so a record that is stale, from
+# other source, corrupt or half-written costs at worst a compile at boot
+# and never a wrong program.
+# ---------------------------------------------------------------------------
+
+_RECORD_VERSION = 1
+#: program kind -> entries of one shape: (rows, chunk, blocks) / (rows, blocks)
+_RECORD_ARITY = {"prefill": 3, "decode": 2}
+
+
+def program_record_path(identity: str) -> str:
+    """Where the record of the deployment ``identity`` lives: one file in
+    the directory JAX's persistent cache is configured with NOW
+    (``enable_compile_cache()`` or ``JAX_COMPILATION_CACHE_DIR`` set it),
+    named by a digest of the identity.  '' where no persistent cache is on
+    (``SELDON_COMPILE_CACHE=0``, no directory): then nothing is recorded
+    and nothing is loaded at boot."""
+    if os.environ.get("SELDON_COMPILE_CACHE", "1") == "0":
+        return ""
+    import jax
+
+    cache_dir = jax.config.jax_compilation_cache_dir
+    if not cache_dir or not jax.config.jax_enable_compilation_cache:
+        return ""
+    digest = hashlib.sha256(identity.encode()).hexdigest()[:16]
+    return os.path.join(cache_dir, f"genserver-programs-{digest}.json")
+
+
+def read_program_record(path: str, identity: str) -> Dict[str, Set[tuple]]:
+    """The shapes the record at ``path`` lists, by program kind.  A file
+    that is absent reads as empty in silence; one that is truncated, not
+    JSON, of another version or identity, or lists anything but tuples of
+    positive integers reads as empty with one warning."""
+    empty: Dict[str, Set[tuple]] = {kind: set() for kind in _RECORD_ARITY}
+    try:
+        with open(path) as f:
+            doc = json.load(f)
+        if (doc["version"], doc["identity"]) != (_RECORD_VERSION, identity):
+            raise ValueError("another version or deployment")
+        out = {kind: {tuple(shape) for shape in doc[kind]}
+               for kind in _RECORD_ARITY}
+        for kind, arity in _RECORD_ARITY.items():
+            for shape in out[kind]:
+                if len(shape) != arity or not all(
+                        type(n) is int and n > 0 for n in shape):
+                    raise ValueError(f"{kind} shape {shape!r}")
+        return out
+    except FileNotFoundError:
+        return empty
+    except (OSError, ValueError, KeyError, TypeError) as e:
+        logger.warning("program record %s ignored (%s: %s): this boot loads "
+                       "no program ahead of its first request",
+                       path, type(e).__name__, e)
+        return empty
+
+
+def write_program_record(path: str, identity: str,
+                         programs: Dict[str, Set[tuple]]) -> bool:
+    """Write the record whole, atomically (a reader sees the old file or
+    the new one).  False, with a warning, where the directory cannot be
+    written: the caller stops recording."""
+    doc = {"version": _RECORD_VERSION, "identity": identity,
+           **{kind: sorted(programs[kind]) for kind in _RECORD_ARITY}}
+    tmp = f"{path}.{os.getpid()}.tmp"
+    try:
+        with open(tmp, "w") as f:
+            json.dump(doc, f)
+        os.replace(tmp, path)
+        return True
+    except OSError as e:
+        logger.warning("program record %s not written (%s: %s)",
+                       path, type(e).__name__, e)
+        return False

@@ -62,6 +62,8 @@ from __future__ import annotations
 
 import concurrent.futures
 import functools
+import hashlib
+import json
 import logging
 import os
 import queue
@@ -75,6 +77,11 @@ import numpy as np
 from seldon_core_tpu.messages import LoadShedError
 from seldon_core_tpu.runtime.autopilot import SHED_INFO_PREFIX
 from seldon_core_tpu.runtime.brownout import BROWNOUT, BROWNOUT_INFO_PREFIX
+from seldon_core_tpu.runtime.compilecache import (
+    program_record_path,
+    read_program_record,
+    write_program_record,
+)
 from seldon_core_tpu.runtime.qos import current_tier, tier_rank
 from seldon_core_tpu.utils.costledger import costledger_enabled
 from seldon_core_tpu.utils.hotrecord import SPINE
@@ -180,6 +187,56 @@ _FENCE_EVERY = 19
 # late to a completion -- so the least of a few is what the sleep before
 # the next round cannot move.
 _ROUND_READINGS = 8
+
+
+# How many threads fetch (or, cold, compile) and load the programs a boot
+# brings up ahead of its first request, behind the ONE thread that traces and
+# lowers them (``GenServer._load``).  Measured on one TPU v5e host of 13 cores
+# (PERF.md section 6, PR 33, calls P33a / P33b: the benchmark's 24 programs
+# of 30 layers, warm persistent cache).  A program is ~1.5 s of Python under
+# the GIL (trace 0.8-1.3 s, jaxpr -> MLIR 0.35-0.45 s) and 1.43 s of C++
+# (cache key, file, deserialise, load); 24 of them one after another 70.0 s.
+# Threads that each do both fight for the GIL: 2 / 4 / 8 / 24 of them read
+# 60.3 / 50.3 / 52.9-54.7 / 54.3 s.  One tracer with 1 / 2 / 3 / 4 / 8
+# loaders behind it reads 49.1 / 43.3 / 41.7 / 44.7 / 43.9 s -- the tracer's
+# 40-43 s is the floor, two loaders reach it and more cost nothing.  Cold,
+# the loaders are what compiles side by side (5-12 s a program: six decode
+# programs behind eighteen fetched ones in 49.1 s with 8), so the constant is
+# the widest that still leaves the tracer and the scheduler a core each.
+_LOAD_THREADS = 8
+
+
+@functools.lru_cache(maxsize=None)
+def _keep_out_of_program_locations() -> None:
+    """Tell JAX that this file's frames are no part of a traced program's
+    source locations.  A Pallas kernel's body is serialised with its MLIR
+    locations, up to ten frames of the Python stack it was traced under,
+    and -- unlike the HLO around it -- hashed as it stands into the
+    persistent compile cache's key (PERF.md section 6, PR 33).  The frames
+    above ``paged_decode_round`` are this file's: the tick's when a request
+    first needs a shape, ``_load``'s worker's when a boot loads it from the
+    record.  With them out, both trace one program under one key, and an
+    edit that moves a line here no longer re-compiles the decode programs.
+    JAX's own libraries register themselves the same way; where this JAX
+    has no such registry the keys differ as before, and a program is at
+    worst compiled once more."""
+    try:
+        from jax._src import source_info_util
+
+        source_info_util.register_exclusion(os.path.abspath(__file__))
+    except Exception:  # noqa: BLE001 - a private registry: absent, keys differ
+        logger.debug("no source-location registry in this JAX", exc_info=True)
+
+
+def _abstract(x):
+    """``x`` as a lowering sees it and no more: shape, dtype and, where
+    the array is committed to its devices, the sharding -- uncommitted
+    arrays and host arrays lower alike (``jax.jit`` keys on exactly this)."""
+    import jax
+
+    committed = isinstance(x, jax.Array) and x.committed
+    return jax.ShapeDtypeStruct(
+        x.shape, x.dtype, sharding=x.sharding if committed else None)
 
 
 @functools.lru_cache(maxsize=None)
@@ -667,6 +724,15 @@ class GenServer:
         # program a fresh process traces and loads: (rows, chunk, nblk) of
         # prefill, (rows, nblk) of decode.  /stats reports their counts
         self._programs: Dict[str, set] = {"prefill": set(), "decode": set()}
+        # ... and the shapes this boot loaded before its first request,
+        # from the record an earlier boot of the same deployment left beside
+        # the persistent compile cache (_load_programs; '' = no record is
+        # kept: no cache, a mesh, a draft model)
+        self._loaded: Dict[str, set] = {"prefill": set(), "decode": set()}
+        self._boot_load_s = 0.0
+        self._missed = 0            # dispatched shapes it had not loaded
+        self._record_path = ""
+        self._identity = ""
         # flight-recorder scratch (utils/genperf.py): the bubble ledger
         # stamps the END of every tick and classifies the gap before the
         # NEXT one by how this one ended; the per-tick accumulators are
@@ -927,7 +993,15 @@ class GenServer:
             "steps_total": dict(self.steps_total),
             "tokens_emitted_total": self.tokens_emitted_total,
             "tick_errors_total": self.tick_errors_total,
-            "programs": {k: len(v) for k, v in self._programs.items()},
+            # distinct shapes dispatched since boot; of the record's, how
+            # many this boot loaded ahead and in how long; dispatched shapes
+            # it had not loaded (each traced and loaded by a request)
+            "programs": {
+                **{k: len(v) for k, v in self._programs.items()},
+                "loaded_at_boot": sum(map(len, self._loaded.values())),
+                "boot_load_s": round(self._boot_load_s, 3),
+                "missed": self._missed,
+            },
             # what the wake-up before a round's end rests on (_pace): a
             # round's device ms by row count, the guard it wakes ahead by,
             # the fenced round's slack the guard aims at
@@ -1030,7 +1104,9 @@ class GenServer:
                 self.draft_cfg, self.num_blocks, self.block_size)
             self._draft_allocator = BlockAllocator(self.num_blocks)
         self._register_decode_costs()
+        _keep_out_of_program_locations()
         self._init_carry()
+        self._load_programs()
         if self.prefix_ids is not None:
             # the shared prefix is computed ONCE, here, into pinned blocks:
             # one row whose table is those blocks.  Its full blocks are
@@ -1120,6 +1196,165 @@ class GenServer:
             temperature=self.temperature, top_k=self.top_k,
             top_p=self.top_p, eos_token=self.eos_token)
         return tok, key_data
+
+    # -- the programs, and the record of which ones this deployment runs ----
+
+    def _program(self, kind: str, *operands, state=None):
+        """The jitted program of ``kind`` and the arguments a dispatch
+        hands it, ``operands`` being the ones a batch brings (prefill:
+        tokens, tables, start, width; decode: tables, token, n_valid,
+        active, seen_eos, keys).  The one place that states them: a tick
+        passes arrays and calls it, the boot passes their shapes (and, as
+        ``state``, the parameters' and the pool's) and lowers it, and the
+        two cannot drift into different programs."""
+        from seldon_core_tpu.models.generate import (
+            paged_decode_round_jit,
+            paged_forward_jit,
+        )
+
+        params, pool = state or (self.params, self._pool)
+        if kind == "prefill":
+            toks, tables, start, width = operands
+            return (paged_forward_jit,
+                    (params, toks, pool, tables, start, width),
+                    {"cfg": self.cfg, "last_only": True})
+        tables, token, n_valid, active, seen, keys = operands
+        return (paged_decode_round_jit,
+                (params, pool, tables, token, n_valid, active, seen, keys,
+                 self.cfg),
+                {"span": self.span, "temperature": self.temperature,
+                 "top_k": self.top_k, "top_p": self.top_p,
+                 "eos_token": self.eos_token, "inplace": self._inplace})
+
+    def _note_program(self, kind: str, shape: tuple) -> None:
+        """A tick is about to dispatch ``shape``.  One the boot did not
+        load is traced and loaded by the ``jit`` call of this tick --
+        seconds on the scheduler thread, ``missed`` in /stats -- and
+        enters the record, so the next boot loads it ahead."""
+        seen = self._programs[kind]
+        if shape in seen:
+            return
+        seen.add(shape)
+        if shape not in self._loaded[kind]:
+            self._missed += 1
+            self._write_record()
+
+    def _write_record(self) -> None:
+        """The record anew: what this boot loaded and what it dispatched.
+        A directory that cannot be written ends the recording."""
+        if self._record_path and not write_program_record(
+                self._record_path, self._identity, {
+                    kind: self._loaded[kind] | self._programs[kind]
+                    for kind in self._programs}):
+            self._record_path = ""
+
+    def _deployment_identity(self) -> str:
+        """Everything that is static to the two programs, as one string:
+        a record is read only by a boot that would build the very same
+        programs from the very same shapes (a sweep at other ``slots``,
+        another model, the CPU's gather path beside the chip's kernel in
+        one cache directory each keep their own)."""
+        import jax
+
+        def digest(tree) -> str:
+            leaves, _ = jax.tree_util.tree_flatten_with_path(tree)
+            return hashlib.sha256(repr([
+                (jax.tree_util.keystr(path), x.shape, str(x.dtype))
+                for path, x in leaves]).encode()).hexdigest()[:16]
+
+        return json.dumps({
+            "cfg": repr(self.cfg), "params": digest(self.params),
+            "pool": digest(self._pool), "slots": self.slots,
+            "block_size": self.block_size, "num_blocks": self.num_blocks,
+            "prefill_chunk": self.prefill_chunk, "span": self.span,
+            "temperature": self.temperature, "top_k": self.top_k,
+            "top_p": self.top_p, "eos_token": self.eos_token,
+            "inplace": self._inplace, "role": self.role,
+        }, sort_keys=True)
+
+    def _load_programs(self) -> None:
+        """Load every shape the record lists before the first request.
+        Blocks ``_init_device``; a request that arrives meanwhile waits as
+        it waits for a compile.  A listed shape whose load raises is
+        dropped from the record and left to its first request.  A server
+        whose programs this cannot state exactly -- partitioned over a
+        mesh, with a draft model -- keeps no record and loads nothing, as
+        does one without a persistent cache."""
+        if self.mesh is not None or self.spec:
+            return
+        self._identity = self._deployment_identity()
+        self._record_path = program_record_path(self._identity)
+        if not self._record_path:
+            return
+        listed = read_program_record(self._record_path, self._identity)
+        jobs = [(kind, shape) for kind in sorted(listed)
+                for shape in sorted(listed[kind])]
+        if not jobs:
+            return
+        t0 = time.perf_counter()
+        for kind, shape in self._load(jobs):
+            self._loaded[kind].add(shape)
+        self._boot_load_s = time.perf_counter() - t0
+        n = sum(map(len, self._loaded.values()))
+        logger.info("loaded %d of the record's %d programs in %.1f s (%s)",
+                    n, len(jobs), self._boot_load_s, self._record_path)
+        if n < len(jobs):
+            self._write_record()    # without the ones that raised
+
+    def _load(self, jobs: list) -> list:
+        """Bring each ``(kind, shape)`` to the state its first dispatch
+        needs -- traced, lowered, fetched from the persistent cache (or
+        compiled, cold) and loaded -- from abstract arguments: nothing
+        runs, the pool is not donated.  ``.lower().compile()`` fills the
+        very caches the ``jit`` call reads, so the tick finds the program
+        ready and dispatches as ever (and the persistent cache's key is
+        the one a tick's own trace would give:
+        ``_keep_out_of_program_locations``).  One worker traces and
+        lowers, program after program (Python under the GIL: threads that
+        share it only slow each other), and ``_LOAD_THREADS`` behind it
+        fetch and load what it hands them.  Returns the jobs that loaded;
+        one that raised is logged."""
+        import jax
+
+        state = jax.tree_util.tree_map(_abstract, (self.params, self._pool))
+        take = _carry_ops()[0]
+
+        def lower(job):
+            kind, shape = job
+            B = shape[0]
+
+            def S(shape, dtype=np.int32):
+                return jax.ShapeDtypeStruct(shape, dtype)
+
+            if kind == "prefill":
+                _, C, nblk = shape
+                operands = (S((B, C)), S((B, nblk)), S((B,)), S((B,)))
+            else:
+                # what `take` hands a round, by its own account
+                token, seen, keys = jax.eval_shape(
+                    take, self._carry, S((B,)))
+                operands = (S(shape), token, S((B,)), S((B,), bool), seen,
+                            _abstract(self._zero_keys[B])
+                            if keys is None else keys)
+            fn, args, kw = self._program(kind, *operands, state=state)
+            return fn.lower(*args, **kw)
+
+        loaded = []
+        with concurrent.futures.ThreadPoolExecutor(1) as tracer, \
+                concurrent.futures.ThreadPoolExecutor(
+                    min(_LOAD_THREADS, len(jobs))) as loaders:
+            lowered = [tracer.submit(lower, job) for job in jobs]
+            done = [loaders.submit(lambda fut: fut.result().compile(), fut)
+                    for fut in lowered]
+            for job, fut in zip(jobs, done):
+                try:
+                    fut.result()
+                    loaded.append(job)
+                except Exception as e:  # noqa: BLE001 - a hint must not stop a boot
+                    logger.warning(
+                        "%s program %s did not load ahead of its dispatch "
+                        "(%s: %s)", *job, type(e).__name__, e, exc_info=True)
+        return loaded
 
     def _register_decode_costs(self) -> None:
         """Analytic per-token cost features for the SERVED decode lane,
@@ -1874,7 +2109,7 @@ class GenServer:
                 self._blocks_needed(int(start[i]) + widths[i])
                 for i in range(len(batch))
             ))
-            self._programs["prefill"].add((B, C, nblk))
+            self._note_program("prefill", (B, C, nblk))
             tables = np.zeros((B, nblk), np.int32)
             for i, seq in enumerate(batch):
                 tables[i] = self._table(seq, nblk)
@@ -1919,10 +2154,9 @@ class GenServer:
                      kv_positions=int(start.sum()) + sum(widths))
               if fenced else _Phase("GenServer._prefill_tick/build")):
             t_dispatch = time.perf_counter()
-            logits, self._pool = paged_forward_jit(
-                self.params, toks, self._pool, tables, start, width,
-                cfg=self.cfg, last_only=True,
-            )
+            fn, args, kw = self._program(
+                "prefill", toks, tables, start, width)
+            logits, self._pool = fn(*args, **kw)
             if self.spec:
                 d_nblk = _pow2(max(
                     self._blocks_needed(seq.prefill_pos + widths[i])
@@ -2049,8 +2283,6 @@ class GenServer:
         token readback the streams need, now or a tick later."""
         import jax
 
-        from seldon_core_tpu.models.generate import paged_decode_round_jit
-
         with _Phase("GenServer._decode_round/capacity"):
             for seq in self._decodable():
                 if seq not in self._active or seq.done:
@@ -2075,7 +2307,7 @@ class GenServer:
                 max(self._blocks_needed(s.n_valid + self.span)
                     for s in batch),
                 self._allocator.capacity)
-            self._programs["decode"].add((B, nblk))
+            self._note_program("decode", (B, nblk))
             tables = np.zeros((B, nblk), np.int32)
             n_valid = np.zeros((B,), np.int32)
             active = np.zeros((B,), bool)
@@ -2122,16 +2354,10 @@ class GenServer:
             t_dispatch = time.perf_counter()
             take, put, _ = _carry_ops()
             token, seen, keys = take(self._carry, idx)
-            toks, self._pool, token, _nv, seen, keys = (
-                paged_decode_round_jit(
-                    self.params, self._pool, tables, token, n_valid,
-                    active, seen,
-                    self._zero_keys[B] if keys is None else keys,
-                    self.cfg, span=self.span, temperature=self.temperature,
-                    top_k=self.top_k, top_p=self.top_p,
-                    eos_token=self.eos_token, inplace=self._inplace,
-                )
-            )
+            fn, args, kw = self._program(
+                "decode", tables, token, n_valid, active, seen,
+                self._zero_keys[B] if keys is None else keys)
+            toks, self._pool, token, _nv, seen, keys = fn(*args, **kw)
             self._carry, key_data = put(
                 self._carry, idx, token, seen,
                 keys if self.temperature > 0.0 else None)

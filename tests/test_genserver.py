@@ -7,6 +7,7 @@ round, int8 KV pools, shared-prefix block reuse, and speculative
 draft/verify rounds."""
 
 import json
+import threading
 import time
 
 import jax
@@ -317,12 +318,12 @@ def test_in_place_a_decode_round_has_one_program_per_row_count(
 
     toks, shapes, counts = serve()
     assert shapes == {(b, w) for b in (1, 2) for w in (2, 4, 8)}
-    assert counts == {"prefill": 2, "decode": 6}
+    assert (counts["prefill"], counts["decode"]) == (2, 6)
     monkeypatch.setattr(gen_mod, "decode_inplace",
                         lambda pool, mesh=None: "interpret")
     toks_inplace, shapes, counts = serve()
     assert shapes == {(1, 32), (2, 32)}    # 63 blocks a row can hold
-    assert counts == {"prefill": 2, "decode": 2}
+    assert (counts["prefill"], counts["decode"]) == (2, 2)
     np.testing.assert_array_equal(toks_inplace, toks)
     np.testing.assert_array_equal(toks, np.asarray(generate(
         params, jnp.asarray(prompts, jnp.int32), CFG, max_new_tokens=14)))
@@ -1244,3 +1245,340 @@ def test_the_wake_up_guard_follows_the_wait_for_the_round_paced_against(
     assert srv._guard_s == 0.1                  # half a round bounds it
     assert srv.snapshot()["pace"] == {
         "round_ms": {"4": 200.0}, "guard_ms": 100.0, "slack_ms": 10000.0}
+
+
+# -- the record of a deployment's programs, and the boot that loads them -----
+
+_JAX_EVENTS: list = []      # [] while no test listens; else [names]
+_LOWERED_OR_COMPILED = ("jaxpr_to_mlir_module_duration",
+                        "backend_compile_duration")
+
+
+@pytest.fixture(scope="module")
+def _jax_events_listener():
+    import jax.monitoring
+
+    def on_duration(name, secs, **kw):
+        if _JAX_EVENTS:
+            name = name.rsplit("/", 1)[-1]
+            _JAX_EVENTS[0].append(name)
+            if name == "jaxpr_to_mlir_module_duration":
+                _JAX_EVENTS[0].append(
+                    "lowered on " + threading.current_thread().name)
+
+    jax.monitoring.register_event_duration_secs_listener(on_duration)
+
+
+@pytest.fixture
+def jax_events(_jax_events_listener):
+    """JAX's own monitoring duration events while the test runs: the list
+    of their names, to clear and to count."""
+    names: list = []
+    _JAX_EVENTS.append(names)
+    yield names
+    _JAX_EVENTS.clear()
+
+
+@pytest.fixture
+def cache_dir(tmp_path, monkeypatch):
+    """JAX's persistent cache configured with a directory of the test's
+    own, as ``enable_compile_cache()`` configures it in an engine, and the
+    setting restored: the record follows it.  The prefill chunk is pinned,
+    so which shapes a server dispatches is arithmetic, not timing."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    monkeypatch.setenv("SELDON_TPU_GEN_PREFILL_CHUNK_MAX", "4")
+    monkeypatch.delenv("SELDON_COMPILE_CACHE", raising=False)
+    before = jax.config.jax_compilation_cache_dir
+    jax.config.update("jax_compilation_cache_dir", str(tmp_path))
+    compilation_cache.reset_cache()
+    yield tmp_path
+    jax.config.update("jax_compilation_cache_dir", before)
+    compilation_cache.reset_cache()
+
+
+_RECORD_PROMPTS = np.random.default_rng(33).integers(0, 48, size=(3, 7))
+
+
+def _records(cache_dir):
+    return sorted(cache_dir.glob("genserver-programs-*.json"))
+
+
+def _serve_recorded(srv, prompts=_RECORD_PROMPTS):
+    """Three rows together, then one alone, then a stream: the same
+    shapes whatever the timing.  Returns every token served."""
+    out = [srv.submit(prompts.astype(float)).future.result(timeout=240),
+           srv.submit(prompts[:1].astype(float)).future.result(timeout=240)]
+    out += list(srv.stream(prompts[1:2].astype(float), chunk=3))
+    _settle(srv)
+    return [np.asarray(o).tolist() for o in out]
+
+
+def _shapes(srv):
+    return {k: set(v) for k, v in srv._programs.items()}
+
+
+def _boot_and_serve_one(params, **kw):
+    """One boot that serves one single-row request: its tokens and its
+    settled ``programs`` block."""
+    srv = _server(params, **kw)
+    try:
+        got = srv.submit(
+            _RECORD_PROMPTS[:1].astype(float)).future.result(timeout=240)
+        return got, _settle(srv)["programs"]
+    finally:
+        srv.stop()
+
+
+def test_program_record_round_trips(tmp_path, caplog):
+    from seldon_core_tpu.runtime.compilecache import (
+        read_program_record,
+        write_program_record,
+    )
+
+    path = str(tmp_path / "record.json")
+    empty = {"prefill": set(), "decode": set()}
+    with caplog.at_level("WARNING"):
+        assert read_program_record(path, "dep") == empty   # absent: silent
+    assert not caplog.records
+    programs = {"prefill": {(1, 4, 1), (4, 4, 2)}, "decode": {(4, 8)}}
+    assert write_program_record(path, "dep", programs)
+    assert read_program_record(path, "dep") == programs
+    assert [f.name for f in tmp_path.iterdir()] == ["record.json"]
+    # a directory that cannot be written: False, and the caller stops
+    assert not write_program_record(
+        str(tmp_path / "absent" / "record.json"), "dep", programs)
+
+
+def test_a_second_boot_loads_what_the_first_dispatched(
+        params, cache_dir, jax_events, monkeypatch):
+    """The first server of a deployment traces and loads each shape when
+    a request first needs it and writes the record once a new shape; the
+    second loads them all inside ``_init_device``, then serves the same
+    requests with nothing traced, lowered or compiled, nothing missed,
+    nothing written, and the same tokens and chunks."""
+    from seldon_core_tpu.runtime import genserver as gs_mod
+
+    writes = []
+    real_write = gs_mod.write_program_record
+    monkeypatch.setattr(
+        gs_mod, "write_program_record",
+        lambda *a: writes.append(a[0]) or real_write(*a))
+    first = _server(params)
+    try:
+        first._ensure_device()
+        assert not _records(cache_dir)          # nothing dispatched yet
+        want = _serve_recorded(first)
+        shapes = _shapes(first)
+        progs = first.snapshot()["programs"]
+    finally:
+        first.stop()
+    n = len(shapes["prefill"]) + len(shapes["decode"])
+    assert n >= 4 and len(writes) == n          # once a new shape
+    assert progs == {"prefill": len(shapes["prefill"]),
+                     "decode": len(shapes["decode"]),
+                     "loaded_at_boot": 0, "boot_load_s": 0.0, "missed": n}
+    (record,) = _records(cache_dir)
+    doc = json.loads(record.read_text())
+    assert {k: {tuple(x) for x in doc[k]} for k in shapes} == shapes
+    assert "LMConfig(" in doc["identity"]       # names what it belongs to
+
+    jax.clear_caches()                          # as a new process would be
+    del writes[:]
+    second = _server(params)
+    try:
+        del jax_events[:]
+        second._ensure_device()
+        # the boot did the lowering, once a listed shape
+        assert jax_events.count("jaxpr_to_mlir_module_duration") >= n
+        assert second._loaded == shapes
+        del jax_events[:]
+        got = _serve_recorded(second)
+        assert not [e for e in jax_events if e in _LOWERED_OR_COMPILED]
+        progs = second.snapshot()["programs"]
+    finally:
+        second.stop()
+    assert got == want
+    assert progs["loaded_at_boot"] == n and progs["missed"] == 0
+    assert progs["boot_load_s"] > 0.0
+    assert (progs["prefill"], progs["decode"]) == (
+        len(shapes["prefill"]), len(shapes["decode"]))
+    assert not writes                           # no set grew past the record
+
+
+def test_a_shape_outside_the_record_runs_is_missed_and_enters_the_record(
+        params, cache_dir, jax_events):
+    """... traced by the tick's own ``jit`` call, as ever: the boot's
+    worker and the tick trace one program under one cache key because
+    this file's frames stay out of a program's source locations."""
+    from jax._src import source_info_util
+
+    from seldon_core_tpu.runtime import genserver as gs_mod
+
+    first = _server(params)
+    try:
+        first.submit(_RECORD_PROMPTS[:1].astype(float)).future.result(
+            timeout=240)
+        few = _shapes(first)
+    finally:
+        first.stop()
+    jax.clear_caches()
+    second = _server(params)
+    try:
+        second._ensure_device()
+        del jax_events[:]
+        got = _serve_recorded(second)           # three rows: new shapes
+        progs = _settle(second)["programs"]
+        every = _shapes(second)
+    finally:
+        second.stop()
+    assert {e for e in jax_events if e.startswith("lowered on ")} == {
+        "lowered on genserver"}
+    assert not source_info_util.is_user_filename(gs_mod.__file__)
+    assert source_info_util.is_user_filename(__file__)
+    n_few = len(few["prefill"]) + len(few["decode"])
+    n_every = len(every["prefill"]) + len(every["decode"])
+    assert progs["loaded_at_boot"] == n_few
+    assert progs["missed"] == n_every - n_few > 0
+    np.testing.assert_array_equal(got[0], np.asarray(generate(
+        params, jnp.asarray(_RECORD_PROMPTS, jnp.int32), CFG,
+        max_new_tokens=10)))
+    third = _server(params)
+    try:
+        third._ensure_device()
+        assert third._loaded == every
+    finally:
+        third.stop()
+
+
+@pytest.mark.parametrize("other", ["slots", "span", "inplace"])
+def test_a_record_of_another_identity_is_not_read(
+        params, cache_dir, other, monkeypatch):
+    from seldon_core_tpu.models import generate as gen_mod
+
+    _boot_and_serve_one(params, max_new_tokens=4)
+    assert len(_records(cache_dir)) == 1
+    if other == "inplace":
+        monkeypatch.setattr(gen_mod, "decode_inplace",
+                            lambda pool, mesh=None: "interpret")
+    _, progs = _boot_and_serve_one(
+        params, max_new_tokens=4,
+        **{"slots": {"slots": 4}, "span": {"span": 2}}.get(other, {}))
+    assert progs["loaded_at_boot"] == 0 and progs["missed"] > 0
+    assert len(_records(cache_dir)) == 2        # each keeps its own
+
+
+@pytest.mark.parametrize("damage", ["truncated", "not_json", "version",
+                                    "shape"])
+def test_a_damaged_record_is_ignored_with_one_warning_and_a_normal_boot(
+        params, cache_dir, damage, caplog):
+    want, _ = _boot_and_serve_one(params, max_new_tokens=4)
+    (record,) = _records(cache_dir)
+    text = record.read_text()
+    doc = json.loads(text)
+    if damage == "truncated":
+        record.write_text(text[:len(text) // 2])
+    elif damage == "not_json":
+        record.write_bytes(b"\x00\xff record")
+    elif damage == "version":
+        record.write_text(json.dumps({**doc, "version": 99}))
+    else:
+        record.write_text(json.dumps({**doc, "decode": [[1, -4], "x"]}))
+    second = _server(params, max_new_tokens=4)
+    try:
+        with caplog.at_level("WARNING"):
+            second._ensure_device()
+        warned = [r for r in caplog.records if "program record" in r.message]
+        assert len(warned) == 1 and str(record) in warned[0].getMessage()
+        assert second.snapshot()["programs"]["loaded_at_boot"] == 0
+        got = second.submit(
+            _RECORD_PROMPTS[:1].astype(float)).future.result(timeout=240)
+        np.testing.assert_array_equal(got, want)
+    finally:
+        second.stop()
+    assert json.loads(record.read_text()) == doc    # written anew, whole
+
+
+def test_a_listed_shape_whose_load_raises_is_dropped_and_left_to_its_request(
+        params, cache_dir, caplog, monkeypatch):
+    first = _server(params)
+    try:
+        want = _serve_recorded(first)
+        shapes = _shapes(first)
+    finally:
+        first.stop()
+    bad = sorted(shapes["decode"])[0]
+    real_program = GenServer._program
+
+    def program(self, kind, *operands, state=None):
+        if (state is not None and kind == "decode"
+                and tuple(operands[0].shape) == bad):
+            raise RuntimeError("no such program")
+        return real_program(self, kind, *operands, state=state)
+
+    monkeypatch.setattr(GenServer, "_program", program)
+    second = _server(params)
+    try:
+        with caplog.at_level("WARNING"):
+            second._ensure_device()
+        warned = [r.getMessage() for r in caplog.records
+                  if "did not load" in r.getMessage()]
+        assert len(warned) == 1 and str(bad) in warned[0]
+        (record,) = _records(cache_dir)
+        assert list(bad) not in json.loads(record.read_text())["decode"]
+        n = len(shapes["prefill"]) + len(shapes["decode"])
+        assert second.snapshot()["programs"]["loaded_at_boot"] == n - 1
+        assert _serve_recorded(second) == want
+        assert second.snapshot()["programs"]["missed"] == 1
+        assert list(bad) in json.loads(record.read_text())["decode"]
+    finally:
+        second.stop()
+
+
+@pytest.mark.parametrize("kind", ["no_cache", "cache_switched_off", "mesh",
+                                  "speculative"])
+def test_servers_that_keep_no_record_write_and_load_nothing(
+        params, cache_dir, kind, monkeypatch, devices8):
+    """No persistent cache, or programs the server cannot state from its
+    own shapes (partitioned over a mesh, a draft model beside the
+    target): today's behaviour, twice over -- the second boot finds
+    nothing to load."""
+    from seldon_core_tpu.models.speculative import SpeculativeGenerator
+    from seldon_core_tpu.parallel.mesh import MeshSpec, build_mesh
+
+    kw = dict(block_size=4, num_blocks=64, slots=4, span=3, prefill_chunk=4)
+    if kind == "no_cache":
+        jax.config.update("jax_compilation_cache_dir", None)
+    elif kind == "cache_switched_off":
+        monkeypatch.setenv("SELDON_COMPILE_CACHE", "0")
+
+    def build():
+        if kind == "speculative":
+            unit = SpeculativeGenerator(
+                vocab=48, d_model=32, n_heads=4, n_layers=2, d_ff=64,
+                max_new_tokens=6, k=3, dtype="float32")
+            return GenServer(
+                **unit.continuous_spec(unit.init_state(jax.random.key(0))),
+                **kw)
+        if kind == "mesh":
+            from seldon_core_tpu.models.generate import TransformerGenerator
+
+            unit = TransformerGenerator(
+                vocab=64, d_model=32, n_heads=2, n_layers=2, d_ff=64,
+                max_new_tokens=6, dtype="float32", eos_token=-1,
+                mesh=build_mesh(MeshSpec({"tp": 2}), devices=devices8[:2]))
+            return GenServer(
+                **unit.continuous_spec(unit.init_state(None)), **kw)
+        return _server(params, max_new_tokens=6, **kw)
+
+    for _ in range(2):
+        srv = build()
+        try:
+            srv.submit(_RECORD_PROMPTS[:1].astype(float)).future.result(
+                timeout=240)
+            progs = _settle(srv)["programs"]
+        finally:
+            srv.stop()
+        assert progs["loaded_at_boot"] == 0 and progs["boot_load_s"] == 0.0
+        assert progs["missed"] == progs["prefill"] + progs["decode"] > 0
+        assert not _records(cache_dir)
