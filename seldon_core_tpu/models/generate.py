@@ -15,7 +15,9 @@ One KV layout, one decoder block, three programs:
     reads or writes it;
   * ``paged_forward`` (a prompt, a prompt chunk or a verify pass),
     ``paged_decode_round`` (``span`` single-token steps as ONE ``lax.scan``
-    inside jit — no per-token dispatch, no host round trip between steps)
+    inside jit — no per-token dispatch, no host round trip between steps;
+    for a generator by diffusion over blocks, ``LMConfig.block_length`` > 1,
+    ``span / block_length`` blocks of denoising passes, ``_denoising_round``)
     and ``paged_spec_round`` (draft + verify) are the device programs.
 
 Two lanes drive those programs and differ only in who owns the pool and
@@ -27,9 +29,10 @@ the tables:
     tables, prefills the whole prompt in one ``paged_forward`` and decodes
     in one round (``generate``) or one round per client chunk
     (``stream_chunks``).  It traces under ``jit``, so it is what serves
-    where the scheduler cannot: an MoE generator (capacity routing couples
-    co-batched rows), a generator inside a graph of several units, and
-    ``SELDON_TPU_GEN_CONTINUOUS=0``.
+    where the scheduler cannot: a generator whose experts are capacity-
+    routed (``moe_every``: the capacity couples co-batched rows; dropless
+    experts, ``d_expert``, do not and ride the scheduler), a generator
+    inside a graph of several units, and ``SELDON_TPU_GEN_CONTINUOUS=0``.
 
 The cache-free forward (training, ``lm_apply``) is models/transformer.py.
 """
@@ -238,7 +241,7 @@ def init_block_pool(cfg: LMConfig, num_blocks: int, block_size: int
     the allocator (runtime/genserver.py) hands out ids >= 1.  int8 pools
     carry per-position f32 scale planes (``[num_blocks, block_size, KV]``,
     ~6% over the values at hd=64)."""
-    hd = cfg.d_model // cfg.n_heads
+    hd = cfg.hd
     kv = cfg.kv_heads
     # XLA:CPU has no native bf16 scatter: a bf16 pool pays TWO whole-pool
     # converts (bf16 -> f32 scatter -> bf16) around EVERY write, which
@@ -313,76 +316,137 @@ def _paged_write(layer, tables, pos, valid, k_new, v_new):
     return out
 
 
-def _attend_paged(q, view, start):
+def _attend_paged(q, view, start, block_length: int = 1, limit=None):
     """q [B, H, W, hd] over a dense paged view; query i of row b sees
     positions <= start[b] + i (its own fresh K/V is already in the pool).
     Per-row ``start`` is what separates this from _attend_cached_causal:
     co-scheduled rows sit at different sequence lengths.  W == 1 with
-    start == n_valid is exactly the cached decode mask (kpos <= n_valid)."""
+    start == n_valid is exactly the cached decode mask (kpos <= n_valid).
+
+    ``block_length`` > 1 is the BLOCK-CAUSAL mask of generation by
+    diffusion over blocks: a position sees every key up to the END of its
+    own block of ``block_length``, the later ones of that block too — as
+    far as the row really holds them, ``limit`` [B] (= start + the row's
+    valid width): what lies past it in the pool is stale."""
     s = _grouped_qk(q, view["k"], view.get("k_s"))  # [B, KV, g, W, L]
     L = view["k"].shape[2]
     W = q.shape[2]
     qpos = start[:, None] + jnp.arange(W)[None, :]          # [B, W]
-    allowed = jnp.arange(L)[None, None, :] <= qpos[:, :, None]  # [B, W, L]
+    if block_length > 1:
+        ends = jnp.minimum((qpos // block_length + 1) * block_length,
+                           limit[:, None])
+        allowed = jnp.arange(L)[None, None, :] < ends[:, :, None]
+    else:
+        allowed = jnp.arange(L)[None, None, :] <= qpos[:, :, None]  # [B,W,L]
     s = jnp.where(allowed[:, None, None, :, :], s, -1e30)
     p = jax.nn.softmax(s, axis=-1)
     return _grouped_pv(p, view["v"], q.shape, q.dtype, view.get("v_s"))
 
 
+def _attend_view_and_fresh(q, view, start, k_new, v_new):
+    """q [B, H, W, hd] — one diffusion block of a row, at positions
+    ``start[b] ..`` — over a dense paged view taken BEFORE the block was
+    begun, and the block's own fresh K/V ``k_new`` / ``v_new``
+    [B, KV, W, hd]: every query sees the view's positions before ``start``
+    (the earlier blocks; what the view holds from there on is stale) and
+    the whole block, later positions too (the block-causal mask), in ONE
+    softmax over both.  The fresh K/V never pass through the pool, so a
+    view gathered once serves every pass over the block."""
+    s_old = _grouped_qk(q, view["k"], view.get("k_s"))      # [B,KV,g,W,L]
+    L = view["k"].shape[2]
+    earlier = jnp.arange(L)[None, :] < start[:, None]       # [B, L]
+    s_old = jnp.where(earlier[:, None, None, None, :], s_old, -1e30)
+    p = jax.nn.softmax(
+        jnp.concatenate([s_old, _grouped_qk(q, k_new)], axis=-1), axis=-1)
+    return (_grouped_pv(p[..., :L], view["v"], q.shape, q.dtype,
+                        view.get("v_s"))
+            + _grouped_pv(p[..., L:], v_new, q.shape, q.dtype))
+
+
 def _paged_block(lp, x, pool_layer, tables, start, valid, cfg: LMConfig,
-                 plan=None, interpret: bool = False):
+                 plan=None, interpret: bool = False, limit=None,
+                 kv_only: bool = False, view=None, write: bool = True):
     """One decoder block over the paged pool: K/V written at per-row
     positions start[b] + i (scratch-routed where ``valid`` is False),
-    attention over each row's own blocks.  x [B, W, D].
+    attention over each row's own blocks.  x [B, W, D] -> (x', pool layer',
+    the FFN's aux: the experts read by a dropless expert layer, else 0).
 
     ``plan`` (ops.paged_attention.decode_plan, width 1 only) selects the
     in-place formulation: attention reads the row's blocks from the pool
-    where they lie.  None takes the gather path."""
+    where they lie.  None takes the gather path.  ``limit`` [B] is how far
+    each row really holds positions, for the block-causal mask of
+    ``cfg.block_length`` > 1 (``_attend_paged``).  ``kv_only`` stops once
+    the K/V are written: the last layer of a pass whose hidden states
+    nobody reads (``_denoising_round``'s commit).  ``view`` is a dense view
+    of this layer's blocks gathered before a diffusion block was begun:
+    attention then goes over it and the fresh K/V together
+    (``_attend_view_and_fresh``) and gathers nothing; ``write`` False
+    leaves the pool as it is (a denoising pass: its K/V are not kept)."""
     from seldon_core_tpu.ops.paged_attention import paged_decode_attention
     from seldon_core_tpu.ops.quant import lm_matmul
 
     B, W, D = x.shape
-    hd = cfg.d_model // cfg.n_heads
+    hd = cfg.hd
     kv_h = cfg.kv_heads
+    q_out = cfg.n_heads * hd
     # the stages below are jax.named_scope's: op metadata only (same
     # programs, same numerics), read back from a profile window's device
     # ops by bench/lib/trace_scopes.py — keep the names stable
     with jax.named_scope("qkv"):
-        h = _rmsnorm(x, lp["ln1"])
+        h = _rmsnorm(x, lp["ln1"], cfg.norm_eps)
         qkv = lm_matmul(lp, "wqkv", h, out_dtype=x.dtype)
-        q, k, v = jnp.split(qkv, [D, D + kv_h * hd], axis=-1)
+        q, k, v = jnp.split(qkv, [q_out, q_out + kv_h * hd], axis=-1)
         q = _heads(q, B, W, cfg.n_heads, hd)
         k = _heads(k, B, W, kv_h, hd)
         v = _heads(v, B, W, kv_h, hd)
+    if cfg.qk_norm:
+        with jax.named_scope("qk_norm"):
+            q = _rmsnorm(q, lp["q_norm"], cfg.norm_eps)
+            k = _rmsnorm(k, lp["k_norm"], cfg.norm_eps)
     positions = start[:, None] + jnp.arange(W)[None, :]  # [B, W] per-row
     if cfg.rope:
         with jax.named_scope("rope"):
             q = apply_rope(q, positions, cfg.rope_base)
             k = apply_rope(k, positions, cfg.rope_base)
-    with jax.named_scope("kv_write"):
-        pool_layer = _paged_write(pool_layer, tables, positions, valid, k, v)
-    if plan is None:
+    if write:
+        with jax.named_scope("kv_write"):
+            pool_layer = _paged_write(pool_layer, tables, positions, valid,
+                                      k, v)
+    if kv_only:
+        return x, pool_layer, jnp.int32(0)
+    gathered = view is not None
+    if plan is None and not gathered:
         with jax.named_scope("kv_gather"):
             view = _paged_view(pool_layer, tables)
     with jax.named_scope("attn"):
-        if plan is None:
-            a = _attend_paged(q, view, start)
+        if gathered:
+            a = _attend_view_and_fresh(q, view, start, k, v)
+        elif plan is None:
+            a = _attend_paged(q, view, start, cfg.block_length, limit)
         else:
             a = paged_decode_attention(
                 q, pool_layer["k"], pool_layer["v"], tables, *plan,
                 interpret=interpret)
-        a = a.transpose(0, 2, 1, 3).reshape(B, W, D)
+        a = a.transpose(0, 2, 1, 3).reshape(B, W, q_out)
     with jax.named_scope("wo"):
         x = x + lm_matmul(lp, "wo", a, out_dtype=x.dtype)
     with jax.named_scope("ffn"):
-        h = _rmsnorm(x, lp["ln2"])
-        y, _lb = _ffn(lp, h, cfg, mesh=None)
+        h = _rmsnorm(x, lp["ln2"], cfg.norm_eps)
+        y, aux = _ffn(lp, h, cfg, mesh=None,
+                      valid=jnp.broadcast_to(valid, (B, W))
+                      if cfg.d_expert else None)
         x = x + y
-    return x, pool_layer
+    return x, pool_layer, aux
+
+
+def _head(params, cfg: LMConfig):
+    """The unembedding matrix [D, V]: the tied embedding, or ``lm_head``
+    where the configuration unties it."""
+    return params["embed"].T if cfg.tie_embeddings else params["lm_head"]
 
 
 def paged_forward(params, tokens, pool, tables, start, width,
-                  cfg: LMConfig, last_only: bool = True):
+                  cfg: LMConfig, last_only: bool = True, head: bool = True):
     """Forward W tokens per row at per-row offsets over the paged pool —
     chunked prefill (one prompt chunk at a time, decode never stalls for
     the whole prompt) and the speculative verify pass share this program.
@@ -393,15 +457,30 @@ def paged_forward(params, tokens, pool, tables, start, width,
     (logits, pool'): logits [B, V] at each row's LAST valid position when
     ``last_only`` (prefill needs only the next-token distribution — the
     unembed is ~20% of prefill FLOPs at real vocab sizes), else [B, W, V]
-    for every position (the verify pass scores all of them)."""
+    for every position (the verify pass scores all of them).
+
+    ``head`` False is for a caller that reads no logits (a generator by
+    diffusion over blocks chooses no token from its prompt): the final norm
+    and the unembedding are left out, and in the logits' place comes the
+    experts the call's expert layers read, an int32 (0 without experts)."""
     B, W = tokens.shape
     valid = jnp.arange(W)[None, :] < width[:, None]  # [B, W]
+    # block diffusion: a prompt's short last block sees itself and no
+    # further; a chunk is whole blocks (the scheduler's chunk is a multiple
+    # of block_length), so no position asks for a key a later chunk brings
+    limit = start + width if cfg.block_length > 1 else None
     with jax.named_scope("embed"):
         x = params["embed"][tokens]
+    read = jnp.int32(0)
     for i in range(cfg.n_layers):
-        x, pool[f"l{i}"] = _paged_block(
-            params[f"l{i}"], x, pool[f"l{i}"], tables, start, valid, cfg
+        x, pool[f"l{i}"], aux = _paged_block(
+            params[f"l{i}"], x, pool[f"l{i}"], tables, start, valid, cfg,
+            limit=limit,
         )
+        if cfg.d_expert:
+            read = read + aux
+    if not head:
+        return read, pool
     with jax.named_scope("unembed"):
         if last_only:
             idx = jnp.clip(width - 1, 0, W - 1)
@@ -409,21 +488,22 @@ def paged_forward(params, tokens, pool, tables, start, width,
                 x, jnp.broadcast_to(idx[:, None, None], (B, 1, x.shape[2])),
                 axis=1,
             )  # [B, 1, D] — before the (positionwise) norm: same numerics
-        x = _rmsnorm(x, params["ln_f"])
-        logits = (x @ params["embed"].T).astype(jnp.float32)
+        x = _rmsnorm(x, params["ln_f"], cfg.norm_eps)
+        logits = (x @ _head(params, cfg)).astype(jnp.float32)
     return (logits[:, 0, :] if last_only else logits), pool
 
 
-def decode_inplace(pool, mesh=None) -> bool:
+def decode_inplace(pool, mesh=None, width: int = 1) -> bool:
     """Whether a decode round over ``pool`` attends in place (the Pallas
     kernel) or through the gather path: ops.paged_attention
     .inplace_supported over what is observable here — the backend, the
-    pool's dtype and shapes, and the caller's mesh."""
+    pool's dtype and shapes, the caller's mesh and the queries a row brings
+    to a step (``width``: 1, or a diffusion block's ``block_length``)."""
     from seldon_core_tpu.ops.paged_attention import inplace_supported
 
     k = pool["l0"]["k"]
     return inplace_supported(
-        width=1, backend=jax.default_backend(), pool_dtype=k.dtype,
+        width=width, backend=jax.default_backend(), pool_dtype=k.dtype,
         mesh=mesh, block_size=k.shape[1], kv_heads=k.shape[2],
         head_dim=k.shape[3])
 
@@ -431,9 +511,12 @@ def decode_inplace(pool, mesh=None) -> bool:
 def paged_decode_round(params, pool, tables, token, n_valid, active,
                        seen_eos, keys, cfg: LMConfig, *, span: int,
                        temperature: float, top_k: int, top_p: float,
-                       eos_token: int, inplace=None):
+                       eos_token: int, inplace=None,
+                       trace_passes: bool = False):
     """``span`` cached decode steps for the whole in-flight batch as ONE
     lax.scan — the scheduler's unit of work between admission points.
+    (``cfg.block_length`` > 1: ``span`` positions as blocks of denoising
+    passes, ``_denoising_round`` below, with the same operands and results.)
 
     ``inplace``: None decides by ``decode_inplace(pool)`` (a caller that
     shards the pool over a mesh passes its own answer: a traced program
@@ -448,28 +531,37 @@ def paged_decode_round(params, pool, tables, token, n_valid, active,
     output contract — until the host retires them at the round boundary);
     keys [B] per-ROW PRNG keys (sampled decoding must not couple co-batched
     requests the way a shared batch key does).  Returns
-    (toks [B, span], pool', token', n_valid', seen_eos', keys')."""
+    (toks [B, span], pool', token', n_valid', seen_eos', keys') and, for a
+    configuration with dropless experts, a seventh: ``{"experts_read":
+    int32}``, the experts the round's expert layers read, summed over its
+    layers and steps."""
     from seldon_core_tpu.ops.paged_attention import decode_plan
 
+    if cfg.block_length > 1:
+        return _denoising_round(
+            params, pool, tables, token, n_valid, active, seen_eos, keys,
+            cfg, span=span, temperature=temperature, eos_token=eos_token,
+            trace_passes=trace_passes)
     if inplace is None:
         inplace = decode_inplace(pool)
     capacity = tables.shape[1] * pool["l0"]["k"].shape[1]
 
     def step(carry, _):
-        pool, token, n_valid, seen_eos, keys = carry
+        pool, token, n_valid, seen_eos, keys, *read = carry
         # the kernel's scalar operands: once a step, shared by the layers
         plan = decode_plan(n_valid, active, capacity) if inplace else None
         with jax.named_scope("embed"):
             x = params["embed"][token][:, None, :]
         for i in range(cfg.n_layers):
-            x, pool[f"l{i}"] = _paged_block(
+            x, pool[f"l{i}"], aux = _paged_block(
                 params[f"l{i}"], x, pool[f"l{i}"], tables, n_valid,
                 active[:, None], cfg, plan=plan,
                 interpret=inplace == "interpret",
             )
+            read = [r + aux for r in read]
         with jax.named_scope("unembed"):
-            x = _rmsnorm(x, params["ln_f"])
-            logits = (x[:, 0, :] @ params["embed"].T).astype(jnp.float32)
+            x = _rmsnorm(x, params["ln_f"], cfg.norm_eps)
+            logits = (x[:, 0, :] @ _head(params, cfg)).astype(jnp.float32)
         with jax.named_scope("sample"):
             if temperature <= 0.0:
                 nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
@@ -486,12 +578,143 @@ def paged_decode_round(params, pool, tables, token, n_valid, active,
                 seen_eos = seen_eos | (nxt == eos_token)
             nxt = jnp.where(active, nxt, 0)
             n_valid = n_valid + active.astype(jnp.int32)
-        return (pool, nxt, n_valid, seen_eos, keys), nxt
+        return (pool, nxt, n_valid, seen_eos, keys, *read), nxt
 
-    (pool, token, n_valid, seen_eos, keys), toks = jax.lax.scan(
-        step, (pool, token, n_valid, seen_eos, keys), None, length=span
+    # the experts read ride the carry only where there are experts: a dense
+    # configuration's program is the one it always was
+    read = (jnp.int32(0),) if cfg.d_expert else ()
+    (pool, token, n_valid, seen_eos, keys, *read), toks = jax.lax.scan(
+        step, (pool, token, n_valid, seen_eos, keys, *read), None,
+        length=span
     )
-    return toks.T, pool, token, n_valid, seen_eos, keys
+    out = (toks.T, pool, token, n_valid, seen_eos, keys)
+    return out + ({"experts_read": read[0]},) if read else out
+
+
+def _denoising_round(params, pool, tables, token, n_valid, active, seen_eos,
+                     keys, cfg: LMConfig, *, span: int, temperature: float,
+                     eos_token: int, trace_passes: bool = False):
+    """A decode round of a generator by diffusion over blocks: ``span /
+    block_length`` blocks a row, one after another (a ``lax.scan``), greedy.
+
+    The sequence is cut into blocks of ``L = cfg.block_length`` at
+    multiples of ``L``.  A row's first block starts where the last whole
+    block of its ``n_valid`` positions ends: the prompt's remainder stands
+    in it unmasked — ``token`` [B, L] holds those ids at the block's first
+    ``n_valid % L`` places (a ``[B]`` token is taken for every place: the
+    prefill chose none, and what stands at a masked place is not read) —
+    and the mask id elsewhere.  For ``cfg.denoising_steps`` passes
+    (``denoise``) the whole block goes through the model over the cache of
+    the earlier blocks under the block-causal mask; the logits at its
+    masked places give a candidate (the argmax over every id but the mask
+    id) and a confidence (that id's softmax probability), and the ``L /
+    denoising_steps`` masked places of highest confidence are fixed.  Then
+    the block goes through once more, whole (``commit``): that pass's K/V
+    is what the cache keeps, it needs no logits, and its last layer stops
+    at its K/V.
+
+    A denoising pass's K/V are NOT stored: each layer's blocks are gathered
+    into a dense view once a block, before its first pass, and every pass
+    attends over that view (the earlier blocks) and its own fresh K/V
+    together (``_attend_view_and_fresh``); only the commit writes the pool.
+    The gather path's price is then paid once a block and not once a pass
+    (five times), and ``_paged_block`` stays the one block both kinds of
+    round run.
+
+    Returns what ``paged_decode_round`` returns: the finished blocks
+    [B, span] (a row's NEW tokens are those from its ``n_valid`` on; after
+    a generated ``eos_token`` a row emits eos, the latch ``seen_eos``
+    carried on), the pool, a ``[B]`` token nobody reads, ``n_valid'`` (the
+    round's end), ``seen_eos'``, ``keys``; then ``{"experts_read": int32}``
+    where the configuration has experts; then, under ``trace_passes``
+    (the benchmark's driver, archs/<arch>/drive.py), what every denoising
+    pass ``saw``, ``picked`` and ``chose``, each [blocks, steps, B, L]."""
+    L, steps = cfg.block_length, cfg.denoising_steps
+    if span % L:
+        raise ValueError(f"span={span} is no whole number of blocks of {L}")
+    if temperature > 0.0:
+        raise ValueError("a round of denoising passes decodes greedily")
+    B = n_valid.shape[0]
+    if token.ndim == 1:
+        token = jnp.broadcast_to(token[:, None], (B, L))
+    base = n_valid - n_valid % L
+    valid = jnp.broadcast_to(active[:, None], (B, L))
+    head = _head(params, cfg)
+
+    def through(pool, views, x, start, commit: bool):
+        """The block's ids ``x`` [B, L] at ``start`` through every layer,
+        over ``views`` (the layers' caches as of the block's start); only
+        the commit writes the pool."""
+        read = jnp.int32(0)
+        with jax.named_scope("embed"):
+            h = params["embed"][x]
+        for i in range(cfg.n_layers):
+            h, layer, aux = _paged_block(
+                params[f"l{i}"], h, pool[f"l{i}"], tables, start, valid,
+                cfg, view=views[i], write=commit,
+                kv_only=commit and i == cfg.n_layers - 1)
+            if commit:
+                pool[f"l{i}"] = layer
+            if cfg.d_expert:
+                read = read + aux
+        return h, pool, read
+
+    def block(carry, b):
+        pool, seen_eos, read = carry
+        start = base + b * L
+        pos = start[:, None] + jnp.arange(L)[None, :]
+        masked = pos >= n_valid[:, None]
+        x = jnp.where(masked, jnp.int32(cfg.mask_id), token)
+        with jax.named_scope("kv_gather"):
+            views = [_paged_view(pool[f"l{i}"], tables)
+                     for i in range(cfg.n_layers)]
+
+        def denoise(c, _):
+            x, masked, read = c
+            with jax.named_scope("denoise"):
+                h, _, r = through(pool, views, x, start, commit=False)
+                with jax.named_scope("unembed"):
+                    h = _rmsnorm(h, params["ln_f"], cfg.norm_eps)
+                    logits = (h @ head).astype(jnp.float32)   # [B, L, V]
+                with jax.named_scope("sample"):
+                    # the mask id is never an answer
+                    logits = logits.at[..., cfg.mask_id].set(-jnp.inf)
+                    chose = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+                    sure = jnp.exp(jnp.max(logits, axis=-1)
+                                   - jax.nn.logsumexp(logits, axis=-1))
+                    sure = jnp.where(masked, sure, -1.0)
+                    rank = jnp.argsort(jnp.argsort(-sure, axis=-1), axis=-1)
+                    picked = masked & (rank < L // steps)
+            return ((jnp.where(picked, chose, x), masked & ~picked,
+                     read + r), (x, picked, chose))
+
+        (x, _, read), seen = jax.lax.scan(
+            denoise, (x, masked, read), None, length=steps)
+        with jax.named_scope("commit"):
+            _, pool, r = through(pool, views, x, start, commit=True)
+        out = x
+        if eos_token >= 0:
+            # only a GENERATED eos stops a row: the prompt's remainder may
+            # hold the id
+            hit = (x == eos_token) & (pos >= n_valid[:, None])
+            hits = hit.astype(jnp.int32)
+            after = (jnp.cumsum(hits, axis=1) - hits) > 0
+            out = jnp.where(seen_eos[:, None] | after,
+                            jnp.int32(eos_token), x)
+            seen_eos = seen_eos | jnp.any(hit, axis=1)
+        out = jnp.where(active[:, None], out, 0)
+        return (pool, seen_eos, read + r), (out, seen)
+
+    (pool, seen_eos, read), (toks, seen) = jax.lax.scan(
+        block, (pool, seen_eos, jnp.int32(0)), jnp.arange(span // L))
+    toks = toks.transpose(1, 0, 2).reshape(B, span)
+    n_valid = jnp.where(active, base + span, n_valid)
+    out = (toks, pool, jnp.zeros((B,), jnp.int32), n_valid, seen_eos, keys)
+    if cfg.d_expert:
+        out += ({"experts_read": read},)
+    if trace_passes:
+        out += (dict(zip(("saw", "picked", "chose"), seen)),)
+    return out
 
 
 def paged_spec_round(t_params, d_params, t_pool, d_pool, t_tables,
@@ -520,7 +743,7 @@ def paged_spec_round(t_params, d_params, t_pool, d_pool, t_tables,
         d_pool, tok, nv = carry
         x = d_params["embed"][tok][:, None, :]
         for i in range(d_cfg.n_layers):
-            x, d_pool[f"l{i}"] = _paged_block(
+            x, d_pool[f"l{i}"], _ = _paged_block(
                 d_params[f"l{i}"], x, d_pool[f"l{i}"], d_tables, nv,
                 active[:, None], d_cfg,
             )
@@ -572,12 +795,13 @@ def paged_copy_block(pool, src, dst):
 # one live pool pytree per model and rebinds it after each dispatch, so XLA
 # mutates the blocks in place instead of copying the whole pool per step
 paged_forward_jit = jax.jit(
-    paged_forward, static_argnames=("cfg", "last_only"), donate_argnums=(2,)
+    paged_forward, static_argnames=("cfg", "last_only", "head"),
+    donate_argnums=(2,)
 )
 paged_decode_round_jit = jax.jit(
     paged_decode_round,
     static_argnames=("cfg", "span", "temperature", "top_k", "top_p",
-                     "eos_token", "inplace"),
+                     "eos_token", "inplace", "trace_passes"),
     donate_argnums=(1,),
 )
 paged_spec_round_jit = jax.jit(
@@ -654,6 +878,44 @@ def _decode(params, carry, tables, cfg: LMConfig, n: int, knobs):
     return toks, tuple(carry)
 
 
+def _denoising_lane(params, prompt, cfg: LMConfig, max_new_tokens: int,
+                    chunk: Optional[int], temperature: float,
+                    eos_token: int):
+    """The static lane of a generator by diffusion over blocks
+    (``cfg.block_length`` > 1): the whole prompt prefilled (it chooses no
+    token), then rounds of whole blocks over the request's private pool —
+    ONE round for everything (``chunk`` None: ``generate``) or one a client
+    chunk, ``chunk`` rounded up to whole blocks (``stream_chunks``).  The
+    first round takes the prompt's remainder into its first block and
+    yields so many tokens fewer.  Yields [B, n] token arrays whose
+    concatenation is [B, max_new_tokens]; both drive the round the
+    scheduler drives, so their answers are its answers."""
+    B, S = prompt.shape
+    L = cfg.block_length
+    rem = S % L
+    whole = -(-(rem + max_new_tokens) // L) * L     # positions to generate
+    pool, tables = private_pool(cfg, B, S - rem + whole)
+    _, pool = paged_forward_jit(
+        params, prompt, pool, tables[:, :-(-S // BLOCK_SIZE)],
+        jnp.zeros((B,), jnp.int32), jnp.full((B,), S, jnp.int32), cfg=cfg,
+        last_only=True)
+    token = jnp.zeros((B, L), jnp.int32).at[:, :rem].set(prompt[:, S - rem:])
+    n_valid = jnp.full((B,), S, jnp.int32)
+    seen = jnp.zeros((B,), bool)
+    keys = jnp.zeros((B,), jnp.uint32)      # greedy: never read
+    per = whole if chunk is None else -(-int(chunk) // L) * L
+    done, skip = 0, rem
+    while done < max_new_tokens:
+        span = min(per, -(-(skip + max_new_tokens - done) // L) * L)
+        toks, pool, _, n_valid, seen, keys, *_ = paged_decode_round_jit(
+            params, pool, tables, token, n_valid, jnp.ones((B,), bool),
+            seen, keys, cfg, span=span, temperature=temperature, top_k=0,
+            top_p=0.0, eos_token=eos_token)
+        new = toks[:, skip:skip + max_new_tokens - done]
+        yield new
+        done, skip = done + new.shape[1], 0
+
+
 def generate(
     params,
     prompt,
@@ -689,6 +951,12 @@ def generate(
     anyway, so no device idle is added, only the host-side enqueue
     overlap of one dispatch."""
     B, S = prompt.shape
+    if cfg.block_length > 1:
+        # blocks of denoising passes: no first token from the prefill, one
+        # round for the whole answer (_denoising_lane)
+        return jnp.concatenate(list(_denoising_lane(
+            params, prompt, cfg, max_new_tokens, None, temperature,
+            eos_token)), axis=1)
     eager = _eager(prompt)
     t0 = time.perf_counter() if eager else 0.0
     first, carry, tables, knobs = _begin(
@@ -742,6 +1010,11 @@ def stream_chunks(params, prompt, cfg: LMConfig, max_new_tokens: int,
     B = prompt.shape[0]
     t0 = time.perf_counter()
     chunk = int(chunk)
+    if cfg.block_length > 1:
+        # one round of whole blocks a chunk (``chunk`` rounded up to them)
+        yield from _denoising_lane(params, prompt, cfg, max_new_tokens, chunk,
+                               temperature, eos_token)
+        return
     first, carry, tables, knobs = _begin(
         params, prompt, cfg, max_new_tokens, temperature, rng, top_k,
         top_p, eos_token, mesh)
@@ -802,7 +1075,12 @@ class TransformerGenerator(Unit):
                  n_experts: int = 8, moe_k: int = 2, mesh=None,
                  quant: str = "none", kv_quant: str = "none",
                  n_kv_heads: int = 0, weights_path: str = "",
-                 rope: bool = True, rope_base: float = 10000.0):
+                 rope: bool = True, rope_base: float = 10000.0,
+                 head_dim: int = 0, qk_norm: bool = False,
+                 norm_eps: float = 1e-6, tie_embeddings: bool = True,
+                 d_expert: int = 0, moe_norm_topk: bool = True,
+                 block_length: int = 1, denoising_steps: int = 1,
+                 mask_id: int = -1):
         # mesh (from the binding's mesh_axes, e.g. {"tp": 4}): params are
         # laid out with the LM's tp shardings and GSPMD partitions the
         # whole prefill+decode program across the mesh — one generator
@@ -817,7 +1095,18 @@ class TransformerGenerator(Unit):
             kv_quant=str(kv_quant),
             n_kv_heads=int(n_kv_heads),
             rope=bool(rope), rope_base=float(rope_base),
+            head_dim=int(head_dim), qk_norm=bool(qk_norm),
+            norm_eps=float(norm_eps), tie_embeddings=bool(tie_embeddings),
+            d_expert=int(d_expert), moe_norm_topk=bool(moe_norm_topk),
+            block_length=int(block_length),
+            denoising_steps=int(denoising_steps), mask_id=int(mask_id),
         )
+        if self.cfg.block_length > 1 and (
+                float(temperature) > 0.0 or str(prefix_tokens).strip()):
+            raise ValueError(
+                "a generator by diffusion over blocks decodes greedily and "
+                "takes no shared prefix (block_length > 1 with "
+                "temperature > 0 or prefix_tokens)")
         self.weights_path = str(weights_path)
         self.seed = int(seed)
         self.max_new_tokens = int(max_new_tokens)
@@ -837,10 +1126,12 @@ class TransformerGenerator(Unit):
                 raise ValueError(
                     f"prefix token {t} outside vocab [0, {self.cfg.vocab})")
         # sampled decoding keys each row by its index in the stacked batch,
-        # and MoE capacity routing couples rows (shared capacity over the
-        # flattened token stream) — either way, coalescing other callers'
-        # rows would change this caller's answer.  The request counter in
-        # state additionally varies the sampling key per request.
+        # and capacity-routed experts (moe_every) couple rows (shared
+        # capacity over the flattened token stream) — either way, coalescing
+        # other callers' rows would change this caller's answer; dropless
+        # experts (d_expert) route a token by that token alone and couple
+        # nothing.  The request counter in state additionally varies the
+        # sampling key per request.
         self.batch_coupled = (
             self.temperature > 0.0 or self.cfg.moe_every > 0
         )
@@ -902,9 +1193,12 @@ class TransformerGenerator(Unit):
         (runtime/genserver.py): everything the per-step scheduler needs to
         run this unit's decoding — params, config, sampling knobs, the
         shared prefix's ids.  Returns None when the unit cannot be
-        continuously scheduled: MoE capacity routing couples co-batched
-        rows through the shared expert-capacity reduction, so co-scheduling
-        other requests' rows would change this request's answer."""
+        continuously scheduled: capacity-routed experts (``moe_every``)
+        couple co-batched rows through the shared expert-capacity reduction,
+        so co-scheduling other requests' rows would change this request's
+        answer.  Dropless experts (``d_expert``) drop nothing and route a
+        token by that token alone: such a unit is scheduled like a dense
+        one, as is a generator by diffusion over blocks."""
         if self.cfg.moe_every > 0:
             return None
         return {

@@ -57,12 +57,24 @@ class LMConfig:
     n_heads: int = 4
     n_layers: int = 2
     d_ff: int = 512
-    # grouped-query attention (LLaMA-2/Mistral-style): n_kv_heads < n_heads
-    # shares each K/V head across n_heads/n_kv_heads query heads.  On TPU
-    # this is a SERVING lever first: the KV cache shrinks by the group
+    # grouped-query attention: n_kv_heads < n_heads shares each K/V head
+    # across n_heads/n_kv_heads query heads, in every forward here (the
+    # cache-free one, the paged programs, the in-place decode kernel).  On
+    # TPU this is a SERVING lever first: the KV cache shrinks by the group
     # factor, and cached decode is HBM-bound on exactly that stream.
     # 0 = multi-head attention (n_kv_heads == n_heads).
     n_kv_heads: int = 0
+    # width of one head where the source publishes it apart from
+    # d_model // n_heads (q is then n_heads * head_dim wide, not d_model);
+    # 0 = d_model // n_heads.  ``hd`` is the one place that resolves it.
+    head_dim: int = 0
+    # an RMSNorm over each q and each k head (one weight vector of ``hd``
+    # each a layer) before the rotary embedding
+    qk_norm: bool = False
+    # the eps of every RMSNorm
+    norm_eps: float = 1e-6
+    # False: the unembedding is its own matrix ``lm_head`` [D, V]
+    tie_embeddings: bool = True
     dtype: Any = jnp.bfloat16
     # MoE: every ``moe_every``-th block (1-indexed) swaps its dense FFN for
     # a mixture of ``n_experts`` experts, top-``moe_k`` routed, sharded over
@@ -88,6 +100,28 @@ class LMConfig:
     # KV cache stores rotated keys and cached decode needs no extra state.
     rope: bool = True
     rope_base: float = 10000.0
+    # DROPLESS routed experts (parallel/moe.py ``moe_dropless``): > 0 makes
+    # every layer's FFN ``n_experts`` gated-SiLU experts of this width, the
+    # top ``moe_k`` of a float32 softmax router a token, their weights
+    # renormalised over the chosen (``moe_norm_topk``).  No capacity, no
+    # token dropped: a row's answer does not depend on who shares its batch,
+    # which is what lets the scheduler co-batch such a generator.  Serving
+    # only (the paged programs); ``moe_every`` is the capacity-routed layer
+    # of ``lm_apply`` / ``lm_loss``.
+    d_expert: int = 0
+    moe_norm_topk: bool = True
+    # generation by diffusion over blocks (models/generate.py): > 1 makes a
+    # decode round ``denoising_steps`` passes over each block of
+    # ``block_length`` positions that start as ``mask_id``, under a
+    # block-causal mask; 1 = one token a step, causal.
+    block_length: int = 1
+    denoising_steps: int = 1
+    mask_id: int = -1
+
+    @property
+    def hd(self) -> int:
+        """Width of one attention head."""
+        return self.head_dim or self.d_model // self.n_heads
 
     def is_moe_layer(self, i: int) -> bool:
         return self.moe_every > 0 and (i + 1) % self.moe_every == 0
@@ -114,10 +148,23 @@ class LMConfig:
                 f"n_heads={self.n_heads} not divisible by "
                 f"n_kv_heads={kv}"
             )
-        if self.rope and (self.d_model // self.n_heads) % 2 != 0:
+        if self.rope and self.hd % 2 != 0:
             raise ValueError(
-                f"RoPE needs an even head dim, got "
-                f"{self.d_model // self.n_heads}"
+                f"RoPE needs an even head dim, got {self.hd}"
+            )
+        if self.d_expert and (self.moe_every
+                              or not 0 < self.moe_k <= self.n_experts):
+            raise ValueError(
+                "d_expert (dropless experts in every layer) needs "
+                "moe_every=0 and 0 < moe_k <= n_experts"
+            )
+        if self.block_length > 1 and (
+                self.block_length % self.denoising_steps
+                or not 0 <= self.mask_id < self.vocab):
+            raise ValueError(
+                f"block_length={self.block_length} needs denoising_steps "
+                f"that divide it and a mask_id inside the vocabulary, got "
+                f"{self.denoising_steps} and {self.mask_id}"
             )
 
     @property
@@ -185,17 +232,25 @@ def lm_init(rng, cfg: LMConfig) -> Dict[str, Any]:
     params: Dict[str, Any] = {
         "embed": dense(keys[0], (cfg.vocab, cfg.d_model), cfg.d_model),
     }
-    hd = cfg.d_model // cfg.n_heads
-    qkv_out = cfg.d_model + 2 * cfg.kv_heads * hd  # q | k | v segments
+    hd = cfg.hd
+    q_out = cfg.n_heads * hd
+    qkv_out = q_out + 2 * cfg.kv_heads * hd  # q | k | v segments
     for i in range(cfg.n_layers):
         k = keys[1 + 4 * i : 1 + 4 * (i + 1)]
         lp = {
             "ln1": jnp.ones((cfg.d_model,), dt),
             "wqkv": dense(k[0], (cfg.d_model, qkv_out), cfg.d_model),
-            "wo": dense(k[1], (cfg.d_model, cfg.d_model), cfg.d_model),
+            "wo": dense(k[1], (q_out, cfg.d_model), q_out),
             "ln2": jnp.ones((cfg.d_model,), dt),
         }
-        if cfg.is_moe_layer(i):
+        if cfg.qk_norm:
+            lp["q_norm"] = jnp.ones((hd,), dt)
+            lp["k_norm"] = jnp.ones((hd,), dt)
+        if cfg.d_expert:
+            from seldon_core_tpu.parallel.moe import dropless_init
+
+            lp.update(dropless_init(k[2], cfg))
+        elif cfg.is_moe_layer(i):
             from seldon_core_tpu.parallel.moe import MoEConfig, moe_init
 
             lp["moe"] = moe_init(
@@ -208,6 +263,10 @@ def lm_init(rng, cfg: LMConfig) -> Dict[str, Any]:
             lp["w2"] = dense(k[3], (cfg.d_ff, cfg.d_model), cfg.d_ff)
         params[f"l{i}"] = lp
     params["ln_f"] = jnp.ones((cfg.d_model,), dt)
+    if not cfg.tie_embeddings:
+        params["lm_head"] = dense(
+            jax.random.fold_in(keys[0], 1), (cfg.d_model, cfg.vocab),
+            cfg.d_model)
     return params
 
 
@@ -347,7 +406,7 @@ def _block(lp, x, cfg: LMConfig, mesh: Optional[Mesh], causal: bool,
     from seldon_core_tpu.ops.quant import lm_matmul
 
     B, S, D = x.shape
-    hd = cfg.d_model // cfg.n_heads
+    hd = cfg.hd
     kv = cfg.kv_heads
     h = _rmsnorm(x, lp["ln1"])
     qkv = lm_matmul(lp, "wqkv", h, out_dtype=x.dtype)  # [B,S,D+2*kv*hd]
@@ -371,8 +430,18 @@ def _block(lp, x, cfg: LMConfig, mesh: Optional[Mesh], causal: bool,
     return x + y, lb
 
 
-def _ffn(lp, h, cfg: LMConfig, mesh: Optional[Mesh]):
-    """Dense or MoE feed-forward on h [B,S,D] -> (y, lb_loss)."""
+def _ffn(lp, h, cfg: LMConfig, mesh: Optional[Mesh], valid=None):
+    """Feed-forward on h [B,S,D] -> (y, aux): the dense two-matrix
+    tanh-GELU FFN (aux 0), the capacity-routed experts of ``moe_every``
+    (aux the load-balance loss) or, for ``cfg.d_expert``, the dropless
+    routed gated-SiLU experts (aux the number of experts read; ``valid``
+    [B,S] keeps pad positions from picking any)."""
+    if cfg.d_expert:
+        from seldon_core_tpu.parallel.moe import moe_dropless
+
+        if valid is None:
+            valid = jnp.ones(h.shape[:2], bool)
+        return moe_dropless(lp, h, valid, cfg)
     if "moe" in lp:
         from seldon_core_tpu.parallel.moe import MoEConfig, moe_apply
 
@@ -394,6 +463,13 @@ def lm_apply(
     """tokens [B, S] int32 -> logits [B, S, V] (f32).  ``use_flash`` uses
     the Pallas flash kernel on single-chip meshes (differentiable).
     ``return_lb`` additionally returns the summed MoE load-balance loss."""
+    if (cfg.d_expert or cfg.qk_norm or cfg.head_dim or cfg.block_length > 1
+            or not cfg.tie_embeddings):
+        raise ValueError(
+            "the cache-free forward implements the repo's own block only; "
+            "a configuration with head_dim, qk_norm, an untied head, "
+            "dropless experts or block diffusion is served by the paged "
+            "programs (models/generate.py)")
     x = params["embed"][tokens]  # [B,S,D]
     lb_total = jnp.float32(0.0)
     for i in range(cfg.n_layers):

@@ -7,12 +7,23 @@ experts, experts live one shard per chip along ``ep``, and the token
 shuffle to/from expert shards is an all-to-all that XLA inserts from the
 sharding annotations (GSPMD — no hand-written collectives).
 
-Everything is static-shaped for the MXU: routing uses the classic
-dispatch/combine one-hot tensors (Switch-Transformer style) with a fixed
-per-expert capacity ``C = ceil(k * T * capacity_factor / E)``; tokens past
-capacity overflow and pass through on the residual path.  The heavy math is
-two batched einsums over ``[E, C, D]`` blocks, sharded ``P('ep', ...)`` so
-each chip multiplies only its experts' blocks.
+Two layers live here.
+
+``moe_apply`` (training, ``lm_apply`` / ``lm_loss``; ``LMConfig.moe_every``)
+is static-shaped for the MXU: routing uses the classic dispatch/combine
+one-hot tensors (Switch-Transformer style) with a fixed per-expert capacity
+``C = ceil(k * T * capacity_factor / E)``; tokens past capacity overflow and
+pass through on the residual path.  The heavy math is two batched einsums
+over ``[E, C, D]`` blocks, sharded ``P('ep', ...)`` so each chip multiplies
+only its experts' blocks.  Its capacity couples the rows of a batch.
+
+``moe_dropless`` (serving, the paged programs; ``LMConfig.d_expert``) drops
+nothing: a float32 softmax router, the top ``k`` a token, the picks sorted
+by expert and two grouped matmuls (``_grouped_matmul``) over the experts
+held.  A token's result depends on that token alone, so a row's answer is
+the same whoever shares its batch -- and the grouped matmul visits only the
+experts a pass chose, so the weights read grow with the experts CHOSEN, not
+the experts held.
 """
 
 from __future__ import annotations
@@ -26,7 +37,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 __all__ = ["MoEConfig", "moe_init", "moe_apply", "moe_param_shardings",
-           "moe_leaf_spec"]
+           "moe_leaf_spec", "dropless_init", "moe_dropless"]
 
 
 @dataclass(frozen=True)
@@ -171,3 +182,134 @@ def moe_apply(
     lb_loss = cfg.n_experts * jnp.sum(density * gates.mean(0))
     overflow = 1.0 - got.sum() / (cfg.k * T)
     return y.reshape(orig_shape), {"lb_loss": lb_loss, "overflow": overflow}
+
+
+# ---------------------------------------------------------------------------
+# The dropless layer of the served path
+# ---------------------------------------------------------------------------
+
+
+def dropless_init(rng, cfg) -> Dict[str, Any]:
+    """One layer's router and experts for ``cfg`` (an ``LMConfig`` with
+    ``d_expert`` > 0): ``router`` [D, E]; ``e_gate_up`` [E, D, 2F], each
+    expert's gate and up matrices side by side (one grouped matmul reads
+    both); ``e_down`` [E, F, D].  All in the model's dtype, drawn in it: an
+    expert stack is gigabytes at real widths and a float32 draw would be
+    twice that beside it."""
+    kr, kg, kd = jax.random.split(rng, 3)
+    E, D, F, dt = cfg.n_experts, cfg.d_model, cfg.d_expert, cfg.dtype
+
+    def dense(key, shape, fan_in):
+        return (jax.random.normal(key, shape, dt)
+                * jnp.asarray(fan_in ** -0.5, dt)).astype(dt)
+
+    return {"router": dense(kr, (D, E), D),
+            "e_gate_up": dense(kg, (E, D, 2 * F), D),
+            "e_down": dense(kd, (E, F, D), F)}
+
+
+# The grouped matmul's tiles on the TPU (megablox ``gmm``): a group's rows
+# against its expert's matrix, the WHOLE contraction and as much of the
+# output width as keeps one weight tile within ``_WEIGHT_TILE_BYTES`` (it is
+# double-buffered in a v5e's 16 MiB of scoped vector memory: 2048 x 1536
+# bf16 = 6.3 MB fits, a 256-row tile beside it does not), ``_ROW_TILE`` rows
+# at decode sizes and ``_ROW_TILE_WIDE`` from ``_WIDE_FROM`` rows up.
+# Measured on one TPU v5e (PERF.md section 6, PR 34, call P34b: 128 experts
+# of 2048 x 1536 and 768 x 2048, bf16, uniform top-8 picks; ms a matmul,
+# gate|up / down, beside the least time for the bytes of the experts hit):
+#   tokens (experts hit)   least        jax.lax.ragged_dot   gmm, these tiles
+#   16    (84)          0.65 / 0.32      0.99 / 0.67         0.76 / 0.40
+#   64    (125)         0.96 / 0.48      2.68 / 1.59         1.13 / 0.61
+#   1024  (128)         0.98 / 0.49      3.14 / 1.92         1.51 / 0.88
+# XLA's own lowering of ragged_dot streams the chosen experts at 35-60% of
+# the chip's bandwidth, these tiles at 80-85%, to the same bits; row tiles
+# of 16 / 32 / 64 read within 3% of each other, narrower output tiles
+# (512, 768, 1024) within 3% of the whole width.
+_ROW_TILE, _ROW_TILE_WIDE, _WIDE_FROM = 64, 128, 2048
+_WEIGHT_TILE_BYTES = 6_500_000
+
+
+def _ragged_dot(x, w, sizes):
+    return jax.lax.ragged_dot(x, w, sizes,
+                              preferred_element_type=jnp.float32)
+
+
+def _gmm(x, w, sizes, interpret=False):
+    from jax.experimental.pallas.ops.tpu.megablox import gmm
+
+    M, K = x.shape
+    N = w.shape[2]
+    tm = _ROW_TILE_WIDE if M >= _WIDE_FROM else min(_ROW_TILE, -(-M // 16) * 16)
+    tn = N
+    while (K * tn * w.dtype.itemsize > _WEIGHT_TILE_BYTES and tn % 256 == 0):
+        tn //= 2
+    rows = -(-M // tm) * tm             # whole row tiles: the pad lies past
+    if rows != M:                       # every group and is never computed
+        x = jnp.pad(x, ((0, rows - M), (0, 0)))
+    out = gmm(x, w, sizes, preferred_element_type=jnp.float32,
+              tiling=(tm, K, tn), interpret=interpret)
+    return out[:M]
+
+
+def _grouped_matmul(x, w, sizes, impl=None):
+    """x [M, K], its rows sorted by group, times w [G, K, N] by
+    ``sizes`` [G] rows a group -> [M, N] float32; rows past the last group
+    hold nothing defined.  A group without rows is not visited: its matrix
+    is not read.
+
+    ``impl`` None leaves the choice to the platform the program is LOWERED
+    for (``jax.lax.platform_dependent``: a program compiled ahead of time
+    for a described chip takes the chip's branch): megablox's ``gmm`` (a
+    Pallas TPU kernel) with the tiles above on a TPU,
+    ``jax.lax.ragged_dot`` anywhere else (the same contract; XLA's own
+    lowering).  ``"gmm_interpret"`` runs the kernel in interpret mode
+    (tests on the CPU)."""
+    if impl is None:
+        return jax.lax.platform_dependent(x, w, sizes, tpu=_gmm,
+                                          default=_ragged_dot)
+    if impl == "ragged_dot":
+        return _ragged_dot(x, w, sizes)
+    return _gmm(x, w, sizes, interpret=impl == "gmm_interpret")
+
+
+def moe_dropless(lp, h, valid, cfg, impl=None):
+    """Dropless top-k routed gated-SiLU experts on h [B, W, D] ->
+    (y [B, W, D], experts read: int32 scalar).
+
+    ``g = softmax(h Wr)`` over all experts in float32; the ``moe_k``
+    largest; ``w_e = g_e / sum of the chosen`` (``moe_norm_topk``);
+    ``y = sum_e w_e (silu(h W_gate,e) * (h W_up,e)) W_down,e``.
+
+    The T*k picks are sorted by expert and go through the experts as two
+    grouped matmuls (``_grouped_matmul``; ``impl`` is its), which visit a
+    group's weight tiles only where the group has rows: an expert nobody
+    chose is not read.  ``valid`` [B, W] marks the real tokens: a pad
+    position or an empty slot picks nothing (its picks sort behind every
+    group and lie outside all of them), so padding reads no expert and the
+    count returned -- groups with at least one row -- is of real work."""
+    B, W, D = h.shape
+    E, k, F = cfg.n_experts, cfg.moe_k, cfg.d_expert
+    x = h.reshape(B * W, D)
+    live = valid.reshape(B * W)
+    with jax.named_scope("router"):
+        logits = jax.lax.dot_general(
+            x, lp["router"], (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        gates = jax.nn.softmax(logits, axis=-1)
+        top_w, top_e = jax.lax.top_k(gates, k)                # [T, k]
+        if cfg.moe_norm_topk:
+            top_w = top_w / jnp.sum(top_w, axis=-1, keepdims=True)
+        pick = jnp.where(live[:, None], top_e, E).reshape(-1)  # [T*k]
+        order = jnp.argsort(pick, stable=True)
+        sizes = jnp.zeros((E + 1,), jnp.int32).at[pick].add(1)[:E]
+        read = jnp.count_nonzero(sizes).astype(jnp.int32)
+    with jax.named_scope("experts"):
+        xs = x[order // k]                                    # [T*k, D]
+        gu = _grouped_matmul(xs, lp["e_gate_up"], sizes, impl)
+        act = (jax.nn.silu(gu[:, :F]) * gu[:, F:]).astype(x.dtype)
+        ys = _grouped_matmul(act, lp["e_down"], sizes, impl)
+        # back to token order; rows past the last group hold nothing defined
+        ys = ys[jnp.argsort(order)].reshape(B * W, k, D)
+        y = jnp.sum(ys * top_w[..., None], axis=1)
+        y = jnp.where(live[:, None], y, 0.0).astype(h.dtype)
+    return y.reshape(B, W, D), read

@@ -313,7 +313,7 @@ class _Flight:
     next program may already be queued behind this one."""
 
     __slots__ = ("kind", "rows", "ready", "out", "keys", "t_dispatch",
-                 "t_done", "size", "chunk", "host_s", "attr")
+                 "t_done", "size", "chunk", "host_s", "attr", "read", "skip")
 
     def __init__(self, kind: str, size: int, rows: list, ready, out, keys,
                  t_dispatch: float, attr: tuple):
@@ -328,6 +328,9 @@ class _Flight:
         self.attr = attr              # cost ledger: (padded units, rows)
         self.t_done: Optional[float] = None   # observed completion
         self.chunk = 0                # prefill: the saturated chunk width
+        self.read = None              # the experts read (an int32), if any
+        self.skip = None              # decode: a row's tokens start so far
+        #                               into the round (its prompt's remainder)
         self.host_s = 0.0             # prefill: build + dispatch wall
 
 
@@ -624,6 +627,26 @@ class GenServer:
         self.span = span or _env_int("SELDON_TPU_GEN_SPAN", 8)
         self.prefill_chunk = prefill_chunk or _env_int(
             "SELDON_TPU_GEN_PREFILL_CHUNK", 128)
+        # a generator by diffusion over blocks (cfg.block_length > 1): a
+        # round is whole blocks of denoising passes, a prefill chooses no
+        # token, and a row's first round starts where its prompt's last
+        # whole block ends (_decode_round).  Blocks lie at multiples of the
+        # block length, so a round, a KV block and a prefill chunk are each
+        # a whole number of them
+        self._block = int(getattr(cfg, "block_length", 1))
+        if self._block > 1:
+            if (self.spec or prefix_ids is not None or self.temperature > 0.0
+                    or role in ("prefill", "decode")):
+                raise ValueError(
+                    "a generator by diffusion over blocks is served greedy, "
+                    "unified, without a draft model or a shared prefix")
+            for name, n in (("span", self.span),
+                            ("block_size", self.block_size),
+                            ("prefill_chunk", self.prefill_chunk)):
+                if n % self._block:
+                    raise ValueError(
+                        f"{name}={n} is no whole number of diffusion blocks "
+                        f"of {self._block}")
         # bounded admission queue: sustained overload must fail typed
         # (retryable 503 via LoadShedError) with flat memory, never grow
         # the waiting deques without limit.  Generous by default — the
@@ -747,6 +770,12 @@ class GenServer:
         self._tick_dev_steps = 0             # single-token device steps
         self._tick_inplace_steps = 0         # ... that attended in place
         self._tick_ahead_steps = 0           # ... dispatched ahead of a read
+        self._tick_passes = 0                # passes of the model dispatched
+        self._tick_row_passes = 0            # ... times the real rows in each
+        self._tick_expert_slots = 0          # experts held x layers x passes
+        self._tick_experts_read = 0          # experts the rounds read back
+        self._tick_prefill = [0, 0, 0]       # prefill: calls, experts read,
+        #                                      experts held x layers x calls
         self._tick_tokens = 0                # tokens emitted
         self._tick_retired = 0               # sequences retired
         self._phases: Dict[str, float] = {}  # phase -> host wall s
@@ -985,6 +1014,13 @@ class GenServer:
             },
             "block_size": self.block_size,
             "span": self.span,
+            # how a round decodes its span: blocks of so many positions,
+            # so many denoising passes a block (1 and 1: a token a step)
+            "round": {
+                "block_length": self._block,
+                "denoising_steps": int(
+                    getattr(self.cfg, "denoising_steps", 1)),
+            },
             "prefill_chunk": self.prefill_chunk,
             "prefill_chunk_effective": self._chunk_eff,
             "admitted_total": self.admitted_total,
@@ -1098,7 +1134,8 @@ class GenServer:
             self._pool = shard_gen_pool(self.mesh, self._pool)
         # the Pallas kernel or the gather path: decided here, once, because
         # only the scheduler sees the mesh its pool is sharded over
-        self._inplace = decode_inplace(self._pool, self.mesh)
+        self._inplace = decode_inplace(self._pool, self.mesh,
+                                       width=self._block)
         if self.spec:
             self._draft_pool = init_block_pool(
                 self.draft_cfg, self.num_blocks, self.block_size)
@@ -1174,6 +1211,8 @@ class GenServer:
                 # raw key data from the host: an imported row's (decode role)
                 key_data = np.asarray(key_data)
                 carry, _ = put(carry, idx, tok, seen, key_data)
+            if self._block > 1:
+                return      # a prefill of diffusion blocks picks no token
             first(carry, np.zeros((rows, self.cfg.vocab), np.float32), idx,
                   np.zeros((rows,), bool), np.zeros((rows,), np.int32),
                   key_data, temperature=self.temperature, top_k=self.top_k,
@@ -1215,9 +1254,13 @@ class GenServer:
         params, pool = state or (self.params, self._pool)
         if kind == "prefill":
             toks, tables, start, width = operands
+            kw = {"cfg": self.cfg, "last_only": True}
+            if self._block > 1:
+                # no token is chosen from a prompt: no head, and the
+                # experts read in the logits' place (paged_forward)
+                kw["head"] = False
             return (paged_forward_jit,
-                    (params, toks, pool, tables, start, width),
-                    {"cfg": self.cfg, "last_only": True})
+                    (params, toks, pool, tables, start, width), kw)
         tables, token, n_valid, active, seen, keys = operands
         return (paged_decode_round_jit,
                 (params, pool, tables, token, n_valid, active, seen, keys,
@@ -1333,6 +1376,8 @@ class GenServer:
                 # what `take` hands a round, by its own account
                 token, seen, keys = jax.eval_shape(
                     take, self._carry, S((B,)))
+                if self._block > 1:
+                    token = S((B, self._block))     # the host's (_decode_round)
                 operands = (S(shape), token, S((B,)), S((B,), bool), seen,
                             _abstract(self._zero_keys[B])
                             if keys is None else keys)
@@ -1369,9 +1414,14 @@ class GenServer:
             d, L = cfg.d_model, cfg.n_layers
             ff, v = cfg.d_ff, cfg.vocab
             kvh = getattr(cfg, "kv_heads", 0) or cfg.n_heads
-            hd = d // cfg.n_heads
-            qkv_out = d + 2 * kvh * hd
-            per_layer = d * qkv_out + d * d + 2 * d * ff
+            hd = cfg.hd
+            q_out = cfg.n_heads * hd
+            qkv_out = q_out + 2 * kvh * hd
+            per_layer = d * qkv_out + q_out * d + 2 * d * ff
+            if getattr(cfg, "d_expert", 0):
+                # a token's own work: the router and its moe_k experts
+                per_layer += d * cfg.n_experts - 2 * d * ff + (
+                    cfg.moe_k * 3 * d * cfg.d_expert)
             wb = 1 if getattr(cfg, "quant", "none") == "int8" else 2
             kv_int8 = getattr(cfg, "kv_quant", "none") == "int8"
             kvb = 1 if kv_int8 else 2
@@ -1527,6 +1577,9 @@ class GenServer:
         self._tick_rows = self._tick_real_rows = 0
         self._tick_dev_steps = self._tick_kv_pos = self._tick_kv_blocks = 0
         self._tick_inplace_steps = self._tick_ahead_steps = 0
+        self._tick_passes = self._tick_row_passes = 0
+        self._tick_expert_slots = self._tick_experts_read = 0
+        self._tick_prefill = [0, 0, 0]
         self._tick_attr = {} if costledger_enabled() else None
         self._tick_kv_attr = []
         self._tick_tokens = self._tick_retired = 0
@@ -1612,6 +1665,13 @@ class GenServer:
             "steps": self._tick_dev_steps,
             "inplace_steps": self._tick_inplace_steps,
             "ahead_steps": self._tick_ahead_steps,
+            "passes": self._tick_passes,
+            "row_passes": self._tick_row_passes,
+            "experts_read": self._tick_experts_read,
+            "expert_slots": self._tick_expert_slots,
+            "prefill_calls": self._tick_prefill[0],
+            "prefill_experts_read": self._tick_prefill[1],
+            "prefill_expert_slots": self._tick_prefill[2],
             "kv_positions": self._tick_kv_pos,
             "kv_blocks": self._tick_kv_blocks,
             "kv_ages": tuple(ages),
@@ -1871,11 +1931,16 @@ class GenServer:
         if seq.emitted:
             # rebuild from the ORIGINAL prompt: emitted keeps growing, so
             # folding into the already-folded prompt would duplicate
-            # context on a second preemption
+            # context on a second preemption.  The last token is pending,
+            # not yet in the cache -- but for diffusion blocks, which hold
+            # no pending token: everything emitted is in the cache, and the
+            # readmitted row's next round starts where those tokens end
+            cached = seq.emitted if self._block > 1 else seq.emitted[:-1]
             seq.prompt = np.concatenate(
                 [seq.prompt0,
-                 np.asarray(seq.emitted[:-1], np.int32)]).astype(np.int32)
-            seq.pending = seq.emitted[-1]
+                 np.asarray(cached, np.int32)]).astype(np.int32)
+            if self._block == 1:
+                seq.pending = seq.emitted[-1]
         seq.prefill_pos = 0
         seq.n_valid = 0
         seq.inflight = 0
@@ -2116,10 +2181,13 @@ class GenServer:
             # the rows whose prompt ends here: their first token is picked
             # into the carry, or restored there when the host holds it (a
             # row readmitted after a preemption is never re-sampled)
-            ending = [(seq, i, seq.pending is None)
+            # (a prefill of diffusion blocks chooses no token: its rows join
+            # the round with nothing pending and nothing to read back)
+            picks = self._block == 1
+            ending = [(seq, i, picks and seq.pending is None)
                       for i, seq in enumerate(batch)
                       if seq.prefill_pos + widths[i] >= len(seq.prompt)]
-            if ending:
+            if ending and picks:
                 idx = np.full((B,), self.slots, np.int32)
                 held = np.zeros((B,), bool)
                 held_tok = np.zeros((B,), np.int32)
@@ -2156,7 +2224,12 @@ class GenServer:
             t_dispatch = time.perf_counter()
             fn, args, kw = self._program(
                 "prefill", toks, tables, start, width)
+            # (diffusion blocks: the experts read, in the logits' place)
             logits, self._pool = fn(*args, **kw)
+            self._tick_prefill[0] += 1
+            if getattr(self.cfg, "d_expert", 0):
+                self._tick_prefill[2] += (self.cfg.n_layers
+                                          * self.cfg.n_experts)
             if self.spec:
                 d_nblk = _pow2(max(
                     self._blocks_needed(seq.prefill_pos + widths[i])
@@ -2172,12 +2245,16 @@ class GenServer:
                     d_start, width, cfg=self.draft_cfg, last_only=True,
                 )
             first = keys = None
-            if ending:
+            if ending and picks:
                 first, keys = self._first(logits, idx, held, held_tok,
                                           key_data)
                 first.copy_to_host_async()
             fl = _Flight("prefill", B, ending, logits, first, keys,
                          t_dispatch, attr)
+            if not picks and getattr(self.cfg, "d_expert", 0):
+                # what came in the logits' place: one int32 a chunk
+                fl.read = logits
+                fl.read.copy_to_host_async()
             self._unread.append(fl)
             if fenced:
                 jax.block_until_ready(fl.ready)
@@ -2216,6 +2293,8 @@ class GenServer:
                 first = np.asarray(fl.out)
                 if fl.keys is not None:
                     key_data = np.asarray(fl.keys)
+            if fl.read is not None:
+                self._tick_prefill[1] += int(np.asarray(fl.read))
         with _Phase("GenServer._prefill_tick/emit"):
             for seq, i, fresh in fl.rows:
                 # the per-sequence prefill span (admission -> prompt fully
@@ -2287,14 +2366,14 @@ class GenServer:
             for seq in self._decodable():
                 if seq not in self._active or seq.done:
                     continue  # preempted, or finished in a drain, above
-                if not self._ensure_capacity(seq, seq.n_valid + self.span):
+                upto = self._round_base(seq) + self.span
+                if not self._ensure_capacity(seq, upto):
                     # pool exhausted even after eviction: this sequence is
                     # alone and cannot fit — surface a typed failure
                     self._active.remove(seq)
                     self._finish_error(seq, RuntimeError(
                         "KV pool too small for sequence length "
-                        f"{seq.n_valid + self.span} (grow "
-                        "SELDON_TPU_GEN_POOL_BLOCKS)"))
+                        f"{upto} (grow SELDON_TPU_GEN_POOL_BLOCKS)"))
                     return None
 
         with _Phase("GenServer._decode_round/build"):
@@ -2304,7 +2383,7 @@ class GenServer:
             B = _pow2(len(batch))
             nblk = _decode_table_width(
                 self._inplace, B,
-                max(self._blocks_needed(s.n_valid + self.span)
+                max(self._blocks_needed(self._round_base(s) + self.span)
                     for s in batch),
                 self._allocator.capacity)
             self._note_program("decode", (B, nblk))
@@ -2312,14 +2391,27 @@ class GenServer:
             n_valid = np.zeros((B,), np.int32)
             active = np.zeros((B,), bool)
             idx = np.full((B,), self.slots, np.int32)   # pads: scratch
-            rows = []
+            # diffusion blocks: the ids a row's first round finds in its
+            # first block, the prompt's remainder (no pending token rides
+            # the carry: nothing of a round depends on the one before it
+            # but the eos latch, which stays on the device)
+            held = (np.zeros((B, self._block), np.int32)
+                    if self._block > 1 else None)
+            rows, skip = [], []
             for i, s in enumerate(batch):
                 tables[i] = self._table(s, nblk)
                 n_valid[i] = s.n_valid
                 active[i] = True
                 idx[i] = s.slot
+                # the round's first `off` positions are the row's own
+                # prompt again (0 but for a diffusion row's first round)
+                off = s.n_valid - self._round_base(s)
+                if off:
+                    held[i, :off] = s.prompt[len(s.prompt) - off:]
+                skip.append(off)
                 rows.append((s, min(
-                    self.span, s.max_new - len(s.emitted) - s.inflight)))
+                    self.span - off,
+                    s.max_new - len(s.emitted) - s.inflight)))
             OBSERVATORY.note_padding(len(batch), B)
             self._tick_rows += B
             self._tick_real_rows += len(batch)
@@ -2332,10 +2424,30 @@ class GenServer:
                 self._blocks_needed(s.n_valid + self.span) for s in batch)
             # cache positions the round streams (served HBM-BW accounting):
             # each of the span steps attends over ~n_valid + step positions
-            kv_positions = sum(
-                self.span * (s.n_valid + self.span // 2) for s in batch)
+            cfg = self.cfg
+            blocks = self.span // self._block
+            if self._block > 1:
+                # every pass of a block reads the row's cache up to the
+                # block's end once, whatever the queries in it
+                passes = blocks * (cfg.denoising_steps + 1)
+                kv_positions = sum(
+                    (cfg.denoising_steps + 1)
+                    * (self._round_base(s) + (b + 1) * self._block)
+                    for s in batch for b in range(blocks))
+                # the pass that writes a block's K/V stops at its last
+                # layer's K/V: one expert layer fewer
+                layer_passes = passes * cfg.n_layers - blocks
+            else:
+                passes = self.span
+                kv_positions = sum(
+                    self.span * (s.n_valid + self.span // 2) for s in batch)
+                layer_passes = passes * cfg.n_layers
             self._tick_kv_pos += kv_positions
             self._tick_dev_steps += self.span
+            self._tick_passes += passes
+            self._tick_row_passes += passes * len(batch)
+            if getattr(cfg, "d_expert", 0):
+                self._tick_expert_slots += layer_passes * cfg.n_experts
             if self._inplace:
                 self._tick_inplace_steps += self.span
             if self._unread:
@@ -2349,21 +2461,28 @@ class GenServer:
         with (_Phase("GenServer._decode_round/device", rows=B,
                      real_rows=len(batch), nblk=nblk,
                      kv_positions=kv_positions,
-                     inplace=int(bool(self._inplace)))
+                     inplace=int(bool(self._inplace)), passes=passes,
+                     blocks=blocks)
               if fenced else _Phase("GenServer._decode_round/build")):
             t_dispatch = time.perf_counter()
             take, put, _ = _carry_ops()
             token, seen, keys = take(self._carry, idx)
             fn, args, kw = self._program(
-                "decode", tables, token, n_valid, active, seen,
-                self._zero_keys[B] if keys is None else keys)
-            toks, self._pool, token, _nv, seen, keys = fn(*args, **kw)
+                "decode", tables, token if held is None else held, n_valid,
+                active, seen, self._zero_keys[B] if keys is None else keys)
+            toks, self._pool, token, _nv, seen, keys, *extra = fn(
+                *args, **kw)
             self._carry, key_data = put(
                 self._carry, idx, token, seen,
                 keys if self.temperature > 0.0 else None)
             toks.copy_to_host_async()
             fl = _Flight("decode", B, rows, toks, toks, key_data, t_dispatch,
                          attr)
+            fl.skip = skip
+            if extra and "experts_read" in extra[0]:
+                # one int32 a round, read back beside the round's tokens
+                fl.read = extra[0]["experts_read"]
+                fl.read.copy_to_host_async()
             self._unread.append(fl)
             if fenced:
                 jax.block_until_ready(fl.ready)
@@ -2380,10 +2499,17 @@ class GenServer:
                 old = self._slack_s
                 self._slack_s = (slack if old == 0.0
                                  else 0.5 * (old + min(slack, 2.0 * old)))
-        for s, share in rows:
+        for (s, share), off in zip(rows, skip):
             s.inflight += share
-            s.n_valid += self.span
+            s.n_valid += self.span - off
         return fl
+
+    def _round_base(self, seq: _Sequence) -> int:
+        """The position ``seq``'s next decode round starts on: right after
+        what it holds, or -- diffusion blocks -- where the last whole block
+        of that ends (the rest of the prompt goes into the round's first
+        block again, and the round emits so many tokens fewer)."""
+        return seq.n_valid - seq.n_valid % self._block
 
     def _decode_collect(self, fl: _Flight) -> None:
         """Wait for a dispatched round and read its tokens back -- the one
@@ -2394,21 +2520,32 @@ class GenServer:
             toks = np.asarray(fl.out)
             if fl.keys is not None:
                 key_data = np.asarray(fl.keys)
+            if fl.read is not None:
+                self._tick_experts_read += int(np.asarray(fl.read))
         with _Phase("GenServer._decode_round/emit"):
             for i, (s, take) in enumerate(fl.rows):
+                off = fl.skip[i]
                 s.inflight -= take
-                s.pending = int(toks[i, -1])
+                if self._block == 1:
+                    s.pending = int(toks[i, -1])
                 if key_data is not None:
                     s.key_data = key_data[i]
                 if s.done:
                     continue    # stopped a round ago: this one was padding
-                self._emit_tokens(s, [int(t) for t in toks[i, :take]])
+                # one request for the cost ledger's per-request usage: a
+                # dense row's is noted with its prefill's first token; a
+                # diffusion row's prefill chose none, so its first tokens
+                # bring it (a row readmitted after a preemption has emitted)
+                first = self._block > 1 and not s.emitted
+                self._emit_tokens(
+                    s, [int(t) for t in toks[i, off:off + take]])
                 self._seq_event(s, "decode_round", n_valid=s.n_valid,
                                 take=take)
                 self._tick_tokens += take
                 if take > 0:
                     self._attr_note("decode", 0, [
-                        (s.request.tenant, s.request.tier, 0, 0, take)])
+                        (s.request.tenant, s.request.tier, 0, int(first),
+                         take)])
 
     def _spec_round(self) -> int:
         """One speculative draft/verify round for every RUNNING sequence

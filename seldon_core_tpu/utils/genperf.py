@@ -126,6 +126,18 @@ class GenPerf:
         self.decode_steps = 0        # single-token device steps run
         self.decode_inplace_steps = 0  # ... that attended in place
         self.decode_ahead_steps = 0    # ... dispatched ahead of a readback
+        self.decode_passes = 0         # passes of the model (a token a step:
+        #                                one a step; diffusion blocks: the
+        #                                denoising passes and the K/V one)
+        self.decode_row_passes = 0     # ... times the real rows in each
+        self.decode_experts_read = 0   # experts the expert layers read
+        self.decode_expert_slots = 0   # experts held x expert layers x passes
+        # prefill programs dispatched, the experts their expert layers read
+        # (read back where a prefill returns the count) of the experts held
+        # x expert layers x calls
+        self.prefill_calls = 0
+        self.prefill_experts_read = 0
+        self.prefill_expert_slots = 0
         self.decode_kv_positions = 0  # cache positions streamed per step
         self.kv_block_age = Reservoir(1024)   # seconds held at release
         self.kv_blocks_released = 0
@@ -197,6 +209,20 @@ class GenPerf:
                     detail.get("ahead_steps", 0) or 0)
                 self.decode_kv_positions += int(
                     detail.get("kv_positions", 0) or 0)
+                self.decode_passes += int(detail.get("passes", 0) or 0)
+                self.decode_row_passes += int(
+                    detail.get("row_passes", 0) or 0)
+                self.decode_experts_read += int(
+                    detail.get("experts_read", 0) or 0)
+                self.decode_expert_slots += int(
+                    detail.get("expert_slots", 0) or 0)
+            # a chunk is read back a tick after it was dispatched, whatever
+            # that tick's kind
+            self.prefill_calls += int(detail.get("prefill_calls", 0) or 0)
+            self.prefill_experts_read += int(
+                detail.get("prefill_experts_read", 0) or 0)
+            self.prefill_expert_slots += int(
+                detail.get("prefill_expert_slots", 0) or 0)
             for n_blocks, age_s in kv_ages:
                 self.kv_blocks_released += int(n_blocks)
                 self.kv_block_age.observe(float(age_s))
@@ -247,6 +273,19 @@ class GenPerf:
             inplace_steps = self.decode_inplace_steps
             ahead_steps = self.decode_ahead_steps
             kv_pos = self.decode_kv_positions
+            passes = {
+                # passes of the model a decode round made, and summed over
+                # the real rows of each (real_tokens / row_passes = tokens
+                # fixed a row-pass: 1 for a token a step, 4/5 for blocks of
+                # four under four denoising passes and the K/V one)
+                "passes": self.decode_passes,
+                "row_passes": self.decode_row_passes,
+                # experts the expert layers read (the rounds' own count,
+                # read back with their tokens) of the experts they hold
+                # x layers x passes: 0 / 0 without experts
+                "experts_read": self.decode_experts_read,
+                "expert_slots": self.decode_expert_slots,
+            }
         out: Dict[str, Any] = {
             "decode_device_s": round(dev_s, 4),
             "real_tokens": tokens,
@@ -262,6 +301,7 @@ class GenPerf:
             # live cache positions the single-token steps attended over,
             # summed: the program's own count for a roofline reader
             "kv_positions": kv_pos,
+            **passes,
             "served_decode_mfu_pct": None,
             "served_decode_hbm_bw_util_pct": None,
             "served_decode_tok_s_device": (
@@ -380,6 +420,11 @@ class GenPerf:
                         "counts": list(self.req_ttft_hist),
                     },
                 },
+            }
+            doc["served_prefill"] = {
+                "calls": self.prefill_calls,
+                "experts_read": self.prefill_experts_read,
+                "expert_slots": self.prefill_expert_slots,
             }
         doc["served_decode"] = self.served_decode()
         return doc

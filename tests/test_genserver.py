@@ -320,7 +320,7 @@ def test_in_place_a_decode_round_has_one_program_per_row_count(
     assert shapes == {(b, w) for b in (1, 2) for w in (2, 4, 8)}
     assert (counts["prefill"], counts["decode"]) == (2, 6)
     monkeypatch.setattr(gen_mod, "decode_inplace",
-                        lambda pool, mesh=None: "interpret")
+                        lambda pool, mesh=None, width=1: "interpret")
     toks_inplace, shapes, counts = serve()
     assert shapes == {(1, 32), (2, 32)}    # 63 blocks a row can hold
     assert (counts["prefill"], counts["decode"]) == (2, 2)
@@ -1059,6 +1059,11 @@ def test_an_eos_on_the_last_row_leaves_no_round_unread_over_an_idle_spell(
         assert device_s() - before <= served
         assert all(ms < 1e3 * served
                    for ms in snap["pace"]["round_ms"].values())
+        # as above: the row's last round is read before the loop parks, a
+        # moment after the request's future resolved
+        deadline = time.monotonic() + 5
+        while srv._unread and time.monotonic() < deadline:
+            time.sleep(0.005)
         assert snap["kv_blocks"]["used"] == 0 and not srv._unread
     finally:
         srv.stop()
@@ -1460,7 +1465,7 @@ def test_a_record_of_another_identity_is_not_read(
     assert len(_records(cache_dir)) == 1
     if other == "inplace":
         monkeypatch.setattr(gen_mod, "decode_inplace",
-                            lambda pool, mesh=None: "interpret")
+                            lambda pool, mesh=None, width=1: "interpret")
     _, progs = _boot_and_serve_one(
         params, max_new_tokens=4,
         **{"slots": {"slots": 4}, "span": {"span": 2}}.get(other, {}))

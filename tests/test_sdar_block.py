@@ -1,0 +1,518 @@
+"""The served path of a generator by diffusion over blocks with dropless
+routed experts (SDAR-30B-A3B-Chat's mechanisms, bench/configs/
+sdar-30b-a3b.json) at a tiny size on the CPU, in float32: the paged
+programs, the static lane and ``GenServer`` against the plain reference
+of bench/archs/sdar_moe/, which shares no code with them.
+
+Tolerances: tokens are compared exactly.  Both sides compute in float32
+(the reference at ``highest``; XLA:CPU's float32 dots are exact to
+rounding), the weights are seeded, and an argmax or a confidence ranking
+flips only on a tie of two float32 logits, which these seeds do not have;
+the expert layer against its loop is held to 1e-5 of values of order 1
+(summation order over the chosen experts)."""
+
+import importlib.util
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from seldon_core_tpu.models.generate import (
+    TransformerGenerator,
+    generate,
+    init_block_pool,
+    paged_decode_round_jit,
+    paged_forward_jit,
+    stream_chunks,
+)
+from seldon_core_tpu.models.transformer import LMConfig
+from seldon_core_tpu.parallel.moe import dropless_init, moe_dropless
+from seldon_core_tpu.runtime.genserver import GenServer
+from seldon_core_tpu.runtime.qos import qos_scope
+from seldon_core_tpu.utils.costledger import LEDGER
+from seldon_core_tpu.utils.genperf import GENPERF
+from seldon_core_tpu.utils.hotrecord import SPINE
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+MASK = 90
+
+
+def _reference():
+    path = os.path.join(REPO, "bench", "archs", "sdar_moe", "reference.py")
+    spec = importlib.util.spec_from_file_location("sdar_reference", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+REF = _reference()
+
+
+def config(steps=4):
+    """The configuration file's keys at a tiny size (what the reference
+    reads) and the unit built from them as the deployment builds it."""
+    doc = dict(hidden_size=32, num_attention_heads=4, num_key_value_heads=2,
+               head_dim=16, num_hidden_layers=2, rope_theta=10000.0,
+               rms_norm_eps=1e-5, block_length=4, denoising_steps=steps,
+               mask_token_id=MASK, num_experts=8, num_experts_per_tok=2,
+               norm_topk_prob=True, moe_intermediate_size=16, vocab_size=96)
+    unit = TransformerGenerator(
+        vocab=96, d_model=32, n_heads=4, n_kv_heads=2, head_dim=16,
+        n_layers=2, qk_norm=True, norm_eps=1e-5, tie_embeddings=False,
+        d_expert=16, n_experts=8, moe_k=2, moe_norm_topk=True,
+        block_length=4, denoising_steps=steps, mask_id=MASK,
+        dtype="float32", rope_base=10000.0, seed=5)
+    return doc, unit
+
+
+@pytest.fixture(scope="module", params=[4, 2], ids=["steps4", "steps2"])
+def model(request):
+    doc, unit = config(request.param)
+    return doc, unit, unit.init_state(None)["params"]
+
+
+def reference_answer(params, prompt, doc, max_new, eos=-1):
+    """The greedy answer as the published generation has it, every pass a
+    whole forward of the reference over the row so far: no cache."""
+    L, steps = doc["block_length"], doc["denoising_steps"]
+    seq = [int(t) for t in prompt]
+    n0 = len(seq)
+    while len(seq) - n0 < max_new:
+        start = len(seq) - len(seq) % L
+        block = seq[start:] + [MASK] * (L - (len(seq) - start))
+        masked = np.arange(L) >= len(seq) - start
+        for _ in range(steps):
+            ids = np.asarray([seq[:start] + block], np.int32)
+            logits = np.array(REF.forward(
+                params, jnp.asarray(ids), doc,
+                jnp.asarray([start + np.arange(L)]),
+                jnp.asarray([ids.shape[1]]))[0])
+            logits[:, MASK] = -np.inf
+            chose = logits.argmax(-1)
+            peak = logits.max(-1, keepdims=True)
+            sure = 1.0 / np.exp(logits - peak).sum(-1)
+            sure = np.where(masked, sure, -1.0)
+            for i in np.argsort(-sure, kind="stable")[:L // steps]:
+                if masked[i]:
+                    block[i], masked[i] = int(chose[i]), False
+        seq = seq[:start] + block
+    out = seq[n0:n0 + max_new]
+    if eos in out:
+        out = out[:out.index(eos) + 1] + [eos] * (
+            max_new - out.index(eos) - 1)
+    return np.asarray(out, np.int32)
+
+
+def prompts(lens, seed=0):
+    rng = np.random.default_rng(seed)
+    return [rng.integers(0, MASK, n).astype(np.int32) for n in lens]
+
+
+# -- (a) the paged programs against the reference ---------------------------
+
+
+def test_chunked_prefill_then_a_round_equal_the_reference(model):
+    """``paged_forward`` chunk by chunk (chunks of 8 = two blocks) and one
+    ``paged_decode_round`` of two blocks over rows whose prompts are and are
+    not a whole number of blocks: the prefill's logits to rounding, the
+    round's tokens to the id."""
+    doc, unit, params = model
+    lens = [5, 8, 13, 16]
+    rows = prompts(lens)
+    bs, C, span = 8, 8, 8
+    pool = init_block_pool(unit.cfg, 16, bs)
+    tables = jnp.asarray(1 + np.arange(12).reshape(4, 3), jnp.int32)
+    pos = [0] * 4
+    last = [None] * 4
+    while any(p < n for p, n in zip(pos, lens)):
+        toks = np.zeros((4, C), np.int32)
+        width = np.zeros((4,), np.int32)
+        for r, row in enumerate(rows):
+            w = min(C, lens[r] - pos[r])
+            toks[r, :w], width[r] = row[pos[r]:pos[r] + w], w
+        logits, pool = paged_forward_jit(
+            params, jnp.asarray(toks), pool, tables,
+            jnp.asarray(pos, jnp.int32), jnp.asarray(width), cfg=unit.cfg)
+        for r in range(4):
+            if width[r] and pos[r] + width[r] == lens[r]:
+                last[r] = np.asarray(logits[r])
+            pos[r] += int(width[r])
+    for r, row in enumerate(rows):
+        want = np.asarray(REF.forward(
+            params, jnp.asarray(row[None]), doc,
+            jnp.asarray([[lens[r] - 1]]), jnp.asarray([lens[r]]))[0, 0])
+        assert np.abs(last[r] - want).max() < 1e-4
+    held = np.zeros((4, 4), np.int32)
+    for r, row in enumerate(rows):
+        held[r, :lens[r] % 4] = row[lens[r] - lens[r] % 4:]
+    toks, pool, _, n_valid, *_ = paged_decode_round_jit(
+        params, pool, tables, jnp.asarray(held), jnp.asarray(lens, jnp.int32),
+        jnp.ones((4,), bool), jnp.zeros((4,), bool),
+        jnp.zeros((4,), jnp.uint32), unit.cfg, span=span, temperature=0.0,
+        top_k=0, top_p=0.0, eos_token=-1)
+    toks = np.asarray(toks)
+    for r, row in enumerate(rows):
+        rem = lens[r] % 4
+        np.testing.assert_array_equal(toks[r, :rem], row[lens[r] - rem:])
+        np.testing.assert_array_equal(
+            toks[r, rem:], reference_answer(params, row, doc, span - rem))
+        assert int(n_valid[r]) == lens[r] - rem + span
+
+
+def test_static_lane_one_round_or_many_gives_the_reference_answer(model):
+    doc, unit, params = model
+    for n, max_new in ((7, 10), (3, 5), (12, 8)):
+        rows = np.stack(prompts([n, n], seed=n))
+        want = np.stack([reference_answer(params, r, doc, max_new)
+                         for r in rows])
+        got = generate(params, jnp.asarray(rows), unit.cfg,
+                       max_new_tokens=max_new)
+        np.testing.assert_array_equal(np.asarray(got), want)
+        chunks = list(stream_chunks(params, jnp.asarray(rows), unit.cfg,
+                                    max_new_tokens=max_new, chunk=4))
+        np.testing.assert_array_equal(
+            np.concatenate([np.asarray(c) for c in chunks], 1), want)
+        assert all(c.shape[1] <= 4 for c in chunks)
+
+
+# -- (b) nothing couples the rows of a batch ----------------------------------
+
+
+def test_a_rows_tokens_do_not_depend_on_who_shares_its_batch(model):
+    """What ``continuous_spec`` rests on: dropless routing takes a token by
+    that token alone."""
+    doc, unit, params = model
+    rows = np.stack(prompts([9] * 8, seed=3))
+    alone = generate(params, jnp.asarray(rows[:1]), unit.cfg,
+                     max_new_tokens=11)
+    together = generate(params, jnp.asarray(rows), unit.cfg,
+                        max_new_tokens=11)
+    np.testing.assert_array_equal(np.asarray(together)[:1],
+                                  np.asarray(alone))
+    state = unit.init_state(None)
+    assert unit.continuous_spec(state) is not None
+    assert unit.batch_coupled is False
+
+
+def expert_loop(lp, h, cfg):
+    """Every token through each expert it chose, one expert at a time."""
+    x = np.asarray(h, np.float64).reshape(-1, h.shape[-1])
+    logits = x @ np.asarray(lp["router"], np.float64)
+    gates = np.exp(logits - logits.max(-1, keepdims=True))
+    gates /= gates.sum(-1, keepdims=True)
+    out = np.zeros_like(x)
+    for t in range(x.shape[0]):
+        top = np.argsort(-gates[t], kind="stable")[:cfg.moe_k]
+        w = gates[t, top] / (gates[t, top].sum() if cfg.moe_norm_topk
+                             else 1.0)
+        for e, we in zip(top, w):
+            gu = x[t] @ np.asarray(lp["e_gate_up"][e], np.float64)
+            g, u = gu[:cfg.d_expert], gu[cfg.d_expert:]
+            out[t] += we * ((g / (1 + np.exp(-g)) * u)
+                            @ np.asarray(lp["e_down"][e], np.float64))
+    return out.reshape(h.shape)
+
+
+@pytest.mark.parametrize("k, one_expert, norm", [
+    (2, False, True), (2, False, False), (1, True, True), (3, True, True),
+], ids=["top2", "top2-unnormalised", "every-pick-on-one-expert",
+        "one-expert-first-of-three"])
+def test_dropless_layer_equals_a_loop_over_experts(k, one_expert, norm):
+    cfg = LMConfig(vocab=96, d_model=32, n_heads=4, d_expert=16,
+                   n_experts=8, moe_k=k, moe_norm_topk=norm,
+                   dtype=jnp.float32)
+    lp = dropless_init(jax.random.key(1), cfg)
+    if one_expert:
+        # expert 5's router column dwarfs the rest: every token's first pick
+        lp["router"] = lp["router"].at[:, 5].set(0.0)
+        h = jax.random.normal(jax.random.key(2), (3, 5, 32))
+        lp["router"] = lp["router"].at[0, 5].set(50.0)
+        h = h.at[..., 0].set(1.0)
+    else:
+        h = jax.random.normal(jax.random.key(2), (3, 5, 32))
+    valid = jnp.ones((3, 5), bool).at[1, 3:].set(False).at[2].set(False)
+    y, read = moe_dropless(lp, h, valid, cfg)
+    want = expert_loop(lp, h, cfg)
+    live = np.asarray(valid)
+    assert np.abs(np.asarray(y)[live] - want[live]).max() < 1e-5
+    # a pad position picks nothing: it adds nothing and reads no expert
+    assert not np.asarray(y)[~live].any()
+    logits = np.asarray(h).reshape(-1, 32) @ np.asarray(lp["router"])
+    chosen = np.argsort(-logits, axis=-1, kind="stable")[:, :k]
+    assert int(read) == len(set(chosen[live.reshape(-1)].ravel()))
+    if one_expert and k == 1:
+        assert int(read) == 1
+
+
+def test_the_tpu_kernel_and_xlas_grouped_matmul_agree():
+    """What the chip runs (megablox ``gmm`` under the repo's tiles, here in
+    Pallas interpret mode) against what the CPU runs (``ragged_dot``): the
+    same layer to rounding, pad positions and empty experts included."""
+    cfg = LMConfig(vocab=96, d_model=128, n_heads=4, d_expert=128,
+                   n_experts=32, moe_k=2, dtype=jnp.float32)
+    lp = dropless_init(jax.random.key(4), cfg)
+    h = jax.random.normal(jax.random.key(5), (2, 7, 128))
+    valid = jnp.ones((2, 7), bool).at[1, 4:].set(False)
+    want, read = moe_dropless(lp, h, valid, cfg, impl="ragged_dot")
+    got, read2 = moe_dropless(lp, h, valid, cfg, impl="gmm_interpret")
+    assert int(read) == int(read2) <= 22         # 11 real tokens' picks
+    assert np.abs(np.asarray(got) - np.asarray(want)).max() < 1e-4
+    assert not np.asarray(got)[1, 4:].any()
+
+
+# -- (c) GenServer: the same answers through the scheduler -------------------
+
+
+@pytest.fixture()
+def clean_genperf():
+    SPINE.drain()
+    SPINE.reset()
+    GENPERF.reset()
+    yield
+    SPINE.drain()
+    SPINE.reset()
+    GENPERF.reset()
+
+
+def served_decode(tokens):
+    """``/genperf`` ``served_decode`` once the tick that emitted the last
+    of ``tokens`` has published its record (a request's future resolves
+    inside that tick, before the record is out)."""
+    import time
+
+    deadline = time.monotonic() + 10
+    while True:
+        SPINE.drain()
+        served = GENPERF.document()["served_decode"]
+        if served["real_tokens"] >= tokens or time.monotonic() > deadline:
+            return served
+        time.sleep(0.02)
+
+
+def server(unit, params, **kw):
+    state = {"params": params}
+    kw = {"block_size": 8, "num_blocks": 64, "slots": 4, "span": 8,
+          "prefill_chunk": 16, **kw}
+    return GenServer(**unit.continuous_spec(state), **kw)
+
+
+def test_genserver_serves_the_reference_answer(model, clean_genperf):
+    """Unary and streamed; a prompt shorter than one block; ``max_new`` that
+    is no multiple of the block length; rows of different lengths
+    co-scheduled."""
+    doc, unit, params = model
+    srv = server(unit, params)
+    try:
+        cases = [(3, 5), (7, 10), (16, 8), (21, 13)]
+        reqs = []
+        for n, max_new in cases:
+            rows = np.stack(prompts([n, n], seed=10 + n))
+            reqs.append((rows, max_new, srv.submit(rows, max_new=max_new)))
+        for rows, max_new, req in reqs:
+            want = np.stack([reference_answer(params, r, doc, max_new)
+                             for r in rows])
+            np.testing.assert_array_equal(
+                req.future.result(timeout=180), want)
+            np.testing.assert_array_equal(np.asarray(generate(
+                params, jnp.asarray(rows), unit.cfg,
+                max_new_tokens=max_new)), want)
+            chunks = list(srv.stream(rows, chunk=3, max_new=max_new))
+            np.testing.assert_array_equal(np.concatenate(chunks, 1), want)
+        snap = srv.snapshot()
+        assert snap["round"] == {
+            "block_length": 4, "denoising_steps": doc["denoising_steps"]}
+        assert snap["tick_errors_total"] == 0
+    finally:
+        srv.stop()
+
+
+def test_genserver_stops_at_an_eos_inside_a_block(model):
+    doc, unit, params = model
+    row = prompts([6], seed=21)[0]
+    free = reference_answer(params, row, doc, 12)
+    eos = int(free[5])                       # inside the second block
+    want = reference_answer(params, row, doc, 12, eos=eos)
+    assert (want[-1] == eos) and list(want).index(eos) <= 5
+    spec = {**unit.continuous_spec({"params": params}), "eos_token": eos}
+    srv = GenServer(**spec, block_size=8, num_blocks=64, slots=4, span=8,
+                    prefill_chunk=16)
+    try:
+        got = srv.submit(row[None], max_new=12).future.result(timeout=180)
+        np.testing.assert_array_equal(got[0], want)
+        np.testing.assert_array_equal(np.asarray(generate(
+            params, jnp.asarray(row[None]), unit.cfg, max_new_tokens=12,
+            eos_token=eos))[0], want)
+    finally:
+        srv.stop()
+
+
+def test_genserver_preempts_and_readmits_mid_answer(model):
+    """A pool too small for two whole rows: the younger is evicted between
+    rounds, its emitted tokens become prompt (no pending token: all of them
+    are in the cache), and its answer is the reference's all the same."""
+    doc, unit, params = model
+    rows = prompts([6, 6], seed=31)
+    want = [reference_answer(params, r, doc, 18) for r in rows]
+    # each row grows to 6 + 18 (+ a round's slack) positions = 7 blocks of
+    # 4; a pool of 10 holds both admissions, not both answers
+    SPINE.drain()
+    LEDGER.reset()
+    srv = server(unit, params, block_size=4, num_blocks=11, span=8,
+                 prefill_chunk=8)
+    try:
+        with qos_scope("anna", "interactive"):
+            reqs = [srv.submit(r[None], max_new=18) for r in rows]
+        for req, w in zip(reqs, want):
+            np.testing.assert_array_equal(
+                req.future.result(timeout=240)[0], w)
+        snap = srv.snapshot()
+        assert snap["preempted_total"] >= 1
+    finally:
+        srv.stop()
+    # the cost ledger's per-request usage: a prefill that chooses no token
+    # notes no request, a row's first tokens do -- once, though the evicted
+    # row was prefilled twice
+    SPINE.drain()
+    assert LEDGER._usage["anna"][1] == 2.0
+
+
+def test_a_diffusion_generator_refuses_what_it_cannot_serve(model):
+    doc, unit, params = model
+    spec = unit.continuous_spec({"params": params})
+    for kw, match in (({"span": 6}, "span=6"), ({"block_size": 6}, "block_size"),
+                      ({"prefill_chunk": 10}, "prefill_chunk"),
+                      ({"temperature": 0.7}, "greedy")):
+        with pytest.raises(ValueError, match=match):
+            GenServer(**{**spec, "block_size": 8, "num_blocks": 16,
+                         "slots": 2, "span": 8, "prefill_chunk": 16, **kw})
+    with pytest.raises(ValueError, match="greedily"):
+        TransformerGenerator(vocab=96, block_length=4, denoising_steps=4,
+                             mask_id=MASK, temperature=0.5)
+    with pytest.raises(ValueError, match="denoising_steps"):
+        LMConfig(vocab=96, block_length=4, denoising_steps=3, mask_id=MASK)
+    with pytest.raises(ValueError, match="cache-free forward"):
+        from seldon_core_tpu.models.transformer import lm_apply
+
+        lm_apply(params, jnp.zeros((1, 4), jnp.int32), unit.cfg)
+
+
+# -- spans and counters --------------------------------------------------------
+
+
+def test_genperf_counts_passes_and_experts_apart_from_tokens(model,
+                                                             clean_genperf):
+    """One row, a prompt of 6 (remainder 2), 14 tokens: two rounds of two
+    blocks, ``steps`` + 1 passes a block; the first round emits 6."""
+    doc, unit, params = model
+    steps, layers, E = doc["denoising_steps"], 2, doc["num_experts"]
+    srv = server(unit, params)
+    try:
+        row = prompts([6], seed=41)[0]
+        srv.submit(row[None], max_new=14).future.result(timeout=180)
+        served = served_decode(14)
+        prefill = GENPERF.document()["served_prefill"]
+    finally:
+        srv.stop()
+    # the prompt is one chunk: its program returns the experts its two
+    # layers read in the logits' place (a token picks 2 of E)
+    assert prefill["calls"] == 1 and prefill["expert_slots"] == layers * E
+    assert 2 * 2 <= prefill["experts_read"] <= prefill["expert_slots"]
+    passes = 2 * 2 * (steps + 1)
+    assert served["device_steps"] == 16 and served["real_tokens"] == 14
+    assert served["passes"] == served["row_passes"] == passes
+    assert served["inplace_steps"] == 0
+    # the K/V-writing pass stops at its last layer's K/V: one expert layer
+    # fewer a block
+    assert served["expert_slots"] == E * 4 * (steps * layers + layers - 1)
+    # one row's block of 4 tokens picks at most 8 distinct experts of 8
+    assert 4 * 2 <= served["experts_read"] <= served["expert_slots"]
+    assert served["experts_read"] < served["expert_slots"]
+
+
+def test_a_dense_generator_reports_no_experts_and_one_pass_a_step(
+        clean_genperf):
+    unit = TransformerGenerator(vocab=48, d_model=32, n_heads=4, n_layers=2,
+                                d_ff=64, dtype="float32")
+    state = unit.init_state(None)
+    srv = GenServer(**unit.continuous_spec(state), block_size=4,
+                    num_blocks=32, slots=2, span=4, prefill_chunk=8)
+    try:
+        srv.submit(np.arange(5)[None], max_new=9).future.result(timeout=180)
+        served = served_decode(9)
+        assert srv.snapshot()["round"] == {
+            "block_length": 1, "denoising_steps": 1}
+    finally:
+        srv.stop()
+    assert served["passes"] == served["row_passes"] == served["device_steps"]
+    assert served["experts_read"] == served["expert_slots"] == 0
+    SPINE.drain()
+    assert GENPERF.document()["served_prefill"] == {
+        "calls": 1, "experts_read": 0, "expert_slots": 0}
+
+
+def test_observe_tick_folds_the_new_counters():
+    GENPERF.reset()
+    for kind in ("decode", "mixed", "prefill"):
+        GENPERF.observe_tick(kind, {
+            "wall_s": 0.01, "device_s": 0.008, "steps": 8, "tokens": 6,
+            "passes": 10, "row_passes": 30, "experts_read": 700,
+            "expert_slots": 8704, "prefill_calls": 2,
+            "prefill_experts_read": 1500, "prefill_expert_slots": 1792})
+    served = GENPERF.document()["served_decode"]
+    prefill = GENPERF.document()["served_prefill"]
+    GENPERF.reset()
+    # a chunk is read back in whatever tick comes next: every kind folds it
+    assert prefill == {"calls": 6, "experts_read": 4500,
+                       "expert_slots": 5376}
+    # a prefill tick's are not a decode round's
+    assert (served["passes"], served["row_passes"], served["experts_read"],
+            served["expert_slots"]) == (20, 60, 1400, 17408)
+
+
+def test_the_block_names_its_stages_for_the_trace(model):
+    """``jax.named_scope`` ``qk_norm``, ``router``, ``experts`` inside the
+    block and ``denoise`` / ``commit`` around a pass: op metadata the trace
+    readers sort device time by (bench/readers/trace_stages.py)."""
+    doc, unit, params = model
+    pool = init_block_pool(unit.cfg, 8, 8)
+    text = paged_decode_round_jit.lower(
+        params, pool, jnp.ones((2, 2), jnp.int32), jnp.zeros((2, 4), jnp.int32),
+        jnp.asarray([5, 8], jnp.int32), jnp.ones((2,), bool),
+        jnp.zeros((2,), bool), jnp.zeros((2,), jnp.uint32), unit.cfg, span=8,
+        temperature=0.0, top_k=0, top_p=0.0, eos_token=-1
+    ).as_text(debug_info=True)
+    for scope in ("denoise/qk_norm", "denoise/ffn/router",
+                  "denoise/ffn/experts", "commit/ffn/experts",
+                  "denoise/unembed", "commit/kv_write", "denoise/attn"):
+        assert scope in text, scope
+    assert "commit/unembed" not in text
+
+
+def test_a_prefill_without_its_head_returns_the_experts_read(model):
+    """``head=False`` (what ``GenServer`` asks of a generator whose prompt
+    chooses no token): the same pool as with the head, no ``unembed`` in
+    the program, and the count of expert groups with a real token in the
+    logits' place."""
+    doc, unit, params = model
+    rows = np.stack(prompts([6, 6], seed=5))
+    rows[1, 3:] = 0
+    args = (jnp.asarray(rows), jnp.asarray([[1, 2], [3, 4]], jnp.int32),
+            jnp.zeros((2,), jnp.int32), jnp.asarray([6, 3], jnp.int32))
+
+    def run(**kw):
+        pool = init_block_pool(unit.cfg, 8, 8)
+        return paged_forward_jit(params, args[0], pool, *args[1:],
+                                 cfg=unit.cfg, **kw)
+
+    logits, pool = run()
+    read, pool2 = run(head=False)
+    assert logits.shape == (2, unit.cfg.vocab)
+    jax.tree.map(np.testing.assert_array_equal, pool, pool2)
+    # two layers of 8 experts; 9 real tokens pick 2 each
+    assert read.dtype == jnp.int32 and 2 * 2 <= int(read) <= 2 * 8
+    text = paged_forward_jit.lower(
+        params, args[0], init_block_pool(unit.cfg, 8, 8), *args[1:],
+        cfg=unit.cfg, head=False).as_text(debug_info=True)
+    assert "ffn/experts" in text and "unembed" not in text
