@@ -224,9 +224,11 @@ def mask_after_eos(toks, eos_token: int):
 # position-ordered dense view (pool[tables], pure XLA: _paged_view +
 # _attend_paged) and serves every width, dtype, backend and mesh; the
 # IN-PLACE path (ops/paged_attention.py, a Pallas TPU kernel) reads a row's
-# blocks where they lie and serves the width-1 decode step where
-# ops.paged_attention.inplace_supported says so.  The gather path is the
-# reference the kernel is tested against.  Writes SCATTER fresh K/V at
+# blocks where they lie and serves a decode round's step — one query a row,
+# or the queries of one diffusion block a row, which see the cache before
+# the block through the kernel and the block's own fresh K/V beside it —
+# where ops.paged_attention.inplace_supported says so.  The gather path is
+# the reference the kernel is tested against.  Writes SCATTER fresh K/V at
 # (table[pos // bs], pos % bs) — the vLLM reshape_and_cache shape — and the
 # pool's layout is the one XLA's TPU scatter wants (a token's KV x hd
 # window minor-most): any other makes XLA re-lay the whole pool around
@@ -363,6 +365,34 @@ def _attend_view_and_fresh(q, view, start, k_new, v_new):
             + _grouped_pv(p[..., L:], v_new, q.shape, q.dtype))
 
 
+def _attend_pool_and_fresh(q, pool_layer, tables, plan, k_new, v_new,
+                           interpret: bool = False):
+    """``_attend_view_and_fresh`` with the pool read in place: the kernel
+    (ops.paged_attention, ``plan`` = ``decode_plan(start, active, capacity,
+    fresh=0)``) gives each query's weighted sum over the row's cache before
+    ``start`` with that softmax's largest score and mass, and the block's
+    own fresh keys join here, in float32: ONE softmax over both parts, by
+    the larger of the two maxima.  A row whose block starts at 0 has no
+    mass in the pool and takes the fresh part alone."""
+    from seldon_core_tpu.ops.paged_attention import paged_decode_attention
+
+    old, peak, mass = paged_decode_attention(
+        q, pool_layer["k"], pool_layer["v"], tables, *plan,
+        interpret=interpret, stats=True)             # [B, KV, g, W(, hd)]
+    B, KV, g, W, hd = old.shape
+    s = _grouped_qk(q, k_new)                        # [B, KV, g, W, W]
+    top = jnp.maximum(peak, s.max(axis=-1))
+    p = jnp.exp(s - top[..., None])
+    mass = mass * jnp.exp(peak - top)
+    fresh = jax.lax.dot_general(
+        p.astype(v_new.dtype).reshape(B, KV, g * W, W),
+        v_new, (((3,), (2,)), ((0, 1), (0, 1))),
+        preferred_element_type=jnp.float32).reshape(old.shape)
+    a = ((old * mass[..., None] + fresh)
+         / (mass + p.sum(axis=-1))[..., None])
+    return a.astype(q.dtype).reshape(q.shape)
+
+
 def _paged_block(lp, x, pool_layer, tables, start, valid, cfg: LMConfig,
                  plan=None, interpret: bool = False, limit=None,
                  kv_only: bool = False, view=None, write: bool = True):
@@ -371,10 +401,13 @@ def _paged_block(lp, x, pool_layer, tables, start, valid, cfg: LMConfig,
     attention over each row's own blocks.  x [B, W, D] -> (x', pool layer',
     the FFN's aux: the experts read by a dropless expert layer, else 0).
 
-    ``plan`` (ops.paged_attention.decode_plan, width 1 only) selects the
-    in-place formulation: attention reads the row's blocks from the pool
-    where they lie.  None takes the gather path.  ``limit`` [B] is how far
-    each row really holds positions, for the block-causal mask of
+    ``plan`` (ops.paged_attention.decode_plan) selects the in-place
+    formulation: attention reads the row's blocks from the pool where they
+    lie — one query a row over its cache and its own fresh K/V, or, for
+    ``cfg.block_length`` > 1, a diffusion block's queries over the cache
+    before ``start`` with the block's fresh K/V joined outside the kernel
+    (``_attend_pool_and_fresh``).  None takes the gather path.  ``limit``
+    [B] is how far each row really holds positions, for the mask of
     ``cfg.block_length`` > 1 (``_attend_paged``).  ``kv_only`` stops once
     the K/V are written: the last layer of a pass whose hidden states
     nobody reads (``_denoising_round``'s commit).  ``view`` is a dense view
@@ -423,6 +456,9 @@ def _paged_block(lp, x, pool_layer, tables, start, valid, cfg: LMConfig,
             a = _attend_view_and_fresh(q, view, start, k, v)
         elif plan is None:
             a = _attend_paged(q, view, start, cfg.block_length, limit)
+        elif cfg.block_length > 1:
+            a = _attend_pool_and_fresh(q, pool_layer, tables, plan, k, v,
+                                       interpret)
         else:
             a = paged_decode_attention(
                 q, pool_layer["k"], pool_layer["v"], tables, *plan,
@@ -493,19 +529,22 @@ def paged_forward(params, tokens, pool, tables, start, width,
     return (logits[:, 0, :] if last_only else logits), pool
 
 
-def decode_inplace(pool, mesh=None, width: int = 1) -> bool:
+def decode_inplace(pool, mesh=None, width: int = 1, heads=None,
+                   rows: int = 1) -> bool:
     """Whether a decode round over ``pool`` attends in place (the Pallas
     kernel) or through the gather path: ops.paged_attention
     .inplace_supported over what is observable here — the backend, the
-    pool's dtype and shapes, the caller's mesh and the queries a row brings
-    to a step (``width``: 1, or a diffusion block's ``block_length``)."""
+    pool's dtype and shapes, the caller's mesh, the queries a row brings
+    to a step (``width``: 1, or a diffusion block's ``block_length``) and
+    the query ``heads`` and padded ``rows`` of the widest batch the caller
+    will bring, which the kernel holds whole."""
     from seldon_core_tpu.ops.paged_attention import inplace_supported
 
     k = pool["l0"]["k"]
     return inplace_supported(
         width=width, backend=jax.default_backend(), pool_dtype=k.dtype,
         mesh=mesh, block_size=k.shape[1], kv_heads=k.shape[2],
-        head_dim=k.shape[3])
+        head_dim=k.shape[3], heads=heads, rows=rows)
 
 
 def paged_decode_round(params, pool, tables, token, n_valid, active,
@@ -518,11 +557,11 @@ def paged_decode_round(params, pool, tables, token, n_valid, active,
     (``cfg.block_length`` > 1: ``span`` positions as blocks of denoising
     passes, ``_denoising_round`` below, with the same operands and results.)
 
-    ``inplace``: None decides by ``decode_inplace(pool)`` (a caller that
-    shards the pool over a mesh passes its own answer: a traced program
-    cannot see shardings); True / False force the kernel / the gather
-    path; "interpret" runs the kernel in Pallas interpret mode (tests on
-    the CPU).
+    ``inplace``: None decides by ``decode_inplace(pool, width=...)`` for
+    this batch (a caller that shards the pool over a mesh passes its own
+    answer: a traced program cannot see shardings); True / False force the
+    kernel / the gather path; "interpret" runs the kernel in Pallas
+    interpret mode (tests on the CPU).
 
     token [B] pending tokens; n_valid [B] per-row cache length; active [B]
     masks empty slots (their writes go to scratch, their samples are
@@ -541,9 +580,10 @@ def paged_decode_round(params, pool, tables, token, n_valid, active,
         return _denoising_round(
             params, pool, tables, token, n_valid, active, seen_eos, keys,
             cfg, span=span, temperature=temperature, eos_token=eos_token,
-            trace_passes=trace_passes)
+            inplace=inplace, trace_passes=trace_passes)
     if inplace is None:
-        inplace = decode_inplace(pool)
+        inplace = decode_inplace(pool, heads=cfg.n_heads,
+                                 rows=n_valid.shape[0])
     capacity = tables.shape[1] * pool["l0"]["k"].shape[1]
 
     def step(carry, _):
@@ -593,7 +633,8 @@ def paged_decode_round(params, pool, tables, token, n_valid, active,
 
 def _denoising_round(params, pool, tables, token, n_valid, active, seen_eos,
                      keys, cfg: LMConfig, *, span: int, temperature: float,
-                     eos_token: int, trace_passes: bool = False):
+                     eos_token: int, inplace=None,
+                     trace_passes: bool = False):
     """A decode round of a generator by diffusion over blocks: ``span /
     block_length`` blocks a row, one after another (a ``lax.scan``), greedy.
 
@@ -613,13 +654,17 @@ def _denoising_round(params, pool, tables, token, n_valid, active, seen_eos,
     is what the cache keeps, it needs no logits, and its last layer stops
     at its K/V.
 
-    A denoising pass's K/V are NOT stored: each layer's blocks are gathered
-    into a dense view once a block, before its first pass, and every pass
-    attends over that view (the earlier blocks) and its own fresh K/V
-    together (``_attend_view_and_fresh``); only the commit writes the pool.
-    The gather path's price is then paid once a block and not once a pass
-    (five times), and ``_paged_block`` stays the one block both kinds of
-    round run.
+    A denoising pass's K/V are NOT stored: every pass, the commit too,
+    attends over the cache before the block's start and its own fresh K/V
+    in ONE softmax, and only the commit writes the pool.  ``inplace`` (as
+    for ``paged_decode_round``: None decides by ``decode_inplace(pool,
+    width=L)``) says how the cache is read: in place, the block's ``L``
+    queries a row folded into the kernel's query group, the fresh part
+    joined outside it (``_attend_pool_and_fresh``), nothing gathered; or
+    on the gather path, each layer's blocks gathered into a dense view once
+    a block, before its first pass (``_attend_view_and_fresh``) — once a
+    block and not once a pass.  ``_paged_block`` stays the one block both
+    kinds of round run.
 
     Returns what ``paged_decode_round`` returns: the finished blocks
     [B, span] (a row's NEW tokens are those from its ``n_valid`` on; after
@@ -629,21 +674,27 @@ def _denoising_round(params, pool, tables, token, n_valid, active, seen_eos,
     where the configuration has experts; then, under ``trace_passes``
     (the benchmark's driver, archs/<arch>/drive.py), what every denoising
     pass ``saw``, ``picked`` and ``chose``, each [blocks, steps, B, L]."""
+    from seldon_core_tpu.ops.paged_attention import decode_plan
+
     L, steps = cfg.block_length, cfg.denoising_steps
     if span % L:
         raise ValueError(f"span={span} is no whole number of blocks of {L}")
     if temperature > 0.0:
         raise ValueError("a round of denoising passes decodes greedily")
     B = n_valid.shape[0]
+    if inplace is None:
+        inplace = decode_inplace(pool, width=L, heads=cfg.n_heads, rows=B)
+    capacity = tables.shape[1] * pool["l0"]["k"].shape[1]
     if token.ndim == 1:
         token = jnp.broadcast_to(token[:, None], (B, L))
     base = n_valid - n_valid % L
     valid = jnp.broadcast_to(active[:, None], (B, L))
     head = _head(params, cfg)
 
-    def through(pool, views, x, start, commit: bool):
+    def through(pool, plan, views, x, start, commit: bool):
         """The block's ids ``x`` [B, L] at ``start`` through every layer,
-        over ``views`` (the layers' caches as of the block's start); only
+        over the cache before ``start`` — by the kernel's ``plan``, or
+        ``views``, the layers' caches gathered at the block's start; only
         the commit writes the pool."""
         read = jnp.int32(0)
         with jax.named_scope("embed"):
@@ -651,7 +702,8 @@ def _denoising_round(params, pool, tables, token, n_valid, active, seen_eos,
         for i in range(cfg.n_layers):
             h, layer, aux = _paged_block(
                 params[f"l{i}"], h, pool[f"l{i}"], tables, start, valid,
-                cfg, view=views[i], write=commit,
+                cfg, plan=plan, interpret=inplace == "interpret",
+                view=views and views[i], write=commit,
                 kv_only=commit and i == cfg.n_layers - 1)
             if commit:
                 pool[f"l{i}"] = layer
@@ -665,14 +717,20 @@ def _denoising_round(params, pool, tables, token, n_valid, active, seen_eos,
         pos = start[:, None] + jnp.arange(L)[None, :]
         masked = pos >= n_valid[:, None]
         x = jnp.where(masked, jnp.int32(cfg.mask_id), token)
-        with jax.named_scope("kv_gather"):
-            views = [_paged_view(pool[f"l{i}"], tables)
-                     for i in range(cfg.n_layers)]
+        # how every pass of the block reads the cache before ``start``:
+        # the kernel's scalar operands, or each layer's view, once a block
+        plan = views = None
+        if inplace:
+            plan = decode_plan(start, active, capacity, fresh=0)
+        else:
+            with jax.named_scope("kv_gather"):
+                views = [_paged_view(pool[f"l{i}"], tables)
+                         for i in range(cfg.n_layers)]
 
         def denoise(c, _):
             x, masked, read = c
             with jax.named_scope("denoise"):
-                h, _, r = through(pool, views, x, start, commit=False)
+                h, _, r = through(pool, plan, views, x, start, commit=False)
                 with jax.named_scope("unembed"):
                     h = _rmsnorm(h, params["ln_f"], cfg.norm_eps)
                     logits = (h @ head).astype(jnp.float32)   # [B, L, V]
@@ -691,7 +749,7 @@ def _denoising_round(params, pool, tables, token, n_valid, active, seen_eos,
         (x, _, read), seen = jax.lax.scan(
             denoise, (x, masked, read), None, length=steps)
         with jax.named_scope("commit"):
-            _, pool, r = through(pool, views, x, start, commit=True)
+            _, pool, r = through(pool, plan, views, x, start, commit=True)
         out = x
         if eos_token >= 0:
             # only a GENERATED eos stops a row: the prompt's remainder may
@@ -841,7 +899,9 @@ def _begin(params, prompt, cfg: LMConfig, max_new_tokens: int,
     B, S = prompt.shape
     pool, tables = private_pool(cfg, B, S + max_new_tokens)
     knobs = dict(temperature=temperature, top_k=top_k, top_p=top_p,
-                 eos_token=eos_token, inplace=decode_inplace(pool, mesh))
+                 eos_token=eos_token,
+                 inplace=decode_inplace(pool, mesh, heads=cfg.n_heads,
+                                        rows=B))
     # prefill sees the prompt's own blocks only: its attention would
     # otherwise span (masked) the blocks the decode round has yet to fill
     logits, pool = paged_forward_jit(
@@ -880,7 +940,7 @@ def _decode(params, carry, tables, cfg: LMConfig, n: int, knobs):
 
 def _denoising_lane(params, prompt, cfg: LMConfig, max_new_tokens: int,
                     chunk: Optional[int], temperature: float,
-                    eos_token: int):
+                    eos_token: int, mesh=None):
     """The static lane of a generator by diffusion over blocks
     (``cfg.block_length`` > 1): the whole prompt prefilled (it chooses no
     token), then rounds of whole blocks over the request's private pool —
@@ -895,6 +955,7 @@ def _denoising_lane(params, prompt, cfg: LMConfig, max_new_tokens: int,
     rem = S % L
     whole = -(-(rem + max_new_tokens) // L) * L     # positions to generate
     pool, tables = private_pool(cfg, B, S - rem + whole)
+    inplace = decode_inplace(pool, mesh, width=L, heads=cfg.n_heads, rows=B)
     _, pool = paged_forward_jit(
         params, prompt, pool, tables[:, :-(-S // BLOCK_SIZE)],
         jnp.zeros((B,), jnp.int32), jnp.full((B,), S, jnp.int32), cfg=cfg,
@@ -910,7 +971,7 @@ def _denoising_lane(params, prompt, cfg: LMConfig, max_new_tokens: int,
         toks, pool, _, n_valid, seen, keys, *_ = paged_decode_round_jit(
             params, pool, tables, token, n_valid, jnp.ones((B,), bool),
             seen, keys, cfg, span=span, temperature=temperature, top_k=0,
-            top_p=0.0, eos_token=eos_token)
+            top_p=0.0, eos_token=eos_token, inplace=inplace)
         new = toks[:, skip:skip + max_new_tokens - done]
         yield new
         done, skip = done + new.shape[1], 0
@@ -956,7 +1017,7 @@ def generate(
         # round for the whole answer (_denoising_lane)
         return jnp.concatenate(list(_denoising_lane(
             params, prompt, cfg, max_new_tokens, None, temperature,
-            eos_token)), axis=1)
+            eos_token, mesh)), axis=1)
     eager = _eager(prompt)
     t0 = time.perf_counter() if eager else 0.0
     first, carry, tables, knobs = _begin(
@@ -1013,7 +1074,7 @@ def stream_chunks(params, prompt, cfg: LMConfig, max_new_tokens: int,
     if cfg.block_length > 1:
         # one round of whole blocks a chunk (``chunk`` rounded up to them)
         yield from _denoising_lane(params, prompt, cfg, max_new_tokens, chunk,
-                               temperature, eos_token)
+                                   temperature, eos_token, mesh)
         return
     first, carry, tables, knobs = _begin(
         params, prompt, cfg, max_new_tokens, temperature, rng, top_k,

@@ -1,6 +1,7 @@
 """Paged decode attention over the KV block pool IN PLACE — a Pallas TPU
-kernel for the continuous-batching decode step (models/generate.py
-``_paged_block`` at query width 1).
+kernel for the continuous-batching decode round (models/generate.py
+``_paged_block``): one query a row (a decode step) or the ``W`` queries of
+one diffusion block a row, which all see the same cache positions.
 
 The XLA formulation of paged attention (``_paged_view`` + ``_attend_paged``)
 gathers every row's blocks into a dense ``[B, KV, nblk*bs, hd]`` copy sized
@@ -25,7 +26,14 @@ where they lie, through the block table, and stops at the row's length:
     word by a shift or a mask (``_head_rows``);
   * online softmax in float32 across a row's chunks; scores accumulate in
     float32 from pool-dtype operands and ``p`` is cast to the model dtype
-    before ``p @ V`` — the roundings of ``_grouped_qk`` / ``_grouped_pv``.
+    before ``p @ V`` — the roundings of ``_grouped_qk`` / ``_grouped_pv``;
+  * a row's ``W`` queries are folded into the query group of each KV head
+    (``g * W`` query rows a head): there is no mask inside a block, so the
+    one per-row length serves them all.  For a caller whose queries also
+    see keys that are not in the pool (a diffusion block's own fresh K/V)
+    the kernel returns the softmax's running max and sum beside the
+    weighted sum (``stats``), and the caller joins the two parts in one
+    softmax (generate._attend_pool_and_fresh).
 
 Why not a pool laid out for the kernel (``[N, KV, bs, hd]``, a head's block
 one plain tile): XLA's TPU scatter re-lays such a pool to ``[N, bs, KV, hd]``
@@ -33,9 +41,12 @@ around every ``_paged_write`` — two whole-pool copies per layer (PERF.md §6,
 PR 25).
 
 Which formulation serves is decided by ONE pure function,
-``inplace_supported``: the kernel for width 1 on a TPU backend with a float
-pool, no mesh (a Mosaic call does not partition under GSPMD) and shapes the
-kernel tiles; the gather path otherwise.  The gather path is also the
+``inplace_supported``: the kernel on a TPU backend with a float pool, no
+mesh (a Mosaic call does not partition under GSPMD), shapes the kernel
+tiles and folded queries that fit its vector memory; the gather path
+otherwise.  ``width`` there is the queries a row brings to a round's step,
+all of ONE block: the causal widths of a prefill chunk or a verify pass
+(``paged_forward``) do not ask and keep the gather path, which is also the
 reference the kernel is tested against (tests/test_paged_attention.py).
 """
 
@@ -55,6 +66,11 @@ __all__ = ["blocks_per_chunk", "decode_plan", "inplace_supported",
 _CHUNK_POSITIONS = 512          # K/V positions one compute step covers
 _BUFFER_BYTES = 8 * 1024 * 1024  # both K and V chunks, double-buffered
 _MASK = -1e30                   # generate._attend_paged's mask value
+_STAT_LANES = 128               # one lane tile: the max in lane 0, the sum in 1..
+# what the kernel may hold in vector memory: the chunk buffers, the whole
+# batch's folded queries and outputs, the accumulators.  Mosaic's own limit
+# on a v5e is 16 MiB; the rest is its scores and spills
+_VMEM_BYTES = 12 * 1024 * 1024
 
 
 def blocks_per_chunk(block_size: int, kv_heads: int, head_dim: int,
@@ -70,35 +86,59 @@ def blocks_per_chunk(block_size: int, kv_heads: int, head_dim: int,
     return c if c * block_bytes <= _BUFFER_BYTES else 0
 
 
+def _query_rows(heads: int, kv_heads: int, width: int) -> int:
+    """Query rows a KV head serves, ``g * W``, in whole float32 sublane
+    tiles."""
+    return -(-(heads // kv_heads) * width // 8) * 8
+
+
 def inplace_supported(*, width: int, backend: str, pool_dtype: Any,
                       mesh: Optional[Any], block_size: int, kv_heads: int,
-                      head_dim: int) -> bool:
+                      head_dim: int, heads: Optional[int] = None,
+                      rows: int = 1) -> bool:
     """True where decode attention runs over the pool in place (this
     module's kernel), False where it takes the gather path.  Decided from
-    what the caller can observe, never from a model's name or a switch."""
-    if width != 1 or backend != "tpu" or mesh is not None:
+    what the caller can observe, never from a model's name or a switch.
+
+    ``width`` is the queries a row brings to a step, all of one block (1: a
+    decode step); ``heads`` and ``rows`` size the batch's folded queries,
+    which the kernel holds whole in vector memory (a caller that does not
+    say is answered for one row of one query head a KV head)."""
+    if width < 1 or backend != "tpu" or mesh is not None:
         return False
     dt = jnp.dtype(pool_dtype)
     if not jnp.issubdtype(dt, jnp.floating):
         return False
     # the 32-bit view: a position's KV heads are one memory tile of
     # 1, 2, 4 or 8 rows of 128 words, and a block is whole (8, 128) tiles
-    rows = kv_heads * dt.itemsize / 4
-    if (dt.itemsize not in (2, 4) or rows not in (1, 2, 4, 8)
-            or head_dim % 128 or block_size * rows % 8):
+    words = kv_heads * dt.itemsize / 4
+    if (dt.itemsize not in (2, 4) or words not in (1, 2, 4, 8)
+            or head_dim % 128 or block_size * words % 8):
         return False
-    return blocks_per_chunk(block_size, kv_heads, head_dim,
-                            dt.itemsize, 1) > 0
+    chunk = blocks_per_chunk(block_size, kv_heads, head_dim, dt.itemsize,
+                             max(1, _CHUNK_POSITIONS // block_size))
+    if chunk == 0:
+        return False
+    group = kv_heads * _query_rows(heads or kv_heads, kv_heads, width) * 4
+    held = (chunk * 4 * kv_heads * block_size * head_dim * dt.itemsize
+            + 2 * rows * group * head_dim            # queries in, sums out
+            + group * (head_dim + 2 * _STAT_LANES))  # the accumulators
+    if width > 1:
+        held += rows * group * _STAT_LANES           # the statistics out
+    return held <= _VMEM_BYTES
 
 
-def decode_plan(n_valid, active, capacity: int):
+def decode_plan(n_valid, active, capacity: int, fresh: int = 1):
     """The kernel's scalar operands for one decode step, shared by every
-    layer: ``lengths`` [B] (``n_valid + 1`` — the row's own fresh K/V is
-    already in the pool — 0 for an inactive row, at most the table's
-    ``capacity``), ``order`` [B] (the live rows' indices first) and
-    ``count`` [1] (how many are live)."""
+    layer: ``lengths`` [B] (``n_valid + fresh`` — ``fresh`` 1: the row's
+    own new K/V is already in the pool and its query sees it; 0: the
+    queries of a diffusion block that starts at ``n_valid`` see the cache
+    before it and nothing of the pool from there on — 0 for an inactive
+    row, at most the table's ``capacity``), ``order`` [B] (the live rows'
+    indices first) and ``count`` [1] (how many are live: a row of length 0
+    is not)."""
     B = n_valid.shape[0]
-    lengths = jnp.where(active, jnp.minimum(n_valid + 1, capacity), 0)
+    lengths = jnp.where(active, jnp.minimum(n_valid + fresh, capacity), 0)
     lengths = lengths.astype(jnp.int32)
     seen = jnp.cumsum(lengths > 0)  # live rows up to and including b
     # the r-th live row's index = rows that come before it
@@ -127,15 +167,26 @@ def _head_rows(buf, h: int, dtype):
     return pltpu.bitcast(w, jnp.float32).astype(dtype)
 
 
+def _first_lane(shape):
+    """True in lane 0 of a statistics tile: the max's place, the sum fills
+    the rest."""
+    return jax.lax.broadcasted_iota(jnp.int32, shape, len(shape) - 1) == 0
+
+
 def _kernel(len_ref, order_ref, count_ref, tbl_ref, q_ref, k_hbm, v_hbm,
-            o_ref, kbuf, vbuf, sem, m_scr, l_scr, acc_scr, *, nblk: int,
-            p_dtype):
+            o_ref, *refs, nblk: int, p_dtype):
+    # ``stat_ref``: the second output, there only for a caller that asked
+    # for the statistics
+    *stat_ref, kbuf, vbuf, sem, m_scr, l_scr, acc_scr = refs
     B, KV, g, hd = q_ref.shape
     _, C, bs, _, _ = kbuf.shape
     T = C * bs
     scale = jnp.float32(1.0 / (hd ** 0.5))
 
     o_ref[...] = jnp.zeros_like(o_ref)
+    for ref in stat_ref:
+        # a row the walk never reaches: no weight in the caller's softmax
+        ref[...] = jnp.where(_first_lane(ref.shape), _MASK, 0.0)
     if C > 1:
         # a chunk's blocks past the row's length are not fetched: whatever
         # VMEM held there meets p == 0, which must not be 0 * NaN
@@ -213,26 +264,37 @@ def _kernel(len_ref, order_ref, count_ref, tbl_ref, q_ref, k_hbm, v_hbm,
 
         t = jax.lax.fori_loop(0, chunks, chunk, t)
         o_ref[b] = acc_scr[...] / l_scr[...]
+        for ref in stat_ref:
+            ref[b] = jnp.where(_first_lane(ref.shape[1:]), m_scr[...],
+                               l_scr[...])
         return t
 
     jax.lax.fori_loop(0, count, row, jnp.int32(0))
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
+@functools.partial(jax.jit, static_argnames=("interpret", "stats"))
 def paged_decode_attention(q, k_pool, v_pool, tables, lengths, order, count,
-                           *, interpret: bool = False):
-    """Width-1 attention of every live row over its own blocks.
+                           *, interpret: bool = False, stats: bool = False):
+    """Attention of every live row's ``W`` queries over the first
+    ``lengths[b]`` positions of its own blocks, no mask among the queries.
 
-    q [B, H, 1, hd] in the model dtype; k_pool / v_pool
+    q [B, H, W, hd] in the model dtype; k_pool / v_pool
     ``[N, bs, KV, hd]``; tables [B, nblk] int32; ``lengths, order, count``
-    from ``decode_plan``.  Returns [B, H, 1, hd] in q's dtype; an inactive
-    row's output is zeros.  Jitted so that a program's layers share one
-    trace and one lowering of the kernel."""
-    B, H, _, hd = q.shape
+    from ``decode_plan``.  Returns [B, H, W, hd] in q's dtype; a row that is
+    not live (inactive, or of length 0) gives zeros.  Jitted so that a
+    program's layers share one trace and one lowering of the kernel.
+
+    ``stats`` (static) is for a caller whose queries see more keys than the
+    pool holds for them: it returns ``(out, peak, mass)`` in float32 — the
+    same weighted sum ``[B, KV, g, W, hd]``, the largest score
+    ``[B, KV, g, W]`` and the sum of ``exp(score - peak)`` — to be joined
+    with the other keys' part in one softmax; a row that is not live has
+    mass 0."""
+    B, H, W, hd = q.shape
     _, bs, KV, _ = k_pool.shape
     nblk = tables.shape[1]
     g = H // KV
-    gp = -(-g // 8) * 8  # float32 sublane tile
+    gp = _query_rows(H, KV, W)
     C = blocks_per_chunk(bs, KV, hd, k_pool.dtype.itemsize, nblk)
     if C == 0:
         raise ValueError(
@@ -241,20 +303,23 @@ def paged_decode_attention(q, k_pool, v_pool, tables, lengths, order, count,
             "(inplace_supported)")
     # float32 in and out: the model dtype's values exactly, in (8, 128)
     # tiles whatever the group size; cast back to the pool dtype in VMEM
-    qg = q.reshape(B, KV, g, hd).astype(jnp.float32)
-    if gp != g:
-        qg = jnp.pad(qg, ((0, 0), (0, 0), (0, gp - g), (0, 0)))
+    qg = q.reshape(B, KV, g * W, hd).astype(jnp.float32)
+    if gp != g * W:
+        qg = jnp.pad(qg, ((0, 0), (0, 0), (0, gp - g * W), (0, 0)))
+    vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
+    summed = jax.ShapeDtypeStruct((B, KV, gp, hd), jnp.float32)
+    stat = jax.ShapeDtypeStruct((B, KV, gp, _STAT_LANES), jnp.float32)
     out = pl.pallas_call(
         functools.partial(_kernel, nblk=nblk, p_dtype=q.dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4,
             grid=(1,),
             in_specs=[
-                pl.BlockSpec(memory_space=pltpu.VMEM),
+                vmem,
                 pl.BlockSpec(memory_space=pl.ANY),
                 pl.BlockSpec(memory_space=pl.ANY),
             ],
-            out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
+            out_specs=(vmem, vmem) if stats else vmem,
             scratch_shapes=[
                 pltpu.VMEM((2, C, bs, KV, hd), k_pool.dtype),
                 pltpu.VMEM((2, C, bs, KV, hd), v_pool.dtype),
@@ -264,7 +329,12 @@ def paged_decode_attention(q, k_pool, v_pool, tables, lengths, order, count,
                 pltpu.VMEM((KV, gp, hd), jnp.float32),
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct((B, KV, gp, hd), jnp.float32),
+        out_shape=(summed, stat) if stats else summed,
         interpret=interpret,
     )(lengths, order, count, tables.reshape(-1), qg, k_pool, v_pool)
-    return out[:, :, :g].astype(q.dtype).reshape(B, H, 1, hd)
+    if not stats:
+        return out[:, :, :g * W].astype(q.dtype).reshape(B, H, W, hd)
+    out, stat = out
+    stat = stat[:, :, :g * W].reshape(B, KV, g, W, _STAT_LANES)
+    return (out[:, :, :g * W].reshape(B, KV, g, W, hd),
+            stat[..., 0], stat[..., 1])

@@ -1134,8 +1134,9 @@ class GenServer:
             self._pool = shard_gen_pool(self.mesh, self._pool)
         # the Pallas kernel or the gather path: decided here, once, because
         # only the scheduler sees the mesh its pool is sharded over
-        self._inplace = decode_inplace(self._pool, self.mesh,
-                                       width=self._block)
+        self._inplace = decode_inplace(
+            self._pool, self.mesh, width=self._block,
+            heads=self.cfg.n_heads, rows=_pow2(self.slots))
         if self.spec:
             self._draft_pool = init_block_pool(
                 self.draft_cfg, self.num_blocks, self.block_size)

@@ -320,13 +320,76 @@ def test_in_place_a_decode_round_has_one_program_per_row_count(
     assert shapes == {(b, w) for b in (1, 2) for w in (2, 4, 8)}
     assert (counts["prefill"], counts["decode"]) == (2, 6)
     monkeypatch.setattr(gen_mod, "decode_inplace",
-                        lambda pool, mesh=None, width=1: "interpret")
+                        lambda pool, mesh=None, **sizes: "interpret")
     toks_inplace, shapes, counts = serve()
     assert shapes == {(1, 32), (2, 32)}    # 63 blocks a row can hold
     assert (counts["prefill"], counts["decode"]) == (2, 2)
     np.testing.assert_array_equal(toks_inplace, toks)
     np.testing.assert_array_equal(toks, np.asarray(generate(
         params, jnp.asarray(prompts, jnp.int32), CFG, max_new_tokens=14)))
+
+
+def test_in_place_a_block_generator_books_every_round_and_one_width(
+        monkeypatch):
+    """A generator by diffusion over blocks (blocks of 4 queries a row,
+    routed experts) whose ``decode_inplace`` answers "interpret": every
+    round attends over the pool in place — ``served_decode.inplace_steps``
+    equals ``device_steps``, 0 on the gather path — and the scheduler
+    dispatches ONE decode table width a row count where the gather path
+    took a power of two a need; the answers are the same."""
+    from seldon_core_tpu.models import generate as gen_mod
+    from seldon_core_tpu.models.generate import TransformerGenerator
+    from seldon_core_tpu.utils.genperf import GENPERF
+    from seldon_core_tpu.utils.hotrecord import SPINE
+
+    unit = TransformerGenerator(
+        vocab=96, d_model=32, n_heads=4, n_kv_heads=2, head_dim=16,
+        n_layers=2, qk_norm=True, tie_embeddings=False, d_expert=16,
+        n_experts=8, moe_k=2, moe_norm_topk=True, block_length=4,
+        denoising_steps=2, mask_id=90, dtype="float32", seed=5)
+    spec = unit.continuous_spec(unit.init_state(None))
+    rng = np.random.default_rng(8)
+    # rows of 1 and 3 whole pool blocks and a remainder, three rounds each:
+    # tables of 2, 3 -> 4 and 4 blocks at two rows, 4, 5 -> 8 and 6 -> 8 at
+    # one, on the gather path
+    batches = [rng.integers(0, 90, size=(2, 9)), rng.integers(0, 90, (1, 26))]
+
+    def serve():
+        SPINE.drain()
+        GENPERF.reset()
+        srv = GenServer(**spec, block_size=8, num_blocks=64, slots=2, span=8,
+                        prefill_chunk=16)
+        try:
+            got = [srv.submit(b.astype(float), max_new=20).future.result(
+                timeout=240) for b in batches]
+            progs = _settle(srv)["programs"]
+            shapes = set(srv._programs["decode"])
+        finally:
+            srv.stop()
+        SPINE.drain()
+        return got, shapes, progs, GENPERF.document()["served_decode"]
+
+    want, shapes, progs, served = serve()
+    assert shapes == {(2, 2), (2, 4), (1, 4), (1, 8)}
+    assert progs["decode"] == 4
+    assert served["inplace_steps"] == 0 < served["device_steps"]
+    seen = []
+
+    def interpret(pool, mesh=None, **sizes):
+        seen.append(sizes)
+        return "interpret"
+
+    monkeypatch.setattr(gen_mod, "decode_inplace", interpret)
+    got, shapes, progs, served = serve()
+    # what the scheduler tells the choosing function: a block's queries,
+    # the query heads and its widest padded batch
+    assert seen == [{"width": 4, "heads": 4, "rows": 2}]
+    assert shapes == {(2, 32), (1, 32)}    # 63 blocks a row can hold
+    assert progs["decode"] == 2
+    assert served["inplace_steps"] == served["device_steps"] > 0
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(g, w)
+    GENPERF.reset()
 
 
 # -- admission / retirement / exhaustion -------------------------------------
@@ -1465,7 +1528,7 @@ def test_a_record_of_another_identity_is_not_read(
     assert len(_records(cache_dir)) == 1
     if other == "inplace":
         monkeypatch.setattr(gen_mod, "decode_inplace",
-                            lambda pool, mesh=None, width=1: "interpret")
+                            lambda pool, mesh=None, **sizes: "interpret")
     _, progs = _boot_and_serve_one(
         params, max_new_tokens=4,
         **{"slots": {"slots": 4}, "span": {"span": 2}}.get(other, {}))

@@ -1,8 +1,9 @@
 """The in-place paged decode attention kernel (ops/paged_attention.py)
-against the gather path it replaces at width 1 (generate._paged_view +
-_attend_paged): Pallas interpret mode on the CPU, the choosing function, the
-decode round with the in-place path forced, and the kernel compiled for a
-described v5e at the benchmark cell's shapes."""
+against the gather path it replaces — at width 1 (generate._paged_view +
+_attend_paged) and for the queries of one diffusion block a row
+(_attend_view_and_fresh): Pallas interpret mode on the CPU, the choosing
+function, the decode round with the in-place path forced, and the kernel
+compiled for a described v5e at the benchmark cells' shapes."""
 
 import jax
 import jax.numpy as jnp
@@ -11,6 +12,8 @@ import pytest
 
 from seldon_core_tpu.models.generate import (
     _attend_paged,
+    _attend_pool_and_fresh,
+    _attend_view_and_fresh,
     _paged_view,
     decode_inplace,
     init_block_pool,
@@ -28,13 +31,13 @@ from seldon_core_tpu.ops.paged_attention import (
 KV, HD = 2, 128
 
 
-def _case(bs, g, dtype, n_valid, active, nblk, seed=0):
+def _case(bs, g, dtype, n_valid, active, nblk, seed=0, width=1):
     """Random q and pools, and tables of scrambled, non-contiguous block
     ids; an inactive row's table is all zeros (the scheduler's padding)."""
     rng = np.random.default_rng(seed)
     B, H = len(n_valid), KV * g
     N = B * nblk + 3
-    q = jnp.asarray(rng.normal(size=(B, H, 1, HD)), dtype)
+    q = jnp.asarray(rng.normal(size=(B, H, width, HD)), dtype)
     pool = {name: jnp.asarray(rng.normal(size=(N, bs, KV, HD)), dtype)
             for name in ("k", "v")}
     ids = rng.permutation(np.arange(1, N))[:B * nblk].reshape(B, nblk)
@@ -111,9 +114,116 @@ def test_decode_plan_compacts_live_rows():
     assert order.tolist()[:3] == [0, 2, 3] and count.tolist() == [3]
 
 
+def test_decode_plan_of_a_block_stops_at_its_start():
+    """``fresh=0``: the queries of a block see the cache BEFORE it; a row
+    whose block starts at 0 has nothing there and is not live."""
+    lengths, order, count = decode_plan(
+        jnp.asarray([8, 0, 0, 12], jnp.int32),
+        jnp.asarray([True, True, False, True]), 32, fresh=0)
+    assert lengths.tolist() == [8, 0, 0, 12]
+    assert order.tolist()[:2] == [0, 3] and count.tolist() == [2]
+
+
+def _block_case(bs, g, W, dtype, starts, active, nblk, seed=0):
+    """``_case`` for a block of ``W`` queries a row at ``starts``, with the
+    block's own fresh K/V, which never pass through the pool."""
+    q, pool, tables, start, act = _case(bs, g, dtype, starts, active, nblk,
+                                        seed=seed, width=W)
+    rng = np.random.default_rng(seed + 100)
+    k_new, v_new = (jnp.asarray(rng.normal(size=(len(starts), KV, W, HD)),
+                                dtype) for _ in "kv")
+    return q, pool, tables, start, act, k_new, v_new
+
+
+def _block_both(q, pool, tables, start, act, k_new, v_new):
+    want = _attend_view_and_fresh(q, _paged_view(pool, tables), start,
+                                  k_new, v_new)
+    capacity = tables.shape[1] * pool["k"].shape[1]
+    got = _attend_pool_and_fresh(
+        q, pool, tables, decode_plan(start, act, capacity, fresh=0),
+        k_new, v_new, interpret=True)
+    return np.asarray(got, np.float32), np.asarray(want, np.float32)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("g", [8, 12])
+@pytest.mark.parametrize("W", [4, 8])
+@pytest.mark.parametrize("bs", [16, 256])
+def test_block_of_queries_matches_the_gather_path(bs, W, g, dtype):
+    """A diffusion block's ``W`` queries a row over the cache before the
+    block's start (the kernel, its statistics) and the block's fresh K/V
+    (joined outside it) against ``_attend_view_and_fresh``: a block that
+    starts at 0 (nothing in the pool: the fresh part alone), after one
+    block of the block length, after exactly one pool block, not on a pool
+    block's boundary, several pool blocks in, and one that leaves the table
+    just room for itself; an inactive row between live ones."""
+    nblk = 4
+    starts = [0, W, bs, 0, bs + W, 2 * bs + bs // 2 - bs // 2 % W,
+              nblk * bs - W]
+    active = [True, True, True, False, True, True, True]
+    got, want = _block_both(
+        *_block_case(bs, g, W, dtype, starts, active, nblk))
+    live = np.asarray(active)
+    np.testing.assert_allclose(got[live], want[live], atol=_tol(dtype),
+                               rtol=0)
+    assert np.isfinite(got).all()          # never NaN, an unread row neither
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+def test_block_of_queries_reads_nothing_from_its_start_on(dtype):
+    """What the pool holds from a block's start on is stale (a pass over
+    the block writes nothing, the commit writes before it attends): huge
+    values there (finite, as stale K/V are: a masked position still meets
+    ``0 * v`` on either path), and NaN in every table entry past that pool
+    block, which is never fetched, change nothing."""
+    bs, W, nblk = 16, 4, 8
+    starts = [0, 12, bs + 8, 3 * bs]
+    case = _block_case(bs, 8, W, dtype, starts, [True] * 4, nblk, seed=2)
+    q, pool, tables, start, act, k_new, v_new = case
+    _, want = _block_both(*case)
+    poisoned = dict(pool)
+    for r, n in enumerate(starts):
+        ids = np.asarray(tables)[r]
+        for name in ("k", "v"):
+            arr = poisoned[name].at[ids[n // bs], n % bs:].set(1e4)
+            poisoned[name] = arr.at[ids[n // bs + 1:]].set(jnp.nan)
+    got, _ = _block_both(q, poisoned, tables, start, act, k_new, v_new)
+    np.testing.assert_allclose(got, want, atol=_tol(dtype), rtol=0)
+
+
+def test_statistics_of_a_row_that_is_not_live_carry_no_mass():
+    """The second output exists only for a caller that asks; an inactive
+    row and a row of length 0 give zeros, no mass and a finite peak."""
+    q, pool, tables, start, act = _case(
+        16, 8, jnp.float32, [0, 20, 0], [True, True, False], 4, width=4)
+    plan = decode_plan(start, act, 64, fresh=0)
+    out, peak, mass = paged_decode_attention(
+        q, pool["k"], pool["v"], tables, *plan, interpret=True, stats=True)
+    assert out.shape == (3, KV, 8, 4, HD) and peak.shape == mass.shape == (
+        3, KV, 8, 4)
+    for r in (0, 2):
+        assert not np.asarray(out[r]).any() and not np.asarray(mass[r]).any()
+    assert np.isfinite(np.asarray(peak)).all()
+    assert (np.asarray(mass[1]) >= 1.0).all()   # the peak's own exp(0)
+    plain = paged_decode_attention(q, pool["k"], pool["v"], tables, *plan,
+                                   interpret=True)
+    np.testing.assert_allclose(
+        np.asarray(plain), np.asarray(out).reshape(plain.shape), atol=1e-6)
+
+
+# a diffusion block of 4 queries a row at the second cell's widths: 32 query
+# heads over 4 KV heads, 32 padded rows
+BLOCK = {"width": 4, "kv_heads": 4, "heads": 32, "rows": 32}
+
+
 @pytest.mark.parametrize("kw,want", [
     ({}, True),
-    ({"width": 2}, False),                    # prefill chunk, verify pass
+    ({"heads": 24, "rows": 32}, True),        # the dense cell, told in full
+    # a block too wide to fold: 32 rows x 4 KV heads x 8 x 64 query rows of
+    # 128 float32, in and out, is 64 MiB of a 16-MiB vector memory
+    ({**BLOCK, "width": 64}, False),
     ({"pool_dtype": jnp.int8}, False),        # quantized pool
     ({"mesh": object()}, False),              # GSPMD cannot partition it
     ({"backend": "cpu"}, False),
@@ -123,8 +233,21 @@ def test_decode_plan_compacts_live_rows():
     ({"kv_heads": 6}, False),                 # not a memory tile
     ({"kv_heads": 1}, False),                 # half a word a position
     ({"kv_heads": 1, "pool_dtype": jnp.float32}, True),
-], ids=["cell", "wide", "int8", "mesh", "cpu", "f32", "bs16", "hd64", "kv6",
-        "kv1-bf16", "kv1-f32"])
+    (BLOCK, True),                            # 8 x 4 = 32 query rows a head
+    ({**BLOCK, "width": 8}, False),           # twice that: 12 MiB + buffers
+    ({**BLOCK, "width": 8, "rows": 16}, True),
+    ({**BLOCK, "rows": 128}, False),          # too many rows to hold whole
+    ({**BLOCK, "width": 2}, True),
+    ({**BLOCK, "width": 0}, False),           # no query at all
+    ({**BLOCK, "mesh": object()}, False),
+    ({**BLOCK, "pool_dtype": jnp.int8}, False),
+    ({**BLOCK, "backend": "cpu"}, False),
+    ({**BLOCK, "head_dim": 64}, False),
+    ({"width": 4}, True),                     # sizes untold: the shapes alone
+], ids=["cell", "cell-sized", "wide", "int8", "mesh", "cpu", "f32", "bs16",
+        "hd64", "kv6", "kv1-bf16", "kv1-f32", "block4", "block8-32rows",
+        "block8-16rows", "block4-128rows", "block2", "block0", "block-mesh",
+        "block-int8", "block-cpu", "block-hd64", "block-unsized"])
 def test_inplace_supported_chooses_by_what_it_can_observe(kw, want):
     base = dict(width=1, backend="tpu", pool_dtype=jnp.bfloat16, mesh=None,
                 block_size=256, kv_heads=2, head_dim=128)
@@ -262,26 +385,34 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
-@pytest.mark.parametrize("B,H,kv,bs,nblk,dtype", [
-    (16, 24, 2, 256, 4, jnp.bfloat16),   # the benchmark cell's decode shape
-    (32, 24, 2, 256, 8, jnp.bfloat16),
-    (32, 24, 2, 256, 128, jnp.bfloat16),  # its one width since PR 28
-    (8, 8, 4, 16, 16, jnp.bfloat16),     # the program's default block size
-    (8, 8, 2, 16, 4, jnp.float32),
-], ids=["cell-16x4", "cell-32x8", "cell-32x128-floor", "bs16-kv4", "f32"])
+@pytest.mark.parametrize("B,H,kv,bs,nblk,dtype,W", [
+    (16, 24, 2, 256, 4, jnp.bfloat16, 1),  # the dense cell's decode shape
+    (32, 24, 2, 256, 8, jnp.bfloat16, 1),
+    (32, 24, 2, 256, 128, jnp.bfloat16, 1),  # its one width since PR 28
+    (8, 8, 4, 16, 16, jnp.bfloat16, 1),    # the program's default block size
+    (8, 8, 2, 16, 4, jnp.float32, 1),
+    (32, 32, 4, 256, 128, jnp.bfloat16, 4),  # sdar-30b-a3b's block of 4
+    (1, 32, 4, 256, 128, jnp.bfloat16, 4),
+    (16, 32, 4, 256, 128, jnp.bfloat16, 8),  # the widest fold it is told fits
+    (8, 24, 2, 16, 16, jnp.float32, 4),    # 12 x 4 query rows, float32 pool
+], ids=["cell-16x4", "cell-32x8", "cell-32x128-floor", "bs16-kv4", "f32",
+        "block4-32x128", "block4-1x128", "block8-16x128", "block4-f32"])
 def test_kernel_compiles_for_a_described_v5e(one_chip, B, H, kv, bs, nblk,
-                                             dtype):
+                                             dtype, W):
     """Mosaic accepts the kernel at real widths: the 32-bit view of the
-    interleaved block, the strided head loads and the chunk buffers'
-    VMEM.  A compile, not a run: it says nothing about results or time."""
+    interleaved block, the strided head loads, the chunk buffers' VMEM and,
+    for a block of ``W`` queries a row, the folded queries, their outputs
+    and the statistics beside them — every shape ``inplace_supported``
+    says yes to here.  A compile, not a run: it says nothing about results
+    or time."""
     from jax.experimental.compilation_cache import compilation_cache
 
     def s(shape, dt):
         return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
 
-    assert inplace_supported(width=1, backend="tpu", pool_dtype=dtype,
+    assert inplace_supported(width=W, backend="tpu", pool_dtype=dtype,
                              mesh=None, block_size=bs, kv_heads=kv,
-                             head_dim=HD)
+                             head_dim=HD, heads=H, rows=B)
     N = 64
     # a compile for a described chip is written to the persistent cache
     # but can never be read back here: keep it out
@@ -290,9 +421,10 @@ def test_kernel_compiles_for_a_described_v5e(one_chip, B, H, kv, bs, nblk,
     compilation_cache.reset_cache()
     try:
         compiled = paged_decode_attention.lower(
-            s((B, H, 1, HD), dtype), s((N, bs, kv, HD), dtype),
+            s((B, H, W, HD), dtype), s((N, bs, kv, HD), dtype),
             s((N, bs, kv, HD), dtype), s((B, nblk), jnp.int32),
             s((B,), jnp.int32), s((B,), jnp.int32), s((1,), jnp.int32),
+            stats=W > 1,
         ).compile()
     finally:
         jax.config.update("jax_enable_compilation_cache", cache_was)

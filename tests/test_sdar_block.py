@@ -161,6 +161,76 @@ def test_chunked_prefill_then_a_round_equal_the_reference(model):
         assert int(n_valid[r]) == lens[r] - rem + span
 
 
+def test_a_round_in_place_equals_the_round_on_the_gather_path(model):
+    """Two whole rounds of ``_denoising_round`` with the block's queries
+    attending over the pool IN PLACE (``inplace="interpret"``: the Pallas
+    kernel for a block of queries a row, its statistics joined with the
+    block's fresh K/V outside it) against the gather path: rows whose first
+    round begins with a prompt remainder, a row whose first block starts at
+    position 0, an empty slot, a table padded past what any row owns, and an
+    ``eos`` that a row generates inside the first round.  The SAME tokens
+    to the id; the committed K/V of the live blocks to float32 rounding
+    (the two paths sum a softmax in different orders: 1e-5 of values of
+    order 1, where a wrong block or start reads at order 1)."""
+    doc, unit, params = model
+    lens = [3, 6, 9, 16, 0]                  # row 4 is an empty slot
+    rows = prompts(lens[:4], seed=7)
+    bs, own, width, span, B = 8, 5, 8, 8, 5
+    tables = np.zeros((B, width), np.int32)
+    tables[:4, :own] = 1 + np.random.default_rng(3).permutation(
+        4 * own).reshape(4, own)
+    active = jnp.asarray(np.asarray(lens) > 0)
+
+    def two_rounds(inplace, eos):
+        pool = init_block_pool(unit.cfg, 1 + 4 * own, bs)
+        toks = np.zeros((B, 16), np.int32)
+        for r, row in enumerate(rows):
+            toks[r, :lens[r]] = row
+        _, pool = paged_forward_jit(
+            params, jnp.asarray(toks), pool, jnp.asarray(tables[:, :own]),
+            jnp.zeros((B,), jnp.int32), jnp.asarray(lens, jnp.int32),
+            cfg=unit.cfg)
+        held = np.zeros((B, 4), np.int32)
+        for r, row in enumerate(rows):
+            held[r, :lens[r] % 4] = row[lens[r] - lens[r] % 4:]
+        token, n_valid = jnp.asarray(held), jnp.asarray(lens, jnp.int32)
+        seen, keys, out = jnp.zeros((B,), bool), jnp.zeros((B,), jnp.uint32), []
+        for _ in range(2):
+            t, pool, token, n_valid, seen, keys, *_ = paged_decode_round_jit(
+                params, pool, jnp.asarray(tables), token, n_valid, active,
+                seen, keys, unit.cfg, span=span, temperature=0.0, top_k=0,
+                top_p=0.0, eos_token=eos, inplace=inplace)
+            out.append(np.asarray(t))
+        return np.concatenate(out, 1), pool, np.asarray(n_valid), seen
+
+    free, _, _, _ = two_rounds(False, -1)
+    eos = int(free[1, 5])                    # row 1's 4th new token
+    want, pool_want, n_want, seen_want = two_rounds(False, eos)
+    got, pool, n_got, seen = two_rounds("interpret", eos)
+    assert np.asarray(seen_want)[1] and (want[1, 6:] == eos).all()
+    np.testing.assert_array_equal(got, want)
+    np.testing.assert_array_equal(n_got, n_want)
+    np.testing.assert_array_equal(np.asarray(seen), np.asarray(seen_want))
+    live = tables[:4, :own].reshape(-1)      # not the scratch block
+    for li in pool_want:
+        for name in ("k", "v"):
+            np.testing.assert_allclose(
+                np.asarray(pool[li][name])[live],
+                np.asarray(pool_want[li][name])[live], atol=1e-5, rtol=0)
+    # nothing is gathered in place: no op of the program sits under
+    # ``kv_gather``, and the kernel is what ``attn`` holds
+    args = (params, init_block_pool(unit.cfg, 1 + 4 * own, bs),
+            jnp.asarray(tables), jnp.zeros((B, 4), jnp.int32),
+            jnp.asarray(lens, jnp.int32), active, jnp.zeros((B,), bool),
+            jnp.zeros((B,), jnp.uint32), unit.cfg)
+    kw = dict(span=span, temperature=0.0, top_k=0, top_p=0.0, eos_token=-1)
+    text = paged_decode_round_jit.lower(
+        *args, **kw, inplace="interpret").as_text(debug_info=True)
+    assert "kv_gather" not in text and "denoise/attn" in text
+    assert "kv_gather" in paged_decode_round_jit.lower(
+        *args, **kw, inplace=False).as_text(debug_info=True)
+
+
 def test_static_lane_one_round_or_many_gives_the_reference_answer(model):
     doc, unit, params = model
     for n, max_new in ((7, 10), (3, 5), (12, 8)):
