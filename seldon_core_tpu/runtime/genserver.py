@@ -313,11 +313,14 @@ class _Flight:
     next program may already be queued behind this one."""
 
     __slots__ = ("kind", "rows", "ready", "out", "keys", "t_dispatch",
-                 "t_done", "size", "chunk", "host_s", "attr", "read", "skip")
+                 "t_done", "size", "chunk", "host_s", "attr", "read", "skip",
+                 "seq")
 
     def __init__(self, kind: str, size: int, rows: list, ready, out, keys,
-                 t_dispatch: float, attr: tuple):
+                 t_dispatch: float, attr: tuple, seq: int = 0):
         self.kind = kind              # "decode" | "prefill"
+        self.seq = seq                # the server's dispatch number: what
+        #                               its spans on a trace are joined by
         self.size = size              # padded row count
         self.rows = rows              # decode: (seq, take); prefill:
         #                               (seq, row index, fresh first token)
@@ -687,6 +690,9 @@ class GenServer:
         #: Between ticks it holds at most the one decode round the tick
         #: keeps ahead of its own readback (_depth)
         self._unread: deque = deque()
+        #: programs dispatched since boot, prefill and decode alike: the
+        #: number a program's spans carry (dispatch, ``/wait``, ``/emit``)
+        self._dispatched = 0
         # the server's own observations the wake-up before a round's end
         # rests on (_pace): the last observed completion and whether the
         # host was waiting when it came, a round's booked device seconds by
@@ -774,8 +780,9 @@ class GenServer:
         self._tick_row_passes = 0            # ... times the real rows in each
         self._tick_expert_slots = 0          # experts held x layers x passes
         self._tick_experts_read = 0          # experts the rounds read back
-        self._tick_prefill = [0, 0, 0]       # prefill: calls, experts read,
-        #                                      experts held x layers x calls
+        self._tick_prefill = [0, 0, 0, 0]    # prefill: calls, experts read,
+        #                                      experts held x layers x calls,
+        #                                      prompt tokens
         self._tick_tokens = 0                # tokens emitted
         self._tick_retired = 0               # sequences retired
         self._phases: Dict[str, float] = {}  # phase -> host wall s
@@ -1580,7 +1587,7 @@ class GenServer:
         self._tick_inplace_steps = self._tick_ahead_steps = 0
         self._tick_passes = self._tick_row_passes = 0
         self._tick_expert_slots = self._tick_experts_read = 0
-        self._tick_prefill = [0, 0, 0]
+        self._tick_prefill = [0, 0, 0, 0]
         self._tick_attr = {} if costledger_enabled() else None
         self._tick_kv_attr = []
         self._tick_tokens = self._tick_retired = 0
@@ -1673,6 +1680,7 @@ class GenServer:
             "prefill_calls": self._tick_prefill[0],
             "prefill_experts_read": self._tick_prefill[1],
             "prefill_expert_slots": self._tick_prefill[2],
+            "prefill_tokens": self._tick_prefill[3],
             "kv_positions": self._tick_kv_pos,
             "kv_blocks": self._tick_kv_blocks,
             "kv_ages": tuple(ages),
@@ -1768,7 +1776,7 @@ class GenServer:
         wake = (max(fl.t_dispatch, self._last_done) + min(readings)
                 - self._guard_s)
         self._paced = fl
-        with _Phase("GenServer._decode_round/wait"), self._wake:
+        with _Phase("GenServer._decode_round/wait", seq=fl.seq), self._wake:
             while not self._stopped:
                 left = wake - time.perf_counter()
                 if left <= 0:
@@ -1806,7 +1814,7 @@ class GenServer:
             # since its dispatch
             seen = not fl.ready.is_ready()
             t_wait = time.perf_counter()
-            with _Phase(name + "/wait"):
+            with _Phase(name + "/wait", seq=fl.seq):
                 jax.block_until_ready(fl.ready)
             fl.t_done = time.perf_counter()
             if fl is self._paced and self._unread[-1] is not fl:
@@ -2214,23 +2222,37 @@ class GenServer:
             self._tick_kv_blocks += sum(
                 self._blocks_needed(int(start[i]) + widths[i])
                 for i in range(len(batch)))
+            # what this call is given, counted once: the tick record's
+            # share (/genperf ``served_prefill``) and the dispatching
+            # span's arguments are these same numbers
+            tokens = sum(widths)
+            expert_slots = (self.cfg.n_layers * self.cfg.n_experts
+                            if getattr(self.cfg, "d_expert", 0) else 0)
+            self._tick_prefill[0] += 1
+            self._tick_prefill[2] += expert_slots
+            self._tick_prefill[3] += tokens
+            self._dispatched += 1
+            work = dict(
+                seq=self._dispatched, rows=B, real_rows=len(batch),
+                nblk=nblk, tokens=tokens,
+                kv_positions=int(start.sum()) + tokens,
+                # positions the call's tokens attend to: causal, a token's
+                # own index + 1
+                attended=sum(w * int(start[i]) + w * (w + 1) // 2
+                             for i, w in enumerate(widths)),
+                expert_slots=expert_slots)
         # fenced (depth 0): dispatch -> ready with nothing queued ahead, the
         # annotation a trace reduction sets the module event against (how
         # much of the fence is not device time).  Otherwise the dispatch is
-        # one more piece of building, behind the round still running
-        with (_Phase("GenServer._prefill_tick/device", rows=B,
-                     real_rows=len(batch), nblk=nblk, tokens=sum(widths),
-                     kv_positions=int(start.sum()) + sum(widths))
-              if fenced else _Phase("GenServer._prefill_tick/build")):
+        # one more piece of building, behind the round still running.
+        # Either way the span says what work the program was given
+        with _Phase("GenServer._prefill_tick/"
+                    + ("device" if fenced else "build"), **work):
             t_dispatch = time.perf_counter()
             fn, args, kw = self._program(
                 "prefill", toks, tables, start, width)
             # (diffusion blocks: the experts read, in the logits' place)
             logits, self._pool = fn(*args, **kw)
-            self._tick_prefill[0] += 1
-            if getattr(self.cfg, "d_expert", 0):
-                self._tick_prefill[2] += (self.cfg.n_layers
-                                          * self.cfg.n_experts)
             if self.spec:
                 d_nblk = _pow2(max(
                     self._blocks_needed(seq.prefill_pos + widths[i])
@@ -2251,7 +2273,7 @@ class GenServer:
                                           key_data)
                 first.copy_to_host_async()
             fl = _Flight("prefill", B, ending, logits, first, keys,
-                         t_dispatch, attr)
+                         t_dispatch, attr, work["seq"])
             if not picks and getattr(self.cfg, "d_expert", 0):
                 # what came in the logits' place: one int32 a chunk
                 fl.read = logits
@@ -2289,14 +2311,16 @@ class GenServer:
         and hand finished prompts off on a prefill replica."""
         self._await(fl, "GenServer._prefill_tick")
         first = key_data = None
+        counted = {}        # what the program counted itself, if anything
         with _Phase("GenServer._prefill_tick/readback"):
             if fl.out is not None:
                 first = np.asarray(fl.out)
                 if fl.keys is not None:
                     key_data = np.asarray(fl.keys)
             if fl.read is not None:
-                self._tick_prefill[1] += int(np.asarray(fl.read))
-        with _Phase("GenServer._prefill_tick/emit"):
+                counted["experts_read"] = int(np.asarray(fl.read))
+                self._tick_prefill[1] += counted["experts_read"]
+        with _Phase("GenServer._prefill_tick/emit", seq=fl.seq, **counted):
             for seq, i, fresh in fl.rows:
                 # the per-sequence prefill span (admission -> prompt fully
                 # cached): the "prefill dispatch" leg of a federated trace's
@@ -2443,28 +2467,34 @@ class GenServer:
                 kv_positions = sum(
                     self.span * (s.n_valid + self.span // 2) for s in batch)
                 layer_passes = passes * cfg.n_layers
+            expert_slots = (layer_passes * cfg.n_experts
+                            if getattr(cfg, "d_expert", 0) else 0)
             self._tick_kv_pos += kv_positions
             self._tick_dev_steps += self.span
             self._tick_passes += passes
             self._tick_row_passes += passes * len(batch)
-            if getattr(cfg, "d_expert", 0):
-                self._tick_expert_slots += layer_passes * cfg.n_experts
+            self._tick_expert_slots += expert_slots
             if self._inplace:
                 self._tick_inplace_steps += self.span
             if self._unread:
                 # queued behind a program whose results are still unread:
                 # the device goes from that one to this without the host
                 self._tick_ahead_steps += self.span
+            # the round's work as the tick record counts it above, on the
+            # dispatching span too, under the server's dispatch number
+            self._dispatched += 1
+            work = dict(
+                seq=self._dispatched, rows=B, real_rows=len(batch),
+                nblk=nblk, kv_positions=kv_positions,
+                inplace=int(bool(self._inplace)), passes=passes,
+                blocks=blocks, expert_slots=expert_slots)
         # fenced (depth 0): dispatch -> ready with nothing queued ahead, the
         # annotation the trace sets paged_decode_round's module event against
         # (decode_fence_slack_ms) and the guard's second half.  Otherwise
-        # the dispatch is one more piece of building
-        with (_Phase("GenServer._decode_round/device", rows=B,
-                     real_rows=len(batch), nblk=nblk,
-                     kv_positions=kv_positions,
-                     inplace=int(bool(self._inplace)), passes=passes,
-                     blocks=blocks)
-              if fenced else _Phase("GenServer._decode_round/build")):
+        # the dispatch is one more piece of building.  Either way the span
+        # says what work the program was given
+        with _Phase("GenServer._decode_round/"
+                    + ("device" if fenced else "build"), **work):
             t_dispatch = time.perf_counter()
             take, put, _ = _carry_ops()
             token, seen, keys = take(self._carry, idx)
@@ -2478,7 +2508,7 @@ class GenServer:
                 keys if self.temperature > 0.0 else None)
             toks.copy_to_host_async()
             fl = _Flight("decode", B, rows, toks, toks, key_data, t_dispatch,
-                         attr)
+                         attr, work["seq"])
             fl.skip = skip
             if extra and "experts_read" in extra[0]:
                 # one int32 a round, read back beside the round's tokens
@@ -2517,13 +2547,15 @@ class GenServer:
         host sync a round needs -- then emit each row's share."""
         self._await(fl, "GenServer._decode_round")
         key_data = None
+        counted = {}        # what the round counted itself, if anything
         with _Phase("GenServer._decode_round/readback"):
             toks = np.asarray(fl.out)
             if fl.keys is not None:
                 key_data = np.asarray(fl.keys)
             if fl.read is not None:
-                self._tick_experts_read += int(np.asarray(fl.read))
-        with _Phase("GenServer._decode_round/emit"):
+                counted["experts_read"] = int(np.asarray(fl.read))
+                self._tick_experts_read += counted["experts_read"]
+        with _Phase("GenServer._decode_round/emit", seq=fl.seq, **counted):
             for i, (s, take) in enumerate(fl.rows):
                 off = fl.skip[i]
                 s.inflight -= take

@@ -138,6 +138,7 @@ class GenPerf:
         self.prefill_calls = 0
         self.prefill_experts_read = 0
         self.prefill_expert_slots = 0
+        self.prefill_tokens = 0        # prompt tokens the calls were given
         self.decode_kv_positions = 0  # cache positions streamed per step
         self.kv_block_age = Reservoir(1024)   # seconds held at release
         self.kv_blocks_released = 0
@@ -223,6 +224,7 @@ class GenPerf:
                 detail.get("prefill_experts_read", 0) or 0)
             self.prefill_expert_slots += int(
                 detail.get("prefill_expert_slots", 0) or 0)
+            self.prefill_tokens += int(detail.get("prefill_tokens", 0) or 0)
             for n_blocks, age_s in kv_ages:
                 self.kv_blocks_released += int(n_blocks)
                 self.kv_block_age.observe(float(age_s))
@@ -425,6 +427,7 @@ class GenPerf:
                 "calls": self.prefill_calls,
                 "experts_read": self.prefill_experts_read,
                 "expert_slots": self.prefill_expert_slots,
+                "tokens": self.prefill_tokens,
             }
         doc["served_decode"] = self.served_decode()
         return doc

@@ -38,6 +38,82 @@ def v5e_peaks(monkeypatch):
     monkeypatch.setattr(PerfObservatory, "peaks", lambda self: peaks)
 
 
+class SpanLog:
+    """What ``jax.profiler.TraceAnnotation`` was opened with while the
+    recorder stood in its place: ``log`` holds ``(thread id, "B" | "E",
+    name, arguments)`` in the order the scheduler opened and closed them."""
+
+    #: what a dispatching span says of the program it launches
+    DECODE_WORK = {"seq", "rows", "real_rows", "nblk", "kv_positions",
+                   "inplace", "passes", "blocks", "expert_slots"}
+    PREFILL_WORK = {"seq", "rows", "real_rows", "nblk", "tokens",
+                    "kv_positions", "attended", "expert_slots"}
+    FUNCTIONS = {"decode": "GenServer._decode_round",
+                 "prefill": "GenServer._prefill_tick"}
+
+    def __init__(self):
+        self.log = []
+
+    def opened(self):
+        """``(name, arguments, the span opened before it under the same
+        parent or None)`` of every span, in opening order."""
+        out, stack = [], [[None]]
+        for _, kind, name, args in self.log:
+            if kind == "B":
+                out.append((name, args, stack[-1][-1]))
+                stack[-1].append(name)
+                stack.append([None])
+            else:
+                stack.pop()
+        return out
+
+    def dispatches(self, kind):
+        """The arguments of every span that wraps the ``jit`` call of
+        ``kind``'s program: ``/device`` where fenced, else the ``/build``
+        that carries arguments.  Asserts on the way that each says all of
+        its work, that the span before it is the argument-less ``/build``
+        of the same function, and that no other ``/build`` says anything."""
+        fn = self.FUNCTIONS[kind]
+        want = self.DECODE_WORK if kind == "decode" else self.PREFILL_WORK
+        found = []
+        for name, args, before in self.opened():
+            if name == fn + "/device" or (name == fn + "/build" and args):
+                assert set(args) == want, (name, args)
+                assert before == fn + "/build", (name, before)
+                found.append(args)
+        return found
+
+    def carrying(self, suffix, kind):
+        """The arguments of every ``<function>/<suffix>`` span."""
+        return [args for _, k, name, args in self.log
+                if k == "B" and name == self.FUNCTIONS[kind] + suffix]
+
+
+@pytest.fixture
+def recorded_spans(monkeypatch):
+    """``jax.profiler.TraceAnnotation`` swapped for a recorder (no profiler
+    session): the scheduler's spans and their arguments, as a ``SpanLog``."""
+    import threading
+
+    spans = SpanLog()
+
+    class Recorder:
+        def __init__(self, name, **args):
+            self.name, self.args = name, args
+
+        def __enter__(self):
+            spans.log.append(
+                (threading.get_ident(), "B", self.name, self.args))
+            return self
+
+        def __exit__(self, *exc):
+            spans.log.append(
+                (threading.get_ident(), "E", self.name, self.args))
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Recorder)
+    return spans
+
+
 @pytest.fixture(scope="session")
 def devices8():
     devs = jax.devices()

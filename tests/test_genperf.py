@@ -624,17 +624,17 @@ WAIT_NAMES = {"GenServer._decode_round/wait", "GenServer._prefill_tick/wait"}
 
 @pytest.mark.parametrize("depth", [0, 1])
 def test_scheduler_opens_every_phase_annotation_properly_nested(
-        params, monkeypatch, depth):
+        params, monkeypatch, depth, recorded_spans):
     """With jax.profiler.TraceAnnotation replaced by a recorder (no
     profiler session), one tiny scheduler run opens every phase name the
     trace reductions look for, as a well-formed stack on the scheduler
     thread, each sub-phase inside its function inside ``_tick``, and the
-    ``/device`` phases carry what rode the dispatch.  Held at depth 0 the
-    names are the synchronous order's; a round ahead, the waits for
-    queued programs are ``/wait`` and ``/device`` stays the fenced
-    rounds' (the first, then one in ``_FENCE_EVERY``)."""
-    import threading
-
+    span that wraps a program's dispatch -- ``/device`` where fenced, the
+    second ``/build`` otherwise -- says what work the program was given
+    under the server's dispatch number; the first ``/build`` says nothing.
+    Held at depth 0 the names are the synchronous order's; a round ahead,
+    the waits for queued programs are ``/wait`` and ``/device`` stays the
+    fenced rounds' (the first, then one in ``_FENCE_EVERY``)."""
     from seldon_core_tpu.runtime import genserver as gs
 
     if depth == 0:
@@ -642,20 +642,7 @@ def test_scheduler_opens_every_phase_annotation_properly_nested(
     else:
         monkeypatch.setattr(gs, "_FENCE_EVERY", 10 ** 9)
 
-    log = []          # (thread id, "B"/"E", name, args)
-
-    class Recorder:
-        def __init__(self, name, **args):
-            self.name, self.args = name, args
-
-        def __enter__(self):
-            log.append((threading.get_ident(), "B", self.name, self.args))
-            return self
-
-        def __exit__(self, *exc):
-            log.append((threading.get_ident(), "E", self.name, self.args))
-
-    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Recorder)
+    log = recorded_spans.log      # (thread id, "B"/"E", name, args)
     srv = _server(params)
     try:
         reqs = [srv.submit(np.full((1, 6), i + 1.0)) for i in range(3)]
@@ -699,6 +686,68 @@ def test_scheduler_opens_every_phase_annotation_properly_nested(
     if depth == 0:
         assert any(a["real_rows"] > 1
                    for a in device_args["GenServer._decode_round/device"])
+    # fenced or not, every dispatch says its work (``dispatches`` asserts
+    # the full set and the argument-less ``/build`` before it) under one
+    # number a server, rising by one a program across both kinds
+    work = {kind: recorded_spans.dispatches(kind)
+            for kind in ("decode", "prefill")}
+    seqs = sorted(a["seq"] for calls in work.values() for a in calls)
+    assert seqs == list(range(1, len(seqs) + 1))
+    for kind, calls in work.items():
+        assert calls and [a["seq"] for a in calls] == sorted(
+            a["seq"] for a in calls)
+        fenced = len(device_args.get(
+            recorded_spans.FUNCTIONS[kind] + "/device", []))
+        if depth == 0:
+            assert fenced == len(calls)
+        elif kind == "decode":
+            assert fenced < len(calls)      # some rode a second /build
+        # what was dispatched is read back under its own number, once
+        assert sorted(a["seq"] for a in recorded_spans.carrying(
+            "/emit", kind)) == [a["seq"] for a in calls]
+        assert all(set(a) == {"seq"} and a["seq"] in seqs
+                   for a in recorded_spans.carrying("/wait", kind))
+    assert all(a["expert_slots"] == 0 and a["passes"] == srv.span
+               and a["blocks"] == srv.span
+               for a in work["decode"])
+
+
+def test_dispatching_spans_sum_to_the_genperf_counters(params,
+                                                       recorded_spans):
+    """Over a server's life the arguments of its dispatching spans add up
+    to what ``/genperf`` counted: they are the tick record's own numbers,
+    so a trace reader that sums the calls it joined and a counter reader
+    that subtracts two documents speak of the same work."""
+    srv = _server(params)
+    try:
+        reqs = [srv.submit(np.full((1, 5 + 3 * i), i + 1.0))
+                for i in range(4)]
+        for r in reqs:
+            r.future.result(timeout=30)
+        _settle(srv)
+        time.sleep(0.05)
+        SPINE.drain()
+        doc = GENPERF.document()
+    finally:
+        srv.stop()
+    decode = recorded_spans.dispatches("decode")
+    prefill = recorded_spans.dispatches("prefill")
+    served = doc["served_decode"]
+    assert sum(a["kv_positions"] for a in decode) == served["kv_positions"]
+    assert sum(a["passes"] for a in decode) == served["passes"]
+    assert sum(a["passes"] * a["real_rows"] for a in decode) == \
+        served["row_passes"]
+    assert len(decode) * srv.span == served["device_steps"]
+    assert len(prefill) == doc["served_prefill"]["calls"]
+    assert sum(a["tokens"] for a in prefill) == \
+        doc["served_prefill"]["tokens"] == sum(5 + 3 * i for i in range(4))
+    # a prompt's chunks attend causally: over its calls, n(n+1)/2 positions
+    assert sum(a["attended"] for a in prefill) == sum(
+        n * (n + 1) // 2 for n in (5 + 3 * i for i in range(4)))
+    assert sum(a["kv_positions"] for a in prefill) >= sum(
+        a["tokens"] for a in prefill)
+    assert doc["rows"]["real_total"] == sum(
+        a["real_rows"] for a in decode + prefill)
 
 
 def test_new_metric_families_registered():

@@ -501,6 +501,58 @@ def test_genperf_counts_passes_and_experts_apart_from_tokens(model,
     assert served["experts_read"] < served["expert_slots"]
 
 
+def test_dispatching_spans_say_a_rounds_passes_and_experts(
+        model, clean_genperf, recorded_spans):
+    """Every dispatch of a diffusion-block generator says its work on the
+    span that wraps it -- the round's passes and blocks, the cache
+    positions its passes read, the experts held x expert-layer passes --
+    and what the program counted itself comes back on ``/emit`` under the
+    same number: summed over a server's life they are ``/genperf``'s."""
+    doc, unit, params = model
+    srv = server(unit, params)
+    try:
+        reqs = [srv.submit(np.stack(prompts([n, n], seed=60 + n)),
+                           max_new=max_new)
+                for n, max_new in ((6, 14), (21, 9), (3, 8))]
+        for r in reqs:
+            r.future.result(timeout=180)
+        served = served_decode(2 * (14 + 9 + 8))
+        prefill = GENPERF.document()["served_prefill"]
+    finally:
+        srv.stop()
+    rounds = recorded_spans.dispatches("decode")
+    chunks = recorded_spans.dispatches("prefill")
+    seqs = sorted(a["seq"] for a in rounds + chunks)
+    assert seqs == list(range(1, len(seqs) + 1))
+    steps = doc["denoising_steps"]
+    assert all(a["blocks"] == 2 and a["passes"] == 2 * (steps + 1)
+               and a["inplace"] == 0 for a in rounds)
+    for key in ("kv_positions", "expert_slots", "passes"):
+        assert sum(a[key] for a in rounds) == served[key] > 0, key
+    assert sum(a["passes"] * a["real_rows"] for a in rounds) == \
+        served["row_passes"]
+    read = {kind: recorded_spans.carrying("/emit", kind)
+            for kind in ("decode", "prefill")}
+    assert sorted(a["seq"] for a in read["decode"]) == [
+        a["seq"] for a in rounds]
+    assert sorted(a["seq"] for a in read["prefill"]) == [
+        a["seq"] for a in chunks]
+    assert sum(a["experts_read"] for a in read["decode"]) == \
+        served["experts_read"] > 0
+    assert sum(a["experts_read"] for a in read["prefill"]) == \
+        prefill["experts_read"] > 0
+    # a call never reads more experts than it holds slots for
+    slots = {a["seq"]: a["expert_slots"] for a in rounds + chunks}
+    assert all(0 < a["experts_read"] <= slots[a["seq"]]
+               for a in read["decode"] + read["prefill"])
+    assert (len(chunks), sum(a["tokens"] for a in chunks),
+            sum(a["expert_slots"] for a in chunks)) == (
+        prefill["calls"], prefill["tokens"], prefill["expert_slots"])
+    assert prefill["tokens"] == 2 * (6 + 21 + 3)
+    assert sum(a["attended"] for a in chunks) == 2 * sum(
+        n * (n + 1) // 2 for n in (6, 21, 3))
+
+
 def test_a_dense_generator_reports_no_experts_and_one_pass_a_step(
         clean_genperf):
     unit = TransformerGenerator(vocab=48, d_model=32, n_heads=4, n_layers=2,
@@ -519,7 +571,7 @@ def test_a_dense_generator_reports_no_experts_and_one_pass_a_step(
     assert served["experts_read"] == served["expert_slots"] == 0
     SPINE.drain()
     assert GENPERF.document()["served_prefill"] == {
-        "calls": 1, "experts_read": 0, "expert_slots": 0}
+        "calls": 1, "experts_read": 0, "expert_slots": 0, "tokens": 5}
 
 
 def test_observe_tick_folds_the_new_counters():
@@ -529,13 +581,14 @@ def test_observe_tick_folds_the_new_counters():
             "wall_s": 0.01, "device_s": 0.008, "steps": 8, "tokens": 6,
             "passes": 10, "row_passes": 30, "experts_read": 700,
             "expert_slots": 8704, "prefill_calls": 2,
-            "prefill_experts_read": 1500, "prefill_expert_slots": 1792})
+            "prefill_experts_read": 1500, "prefill_expert_slots": 1792,
+            "prefill_tokens": 300})
     served = GENPERF.document()["served_decode"]
     prefill = GENPERF.document()["served_prefill"]
     GENPERF.reset()
     # a chunk is read back in whatever tick comes next: every kind folds it
     assert prefill == {"calls": 6, "experts_read": 4500,
-                       "expert_slots": 5376}
+                       "expert_slots": 5376, "tokens": 900}
     # a prefill tick's are not a decode round's
     assert (served["passes"], served["row_passes"], served["experts_read"],
             served["expert_slots"]) == (20, 60, 1400, 17408)
