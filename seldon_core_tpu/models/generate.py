@@ -39,6 +39,7 @@ The cache-free forward (training, ``lm_apply``) is models/transformer.py.
 
 from __future__ import annotations
 
+import functools
 import itertools
 import time
 from typing import Any, Dict, Optional
@@ -393,6 +394,17 @@ def _attend_pool_and_fresh(q, pool_layer, tables, plan, k_new, v_new,
     return a.astype(q.dtype).reshape(q.shape)
 
 
+# A ``jit`` of its own inside the programs that call it: a program traces and
+# lowers the block once per distinct (shapes, static arguments) -- a dense
+# program once, a round of denoising passes three times (a pass that writes
+# nothing, the commit, the commit's last layer) -- and every other layer is a
+# cached bind that lowers to a ``call`` of one private function, so trace,
+# lowering and the module's text no longer grow with depth (PERF.md section
+# 6, PR 37).  XLA inlines the calls before it optimises.  Nothing is donated
+# here: the programs donate the pool.  ``_paged_block.__wrapped__`` is the
+# plain body.
+@functools.partial(
+    jax.jit, static_argnames=("cfg", "interpret", "kv_only", "write"))
 def _paged_block(lp, x, pool_layer, tables, start, valid, cfg: LMConfig,
                  plan=None, interpret: bool = False, limit=None,
                  kv_only: bool = False, view=None, write: bool = True):

@@ -203,6 +203,14 @@ _ROUND_READINGS = 8
 # the loaders are what compiles side by side (5-12 s a program: six decode
 # programs behind eighteen fetched ones in 49.1 s with 8), so the constant is
 # the widest that still leaves the tracer and the scheduler a core each.
+# Since PR 37 a program traces its decoder block once, not once a layer
+# (``generate._paged_block`` is a ``jit`` of its own), and the same 24
+# programs are 10.5-10.7 s of the tracer (/stats ``boot_trace_s``) inside a
+# load of 14.3-14.7 s (PERF.md section 6, PR 37, call P37b; the sparse
+# configuration's 24 of 7 layers: 28.1-29.1 s inside 29.4-30.3, its decode
+# programs 2.4 s each, most of it the kernels' and the expert layer's own
+# trace and lowering): the tracer is still the longer of the two, so the
+# arrangement stands.
 _LOAD_THREADS = 8
 
 
@@ -759,6 +767,7 @@ class GenServer:
         # kept: no cache, a mesh, a draft model)
         self._loaded: Dict[str, set] = {"prefill": set(), "decode": set()}
         self._boot_load_s = 0.0
+        self._boot_trace_s = 0.0
         self._missed = 0            # dispatched shapes it had not loaded
         self._record_path = ""
         self._identity = ""
@@ -1037,12 +1046,15 @@ class GenServer:
             "tokens_emitted_total": self.tokens_emitted_total,
             "tick_errors_total": self.tick_errors_total,
             # distinct shapes dispatched since boot; of the record's, how
-            # many this boot loaded ahead and in how long; dispatched shapes
-            # it had not loaded (each traced and loaded by a request)
+            # many this boot loaded ahead, in how long, and how much of
+            # that its one tracer thread spent tracing and lowering (the
+            # interpreter's share of the load); dispatched shapes it had
+            # not loaded (each traced and loaded by a request)
             "programs": {
                 **{k: len(v) for k, v in self._programs.items()},
                 "loaded_at_boot": sum(map(len, self._loaded.values())),
                 "boot_load_s": round(self._boot_load_s, 3),
+                "boot_trace_s": round(self._boot_trace_s, 3),
                 "missed": self._missed,
             },
             # what the wake-up before a round's end rests on (_pace): a
@@ -1347,8 +1359,9 @@ class GenServer:
             self._loaded[kind].add(shape)
         self._boot_load_s = time.perf_counter() - t0
         n = sum(map(len, self._loaded.values()))
-        logger.info("loaded %d of the record's %d programs in %.1f s (%s)",
-                    n, len(jobs), self._boot_load_s, self._record_path)
+        logger.info("loaded %d of the record's %d programs in %.1f s, "
+                    "%.1f s of them tracing (%s)", n, len(jobs),
+                    self._boot_load_s, self._boot_trace_s, self._record_path)
         if n < len(jobs):
             self._write_record()    # without the ones that raised
 
@@ -1390,12 +1403,18 @@ class GenServer:
                             _abstract(self._zero_keys[B])
                             if keys is None else keys)
             fn, args, kw = self._program(kind, *operands, state=state)
-            return fn.lower(*args, **kw)
+            t0 = time.perf_counter()
+            lowered = fn.lower(*args, **kw)
+            # the one tracer thread's own seconds: trace and lowering
+            self._boot_trace_s += time.perf_counter() - t0
+            return lowered
 
         loaded = []
-        with concurrent.futures.ThreadPoolExecutor(1) as tracer, \
+        with concurrent.futures.ThreadPoolExecutor(
+                1, "genserver-trace") as tracer, \
                 concurrent.futures.ThreadPoolExecutor(
-                    min(_LOAD_THREADS, len(jobs))) as loaders:
+                    min(_LOAD_THREADS, len(jobs)),
+                    "genserver-load") as loaders:
             lowered = [tracer.submit(lower, job) for job in jobs]
             done = [loaders.submit(lambda fut: fut.result().compile(), fut)
                     for fut in lowered]

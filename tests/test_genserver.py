@@ -1334,7 +1334,16 @@ def _jax_events_listener():
                 _JAX_EVENTS[0].append(
                     "lowered on " + threading.current_thread().name)
 
+    def on_event(name, **kw):
+        # the persistent cache's own, by the thread that asked it:
+        # "cache_hits on genserver-load", "cache_misses on MainThread"
+        if _JAX_EVENTS and "compilation_cache" in name:
+            _JAX_EVENTS[0].append(
+                name.rsplit("/", 1)[-1] + " on "
+                + threading.current_thread().name.rsplit("_", 1)[0])
+
     jax.monitoring.register_event_duration_secs_listener(on_duration)
+    jax.monitoring.register_event_listener(on_event)
 
 
 @pytest.fixture
@@ -1363,6 +1372,17 @@ def cache_dir(tmp_path, monkeypatch):
     yield tmp_path
     jax.config.update("jax_compilation_cache_dir", before)
     compilation_cache.reset_cache()
+
+
+@pytest.fixture
+def cache_keeps_every_program(cache_dir):
+    """The persistent cache takes the CPU's quick compiles too, so a
+    second boot's fetches say under which key the first one wrote."""
+    name = "jax_persistent_cache_min_compile_time_secs"
+    before = getattr(jax.config, name)
+    jax.config.update(name, 0.0)
+    yield
+    jax.config.update(name, before)
 
 
 _RECORD_PROMPTS = np.random.default_rng(33).integers(0, 48, size=(3, 7))
@@ -1419,7 +1439,8 @@ def test_program_record_round_trips(tmp_path, caplog):
 
 
 def test_a_second_boot_loads_what_the_first_dispatched(
-        params, cache_dir, jax_events, monkeypatch):
+        params, cache_dir, cache_keeps_every_program, jax_events,
+        monkeypatch):
     """The first server of a deployment traces and loads each shape when
     a request first needs it and writes the record once a new shape; the
     second loads them all inside ``_init_device``, then serves the same
@@ -1427,6 +1448,7 @@ def test_a_second_boot_loads_what_the_first_dispatched(
     nothing written, and the same tokens and chunks."""
     from seldon_core_tpu.runtime import genserver as gs_mod
 
+    jax.clear_caches()      # what an earlier test compiled is in no file
     writes = []
     real_write = gs_mod.write_program_record
     monkeypatch.setattr(
@@ -1445,7 +1467,8 @@ def test_a_second_boot_loads_what_the_first_dispatched(
     assert n >= 4 and len(writes) == n          # once a new shape
     assert progs == {"prefill": len(shapes["prefill"]),
                      "decode": len(shapes["decode"]),
-                     "loaded_at_boot": 0, "boot_load_s": 0.0, "missed": n}
+                     "loaded_at_boot": 0, "boot_load_s": 0.0,
+                     "boot_trace_s": 0.0, "missed": n}
     (record,) = _records(cache_dir)
     doc = json.loads(record.read_text())
     assert {k: {tuple(x) for x in doc[k]} for k in shapes} == shapes
@@ -1460,6 +1483,10 @@ def test_a_second_boot_loads_what_the_first_dispatched(
         # the boot did the lowering, once a listed shape
         assert jax_events.count("jaxpr_to_mlir_module_duration") >= n
         assert second._loaded == shapes
+        # ... and fetched every one under the key the first server's TICK
+        # had written it under: one program under one key, whoever traces
+        assert jax_events.count("cache_hits on genserver-load") == n
+        assert "cache_misses on genserver-load" not in jax_events
         del jax_events[:]
         got = _serve_recorded(second)
         assert not [e for e in jax_events if e in _LOWERED_OR_COMPILED]
@@ -1468,7 +1495,8 @@ def test_a_second_boot_loads_what_the_first_dispatched(
         second.stop()
     assert got == want
     assert progs["loaded_at_boot"] == n and progs["missed"] == 0
-    assert progs["boot_load_s"] > 0.0
+    # the tracer thread's seconds inside ``fn.lower``, within the load's wall
+    assert 0.0 < progs["boot_trace_s"] <= progs["boot_load_s"]
     assert (progs["prefill"], progs["decode"]) == (
         len(shapes["prefill"]), len(shapes["decode"]))
     assert not writes                           # no set grew past the record
@@ -1648,5 +1676,6 @@ def test_servers_that_keep_no_record_write_and_load_nothing(
         finally:
             srv.stop()
         assert progs["loaded_at_boot"] == 0 and progs["boot_load_s"] == 0.0
+        assert progs["boot_trace_s"] == 0.0
         assert progs["missed"] == progs["prefill"] + progs["decode"] > 0
         assert not _records(cache_dir)

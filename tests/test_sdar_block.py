@@ -13,6 +13,7 @@ the expert layer against its loop is held to 1e-5 of values of order 1
 
 import importlib.util
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -71,6 +72,16 @@ def config(steps=4):
 def model(request):
     doc, unit = config(request.param)
     return doc, unit, unit.init_state(None)["params"]
+
+
+def _op_paths(lowered) -> str:
+    """The scope path of every op of the compiled program, a line each --
+    XLA's ``op_name``, which a trace's ``tf_op`` repeats -- with the
+    block's own ``jit`` taken out: a stage lies under the pass that runs
+    it (``.../denoise/jit(_paged_block)/attn/...``)."""
+    return "\n".join(re.findall(
+        r'op_name="([^"]*)"', lowered.compile().as_text())).replace(
+            "jit(_paged_block)/", "")
 
 
 def reference_answer(params, prompt, doc, max_new, eos=-1):
@@ -224,8 +235,8 @@ def test_a_round_in_place_equals_the_round_on_the_gather_path(model):
             jnp.asarray(lens, jnp.int32), active, jnp.zeros((B,), bool),
             jnp.zeros((B,), jnp.uint32), unit.cfg)
     kw = dict(span=span, temperature=0.0, top_k=0, top_p=0.0, eos_token=-1)
-    text = paged_decode_round_jit.lower(
-        *args, **kw, inplace="interpret").as_text(debug_info=True)
+    text = _op_paths(paged_decode_round_jit.lower(
+        *args, **kw, inplace="interpret"))
     assert "kv_gather" not in text and "denoise/attn" in text
     assert "kv_gather" in paged_decode_round_jit.lower(
         *args, **kw, inplace=False).as_text(debug_info=True)
@@ -600,12 +611,11 @@ def test_the_block_names_its_stages_for_the_trace(model):
     readers sort device time by (bench/readers/trace_stages.py)."""
     doc, unit, params = model
     pool = init_block_pool(unit.cfg, 8, 8)
-    text = paged_decode_round_jit.lower(
+    text = _op_paths(paged_decode_round_jit.lower(
         params, pool, jnp.ones((2, 2), jnp.int32), jnp.zeros((2, 4), jnp.int32),
         jnp.asarray([5, 8], jnp.int32), jnp.ones((2,), bool),
         jnp.zeros((2,), bool), jnp.zeros((2,), jnp.uint32), unit.cfg, span=8,
-        temperature=0.0, top_k=0, top_p=0.0, eos_token=-1
-    ).as_text(debug_info=True)
+        temperature=0.0, top_k=0, top_p=0.0, eos_token=-1))
     for scope in ("denoise/qk_norm", "denoise/ffn/router",
                   "denoise/ffn/experts", "commit/ffn/experts",
                   "denoise/unembed", "commit/kv_write", "denoise/attn"):
