@@ -9,10 +9,13 @@ REST/gRPC data plane as every other model.
 
 One KV layout, one decoder block, three programs:
   * the cache is a POOL of fixed-size blocks (``init_block_pool``:
-    ``[num_blocks, block_size, KV, hd]`` per layer, int8 with per-position
-    scales under ``LMConfig.kv_quant``) and a row reaches its blocks
-    through a block table; ``_paged_block`` is the only decoder block that
-    reads or writes it;
+    ``[num_blocks, block_size, KV, hd]`` per attention layer, int8 with
+    per-position scales under ``LMConfig.kv_quant``) and a row reaches its
+    blocks through a block table; a gated short-convolution layer
+    (``LMConfig.layer_kinds``) holds no K/V but a fixed-size state a
+    sequence, ``[num_blocks, conv_kernel - 1, D]``, found by the row's
+    FIRST block; ``_paged_block`` is the only decoder block that reads or
+    writes either;
   * ``paged_forward`` (a prompt, a prompt chunk or a verify pass),
     ``paged_decode_round`` (``span`` single-token steps as ONE ``lax.scan``
     inside jit — no per-token dispatch, no host round trip between steps;
@@ -239,11 +242,17 @@ def mask_after_eos(toks, eos_token: int):
 
 def init_block_pool(cfg: LMConfig, num_blocks: int, block_size: int
                     ) -> Dict[str, Any]:
-    """Per-layer {k, v[, k_s, v_s]} pools shaped
-    ``[num_blocks, block_size, KV, hd]``.  Block 0 is the scratch block —
-    the allocator (runtime/genserver.py) hands out ids >= 1.  int8 pools
-    carry per-position f32 scale planes (``[num_blocks, block_size, KV]``,
-    ~6% over the values at hd=64)."""
+    """One entry a layer, by the layer's mixer (``LMConfig.kind``).  An
+    attention layer's is {k, v[, k_s, v_s]} shaped ``[num_blocks,
+    block_size, KV, hd]``; int8 pools carry per-position f32 scale planes
+    (``[num_blocks, block_size, KV]``, ~6% over the values at hd=64).  A
+    gated short-convolution layer's is {conv}: ``[num_blocks,
+    conv_kernel - 1, D]`` in the pool's dtype, the gated input at a
+    sequence's last positions, kept at the id of the sequence's FIRST
+    block -- unique to a live sequence, freed and copied with the block --
+    and no K/V is allocated for it.  Block 0 is the scratch block — the
+    allocator (runtime/genserver.py) hands out ids >= 1 — and entry 0 of a
+    state the scratch state."""
     hd = cfg.hd
     kv = cfg.kv_heads
     # XLA:CPU has no native bf16 scatter: a bf16 pool pays TWO whole-pool
@@ -256,7 +265,10 @@ def init_block_pool(cfg: LMConfig, num_blocks: int, block_size: int
     if dtype == jnp.bfloat16 and jax.default_backend() == "cpu":
         dtype = jnp.float32
 
-    def layer():
+    def layer(mixer):
+        if mixer == "conv":
+            return {"conv": jnp.zeros(
+                (num_blocks, cfg.conv_kernel - 1, cfg.d_model), dtype)}
         if cfg.kv_quant == "int8":
             return {
                 "k": jnp.zeros((num_blocks, block_size, kv, hd), jnp.int8),
@@ -269,7 +281,13 @@ def init_block_pool(cfg: LMConfig, num_blocks: int, block_size: int
             "v": jnp.zeros((num_blocks, block_size, kv, hd), dtype),
         }
 
-    return {f"l{i}": layer() for i in range(cfg.n_layers)}
+    return {f"l{i}": layer(cfg.kind(i)[0]) for i in range(cfg.n_layers)}
+
+
+def _pool_kv(pool):
+    """The first attention layer's entry of ``pool`` (every attention layer
+    has its shapes and dtype), or None where no layer holds K/V."""
+    return next((layer for layer in pool.values() if "k" in layer), None)
 
 
 def _paged_view(layer, tables):
@@ -394,9 +412,55 @@ def _attend_pool_and_fresh(q, pool_layer, tables, plan, k_new, v_new,
     return a.astype(q.dtype).reshape(q.shape)
 
 
+def _short_conv(lp, x, pool_layer, tables, start, valid, cfg: LMConfig):
+    """The gated short-convolution mixer on x [B, W, D] -> (x', pool
+    layer'): ``[b | c | u] = in_proj(norm(x))``; ``z = b * u``; ``y_t =
+    sum_j w[j] * z_(t - (K-1) + j)``, a depthwise causal convolution of
+    ``K = cfg.conv_kernel`` taps; ``x + out_proj(c * y)``.
+
+    ``z`` before a row's first position of this call is the row's state,
+    ``pool_layer["conv"][tables[b, 0]]`` -- ``z`` at the K-1 positions
+    before ``start[b]`` -- and zero for a row that starts at 0, whatever the
+    entry holds (a reused block needs no reset).  The state left is ``z`` at
+    the last K-1 of the row's valid positions: a call of one valid token
+    leaves ``[.., old z_-1, new z_0]``, pad positions (``valid`` False, to
+    the right of the valid ones) enter neither a valid position's sum nor
+    the state, and a row with no valid position (an empty slot) writes the
+    scratch entry 0."""
+    from seldon_core_tpu.ops.quant import lm_matmul
+
+    B, W, D = x.shape
+    K = cfg.conv_kernel
+    state = pool_layer["conv"]
+    with jax.named_scope("conv_in"):
+        h = _rmsnorm(x, lp["ln1"], cfg.norm_eps)
+        bcu = lm_matmul(lp, "conv_in", h, out_dtype=x.dtype)
+    with jax.named_scope("conv"):
+        b, c, u = jnp.split(bcu, 3, axis=-1)
+        z = b * u
+        slot = tables[:, 0]
+        width = jnp.sum(jnp.broadcast_to(valid, (B, W)), axis=1)
+        old = jnp.where((start > 0)[:, None, None], state[slot], 0)
+        zz = jnp.concatenate([old.astype(z.dtype), z], axis=1)  # [B,K-1+W,D]
+        taps = lp["conv_w"].astype(jnp.float32)
+        y = sum(taps[j] * zz[:, j:j + W].astype(jnp.float32)
+                for j in range(K))
+        y = c * y.astype(x.dtype)
+        # zz index of a row's valid position i is K-1+i: its last K-1 valid
+        # positions (reaching into the old state where it has fewer)
+        keep = width[:, None, None] + jnp.arange(K - 1)[None, :, None]
+        left = jnp.take_along_axis(zz, keep, axis=1)
+        state = state.at[jnp.where(width > 0, slot, 0)].set(
+            left.astype(state.dtype))
+    with jax.named_scope("conv_out"):
+        x = x + lm_matmul(lp, "conv_out", y, out_dtype=x.dtype)
+    return x, {"conv": state}
+
+
 # A ``jit`` of its own inside the programs that call it: a program traces and
 # lowers the block once per distinct (shapes, static arguments) -- a dense
-# program once, a round of denoising passes three times (a pass that writes
+# program once, one whose layers are of several kinds (``kind``) once a kind,
+# a round of denoising passes three times (a pass that writes
 # nothing, the commit, the commit's last layer) -- and every other layer is a
 # cached bind that lowers to a ``call`` of one private function, so trace,
 # lowering and the module's text no longer grow with depth (PERF.md section
@@ -404,14 +468,22 @@ def _attend_pool_and_fresh(q, pool_layer, tables, plan, k_new, v_new,
 # here: the programs donate the pool.  ``_paged_block.__wrapped__`` is the
 # plain body.
 @functools.partial(
-    jax.jit, static_argnames=("cfg", "interpret", "kv_only", "write"))
+    jax.jit,
+    static_argnames=("cfg", "interpret", "kv_only", "write", "kind"))
 def _paged_block(lp, x, pool_layer, tables, start, valid, cfg: LMConfig,
                  plan=None, interpret: bool = False, limit=None,
-                 kv_only: bool = False, view=None, write: bool = True):
+                 kv_only: bool = False, view=None, write: bool = True,
+                 kind=None):
     """One decoder block over the paged pool: K/V written at per-row
     positions start[b] + i (scratch-routed where ``valid`` is False),
     attention over each row's own blocks.  x [B, W, D] -> (x', pool layer',
     the FFN's aux: the experts read by a dropless expert layer, else 0).
+
+    ``kind`` is the layer's ``(mixer, ffn)`` (``LMConfig.kind(i)``; None: an
+    attention layer whose FFN is read off its weights): a "conv" layer
+    takes ``_short_conv`` in the attention's place -- its pool entry is the
+    state, and what follows about K/V does not concern it -- and the FFN is
+    ``transformer._ffn``'s of that kind.
 
     ``plan`` (ops.paged_attention.decode_plan) selects the in-place
     formulation: attention reads the row's blocks from the pool where they
@@ -434,6 +506,21 @@ def _paged_block(lp, x, pool_layer, tables, start, valid, cfg: LMConfig,
     hd = cfg.hd
     kv_h = cfg.kv_heads
     q_out = cfg.n_heads * hd
+    mixer, ffn = kind or ("attn", None)
+
+    def feed_forward(x):
+        with jax.named_scope("ffn"):
+            h = _rmsnorm(x, lp["ln2"], cfg.norm_eps)
+            y, aux = _ffn(lp, h, cfg, mesh=None,
+                          valid=jnp.broadcast_to(valid, (B, W))
+                          if cfg.d_expert else None, kind=ffn)
+            return x + y, aux
+
+    if mixer == "conv":
+        x, pool_layer = _short_conv(lp, x, pool_layer, tables, start, valid,
+                                    cfg)
+        x, aux = feed_forward(x)
+        return x, pool_layer, aux
     # the stages below are jax.named_scope's: op metadata only (same
     # programs, same numerics), read back from a profile window's device
     # ops by bench/lib/trace_scopes.py — keep the names stable
@@ -478,12 +565,7 @@ def _paged_block(lp, x, pool_layer, tables, start, valid, cfg: LMConfig,
         a = a.transpose(0, 2, 1, 3).reshape(B, W, q_out)
     with jax.named_scope("wo"):
         x = x + lm_matmul(lp, "wo", a, out_dtype=x.dtype)
-    with jax.named_scope("ffn"):
-        h = _rmsnorm(x, lp["ln2"], cfg.norm_eps)
-        y, aux = _ffn(lp, h, cfg, mesh=None,
-                      valid=jnp.broadcast_to(valid, (B, W))
-                      if cfg.d_expert else None)
-        x = x + y
+    x, aux = feed_forward(x)
     return x, pool_layer, aux
 
 
@@ -523,7 +605,7 @@ def paged_forward(params, tokens, pool, tables, start, width,
     for i in range(cfg.n_layers):
         x, pool[f"l{i}"], aux = _paged_block(
             params[f"l{i}"], x, pool[f"l{i}"], tables, start, valid, cfg,
-            limit=limit,
+            limit=limit, kind=cfg.kind(i),
         )
         if cfg.d_expert:
             read = read + aux
@@ -552,7 +634,10 @@ def decode_inplace(pool, mesh=None, width: int = 1, heads=None,
     will bring, which the kernel holds whole."""
     from seldon_core_tpu.ops.paged_attention import inplace_supported
 
-    k = pool["l0"]["k"]
+    kv = _pool_kv(pool)
+    if kv is None:
+        return False        # no layer attends: nothing to read in place
+    k = kv["k"]
     return inplace_supported(
         width=width, backend=jax.default_backend(), pool_dtype=k.dtype,
         mesh=mesh, block_size=k.shape[1], kv_heads=k.shape[2],
@@ -596,7 +681,8 @@ def paged_decode_round(params, pool, tables, token, n_valid, active,
     if inplace is None:
         inplace = decode_inplace(pool, heads=cfg.n_heads,
                                  rows=n_valid.shape[0])
-    capacity = tables.shape[1] * pool["l0"]["k"].shape[1]
+    kv = _pool_kv(pool)     # (a plan is made only where a layer attends)
+    capacity = tables.shape[1] * kv["k"].shape[1] if kv else 0
 
     def step(carry, _):
         pool, token, n_valid, seen_eos, keys, *read = carry
@@ -608,7 +694,7 @@ def paged_decode_round(params, pool, tables, token, n_valid, active,
             x, pool[f"l{i}"], aux = _paged_block(
                 params[f"l{i}"], x, pool[f"l{i}"], tables, n_valid,
                 active[:, None], cfg, plan=plan,
-                interpret=inplace == "interpret",
+                interpret=inplace == "interpret", kind=cfg.kind(i),
             )
             read = [r + aux for r in read]
         with jax.named_scope("unembed"):
@@ -696,7 +782,7 @@ def _denoising_round(params, pool, tables, token, n_valid, active, seen_eos,
     B = n_valid.shape[0]
     if inplace is None:
         inplace = decode_inplace(pool, width=L, heads=cfg.n_heads, rows=B)
-    capacity = tables.shape[1] * pool["l0"]["k"].shape[1]
+    capacity = tables.shape[1] * _pool_kv(pool)["k"].shape[1]
     if token.ndim == 1:
         token = jnp.broadcast_to(token[:, None], (B, L))
     base = n_valid - n_valid % L
@@ -716,7 +802,7 @@ def _denoising_round(params, pool, tables, token, n_valid, active, seen_eos,
                 params[f"l{i}"], h, pool[f"l{i}"], tables, start, valid,
                 cfg, plan=plan, interpret=inplace == "interpret",
                 view=views and views[i], write=commit,
-                kv_only=commit and i == cfg.n_layers - 1)
+                kv_only=commit and i == cfg.n_layers - 1, kind=cfg.kind(i))
             if commit:
                 pool[f"l{i}"] = layer
             if cfg.d_expert:
@@ -806,6 +892,11 @@ def paged_spec_round(t_params, d_params, t_pool, d_pool, t_tables,
     (new_toks [B, k+1], gained [B], corrected [B], t_pool', d_pool'):
     row b's round output is new_toks[b, :gained[b]], its next pending
     token is corrected[b]."""
+    if "c" in t_cfg.layer_kinds + d_cfg.layer_kinds:
+        raise ValueError(
+            "speculative decoding cannot serve a gated short-convolution "
+            "layer: a rejected draft would have to roll the layer's state "
+            "back, and the state keeps no history to roll back to")
     B = token.shape[0]
     W = k + 1
 
@@ -815,7 +906,7 @@ def paged_spec_round(t_params, d_params, t_pool, d_pool, t_tables,
         for i in range(d_cfg.n_layers):
             x, d_pool[f"l{i}"], _ = _paged_block(
                 d_params[f"l{i}"], x, d_pool[f"l{i}"], d_tables, nv,
-                active[:, None], d_cfg,
+                active[:, None], d_cfg, kind=d_cfg.kind(i),
             )
         x = _rmsnorm(x, d_params["ln_f"])
         logits = (x[:, 0, :] @ d_params["embed"].T).astype(jnp.float32)
@@ -847,7 +938,8 @@ def paged_spec_round(t_params, d_params, t_pool, d_pool, t_tables,
 
 
 def paged_copy_block(pool, src, dst):
-    """Copy block ``src`` onto block ``dst`` in every layer, pool to pool.
+    """Copy block ``src`` onto block ``dst`` in every layer, pool to pool
+    (a short-convolution layer's state at that id with it).
     A shared prefix's full blocks are written once and SHARED by block-table
     reference across every sequence (pinned in the allocator); the partly
     filled boundary block must be private, because the sequence's own
@@ -947,7 +1039,8 @@ def _decode(params, carry, tables, cfg: LMConfig, n: int, knobs):
     toks, *carry = paged_decode_round_jit(
         params, pool, tables, token, n_valid,
         jnp.ones(token.shape, bool), seen_eos, keys, cfg, span=n, **knobs)
-    return toks, tuple(carry)
+    # (a configuration with experts returns their count too: not carried)
+    return toks, tuple(carry[:5])
 
 
 def _denoising_lane(params, prompt, cfg: LMConfig, max_new_tokens: int,
@@ -1153,7 +1246,9 @@ class TransformerGenerator(Unit):
                  norm_eps: float = 1e-6, tie_embeddings: bool = True,
                  d_expert: int = 0, moe_norm_topk: bool = True,
                  block_length: int = 1, denoising_steps: int = 1,
-                 mask_id: int = -1):
+                 mask_id: int = -1, layer_kinds: str = "",
+                 conv_kernel: int = 3, dense_layers: int = 0,
+                 router: str = "softmax"):
         # mesh (from the binding's mesh_axes, e.g. {"tp": 4}): params are
         # laid out with the LM's tp shardings and GSPMD partitions the
         # whole prefill+decode program across the mesh — one generator
@@ -1173,7 +1268,17 @@ class TransformerGenerator(Unit):
             d_expert=int(d_expert), moe_norm_topk=bool(moe_norm_topk),
             block_length=int(block_length),
             denoising_steps=int(denoising_steps), mask_id=int(mask_id),
+            # one letter a layer ("ccacccac..": a deployment document
+            # carries scalars, so the pattern comes as ONE string)
+            layer_kinds=str(layer_kinds), conv_kernel=int(conv_kernel),
+            dense_layers=int(dense_layers), router=str(router),
         )
+        if "c" in self.cfg.layer_kinds and str(prefix_tokens).strip():
+            raise ValueError(
+                "a generator with gated short-convolution layers takes no "
+                "shared prefix (prefix_tokens): the prefix's pinned blocks "
+                "are shared by table reference, and a layer's state after "
+                "the prefix is one sequence's, found by its own first block")
         if self.cfg.block_length > 1 and (
                 float(temperature) > 0.0 or str(prefix_tokens).strip()):
             raise ValueError(

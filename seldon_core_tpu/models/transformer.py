@@ -117,6 +117,22 @@ class LMConfig:
     block_length: int = 1
     denoising_steps: int = 1
     mask_id: int = -1
+    # THE LAYER KINDS, one description (``kind``): what mixes a layer's
+    # positions and what its FFN is.  ``layer_kinds`` is one letter a layer,
+    # "a" causal attention, "c" a gated short convolution (``[B | C | X] =
+    # in_proj(u)``; a depthwise causal convolution of ``conv_kernel`` taps
+    # over ``B * X``, gated by ``C``; ``out_proj``: a layer that keeps the
+    # last ``conv_kernel - 1`` positions of ``B * X`` a sequence in the
+    # K/V's place, models/generate.py); "" = attention in every layer.
+    # ``dense_layers``: the first so many layers' FFN is a dense gated-SiLU
+    # FFN of width ``d_ff`` (``w2(silu(w1 u) * w3 u)``) where the others
+    # hold the experts of ``d_expert``.  ``router``: how an expert layer
+    # scores, "softmax" or "sigmoid_bias" (parallel/moe.py).  Serving only,
+    # as ``d_expert`` is.
+    layer_kinds: str = ""
+    conv_kernel: int = 3
+    dense_layers: int = 0
+    router: str = "softmax"
 
     @property
     def hd(self) -> int:
@@ -125,6 +141,26 @@ class LMConfig:
 
     def is_moe_layer(self, i: int) -> bool:
         return self.moe_every > 0 and (i + 1) % self.moe_every == 0
+
+    def kind(self, i: int) -> Tuple[str, str]:
+        """Layer ``i`` as ``(mixer, ffn)``: "attn" | "conv", and "gelu" (the
+        two-matrix FFN), "moe" (capacity-routed, ``moe_every``), "gated"
+        (a leading dense gated-SiLU layer) or "experts" (dropless).  The
+        one place that reads the fields above; a program traces the
+        decoder block once a distinct kind."""
+        mixer = "conv" if self.layer_kinds[i:i + 1] == "c" else "attn"
+        if self.d_expert:
+            return mixer, "gated" if i < self.dense_layers else "experts"
+        return mixer, "moe" if self.is_moe_layer(i) else "gelu"
+
+    @property
+    def kinds(self) -> Tuple[Tuple[str, str], ...]:
+        return tuple(self.kind(i) for i in range(self.n_layers))
+
+    @property
+    def expert_layers(self) -> int:
+        """Layers whose FFN is dropless routed experts."""
+        return sum(ffn == "experts" for _, ffn in self.kinds)
 
     def __post_init__(self):
         if self.d_model % self.n_heads != 0:
@@ -158,6 +194,30 @@ class LMConfig:
                 "d_expert (dropless experts in every layer) needs "
                 "moe_every=0 and 0 < moe_k <= n_experts"
             )
+        if self.layer_kinds and (
+                len(self.layer_kinds) != self.n_layers
+                or set(self.layer_kinds) - set("ac")
+                or self.conv_kernel < 2):
+            raise ValueError(
+                f"layer_kinds={self.layer_kinds!r} is one letter a layer, "
+                f"'a' or 'c', for n_layers={self.n_layers}, and a "
+                f"convolution has at least 2 taps (got {self.conv_kernel})")
+        if not 0 <= self.dense_layers <= (self.n_layers if self.d_expert
+                                          else 0):
+            raise ValueError(
+                f"dense_layers={self.dense_layers} are the leading layers of "
+                "a configuration with experts (d_expert) and at most "
+                "n_layers")
+        if self.router not in ("softmax", "sigmoid_bias"):
+            raise ValueError(
+                f"router={self.router!r} not supported "
+                "(softmax | sigmoid_bias)")
+        if "c" in self.layer_kinds and (self.block_length > 1
+                                        or self.moe_every):
+            raise ValueError(
+                "a gated short-convolution layer rides neither a round of "
+                "denoising passes (block_length > 1: its state would have "
+                "to be rolled back a pass) nor moe_every")
         if self.block_length > 1 and (
                 self.block_length % self.denoising_steps
                 or not 0 <= self.mask_id < self.vocab):
@@ -235,22 +295,33 @@ def lm_init(rng, cfg: LMConfig) -> Dict[str, Any]:
     hd = cfg.hd
     q_out = cfg.n_heads * hd
     qkv_out = q_out + 2 * cfg.kv_heads * hd  # q | k | v segments
+    D = cfg.d_model
     for i in range(cfg.n_layers):
         k = keys[1 + 4 * i : 1 + 4 * (i + 1)]
-        lp = {
-            "ln1": jnp.ones((cfg.d_model,), dt),
-            "wqkv": dense(k[0], (cfg.d_model, qkv_out), cfg.d_model),
-            "wo": dense(k[1], (q_out, cfg.d_model), q_out),
-            "ln2": jnp.ones((cfg.d_model,), dt),
-        }
-        if cfg.qk_norm:
-            lp["q_norm"] = jnp.ones((hd,), dt)
-            lp["k_norm"] = jnp.ones((hd,), dt)
-        if cfg.d_expert:
+        mixer, ffn = cfg.kind(i)
+        lp = {"ln1": jnp.ones((D,), dt), "ln2": jnp.ones((D,), dt)}
+        if mixer == "conv":
+            # in_proj -> B | C | X side by side; the taps [K, D], the last
+            # one on the position itself; out_proj
+            lp["conv_in"] = dense(k[0], (D, 3 * D), D)
+            lp["conv_w"] = dense(jax.random.fold_in(k[0], 1),
+                                 (cfg.conv_kernel, D), cfg.conv_kernel)
+            lp["conv_out"] = dense(k[1], (D, D), D)
+        else:
+            lp["wqkv"] = dense(k[0], (D, qkv_out), D)
+            lp["wo"] = dense(k[1], (q_out, D), q_out)
+            if cfg.qk_norm:
+                lp["q_norm"] = jnp.ones((hd,), dt)
+                lp["k_norm"] = jnp.ones((hd,), dt)
+        if ffn == "experts":
             from seldon_core_tpu.parallel.moe import dropless_init
 
             lp.update(dropless_init(k[2], cfg))
-        elif cfg.is_moe_layer(i):
+        elif ffn == "gated":
+            lp["w1"] = dense(k[2], (D, cfg.d_ff), D)
+            lp["w3"] = dense(jax.random.fold_in(k[2], 1), (D, cfg.d_ff), D)
+            lp["w2"] = dense(k[3], (cfg.d_ff, D), cfg.d_ff)
+        elif ffn == "moe":
             from seldon_core_tpu.parallel.moe import MoEConfig, moe_init
 
             lp["moe"] = moe_init(
@@ -292,7 +363,7 @@ def param_shardings(mesh: Mesh, params) -> Any:
                 # output axis replicated (the psum happens over tp)
                 return P("tp", None) if (has_tp and kind == "q") else P()
             return P()
-        if name in ("wqkv", "w1"):
+        if name in ("wqkv", "w1", "w3"):
             return P(None, "tp") if has_tp else P()
         if name in ("wo", "w2"):
             return P("tp", None) if has_tp else P()
@@ -430,19 +501,32 @@ def _block(lp, x, cfg: LMConfig, mesh: Optional[Mesh], causal: bool,
     return x + y, lb
 
 
-def _ffn(lp, h, cfg: LMConfig, mesh: Optional[Mesh], valid=None):
-    """Feed-forward on h [B,S,D] -> (y, aux): the dense two-matrix
-    tanh-GELU FFN (aux 0), the capacity-routed experts of ``moe_every``
-    (aux the load-balance loss) or, for ``cfg.d_expert``, the dropless
-    routed gated-SiLU experts (aux the number of experts read; ``valid``
-    [B,S] keeps pad positions from picking any)."""
-    if cfg.d_expert:
+def _ffn(lp, h, cfg: LMConfig, mesh: Optional[Mesh], valid=None,
+         kind: Optional[str] = None):
+    """Feed-forward on h [B,S,D] -> (y, aux), by the layer's ``kind``
+    (``LMConfig.kind``'s second; None reads it off the layer's weights):
+    "gelu" the dense two-matrix tanh-GELU FFN (aux 0), "moe" the
+    capacity-routed experts of ``moe_every`` (aux the load-balance loss),
+    "experts" the dropless routed gated-SiLU experts of ``cfg.d_expert``
+    (aux the number of experts read; ``valid`` [B,S] keeps pad positions
+    from picking any), "gated" a leading dense layer of such a
+    configuration, ``w2(silu(w1 h) * w3 h)`` (aux 0)."""
+    from seldon_core_tpu.ops.quant import lm_matmul
+
+    if kind is None:
+        kind = ("experts" if "router" in lp else "gated" if "w3" in lp
+                else "moe" if "moe" in lp else "gelu")
+    if kind == "experts":
         from seldon_core_tpu.parallel.moe import moe_dropless
 
         if valid is None:
             valid = jnp.ones(h.shape[:2], bool)
         return moe_dropless(lp, h, valid, cfg)
-    if "moe" in lp:
+    if kind == "gated":
+        u = (jax.nn.silu(lm_matmul(lp, "w1", h, out_dtype=h.dtype))
+             * lm_matmul(lp, "w3", h, out_dtype=h.dtype))
+        return lm_matmul(lp, "w2", u, out_dtype=h.dtype), jnp.int32(0)
+    if kind == "moe":
         from seldon_core_tpu.parallel.moe import MoEConfig, moe_apply
 
         mcfg = MoEConfig(d_model=cfg.d_model, d_ff=cfg.d_ff,
@@ -450,8 +534,6 @@ def _ffn(lp, h, cfg: LMConfig, mesh: Optional[Mesh], valid=None):
                          dtype=cfg.dtype)
         y, aux = moe_apply(lp["moe"], h, mcfg, mesh=mesh)
         return y, aux["lb_loss"]
-    from seldon_core_tpu.ops.quant import lm_matmul
-
     u = jax.nn.gelu(lm_matmul(lp, "w1", h, out_dtype=h.dtype))
     return lm_matmul(lp, "w2", u, out_dtype=h.dtype), jnp.float32(0.0)
 
@@ -464,12 +546,12 @@ def lm_apply(
     the Pallas flash kernel on single-chip meshes (differentiable).
     ``return_lb`` additionally returns the summed MoE load-balance loss."""
     if (cfg.d_expert or cfg.qk_norm or cfg.head_dim or cfg.block_length > 1
-            or not cfg.tie_embeddings):
+            or not cfg.tie_embeddings or "c" in cfg.layer_kinds):
         raise ValueError(
             "the cache-free forward implements the repo's own block only; "
             "a configuration with head_dim, qk_norm, an untied head, "
-            "dropless experts or block diffusion is served by the paged "
-            "programs (models/generate.py)")
+            "dropless experts, short-convolution layers or block diffusion "
+            "is served by the paged programs (models/generate.py)")
     x = params["embed"][tokens]  # [B,S,D]
     lb_total = jnp.float32(0.0)
     for i in range(cfg.n_layers):

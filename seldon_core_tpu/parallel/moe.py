@@ -195,7 +195,10 @@ def dropless_init(rng, cfg) -> Dict[str, Any]:
     expert's gate and up matrices side by side (one grouped matmul reads
     both); ``e_down`` [E, F, D].  All in the model's dtype, drawn in it: an
     expert stack is gigabytes at real widths and a float32 draw would be
-    twice that beside it."""
+    twice that beside it.  Under ``cfg.router`` "sigmoid_bias" also
+    ``expert_bias`` [E] float32, drawn from the seed and NOT zero (a real
+    one is what load balancing left behind): a tenth of a sigmoid's range,
+    which moves most tokens' chosen set."""
     kr, kg, kd = jax.random.split(rng, 3)
     E, D, F, dt = cfg.n_experts, cfg.d_model, cfg.d_expert, cfg.dtype
 
@@ -203,9 +206,13 @@ def dropless_init(rng, cfg) -> Dict[str, Any]:
         return (jax.random.normal(key, shape, dt)
                 * jnp.asarray(fan_in ** -0.5, dt)).astype(dt)
 
-    return {"router": dense(kr, (D, E), D),
-            "e_gate_up": dense(kg, (E, D, 2 * F), D),
-            "e_down": dense(kd, (E, F, D), F)}
+    out = {"router": dense(kr, (D, E), D),
+           "e_gate_up": dense(kg, (E, D, 2 * F), D),
+           "e_down": dense(kd, (E, F, D), F)}
+    if cfg.router == "sigmoid_bias":
+        out["expert_bias"] = 0.1 * jax.random.normal(
+            jax.random.fold_in(kr, 1), (E,), jnp.float32)
+    return out
 
 
 # The grouped matmul's tiles on the TPU (megablox ``gmm``): a group's rows
@@ -279,6 +286,10 @@ def moe_dropless(lp, h, valid, cfg, impl=None):
     ``g = softmax(h Wr)`` over all experts in float32; the ``moe_k``
     largest; ``w_e = g_e / sum of the chosen`` (``moe_norm_topk``);
     ``y = sum_e w_e (silu(h W_gate,e) * (h W_up,e)) W_down,e``.
+    Under ``cfg.router`` "sigmoid_bias": ``g = sigmoid(h Wr)``; chosen are
+    the ``moe_k`` largest of ``g + expert_bias``; the weights are the
+    UNBIASED ``g`` at the chosen, ``/ (their sum + 1e-6)`` -- the bias
+    steers who is chosen and never what a choice weighs.
 
     The T*k picks are sorted by expert and go through the experts as two
     grouped matmuls (``_grouped_matmul``; ``impl`` is its), which visit a
@@ -295,10 +306,18 @@ def moe_dropless(lp, h, valid, cfg, impl=None):
         logits = jax.lax.dot_general(
             x, lp["router"], (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
-        gates = jax.nn.softmax(logits, axis=-1)
-        top_w, top_e = jax.lax.top_k(gates, k)                # [T, k]
-        if cfg.moe_norm_topk:
-            top_w = top_w / jnp.sum(top_w, axis=-1, keepdims=True)
+        if cfg.router == "sigmoid_bias":
+            gates = jax.nn.sigmoid(logits)
+            _, top_e = jax.lax.top_k(gates + lp["expert_bias"], k)
+            top_w = jnp.take_along_axis(gates, top_e, axis=-1)
+            if cfg.moe_norm_topk:
+                top_w = top_w / (jnp.sum(top_w, axis=-1, keepdims=True)
+                                 + 1e-6)
+        else:
+            gates = jax.nn.softmax(logits, axis=-1)
+            top_w, top_e = jax.lax.top_k(gates, k)            # [T, k]
+            if cfg.moe_norm_topk:
+                top_w = top_w / jnp.sum(top_w, axis=-1, keepdims=True)
         pick = jnp.where(live[:, None], top_e, E).reshape(-1)  # [T*k]
         order = jnp.argsort(pick, stable=True)
         sizes = jnp.zeros((E + 1,), jnp.int32).at[pick].add(1)[:E]

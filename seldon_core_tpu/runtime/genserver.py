@@ -658,6 +658,33 @@ class GenServer:
                     raise ValueError(
                         f"{name}={n} is no whole number of diffusion blocks "
                         f"of {self._block}")
+        # a generator with gated short-convolution layers (cfg.layer_kinds)
+        # keeps, beside its K/V blocks, a fixed-size state a sequence and a
+        # layer, in the pool at the id of the sequence's FIRST block
+        # (models/generate.py init_block_pool): zero at position 0, carried
+        # over chunks and rounds, freed with the block, recomputed from the
+        # prompt after a preemption.  Nothing snapshots or rolls it back,
+        # so the lanes that would have to are refused by name
+        self._stateful = "c" in getattr(cfg, "layer_kinds", "")
+        # layers whose FFN is dropless routed experts (not the leading
+        # dense ones): what a pass's expert slots are counted over
+        self._routed = getattr(cfg, "expert_layers", 0)
+        if self._stateful:
+            for refused, why in (
+                    (self.spec,
+                     "speculative decoding: a rejected draft would have to "
+                     "roll the layers' state back"),
+                    (prefix_ids is not None,
+                     "a shared prefix: its pinned blocks are shared by "
+                     "table reference, the state after it is one "
+                     "sequence's"),
+                    (role in ("prefill", "decode"),
+                     "the prefill / decode roles: a handoff streams K/V "
+                     "blocks, not the layers' state")):
+                if refused:
+                    raise ValueError(
+                        "a generator with gated short-convolution layers "
+                        f"is served unified and cannot take {why}")
         # bounded admission queue: sustained overload must fail typed
         # (retryable 503 via LoadShedError) with flat memory, never grow
         # the waiting deques without limit.  Generous by default — the
@@ -789,9 +816,11 @@ class GenServer:
         self._tick_row_passes = 0            # ... times the real rows in each
         self._tick_expert_slots = 0          # experts held x layers x passes
         self._tick_experts_read = 0          # experts the rounds read back
-        self._tick_prefill = [0, 0, 0, 0]    # prefill: calls, experts read,
-        #                                      experts held x layers x calls,
-        #                                      prompt tokens
+        self._tick_prefill = [0] * 6         # prefill: calls, experts read,
+        #                                      experts held x layers x calls
+        #                                      that count them, prompt tokens,
+        #                                      rows, rows that began from a
+        #                                      carried state
         self._tick_tokens = 0                # tokens emitted
         self._tick_retired = 0               # sequences retired
         self._phases: Dict[str, float] = {}  # phase -> host wall s
@@ -1444,26 +1473,34 @@ class GenServer:
             hd = cfg.hd
             q_out = cfg.n_heads * hd
             qkv_out = q_out + 2 * kvh * hd
-            per_layer = d * qkv_out + q_out * d + 2 * d * ff
-            if getattr(cfg, "d_expert", 0):
-                # a token's own work: the router and its moe_k experts
-                per_layer += d * cfg.n_experts - 2 * d * ff + (
-                    cfg.moe_k * 3 * d * cfg.d_expert)
+            # a token's own work in a layer, by the layer's kind
+            # (LMConfig.kind): the mixer's matrices and the FFN's -- of an
+            # expert layer the router and the token's moe_k experts
+            mixers = {"attn": d * qkv_out + q_out * d, "conv": 4 * d * d}
+            ffns = {"gelu": 2 * d * ff, "moe": 2 * d * ff,
+                    "gated": 3 * d * ff,
+                    "experts": d * cfg.n_experts + (
+                        cfg.moe_k * 3 * d * getattr(cfg, "d_expert", 0))}
+            kinds = getattr(cfg, "kinds", (("attn", "gelu"),) * L)
+            layers = sum(mixers[m] + ffns[f] for m, f in kinds)
+            attending = sum(m == "attn" for m, _ in kinds)
             wb = 1 if getattr(cfg, "quant", "none") == "int8" else 2
             kv_int8 = getattr(cfg, "kv_quant", "none") == "int8"
             kvb = 1 if kv_int8 else 2
             OBSERVATORY.record_compile("gen_decode_step", {
                 # matmul FLOPs per generated token (attention's
                 # position-dependent term excluded)
-                "flops": float(2 * (L * per_layer + d * v)),
+                "flops": float(2 * (layers + d * v)),
                 # HBM bytes ONE device step streams regardless of batch:
                 # every matmul'd weight once, the bf16 unembed once
-                "bytes_accessed": float(wb * L * per_layer + 2 * d * v),
+                "bytes_accessed": float(wb * layers + 2 * d * v),
                 "output_bytes": 0.0,
                 # HBM bytes per CACHE POSITION a step's attention reads
-                # (k + v across layers, + f32 scales when int8 KV)
+                # (k + v across the layers that attend, + f32 scales when
+                # int8 KV)
                 "kv_bytes_per_position": float(
-                    L * (2 * kvh * hd * kvb + (8 * kvh if kv_int8 else 0))
+                    attending * (2 * kvh * hd * kvb
+                                 + (8 * kvh if kv_int8 else 0))
                 ),
             }, None)
         except Exception:  # noqa: BLE001 - accounting must not block serving
@@ -1606,7 +1643,7 @@ class GenServer:
         self._tick_inplace_steps = self._tick_ahead_steps = 0
         self._tick_passes = self._tick_row_passes = 0
         self._tick_expert_slots = self._tick_experts_read = 0
-        self._tick_prefill = [0, 0, 0, 0]
+        self._tick_prefill = [0] * 6
         self._tick_attr = {} if costledger_enabled() else None
         self._tick_kv_attr = []
         self._tick_tokens = self._tick_retired = 0
@@ -1700,6 +1737,8 @@ class GenServer:
             "prefill_experts_read": self._tick_prefill[1],
             "prefill_expert_slots": self._tick_prefill[2],
             "prefill_tokens": self._tick_prefill[3],
+            "prefill_rows": self._tick_prefill[4],
+            "prefill_carried_rows": self._tick_prefill[5],
             "kv_positions": self._tick_kv_pos,
             "kv_blocks": self._tick_kv_blocks,
             "kv_ages": tuple(ages),
@@ -2245,11 +2284,20 @@ class GenServer:
             # share (/genperf ``served_prefill``) and the dispatching
             # span's arguments are these same numbers
             tokens = sum(widths)
-            expert_slots = (self.cfg.n_layers * self.cfg.n_experts
-                            if getattr(self.cfg, "d_expert", 0) else 0)
+            # the experts a call could read, where the call counts the ones
+            # it did: a prefill that chooses no token returns that count in
+            # the logits' place, one that chooses returns its logits and no
+            # count, and slots nobody counts against would read as 0 read
+            expert_slots = (self._routed * self.cfg.n_experts
+                            if self._block > 1 else 0)
+            # rows whose layers' state is carried in from an earlier chunk
+            carried = (sum(int(start[i]) > 0 for i in range(len(batch)))
+                       if self._stateful else 0)
             self._tick_prefill[0] += 1
             self._tick_prefill[2] += expert_slots
             self._tick_prefill[3] += tokens
+            self._tick_prefill[4] += len(batch)
+            self._tick_prefill[5] += carried
             self._dispatched += 1
             work = dict(
                 seq=self._dispatched, rows=B, real_rows=len(batch),
@@ -2259,7 +2307,7 @@ class GenServer:
                 # own index + 1
                 attended=sum(w * int(start[i]) + w * (w + 1) // 2
                              for i, w in enumerate(widths)),
-                expert_slots=expert_slots)
+                expert_slots=expert_slots, carried_rows=carried)
         # fenced (depth 0): dispatch -> ready with nothing queued ahead, the
         # annotation a trace reduction sets the module event against (how
         # much of the fence is not device time).  Otherwise the dispatch is
@@ -2480,14 +2528,15 @@ class GenServer:
                     for s in batch for b in range(blocks))
                 # the pass that writes a block's K/V stops at its last
                 # layer's K/V: one expert layer fewer
-                layer_passes = passes * cfg.n_layers - blocks
+                skipped = blocks
             else:
                 passes = self.span
                 kv_positions = sum(
                     self.span * (s.n_valid + self.span // 2) for s in batch)
-                layer_passes = passes * cfg.n_layers
-            expert_slots = (layer_passes * cfg.n_experts
-                            if getattr(cfg, "d_expert", 0) else 0)
+                skipped = 0
+            # experts held x passes of the layers that hold experts
+            expert_slots = ((passes * self._routed - skipped)
+                            * cfg.n_experts if self._routed else 0)
             self._tick_kv_pos += kv_positions
             self._tick_dev_steps += self.span
             self._tick_passes += passes

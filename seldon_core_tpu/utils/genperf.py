@@ -30,6 +30,13 @@ page"):
     (``OBSERVATORY.cost_features("gen_decode_step")``, registered by the
     scheduler at device init) priced against REAL (unpadded) tokens over
     the fenced decode device time, normalized by ``OBSERVATORY.peaks()``;
+  * ``served_decode.kv_positions`` -- positions ATTENDED OVER: in a
+    generator whose layers are not all attention (``LMConfig.layer_kinds``)
+    only the attention layers hold K/V at them, and a reader prices the
+    bytes a position by those layers alone; the short-convolution layers'
+    fixed-size state is per row and per step, not per position.
+    ``served_prefill.carried_rows`` of ``.rows`` are the prefill rows that
+    began from such a state left by an earlier chunk;
   * an idle-poll duty cycle (idle tick wall / scheduler wall) so a
     hot-spinning scheduler reads as a bubble, not as silence;
   * a KV-block age histogram (block residency at release) for pool
@@ -139,6 +146,8 @@ class GenPerf:
         self.prefill_experts_read = 0
         self.prefill_expert_slots = 0
         self.prefill_tokens = 0        # prompt tokens the calls were given
+        self.prefill_rows = 0          # real rows the calls were given
+        self.prefill_carried_rows = 0  # ... that began from a carried state
         self.decode_kv_positions = 0  # cache positions streamed per step
         self.kv_block_age = Reservoir(1024)   # seconds held at release
         self.kv_blocks_released = 0
@@ -225,6 +234,9 @@ class GenPerf:
             self.prefill_expert_slots += int(
                 detail.get("prefill_expert_slots", 0) or 0)
             self.prefill_tokens += int(detail.get("prefill_tokens", 0) or 0)
+            self.prefill_rows += int(detail.get("prefill_rows", 0) or 0)
+            self.prefill_carried_rows += int(
+                detail.get("prefill_carried_rows", 0) or 0)
             for n_blocks, age_s in kv_ages:
                 self.kv_blocks_released += int(n_blocks)
                 self.kv_block_age.observe(float(age_s))
@@ -301,7 +313,10 @@ class GenPerf:
             # wait for the host between that round and the one before
             "ahead_steps": ahead_steps,
             # live cache positions the single-token steps attended over,
-            # summed: the program's own count for a roofline reader
+            # summed: the program's own count for a roofline reader.  Only
+            # the attention layers hold K/V at them: a gated short-
+            # convolution layer reads a fixed-size state a row instead,
+            # whatever the row's length (module docstring)
             "kv_positions": kv_pos,
             **passes,
             "served_decode_mfu_pct": None,
@@ -428,6 +443,11 @@ class GenPerf:
                 "experts_read": self.prefill_experts_read,
                 "expert_slots": self.prefill_expert_slots,
                 "tokens": self.prefill_tokens,
+                # real rows of the calls, and those of them whose chunk
+                # began from a state an earlier chunk left (start > 0 in a
+                # generator with short-convolution layers; 0 without)
+                "rows": self.prefill_rows,
+                "carried_rows": self.prefill_carried_rows,
             }
         doc["served_decode"] = self.served_decode()
         return doc

@@ -47,7 +47,8 @@ class SpanLog:
     DECODE_WORK = {"seq", "rows", "real_rows", "nblk", "kv_positions",
                    "inplace", "passes", "blocks", "expert_slots"}
     PREFILL_WORK = {"seq", "rows", "real_rows", "nblk", "tokens",
-                    "kv_positions", "attended", "expert_slots"}
+                    "kv_positions", "attended", "expert_slots",
+                    "carried_rows"}
     FUNCTIONS = {"decode": "GenServer._decode_round",
                  "prefill": "GenServer._prefill_tick"}
 

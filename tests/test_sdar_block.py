@@ -582,7 +582,8 @@ def test_a_dense_generator_reports_no_experts_and_one_pass_a_step(
     assert served["experts_read"] == served["expert_slots"] == 0
     SPINE.drain()
     assert GENPERF.document()["served_prefill"] == {
-        "calls": 1, "experts_read": 0, "expert_slots": 0, "tokens": 5}
+        "calls": 1, "experts_read": 0, "expert_slots": 0, "tokens": 5,
+        "rows": 1, "carried_rows": 0}
 
 
 def test_observe_tick_folds_the_new_counters():
@@ -593,13 +594,15 @@ def test_observe_tick_folds_the_new_counters():
             "passes": 10, "row_passes": 30, "experts_read": 700,
             "expert_slots": 8704, "prefill_calls": 2,
             "prefill_experts_read": 1500, "prefill_expert_slots": 1792,
-            "prefill_tokens": 300})
+            "prefill_tokens": 300, "prefill_rows": 7,
+            "prefill_carried_rows": 3})
     served = GENPERF.document()["served_decode"]
     prefill = GENPERF.document()["served_prefill"]
     GENPERF.reset()
     # a chunk is read back in whatever tick comes next: every kind folds it
     assert prefill == {"calls": 6, "experts_read": 4500,
-                       "expert_slots": 5376, "tokens": 900}
+                       "expert_slots": 5376, "tokens": 900, "rows": 21,
+                       "carried_rows": 9}
     # a prefill tick's are not a decode round's
     assert (served["passes"], served["row_passes"], served["experts_read"],
             served["expert_slots"]) == (20, 60, 1400, 17408)
