@@ -206,7 +206,9 @@ def mask_after_eos(toks, eos_token: int):
 #
 # Continuous batching co-schedules sequences of different ages in one decode
 # batch, so the cache is a POOL of fixed-size blocks ([num_blocks,
-# block_size, KV, hd] per layer) and each sequence carries a BLOCK TABLE
+# block_size, KV, hd] per layer; [num_blocks, block_size, KV * hd / 128,
+# 128] where heads narrower than 128 lanes fill rows together --
+# init_block_pool) and each sequence carries a BLOCK TABLE
 # mapping its logical block i to a physical pool block.  The scheduler's
 # allocation/free/eviction and occupancy accounting are host-side
 # (runtime/genserver.py BlockAllocator); the static lane's private pool
@@ -240,21 +242,44 @@ def mask_after_eos(toks, eos_token: int):
 # positions write there, so inactive slots never need a branch.
 
 
-def init_block_pool(cfg: LMConfig, num_blocks: int, block_size: int
-                    ) -> Dict[str, Any]:
+def init_block_pool(cfg: LMConfig, num_blocks: int, block_size: int,
+                    mesh=None) -> Dict[str, Any]:
     """One entry a layer, by the layer's mixer (``LMConfig.kind``).  An
     attention layer's is {k, v[, k_s, v_s]} shaped ``[num_blocks,
     block_size, KV, hd]``; int8 pools carry per-position f32 scale planes
-    (``[num_blocks, block_size, KV]``, ~6% over the values at hd=64).  A
-    gated short-convolution layer's is {conv}: ``[num_blocks,
+    (``[num_blocks, block_size, KV]``, ~6% over the values at hd=64).
+
+    A float pool whose head is narrower than the 128 lanes of a row is
+    ``[num_blocks, block_size, KV * hd / 128, 128]`` where the KV heads
+    fill whole rows (ops.paged_attention.heads_per_row: hd 64 with an even
+    KV count -- row j holds head 2j in lanes 0-63 and head 2j+1 in 64-127):
+    the same KV x hd values a token, in the same order, under the shape
+    the in-place decode kernel reads.  A pool its caller will shard over a
+    ``mesh`` keeps a head a row: the kernel does not serve it
+    (``inplace_supported``) and its KV heads are what is sharded
+    (runtime/servingmesh.py shard_gen_pool).  It is decided HERE, from the
+    model's shapes and on every backend, and never by a reshape of the pool
+    inside a program: a ``[N, bs, 8, 64]`` bfloat16 array lies on a TPU
+    with its positions minor-most, not its window, and a program re-lays
+    it, padded to 128 lanes, around every scatter (PERF.md section 6, PR
+    40: what the gather path of such a model paid), so a reshape there is
+    a copy of the whole pool around every layer.  The small operands meet
+    the pool instead: ``_paged_write`` reshapes the fresh rows before the
+    scatter, ``_paged_view`` the gathered copy after the gather, and nobody
+    else may read KV or hd off the pool's shape (they are ``cfg``'s).
+
+    A gated short-convolution layer's is {conv}: ``[num_blocks,
     conv_kernel - 1, D]`` in the pool's dtype, the gated input at a
     sequence's last positions, kept at the id of the sequence's FIRST
     block -- unique to a live sequence, freed and copied with the block --
     and no K/V is allocated for it.  Block 0 is the scratch block — the
     allocator (runtime/genserver.py) hands out ids >= 1 — and entry 0 of a
     state the scratch state."""
+    from seldon_core_tpu.ops.paged_attention import heads_per_row
+
     hd = cfg.hd
     kv = cfg.kv_heads
+    pair = heads_per_row(kv, hd) if mesh is None else 1
     # XLA:CPU has no native bf16 scatter: a bf16 pool pays TWO whole-pool
     # converts (bf16 -> f32 scatter -> bf16) around EVERY write, which
     # scales step cost with POOL size instead of batch size (measured:
@@ -276,10 +301,8 @@ def init_block_pool(cfg: LMConfig, num_blocks: int, block_size: int
                 "k_s": jnp.zeros((num_blocks, block_size, kv), jnp.float32),
                 "v_s": jnp.zeros((num_blocks, block_size, kv), jnp.float32),
             }
-        return {
-            "k": jnp.zeros((num_blocks, block_size, kv, hd), dtype),
-            "v": jnp.zeros((num_blocks, block_size, kv, hd), dtype),
-        }
+        rows = (num_blocks, block_size, kv // pair, hd * pair)
+        return {"k": jnp.zeros(rows, dtype), "v": jnp.zeros(rows, dtype)}
 
     return {f"l{i}": layer(cfg.kind(i)[0]) for i in range(cfg.n_layers)}
 
@@ -290,16 +313,27 @@ def _pool_kv(pool):
     return next((layer for layer in pool.values() if "k" in layer), None)
 
 
-def _paged_view(layer, tables):
+def _paged_view(layer, tables, head_dim=None):
     """Gather one layer's blocks into a dense position-ordered cache view:
     pool [N, bs, KV, hd] + tables [B, nblk] -> {k, v[, k_s, v_s]} with k/v
     [B, KV, nblk*bs, hd] — the _grouped_qk/_grouped_pv layout, so paged
-    attention reuses the exact dot formulations the dense caches use."""
+    attention reuses the exact dot formulations the dense caches use.
+    ``head_dim`` is the model's head width where the pool's rows may carry
+    several heads (``init_block_pool``; None: a row is a head): the copy,
+    not the pool, is cut back into heads."""
     out = {}
     for name in ("k", "v"):
         g = layer[name][tables]  # [B, nblk, bs, KV, hd]
-        B, nblk, bs, KV, hd = g.shape
-        out[name] = g.transpose(0, 3, 1, 2, 4).reshape(B, KV, nblk * bs, hd)
+        B, nblk, bs = g.shape[:3]
+        hd = head_dim or g.shape[4]
+        if hd != g.shape[4]:
+            # the copy is cut into heads as a value of its own: fused with
+            # the gather, XLA's TPU layout assignment picks the POOL's
+            # layout for this reshape and re-lays the whole pool around
+            # the chunk's scatter (PERF.md section 6, PR 40)
+            g = jax.lax.optimization_barrier(g)
+        g = g.reshape(B, nblk, bs, -1, hd)
+        out[name] = g.transpose(0, 3, 1, 2, 4).reshape(B, -1, nblk * bs, hd)
     for name in ("k_s", "v_s"):
         if name in layer:
             g = layer[name][tables]  # [B, nblk, bs, KV]
@@ -314,7 +348,9 @@ def _paged_write(layer, tables, pos, valid, k_new, v_new):
     through each row's table.  ``valid`` [B, W] False routes the write to
     the scratch block 0 (masked rows / pad positions) — garbage lands in
     scratch, never in a live sequence's blocks.  int8 pools quantize here
-    (per-token absmax, _quantize_kv) and scatter the scale planes too."""
+    (per-token absmax, _quantize_kv) and scatter the scale planes too.  The
+    fresh rows take the shape of the pool's rows (``init_block_pool``: a
+    token's KV x hd values, in that order, whichever way they are cut)."""
     bs = layer["k"].shape[1]
     nblk = tables.shape[1]
     idx = jnp.clip(pos // bs, 0, nblk - 1)
@@ -330,10 +366,11 @@ def _paged_write(layer, tables, pos, valid, k_new, v_new):
         out["k_s"] = layer["k_s"].at[blk, off].set(k_s.transpose(0, 2, 1))
         out["v_s"] = layer["v_s"].at[blk, off].set(v_s.transpose(0, 2, 1))
     else:
-        out["k"] = layer["k"].at[blk, off].set(
-            k_new.transpose(0, 2, 1, 3).astype(layer["k"].dtype))
-        out["v"] = layer["v"].at[blk, off].set(
-            v_new.transpose(0, 2, 1, 3).astype(layer["v"].dtype))
+        for name, new in (("k", k_new), ("v", v_new)):
+            rows = new.transpose(0, 2, 1, 3).reshape(
+                blk.shape + layer[name].shape[2:])
+            out[name] = layer[name].at[blk, off].set(
+                rows.astype(layer[name].dtype))
     return out
 
 
@@ -549,7 +586,7 @@ def _paged_block(lp, x, pool_layer, tables, start, valid, cfg: LMConfig,
     gathered = view is not None
     if plan is None and not gathered:
         with jax.named_scope("kv_gather"):
-            view = _paged_view(pool_layer, tables)
+            view = _paged_view(pool_layer, tables, hd)
     with jax.named_scope("attn"):
         if gathered:
             a = _attend_view_and_fresh(q, view, start, k, v)
@@ -624,24 +661,29 @@ def paged_forward(params, tokens, pool, tables, start, width,
 
 
 def decode_inplace(pool, mesh=None, width: int = 1, heads=None,
-                   rows: int = 1) -> bool:
+                   rows: int = 1, head_dim=None) -> bool:
     """Whether a decode round over ``pool`` attends in place (the Pallas
     kernel) or through the gather path: ops.paged_attention
     .inplace_supported over what is observable here — the backend, the
     pool's dtype and shapes, the caller's mesh, the queries a row brings
     to a step (``width``: 1, or a diffusion block's ``block_length``) and
     the query ``heads`` and padded ``rows`` of the widest batch the caller
-    will bring, which the kernel holds whole."""
+    will bring, which the kernel holds whole.  ``head_dim`` is the model's
+    head width (``cfg.hd``): a pool row may carry several heads
+    (``init_block_pool``), so the KV heads are the row's values over it; a
+    caller that does not say is answered for a head a row."""
     from seldon_core_tpu.ops.paged_attention import inplace_supported
 
     kv = _pool_kv(pool)
     if kv is None:
         return False        # no layer attends: nothing to read in place
     k = kv["k"]
+    head_dim = head_dim or k.shape[3]
     return inplace_supported(
         width=width, backend=jax.default_backend(), pool_dtype=k.dtype,
-        mesh=mesh, block_size=k.shape[1], kv_heads=k.shape[2],
-        head_dim=k.shape[3], heads=heads, rows=rows)
+        mesh=mesh, block_size=k.shape[1],
+        kv_heads=k.shape[2] * k.shape[3] // head_dim, head_dim=head_dim,
+        heads=heads, rows=rows)
 
 
 def paged_decode_round(params, pool, tables, token, n_valid, active,
@@ -680,7 +722,7 @@ def paged_decode_round(params, pool, tables, token, n_valid, active,
             inplace=inplace, trace_passes=trace_passes)
     if inplace is None:
         inplace = decode_inplace(pool, heads=cfg.n_heads,
-                                 rows=n_valid.shape[0])
+                                 rows=n_valid.shape[0], head_dim=cfg.hd)
     kv = _pool_kv(pool)     # (a plan is made only where a layer attends)
     capacity = tables.shape[1] * kv["k"].shape[1] if kv else 0
 
@@ -781,7 +823,8 @@ def _denoising_round(params, pool, tables, token, n_valid, active, seen_eos,
         raise ValueError("a round of denoising passes decodes greedily")
     B = n_valid.shape[0]
     if inplace is None:
-        inplace = decode_inplace(pool, width=L, heads=cfg.n_heads, rows=B)
+        inplace = decode_inplace(pool, width=L, heads=cfg.n_heads, rows=B,
+                                 head_dim=cfg.hd)
     capacity = tables.shape[1] * _pool_kv(pool)["k"].shape[1]
     if token.ndim == 1:
         token = jnp.broadcast_to(token[:, None], (B, L))
@@ -822,7 +865,7 @@ def _denoising_round(params, pool, tables, token, n_valid, active, seen_eos,
             plan = decode_plan(start, active, capacity, fresh=0)
         else:
             with jax.named_scope("kv_gather"):
-                views = [_paged_view(pool[f"l{i}"], tables)
+                views = [_paged_view(pool[f"l{i}"], tables, cfg.hd)
                          for i in range(cfg.n_layers)]
 
         def denoise(c, _):
@@ -982,13 +1025,13 @@ paged_copy_block_jit = jax.jit(paged_copy_block, donate_argnums=(0,))
 BLOCK_SIZE = 16
 
 
-def private_pool(cfg: LMConfig, rows: int, positions: int):
+def private_pool(cfg: LMConfig, rows: int, positions: int, mesh=None):
     """A request's own pool and tables: ``rows`` rows of ``positions``
     positions, ``1 + rows * ceil(positions / BLOCK_SIZE)`` blocks in all.
     Row b owns blocks ``1 + b*n .. b*n + n`` in order (identity tables —
     nothing to allocate, free or evict); block 0 is the scratch block."""
     n = -(-positions // BLOCK_SIZE)
-    pool = init_block_pool(cfg, 1 + rows * n, BLOCK_SIZE)
+    pool = init_block_pool(cfg, 1 + rows * n, BLOCK_SIZE, mesh)
     tables = 1 + jnp.arange(rows * n, dtype=jnp.int32).reshape(rows, n)
     return pool, tables
 
@@ -1001,11 +1044,11 @@ def _begin(params, prompt, cfg: LMConfig, max_new_tokens: int,
     paged_decode_round threads (pool, pending token, n_valid, seen_eos,
     per-row keys) and ``knobs`` its static keywords."""
     B, S = prompt.shape
-    pool, tables = private_pool(cfg, B, S + max_new_tokens)
+    pool, tables = private_pool(cfg, B, S + max_new_tokens, mesh)
     knobs = dict(temperature=temperature, top_k=top_k, top_p=top_p,
                  eos_token=eos_token,
                  inplace=decode_inplace(pool, mesh, heads=cfg.n_heads,
-                                        rows=B))
+                                        rows=B, head_dim=cfg.hd))
     # prefill sees the prompt's own blocks only: its attention would
     # otherwise span (masked) the blocks the decode round has yet to fill
     logits, pool = paged_forward_jit(
@@ -1059,8 +1102,9 @@ def _denoising_lane(params, prompt, cfg: LMConfig, max_new_tokens: int,
     L = cfg.block_length
     rem = S % L
     whole = -(-(rem + max_new_tokens) // L) * L     # positions to generate
-    pool, tables = private_pool(cfg, B, S - rem + whole)
-    inplace = decode_inplace(pool, mesh, width=L, heads=cfg.n_heads, rows=B)
+    pool, tables = private_pool(cfg, B, S - rem + whole, mesh)
+    inplace = decode_inplace(pool, mesh, width=L, heads=cfg.n_heads, rows=B,
+                             head_dim=cfg.hd)
     _, pool = paged_forward_jit(
         params, prompt, pool, tables[:, :-(-S // BLOCK_SIZE)],
         jnp.zeros((B,), jnp.int32), jnp.full((B,), S, jnp.int32), cfg=cfg,

@@ -40,10 +40,41 @@ one plain tile): XLA's TPU scatter re-lays such a pool to ``[N, bs, KV, hd]``
 around every ``_paged_write`` — two whole-pool copies per layer (PERF.md §6,
 PR 25).
 
+A head NARROWER than the 128 lanes (``hd`` 64: ``heads_per_row``) rides a
+row with its neighbours.  A token's window is ``KV x hd`` values either way,
+so the pool of such a model is made ``[N, bs, KV * hd / 128, 128]``
+(``generate.init_block_pool``): row ``j`` of a position holds head ``2j`` in
+lanes 0-63 and head ``2j + 1`` in lanes 64-127, and the kernel's body runs
+unchanged over ``KV / 2`` heads of 128.  What changes is the wrapper's, on
+operands of a few KB (``paged_decode_attention``):
+
+  * the two heads' query groups fold into ONE group of ``2 * g * W`` rows a
+    pool row; a query of head ``2j`` is its 64 values in lanes 0-63 and 0.0
+    in 64-127, one of head ``2j + 1`` the other way round.  ``q_pad . k_row``
+    is then exactly the 64-wide score — the other head's half contributes
+    products with 0.0, which add nothing in float32 (stored K/V are finite)
+    — so a row's softmax is its own head's, and of ``p @ v_row``
+    ``[2 * g * W, 128]`` a row keeps its own half.  The scale stays
+    ``1 / sqrt(hd)``: the model's head, not the row's lanes.  The MXU does
+    twice the attention's FLOPs; the step is bound by the DMA of K/V, which
+    is the same bytes.
+  * the POOL has that shape from the start, and is never reshaped inside a
+    program: a ``[N, bs, 8, 64]`` bfloat16 array does not lie on a TPU as
+    its shape reads — the chip keeps it with the POSITIONS minor-most
+    ((8, 128) tiles over hd x bs) and a program re-lays it, padded to 128
+    lanes, around every scatter (PERF.md §6, PR 40: 134 MB of temporaries
+    for one decode step's write into a 67-MB pool, 0 into the paired one)
+    — so a ``reshape`` to ``[N, bs, 4, 128]`` there is a copy of the whole
+    pool, around every layer.  The small operands meet the pool instead:
+    ``generate._paged_write`` reshapes the fresh rows before the scatter,
+    ``generate._paged_view`` the gathered copy after the gather (prefill
+    and verify keep the gather path and its numerics).
+
 Which formulation serves is decided by ONE pure function,
 ``inplace_supported``: the kernel on a TPU backend with a float pool, no
 mesh (a Mosaic call does not partition under GSPMD), shapes the kernel
-tiles and folded queries that fit its vector memory; the gather path
+tiles (a head of 128 lanes or a multiple, or heads that fill rows of 128
+together) and folded queries that fit its vector memory; the gather path
 otherwise.  ``width`` there is the queries a row brings to a round's step,
 all of ONE block: the causal widths of a prefill chunk or a verify pass
 (``paged_forward``) do not ask and keep the gather path, which is also the
@@ -60,12 +91,13 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["blocks_per_chunk", "decode_plan", "inplace_supported",
-           "paged_decode_attention"]
+__all__ = ["blocks_per_chunk", "decode_plan", "heads_per_row",
+           "inplace_supported", "paged_decode_attention"]
 
 _CHUNK_POSITIONS = 512          # K/V positions one compute step covers
 _BUFFER_BYTES = 8 * 1024 * 1024  # both K and V chunks, double-buffered
 _MASK = -1e30                   # generate._attend_paged's mask value
+_LANES = 128                    # a vector register's lanes: a pool row
 _STAT_LANES = 128               # one lane tile: the max in lane 0, the sum in 1..
 # what the kernel may hold in vector memory: the chunk buffers, the whole
 # batch's folded queries and outputs, the accumulators.  Mosaic's own limit
@@ -86,9 +118,22 @@ def blocks_per_chunk(block_size: int, kv_heads: int, head_dim: int,
     return c if c * block_bytes <= _BUFFER_BYTES else 0
 
 
+def heads_per_row(kv_heads: int, head_dim: int) -> int:
+    """KV heads one row of the pool carries: 1 (the pool is ``[N, bs, KV,
+    hd]``), or ``128 // head_dim`` for a head narrower than the 128 lanes
+    whose KV heads fill whole rows (the pool is ``[N, bs, KV * hd / 128,
+    128]``: the same bytes, a token's window still minor-most).  A matter of
+    the model's shapes alone, because the pool is made before anybody knows
+    which formulation will read it: ``generate.init_block_pool`` asks here
+    for a float pool, and an int8 pool keeps a head a row (its scale planes
+    are per head)."""
+    pair = _LANES // head_dim if head_dim and _LANES % head_dim == 0 else 1
+    return pair if kv_heads % pair == 0 else 1
+
+
 def _query_rows(heads: int, kv_heads: int, width: int) -> int:
-    """Query rows a KV head serves, ``g * W``, in whole float32 sublane
-    tiles."""
+    """Query rows a pool row's heads serve, ``g * W`` a head, in whole
+    float32 sublane tiles."""
     return -(-(heads // kv_heads) * width // 8) * 8
 
 
@@ -109,6 +154,12 @@ def inplace_supported(*, width: int, backend: str, pool_dtype: Any,
     dt = jnp.dtype(pool_dtype)
     if not jnp.issubdtype(dt, jnp.floating):
         return False
+    # heads narrower than a row ride it together: from here on the shape is
+    # the pool's, ``kv_heads / pair`` heads of 128 (``heads`` stays: a row's
+    # heads serve ``pair`` query groups)
+    pair = heads_per_row(kv_heads, head_dim)
+    heads = heads or kv_heads
+    kv_heads, head_dim = kv_heads // pair, head_dim * pair
     # the 32-bit view: a position's KV heads are one memory tile of
     # 1, 2, 4 or 8 rows of 128 words, and a block is whole (8, 128) tiles
     words = kv_heads * dt.itemsize / 4
@@ -119,7 +170,7 @@ def inplace_supported(*, width: int, backend: str, pool_dtype: Any,
                              max(1, _CHUNK_POSITIONS // block_size))
     if chunk == 0:
         return False
-    group = kv_heads * _query_rows(heads or kv_heads, kv_heads, width) * 4
+    group = kv_heads * _query_rows(heads, kv_heads, width) * 4
     held = (chunk * 4 * kv_heads * block_size * head_dim * dt.itemsize
             + 2 * rows * group * head_dim            # queries in, sums out
             + group * (head_dim + 2 * _STAT_LANES))  # the accumulators
@@ -174,14 +225,14 @@ def _first_lane(shape):
 
 
 def _kernel(len_ref, order_ref, count_ref, tbl_ref, q_ref, k_hbm, v_hbm,
-            o_ref, *refs, nblk: int, p_dtype):
+            o_ref, *refs, nblk: int, p_dtype, scale: float):
     # ``stat_ref``: the second output, there only for a caller that asked
     # for the statistics
     *stat_ref, kbuf, vbuf, sem, m_scr, l_scr, acc_scr = refs
     B, KV, g, hd = q_ref.shape
     _, C, bs, _, _ = kbuf.shape
     T = C * bs
-    scale = jnp.float32(1.0 / (hd ** 0.5))
+    scale = jnp.float32(scale)
 
     o_ref[...] = jnp.zeros_like(o_ref)
     for ref in stat_ref:
@@ -279,8 +330,11 @@ def paged_decode_attention(q, k_pool, v_pool, tables, lengths, order, count,
     ``lengths[b]`` positions of its own blocks, no mask among the queries.
 
     q [B, H, W, hd] in the model dtype; k_pool / v_pool
-    ``[N, bs, KV, hd]``; tables [B, nblk] int32; ``lengths, order, count``
-    from ``decode_plan``.  Returns [B, H, W, hd] in q's dtype; a row that is
+    ``[N, bs, KV, hd]``, or ``[N, bs, KV / pair, pair * hd]`` where ``pair``
+    heads ride a row (the module docstring; told by the pool's last
+    dimension against q's); tables [B, nblk] int32; ``lengths, order,
+    count`` from ``decode_plan``.  Returns [B, H, W, hd] in q's dtype; a
+    row that is
     not live (inactive, or of length 0) gives zeros.  Jitted so that a
     program's layers share one trace and one lowering of the kernel.
 
@@ -291,26 +345,33 @@ def paged_decode_attention(q, k_pool, v_pool, tables, lengths, order, count,
     with the other keys' part in one softmax; a row that is not live has
     mass 0."""
     B, H, W, hd = q.shape
-    _, bs, KV, _ = k_pool.shape
+    _, bs, KV, lanes = k_pool.shape     # the pool's rows: KV / pair of them
+    pair = lanes // hd                  # model heads a pool row carries
     nblk = tables.shape[1]
-    g = H // KV
+    rows = H // KV * W                  # pair * g * W query rows a pool row
     gp = _query_rows(H, KV, W)
-    C = blocks_per_chunk(bs, KV, hd, k_pool.dtype.itemsize, nblk)
+    C = blocks_per_chunk(bs, KV, lanes, k_pool.dtype.itemsize, nblk)
     if C == 0:
         raise ValueError(
-            f"one block of {KV} x {bs} x {hd} {k_pool.dtype} does not fit "
-            "the kernel's chunk buffers; take the gather path "
+            f"one block of {KV} x {bs} x {lanes} {k_pool.dtype} does not "
+            "fit the kernel's chunk buffers; take the gather path "
             "(inplace_supported)")
     # float32 in and out: the model dtype's values exactly, in (8, 128)
     # tiles whatever the group size; cast back to the pool dtype in VMEM
-    qg = q.reshape(B, KV, g * W, hd).astype(jnp.float32)
-    if gp != g * W:
-        qg = jnp.pad(qg, ((0, 0), (0, 0), (0, gp - g * W), (0, 0)))
+    qg = q.reshape(B, KV, pair, rows // pair, hd).astype(jnp.float32)
+    if pair > 1:
+        # a head's queries in its own lanes of the row, 0.0 in the others'
+        own = jnp.eye(pair, dtype=bool)[:, None, :, None]
+        qg = jnp.where(own, qg[:, :, :, :, None, :], 0.0)
+    qg = qg.reshape(B, KV, rows, lanes)
+    if gp != rows:
+        qg = jnp.pad(qg, ((0, 0), (0, 0), (0, gp - rows), (0, 0)))
     vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
-    summed = jax.ShapeDtypeStruct((B, KV, gp, hd), jnp.float32)
+    summed = jax.ShapeDtypeStruct((B, KV, gp, lanes), jnp.float32)
     stat = jax.ShapeDtypeStruct((B, KV, gp, _STAT_LANES), jnp.float32)
     out = pl.pallas_call(
-        functools.partial(_kernel, nblk=nblk, p_dtype=q.dtype),
+        functools.partial(_kernel, nblk=nblk, p_dtype=q.dtype,
+                          scale=1.0 / (hd ** 0.5)),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4,
             grid=(1,),
@@ -321,20 +382,23 @@ def paged_decode_attention(q, k_pool, v_pool, tables, lengths, order, count,
             ],
             out_specs=(vmem, vmem) if stats else vmem,
             scratch_shapes=[
-                pltpu.VMEM((2, C, bs, KV, hd), k_pool.dtype),
-                pltpu.VMEM((2, C, bs, KV, hd), v_pool.dtype),
+                pltpu.VMEM((2, C, bs, KV, lanes), k_pool.dtype),
+                pltpu.VMEM((2, C, bs, KV, lanes), v_pool.dtype),
                 pltpu.SemaphoreType.DMA((2, 2)),
                 pltpu.VMEM((KV, gp, 1), jnp.float32),
                 pltpu.VMEM((KV, gp, 1), jnp.float32),
-                pltpu.VMEM((KV, gp, hd), jnp.float32),
+                pltpu.VMEM((KV, gp, lanes), jnp.float32),
             ],
         ),
         out_shape=(summed, stat) if stats else summed,
         interpret=interpret,
     )(lengths, order, count, tables.reshape(-1), qg, k_pool, v_pool)
+    out, stat = out if stats else (out, None)
+    out = out[:, :, :rows].reshape(B, KV, pair, rows // pair, pair, hd)
+    # of a row's sum over the wide values, the half that is its own head's
+    out = jnp.stack([out[:, :, s, :, s] for s in range(pair)], axis=2)
     if not stats:
-        return out[:, :, :g * W].astype(q.dtype).reshape(B, H, W, hd)
-    out, stat = out
-    stat = stat[:, :, :g * W].reshape(B, KV, g, W, _STAT_LANES)
-    return (out[:, :, :g * W].reshape(B, KV, g, W, hd),
-            stat[..., 0], stat[..., 1])
+        return out.astype(q.dtype).reshape(B, H, W, hd)
+    g = H // (KV * pair)
+    stat = stat[:, :, :rows].reshape(B, KV * pair, g, W, _STAT_LANES)
+    return (out.reshape(B, KV * pair, g, W, hd), stat[..., 0], stat[..., 1])

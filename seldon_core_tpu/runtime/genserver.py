@@ -13,7 +13,8 @@ pool and the tables:
 
   * **Paged KV pool** — one process-wide per-layer block pool
     (``models/generate.py init_block_pool``: ``[num_blocks, block_size,
-    KV, hd]``); sequences hold block tables, the :class:`BlockAllocator`
+    KV, hd]``, heads narrower than 128 lanes several a row); sequences
+    hold block tables, the :class:`BlockAllocator`
     does alloc/free/eviction (preempt-youngest recompute) and occupancy
     accounting.  A shared prefix is computed once, at boot, into PINNED
     blocks: every sequence's table references the same physical blocks
@@ -1169,7 +1170,7 @@ class GenServer:
         )
 
         self._pool = init_block_pool(
-            self.cfg, self.num_blocks, self.block_size)
+            self.cfg, self.num_blocks, self.block_size, self.mesh)
         self._allocator = BlockAllocator(self.num_blocks)
         if self.mesh is not None:
             # tensor-parallel dispatch (runtime/servingmesh.py): the
@@ -1184,7 +1185,8 @@ class GenServer:
         # only the scheduler sees the mesh its pool is sharded over
         self._inplace = decode_inplace(
             self._pool, self.mesh, width=self._block,
-            heads=self.cfg.n_heads, rows=_pow2(self.slots))
+            heads=self.cfg.n_heads, rows=_pow2(self.slots),
+            head_dim=self.cfg.hd)
         if self.spec:
             self._draft_pool = init_block_pool(
                 self.draft_cfg, self.num_blocks, self.block_size)
@@ -2755,12 +2757,14 @@ class GenServer:
                 "prefill-role replica has no decode peers configured "
                 "(--decode-peers / ENGINE_DECODE_PEERS)"))
             return
-        l0 = self._pool["l0"]
         meta = kvstream.KvBeginMeta(
             n_layers=len(self._pool),
             block_size=self.block_size,
-            kv_heads=int(l0["k"].shape[2]),
-            head_dim=int(l0["k"].shape[3]),
+            # the MODEL's heads: a pool row may carry several of them
+            # (models/generate.py init_block_pool), the wire's bytes are
+            # a token's KV x hd values either way
+            kv_heads=self.cfg.kv_heads,
+            head_dim=self.cfg.hd,
             dtype=kvstream.pool_dtype_name(self._pool),
             n_blocks=len(seq.blocks),
             n_valid=seq.n_valid,
@@ -2843,7 +2847,8 @@ class GenServer:
 
         self._ensure_device()
         kvstream.validate_against_pool(
-            meta, self._pool, self.block_size, self._prefix_len)
+            meta, self._pool, self.block_size, self._prefix_len,
+            head_dim=self.cfg.hd)
         blocks = self._allocator.reserve(meta.n_blocks)
         if blocks is None:
             RECORDER.record_kv_handoff("refused")

@@ -354,8 +354,12 @@ def _kv_scatter_chunk(pool, idx, chunk):
     for li, layer in pool.items():
         new = dict(layer)
         for name, vals in chunk[li].items():
+            # staged blocks have the wire's shape, the model's heads; a
+            # pool row may carry several (models/generate.py
+            # init_block_pool): the same values in the same order
             new[name] = layer[name].at[idx].set(
-                vals.astype(layer[name].dtype))
+                vals.reshape(vals.shape[:1] + layer[name].shape[1:])
+                .astype(layer[name].dtype))
         out[li] = new
     return out
 
@@ -400,13 +404,17 @@ def scatter_staged(pool, local_blocks: List[int],
 
 
 def validate_against_pool(meta: KvBeginMeta, pool, block_size: int,
-                          prefix_len: int) -> None:
+                          prefix_len: int,
+                          head_dim: Optional[int] = None) -> None:
     """Typed compatibility check before any block is reserved: layer
     count, geometry, dtype and shared-prefix agreement must all match
-    the receiving pool or the handoff is refused up front."""
+    the receiving pool or the handoff is refused up front.  ``head_dim``
+    is the receiving MODEL's head width: a pool row may carry several
+    heads (models/generate.py init_block_pool; None: a row is a head)."""
     n_layers = len(pool)
     l0 = pool["l0"]
-    kv, hd = int(l0["k"].shape[2]), int(l0["k"].shape[3])
+    hd = int(head_dim or l0["k"].shape[3])
+    kv = int(l0["k"].shape[2]) * int(l0["k"].shape[3]) // hd
     dtype = str(np.dtype(l0["k"].dtype)) if "k_s" not in l0 else "int8"
     # jax bf16 dtype stringifies as 'bfloat16' through np.dtype
     if (meta.n_layers, meta.block_size, meta.kv_heads, meta.head_dim) != \
@@ -426,32 +434,8 @@ def validate_against_pool(meta: KvBeginMeta, pool, block_size: int,
             "same deployment spec")
 
 
-def export_meta_for(seq, *, pool_dtype: str, block_size: int,
-                    prefix_len: int, n_blocks: int) -> KvBeginMeta:
-    """Build the BEGIN metadata off a finished-prefill sequence
-    (runtime/genserver.py ``_Sequence``)."""
-    l_meta = KvBeginMeta(
-        n_layers=0, block_size=block_size, kv_heads=0, head_dim=0,
-        dtype=pool_dtype, n_blocks=n_blocks, n_valid=seq.n_valid,
-        pending=int(seq.pending), max_new=int(seq.max_new),
-        prefix_len=prefix_len, prompt=np.asarray(seq.prompt, np.int32),
-        emitted=list(seq.emitted), key_data=seq.key_data,
-        tier=seq.request.tier,
-    )
-    return l_meta
-
-
 def pool_dtype_name(pool) -> str:
     l0 = pool["l0"]
     if "k_s" in l0:
         return "int8"
     return str(np.dtype(l0["k"].dtype))
-
-
-def fill_geometry(meta: KvBeginMeta, pool) -> KvBeginMeta:
-    """Stamp the pool's layer/head geometry onto export metadata."""
-    l0 = pool["l0"]
-    meta.n_layers = len(pool)
-    meta.kv_heads = int(l0["k"].shape[2])
-    meta.head_dim = int(l0["k"].shape[3])
-    return meta

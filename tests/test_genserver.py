@@ -295,19 +295,52 @@ def test_decode_table_width_follows_which_attention_serves(rows, row_max):
         assert rows * floor <= _DECODE_TABLE_ENTRIES
 
 
+def test_a_64_wide_head_gets_one_decode_width_a_row_count():
+    """lfm2-8b-a1b's deployment (bench/configs/lfm2-8b-a1b.json: 32 slots,
+    256 blocks of 256, 8 KV heads of 64 under 32 query heads, bfloat16):
+    on a TPU the choosing function says in place -- two heads ride a row
+    of the pool -- so a row count has ONE table width whatever its rows
+    need (1 to 8 blocks under the mix: four widths on the gather path): 6
+    decode programs where the gather path loads 24."""
+    from seldon_core_tpu.ops.paged_attention import inplace_supported
+
+    def shapes(backend):
+        inplace = inplace_supported(
+            width=1, backend=backend, pool_dtype=jnp.bfloat16, mesh=None,
+            block_size=256, kv_heads=8, head_dim=64, heads=32, rows=32)
+        return {(rows, _decode_table_width(inplace, rows, need, 255))
+                for rows in (1, 2, 4, 8, 16, 32) for need in range(1, 9)}
+
+    assert len(shapes("cpu")) == 24
+    assert shapes("tpu") == {(rows, 128) for rows in (1, 2, 4, 8, 16, 32)}
+
+
+# two KV heads of 64: one 128-lane row of the pool a position
+# (models/generate.py init_block_pool)
+CFG_HD64 = LMConfig(vocab=48, d_model=32, n_heads=4, n_kv_heads=2,
+                    head_dim=64, n_layers=2, d_ff=64, dtype=jnp.float32)
+
+
+@pytest.mark.parametrize("cfg", [CFG, CFG_HD64], ids=["hd8", "hd64-paired"])
 def test_in_place_a_decode_round_has_one_program_per_row_count(
-        params, monkeypatch):
+        cfg, monkeypatch):
     """Prompts whose rows cross three block boundaries, at two row counts.
     The CPU's gather path dispatches a decode shape per (rows, power-of-two
     width) as ever; with the in-place kernel serving (interpret mode) it is
-    one per row count, and the tokens are the same."""
+    one per row count, and the tokens are the same -- over a pool whose
+    rows carry two heads too."""
     from seldon_core_tpu.models import generate as gen_mod
+
+    params = lm_init(jax.random.key(3), cfg)
 
     prompts = np.random.default_rng(21).integers(0, 48, size=(3, 3))
 
     def serve():
-        srv = _server(params, max_new_tokens=14)
+        srv = _server(params, max_new_tokens=14, cfg=cfg)
         try:
+            srv._ensure_device()
+            assert srv._pool["l0"]["k"].shape[2:] == (
+                (1, 128) if cfg is CFG_HD64 else (4, 8))
             # block 4, span 3: tables of 2, 3, 4 and 5 blocks
             got = [srv.submit(p.astype(float)).future.result(timeout=240)
                    for p in (prompts[:2], prompts[2:])]
@@ -326,7 +359,7 @@ def test_in_place_a_decode_round_has_one_program_per_row_count(
     assert (counts["prefill"], counts["decode"]) == (2, 2)
     np.testing.assert_array_equal(toks_inplace, toks)
     np.testing.assert_array_equal(toks, np.asarray(generate(
-        params, jnp.asarray(prompts, jnp.int32), CFG, max_new_tokens=14)))
+        params, jnp.asarray(prompts, jnp.int32), cfg, max_new_tokens=14)))
 
 
 def test_in_place_a_block_generator_books_every_round_and_one_width(
@@ -382,8 +415,8 @@ def test_in_place_a_block_generator_books_every_round_and_one_width(
     monkeypatch.setattr(gen_mod, "decode_inplace", interpret)
     got, shapes, progs, served = serve()
     # what the scheduler tells the choosing function: a block's queries,
-    # the query heads and its widest padded batch
-    assert seen == [{"width": 4, "heads": 4, "rows": 2}]
+    # the query heads, its widest padded batch and the model's head width
+    assert seen == [{"width": 4, "heads": 4, "rows": 2, "head_dim": 16}]
     assert shapes == {(2, 32), (1, 32)}    # 63 blocks a row can hold
     assert progs["decode"] == 2
     assert served["inplace_steps"] == served["device_steps"] > 0

@@ -3,7 +3,11 @@ against the gather path it replaces — at width 1 (generate._paged_view +
 _attend_paged) and for the queries of one diffusion block a row
 (_attend_view_and_fresh): Pallas interpret mode on the CPU, the choosing
 function, the decode round with the in-place path forced, and the kernel
-compiled for a described v5e at the benchmark cells' shapes."""
+compiled for a described v5e at the benchmark cells' shapes.  A head
+narrower than a 128-lane row is a further case of each: the pool is then
+made as ``init_block_pool`` makes it, several KV heads a row."""
+
+import itertools
 
 import jax
 import jax.numpy as jnp
@@ -15,6 +19,7 @@ from seldon_core_tpu.models.generate import (
     _attend_pool_and_fresh,
     _attend_view_and_fresh,
     _paged_view,
+    _paged_write,
     decode_inplace,
     init_block_pool,
     paged_decode_round_jit,
@@ -24,6 +29,7 @@ from seldon_core_tpu.models.transformer import LMConfig, lm_init
 from seldon_core_tpu.ops.paged_attention import (
     blocks_per_chunk,
     decode_plan,
+    heads_per_row,
     inplace_supported,
     paged_decode_attention,
 )
@@ -31,14 +37,19 @@ from seldon_core_tpu.ops.paged_attention import (
 KV, HD = 2, 128
 
 
-def _case(bs, g, dtype, n_valid, active, nblk, seed=0, width=1):
+def _case(bs, g, dtype, n_valid, active, nblk, seed=0, width=1, kv=KV,
+          hd=HD):
     """Random q and pools, and tables of scrambled, non-contiguous block
-    ids; an inactive row's table is all zeros (the scheduler's padding)."""
+    ids; an inactive row's table is all zeros (the scheduler's padding).
+    The pool has ``init_block_pool``'s shape: ``heads_per_row`` KV heads a
+    row of the pool, its bytes those of ``[N, bs, kv, hd]``."""
     rng = np.random.default_rng(seed)
-    B, H = len(n_valid), KV * g
+    B, H = len(n_valid), kv * g
     N = B * nblk + 3
-    q = jnp.asarray(rng.normal(size=(B, H, width, HD)), dtype)
-    pool = {name: jnp.asarray(rng.normal(size=(N, bs, KV, HD)), dtype)
+    pair = heads_per_row(kv, hd)
+    q = jnp.asarray(rng.normal(size=(B, H, width, hd)), dtype)
+    pool = {name: jnp.asarray(rng.normal(size=(N, bs, kv, hd)), dtype
+                              ).reshape(N, bs, kv // pair, hd * pair)
             for name in ("k", "v")}
     ids = rng.permutation(np.arange(1, N))[:B * nblk].reshape(B, nblk)
     tables = np.where(np.asarray(active)[:, None], ids, 0).astype(np.int32)
@@ -47,7 +58,7 @@ def _case(bs, g, dtype, n_valid, active, nblk, seed=0, width=1):
 
 
 def _both(q, pool, tables, n_valid, active):
-    want = _attend_paged(q, _paged_view(pool, tables), n_valid)
+    want = _attend_paged(q, _paged_view(pool, tables, q.shape[-1]), n_valid)
     capacity = tables.shape[1] * pool["k"].shape[1]
     got = paged_decode_attention(
         q, pool["k"], pool["v"], tables,
@@ -60,18 +71,42 @@ def _tol(dtype):
     return 3e-2 if dtype == jnp.bfloat16 else 2e-5
 
 
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
-                         ids=["float32", "bfloat16"])
-@pytest.mark.parametrize("g", [1, 4, 12])
-@pytest.mark.parametrize("bs", [16, 256])
-def test_kernel_matches_gather_path_on_ragged_rows(bs, g, dtype):
+def _head_cases(*axes, narrow):
+    """Every combination of ``axes`` (lists of values, ``dtype`` the last)
+    at the 128-wide head the tests began with, then the ``narrow`` heads
+    (``(kv, hd)``; a subset of the axes each) that ride a row together.  A
+    position's KV heads must fill whole 32-bit rows of 128 words: two heads
+    of 64 in bfloat16 are half a row, and only float32 serves them."""
+    names = {jnp.float32: "float32", jnp.bfloat16: "bfloat16"}
+    out = []
+    for (kv, hd), sub in [((KV, HD), axes)] + narrow:
+        for combo in itertools.product(*sub):
+            if kv * hd * jnp.dtype(combo[-1]).itemsize % 512 == 0:
+                out.append(pytest.param(
+                    *combo, kv, hd,
+                    id="-".join([*map(str, combo[:-1]), names[combo[-1]],
+                                 f"kv{kv}", f"hd{hd}"])))
+    return out
+
+
+_FLOATS = [jnp.float32, jnp.bfloat16]
+
+
+@pytest.mark.parametrize("bs,g,dtype,kv,hd", _head_cases(
+    [16, 256], [1, 4, 12], _FLOATS, narrow=[
+        ((8, 64), ([16, 256], [1, 4], _FLOATS)),   # lfm2-8b-a1b: g 4
+        ((2, 64), ([16, 256], [4, 12], _FLOATS)),  # one row of the pool
+        ((8, 32), ([16], [4], _FLOATS)),           # four heads a row
+    ]))
+def test_kernel_matches_gather_path_on_ragged_rows(bs, g, dtype, kv, hd):
     """Lengths 1, exactly a block, a block + 1, mid-table and the full
     table; one inactive row between live ones."""
     nblk = 4
     lengths = [1, bs, bs + 1, 0, 2 * bs + bs // 2, nblk * bs]
     active = [n > 0 for n in lengths]
     n_valid = [max(n - 1, 0) for n in lengths]  # the kernel reads n_valid + 1
-    got, want = _both(*_case(bs, g, dtype, n_valid, active, nblk))
+    got, want = _both(*_case(bs, g, dtype, n_valid, active, nblk, kv=kv,
+                             hd=hd))
     live = np.asarray(active)
     np.testing.assert_allclose(got[live], want[live], atol=_tol(dtype),
                                rtol=0)
@@ -124,20 +159,21 @@ def test_decode_plan_of_a_block_stops_at_its_start():
     assert order.tolist()[:2] == [0, 3] and count.tolist() == [2]
 
 
-def _block_case(bs, g, W, dtype, starts, active, nblk, seed=0):
+def _block_case(bs, g, W, dtype, starts, active, nblk, seed=0, kv=KV,
+                hd=HD):
     """``_case`` for a block of ``W`` queries a row at ``starts``, with the
     block's own fresh K/V, which never pass through the pool."""
     q, pool, tables, start, act = _case(bs, g, dtype, starts, active, nblk,
-                                        seed=seed, width=W)
+                                        seed=seed, width=W, kv=kv, hd=hd)
     rng = np.random.default_rng(seed + 100)
-    k_new, v_new = (jnp.asarray(rng.normal(size=(len(starts), KV, W, HD)),
+    k_new, v_new = (jnp.asarray(rng.normal(size=(len(starts), kv, W, hd)),
                                 dtype) for _ in "kv")
     return q, pool, tables, start, act, k_new, v_new
 
 
 def _block_both(q, pool, tables, start, act, k_new, v_new):
-    want = _attend_view_and_fresh(q, _paged_view(pool, tables), start,
-                                  k_new, v_new)
+    want = _attend_view_and_fresh(
+        q, _paged_view(pool, tables, q.shape[-1]), start, k_new, v_new)
     capacity = tables.shape[1] * pool["k"].shape[1]
     got = _attend_pool_and_fresh(
         q, pool, tables, decode_plan(start, act, capacity, fresh=0),
@@ -145,12 +181,12 @@ def _block_both(q, pool, tables, start, act, k_new, v_new):
     return np.asarray(got, np.float32), np.asarray(want, np.float32)
 
 
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
-                         ids=["float32", "bfloat16"])
-@pytest.mark.parametrize("g", [8, 12])
-@pytest.mark.parametrize("W", [4, 8])
-@pytest.mark.parametrize("bs", [16, 256])
-def test_block_of_queries_matches_the_gather_path(bs, W, g, dtype):
+@pytest.mark.parametrize("bs,W,g,dtype,kv,hd", _head_cases(
+    [16, 256], [4, 8], [8, 12], _FLOATS, narrow=[
+        ((8, 64), ([16, 256], [4], [4], _FLOATS)),
+        ((2, 64), ([16], [4, 8], [8], _FLOATS)),
+    ]))
+def test_block_of_queries_matches_the_gather_path(bs, W, g, dtype, kv, hd):
     """A diffusion block's ``W`` queries a row over the cache before the
     block's start (the kernel, its statistics) and the block's fresh K/V
     (joined outside it) against ``_attend_view_and_fresh``: a block that
@@ -163,7 +199,7 @@ def test_block_of_queries_matches_the_gather_path(bs, W, g, dtype):
               nblk * bs - W]
     active = [True, True, True, False, True, True, True]
     got, want = _block_both(
-        *_block_case(bs, g, W, dtype, starts, active, nblk))
+        *_block_case(bs, g, W, dtype, starts, active, nblk, kv=kv, hd=hd))
     live = np.asarray(active)
     np.testing.assert_allclose(got[live], want[live], atol=_tol(dtype),
                                rtol=0)
@@ -218,6 +254,10 @@ def test_statistics_of_a_row_that_is_not_live_carry_no_mass():
 BLOCK = {"width": 4, "kv_heads": 4, "heads": 32, "rows": 32}
 
 
+# the third cell's widths: 32 query heads over 8 KV heads, 32 padded rows
+LFM2 = {"kv_heads": 8, "heads": 32, "rows": 32}
+
+
 @pytest.mark.parametrize("kw,want", [
     ({}, True),
     ({"heads": 24, "rows": 32}, True),        # the dense cell, told in full
@@ -229,7 +269,9 @@ BLOCK = {"width": 4, "kv_heads": 4, "heads": 32, "rows": 32}
     ({"backend": "cpu"}, False),
     ({"pool_dtype": jnp.float32}, True),
     ({"block_size": 16}, True),
-    ({"head_dim": 64}, False),                # not a 128-lane row
+    # two heads of 64 ride one 128-lane row -- where a position's heads
+    # fill whole 32-bit rows: two of them in bfloat16 are half a row
+    ({"head_dim": 64}, False),
     ({"kv_heads": 6}, False),                 # not a memory tile
     ({"kv_heads": 1}, False),                 # half a word a position
     ({"kv_heads": 1, "pool_dtype": jnp.float32}, True),
@@ -242,12 +284,26 @@ BLOCK = {"width": 4, "kv_heads": 4, "heads": 32, "rows": 32}
     ({**BLOCK, "mesh": object()}, False),
     ({**BLOCK, "pool_dtype": jnp.int8}, False),
     ({**BLOCK, "backend": "cpu"}, False),
-    ({**BLOCK, "head_dim": 64}, False),
+    ({**BLOCK, "head_dim": 64}, True),        # 2 rows of 2 heads, 64 queries
     ({"width": 4}, True),                     # sizes untold: the shapes alone
+    ({**LFM2, "head_dim": 64}, True),         # lfm2-8b-a1b: 4 rows of 2 heads
+    ({"head_dim": 64, "pool_dtype": jnp.float32}, True),
+    ({"head_dim": 64, "kv_heads": 4}, True),
+    ({"head_dim": 64, "kv_heads": 4, "block_size": 16, "heads": 16,
+      "rows": 64}, True),                     # chip_smoke.py's model
+    ({"head_dim": 64, "kv_heads": 3}, False),  # a head left over
+    ({"head_dim": 64, "kv_heads": 5, "pool_dtype": jnp.float32}, False),
+    ({"head_dim": 96, "kv_heads": 4}, False),  # fills no row
+    ({**LFM2, "head_dim": 64, "pool_dtype": jnp.int8}, False),
+    ({**LFM2, "head_dim": 64, "mesh": object()}, False),
+    ({**LFM2, "head_dim": 32}, True),         # four heads a row
+    ({"head_dim": 256}, True),                # two rows a head: as ever
 ], ids=["cell", "cell-sized", "wide", "int8", "mesh", "cpu", "f32", "bs16",
         "hd64", "kv6", "kv1-bf16", "kv1-f32", "block4", "block8-32rows",
         "block8-16rows", "block4-128rows", "block2", "block0", "block-mesh",
-        "block-int8", "block-cpu", "block-hd64", "block-unsized"])
+        "block-int8", "block-cpu", "block-hd64", "block-unsized", "lfm2",
+        "hd64-f32", "hd64-kv4", "hd64-smoke", "hd64-kv3", "hd64-kv5-f32",
+        "hd96", "hd64-int8", "hd64-mesh", "hd32", "hd256"])
 def test_inplace_supported_chooses_by_what_it_can_observe(kw, want):
     base = dict(width=1, backend="tpu", pool_dtype=jnp.bfloat16, mesh=None,
                 block_size=256, kv_heads=2, head_dim=128)
@@ -265,7 +321,14 @@ CFG = LMConfig(vocab=64, d_model=64, n_heads=4, n_kv_heads=2, n_layers=2,
                d_ff=128, dtype=jnp.float32)
 
 
-def _three_rounds(inplace, width):
+# gated short convolutions and attention at a 64-wide head, two KV heads:
+# the pool's K/V are one row of 128 a position (float32: 32-bit words)
+HYBRID = LMConfig(vocab=64, d_model=64, n_heads=4, n_kv_heads=2, head_dim=64,
+                  n_layers=4, layer_kinds="caca", conv_kernel=3, d_ff=128,
+                  qk_norm=True, dtype=jnp.float32)
+
+
+def _three_rounds(inplace, width, CFG=CFG):
     """Ragged rows prefilled, then three rounds of paged_decode_round over
     tables ``width`` wide (a row owns 8 blocks; the rest is the scheduler's
     zero padding): the tokens, the pool, and the live block ids."""
@@ -302,17 +365,62 @@ def _assert_same_round(got, want):
     (toks, pool, live), (toks_want, pool_want, _) = got, want
     np.testing.assert_array_equal(toks, toks_want)
     for li in pool_want:                   # live: not the scratch block
-        for name in ("k", "v"):
+        for name in pool_want[li]:         # k and v, or a conv layer's state
             np.testing.assert_allclose(
                 np.asarray(pool[li][name])[live],
                 np.asarray(pool_want[li][name])[live], atol=1e-5, rtol=0)
 
 
-def test_decode_round_in_place_emits_the_gather_paths_tokens():
+@pytest.mark.parametrize("cfg", [CFG, HYBRID], ids=["hd16", "conv-attn-hd64"])
+def test_decode_round_in_place_emits_the_gather_paths_tokens(cfg):
     """Three rounds of paged_decode_round with the in-place path forced
     through the function's own argument (interpret mode): the same greedy
-    tokens and the same pool as the gather path."""
-    _assert_same_round(_three_rounds("interpret", 8), _three_rounds(False, 8))
+    tokens and the same pool as the gather path -- also where conv layers
+    stand between attention layers whose two 64-wide KV heads share a row
+    of the pool."""
+    got = _three_rounds("interpret", 8, cfg)
+    _assert_same_round(got, _three_rounds(False, 8, cfg))
+    row = 1 if cfg is CFG else 2
+    k = next(layer["k"] for layer in got[1].values() if "k" in layer)
+    assert k.shape[2:] == (cfg.kv_heads // row, cfg.hd * row)
+
+
+@pytest.mark.parametrize("kv,hd,dtype,shape", [
+    (8, 64, jnp.bfloat16, (4, 128)),       # lfm2-8b-a1b
+    (2, 64, jnp.float32, (1, 128)),
+    (8, 32, jnp.float32, (2, 128)),
+    (3, 64, jnp.float32, (3, 64)),         # a head left over: a head a row
+    (2, 128, jnp.float32, (2, 128)),
+], ids=["kv8-hd64", "kv2-hd64", "kv8-hd32", "kv3-hd64", "kv2-hd128"])
+def test_a_written_chunk_reads_back_bit_for_bit(kv, hd, dtype, shape):
+    """``init_block_pool`` -> ``_paged_write`` -> ``_paged_view``: whatever
+    shape the pool's rows have, the gather path (prefill, verify) reads
+    back the very values a chunk wrote, a head at its own place -- and a
+    pad position's went to the scratch block."""
+    cfg = LMConfig(vocab=64, d_model=96, n_heads=2 * kv, n_kv_heads=kv,
+                   head_dim=hd, n_layers=1, d_ff=64, dtype=dtype)
+    bs, W = 4, 6
+    pool = init_block_pool(cfg, 9, bs)["l0"]
+    stored = pool["k"].dtype        # float32 on the CPU (no bf16 scatter)
+    assert pool["k"].shape == (9, bs) + shape
+    rng = np.random.default_rng(3)
+    k_new, v_new = (jnp.asarray(rng.normal(size=(2, kv, W, hd)), dtype)
+                    for _ in "kv")
+    tables = jnp.asarray([[5, 2, 7], [3, 8, 1]], jnp.int32)
+    start = np.asarray([3, 0])
+    pos = jnp.asarray(start[:, None] + np.arange(W)[None, :])
+    valid = jnp.asarray(np.arange(W)[None, :] < np.asarray([[6], [4]]))
+    pool = _paged_write(pool, tables, pos, valid, k_new, v_new)
+    view = _paged_view(pool, tables, hd)
+    for name, new in (("k", k_new), ("v", v_new)):
+        assert view[name].shape == (2, kv, 3 * bs, hd)
+        for r, (lo, n) in enumerate(zip(start, (6, 4))):
+            np.testing.assert_array_equal(
+                np.asarray(view[name][r, :, lo:lo + n]),
+                np.asarray(new[r, :, :n].astype(stored)))
+            assert not np.asarray(view[name][r, :, lo + n:]).any()
+    assert init_block_pool(cfg, 9, bs, mesh=object())["l0"]["k"].shape == (
+        9, bs, kv, hd)                     # a sharded pool: a head a row
 
 
 def test_decode_round_in_place_is_blind_to_the_tables_padding():
@@ -385,20 +493,29 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
-@pytest.mark.parametrize("B,H,kv,bs,nblk,dtype,W", [
-    (16, 24, 2, 256, 4, jnp.bfloat16, 1),  # the dense cell's decode shape
-    (32, 24, 2, 256, 8, jnp.bfloat16, 1),
-    (32, 24, 2, 256, 128, jnp.bfloat16, 1),  # its one width since PR 28
-    (8, 8, 4, 16, 16, jnp.bfloat16, 1),    # the program's default block size
-    (8, 8, 2, 16, 4, jnp.float32, 1),
-    (32, 32, 4, 256, 128, jnp.bfloat16, 4),  # sdar-30b-a3b's block of 4
-    (1, 32, 4, 256, 128, jnp.bfloat16, 4),
-    (16, 32, 4, 256, 128, jnp.bfloat16, 8),  # the widest fold it is told fits
-    (8, 24, 2, 16, 16, jnp.float32, 4),    # 12 x 4 query rows, float32 pool
+@pytest.mark.parametrize("B,H,kv,bs,nblk,dtype,W,hd", [
+    (16, 24, 2, 256, 4, jnp.bfloat16, 1, HD),  # the dense cell's decode shape
+    (32, 24, 2, 256, 8, jnp.bfloat16, 1, HD),
+    (32, 24, 2, 256, 128, jnp.bfloat16, 1, HD),  # its one width since PR 28
+    (8, 8, 4, 16, 16, jnp.bfloat16, 1, HD),  # the program's default block size
+    (8, 8, 2, 16, 4, jnp.float32, 1, HD),
+    (32, 32, 4, 256, 128, jnp.bfloat16, 4, HD),  # sdar-30b-a3b's block of 4
+    (1, 32, 4, 256, 128, jnp.bfloat16, 4, HD),
+    (16, 32, 4, 256, 128, jnp.bfloat16, 8, HD),  # the widest fold told to fit
+    (8, 24, 2, 16, 16, jnp.float32, 4, HD),  # 12 x 4 query rows, float32 pool
+    (32, 32, 8, 256, 4, jnp.bfloat16, 1, 64),    # lfm2-8b-a1b: 8 KV x 64
+    (32, 32, 8, 256, 8, jnp.bfloat16, 1, 64),
+    (32, 32, 8, 256, 128, jnp.bfloat16, 1, 64),  # the one width it is served
+    (1, 32, 8, 256, 128, jnp.bfloat16, 1, 64),
+    (64, 16, 4, 16, 64, jnp.bfloat16, 1, 64),    # chip_smoke.py's model
+    (8, 8, 2, 16, 4, jnp.float32, 4, 64),        # a block of 4 at head 64
+    (8, 16, 8, 16, 16, jnp.bfloat16, 1, 32),     # four heads a row
 ], ids=["cell-16x4", "cell-32x8", "cell-32x128-floor", "bs16-kv4", "f32",
-        "block4-32x128", "block4-1x128", "block8-16x128", "block4-f32"])
+        "block4-32x128", "block4-1x128", "block8-16x128", "block4-f32",
+        "lfm2-32x4", "lfm2-32x8", "lfm2-32x128-floor", "lfm2-1x128",
+        "smoke-hd64-bs16", "block4-hd64-f32", "hd32"])
 def test_kernel_compiles_for_a_described_v5e(one_chip, B, H, kv, bs, nblk,
-                                             dtype, W):
+                                             dtype, W, hd):
     """Mosaic accepts the kernel at real widths: the 32-bit view of the
     interleaved block, the strided head loads, the chunk buffers' VMEM and,
     for a block of ``W`` queries a row, the folded queries, their outputs
@@ -412,8 +529,10 @@ def test_kernel_compiles_for_a_described_v5e(one_chip, B, H, kv, bs, nblk,
 
     assert inplace_supported(width=W, backend="tpu", pool_dtype=dtype,
                              mesh=None, block_size=bs, kv_heads=kv,
-                             head_dim=HD, heads=H, rows=B)
+                             head_dim=hd, heads=H, rows=B)
     N = 64
+    pair = heads_per_row(kv, hd)
+    pool = (N, bs, kv // pair, hd * pair)     # as init_block_pool makes it
     # a compile for a described chip is written to the persistent cache
     # but can never be read back here: keep it out
     cache_was = jax.config.jax_enable_compilation_cache
@@ -421,8 +540,8 @@ def test_kernel_compiles_for_a_described_v5e(one_chip, B, H, kv, bs, nblk,
     compilation_cache.reset_cache()
     try:
         compiled = paged_decode_attention.lower(
-            s((B, H, W, HD), dtype), s((N, bs, kv, HD), dtype),
-            s((N, bs, kv, HD), dtype), s((B, nblk), jnp.int32),
+            s((B, H, W, hd), dtype), s(pool, dtype), s(pool, dtype),
+            s((B, nblk), jnp.int32),
             s((B,), jnp.int32), s((B,), jnp.int32), s((1,), jnp.int32),
             stats=W > 1,
         ).compile()

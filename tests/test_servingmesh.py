@@ -113,12 +113,20 @@ def _wait_blocks_freed(gs, timeout_s=10.0):
 
 # -- export/import round trip -------------------------------------------
 
-def test_disagg_token_identical_greedy_f32():
-    unified = _genserver()
-    decode = _genserver(role="decode")
-    prefill = _genserver(role="prefill",
+@pytest.mark.parametrize("unit_kw,row", [
+    ({}, (2, 16)),
+    # two KV heads of 64 ride one 128-lane row of either pool; the wire
+    # states the model's heads (models/generate.py init_block_pool)
+    ({"head_dim": 64}, (1, 128)),
+], ids=["hd16", "hd64-paired"])
+def test_disagg_token_identical_greedy_f32(unit_kw, row):
+    unified = _genserver(_unit(**unit_kw))
+    decode = _genserver(_unit(**unit_kw), role="decode")
+    prefill = _genserver(_unit(**unit_kw), role="prefill",
                          coordinator=LoopbackCoordinator(decode))
     try:
+        decode._ensure_device()
+        assert decode._pool["l0"]["k"].shape[2:] == row
         y0 = unified.submit(_PROMPT).future.result(timeout=120)
         y1 = prefill.submit(_PROMPT).future.result(timeout=120)
         np.testing.assert_array_equal(y0, y1)
