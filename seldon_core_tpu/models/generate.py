@@ -14,8 +14,9 @@ One KV layout, one decoder block, three programs:
     blocks through a block table; a gated short-convolution layer
     (``LMConfig.layer_kinds``) holds no K/V but a fixed-size state a
     sequence, ``[num_blocks, conv_kernel - 1, D]``, found by the row's
-    FIRST block; ``_paged_block`` is the only decoder block that reads or
-    writes either;
+    FIRST block, and a power-retention layer likewise a float32 matrix
+    state a KV head (ops/retention.py); ``_paged_block`` is the only
+    decoder block that reads or writes any of them;
   * ``paged_forward`` (a prompt, a prompt chunk or a verify pass),
     ``paged_decode_round`` (``span`` single-token steps as ONE ``lax.scan``
     inside jit — no per-token dispatch, no host round trip between steps;
@@ -42,6 +43,7 @@ The cache-free forward (training, ``lm_apply``) is models/transformer.py.
 
 from __future__ import annotations
 
+import contextlib
 import functools
 import itertools
 import time
@@ -274,8 +276,21 @@ def init_block_pool(cfg: LMConfig, num_blocks: int, block_size: int,
     block -- unique to a live sequence, freed and copied with the block --
     and no K/V is allocated for it.  Block 0 is the scratch block — the
     allocator (runtime/genserver.py) hands out ids >= 1 — and entry 0 of a
-    state the scratch state."""
+    state the scratch state.
+
+    A power-retention layer's is {s, z} (ops/retention.py): ``s`` ``[num_
+    blocks, KV * hd, P]`` and the normaliser ``z`` ``[num_blocks, KV, P]``,
+    float32 on every backend, found like a conv state at the sequence's
+    first block's id.  An entry is 34 MB at the published widths whatever
+    the row's length, and there is one a BLOCK: such a generator (every
+    layer is one, ``LMConfig``) is deployed a block a row -- a block size
+    that holds the longest row, as many blocks as rows and the scratch one
+    -- so that the pool IS the table of states and holds nothing else.
+    (``s`` is three-dimensional, rows of (KV head, value lane):
+    bench/tools/rehearse_aot.py describes every four-dimensional float32
+    array of a pool as the chip's bfloat16 K/V.)"""
     from seldon_core_tpu.ops.paged_attention import heads_per_row
+    from seldon_core_tpu.ops.retention import phi_width
 
     hd = cfg.hd
     kv = cfg.kv_heads
@@ -294,6 +309,11 @@ def init_block_pool(cfg: LMConfig, num_blocks: int, block_size: int,
         if mixer == "conv":
             return {"conv": jnp.zeros(
                 (num_blocks, cfg.conv_kernel - 1, cfg.d_model), dtype)}
+        if mixer == "ret":
+            return {"s": jnp.zeros((num_blocks, kv * hd, phi_width(hd)),
+                                   jnp.float32),
+                    "z": jnp.zeros((num_blocks, kv, phi_width(hd)),
+                                   jnp.float32)}
         if cfg.kv_quant == "int8":
             return {
                 "k": jnp.zeros((num_blocks, block_size, kv, hd), jnp.int8),
@@ -494,6 +514,74 @@ def _short_conv(lp, x, pool_layer, tables, start, valid, cfg: LMConfig):
     return x, {"conv": state}
 
 
+def _project_qkv(lp, x, positions, cfg: LMConfig, scope=jax.named_scope):
+    """What an attention layer and a retention layer both begin with, on x
+    [B, W, D]: the layer's first norm, the fused ``wqkv``, heads apart (q
+    [B, H, W, hd]; k, v [B, KV, W, hd]), the per-head norms on q and k under
+    ``cfg.qk_norm`` and the rotary embedding at ``positions`` [B, W] under
+    ``cfg.rope``.  Returns (the normed input, q, k, v).  ``scope`` names the
+    three stages for the trace; a caller that books them under a scope of
+    its own passes one that names nothing."""
+    from seldon_core_tpu.ops.quant import lm_matmul
+
+    B, W, _ = x.shape
+    hd, kv_h = cfg.hd, cfg.kv_heads
+    q_out = cfg.n_heads * hd
+    with scope("qkv"):
+        h = _rmsnorm(x, lp["ln1"], cfg.norm_eps)
+        qkv = lm_matmul(lp, "wqkv", h, out_dtype=x.dtype)
+        q, k, v = jnp.split(qkv, [q_out, q_out + kv_h * hd], axis=-1)
+        q = _heads(q, B, W, cfg.n_heads, hd)
+        k = _heads(k, B, W, kv_h, hd)
+        v = _heads(v, B, W, kv_h, hd)
+    if cfg.qk_norm:
+        with scope("qk_norm"):
+            q = _rmsnorm(q, lp["q_norm"], cfg.norm_eps)
+            k = _rmsnorm(k, lp["k_norm"], cfg.norm_eps)
+    if cfg.rope:
+        with scope("rope"):
+            q = apply_rope(q, positions, cfg.rope_base)
+            k = apply_rope(k, positions, cfg.rope_base)
+    return h, q, k, v
+
+
+def _retention(lp, x, pool_layer, tables, start, valid, cfg: LMConfig):
+    """The power-retention mixer on x [B, W, D] -> (x', pool layer'):
+    ``q, k, v`` as attention projects them (``wqkv``; the per-head norms
+    under ``cfg.qk_norm``, the rotary embedding under ``cfg.rope``) and
+    ``log g = logsigmoid(norm(x) ret_gate + ret_gate_b)``, one a KV head, in
+    float32; ops/retention.py between them and ``wo`` -- the recurrent form
+    for a call of one position a row, the chunk form otherwise.  A row's
+    state is ``pool_layer`` at ``tables[b, 0]``, zero for a row that starts
+    at 0; pad positions (``valid`` False, to the right of the valid ones)
+    enter nothing, and a row with no valid position is skipped whole."""
+    from seldon_core_tpu.ops.quant import lm_matmul
+    from seldon_core_tpu.ops.retention import retention
+
+    B, W, D = x.shape
+    hd, kv_h, H = cfg.hd, cfg.kv_heads, cfg.n_heads
+    q_out = H * hd
+    with jax.named_scope("ret_in"):
+        # one scope for the whole of it: the innermost known scope is the
+        # one a device op is booked under (bench/lib/trace_scopes.py)
+        h, q, k, v = _project_qkv(
+            lp, x, start[:, None] + jnp.arange(W)[None, :], cfg,
+            scope=lambda name: contextlib.nullcontext())
+        log_g = jax.nn.log_sigmoid(
+            jnp.einsum("bwd,dk->bkw", h, lp["ret_gate"],
+                       preferred_element_type=jnp.float32)
+            + lp["ret_gate_b"].astype(jnp.float32)[None, :, None])
+    with jax.named_scope("retention"):
+        width = jnp.sum(jnp.broadcast_to(valid, (B, W)), axis=1)
+        y, pool_layer = retention(
+            q.reshape(B, kv_h, H // kv_h, W, hd), k, v, log_g, pool_layer,
+            tables[:, 0], start, width)
+        y = y.reshape(B, H, W, hd).transpose(0, 2, 1, 3).reshape(B, W, q_out)
+    with jax.named_scope("ret_out"):
+        x = x + lm_matmul(lp, "wo", y, out_dtype=x.dtype)
+    return x, pool_layer
+
+
 # A ``jit`` of its own inside the programs that call it: a program traces and
 # lowers the block once per distinct (shapes, static arguments) -- a dense
 # program once, one whose layers are of several kinds (``kind``) once a kind,
@@ -518,9 +606,10 @@ def _paged_block(lp, x, pool_layer, tables, start, valid, cfg: LMConfig,
 
     ``kind`` is the layer's ``(mixer, ffn)`` (``LMConfig.kind(i)``; None: an
     attention layer whose FFN is read off its weights): a "conv" layer
-    takes ``_short_conv`` in the attention's place -- its pool entry is the
-    state, and what follows about K/V does not concern it -- and the FFN is
-    ``transformer._ffn``'s of that kind.
+    takes ``_short_conv`` in the attention's place and a "ret" layer
+    ``_retention`` -- its pool entry is the state, and what follows about
+    K/V does not concern it -- and the FFN is ``transformer._ffn``'s of
+    that kind.
 
     ``plan`` (ops.paged_attention.decode_plan) selects the in-place
     formulation: attention reads the row's blocks from the pool where they
@@ -541,7 +630,6 @@ def _paged_block(lp, x, pool_layer, tables, start, valid, cfg: LMConfig,
 
     B, W, D = x.shape
     hd = cfg.hd
-    kv_h = cfg.kv_heads
     q_out = cfg.n_heads * hd
     mixer, ffn = kind or ("attn", None)
 
@@ -553,30 +641,16 @@ def _paged_block(lp, x, pool_layer, tables, start, valid, cfg: LMConfig,
                           if cfg.d_expert else None, kind=ffn)
             return x + y, aux
 
-    if mixer == "conv":
-        x, pool_layer = _short_conv(lp, x, pool_layer, tables, start, valid,
-                                    cfg)
+    if mixer in ("conv", "ret"):
+        x, pool_layer = (_short_conv if mixer == "conv" else _retention)(
+            lp, x, pool_layer, tables, start, valid, cfg)
         x, aux = feed_forward(x)
         return x, pool_layer, aux
     # the stages below are jax.named_scope's: op metadata only (same
     # programs, same numerics), read back from a profile window's device
     # ops by bench/lib/trace_scopes.py — keep the names stable
-    with jax.named_scope("qkv"):
-        h = _rmsnorm(x, lp["ln1"], cfg.norm_eps)
-        qkv = lm_matmul(lp, "wqkv", h, out_dtype=x.dtype)
-        q, k, v = jnp.split(qkv, [q_out, q_out + kv_h * hd], axis=-1)
-        q = _heads(q, B, W, cfg.n_heads, hd)
-        k = _heads(k, B, W, kv_h, hd)
-        v = _heads(v, B, W, kv_h, hd)
-    if cfg.qk_norm:
-        with jax.named_scope("qk_norm"):
-            q = _rmsnorm(q, lp["q_norm"], cfg.norm_eps)
-            k = _rmsnorm(k, lp["k_norm"], cfg.norm_eps)
     positions = start[:, None] + jnp.arange(W)[None, :]  # [B, W] per-row
-    if cfg.rope:
-        with jax.named_scope("rope"):
-            q = apply_rope(q, positions, cfg.rope_base)
-            k = apply_rope(k, positions, cfg.rope_base)
+    _, q, k, v = _project_qkv(lp, x, positions, cfg)
     if write:
         with jax.named_scope("kv_write"):
             pool_layer = _paged_write(pool_layer, tables, positions, valid,
@@ -725,6 +799,7 @@ def paged_decode_round(params, pool, tables, token, n_valid, active,
                                  rows=n_valid.shape[0], head_dim=cfg.hd)
     kv = _pool_kv(pool)     # (a plan is made only where a layer attends)
     capacity = tables.shape[1] * kv["k"].shape[1] if kv else 0
+    inplace = inplace if kv else False
 
     def step(carry, _):
         pool, token, n_valid, seen_eos, keys, *read = carry
@@ -935,11 +1010,12 @@ def paged_spec_round(t_params, d_params, t_pool, d_pool, t_tables,
     (new_toks [B, k+1], gained [B], corrected [B], t_pool', d_pool'):
     row b's round output is new_toks[b, :gained[b]], its next pending
     token is corrected[b]."""
-    if "c" in t_cfg.layer_kinds + d_cfg.layer_kinds:
+    if set(t_cfg.layer_kinds + d_cfg.layer_kinds) & set("cr"):
         raise ValueError(
             "speculative decoding cannot serve a gated short-convolution "
-            "layer: a rejected draft would have to roll the layer's state "
-            "back, and the state keeps no history to roll back to")
+            "or retention layer: a rejected draft would have to roll the "
+            "layer's state back, and the state keeps no history to roll "
+            "back to")
     B = token.shape[0]
     W = k + 1
 
@@ -1029,8 +1105,10 @@ def private_pool(cfg: LMConfig, rows: int, positions: int, mesh=None):
     """A request's own pool and tables: ``rows`` rows of ``positions``
     positions, ``1 + rows * ceil(positions / BLOCK_SIZE)`` blocks in all.
     Row b owns blocks ``1 + b*n .. b*n + n`` in order (identity tables —
-    nothing to allocate, free or evict); block 0 is the scratch block."""
-    n = -(-positions // BLOCK_SIZE)
+    nothing to allocate, free or evict); block 0 is the scratch block.  A
+    generator of retention layers keeps nothing by position and a state a
+    BLOCK (``init_block_pool``): its rows get one block each."""
+    n = 1 if "r" in cfg.layer_kinds else -(-positions // BLOCK_SIZE)
     pool = init_block_pool(cfg, 1 + rows * n, BLOCK_SIZE, mesh)
     tables = 1 + jnp.arange(rows * n, dtype=jnp.int32).reshape(rows, n)
     return pool, tables
@@ -1317,12 +1395,19 @@ class TransformerGenerator(Unit):
             layer_kinds=str(layer_kinds), conv_kernel=int(conv_kernel),
             dense_layers=int(dense_layers), router=str(router),
         )
-        if "c" in self.cfg.layer_kinds and str(prefix_tokens).strip():
+        if (set(self.cfg.layer_kinds) & set("cr")
+                and str(prefix_tokens).strip()):
             raise ValueError(
-                "a generator with gated short-convolution layers takes no "
-                "shared prefix (prefix_tokens): the prefix's pinned blocks "
-                "are shared by table reference, and a layer's state after "
-                "the prefix is one sequence's, found by its own first block")
+                "a generator with gated short-convolution or retention "
+                "layers takes no shared prefix (prefix_tokens): the "
+                "prefix's pinned blocks are shared by table reference, and "
+                "a layer's state after the prefix is one sequence's, found "
+                "by its own first block")
+        if "r" in self.cfg.layer_kinds and mesh is not None:
+            raise ValueError(
+                "a generator of retention layers is served on one chip: "
+                "nothing shards a layer's state over a mesh yet (by KV "
+                "head, beside the parameters)")
         if self.cfg.block_length > 1 and (
                 float(temperature) > 0.0 or str(prefix_tokens).strip()):
             raise ValueError(

@@ -123,10 +123,15 @@ class LMConfig:
     # in_proj(u)``; a depthwise causal convolution of ``conv_kernel`` taps
     # over ``B * X``, gated by ``C``; ``out_proj``: a layer that keeps the
     # last ``conv_kernel - 1`` positions of ``B * X`` a sequence in the
-    # K/V's place, models/generate.py); "" = attention in every layer.
+    # K/V's place, models/generate.py), "r" power retention of degree 2
+    # (ops/retention.py: q, k, v and o as attention has them and a gate of
+    # one logit a KV head; a layer that keeps no position but a float32
+    # matrix state a sequence and a KV head, read and rewritten by every
+    # token); "" = attention in every layer.
     # ``dense_layers``: the first so many layers' FFN is a dense gated-SiLU
     # FFN of width ``d_ff`` (``w2(silu(w1 u) * w3 u)``) where the others
-    # hold the experts of ``d_expert``.  ``router``: how an expert layer
+    # hold the experts of ``d_expert`` -- or, without experts, EVERY layer's
+    # (``dense_layers == n_layers``).  ``router``: how an expert layer
     # scores, "softmax" or "sigmoid_bias" (parallel/moe.py).  Serving only,
     # as ``d_expert`` is.
     layer_kinds: str = ""
@@ -143,13 +148,14 @@ class LMConfig:
         return self.moe_every > 0 and (i + 1) % self.moe_every == 0
 
     def kind(self, i: int) -> Tuple[str, str]:
-        """Layer ``i`` as ``(mixer, ffn)``: "attn" | "conv", and "gelu" (the
-        two-matrix FFN), "moe" (capacity-routed, ``moe_every``), "gated"
-        (a leading dense gated-SiLU layer) or "experts" (dropless).  The
+        """Layer ``i`` as ``(mixer, ffn)``: "attn" | "conv" | "ret", and
+        "gelu" (the two-matrix FFN), "moe" (capacity-routed, ``moe_every``),
+        "gated" (a dense gated-SiLU layer) or "experts" (dropless).  The
         one place that reads the fields above; a program traces the
         decoder block once a distinct kind."""
-        mixer = "conv" if self.layer_kinds[i:i + 1] == "c" else "attn"
-        if self.d_expert:
+        mixer = {"c": "conv", "r": "ret"}.get(self.layer_kinds[i:i + 1],
+                                              "attn")
+        if self.d_expert or self.dense_layers:
             return mixer, "gated" if i < self.dense_layers else "experts"
         return mixer, "moe" if self.is_moe_layer(i) else "gelu"
 
@@ -196,28 +202,39 @@ class LMConfig:
             )
         if self.layer_kinds and (
                 len(self.layer_kinds) != self.n_layers
-                or set(self.layer_kinds) - set("ac")
+                or set(self.layer_kinds) - set("acr")
                 or self.conv_kernel < 2):
             raise ValueError(
                 f"layer_kinds={self.layer_kinds!r} is one letter a layer, "
-                f"'a' or 'c', for n_layers={self.n_layers}, and a "
+                f"'a', 'c' or 'r', for n_layers={self.n_layers}, and a "
                 f"convolution has at least 2 taps (got {self.conv_kernel})")
-        if not 0 <= self.dense_layers <= (self.n_layers if self.d_expert
-                                          else 0):
+        if (not 0 <= self.dense_layers <= self.n_layers
+                or (self.dense_layers not in (0, self.n_layers)
+                    and not self.d_expert)
+                or (self.dense_layers and self.moe_every)):
             raise ValueError(
                 f"dense_layers={self.dense_layers} are the leading layers of "
-                "a configuration with experts (d_expert) and at most "
-                "n_layers")
+                "a configuration with experts (d_expert), or all n_layers "
+                "of one without (and without moe_every)")
         if self.router not in ("softmax", "sigmoid_bias"):
             raise ValueError(
                 f"router={self.router!r} not supported "
                 "(softmax | sigmoid_bias)")
-        if "c" in self.layer_kinds and (self.block_length > 1
-                                        or self.moe_every):
+        if set(self.layer_kinds) & set("cr") and (self.block_length > 1
+                                                  or self.moe_every):
             raise ValueError(
-                "a gated short-convolution layer rides neither a round of "
-                "denoising passes (block_length > 1: its state would have "
-                "to be rolled back a pass) nor moe_every")
+                "a gated short-convolution or retention layer rides neither "
+                "a round of denoising passes (block_length > 1: its state "
+                "would have to be rolled back a pass) nor moe_every")
+        if "r" in self.layer_kinds and (
+                set(self.layer_kinds) != {"r"} or self.hd % 2
+                or self.kv_quant != "none"):
+            raise ValueError(
+                "retention layers are served where EVERY layer is one "
+                "(layer_kinds all 'r': a row's state lives at its first "
+                "block's id, one entry a block, so such a generator is "
+                "deployed a block a row and holds no K/V), with an even "
+                "head width and kv_quant none")
         if self.block_length > 1 and (
                 self.block_length % self.denoising_steps
                 or not 0 <= self.mask_id < self.vocab):
@@ -313,6 +330,16 @@ def lm_init(rng, cfg: LMConfig) -> Dict[str, Any]:
             if cfg.qk_norm:
                 lp["q_norm"] = jnp.ones((hd,), dt)
                 lp["k_norm"] = jnp.ones((hd,), dt)
+            if mixer == "ret":
+                # the gate: one logit a KV head; its bias float32 and NOT
+                # zero -- sigmoid of 4.6 .. 7.6 is a decay of 0.99 ..
+                # 0.9995 a position, a memory of 100 to 2,000 positions,
+                # so what an earlier chunk left always weighs
+                lp["ret_gate"] = dense(jax.random.fold_in(k[1], 1),
+                                       (D, cfg.kv_heads), D)
+                lp["ret_gate_b"] = jax.random.uniform(
+                    jax.random.fold_in(k[1], 2), (cfg.kv_heads,),
+                    jnp.float32, 4.6, 7.6)
         if ffn == "experts":
             from seldon_core_tpu.parallel.moe import dropless_init
 
@@ -546,12 +573,14 @@ def lm_apply(
     the Pallas flash kernel on single-chip meshes (differentiable).
     ``return_lb`` additionally returns the summed MoE load-balance loss."""
     if (cfg.d_expert or cfg.qk_norm or cfg.head_dim or cfg.block_length > 1
-            or not cfg.tie_embeddings or "c" in cfg.layer_kinds):
+            or not cfg.tie_embeddings or cfg.dense_layers
+            or set(cfg.layer_kinds) & set("cr")):
         raise ValueError(
             "the cache-free forward implements the repo's own block only; "
             "a configuration with head_dim, qk_norm, an untied head, "
-            "dropless experts, short-convolution layers or block diffusion "
-            "is served by the paged programs (models/generate.py)")
+            "dropless experts, dense gated layers, short-convolution or "
+            "retention layers or block diffusion is served by the paged "
+            "programs (models/generate.py)")
     x = params["embed"][tokens]  # [B,S,D]
     lb_total = jnp.float32(0.0)
     for i in range(cfg.n_layers):

@@ -151,6 +151,15 @@ def _pow2(n: int) -> int:
 _DECODE_TABLE_ENTRIES = 4096
 
 
+def _device_memory_bytes() -> Optional[int]:
+    """What the first device says it can hold, or None where the backend
+    does not say (the CPU)."""
+    import jax
+
+    stats = jax.devices()[0].memory_stats() or {}
+    return stats.get("bytes_limit")
+
+
 def _decode_table_width(inplace, rows: int, need: int, row_max: int) -> int:
     """Width of a decode round's block table: ``rows`` padded rows, the
     longest live row needing ``need`` blocks, a row never holding more than
@@ -665,8 +674,19 @@ class GenServer:
         # (models/generate.py init_block_pool): zero at position 0, carried
         # over chunks and rounds, freed with the block, recomputed from the
         # prompt after a preemption.  Nothing snapshots or rolls it back,
-        # so the lanes that would have to are refused by name
-        self._stateful = "c" in getattr(cfg, "layer_kinds", "")
+        # so the lanes that would have to are refused by name.  A
+        # power-retention layer ("r") keeps such a state too, 34 MB a row
+        # and a layer at the published widths where a convolution's is 8
+        # KB: every layer of such a generator is one, it holds no K/V, and
+        # what the pool holds is one state entry a BLOCK
+        kinds = set(getattr(cfg, "layer_kinds", ""))
+        self._stateful = bool(kinds & set("cr"))
+        self._holds = "state" if "r" in kinds else "KV"
+        # bytes one row's states are over the retention layers (0 without):
+        # what a decode step or a prefill chunk reads and writes a row
+        self._ret_row_bytes = 0
+        if "r" in kinds:
+            self._ret_row_bytes = self._refuse_states_that_cannot_fit()
         # layers whose FFN is dropless routed experts (not the leading
         # dense ones): what a pass's expert slots are counted over
         self._routed = getattr(cfg, "expert_layers", 0)
@@ -684,8 +704,9 @@ class GenServer:
                      "blocks, not the layers' state")):
                 if refused:
                     raise ValueError(
-                        "a generator with gated short-convolution layers "
-                        f"is served unified and cannot take {why}")
+                        "a generator with gated short-convolution or "
+                        "retention layers is served unified and cannot "
+                        f"take {why}")
         # bounded admission queue: sustained overload must fail typed
         # (retryable 503 via LoadShedError) with flat memory, never grow
         # the waiting deques without limit.  Generous by default — the
@@ -1207,7 +1228,7 @@ class GenServer:
             blocks = self._allocator.alloc(self._blocks_needed(P))
             if blocks is None:
                 raise RuntimeError(
-                    f"KV pool ({self.num_blocks} blocks) smaller than "
+                    f"{self._holds} pool ({self.num_blocks} blocks) smaller than "
                     f"the shared prefix ({self._blocks_needed(P)} blocks)")
             _, self._pool = paged_forward_jit(
                 self.params, jnp.asarray(ids), self._pool,
@@ -1218,6 +1239,38 @@ class GenServer:
             full = P // self.block_size
             self._prefix_blocks = blocks[:full]
             self._prefix_tail = blocks[full] if P % self.block_size else None
+
+    def _refuse_states_that_cannot_fit(self) -> int:
+        """A generator of retention layers holds one state entry a BLOCK
+        and a layer (models/generate.py init_block_pool): refuse a pool
+        whose entries the device's memory cannot hold beside the
+        parameters -- the default block of 16 positions and pool of a
+        thousand blocks are 35 GB a layer at the published widths -- and
+        say which two settings to change, where the allocation would only
+        fail.  Returns the bytes of one row's states over the layers."""
+        import jax
+
+        from seldon_core_tpu.models.generate import init_block_pool
+
+        shapes = jax.eval_shape(
+            lambda: init_block_pool(self.cfg, 1, self.block_size))
+        row = sum(a.size * a.dtype.itemsize
+                  for a in jax.tree.leaves(shapes))
+        limit = _device_memory_bytes()
+        held = sum(a.size * a.dtype.itemsize
+                   for a in jax.tree.leaves(self.params))
+        if limit and self.num_blocks * row + held > limit:
+            raise ValueError(
+                f"a generator of retention layers keeps a state of "
+                f"{row / 1e6:.1f} MB a BLOCK of the pool, whatever the "
+                f"block holds: {self.num_blocks} blocks are "
+                f"{self.num_blocks * row / 1e9:.1f} GB beside "
+                f"{held / 1e9:.1f} GB of parameters, and the device has "
+                f"{limit / 1e9:.1f} GB.  Deploy a block a row: set "
+                "SELDON_TPU_GEN_BLOCK_SIZE to the longest row (prompt + "
+                "answer + one round, a multiple of the prefill chunk) and "
+                "SELDON_TPU_GEN_POOL_BLOCKS to the rows held at once + 1")
+        return row
 
     def _new_carry(self):
         """A zeroed carry on the device (``_carry_ops``).  Under a mesh it is
@@ -1478,7 +1531,8 @@ class GenServer:
             # a token's own work in a layer, by the layer's kind
             # (LMConfig.kind): the mixer's matrices and the FFN's -- of an
             # expert layer the router and the token's moe_k experts
-            mixers = {"attn": d * qkv_out + q_out * d, "conv": 4 * d * d}
+            mixers = {"attn": d * qkv_out + q_out * d, "conv": 4 * d * d,
+                      "ret": d * (qkv_out + kvh) + q_out * d}
             ffns = {"gelu": 2 * d * ff, "moe": 2 * d * ff,
                     "gated": 3 * d * ff,
                     "experts": d * cfg.n_experts + (
@@ -1741,6 +1795,7 @@ class GenServer:
             "prefill_tokens": self._tick_prefill[3],
             "prefill_rows": self._tick_prefill[4],
             "prefill_carried_rows": self._tick_prefill[5],
+            "retention_row_bytes": self._ret_row_bytes,
             "kv_positions": self._tick_kv_pos,
             "kv_blocks": self._tick_kv_blocks,
             "kv_ages": tuple(ages),
@@ -2110,7 +2165,7 @@ class GenServer:
                     # is smaller than one request's first chunk
                     del self._waiting[idx]
                     self._finish_error(seq, RuntimeError(
-                        f"KV pool ({self.num_blocks} blocks of "
+                        f"{self._holds} pool ({self.num_blocks} blocks of "
                         f"{self.block_size}) cannot hold one prefill "
                         "chunk (grow SELDON_TPU_GEN_POOL_BLOCKS)"))
                     continue
@@ -2217,8 +2272,8 @@ class GenServer:
                         # Requeueing would livelock (admit -> prefill ->
                         # requeue at full device utilization, forever)
                         self._finish_error(seq, RuntimeError(
-                            f"KV pool ({self.num_blocks} blocks of "
-                            f"{self.block_size}) too small for prompt "
+                            f"{self._holds} pool ({self.num_blocks} blocks "
+                            f"of {self.block_size}) too small for prompt "
                             f"length {len(seq.prompt)} (grow "
                             "SELDON_TPU_GEN_POOL_BLOCKS)"))
                         continue
@@ -2466,7 +2521,7 @@ class GenServer:
                     # alone and cannot fit — surface a typed failure
                     self._active.remove(seq)
                     self._finish_error(seq, RuntimeError(
-                        "KV pool too small for sequence length "
+                        f"{self._holds} pool too small for sequence length "
                         f"{upto} (grow SELDON_TPU_GEN_POOL_BLOCKS)"))
                     return None
 

@@ -148,6 +148,15 @@ class GenPerf:
         self.prefill_tokens = 0        # prompt tokens the calls were given
         self.prefill_rows = 0          # real rows the calls were given
         self.prefill_carried_rows = 0  # ... that began from a carried state
+        # a generator of retention layers (a float32 matrix state a row and
+        # a layer, read and rewritten by every token): the bytes of state
+        # read + written by each kind of call -- the layers' bytes a row x
+        # the decode steps' real rows (``decode_row_passes``) and x the
+        # prefill calls' real rows (``prefill_rows``: a row of a call is one
+        # chunk through every layer; ``prefill_carried_rows`` of them began
+        # from a carried state)
+        self.retention_decode_bytes = 0
+        self.retention_prefill_bytes = 0
         self.decode_kv_positions = 0  # cache positions streamed per step
         self.kv_block_age = Reservoir(1024)   # seconds held at release
         self.kv_blocks_released = 0
@@ -237,6 +246,12 @@ class GenPerf:
             self.prefill_rows += int(detail.get("prefill_rows", 0) or 0)
             self.prefill_carried_rows += int(
                 detail.get("prefill_carried_rows", 0) or 0)
+            state = int(detail.get("retention_row_bytes", 0) or 0)
+            if state:
+                self.retention_decode_bytes += 2 * state * int(
+                    detail.get("row_passes", 0) or 0)
+                self.retention_prefill_bytes += 2 * state * int(
+                    detail.get("prefill_rows", 0) or 0)
             for n_blocks, age_s in kv_ages:
                 self.kv_blocks_released += int(n_blocks)
                 self.kv_block_age.observe(float(age_s))
@@ -299,6 +314,10 @@ class GenPerf:
                 # x layers x passes: 0 / 0 without experts
                 "experts_read": self.decode_experts_read,
                 "expert_slots": self.decode_expert_slots,
+                # a generator of retention layers: the bytes of state
+                # that ``row_passes`` read + wrote (0 for every other
+                # generator)
+                "retention_state_bytes": self.retention_decode_bytes,
             }
         out: Dict[str, Any] = {
             "decode_device_s": round(dev_s, 4),
@@ -448,6 +467,10 @@ class GenPerf:
                 # generator with short-convolution layers; 0 without)
                 "rows": self.prefill_rows,
                 "carried_rows": self.prefill_carried_rows,
+                # a generator of retention layers: a real row of a call is
+                # one chunk through every layer's state, and these are the
+                # bytes ``rows`` read + wrote (0 otherwise)
+                "retention_state_bytes": self.retention_prefill_bytes,
             }
         doc["served_decode"] = self.served_decode()
         return doc

@@ -1,0 +1,588 @@
+"""The served path of a generator of power-retention layers (Brumby-14B-
+Base's mechanism, bench/configs/brumby-14b.json: a float32 matrix state a
+row, a KV head and a layer, kept at the row's first block's id, read and
+rewritten by every token; a gated decay; gated-SiLU FFNs without experts;
+an untied head) at a tiny size on the CPU, in float32: the recurrent form
+(a decode step), the chunk form (a prefill chunk), the static lane and
+``GenServer`` against the plain reference of bench/archs/brumby/, which is
+the ATTENTION form and shares no code with them.
+
+Tolerances: logits within 1e-4 of values of order 1 (both sides float32,
+the reference at ``highest``; what differs is the order of the sums);
+tokens exactly -- an argmax flips only on a tie of two float32 logits,
+which these seeds do not have."""
+
+import importlib.util
+import json
+import os
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from seldon_core_tpu.models import generate as G
+from seldon_core_tpu.models.generate import (
+    TransformerGenerator,
+    generate,
+    init_block_pool,
+    paged_copy_block_jit,
+    paged_decode_round_jit,
+    paged_forward_jit,
+    paged_spec_round,
+    stream_chunks,
+)
+from seldon_core_tpu.models.transformer import LMConfig, lm_apply
+from seldon_core_tpu.ops import retention as R
+from seldon_core_tpu.runtime import genserver
+from seldon_core_tpu.runtime.genserver import GenServer
+from seldon_core_tpu.utils.genperf import GENPERF
+from seldon_core_tpu.utils.hotrecord import SPINE
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def _reference():
+    path = os.path.join(REPO, "bench", "archs", "brumby", "reference.py")
+    spec = importlib.util.spec_from_file_location("brumby_reference", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+REF = _reference()
+LAYERS, KV, HD = 3, 2, 16
+P = R.phi_width(HD)                 # 144 lanes, of which 136 hold a product
+
+
+def config():
+    """The configuration file's keys at a tiny size (what the reference
+    reads) and the unit built from them as the deployment builds it."""
+    doc = dict(hidden_size=32, num_attention_heads=4, num_key_value_heads=KV,
+               head_dim=HD, num_hidden_layers=LAYERS, intermediate_size=48,
+               rope_theta=1000000.0, rms_norm_eps=1e-6, vocab_size=96,
+               retention_degree=2, retention_eps=1e-6)
+    unit = TransformerGenerator(
+        vocab=96, d_model=32, n_heads=4, n_kv_heads=KV, head_dim=HD,
+        n_layers=LAYERS, layer_kinds="r" * LAYERS, dense_layers=LAYERS,
+        d_ff=48, qk_norm=True, tie_embeddings=False, norm_eps=1e-6,
+        rope_base=1000000.0, dtype="float32", seed=7)
+    return doc, unit
+
+
+@pytest.fixture(scope="module")
+def model():
+    doc, unit = config()
+    return doc, unit, unit.init_state(None)["params"]
+
+
+def prompts(lens, seed=0):
+    rng = np.random.default_rng(seed)
+    return [rng.integers(0, 96, n).astype(np.int32) for n in lens]
+
+
+def reference_logits(params, ids, doc):
+    """The reference's logits after EVERY position of one row."""
+    ids = np.asarray(ids, np.int32)[None]
+    return np.asarray(REF.forward(
+        params, jnp.asarray(ids), doc, jnp.arange(ids.shape[1])[None],
+        jnp.asarray([ids.shape[1]]))[0])
+
+
+def reference_answer(params, prompt, doc, max_new):
+    """Greedy, every token from a whole forward pass of the reference over
+    the row so far: no cache, no state."""
+    seq = [int(t) for t in prompt]
+    for _ in range(max_new):
+        seq.append(int(reference_logits(params, seq, doc)[-1].argmax()))
+    return np.asarray(seq[len(prompt):], np.int32)
+
+
+# a block a row, as such a generator is deployed: block 0 is the scratch
+# entry, rows 0 and 1 hold blocks 1 and 2 of 32 positions
+TABLES = jnp.asarray([[1], [2]], jnp.int32)
+BS, BLOCKS = 32, 4
+
+
+def chunked(unit, params, rows, chunk, tables, pool=None):
+    """``rows`` prefilled ``chunk`` tokens a call as the scheduler does:
+    rows of unequal length in one call, the shorter ones right-padded, a
+    row that is through riding along with width 0.  Returns each row's
+    logits from the call that consumed its last token, and the pool."""
+    if pool is None:
+        pool = init_block_pool(unit.cfg, BLOCKS, BS)
+    lens = [len(r) for r in rows]
+    out = [None] * len(rows)
+    for lo in range(0, max(lens), chunk):
+        toks = np.zeros((len(rows), chunk), np.int32)
+        width = np.zeros((len(rows),), np.int32)
+        start = np.zeros((len(rows),), np.int32)
+        for i, r in enumerate(rows):
+            w = max(0, min(chunk, lens[i] - lo))
+            toks[i, :w] = r[lo:lo + w]
+            width[i], start[i] = w, min(lo, lens[i])
+        logits, pool = paged_forward_jit(
+            params, jnp.asarray(toks), pool, tables, jnp.asarray(start),
+            jnp.asarray(width), cfg=unit.cfg, last_only=True)
+        for i in range(len(rows)):
+            if width[i] and lo + width[i] == lens[i]:
+                out[i] = np.asarray(logits[i])
+    return np.stack(out), pool
+
+
+def decode(unit, params, pool, tables, token, n_valid, active, span):
+    B = len(token)
+    return paged_decode_round_jit(
+        params, pool, tables, jnp.asarray(token, jnp.int32),
+        jnp.asarray(n_valid, jnp.int32), jnp.asarray(active, bool),
+        jnp.zeros((B,), bool), jnp.zeros((B,), jnp.uint32), unit.cfg,
+        span=span, temperature=0.0, top_k=0, top_p=0.0, eos_token=-1)
+
+
+# -- the expansion and the parameters ----------------------------------------
+
+
+def test_phi_of_q_dot_phi_of_k_is_the_square_of_q_dot_k():
+    rng = np.random.default_rng(0)
+    for d in (2, 16, 128):
+        q, k = rng.normal(size=(2, 3, d)).astype(np.float32)
+        fq, fk = np.asarray(R.phi(q), np.float64), np.asarray(R.phi(k),
+                                                              np.float64)
+        assert fq.shape == (3, R.phi_width(d)) == (3, (d // 2 + 1) * d)
+        np.testing.assert_allclose(
+            (fq * fk).sum(-1), (q.astype(np.float64) * k).sum(-1) ** 2,
+            rtol=1e-5)
+        # d (d + 1) / 2 lanes hold a product, the last half block none
+        assert (np.asarray(R._diagonal_weights(d)) > 0).sum() == (
+            d * (d + 1) // 2)
+    assert R.phi_width(128) == 8320
+
+
+def test_the_pool_holds_one_state_a_block_and_no_kv(model):
+    doc, unit, params = model
+    pool = init_block_pool(unit.cfg, BLOCKS, BS)
+    for i in range(LAYERS):
+        assert {k: (v.shape, v.dtype) for k, v in pool[f"l{i}"].items()} == {
+            "s": ((BLOCKS, KV * HD, P), jnp.float32),
+            "z": ((BLOCKS, KV, P), jnp.float32)}
+    assert G._pool_kv(pool) is None
+    assert not G.decode_inplace(pool, heads=4, rows=2)
+    lp = params["l0"]
+    assert lp["ret_gate"].shape == (32, KV)
+    # the gate's bias is float32 and far from zero: a decay of 0.99-0.9995
+    bias = np.asarray(lp["ret_gate_b"])
+    assert lp["ret_gate_b"].dtype == jnp.float32
+    assert bias.min() >= 4.6 and bias.max() <= 7.6
+    # every FFN is the dense gated one, no layer holds experts or K/V
+    assert unit.cfg.kinds == (("ret", "gated"),) * LAYERS
+    assert "w3" in lp and "router" not in lp and unit.cfg.expert_layers == 0
+    assert params["lm_head"].shape == (32, 96)
+    # the static lane's pool is a block a row too
+    pool, tables = G.private_pool(unit.cfg, 3, 50)
+    assert pool["l0"]["s"].shape[0] == 4 and tables.tolist() == [[1], [2],
+                                                                 [3]]
+
+
+# -- the programs against the reference ------------------------------------
+
+
+def test_whole_prefill_gives_the_references_logits_at_every_position(model):
+    doc, unit, params = model
+    row = prompts([13], seed=1)[0]
+    pool = init_block_pool(unit.cfg, BLOCKS, BS)
+    logits, _ = paged_forward_jit(
+        params, jnp.asarray(row[None]), pool, TABLES[:1],
+        jnp.zeros((1,), jnp.int32), jnp.asarray([13], jnp.int32),
+        cfg=unit.cfg, last_only=False)
+    np.testing.assert_allclose(np.asarray(logits[0]),
+                               reference_logits(params, row, doc),
+                               atol=1e-4, rtol=0)
+
+
+@pytest.mark.parametrize("chunk", [1, 4, 5, 16])
+def test_chunked_prefill_then_decode_rounds_equal_the_reference(model,
+                                                                chunk):
+    """Two prompts of 13 and 8 tokens (unequal, so every call but a whole
+    one has pad positions or a row of width 0) through the recurrent form
+    alone (chunk 1), the chunk form at a size that divides neither (5), one
+    that divides the shorter (4) and one call for everything (16); then two
+    decode rounds through the state, teacher-checked: every token is the
+    argmax of the reference's whole causal pass over the row so far."""
+    doc, unit, params = model
+    rows = prompts([13, 8], seed=2)
+    logits, pool = chunked(unit, params, rows, chunk, TABLES)
+    for i, r in enumerate(rows):
+        np.testing.assert_allclose(
+            logits[i], reference_logits(params, r, doc)[-1], atol=1e-4,
+            rtol=0)
+    first = logits.argmax(-1).astype(np.int32)
+    n_valid = np.asarray([13, 8], np.int32)
+    got = [first[:, None]]
+    token = first
+    for _ in range(2):
+        toks, pool, token, n_valid, *_ = decode(
+            unit, params, pool, TABLES, token, n_valid, [True, True], 4)
+        got.append(np.asarray(toks))
+    got = np.concatenate(got, axis=1)
+    for i, r in enumerate(rows):
+        np.testing.assert_array_equal(
+            got[i], reference_answer(params, r, doc, 9))
+    # and the state the rounds left is the row's: one more token through a
+    # chunk of two positions (one of them pad) lands on the reference
+    seq = np.concatenate([rows[0], got[0]])
+    nxt, _ = paged_forward_jit(
+        params, jnp.asarray([[seq[-1], 0]], jnp.int32), pool, TABLES[:1],
+        jnp.asarray([len(seq) - 1], jnp.int32), jnp.asarray([1], jnp.int32),
+        cfg=unit.cfg, last_only=True)
+    np.testing.assert_allclose(np.asarray(nxt[0]),
+                               reference_logits(params, seq, doc)[-1],
+                               atol=1e-4, rtol=0)
+
+
+def test_padded_rows_touch_nothing_but_the_scratch_entry(model):
+    """A decode round and a prefill chunk with an empty slot whose table is
+    all zeros (what the scheduler pads with): the live row's tokens are
+    what they are alone, the other row's state is as its prefill left it,
+    and nothing but entry 0 could have been written for the pad."""
+    doc, unit, params = model
+    rows = prompts([9, 6], seed=3)
+    logits, pool = chunked(unit, params, rows, 16, TABLES)
+    before = jax.tree.map(np.asarray, pool)
+    tables = jnp.asarray([[1], [0]], jnp.int32)
+    toks, pool, *_ = decode(
+        unit, params, pool, tables, [int(logits[0].argmax()), 0], [9, 0],
+        [True, False], 4)
+    np.testing.assert_array_equal(
+        np.asarray(toks)[0], reference_answer(params, rows[0], doc, 5)[1:])
+    assert not np.asarray(toks)[1].any()
+    _, pool = paged_forward_jit(
+        params, jnp.zeros((2, 4), jnp.int32), pool, tables,
+        jnp.asarray([13, 0], jnp.int32), jnp.asarray([4, 0], jnp.int32),
+        cfg=unit.cfg, last_only=True)
+    for i in range(LAYERS):
+        for name in ("s", "z"):
+            after = np.asarray(pool[f"l{i}"][name])
+            np.testing.assert_array_equal(after[2:], before[f"l{i}"][name][2:])
+            assert np.abs(after[1] - before[f"l{i}"][name][1]).max() > 0
+
+
+def test_a_block_reused_after_a_longer_row_gives_what_a_fresh_one_gives(
+        model):
+    """A sequence that starts at position 0 reads a zero state whatever its
+    block held: after a longer sequence's prefill and a round over the same
+    block, a new prompt there gives the reference's logits -- bit for bit
+    what a fresh pool gives."""
+    doc, unit, params = model
+    old, new = prompts([21, 7], seed=4)
+    logits, pool = chunked(unit, params, [old], 4, TABLES[:1])
+    _, pool, *_ = decode(unit, params, pool, TABLES[:1],
+                         [int(logits[0].argmax())], [21], [True], 4)
+    assert float(jnp.abs(pool["l0"]["s"][1]).max()) > 0
+    reused, _ = chunked(unit, params, [new], 3, TABLES[:1], pool=pool)
+    fresh, _ = chunked(unit, params, [new], 3, TABLES[:1])
+    np.testing.assert_array_equal(reused, fresh)
+    np.testing.assert_allclose(
+        reused[0], reference_logits(params, new, doc)[-1], atol=1e-4, rtol=0)
+
+
+def test_copying_a_block_copies_the_state_kept_at_its_id(model):
+    doc, unit, params = model
+    row = prompts([6], seed=5)[0]
+    _, pool = chunked(unit, params, [row], 16, TABLES[:1])
+    want = {n: np.asarray(pool["l1"][n][1]) for n in ("s", "z")}
+    pool = paged_copy_block_jit(pool, jnp.int32(1), jnp.int32(3))
+    for n in ("s", "z"):
+        np.testing.assert_array_equal(np.asarray(pool["l1"][n][3]), want[n])
+
+
+def test_static_lane_gives_the_reference_answer(model):
+    doc, unit, params = model
+    rows = np.stack(prompts([10, 10], seed=6))
+    want = np.stack([reference_answer(params, r, doc, 11) for r in rows])
+    np.testing.assert_array_equal(np.asarray(generate(
+        params, jnp.asarray(rows), unit.cfg, max_new_tokens=11)), want)
+    chunks = list(stream_chunks(params, jnp.asarray(rows), unit.cfg,
+                                max_new_tokens=11, chunk=4))
+    np.testing.assert_array_equal(np.concatenate(chunks, axis=1), want)
+
+
+# -- what must fail -----------------------------------------------------------
+
+
+INTACT = R.retention
+
+
+def gate_dropped(q, k, v, log_g, state, slot, start, width):
+    return INTACT(q, k, v, jnp.zeros_like(log_g), state, slot, start, width)
+
+
+def gate_applied_after_the_update(q, k, v, log_g, state, slot, start, width):
+    # S_t = g_t (S_(t-1) + phi(k_t) v_t^T): the token's own term decays too
+    # (phi is quadratic: sqrt(g) on k is g on phi(k))
+    k = (k * jnp.exp(log_g / 2)[..., None]).astype(k.dtype)
+    return INTACT(q, k, v, log_g, state, slot, start, width)
+
+
+def normaliser_left_out(q, k, v, log_g, state, slot, start, width):
+    # the normaliser's column of the state is not carried from call to call
+    return INTACT(q, k, v, log_g,
+                  {**state, "z": jnp.zeros_like(state["z"])}, slot, start,
+                  width)
+
+
+@pytest.mark.parametrize("fault", [
+    None, gate_dropped, gate_applied_after_the_update, normaliser_left_out])
+def test_a_program_that_leaves_out_part_of_the_layer_fails(model, fault,
+                                                           monkeypatch):
+    """Prefill in chunks of 4 and one round, against the reference: the
+    program as it is lies within 1e-4 and emits the reference's tokens; the
+    gate dropped, the gate applied to the wrong side of the update and the
+    normaliser not carried each read a hundred times that or more."""
+    doc, unit, params = model
+    row = prompts([13], seed=8)[0]
+    want = reference_logits(params, row, doc)[-1]
+    jax.clear_caches()
+    if fault is not None:
+        monkeypatch.setattr(R, "retention", fault)
+    try:
+        logits, pool = chunked(unit, params, [row], 4, TABLES[:1])
+        toks, *_ = decode(unit, params, pool, TABLES[:1],
+                          [int(want.argmax())], [13], [True], 4)
+    finally:
+        jax.clear_caches()
+    err = np.abs(logits[0] - want).max()
+    answer = reference_answer(params, row, doc, 5)[1:]
+    if fault is None:
+        assert err < 1e-4
+        np.testing.assert_array_equal(np.asarray(toks)[0], answer)
+    else:
+        assert err > 1e-2, (fault.__name__, err)
+
+
+# -- the unit's description of its layers -----------------------------------
+
+
+def test_the_unit_built_from_the_configuration_file_has_its_kinds():
+    with open(os.path.join(REPO, "bench", "configs",
+                           "brumby-14b.json")) as f:
+        doc = json.load(f)
+    p = doc["unit"]["parameters"]
+    assert p["layer_kinds"] == "r" * doc["num_hidden_layers"]
+    assert p["dense_layers"] == p["n_layers"] == {
+        "from": "num_hidden_layers"}
+    assert (doc["retention_degree"], doc["retention_eps"]) == (2, R.EPS)
+    cfg = LMConfig(
+        vocab=doc["vocab_size"], d_model=doc["hidden_size"],
+        n_heads=doc["num_attention_heads"],
+        n_kv_heads=doc["num_key_value_heads"], head_dim=doc["head_dim"],
+        n_layers=doc["num_hidden_layers"], layer_kinds=p["layer_kinds"],
+        dense_layers=doc["num_hidden_layers"], d_ff=doc["intermediate_size"],
+        qk_norm=p["qk_norm"], tie_embeddings=doc["tie_word_embeddings"])
+    assert set(cfg.kinds) == {("ret", "gated")} and cfg.hd == 128
+    shapes = jax.eval_shape(lambda: init_block_pool(
+        cfg, doc["deployment"]["pool_blocks"],
+        doc["deployment"]["block_size"]))
+    assert shapes["l0"]["s"].shape == (17, 8 * 128, 8320)
+    assert shapes["l0"]["z"].shape == (17, 8, 8320)
+
+
+def test_a_program_traces_the_block_once_and_names_its_stages(model):
+    doc, unit, params = model
+    lowered = paged_decode_round_jit.lower(
+        params, init_block_pool(unit.cfg, BLOCKS, BS), TABLES,
+        jnp.zeros((2,), jnp.int32), jnp.asarray([5, 8], jnp.int32),
+        jnp.ones((2,), bool), jnp.zeros((2,), bool),
+        jnp.zeros((2,), jnp.uint32), unit.cfg, span=4, temperature=0.0,
+        top_k=0, top_p=0.0, eos_token=-1)
+    assert len(set(re.findall(r"func\.func private @(_paged_block\w*)\(",
+                              lowered.as_text()))) == 1
+    text = "\n".join(re.findall(
+        r'op_name="([^"]*)"', lowered.compile().as_text())).replace(
+            "jit(_paged_block)/", "")
+    for scope in ("ret_in/", "retention/", "ret_out/", "ffn/", "unembed/"):
+        assert "/" + scope in text, scope
+    for scope in ("kv_write/", "kv_gather/", "attn/"):
+        assert "/" + scope not in text, scope
+
+
+# -- lanes that cannot hold the state ----------------------------------------
+
+
+def test_lanes_that_cannot_hold_the_state_refuse_by_name(model, monkeypatch):
+    doc, unit, params = model
+    spec = unit.continuous_spec({"params": params})
+    kw = {"block_size": BS, "num_blocks": 4, "slots": 2, "span": 4,
+          "prefill_chunk": 8}
+    draft = TransformerGenerator(vocab=96, d_model=32, n_heads=4, n_layers=1,
+                                 d_ff=32, dtype="float32")
+    d_params = draft.init_state(None)["params"]
+    with pytest.raises(ValueError, match="speculative decoding"):
+        GenServer(**spec, draft_params=d_params, draft_cfg=draft.cfg, **kw)
+    with pytest.raises(ValueError, match="shared prefix"):
+        GenServer(**{**spec, "prefix_ids": np.asarray([1, 2, 3])}, **kw)
+    for role in ("prefill", "decode"):
+        with pytest.raises(ValueError, match="prefill / decode roles"):
+            GenServer(**spec, role=role, **kw)
+    small = dict(vocab=96, d_model=32, n_heads=4, n_layers=2, dense_layers=2)
+    with pytest.raises(ValueError, match="shared prefix"):
+        TransformerGenerator(**small, layer_kinds="rr", prefix_tokens="1,2")
+    with pytest.raises(ValueError, match="one chip"):
+        TransformerGenerator(**small, layer_kinds="rr", mesh=object())
+    with pytest.raises(ValueError, match="roll the layer's state back"):
+        paged_spec_round(params, d_params, init_block_pool(unit.cfg, 4, BS),
+                         init_block_pool(draft.cfg, 16, 4), TABLES, TABLES,
+                         jnp.zeros((2,), jnp.int32),
+                         jnp.zeros((2,), jnp.int32), jnp.ones((2,), bool),
+                         unit.cfg, draft.cfg, k=2)
+    # a state is found where a row's first block is: a layer that holds K/V
+    # beside it needs a table of states smaller than the pool
+    with pytest.raises(ValueError, match="EVERY layer"):
+        LMConfig(n_layers=2, layer_kinds="ra", dense_layers=2)
+    with pytest.raises(ValueError, match="denoising passes"):
+        LMConfig(vocab=96, n_layers=2, layer_kinds="rr", block_length=4,
+                 denoising_steps=4, mask_id=5)
+    # a dense gated FFN in every layer or in the leading ones of an expert
+    # model, nothing between
+    with pytest.raises(ValueError, match="dense_layers"):
+        LMConfig(n_layers=3, layer_kinds="rrr", dense_layers=2)
+    with pytest.raises(ValueError, match="cache-free forward"):
+        lm_apply(params, jnp.zeros((1, 4), jnp.int32), unit.cfg)
+    # a pool of default size would hold a state a block of 16 positions:
+    # refused at boot with the two settings to change, not left to the
+    # allocator
+    monkeypatch.setattr(genserver, "_device_memory_bytes", lambda: 1 << 20)
+    with pytest.raises(ValueError, match="SELDON_TPU_GEN_BLOCK_SIZE.*"
+                                         "SELDON_TPU_GEN_POOL_BLOCKS"):
+        GenServer(**spec, **{**kw, "block_size": 16, "num_blocks": 1024})
+    monkeypatch.setattr(genserver, "_device_memory_bytes", lambda: None)
+    srv = GenServer(**spec, **kw)
+    assert srv._ret_row_bytes == LAYERS * 4 * (KV * HD + KV) * P
+    srv.stop()
+
+
+# -- GenServer ----------------------------------------------------------------
+
+
+@pytest.fixture()
+def clean_genperf():
+    SPINE.drain()
+    SPINE.reset()
+    GENPERF.reset()
+    yield
+    SPINE.drain()
+    SPINE.reset()
+    GENPERF.reset()
+
+
+def server(unit, params, **kw):
+    kw = {"block_size": BS, "num_blocks": 5, "slots": 4, "span": 4,
+          "prefill_chunk": 8, **kw}
+    return GenServer(**unit.continuous_spec({"params": params}), **kw)
+
+
+def settled(tokens):
+    """``/genperf`` once the tick that emitted the last of ``tokens`` has
+    published its record."""
+    import time
+
+    deadline = time.monotonic() + 10
+    while True:
+        SPINE.drain()
+        doc = GENPERF.document()
+        if (doc["served_decode"]["real_tokens"] >= tokens
+                or time.monotonic() > deadline):
+            return doc
+        time.sleep(0.02)
+
+
+def test_genserver_serves_the_reference_answer_a_block_a_row_and_counts(
+        model, clean_genperf, monkeypatch):
+    """Rows of different lengths co-scheduled a block a row (every table is
+    one column wide), prompts of one chunk and of three (the state carried
+    over chunks), unary and streamed -- and what the server says of the
+    state's traffic."""
+    doc, unit, params = model
+    monkeypatch.setenv("SELDON_TPU_GEN_PREFILL_CHUNK_MAX", "8")
+    srv = server(unit, params)
+    try:
+        cases = [(3, 6), (8, 9), (19, 7)]
+        reqs = []
+        for n, max_new in cases:
+            rows = np.stack(prompts([n], seed=20 + n))
+            reqs.append((rows, max_new, srv.submit(rows, max_new=max_new)))
+        for rows, max_new, req in reqs:
+            want = np.stack([reference_answer(params, r, doc, max_new)
+                             for r in rows])
+            np.testing.assert_array_equal(
+                req.future.result(timeout=180), want)
+        rows = np.stack(prompts([19], seed=39))
+        chunks = list(srv.stream(rows, chunk=3, max_new=7))
+        np.testing.assert_array_equal(
+            np.concatenate(chunks, 1),
+            np.stack([reference_answer(params, r, doc, 7) for r in rows]))
+        perf = settled(6 + 9 + 7 + 7)
+        snap = srv.snapshot()
+        assert snap["tick_errors_total"] == 0
+        assert {p[-1] for kind in ("prefill", "decode")
+                for p in srv._programs[kind]} == {1}
+    finally:
+        srv.stop()
+    prefill, served = perf["served_prefill"], perf["served_decode"]
+    state = LAYERS * 4 * (KV * HD + KV) * P     # a row's, over the layers
+    # 19 tokens at chunk 8 are three chunks, the later two carried
+    assert prefill["rows"] == 1 + 1 + 3 + 3
+    assert prefill["carried_rows"] == 2 + 2
+    assert prefill["retention_state_bytes"] == 2 * state * 8
+    assert served["row_passes"] > 0
+    assert served["retention_state_bytes"] == (
+        2 * state * served["row_passes"])
+    assert served["inplace_steps"] == 0
+
+
+def test_genserver_preempts_and_readmits_mid_answer(model):
+    """Blocks of 4 positions and a pool too small for two whole rows (a toy
+    deployment: at the published widths a block is a row and no row ever
+    outgrows it): the younger row is evicted, its blocks -- and the state
+    kept at its first block's id -- go back, and on readmission it is
+    recomputed from the prompt and the tokens it had emitted, from a zero
+    state at position 0: the answer of an uninterrupted run."""
+    doc, unit, params = model
+    rows = prompts([6, 6], seed=31)
+    want = [reference_answer(params, r, doc, 18) for r in rows]
+    srv = server(unit, params, block_size=4, num_blocks=11)
+    try:
+        reqs = [srv.submit(r[None], max_new=18) for r in rows]
+        for req, w in zip(reqs, want):
+            np.testing.assert_array_equal(
+                req.future.result(timeout=240)[0], w)
+        assert srv.snapshot()["preempted_total"] >= 1
+    finally:
+        srv.stop()
+
+
+def test_a_pool_that_cannot_hold_a_row_says_what_it_holds(model):
+    doc, unit, params = model
+    srv = server(unit, params, block_size=4, num_blocks=3)
+    try:
+        with pytest.raises(RuntimeError, match="state pool"):
+            srv.submit(prompts([24], seed=1)[0][None],
+                       max_new=4).future.result(timeout=120)
+    finally:
+        srv.stop()
+
+
+def test_a_generator_without_retention_counts_none(clean_genperf):
+    unit = TransformerGenerator(vocab=48, d_model=32, n_heads=4, n_layers=2,
+                                d_ff=64, dtype="float32")
+    srv = GenServer(**unit.continuous_spec(unit.init_state(None)),
+                    block_size=4, num_blocks=32, slots=2, span=4,
+                    prefill_chunk=4)
+    try:
+        srv.submit(np.arange(10)[None], max_new=5).future.result(timeout=180)
+        perf = settled(5)
+    finally:
+        srv.stop()
+    assert perf["served_prefill"]["rows"] == 3
+    for block in ("served_prefill", "served_decode"):
+        assert perf[block]["retention_state_bytes"] == 0
