@@ -74,6 +74,7 @@ def _eager(x) -> bool:
 
 __all__ = ["generate", "stream_chunks", "sample_token", "mask_after_eos",
            "init_block_pool", "private_pool", "decode_inplace",
+           "retention_fused",
            "paged_forward", "paged_decode_round", "paged_spec_round",
            "paged_copy_block", "TransformerGenerator"]
 
@@ -545,7 +546,8 @@ def _project_qkv(lp, x, positions, cfg: LMConfig, scope=jax.named_scope):
     return h, q, k, v
 
 
-def _retention(lp, x, pool_layer, tables, start, valid, cfg: LMConfig):
+def _retention(lp, x, pool_layer, tables, start, valid, cfg: LMConfig,
+               fused=False):
     """The power-retention mixer on x [B, W, D] -> (x', pool layer'):
     ``q, k, v`` as attention projects them (``wqkv``; the per-head norms
     under ``cfg.qk_norm``, the rotary embedding under ``cfg.rope``) and
@@ -554,7 +556,12 @@ def _retention(lp, x, pool_layer, tables, start, valid, cfg: LMConfig):
     for a call of one position a row, the chunk form otherwise.  A row's
     state is ``pool_layer`` at ``tables[b, 0]``, zero for a row that starts
     at 0; pad positions (``valid`` False, to the right of the valid ones)
-    enter nothing, and a row with no valid position is skipped whole."""
+    enter nothing, and a row with no valid position is skipped whole.
+    ``fused`` is ``retention``'s: whether a call of one position a row
+    updates the state where it lies (the kernel of ops/retention.py).  It
+    rides the calls -- this function's and ``retention``'s -- only where it
+    says so: the call of seven here and of eight there is what the
+    benchmark's fault injectors wrap (tests/bench/test_bench_brumby.py)."""
     from seldon_core_tpu.ops.quant import lm_matmul
     from seldon_core_tpu.ops.retention import retention
 
@@ -575,7 +582,7 @@ def _retention(lp, x, pool_layer, tables, start, valid, cfg: LMConfig):
         width = jnp.sum(jnp.broadcast_to(valid, (B, W)), axis=1)
         y, pool_layer = retention(
             q.reshape(B, kv_h, H // kv_h, W, hd), k, v, log_g, pool_layer,
-            tables[:, 0], start, width)
+            tables[:, 0], start, width, **({"fused": fused} if fused else {}))
         y = y.reshape(B, H, W, hd).transpose(0, 2, 1, 3).reshape(B, W, q_out)
     with jax.named_scope("ret_out"):
         x = x + lm_matmul(lp, "wo", y, out_dtype=x.dtype)
@@ -594,11 +601,12 @@ def _retention(lp, x, pool_layer, tables, start, valid, cfg: LMConfig):
 # plain body.
 @functools.partial(
     jax.jit,
-    static_argnames=("cfg", "interpret", "kv_only", "write", "kind"))
+    static_argnames=("cfg", "interpret", "kv_only", "write", "kind",
+                     "fused"))
 def _paged_block(lp, x, pool_layer, tables, start, valid, cfg: LMConfig,
                  plan=None, interpret: bool = False, limit=None,
                  kv_only: bool = False, view=None, write: bool = True,
-                 kind=None):
+                 kind=None, fused: bool = False):
     """One decoder block over the paged pool: K/V written at per-row
     positions start[b] + i (scratch-routed where ``valid`` is False),
     attention over each row's own blocks.  x [B, W, D] -> (x', pool layer',
@@ -608,8 +616,10 @@ def _paged_block(lp, x, pool_layer, tables, start, valid, cfg: LMConfig,
     attention layer whose FFN is read off its weights): a "conv" layer
     takes ``_short_conv`` in the attention's place and a "ret" layer
     ``_retention`` -- its pool entry is the state, and what follows about
-    K/V does not concern it -- and the FFN is ``transformer._ffn``'s of
-    that kind.
+    K/V does not concern it; ``fused`` has its one-position step update
+    the state where it lies in the pool (the kernel of ops/retention.py,
+    under ``interpret`` in Pallas interpret mode) -- and the FFN is
+    ``transformer._ffn``'s of that kind.
 
     ``plan`` (ops.paged_attention.decode_plan) selects the in-place
     formulation: attention reads the row's blocks from the pool where they
@@ -642,8 +652,11 @@ def _paged_block(lp, x, pool_layer, tables, start, valid, cfg: LMConfig,
             return x + y, aux
 
     if mixer in ("conv", "ret"):
+        how = {}
+        if mixer == "ret" and fused:
+            how["fused"] = "interpret" if interpret else True
         x, pool_layer = (_short_conv if mixer == "conv" else _retention)(
-            lp, x, pool_layer, tables, start, valid, cfg)
+            lp, x, pool_layer, tables, start, valid, cfg, **how)
         x, aux = feed_forward(x)
         return x, pool_layer, aux
     # the stages below are jax.named_scope's: op metadata only (same
@@ -760,6 +773,26 @@ def decode_inplace(pool, mesh=None, width: int = 1, heads=None,
         heads=heads, rows=rows)
 
 
+def retention_fused(pool, mesh=None, heads=None, rows: int = 1) -> bool:
+    """Whether a decode step of a generator of retention layers updates
+    each live row's state where it lies in ``pool`` (the Pallas kernel of
+    ops/retention.py) or row by row in ``jax.numpy``: ops.retention
+    .step_supported over what is observable here, as ``decode_inplace``
+    asks for attention -- the backend, the state's dtype and shapes, the
+    caller's mesh, the query ``heads`` and padded ``rows`` of the widest
+    batch the caller will bring.  False for a pool without such layers."""
+    from seldon_core_tpu.ops.retention import step_supported
+
+    state = next((e for e in pool.values() if "s" in e), None)
+    if state is None:
+        return False
+    kv_heads = state["z"].shape[1]
+    return step_supported(
+        backend=jax.default_backend(), state_dtype=state["s"].dtype,
+        head_dim=state["s"].shape[1] // kv_heads, mesh=mesh,
+        kv_heads=kv_heads, heads=heads, rows=rows)
+
+
 def paged_decode_round(params, pool, tables, token, n_valid, active,
                        seen_eos, keys, cfg: LMConfig, *, span: int,
                        temperature: float, top_k: int, top_p: float,
@@ -774,7 +807,10 @@ def paged_decode_round(params, pool, tables, token, n_valid, active,
     this batch (a caller that shards the pool over a mesh passes its own
     answer: a traced program cannot see shardings); True / False force the
     kernel / the gather path; "interpret" runs the kernel in Pallas
-    interpret mode (tests on the CPU).
+    interpret mode (tests on the CPU).  For a generator of retention
+    layers the kernel is the state's (``retention_fused`` decides, False is
+    the row-by-row step of ops/retention.py): either way the answer says
+    whether the step works on the pool where it lies.
 
     token [B] pending tokens; n_valid [B] per-row cache length; active [B]
     masks empty slots (their writes go to scratch, their samples are
@@ -795,16 +831,18 @@ def paged_decode_round(params, pool, tables, token, n_valid, active,
             cfg, span=span, temperature=temperature, eos_token=eos_token,
             inplace=inplace, trace_passes=trace_passes)
     if inplace is None:
-        inplace = decode_inplace(pool, heads=cfg.n_heads,
-                                 rows=n_valid.shape[0], head_dim=cfg.hd)
+        inplace = (decode_inplace(pool, heads=cfg.n_heads,
+                                  rows=n_valid.shape[0], head_dim=cfg.hd)
+                   or retention_fused(pool, heads=cfg.n_heads,
+                                      rows=n_valid.shape[0]))
     kv = _pool_kv(pool)     # (a plan is made only where a layer attends)
     capacity = tables.shape[1] * kv["k"].shape[1] if kv else 0
-    inplace = inplace if kv else False
 
     def step(carry, _):
         pool, token, n_valid, seen_eos, keys, *read = carry
         # the kernel's scalar operands: once a step, shared by the layers
-        plan = decode_plan(n_valid, active, capacity) if inplace else None
+        plan = (decode_plan(n_valid, active, capacity)
+                if inplace and kv else None)
         with jax.named_scope("embed"):
             x = params["embed"][token][:, None, :]
         for i in range(cfg.n_layers):
@@ -812,6 +850,7 @@ def paged_decode_round(params, pool, tables, token, n_valid, active,
                 params[f"l{i}"], x, pool[f"l{i}"], tables, n_valid,
                 active[:, None], cfg, plan=plan,
                 interpret=inplace == "interpret", kind=cfg.kind(i),
+                fused=bool(inplace),
             )
             read = [r + aux for r in read]
         with jax.named_scope("unembed"):

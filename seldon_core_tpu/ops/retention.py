@@ -30,6 +30,46 @@ before the division; and the state the chunk leaves.  The two must agree
 with each other and with the plain reference's attention form
 (bench/archs/brumby/reference.py), which tests/test_brumby_block.py holds.
 
+Which code runs which form, and where:
+
+  ``_chunk``       every call wider than one position, on every backend:
+                   ``jax.numpy`` under ``retention``'s loop over live rows.
+  ``_fused_step``  a call of one position a row where ``step_supported``
+                   says so -- a TPU backend, a float32 state, a head of
+                   whole 128-lane registers, ``P`` a whole number of tiles
+                   that fit vector memory, no mesh -- decided by what the
+                   caller can observe (``generate.retention_fused`` for a
+                   decode round that is not told and for the scheduler,
+                   which alone sees a mesh): a Pallas TPU kernel,
+                   ONE call for the whole batch, that walks the live rows'
+                   KV heads tile by tile along ``P`` (13 diagonal blocks:
+                   ``[128, 1664]`` float32, 852 KB), DMAs a tile of ``S``
+                   from the pool's entry, builds ``phi(k)`` and ``phi(q)``
+                   of the head in vector memory from k and q (a rotation of
+                   the lanes, a product, the weight: no ``[KV, G, P]`` array
+                   is ever written), updates the tile, adds ``phi(q) . S'``
+                   and ``phi(q) . z'`` into float32 accumulators from the
+                   tile it still holds, and DMAs it back to the SAME entry
+                   (``input_output_aliases``: the programs donate the pool).
+                   The state is read once and written once.
+  ``_step``        the same step in ``jax.numpy``, row by row: the CPU, the
+                   static lane, any shape the kernel refuses -- and the
+                   oracle of the kernel's tests (float32 both, equal to the
+                   order of the sums).
+
+Why the step is a kernel and the chunk form is not: a step is bound by the
+state's bytes (34 MB a row a layer against 43 MFLOP), its floor is one read
+and one write, and XLA stays at 2.1 times that floor however the step is
+written in ``jax.numpy`` -- as above (0.183 ms a row a layer), with the
+read-out taken from the OLD state in the update's pass (0.169), as multiply
++ reduce in place of the dot (0.179) (PERF.md section 6, PR 41, call
+``P41b``): the update is one fusion (read + write, 660 GB/s), the read-out
+a second one that reads the new state AGAIN, and ``phi`` 65 small ones; the
+compiler does not keep a 4 MB head in vector memory between two fusions.
+The kernel's time is its DMA's (the same with the arithmetic taken out:
+PERF.md section 6, PR 42).  The chunk form is bound by compute and wants
+``phi(q)`` expanded tile by tile inside a matmul kernel: not done yet.
+
 ``phi``'s layout is by DIAGONALS: block s (s = 0 .. d/2) holds ``u_l *
 u_((l + s) mod d)`` at lane l -- each unordered pair at circular distance s
 once; the last block's second half would repeat its first and is zero.  So
@@ -43,15 +83,22 @@ The state is float32 (an accumulator over a whole row) and is found at
 ``{"s": [N, KV * d, P], "z": [N, KV, P]}``.  A row that starts at position
 0 starts from zero whatever its entry holds (a reused block needs no reset
 pass).  Rows are taken one after another, the LIVE ones only (``width`` >
-0): a padded row costs nothing and touches nothing, and the temporaries are
-one row's, not the batch's."""
+0; the kernel takes their indices and their count as scalar operands, as
+ops/paged_attention.py takes ``order`` and ``count``): a padded row costs
+nothing and touches nothing -- under the kernel not even the scratch entry
+-- and the temporaries are one row's, not the batch's."""
 
 from __future__ import annotations
+
+import functools
+from typing import Any, Optional
 
 import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 EPS = 1e-6
 
@@ -159,17 +206,333 @@ def _chunk(q, k, v, log_g, width, S, Z):
     return y, S, Z
 
 
-def retention(q, k, v, log_g, state, slot, start, width):
+# -- the recurrent form as ONE pass over the state: the kernel ---------------
+
+_LANES = 128                    # a vector register's lanes
+_TILE_BYTES = 1 << 20           # one tile of S in vector memory, at most
+_SLOTS = 3                      # tiles in flight: read, compute, written
+# the kernel takes the batch in whole groups of rows: a deployment's decode
+# programs (1, 2, 4, 8, 16 rows) then bind ONE trace of it, and a padded
+# row costs it nothing
+_ROW_GROUP = 16
+# what the kernel may hold in vector memory (ops/paged_attention.py allows
+# itself the same): the tiles, a row's normalisers in and out, phi of one
+# head's q and k, the read-out's accumulators, the batch's q, k, v and y
+_VMEM_BYTES = 12 * 1024 * 1024
+
+
+def blocks_per_tile(d: int) -> int:
+    """Diagonal blocks (``d`` lanes each) in one tile of ``S`` ``[d, tile]``:
+    the largest divisor of ``d/2 + 1`` whose tile is at most ``_TILE_BYTES``
+    (13 of 65 at d = 128: five tiles of 852 KB).  0: not even one fits."""
+    nb = d // 2 + 1
+    fit = [c for c in range(1, nb + 1)
+           if nb % c == 0 and 4 * d * d * c <= _TILE_BYTES]
+    return max(fit, default=0)
+
+
+def _rows_held(G: int) -> int:
+    """Rows of the kernel's small operand a KV head: its G queries, its key
+    and its value, in whole float32 sublane tiles."""
+    return -(-(G + 2) // 8) * 8
+
+
+def step_supported(*, backend: str, state_dtype: Any, head_dim: int,
+                   mesh: Optional[Any] = None, kv_heads: int = 1,
+                   heads: Optional[int] = None, rows: int = 1) -> bool:
+    """True where a call of one position a row runs the kernel
+    (``_fused_step``), False where it runs ``_step`` row by row.  Decided
+    from what the caller can observe, as ops.paged_attention
+    .inplace_supported decides for attention: a TPU backend (Mosaic), a
+    float32 state (what the kernel's tiles and its DMA are laid out for),
+    a head of whole 128-lane registers (a diagonal block of ``phi`` is a
+    rotation of them), ``P`` a whole number of tiles that fit vector
+    memory beside the batch's q, k, v and y (``rows`` padded rows of
+    ``heads`` query heads), and no mesh (a Mosaic call does not partition
+    under GSPMD)."""
+    if backend != "tpu" or mesh is not None:
+        return False
+    if jnp.dtype(state_dtype) != jnp.float32:
+        return False
+    if head_dim < _LANES or head_dim % _LANES:
+        return False
+    tile = blocks_per_tile(head_dim)
+    if tile == 0:
+        return False
+    G = (heads or kv_heads) // kv_heads
+    R, P = _rows_held(G), phi_width(head_dim)
+    rows = -(-rows // _ROW_GROUP) * _ROW_GROUP
+    held = 4 * (_SLOTS * head_dim * head_dim * tile     # the tiles of S
+                + 4 * kv_heads * P                      # Z in and out, x 2
+                + 8 * (G + 1) * P                       # phi of q and k
+                + (G + 1) * head_dim * head_dim         # accumulators, v
+                + 2 * rows * kv_heads * R * head_dim)   # q, k, v in, y out
+    return held <= _VMEM_BYTES
+
+
+def _step_kernel(slot_ref, order_ref, count_ref, fresh_ref, g_ref, x_ref,
+                 s_hbm, z_hbm, y_ref, so_hbm, zo_hbm, sbuf, zin, zout, fphi,
+                 vcol, acc, dacc, sems, *, G: int, tile: int):
+    """Every live row's KV heads, tile by tile along ``P``, as ONE pipeline
+    of ``count * KV * nt`` steps: tile tau is read into slot tau % 3 while
+    tau - 1 is computed and written back from where it lies.  ``s_hbm`` /
+    ``so_hbm`` (and ``z_hbm`` / ``zo_hbm``) are the same pool entry, aliased:
+    every tile is read once, before it is written once.  A row's
+    normalisers ``[KV, P]`` come and go whole, a row ahead and a row
+    behind.  At a head's first tile ``phi`` of its queries and its key is
+    built whole (65 diagonal blocks of one register a row) and the
+    read-out's accumulators are cleared; at its last, ``y`` is divided
+    out.  The arithmetic hides behind the DMA (PERF.md section 6, PR 42:
+    the same time with it taken out), so what is written here is written
+    to be traced and lowered quickly -- five decode programs a deployment
+    lower it at every boot -- not to save vector operations."""
+    _, KV, _, d = x_ref.shape
+    nt = zin.shape[1]
+    Pt = tile * d
+    f32 = jnp.float32
+    root2 = np.float32(np.sqrt(2.0))
+    count = count_ref[0]
+    steps = count * (KV * nt)
+
+    y_ref[...] = jnp.zeros_like(y_ref)
+
+    def at(tau):
+        r = jax.lax.div(tau, KV * nt)
+        rest = tau - r * (KV * nt)
+        kv = jax.lax.div(rest, nt)
+        return r, kv, rest - kv * nt
+
+    def s_copy(tau, write: bool):
+        r, kv, t = at(tau)
+        c = jax.lax.rem(tau, _SLOTS)
+        where = (slot_ref[order_ref[r]],
+                 pl.ds(pl.multiple_of(kv * d, d), d),
+                 pl.ds(pl.multiple_of(t * Pt, Pt), Pt))
+        if write:
+            return pltpu.make_async_copy(sbuf.at[c], so_hbm.at[where],
+                                         sems.at[1, c])
+        return pltpu.make_async_copy(s_hbm.at[where], sbuf.at[c],
+                                     sems.at[0, c])
+
+    def z_copies(r, write: bool, fn):
+        blk, c = slot_ref[order_ref[r]], jax.lax.rem(r, 2)
+        for t in range(nt):
+            lanes = pl.ds(t * Pt, Pt)
+            fn(pltpu.make_async_copy(zout.at[c, t], zo_hbm.at[blk, :, lanes],
+                                     sems.at[3, c]) if write else
+               pltpu.make_async_copy(z_hbm.at[blk, :, lanes], zin.at[c, t],
+                                     sems.at[2, c]))
+
+    @pl.when(count > 0)
+    def _():
+        s_copy(0, False).start()
+        z_copies(0, False, lambda dma: dma.start())
+
+    def step(tau, carry):
+        r, kv, t = at(tau)
+        b = order_ref[r]
+        c, zc = jax.lax.rem(tau, _SLOTS), jax.lax.rem(r, 2)
+        g = g_ref[b * KV + kv]
+
+        @pl.when(tau >= 2)
+        def _():    # the slot the next tile lands in has been written back
+            s_copy(tau - 2, True).wait()
+
+        @pl.when(tau + 1 < steps)
+        def _():
+            s_copy(tau + 1, False).start()
+
+        @pl.when(jnp.logical_and(kv == 0, t == 0))
+        def _():
+            z_copies(r, False, lambda dma: dma.wait())
+
+            @pl.when(r + 1 < count)
+            def _():
+                z_copies(r + 1, False, lambda dma: dma.start())
+
+            @pl.when(r >= 2)
+            def _():
+                z_copies(r - 2, True, lambda dma: dma.wait())
+
+        @pl.when(t == 0)
+        def _():
+            # phi of this head's G queries and its key, block by diagonal
+            # block: a rotation of the lanes, a product, the weight -- the
+            # operations of ``phi``, in its order.  A row of it is kept a
+            # (1, d) tile of its own, so that the queries' rows meet a
+            # block of S as ONE broadcast
+            x = x_ref[b, kv]                                # [R, d]
+            lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+
+            def diagonal(s):
+                blk = x * pltpu.roll(x, jnp.where(s == 0, 0, d - s), 1)
+                # 1 on the squares, sqrt(2) on every pair, 0 on the last
+                # block's repeated half (``_diagonal_weights``)
+                w = jnp.where(s == 0, f32(1.0), root2)
+                w = jnp.where(
+                    jnp.logical_and(s == d // 2, lane >= d // 2), f32(0.0), w)
+                blk = blk * w
+                fphi[s] = blk[:G + 1].reshape(G + 1, 1, d)
+
+            def diagonals(i, carry):
+                # a tile's diagonals an iteration: one after another the
+                # rotations wait for each other (a rolled loop of 65 takes
+                # longer than a tile's DMA)
+                for j in range(tile):
+                    diagonal(i * tile + j)
+                return carry
+
+            jax.lax.fori_loop(0, nt, diagonals, 0)
+            # the value down the sublanes, the same in every lane
+            vcol[...] = jnp.broadcast_to(x[G + 1:G + 2, :], (d, d)).T
+            acc[...] = jnp.zeros_like(acc)
+            dacc[...] = jnp.zeros_like(dacc)
+
+        s_copy(tau, False).wait()
+        mine = jax.lax.broadcasted_iota(jnp.int32, (KV, d), 0) == kv
+
+        @pl.when(fresh_ref[b] != 0)
+        def _():    # a row that starts at 0: zeros, whatever the entry holds
+            sbuf[c] = jnp.zeros((d, Pt), f32)
+            zin[zc, t] = jnp.where(
+                jax.lax.broadcasted_iota(jnp.int32, (KV, Pt), 0) == kv,
+                f32(0.0), zin[zc, t])
+
+        def block(j, carry):
+            lanes = pl.ds(pl.multiple_of(j * d, d), d)
+            f = fphi[t * tile + j]                          # [G + 1, 1, d]
+            fk = f[G]                                       # [1, d]
+            S = g * sbuf[c, :, lanes] + vcol[...] * fk      # [d, d]
+            sbuf[c, :, lanes] = S
+            acc[...] += S[None] * f[:G]
+            # this head's row of the normalisers [KV, d]: picked and put
+            # back by a mask (Mosaic loads no single row at a dynamic one)
+            Z = g * jnp.sum(jnp.where(mine, zin[zc, t, :, lanes], f32(0.0)),
+                            axis=0, keepdims=True) + fk     # [1, d]
+            zout[zc, t, :, lanes] = jnp.where(mine, Z, zout[zc, t, :, lanes])
+            dacc[...] += f * Z[None]
+            return carry
+
+        # traced once, unrolled where it is lowered: the lane offsets are
+        # constants again and Mosaic schedules block against block (rolled,
+        # the loop takes longer than the tile's DMA)
+        jax.lax.fori_loop(0, tile, block, 0, unroll=True)
+        s_copy(tau, True).start()
+
+        @pl.when(t == nt - 1)
+        def _():
+            for h in range(G):
+                num = acc[h].T.sum(axis=0, keepdims=True)   # [1, d]
+                den = dacc[h].sum(axis=1, keepdims=True)    # [1, 1]
+                y_ref[b, kv, h:h + 1, :] = (num / d) / (den / d + EPS)
+
+            @pl.when(kv == KV - 1)
+            def _():
+                z_copies(r, True, lambda dma: dma.start())
+
+        return carry
+
+    jax.lax.fori_loop(0, steps, step, 0)
+
+    @pl.when(steps >= 2)
+    def _():
+        s_copy(steps - 2, True).wait()
+
+    @pl.when(steps >= 1)
+    def _():
+        s_copy(steps - 1, True).wait()
+        z_copies(count - 1, True, lambda dma: dma.wait())
+
+    @pl.when(count >= 2)
+    def _():
+        z_copies(count - 2, True, lambda dma: dma.wait())
+
+
+@functools.partial(jax.jit, static_argnames=("interpret", "tile"))
+def _fused_step(q, k, v, log_g, s, z, slot, fresh, order, count, *,
+                interpret: bool = False, tile: Optional[int] = None):
+    """The recurrent form at one position for the ``count`` live rows
+    ``order[:count]`` of the batch, over the pool's entries IN PLACE: q [B,
+    KV, G, d], k, v [B, KV, d], log_g [B, KV]; row b's state is entry
+    ``slot[b]`` of ``s`` / ``z``, zero where ``fresh[b]`` -> (y [B, KV, G,
+    d] float32, zero for a row that is not live; s', z').  ``tile`` is the
+    diagonal blocks a tile holds (``blocks_per_tile``).  Jitted so that a
+    program's layers share one trace and one lowering of the kernel."""
+    B, KV, G, d = q.shape
+    tile = tile or blocks_per_tile(d)
+    if not tile or (d // 2 + 1) % tile:
+        raise ValueError(
+            f"{d // 2 + 1} diagonal blocks of {d} lanes are no whole "
+            f"number of tiles of {tile}; take the row-by-row form "
+            "(step_supported)")
+    nt, R = (d // 2 + 1) // tile, _rows_held(G)
+    f32 = jnp.float32
+    # a head's queries, its key and its value: one small operand, whole in
+    # vector memory, in (8, 128) tiles whatever the group size
+    x = jnp.concatenate([q.astype(f32), k.astype(f32)[:, :, None],
+                         v.astype(f32)[:, :, None]], axis=2)
+    x = jnp.pad(x, ((0, 0), (0, 0), (0, R - G - 2), (0, 0)))
+    vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    y, s, z = pl.pallas_call(
+        functools.partial(_step_kernel, G=G, tile=tile),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            grid=(1,),
+            in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM), vmem, hbm, hbm],
+            out_specs=(vmem, hbm, hbm),
+            scratch_shapes=[
+                pltpu.VMEM((_SLOTS, d, tile * d), f32),     # tiles of S
+                pltpu.VMEM((2, nt, KV, tile * d), f32),     # a row's Z in
+                pltpu.VMEM((2, nt, KV, tile * d), f32),     # ... and out
+                pltpu.VMEM((d // 2 + 1, G + 1, 1, d), f32),  # phi(q), phi(k)
+                pltpu.VMEM((d, d), f32),                    # v, a column
+                pltpu.VMEM((G, d, d), f32),                 # phi(q) . S'
+                pltpu.VMEM((G + 1, 1, d), f32),             # phi(q) . Z'
+                pltpu.SemaphoreType.DMA((4, _SLOTS)),
+            ],
+        ),
+        out_shape=(jax.ShapeDtypeStruct((B, KV, R, d), f32),
+                   jax.ShapeDtypeStruct(s.shape, s.dtype),
+                   jax.ShapeDtypeStruct(z.shape, z.dtype)),
+        # operands 6 and 7 (after the four scalar ones, g and x) are the
+        # pool's entries: updated where they lie
+        input_output_aliases={6: 1, 7: 2},
+        interpret=interpret,
+    )(slot.astype(jnp.int32), order.astype(jnp.int32),
+      count.astype(jnp.int32).reshape(1), fresh.astype(jnp.int32),
+      jnp.exp(log_g).astype(f32).reshape(B * KV), x, s, z)
+    return y[:, :, :G], s, z
+
+
+def retention(q, k, v, log_g, state, slot, start, width, fused=False):
     """q [B, KV, G, W, d], k, v [B, KV, W, d], log_g [B, KV, W] float32
     over ``state`` = {"s", "z"} (the module's text): row b's state is entry
     ``slot[b]``, taken as zero where ``start[b]`` is 0, and its first
     ``width[b]`` positions are valid -- a row of width 0 is skipped: its
     ``y`` is zero and no entry is written.  -> (y [B, KV, G, W, d] in q's
-    dtype, state')."""
+    dtype, state').
+
+    ``fused`` concerns a call of one position a row alone: True takes the
+    kernel (``_fused_step``), "interpret" the kernel in Pallas interpret
+    mode (tests on the CPU), False ``_step`` row by row.  The caller asks
+    ``step_supported`` (models/generate.py ``retention_fused``, as
+    ``decode_inplace`` asks for attention: only a program's caller sees the
+    backend it is lowered for and the mesh)."""
     B, KV, G, W, d = q.shape
-    form = _step if W == 1 else _chunk
     live = width > 0
     order = jnp.argsort(~live, stable=True)             # the live rows first
+    if W == 1 and fused:
+        def rows(a):    # the batch in whole groups: ``_ROW_GROUP``
+            return jnp.pad(a, [(0, -B % _ROW_GROUP)] + [(0, 0)] * (a.ndim - 1))
+
+        y, s, z = _fused_step(
+            rows(q[:, :, :, 0]), rows(k[:, :, 0]), rows(v[:, :, 0]),
+            rows(log_g[:, :, 0]), state["s"], state["z"], rows(slot),
+            rows(start == 0), rows(order), jnp.sum(live),
+            interpret=fused == "interpret")
+        return y[:B, :, :, None].astype(q.dtype), {"s": s, "z": z}
+    form = _step if W == 1 else _chunk
 
     def row(i, carry):
         s, z, y = carry

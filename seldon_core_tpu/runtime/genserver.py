@@ -738,6 +738,7 @@ class GenServer:
         self._stopped = False
         self._pool = None
         self._inplace = False  # decode attends over the pool in place
+        self._ret_fused = False  # ... updates retention states in place
         # what one decode round hands the next, on the device (_carry_ops):
         # a sequence holds a slot of it from admission to retirement
         self._carry = None
@@ -833,6 +834,7 @@ class GenServer:
         self._tick_real_rows = 0             # real rows dispatched
         self._tick_dev_steps = 0             # single-token device steps
         self._tick_inplace_steps = 0         # ... that attended in place
+        self._tick_ret_fused_steps = 0       # ... updated states in place
         self._tick_ahead_steps = 0           # ... dispatched ahead of a read
         self._tick_passes = 0                # passes of the model dispatched
         self._tick_row_passes = 0            # ... times the real rows in each
@@ -1188,6 +1190,7 @@ class GenServer:
             decode_inplace,
             init_block_pool,
             paged_forward_jit,
+            retention_fused,
         )
 
         self._pool = init_block_pool(
@@ -1208,6 +1211,11 @@ class GenServer:
             self._pool, self.mesh, width=self._block,
             heads=self.cfg.n_heads, rows=_pow2(self.slots),
             head_dim=self.cfg.hd)
+        # and likewise for a generator of retention layers: the kernel
+        # that updates a row's state where it lies, or the row-by-row step
+        self._ret_fused = retention_fused(
+            self._pool, self.mesh, heads=self.cfg.n_heads,
+            rows=_pow2(self.slots))
         if self.spec:
             self._draft_pool = init_block_pool(
                 self.draft_cfg, self.num_blocks, self.block_size)
@@ -1371,7 +1379,8 @@ class GenServer:
                  self.cfg),
                 {"span": self.span, "temperature": self.temperature,
                  "top_k": self.top_k, "top_p": self.top_p,
-                 "eos_token": self.eos_token, "inplace": self._inplace})
+                 "eos_token": self.eos_token,
+                 "inplace": self._inplace or self._ret_fused})
 
     def _note_program(self, kind: str, shape: tuple) -> None:
         """A tick is about to dispatch ``shape``.  One the boot did not
@@ -1416,7 +1425,7 @@ class GenServer:
             "prefill_chunk": self.prefill_chunk, "span": self.span,
             "temperature": self.temperature, "top_k": self.top_k,
             "top_p": self.top_p, "eos_token": self.eos_token,
-            "inplace": self._inplace, "role": self.role,
+            "inplace": self._inplace or self._ret_fused, "role": self.role,
         }, sort_keys=True)
 
     def _load_programs(self) -> None:
@@ -1697,6 +1706,7 @@ class GenServer:
         self._tick_rows = self._tick_real_rows = 0
         self._tick_dev_steps = self._tick_kv_pos = self._tick_kv_blocks = 0
         self._tick_inplace_steps = self._tick_ahead_steps = 0
+        self._tick_ret_fused_steps = 0
         self._tick_passes = self._tick_row_passes = 0
         self._tick_expert_slots = self._tick_experts_read = 0
         self._tick_prefill = [0] * 6
@@ -1784,6 +1794,7 @@ class GenServer:
             "tokens": tokens,
             "steps": self._tick_dev_steps,
             "inplace_steps": self._tick_inplace_steps,
+            "retention_fused_steps": self._tick_ret_fused_steps,
             "ahead_steps": self._tick_ahead_steps,
             "passes": self._tick_passes,
             "row_passes": self._tick_row_passes,
@@ -2601,6 +2612,8 @@ class GenServer:
             self._tick_expert_slots += expert_slots
             if self._inplace:
                 self._tick_inplace_steps += self.span
+            if self._ret_fused:
+                self._tick_ret_fused_steps += self.span
             if self._unread:
                 # queued behind a program whose results are still unread:
                 # the device goes from that one to this without the host

@@ -132,6 +132,7 @@ class GenPerf:
         self.decode_tokens = 0       # REAL tokens emitted by decode ticks
         self.decode_steps = 0        # single-token device steps run
         self.decode_inplace_steps = 0  # ... that attended in place
+        self.decode_ret_fused_steps = 0  # ... that updated states in place
         self.decode_ahead_steps = 0    # ... dispatched ahead of a readback
         self.decode_passes = 0         # passes of the model (a token a step:
         #                                one a step; diffusion blocks: the
@@ -224,6 +225,8 @@ class GenPerf:
                 self.decode_steps += int(detail.get("steps", 0) or 0)
                 self.decode_inplace_steps += int(
                     detail.get("inplace_steps", 0) or 0)
+                self.decode_ret_fused_steps += int(
+                    detail.get("retention_fused_steps", 0) or 0)
                 self.decode_ahead_steps += int(
                     detail.get("ahead_steps", 0) or 0)
                 self.decode_kv_positions += int(
@@ -300,6 +303,7 @@ class GenPerf:
             tokens = self.decode_tokens
             steps = self.decode_steps
             inplace_steps = self.decode_inplace_steps
+            ret_fused_steps = self.decode_ret_fused_steps
             ahead_steps = self.decode_ahead_steps
             kv_pos = self.decode_kv_positions
             passes = {
@@ -326,6 +330,11 @@ class GenPerf:
             # ... of which attended over the block pool in place (the
             # Pallas kernel, ops/paged_attention.py), not a gathered view
             "inplace_steps": inplace_steps,
+            # ... of which updated every live row's retention states where
+            # they lie in the pool, one read and one write (the Pallas
+            # kernel of ops/retention.py), not row by row in jax.numpy; 0
+            # for a generator without such layers
+            "retention_fused_steps": ret_fused_steps,
             # ... of which belonged to a round the scheduler put on the
             # device's queue while an earlier program's results were still
             # unread (runtime/genserver.py ``_tick``): the device did not

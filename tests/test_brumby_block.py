@@ -131,13 +131,22 @@ def chunked(unit, params, rows, chunk, tables, pool=None):
     return np.stack(out), pool
 
 
-def decode(unit, params, pool, tables, token, n_valid, active, span):
+def decode(unit, params, pool, tables, token, n_valid, active, span,
+           inplace=None):
     B = len(token)
     return paged_decode_round_jit(
         params, pool, tables, jnp.asarray(token, jnp.int32),
         jnp.asarray(n_valid, jnp.int32), jnp.asarray(active, bool),
         jnp.zeros((B,), bool), jnp.zeros((B,), jnp.uint32), unit.cfg,
-        span=span, temperature=0.0, top_k=0, top_p=0.0, eos_token=-1)
+        span=span, temperature=0.0, top_k=0, top_p=0.0, eos_token=-1,
+        inplace=inplace)
+
+
+# a decode round's step row by row in jax.numpy (what the CPU decides for
+# itself) and through the kernel of ops/retention.py in Pallas interpret
+# mode (what a TPU decides, as far as the CPU can run it)
+BOTH_STEPS = pytest.mark.parametrize(
+    "inplace", [None, "interpret"], ids=["step", "kernel"])
 
 
 # -- the expansion and the parameters ----------------------------------------
@@ -200,9 +209,10 @@ def test_whole_prefill_gives_the_references_logits_at_every_position(model):
                                atol=1e-4, rtol=0)
 
 
+@BOTH_STEPS
 @pytest.mark.parametrize("chunk", [1, 4, 5, 16])
-def test_chunked_prefill_then_decode_rounds_equal_the_reference(model,
-                                                                chunk):
+def test_chunked_prefill_then_decode_rounds_equal_the_reference(model, chunk,
+                                                                inplace):
     """Two prompts of 13 and 8 tokens (unequal, so every call but a whole
     one has pad positions or a row of width 0) through the recurrent form
     alone (chunk 1), the chunk form at a size that divides neither (5), one
@@ -222,7 +232,8 @@ def test_chunked_prefill_then_decode_rounds_equal_the_reference(model,
     token = first
     for _ in range(2):
         toks, pool, token, n_valid, *_ = decode(
-            unit, params, pool, TABLES, token, n_valid, [True, True], 4)
+            unit, params, pool, TABLES, token, n_valid, [True, True], 4,
+            inplace)
         got.append(np.asarray(toks))
     got = np.concatenate(got, axis=1)
     for i, r in enumerate(rows):
@@ -240,7 +251,8 @@ def test_chunked_prefill_then_decode_rounds_equal_the_reference(model,
                                atol=1e-4, rtol=0)
 
 
-def test_padded_rows_touch_nothing_but_the_scratch_entry(model):
+@BOTH_STEPS
+def test_padded_rows_touch_nothing_but_the_scratch_entry(model, inplace):
     """A decode round and a prefill chunk with an empty slot whose table is
     all zeros (what the scheduler pads with): the live row's tokens are
     what they are alone, the other row's state is as its prefill left it,
@@ -252,7 +264,15 @@ def test_padded_rows_touch_nothing_but_the_scratch_entry(model):
     tables = jnp.asarray([[1], [0]], jnp.int32)
     toks, pool, *_ = decode(
         unit, params, pool, tables, [int(logits[0].argmax()), 0], [9, 0],
-        [True, False], 4)
+        [True, False], 4, inplace)
+    if inplace:
+        # the kernel walks the live rows alone: the pad's scratch entry is
+        # not written either
+        for i in range(LAYERS):
+            for name in ("s", "z"):
+                np.testing.assert_array_equal(
+                    np.asarray(pool[f"l{i}"][name])[0],
+                    before[f"l{i}"][name][0])
     np.testing.assert_array_equal(
         np.asarray(toks)[0], reference_answer(params, rows[0], doc, 5)[1:])
     assert not np.asarray(toks)[1].any()
@@ -267,8 +287,9 @@ def test_padded_rows_touch_nothing_but_the_scratch_entry(model):
             assert np.abs(after[1] - before[f"l{i}"][name][1]).max() > 0
 
 
+@BOTH_STEPS
 def test_a_block_reused_after_a_longer_row_gives_what_a_fresh_one_gives(
-        model):
+        model, inplace):
     """A sequence that starts at position 0 reads a zero state whatever its
     block held: after a longer sequence's prefill and a round over the same
     block, a new prompt there gives the reference's logits -- bit for bit
@@ -277,13 +298,20 @@ def test_a_block_reused_after_a_longer_row_gives_what_a_fresh_one_gives(
     old, new = prompts([21, 7], seed=4)
     logits, pool = chunked(unit, params, [old], 4, TABLES[:1])
     _, pool, *_ = decode(unit, params, pool, TABLES[:1],
-                         [int(logits[0].argmax())], [21], [True], 4)
+                         [int(logits[0].argmax())], [21], [True], 4, inplace)
     assert float(jnp.abs(pool["l0"]["s"][1]).max()) > 0
-    reused, _ = chunked(unit, params, [new], 3, TABLES[:1], pool=pool)
+    reused, pool = chunked(unit, params, [new], 3, TABLES[:1], pool=pool)
     fresh, _ = chunked(unit, params, [new], 3, TABLES[:1])
     np.testing.assert_array_equal(reused, fresh)
     np.testing.assert_allclose(
         reused[0], reference_logits(params, new, doc)[-1], atol=1e-4, rtol=0)
+    # the step itself at position 0 (a round whose row has nothing cached
+    # yet): it too reads zero whatever the entry holds
+    alone = decode(unit, params, init_block_pool(unit.cfg, BLOCKS, BS),
+                   TABLES[:1], [int(new[0])], [0], [True], 4, inplace)[0]
+    dirty = decode(unit, params, pool, TABLES[:1], [int(new[0])], [0],
+                   [True], 4, inplace)[0]
+    np.testing.assert_array_equal(np.asarray(dirty), np.asarray(alone))
 
 
 def test_copying_a_block_copies_the_state_kept_at_its_id(model):
@@ -307,28 +335,188 @@ def test_static_lane_gives_the_reference_answer(model):
     np.testing.assert_array_equal(np.concatenate(chunks, axis=1), want)
 
 
+# -- the step as a kernel ---------------------------------------------------
+
+
+def built_state(rng, N, KV, d, keys=4):
+    """Entries that keys built (``Z = sum phi(k_j)``, ``S = sum v_j
+    phi(k_j)^T``): the normaliser of a query near a key is a sum of
+    squares, far from zero."""
+    ks = rng.normal(size=(N, KV, keys, d)).astype(np.float32)
+    vs = rng.normal(size=(N, KV, keys, d)).astype(np.float32)
+    fk = np.asarray(R.phi(ks))
+    s = np.einsum("nkje,nkjp->nkep", vs, fk).reshape(N, KV * d, -1)
+    return {"s": jnp.asarray(s, jnp.float32),
+            "z": jnp.asarray(fk.sum(2), jnp.float32)}
+
+
+# name: head width, KV heads, queries a KV head, diagonal blocks a tile
+# (None: ``blocks_per_tile``), each row's (entry, start, width)
+STEP_CASES = {
+    # pads between live rows, their table all zeros (entry 0)
+    "pads_between_live_rows": (16, 2, 2, None,
+                               [(3, 5, 1), (0, 0, 0), (1, 9, 1), (0, 0, 0),
+                                (4, 2, 1)]),
+    "a_row_at_0_over_a_dirty_entry": (16, 2, 2, None, [(2, 0, 1), (1, 7, 1)]),
+    "five_queries_a_head": (16, 1, 5, None, [(1, 3, 1), (2, 4, 1)]),
+    "one_query_a_head": (16, 2, 1, None, [(1, 3, 1), (2, 4, 1)]),
+    "three_tiles_of_three_blocks": (16, 2, 2, 3, [(1, 3, 1), (0, 0, 0),
+                                                  (2, 0, 1)]),
+    "nine_tiles_of_one_block": (16, 2, 2, 1, [(1, 3, 1), (2, 4, 1)]),
+    "nobody_live": (16, 2, 2, None, [(0, 0, 0), (0, 0, 0)]),
+    # the published head: 65 blocks of 128 lanes, five tiles of 13
+    "a_head_of_128_lanes": (128, 1, 5, None, [(2, 6, 1), (0, 0, 0),
+                                              (1, 0, 1)]),
+}
+
+
+@pytest.mark.parametrize("case", list(STEP_CASES))
+def test_the_step_kernel_agrees_with_the_step_row_by_row(case):
+    """``_fused_step`` in Pallas interpret mode against ``_step`` under
+    ``retention``'s loop over live rows, float32 both: ``y`` and the live
+    rows' entries agree to the order of the sums, every other entry -- the
+    scratch entry of the pads too -- is bit for bit what it was."""
+    d, KV, G, tile, rows = STEP_CASES[case]
+    rng = np.random.default_rng(len(case))
+    B, N = len(rows), 5
+    slot, start, width = (jnp.asarray(c, jnp.int32) for c in zip(*rows))
+    k = rng.normal(size=(B, KV, 1, d)).astype(np.float32)
+    # a query near its key: (q . k)^2 is no difference of large numbers
+    q = (k[:, :, None] + 0.5 * rng.normal(size=(B, KV, G, 1, d))).astype(
+        np.float32)
+    v = rng.normal(size=(B, KV, 1, d)).astype(np.float32)
+    log_g = np.log(rng.uniform(0.9, 0.999, (B, KV, 1))).astype(np.float32)
+    state = built_state(rng, N, KV, d)
+    want_y, want = R.retention(q, k, v, log_g, state, slot, start, width,
+                               fused=False)
+    live = width > 0
+    y, s, z = R._fused_step(
+        jnp.asarray(q)[:, :, :, 0], jnp.asarray(k)[:, :, 0],
+        jnp.asarray(v)[:, :, 0], jnp.asarray(log_g)[:, :, 0], state["s"],
+        state["z"], slot, start == 0, jnp.argsort(~live, stable=True),
+        jnp.sum(live), interpret=True, tile=tile)
+    assert y.dtype == jnp.float32 and y.shape == (B, KV, G, d)
+    np.testing.assert_allclose(np.asarray(y)[:, :, :, None],
+                               np.asarray(want_y), rtol=2e-5, atol=2e-6)
+    for got, name in ((s, "s"), (z, "z")):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want[name]),
+                                   rtol=1e-6, atol=1e-5)
+        untouched = sorted(set(range(N)) - {
+            e for e, _, w in rows if w})
+        np.testing.assert_array_equal(np.asarray(got)[untouched],
+                                      np.asarray(state[name])[untouched])
+    assert not np.asarray(y)[~np.asarray(live)].any()
+    if live.any():
+        assert np.abs(np.asarray(s) - np.asarray(state["s"])).max() > 0
+
+
+def test_the_traced_step_aliases_both_state_operands_to_its_outputs():
+    """The ``pallas_call`` of a decode round's step writes the pool's
+    entries where they lie: operands 6 and 7 (after slot, order, count,
+    fresh, g and the small q|k|v operand) are outputs 1 and 2, in the
+    block's trace as in the kernel's own."""
+    doc, unit = config()
+    cfg = unit.cfg
+    pool = jax.eval_shape(lambda: init_block_pool(cfg, BLOCKS, BS))
+    params = jax.eval_shape(lambda: unit.init_state(None)["params"])
+
+    def block(lp, x, layer, tables, start, valid):
+        return G._paged_block.__wrapped__(
+            lp, x, layer, tables, start, valid, cfg, kind=cfg.kind(0),
+            fused=True, interpret=True)
+
+    jaxpr = jax.make_jaxpr(block)(
+        params["l0"], jax.ShapeDtypeStruct((2, 1, 32), jnp.float32),
+        pool["l0"], TABLES, jnp.zeros((2,), jnp.int32),
+        jnp.ones((2, 1), bool))
+
+    def calls(jp):
+        for eqn in jp.eqns:
+            if eqn.primitive.name == "pallas_call":
+                yield eqn
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from calls(sub)
+
+    found = list(calls(jaxpr.jaxpr))
+    assert len(found) == 1
+    eqn = found[0]
+    assert tuple(eqn.params["input_output_aliases"]) == ((6, 1), (7, 2))
+    for i, o in ((6, 1), (7, 2)):
+        assert eqn.invars[i].aval.shape == eqn.outvars[o].aval.shape
+    assert eqn.invars[6].aval.shape == (BLOCKS, KV * HD, P)
+    assert eqn.invars[7].aval.shape == (BLOCKS, KV, P)
+    # and the call of one position a row with ``fused`` False holds none
+    plain = jax.make_jaxpr(lambda *a: G._paged_block.__wrapped__(
+        *a, cfg, kind=cfg.kind(0)))(
+            params["l0"], jax.ShapeDtypeStruct((2, 1, 32), jnp.float32),
+            pool["l0"], TABLES, jnp.zeros((2,), jnp.int32),
+            jnp.ones((2, 1), bool))
+    assert not list(calls(plain.jaxpr))
+
+
+SUPPORTED = dict(backend="tpu", state_dtype=jnp.float32, head_dim=128,
+                 mesh=None, kv_heads=8, heads=40, rows=16)
+
+
+@pytest.mark.parametrize("reason,change", [
+    ("a_cpu_backend", {"backend": "cpu"}),
+    ("a_bfloat16_state", {"state_dtype": jnp.bfloat16}),
+    ("a_head_of_64", {"head_dim": 64}),
+    ("a_mesh", {"mesh": object()}),
+    ("a_batch_vector_memory_cannot_hold", {"rows": 512}),
+])
+def test_step_supported_refuses(reason, change):
+    """The published shapes on a TPU take the kernel; each of these alone
+    takes ``_step`` row by row."""
+    assert R.step_supported(**SUPPORTED)
+    assert not R.step_supported(**{**SUPPORTED, **change}), reason
+
+
+def test_what_decides_for_the_step():
+    """On this backend (the CPU) nobody takes the kernel unasked:
+    ``retention_fused`` says no of a pool of states and of one without;
+    five tiles of 13 blocks at the published head."""
+    doc, unit = config()
+    pool = init_block_pool(unit.cfg, BLOCKS, BS)
+    assert not G.retention_fused(pool, heads=4, rows=2)
+    dense = TransformerGenerator(vocab=48, d_model=32, n_heads=4, n_layers=1,
+                                 d_ff=32, dtype="float32")
+    assert not G.retention_fused(init_block_pool(dense.cfg, 4, 4))
+    assert R.blocks_per_tile(128) == 13 and R.blocks_per_tile(16) == 9
+    assert R.blocks_per_tile(2048) == 0     # one block is 16 MB
+    with pytest.raises(ValueError, match="whole number of tiles"):
+        R._fused_step(
+            jnp.zeros((1, 1, 1, 16)), jnp.zeros((1, 1, 16)),
+            jnp.zeros((1, 1, 16)), jnp.zeros((1, 1)), jnp.zeros((2, 16, P)),
+            jnp.zeros((2, 1, P)), jnp.zeros((1,), jnp.int32),
+            jnp.zeros((1,), bool), jnp.zeros((1,), jnp.int32), jnp.int32(0),
+            interpret=True, tile=2)
+
+
 # -- what must fail -----------------------------------------------------------
 
 
 INTACT = R.retention
 
 
-def gate_dropped(q, k, v, log_g, state, slot, start, width):
-    return INTACT(q, k, v, jnp.zeros_like(log_g), state, slot, start, width)
+def gate_dropped(q, k, v, log_g, state, slot, start, width, **kw):
+    return INTACT(q, k, v, jnp.zeros_like(log_g), state, slot, start, width,
+                  **kw)
 
 
-def gate_applied_after_the_update(q, k, v, log_g, state, slot, start, width):
+def gate_applied_after_the_update(q, k, v, log_g, state, slot, start, width,
+                                  **kw):
     # S_t = g_t (S_(t-1) + phi(k_t) v_t^T): the token's own term decays too
     # (phi is quadratic: sqrt(g) on k is g on phi(k))
     k = (k * jnp.exp(log_g / 2)[..., None]).astype(k.dtype)
-    return INTACT(q, k, v, log_g, state, slot, start, width)
+    return INTACT(q, k, v, log_g, state, slot, start, width, **kw)
 
 
-def normaliser_left_out(q, k, v, log_g, state, slot, start, width):
+def normaliser_left_out(q, k, v, log_g, state, slot, start, width, **kw):
     # the normaliser's column of the state is not carried from call to call
     return INTACT(q, k, v, log_g,
                   {**state, "z": jnp.zeros_like(state["z"])}, slot, start,
-                  width)
+                  width, **kw)
 
 
 @pytest.mark.parametrize("fault", [
@@ -496,15 +684,25 @@ def settled(tokens):
         time.sleep(0.02)
 
 
+@pytest.mark.parametrize("fused", [False, "interpret"],
+                         ids=["step", "kernel"])
 def test_genserver_serves_the_reference_answer_a_block_a_row_and_counts(
-        model, clean_genperf, monkeypatch):
+        model, clean_genperf, monkeypatch, fused):
     """Rows of different lengths co-scheduled a block a row (every table is
     one column wide), prompts of one chunk and of three (the state carried
     over chunks), unary and streamed -- and what the server says of the
-    state's traffic."""
+    state's traffic and of who updated it: on the CPU the step row by row
+    (``retention_fused`` says no), and the kernel in Pallas interpret mode
+    where it is made to say "interpret", as a TPU says yes."""
+    import seldon_core_tpu.models.generate as gen_mod
+
     doc, unit, params = model
     monkeypatch.setenv("SELDON_TPU_GEN_PREFILL_CHUNK_MAX", "8")
+    if fused:
+        monkeypatch.setattr(gen_mod, "retention_fused",
+                            lambda *a, **kw: fused)
     srv = server(unit, params)
+    assert srv._ret_fused is False and not srv._inplace    # not decided yet
     try:
         cases = [(3, 6), (8, 9), (19, 7)]
         reqs = []
@@ -526,6 +724,7 @@ def test_genserver_serves_the_reference_answer_a_block_a_row_and_counts(
         assert snap["tick_errors_total"] == 0
         assert {p[-1] for kind in ("prefill", "decode")
                 for p in srv._programs[kind]} == {1}
+        assert srv._ret_fused == fused and not srv._inplace
     finally:
         srv.stop()
     prefill, served = perf["served_prefill"], perf["served_decode"]
@@ -538,6 +737,9 @@ def test_genserver_serves_the_reference_answer_a_block_a_row_and_counts(
     assert served["retention_state_bytes"] == (
         2 * state * served["row_passes"])
     assert served["inplace_steps"] == 0
+    assert served["retention_fused_steps"] == (
+        served["device_steps"] if fused else 0)
+    assert served["device_steps"] > 0
 
 
 def test_genserver_preempts_and_readmits_mid_answer(model):
@@ -586,3 +788,4 @@ def test_a_generator_without_retention_counts_none(clean_genperf):
     assert perf["served_prefill"]["rows"] == 3
     for block in ("served_prefill", "served_decode"):
         assert perf[block]["retention_state_bytes"] == 0
+    assert perf["served_decode"]["retention_fused_steps"] == 0

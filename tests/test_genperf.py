@@ -231,6 +231,25 @@ def test_served_decode_counts_the_steps_that_attended_in_place():
     assert served["inplace_steps"] == served["device_steps"] == 24
 
 
+def test_served_decode_counts_the_steps_whose_states_the_kernel_updated():
+    """``retention_fused_steps`` rides the tick record beside
+    ``inplace_steps``: 0 for a tick record that does not say, equal to
+    ``device_steps`` when every round's says so (bench/layer_metrics/
+    decode_retention_fused_share.json divides the two), and it is not the
+    attention kernel's count."""
+    tick = {"wall_s": 0.02, "device_s": 0.01, "tokens": 16, "steps": 8,
+            "device_phases": {"decode": 0.01}, "phases": {"decode": 0.01}}
+    GENPERF.observe_tick("decode", tick)
+    served = GENPERF.document()["served_decode"]
+    assert (served["device_steps"], served["retention_fused_steps"]) == (8, 0)
+    GENPERF.reset()
+    for _ in range(3):
+        GENPERF.observe_tick("mixed", {**tick, "retention_fused_steps": 8})
+    served = GENPERF.document()["served_decode"]
+    assert served["retention_fused_steps"] == served["device_steps"] == 24
+    assert served["inplace_steps"] == 0
+
+
 @pytest.mark.parametrize("depth", [0, 1])
 def test_ahead_steps_fold_into_served_decode(depth):
     """``ahead_steps`` rides the tick record beside ``inplace_steps``: 0 at

@@ -8,6 +8,7 @@ narrower than a 128-lane row is a further case of each: the pool is then
 made as ``init_block_pool`` makes it, several KV heads a row."""
 
 import itertools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -549,3 +550,51 @@ def test_kernel_compiles_for_a_described_v5e(one_chip, B, H, kv, bs, nblk,
         jax.config.update("jax_enable_compilation_cache", cache_was)
         compilation_cache.reset_cache()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("B,KV,G,hd", [
+    (16, 8, 5, 128),    # brumby-14b's widest round: 16 rows of 8 KV x 5
+    (1, 8, 5, 128),
+    (4, 2, 1, 256),     # a head of two registers: 129 blocks, 43 tiles of 3
+], ids=["brumby-16", "brumby-1", "hd256"])
+def test_retention_step_compiles_for_a_described_v5e(one_chip, B, KV, G, hd):
+    """The other kernel of the decode round (ops/retention.py, here beside
+    the attention kernel's compiles because one test file describes the
+    chip): Mosaic accepts it at the published widths -- the lane rotations
+    of ``phi``, the transposes, the masked pick of a head's normalisers,
+    its tiles' vector memory -- and the compiled call writes both state
+    operands where they lie.  A compile, not a run."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    from seldon_core_tpu.ops import retention as R
+
+    def s(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    assert R.step_supported(backend="tpu", state_dtype=jnp.float32,
+                            head_dim=hd, kv_heads=KV, heads=KV * G, rows=B)
+    N, P = 17, R.phi_width(hd)
+    cache_was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        compiled = jax.jit(R._fused_step, donate_argnums=(4, 5)).lower(
+            s((B, KV, G, hd), jnp.bfloat16), s((B, KV, hd), jnp.bfloat16),
+            s((B, KV, hd), jnp.bfloat16), s((B, KV), jnp.float32),
+            s((N, KV * hd, P), jnp.float32), s((N, KV, P), jnp.float32),
+            s((B,), jnp.int32), s((B,), jnp.bool_), s((B,), jnp.int32),
+            s((), jnp.int32),
+        ).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache_was)
+        compilation_cache.reset_cache()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert "output_to_operand_aliasing={{1}: (6, {}), {2}: (7, {})}" in text
+    # nothing but the call (and the program's own parameters) makes a
+    # state-shaped tensor: no copy of the pool around the kernel
+    made = re.findall(
+        r"= f32\[%d,%d,%d\]\S* ([\w\-]+)\(" % (N, KV * hd, P), text)
+    assert set(made) <= {"parameter", "get-tuple-element"}, made
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+
