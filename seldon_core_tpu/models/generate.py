@@ -557,11 +557,12 @@ def _retention(lp, x, pool_layer, tables, start, valid, cfg: LMConfig,
     state is ``pool_layer`` at ``tables[b, 0]``, zero for a row that starts
     at 0; pad positions (``valid`` False, to the right of the valid ones)
     enter nothing, and a row with no valid position is skipped whole.
-    ``fused`` is ``retention``'s: whether a call of one position a row
-    updates the state where it lies (the kernel of ops/retention.py).  It
-    rides the calls -- this function's and ``retention``'s -- only where it
-    says so: the call of seven here and of eight there is what the
-    benchmark's fault injectors wrap (tests/bench/test_bench_brumby.py)."""
+    ``fused`` is ``retention``'s: whether the call works on the state
+    where it lies (the kernels of ops/retention.py: the step for one
+    position a row, the chunk form for any other width).  It rides the
+    calls -- this function's and ``retention``'s -- only where it says so:
+    the call of seven here and of eight there is what the benchmark's
+    fault injectors wrap (tests/bench/test_bench_brumby.py)."""
     from seldon_core_tpu.ops.quant import lm_matmul
     from seldon_core_tpu.ops.retention import retention
 
@@ -616,10 +617,10 @@ def _paged_block(lp, x, pool_layer, tables, start, valid, cfg: LMConfig,
     attention layer whose FFN is read off its weights): a "conv" layer
     takes ``_short_conv`` in the attention's place and a "ret" layer
     ``_retention`` -- its pool entry is the state, and what follows about
-    K/V does not concern it; ``fused`` has its one-position step update
-    the state where it lies in the pool (the kernel of ops/retention.py,
-    under ``interpret`` in Pallas interpret mode) -- and the FFN is
-    ``transformer._ffn``'s of that kind.
+    K/V does not concern it; ``fused`` has it work on the state where it
+    lies in the pool (the kernels of ops/retention.py -- a decode round's
+    step, a prefill call's chunk -- under ``interpret`` in Pallas
+    interpret mode) -- and the FFN is ``transformer._ffn``'s of that kind.
 
     ``plan`` (ops.paged_attention.decode_plan) selects the in-place
     formulation: attention reads the row's blocks from the pool where they
@@ -700,7 +701,8 @@ def _head(params, cfg: LMConfig):
 
 
 def paged_forward(params, tokens, pool, tables, start, width,
-                  cfg: LMConfig, last_only: bool = True, head: bool = True):
+                  cfg: LMConfig, last_only: bool = True, head: bool = True,
+                  fused=None):
     """Forward W tokens per row at per-row offsets over the paged pool —
     chunked prefill (one prompt chunk at a time, decode never stalls for
     the whole prompt) and the speculative verify pass share this program.
@@ -716,8 +718,20 @@ def paged_forward(params, tokens, pool, tables, start, width,
     ``head`` False is for a caller that reads no logits (a generator by
     diffusion over blocks chooses no token from its prompt): the final norm
     and the unembedding are left out, and in the logits' place comes the
-    experts the call's expert layers read, an int32 (0 without experts)."""
+    experts the call's expert layers read, an int32 (0 without experts).
+
+    ``fused`` concerns a generator of retention layers alone: whether a
+    chunk works on each live row's state where it lies in the pool (the
+    chunk kernel of ops/retention.py).  None decides by
+    ``retention_fused(pool, width=W)`` for this batch, as
+    ``paged_decode_round(inplace=None)`` does (a caller with a mesh passes
+    its own answer); True / False force the kernel / ``jax.numpy`` row by
+    row; "interpret" runs the kernel in Pallas interpret mode (tests on
+    the CPU)."""
     B, W = tokens.shape
+    if fused is None:
+        fused = retention_fused(pool, heads=cfg.n_heads, rows=B, width=W,
+                                dtype=params["embed"].dtype)
     valid = jnp.arange(W)[None, :] < width[:, None]  # [B, W]
     # block diffusion: a prompt's short last block sees itself and no
     # further; a chunk is whole blocks (the scheduler's chunk is a multiple
@@ -729,7 +743,8 @@ def paged_forward(params, tokens, pool, tables, start, width,
     for i in range(cfg.n_layers):
         x, pool[f"l{i}"], aux = _paged_block(
             params[f"l{i}"], x, pool[f"l{i}"], tables, start, valid, cfg,
-            limit=limit, kind=cfg.kind(i),
+            limit=limit, kind=cfg.kind(i), fused=bool(fused),
+            interpret=fused == "interpret",
         )
         if cfg.d_expert:
             read = read + aux
@@ -773,24 +788,32 @@ def decode_inplace(pool, mesh=None, width: int = 1, heads=None,
         heads=heads, rows=rows)
 
 
-def retention_fused(pool, mesh=None, heads=None, rows: int = 1) -> bool:
-    """Whether a decode step of a generator of retention layers updates
-    each live row's state where it lies in ``pool`` (the Pallas kernel of
-    ops/retention.py) or row by row in ``jax.numpy``: ops.retention
-    .step_supported over what is observable here, as ``decode_inplace``
-    asks for attention -- the backend, the state's dtype and shapes, the
-    caller's mesh, the query ``heads`` and padded ``rows`` of the widest
-    batch the caller will bring.  False for a pool without such layers."""
-    from seldon_core_tpu.ops.retention import step_supported
+def retention_fused(pool, mesh=None, heads=None, rows: int = 1,
+                    width: int = 1, dtype=None) -> bool:
+    """Whether a call of ``width`` positions a row of a generator of
+    retention layers works on each live row's state where it lies in
+    ``pool`` (the Pallas kernels of ops/retention.py: the step for a
+    decode round's one position, the chunk form for a prefill call's
+    ``width``) or row by row in ``jax.numpy``: ops.retention
+    .step_supported / .chunk_supported over what is observable here, as
+    ``decode_inplace`` asks for attention -- the backend, the state's dtype
+    and shapes, the caller's mesh, the query ``heads`` and padded ``rows``
+    of the widest batch the caller will bring and, for a chunk, the
+    activations' ``dtype``.  False for a pool without such layers."""
+    from seldon_core_tpu.ops.retention import chunk_supported, step_supported
 
     state = next((e for e in pool.values() if "s" in e), None)
     if state is None:
         return False
     kv_heads = state["z"].shape[1]
-    return step_supported(
+    seen = dict(
         backend=jax.default_backend(), state_dtype=state["s"].dtype,
         head_dim=state["s"].shape[1] // kv_heads, mesh=mesh,
-        kv_heads=kv_heads, heads=heads, rows=rows)
+        kv_heads=kv_heads, heads=heads)
+    if width == 1:
+        return step_supported(rows=rows, **seen)
+    return chunk_supported(width=width, act_dtype=dtype or jnp.bfloat16,
+                           **seen)
 
 
 def paged_decode_round(params, pool, tables, token, n_valid, active,
@@ -1115,7 +1138,7 @@ def paged_copy_block(pool, src, dst):
 # one live pool pytree per model and rebinds it after each dispatch, so XLA
 # mutates the blocks in place instead of copying the whole pool per step
 paged_forward_jit = jax.jit(
-    paged_forward, static_argnames=("cfg", "last_only", "head"),
+    paged_forward, static_argnames=("cfg", "last_only", "head", "fused"),
     donate_argnums=(2,)
 )
 paged_decode_round_jit = jax.jit(

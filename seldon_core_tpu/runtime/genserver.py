@@ -739,6 +739,7 @@ class GenServer:
         self._pool = None
         self._inplace = False  # decode attends over the pool in place
         self._ret_fused = False  # ... updates retention states in place
+        self._ret_chunk = {}     # chunk width -> a prefill call does too
         # what one decode round hands the next, on the device (_carry_ops):
         # a sequence holds a slot of it from admission to retirement
         self._carry = None
@@ -840,11 +841,12 @@ class GenServer:
         self._tick_row_passes = 0            # ... times the real rows in each
         self._tick_expert_slots = 0          # experts held x layers x passes
         self._tick_experts_read = 0          # experts the rounds read back
-        self._tick_prefill = [0] * 6         # prefill: calls, experts read,
+        self._tick_prefill = [0] * 7         # prefill: calls, experts read,
         #                                      experts held x layers x calls
         #                                      that count them, prompt tokens,
         #                                      rows, rows that began from a
-        #                                      carried state
+        #                                      carried state, rows whose
+        #                                      chunk ran the retention kernel
         self._tick_tokens = 0                # tokens emitted
         self._tick_retired = 0               # sequences retired
         self._phases: Dict[str, float] = {}  # phase -> host wall s
@@ -1371,6 +1373,8 @@ class GenServer:
                 # no token is chosen from a prompt: no head, and the
                 # experts read in the logits' place (paged_forward)
                 kw["head"] = False
+            if self._ret_row_bytes:
+                kw["fused"] = self._chunk_fused(toks.shape[1])
             return (paged_forward_jit,
                     (params, toks, pool, tables, start, width), kw)
         tables, token, n_valid, active, seen, keys = operands
@@ -1381,6 +1385,22 @@ class GenServer:
                  "top_k": self.top_k, "top_p": self.top_p,
                  "eos_token": self.eos_token,
                  "inplace": self._inplace or self._ret_fused})
+
+    def _chunk_fused(self, width: int):
+        """Whether a prefill call of ``width`` positions a row works on the
+        retention states where they lie in the pool (the chunk kernel of
+        ops/retention.py) or row by row in ``jax.numpy``: asked once a
+        width, here, because only the scheduler sees its mesh (as
+        ``_ret_fused`` is for the decode round).  False without such
+        layers."""
+        if width not in self._ret_chunk:
+            from seldon_core_tpu.models.generate import retention_fused
+
+            self._ret_chunk[width] = retention_fused(
+                self._pool, self.mesh, heads=self.cfg.n_heads,
+                rows=_pow2(self.slots), width=width,
+                dtype=self.params["embed"].dtype)
+        return self._ret_chunk[width]
 
     def _note_program(self, kind: str, shape: tuple) -> None:
         """A tick is about to dispatch ``shape``.  One the boot did not
@@ -1443,7 +1463,15 @@ class GenServer:
         if not self._record_path:
             return
         listed = read_program_record(self._record_path, self._identity)
-        jobs = [(kind, shape) for kind in sorted(listed)
+        # a prefill before a round, as a tick first needs them: where both
+        # programs hold a Pallas kernel (retention layers: the chunk's and
+        # the step's) the kernels share jax.numpy's cached helper traces,
+        # whose source locations are those of whichever was traced first --
+        # and a kernel's locations are hashed into the persistent cache's
+        # key (``_keep_out_of_program_locations``): another order here than
+        # the ticks' and a second boot compiles every program once more
+        jobs = [(kind, shape)
+                for kind in sorted(listed, key=lambda k: k != "prefill")
                 for shape in sorted(listed[kind])]
         if not jobs:
             return
@@ -1709,7 +1737,7 @@ class GenServer:
         self._tick_ret_fused_steps = 0
         self._tick_passes = self._tick_row_passes = 0
         self._tick_expert_slots = self._tick_experts_read = 0
-        self._tick_prefill = [0] * 6
+        self._tick_prefill = [0] * 7
         self._tick_attr = {} if costledger_enabled() else None
         self._tick_kv_attr = []
         self._tick_tokens = self._tick_retired = 0
@@ -1806,6 +1834,7 @@ class GenServer:
             "prefill_tokens": self._tick_prefill[3],
             "prefill_rows": self._tick_prefill[4],
             "prefill_carried_rows": self._tick_prefill[5],
+            "prefill_retention_fused_rows": self._tick_prefill[6],
             "retention_row_bytes": self._ret_row_bytes,
             "kv_positions": self._tick_kv_pos,
             "kv_blocks": self._tick_kv_blocks,
@@ -2366,6 +2395,8 @@ class GenServer:
             self._tick_prefill[3] += tokens
             self._tick_prefill[4] += len(batch)
             self._tick_prefill[5] += carried
+            if self._chunk_fused(C):
+                self._tick_prefill[6] += len(batch)
             self._dispatched += 1
             work = dict(
                 seq=self._dispatched, rows=B, real_rows=len(batch),

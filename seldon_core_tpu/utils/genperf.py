@@ -149,6 +149,7 @@ class GenPerf:
         self.prefill_tokens = 0        # prompt tokens the calls were given
         self.prefill_rows = 0          # real rows the calls were given
         self.prefill_carried_rows = 0  # ... that began from a carried state
+        self.prefill_ret_fused_rows = 0  # ... whose chunk ran the kernel
         # a generator of retention layers (a float32 matrix state a row and
         # a layer, read and rewritten by every token): the bytes of state
         # read + written by each kind of call -- the layers' bytes a row x
@@ -249,6 +250,8 @@ class GenPerf:
             self.prefill_rows += int(detail.get("prefill_rows", 0) or 0)
             self.prefill_carried_rows += int(
                 detail.get("prefill_carried_rows", 0) or 0)
+            self.prefill_ret_fused_rows += int(
+                detail.get("prefill_retention_fused_rows", 0) or 0)
             state = int(detail.get("retention_row_bytes", 0) or 0)
             if state:
                 self.retention_decode_bytes += 2 * state * int(
@@ -476,6 +479,11 @@ class GenPerf:
                 # generator with short-convolution layers; 0 without)
                 "rows": self.prefill_rows,
                 "carried_rows": self.prefill_carried_rows,
+                # ... whose chunk worked on every retention layer's state
+                # where it lies in the pool, one read and one write (the
+                # chunk kernel of ops/retention.py), not row by row in
+                # jax.numpy; 0 for a generator without such layers
+                "retention_fused_rows": self.prefill_ret_fused_rows,
                 # a generator of retention layers: a real row of a call is
                 # one chunk through every layer's state, and these are the
                 # bytes ``rows`` read + wrote (0 otherwise)

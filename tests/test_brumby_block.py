@@ -105,11 +105,14 @@ TABLES = jnp.asarray([[1], [2]], jnp.int32)
 BS, BLOCKS = 32, 4
 
 
-def chunked(unit, params, rows, chunk, tables, pool=None):
+def chunked(unit, params, rows, chunk, tables, pool=None, fused=None):
     """``rows`` prefilled ``chunk`` tokens a call as the scheduler does:
     rows of unequal length in one call, the shorter ones right-padded, a
     row that is through riding along with width 0.  Returns each row's
-    logits from the call that consumed its last token, and the pool."""
+    logits from the call that consumed its last token, and the pool.
+    ``fused`` is ``paged_forward``'s: None lets it decide (the CPU takes
+    ``jax.numpy`` row by row), "interpret" runs the kernels of
+    ops/retention.py in Pallas interpret mode."""
     if pool is None:
         pool = init_block_pool(unit.cfg, BLOCKS, BS)
     lens = [len(r) for r in rows]
@@ -124,7 +127,7 @@ def chunked(unit, params, rows, chunk, tables, pool=None):
             width[i], start[i] = w, min(lo, lens[i])
         logits, pool = paged_forward_jit(
             params, jnp.asarray(toks), pool, tables, jnp.asarray(start),
-            jnp.asarray(width), cfg=unit.cfg, last_only=True)
+            jnp.asarray(width), cfg=unit.cfg, last_only=True, fused=fused)
         for i in range(len(rows)):
             if width[i] and lo + width[i] == lens[i]:
                 out[i] = np.asarray(logits[i])
@@ -142,9 +145,10 @@ def decode(unit, params, pool, tables, token, n_valid, active, span,
         inplace=inplace)
 
 
-# a decode round's step row by row in jax.numpy (what the CPU decides for
-# itself) and through the kernel of ops/retention.py in Pallas interpret
-# mode (what a TPU decides, as far as the CPU can run it)
+# a decode round's step and a prefill call's chunk row by row in jax.numpy
+# (what the CPU decides for itself) and through the kernels of
+# ops/retention.py in Pallas interpret mode (what a TPU decides, as far as
+# the CPU can run it): the one answer goes to both programs
 BOTH_STEPS = pytest.mark.parametrize(
     "inplace", [None, "interpret"], ids=["step", "kernel"])
 
@@ -196,14 +200,16 @@ def test_the_pool_holds_one_state_a_block_and_no_kv(model):
 # -- the programs against the reference ------------------------------------
 
 
-def test_whole_prefill_gives_the_references_logits_at_every_position(model):
+@BOTH_STEPS
+def test_whole_prefill_gives_the_references_logits_at_every_position(
+        model, inplace):
     doc, unit, params = model
     row = prompts([13], seed=1)[0]
     pool = init_block_pool(unit.cfg, BLOCKS, BS)
     logits, _ = paged_forward_jit(
         params, jnp.asarray(row[None]), pool, TABLES[:1],
         jnp.zeros((1,), jnp.int32), jnp.asarray([13], jnp.int32),
-        cfg=unit.cfg, last_only=False)
+        cfg=unit.cfg, last_only=False, fused=inplace)
     np.testing.assert_allclose(np.asarray(logits[0]),
                                reference_logits(params, row, doc),
                                atol=1e-4, rtol=0)
@@ -221,7 +227,7 @@ def test_chunked_prefill_then_decode_rounds_equal_the_reference(model, chunk,
     argmax of the reference's whole causal pass over the row so far."""
     doc, unit, params = model
     rows = prompts([13, 8], seed=2)
-    logits, pool = chunked(unit, params, rows, chunk, TABLES)
+    logits, pool = chunked(unit, params, rows, chunk, TABLES, fused=inplace)
     for i, r in enumerate(rows):
         np.testing.assert_allclose(
             logits[i], reference_logits(params, r, doc)[-1], atol=1e-4,
@@ -245,7 +251,7 @@ def test_chunked_prefill_then_decode_rounds_equal_the_reference(model, chunk,
     nxt, _ = paged_forward_jit(
         params, jnp.asarray([[seq[-1], 0]], jnp.int32), pool, TABLES[:1],
         jnp.asarray([len(seq) - 1], jnp.int32), jnp.asarray([1], jnp.int32),
-        cfg=unit.cfg, last_only=True)
+        cfg=unit.cfg, last_only=True, fused=inplace)
     np.testing.assert_allclose(np.asarray(nxt[0]),
                                reference_logits(params, seq, doc)[-1],
                                atol=1e-4, rtol=0)
@@ -259,7 +265,7 @@ def test_padded_rows_touch_nothing_but_the_scratch_entry(model, inplace):
     and nothing but entry 0 could have been written for the pad."""
     doc, unit, params = model
     rows = prompts([9, 6], seed=3)
-    logits, pool = chunked(unit, params, rows, 16, TABLES)
+    logits, pool = chunked(unit, params, rows, 16, TABLES, fused=inplace)
     before = jax.tree.map(np.asarray, pool)
     tables = jnp.asarray([[1], [0]], jnp.int32)
     toks, pool, *_ = decode(
@@ -279,11 +285,16 @@ def test_padded_rows_touch_nothing_but_the_scratch_entry(model, inplace):
     _, pool = paged_forward_jit(
         params, jnp.zeros((2, 4), jnp.int32), pool, tables,
         jnp.asarray([13, 0], jnp.int32), jnp.asarray([4, 0], jnp.int32),
-        cfg=unit.cfg, last_only=True)
+        cfg=unit.cfg, last_only=True, fused=inplace)
     for i in range(LAYERS):
         for name in ("s", "z"):
             after = np.asarray(pool[f"l{i}"][name])
             np.testing.assert_array_equal(after[2:], before[f"l{i}"][name][2:])
+            if inplace:
+                # the chunk kernel, like the step's, walks the live rows
+                # alone
+                np.testing.assert_array_equal(after[0],
+                                              before[f"l{i}"][name][0])
             assert np.abs(after[1] - before[f"l{i}"][name][1]).max() > 0
 
 
@@ -296,12 +307,13 @@ def test_a_block_reused_after_a_longer_row_gives_what_a_fresh_one_gives(
     what a fresh pool gives."""
     doc, unit, params = model
     old, new = prompts([21, 7], seed=4)
-    logits, pool = chunked(unit, params, [old], 4, TABLES[:1])
+    logits, pool = chunked(unit, params, [old], 4, TABLES[:1], fused=inplace)
     _, pool, *_ = decode(unit, params, pool, TABLES[:1],
                          [int(logits[0].argmax())], [21], [True], 4, inplace)
     assert float(jnp.abs(pool["l0"]["s"][1]).max()) > 0
-    reused, pool = chunked(unit, params, [new], 3, TABLES[:1], pool=pool)
-    fresh, _ = chunked(unit, params, [new], 3, TABLES[:1])
+    reused, pool = chunked(unit, params, [new], 3, TABLES[:1], pool=pool,
+                           fused=inplace)
+    fresh, _ = chunked(unit, params, [new], 3, TABLES[:1], fused=inplace)
     np.testing.assert_array_equal(reused, fresh)
     np.testing.assert_allclose(
         reused[0], reference_logits(params, new, doc)[-1], atol=1e-4, rtol=0)
@@ -488,6 +500,206 @@ def test_what_decides_for_the_step():
         R._fused_step(
             jnp.zeros((1, 1, 1, 16)), jnp.zeros((1, 1, 16)),
             jnp.zeros((1, 1, 16)), jnp.zeros((1, 1)), jnp.zeros((2, 16, P)),
+            jnp.zeros((2, 1, P)), jnp.zeros((1,), jnp.int32),
+            jnp.zeros((1,), bool), jnp.zeros((1,), jnp.int32), jnp.int32(0),
+            interpret=True, tile=2)
+
+
+# -- the chunk form as a kernel ---------------------------------------------
+
+
+# name: head width, KV heads, queries a KV head, chunk width, diagonal
+# blocks a tile (None: ``blocks_per_tile``), each row's (entry, start, width)
+CHUNK_CASES = {
+    # part-filled chunks of 256: one position, a stretch, all but one, all
+    "widths_1_100_255_256_of_256": (16, 1, 2, 256, None,
+                                    [(1, 256, 1), (2, 512, 100),
+                                     (3, 256, 255), (4, 768, 256)]),
+    "a_row_at_0_over_a_dirty_entry": (16, 2, 2, 8, None,
+                                      [(2, 0, 8), (1, 7, 5)]),
+    "a_row_carried_from_a_state": (16, 2, 2, 8, None, [(3, 16, 8)]),
+    "pads_between_live_rows": (16, 2, 2, 8, None,
+                               [(3, 5, 8), (0, 0, 0), (1, 9, 3), (0, 0, 0),
+                                (4, 0, 5)]),
+    "nobody_live": (16, 2, 2, 8, None, [(0, 0, 0), (0, 0, 0)]),
+    "five_queries_a_head": (16, 1, 5, 16, None, [(1, 16, 16), (2, 0, 1)]),
+    "one_query_a_head": (16, 2, 1, 8, None, [(1, 3, 8), (2, 4, 2)]),
+    "three_tiles_of_three_blocks": (16, 2, 2, 8, 3, [(1, 3, 8), (0, 0, 0),
+                                                     (2, 0, 6)]),
+    "nine_tiles_of_one_block": (16, 2, 2, 8, 1, [(1, 3, 8), (2, 4, 7)]),
+    # the published head: 65 blocks of 128 lanes, five tiles of 13
+    "a_head_of_128_lanes": (128, 1, 5, 16, None, [(2, 6, 16), (0, 0, 0),
+                                                  (1, 0, 9)]),
+}
+
+
+def chunk_operands(rng, B, KV, G, W, d):
+    """q near k (``(q . k)^2`` no difference of large numbers), both at
+    the scale the per-head norms leave them, gates near 1."""
+    k = (rng.normal(size=(B, KV, W, d)) / d ** 0.25).astype(np.float32)
+    q = (k[:, :, None] + 0.5 * rng.normal(size=(B, KV, G, W, d))
+         / d ** 0.25).astype(np.float32)
+    v = rng.normal(size=(B, KV, W, d)).astype(np.float32)
+    log_g = np.log(rng.uniform(0.9, 0.999, (B, KV, W))).astype(np.float32)
+    return q, k, v, log_g
+
+
+@pytest.mark.parametrize("case", list(CHUNK_CASES))
+def test_the_chunk_kernel_agrees_with_the_chunk_form_row_by_row(case):
+    """``_fused_chunk`` in Pallas interpret mode against ``_chunk`` under
+    ``retention``'s loop over live rows, float32 both: ``y`` at every valid
+    position and the live rows' entries agree to the order of the sums,
+    every other entry -- the scratch entry of the pads too -- is bit for
+    bit what it was, and through ``retention`` a row that is not live
+    reads zero."""
+    d, KV, G, W, tile, rows = CHUNK_CASES[case]
+    rng = np.random.default_rng(len(case))
+    B, N = len(rows), 5
+    slot, start, width = (jnp.asarray(c, jnp.int32) for c in zip(*rows))
+    q, k, v, log_g = chunk_operands(rng, B, KV, G, W, d)
+    state = built_state(rng, N, KV, d)
+    want_y, want = R.retention(q, k, v, log_g, state, slot, start, width,
+                               fused=False)
+    live = width > 0
+    y, s, z = R._fused_chunk(
+        jnp.asarray(q), jnp.asarray(k), jnp.asarray(v), jnp.asarray(log_g),
+        width, state["s"], state["z"], slot, start == 0,
+        jnp.argsort(~live, stable=True), jnp.sum(live), interpret=True,
+        tile=tile)
+    assert y.dtype == jnp.float32 and y.shape == (B, KV, G, W, d)
+    for b, (_, _, w) in enumerate(rows):
+        np.testing.assert_allclose(
+            np.asarray(y)[b, :, :, :w], np.asarray(want_y)[b, :, :, :w],
+            rtol=2e-5, atol=2e-6)
+    for got, name in ((s, "s"), (z, "z")):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want[name]),
+                                   rtol=1e-6, atol=1e-5)
+        untouched = sorted(set(range(N)) - {e for e, _, w in rows if w})
+        np.testing.assert_array_equal(np.asarray(got)[untouched],
+                                      np.asarray(state[name])[untouched])
+    if live.any():
+        assert np.abs(np.asarray(s) - np.asarray(state["s"])).max() > 0
+    through, _ = R.retention(q, k, v, log_g, state, slot, start, width,
+                             fused="interpret")
+    assert not np.asarray(through)[~np.asarray(live)].any()
+
+
+def test_two_chunks_through_the_kernel_equal_the_step_token_by_token():
+    """A row of 16 positions from zero: two chunks of 8 through the chunk
+    kernel, and sixteen calls of one position through ``_step``: the same
+    ``y`` at every position and the same state at the end."""
+    d, KV, G, W = 16, 2, 2, 8
+    rng = np.random.default_rng(5)
+    q, k, v, log_g = chunk_operands(rng, 1, KV, G, 2 * W, d)
+    slot = jnp.asarray([2], jnp.int32)
+    state = built_state(rng, 4, KV, d)       # dirty: the row starts at 0
+    ys, by_chunk = [], state
+    for lo in (0, W):
+        y, by_chunk = R.retention(
+            q[:, :, :, lo:lo + W], k[:, :, lo:lo + W], v[:, :, lo:lo + W],
+            log_g[:, :, lo:lo + W], by_chunk, slot,
+            jnp.asarray([lo], jnp.int32), jnp.asarray([W], jnp.int32),
+            fused="interpret")
+        ys.append(np.asarray(y))
+    want, by_step = [], state
+    for t in range(2 * W):
+        y, by_step = R.retention(
+            q[:, :, :, t:t + 1], k[:, :, t:t + 1], v[:, :, t:t + 1],
+            log_g[:, :, t:t + 1], by_step, slot, jnp.asarray([t], jnp.int32),
+            jnp.asarray([1], jnp.int32), fused=False)
+        want.append(np.asarray(y))
+    np.testing.assert_allclose(np.concatenate(ys, axis=3),
+                               np.concatenate(want, axis=3), rtol=2e-5,
+                               atol=2e-6)
+    for name in ("s", "z"):
+        np.testing.assert_allclose(
+            np.asarray(by_chunk[name]), np.asarray(by_step[name]), rtol=1e-5,
+            atol=1e-5)
+
+
+def test_the_traced_chunk_aliases_both_state_operands_to_its_outputs():
+    """The ``pallas_call`` of a prefill call's chunk writes the pool's
+    entries where they lie: operands 10 and 11 (after slot, order, count,
+    fresh, the decay over the chunk and the head's q, k, weighted k, v and
+    cumulative decay) are outputs 1 and 2, in the block's trace; and the
+    block with ``fused`` False holds no kernel."""
+    doc, unit = config()
+    cfg = unit.cfg
+    pool = jax.eval_shape(lambda: init_block_pool(cfg, BLOCKS, BS))
+    params = jax.eval_shape(lambda: unit.init_state(None)["params"])
+    operands = (params["l0"], jax.ShapeDtypeStruct((2, 8, 32), jnp.float32),
+                pool["l0"], TABLES, jnp.zeros((2,), jnp.int32),
+                jnp.ones((2, 8), bool))
+
+    def calls(jp):
+        for eqn in jp.eqns:
+            if eqn.primitive.name == "pallas_call":
+                yield eqn
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from calls(sub)
+
+    found = list(calls(jax.make_jaxpr(
+        lambda *a: G._paged_block.__wrapped__(
+            *a, cfg, kind=cfg.kind(0), fused=True, interpret=True))(
+                *operands).jaxpr))
+    assert len(found) == 1
+    eqn = found[0]
+    assert tuple(eqn.params["input_output_aliases"]) == ((10, 1), (11, 2))
+    assert eqn.invars[10].aval.shape == eqn.outvars[1].aval.shape == (
+        BLOCKS, KV * HD, P)
+    assert eqn.invars[11].aval.shape == eqn.outvars[2].aval.shape == (
+        BLOCKS, KV, P)
+    assert not list(calls(jax.make_jaxpr(
+        lambda *a: G._paged_block.__wrapped__(*a, cfg, kind=cfg.kind(0)))(
+            *operands).jaxpr))
+
+
+CHUNK_SUPPORTED = dict({k: v for k, v in SUPPORTED.items() if k != "rows"},
+                       width=256, act_dtype=jnp.bfloat16)
+
+
+@pytest.mark.parametrize("reason,change", [
+    ("a_cpu_backend", {"backend": "cpu"}),
+    ("a_bfloat16_state", {"state_dtype": jnp.bfloat16}),
+    ("a_head_of_64", {"head_dim": 64}),
+    ("a_mesh", {"mesh": object()}),
+    ("a_chunk_of_no_whole_sublane_tiles", {"width": 100}),
+    ("a_chunk_too_wide_for_vector_memory", {"width": 1024}),
+    ("float32_activations_vector_memory_cannot_hold",
+     {"act_dtype": jnp.float32}),
+])
+def test_chunk_supported_refuses(reason, change):
+    """The published shapes on a TPU take the chunk kernel (whatever the
+    batch: it is not asked for the rows) and each of these alone takes
+    ``_chunk`` row by row."""
+    assert R.chunk_supported(**CHUNK_SUPPORTED)
+    assert not R.chunk_supported(**{**CHUNK_SUPPORTED, **change}), reason
+
+
+def test_what_decides_for_the_chunk(monkeypatch):
+    """``retention_fused`` asks ``step_supported`` for a width of one and
+    ``chunk_supported`` for any other; on this backend (the CPU) nobody
+    takes either kernel unasked, and ``paged_forward`` called without an
+    answer asks for itself."""
+    doc, unit = config()
+    pool = init_block_pool(unit.cfg, BLOCKS, BS)
+    assert not G.retention_fused(pool, heads=4, rows=2, width=8)
+    asked = []
+    monkeypatch.setattr(R, "chunk_supported",
+                        lambda **kw: asked.append(kw) or False)
+    monkeypatch.setattr(R, "step_supported",
+                        lambda **kw: asked.append("step") or False)
+    G.retention_fused(pool, heads=4, rows=2, width=8, dtype=jnp.float32)
+    G.retention_fused(pool, heads=4, rows=2)
+    assert asked[1] == "step"
+    assert asked[0] == dict(
+        backend="cpu", state_dtype=jnp.float32, head_dim=HD, mesh=None,
+        kv_heads=KV, heads=4, width=8, act_dtype=jnp.float32)
+    with pytest.raises(ValueError, match="whole number of tiles"):
+        R._fused_chunk(
+            jnp.zeros((1, 1, 1, 8, 16)), jnp.zeros((1, 1, 8, 16)),
+            jnp.zeros((1, 1, 8, 16)), jnp.zeros((1, 1, 8)),
+            jnp.zeros((1,), jnp.int32), jnp.zeros((2, 16, P)),
             jnp.zeros((2, 1, P)), jnp.zeros((1,), jnp.int32),
             jnp.zeros((1,), bool), jnp.zeros((1,), jnp.int32), jnp.int32(0),
             interpret=True, tile=2)
@@ -732,6 +944,10 @@ def test_genserver_serves_the_reference_answer_a_block_a_row_and_counts(
     # 19 tokens at chunk 8 are three chunks, the later two carried
     assert prefill["rows"] == 1 + 1 + 3 + 3
     assert prefill["carried_rows"] == 2 + 2
+    # every call is a chunk of 8 positions a row: the chunk kernel's, where
+    # the one answer the server asks for says so
+    assert prefill["retention_fused_rows"] == (prefill["rows"] if fused
+                                               else 0)
     assert prefill["retention_state_bytes"] == 2 * state * 8
     assert served["row_passes"] > 0
     assert served["retention_state_bytes"] == (
@@ -789,3 +1005,4 @@ def test_a_generator_without_retention_counts_none(clean_genperf):
     for block in ("served_prefill", "served_decode"):
         assert perf[block]["retention_state_bytes"] == 0
     assert perf["served_decode"]["retention_fused_steps"] == 0
+    assert perf["served_prefill"]["retention_fused_rows"] == 0

@@ -250,6 +250,27 @@ def test_served_decode_counts_the_steps_whose_states_the_kernel_updated():
     assert served["inplace_steps"] == 0
 
 
+def test_served_prefill_counts_the_rows_whose_chunk_ran_the_kernel():
+    """``prefill_retention_fused_rows`` rides the tick record beside
+    ``prefill_rows`` whatever the tick's kind: 0 for a record that does
+    not say (the CPU's path, ``jax.numpy`` row by row), equal to ``rows``
+    when every call's chunk ran the kernel of ops/retention.py
+    (bench/layer_metrics/prefill_retention_fused_share.json divides the
+    two); tests/test_brumby_block.py has a server count both."""
+    tick = {"wall_s": 0.02, "device_s": 0.01, "prefill_calls": 1,
+            "prefill_rows": 3, "prefill_carried_rows": 2,
+            "prefill_tokens": 600}
+    GENPERF.observe_tick("prefill", tick)
+    served = GENPERF.document()["served_prefill"]
+    assert (served["rows"], served["retention_fused_rows"]) == (3, 0)
+    GENPERF.reset()
+    for kind in ("prefill", "mixed", "decode"):
+        GENPERF.observe_tick(kind, {**tick, "prefill_retention_fused_rows": 3})
+    served = GENPERF.document()["served_prefill"]
+    assert served["retention_fused_rows"] == served["rows"] == 9
+    assert served["carried_rows"] == 6
+
+
 @pytest.mark.parametrize("depth", [0, 1])
 def test_ahead_steps_fold_into_served_decode(depth):
     """``ahead_steps`` rides the tick record beside ``inplace_steps``: 0 at

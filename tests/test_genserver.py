@@ -1535,6 +1535,32 @@ def test_a_second_boot_loads_what_the_first_dispatched(
     assert not writes                           # no set grew past the record
 
 
+def test_the_boot_loads_the_prefill_programs_before_the_rounds(
+        params, cache_dir, monkeypatch):
+    """... as a tick first needs them.  Where both programs hold a Pallas
+    kernel (a generator of retention layers) the kernels share
+    ``jax.numpy``'s cached helper traces, whose source locations are those
+    of whichever program was traced first, and a kernel's locations are
+    hashed into the persistent cache's key: a boot that traced the rounds
+    first fetched nothing the ticks had written (PERF.md section 6, PR
+    43).  The CPU's programs hold no kernel, so what is held here is the
+    order."""
+    from seldon_core_tpu.runtime import genserver as gs_mod
+
+    listed = {"decode": {(2, 1), (1, 1)}, "prefill": {(2, 4, 1), (1, 4, 1)}}
+    monkeypatch.setattr(gs_mod, "read_program_record", lambda *a: listed)
+    asked = []
+    monkeypatch.setattr(gs_mod.GenServer, "_load",
+                        lambda self, jobs: asked.extend(jobs) or jobs)
+    srv = _server(params)
+    try:
+        srv._ensure_device()
+    finally:
+        srv.stop()
+    assert asked == [("prefill", (1, 4, 1)), ("prefill", (2, 4, 1)),
+                     ("decode", (1, 1)), ("decode", (2, 1))]
+
+
 def test_a_shape_outside_the_record_runs_is_missed_and_enters_the_record(
         params, cache_dir, jax_events):
     """... traced by the tick's own ``jit`` call, as ever: the boot's

@@ -494,6 +494,32 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
+def _described(one_chip, compile_of):
+    """``compile_of(s)`` with the persistent cache off around it (a compile
+    for a described chip is written there but can never be read back
+    here); ``s(shape, dtype)`` is an operand on the described chip."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    def s(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    cache_was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        return compile_of(s)
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache_was)
+        compilation_cache.reset_cache()
+
+
+def _makers(text, shape):
+    """The ops of a compiled program that PRODUCE a tensor of ``shape``
+    (any element type)."""
+    return set(re.findall(
+        r"= \w+\[%s\]\S* ([\w\-]+)\(" % ",".join(map(str, shape)), text))
+
+
 @pytest.mark.parametrize("B,H,kv,bs,nblk,dtype,W,hd", [
     (16, 24, 2, 256, 4, jnp.bfloat16, 1, HD),  # the dense cell's decode shape
     (32, 24, 2, 256, 8, jnp.bfloat16, 1, HD),
@@ -523,32 +549,18 @@ def test_kernel_compiles_for_a_described_v5e(one_chip, B, H, kv, bs, nblk,
     and the statistics beside them — every shape ``inplace_supported``
     says yes to here.  A compile, not a run: it says nothing about results
     or time."""
-    from jax.experimental.compilation_cache import compilation_cache
-
-    def s(shape, dt):
-        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
-
     assert inplace_supported(width=W, backend="tpu", pool_dtype=dtype,
                              mesh=None, block_size=bs, kv_heads=kv,
                              head_dim=hd, heads=H, rows=B)
     N = 64
     pair = heads_per_row(kv, hd)
     pool = (N, bs, kv // pair, hd * pair)     # as init_block_pool makes it
-    # a compile for a described chip is written to the persistent cache
-    # but can never be read back here: keep it out
-    cache_was = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    compilation_cache.reset_cache()
-    try:
-        compiled = paged_decode_attention.lower(
-            s((B, H, W, hd), dtype), s(pool, dtype), s(pool, dtype),
-            s((B, nblk), jnp.int32),
-            s((B,), jnp.int32), s((B,), jnp.int32), s((1,), jnp.int32),
-            stats=W > 1,
-        ).compile()
-    finally:
-        jax.config.update("jax_enable_compilation_cache", cache_was)
-        compilation_cache.reset_cache()
+    compiled = _described(one_chip, lambda s: paged_decode_attention.lower(
+        s((B, H, W, hd), dtype), s(pool, dtype), s(pool, dtype),
+        s((B, nblk), jnp.int32),
+        s((B,), jnp.int32), s((B,), jnp.int32), s((1,), jnp.int32),
+        stats=W > 1,
+    ).compile())
     assert "tpu_custom_call" in compiled.as_text()
 
 
@@ -564,37 +576,118 @@ def test_retention_step_compiles_for_a_described_v5e(one_chip, B, KV, G, hd):
     of ``phi``, the transposes, the masked pick of a head's normalisers,
     its tiles' vector memory -- and the compiled call writes both state
     operands where they lie.  A compile, not a run."""
-    from jax.experimental.compilation_cache import compilation_cache
-
     from seldon_core_tpu.ops import retention as R
-
-    def s(shape, dt):
-        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
 
     assert R.step_supported(backend="tpu", state_dtype=jnp.float32,
                             head_dim=hd, kv_heads=KV, heads=KV * G, rows=B)
     N, P = 17, R.phi_width(hd)
-    cache_was = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    compilation_cache.reset_cache()
-    try:
-        compiled = jax.jit(R._fused_step, donate_argnums=(4, 5)).lower(
+    compiled = _described(one_chip, lambda s: jax.jit(
+        R._fused_step, donate_argnums=(4, 5)).lower(
             s((B, KV, G, hd), jnp.bfloat16), s((B, KV, hd), jnp.bfloat16),
             s((B, KV, hd), jnp.bfloat16), s((B, KV), jnp.float32),
             s((N, KV * hd, P), jnp.float32), s((N, KV, P), jnp.float32),
             s((B,), jnp.int32), s((B,), jnp.bool_), s((B,), jnp.int32),
             s((), jnp.int32),
-        ).compile()
-    finally:
-        jax.config.update("jax_enable_compilation_cache", cache_was)
-        compilation_cache.reset_cache()
+        ).compile())
     text = compiled.as_text()
     assert "tpu_custom_call" in text
     assert "output_to_operand_aliasing={{1}: (6, {}), {2}: (7, {})}" in text
     # nothing but the call (and the program's own parameters) makes a
     # state-shaped tensor: no copy of the pool around the kernel
-    made = re.findall(
-        r"= f32\[%d,%d,%d\]\S* ([\w\-]+)\(" % (N, KV * hd, P), text)
-    assert set(made) <= {"parameter", "get-tuple-element"}, made
+    assert _makers(text, (N, KV * hd, P)) <= {"parameter",
+                                              "get-tuple-element"}
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+
+
+@pytest.mark.parametrize("B,KV,G,W,hd", [
+    (16, 8, 5, 256, 128),   # brumby-14b's widest prefill call
+    (1, 8, 5, 256, 128),
+    (2, 2, 1, 128, 128),    # one query a KV head, a chunk of 128
+], ids=["brumby-16", "brumby-1", "g1-w128"])
+def test_retention_chunk_compiles_for_a_described_v5e(one_chip, B, KV, G, W,
+                                                      hd):
+    """The prefill call's kernel (ops/retention.py ``_fused_chunk``): Mosaic
+    accepts it at the published widths -- the lane rotations of ``phi`` by
+    a traced amount, ``phi(q) . S^T`` with both operands' lanes contracted,
+    the turned cumulative decay, the head's operands and tiles in vector
+    memory -- the compiled call writes both state operands where they lie,
+    and nothing of ``phi``'s shape (``[G * W, P]``, ``[W, P]``) or the
+    state's exists outside it.  A compile, not a run."""
+    from seldon_core_tpu.ops import retention as R
+
+    assert R.chunk_supported(backend="tpu", state_dtype=jnp.float32,
+                             head_dim=hd, width=W, kv_heads=KV,
+                             heads=KV * G)
+    N, P = 17, R.phi_width(hd)
+    bf = jnp.bfloat16
+    compiled = _described(one_chip, lambda s: jax.jit(
+        R._fused_chunk, donate_argnums=(5, 6)).lower(
+            s((B, KV, G, W, hd), bf), s((B, KV, W, hd), bf),
+            s((B, KV, W, hd), bf), s((B, KV, W), jnp.float32),
+            s((B,), jnp.int32), s((N, KV * hd, P), jnp.float32),
+            s((N, KV, P), jnp.float32), s((B,), jnp.int32),
+            s((B,), jnp.bool_), s((B,), jnp.int32), s((), jnp.int32),
+        ).compile())
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert "output_to_operand_aliasing={{1}: (10, {}), {2}: (11, {})}" in text
+    assert _makers(text, (N, KV * hd, P)) <= {"parameter",
+                                              "get-tuple-element"}
+    for rows in (G * W, W):
+        for lead in ((), (KV,), (B, KV)):
+            assert not _makers(text, lead + (rows, P))
+    # what jax.numpy makes around the call: the keys weighted, v turned,
+    # the cumulative decay -- of the batch, and small
+    assert compiled.memory_analysis().temp_size_in_bytes < (
+        B * KV * W * hd * 16)
+
+
+def test_paged_forward_of_retention_layers_compiles_around_the_kernel(
+        one_chip):
+    """The whole prefill program of two retention layers at the published
+    head widths, 16 rows of 256 positions, with the chunk kernel forced as
+    a TPU decides it: through ``_paged_block``'s inner ``jit`` and the
+    layer loop every call still carries the pool aliased, no op but the
+    parameters produces a state-shaped tensor and none a ``phi``-shaped
+    one, and the program's temporaries stay under what PERF.md (section 6,
+    PR 43) states for the cell's eight layers at 16 rows (217,549,824 B
+    where the ``jax.numpy`` chunk form had 245,252,608 B): they are the
+    batch's activations, not ``phi``."""
+    from seldon_core_tpu.models.generate import TransformerGenerator
+    from seldon_core_tpu.ops import retention as R
+
+    B, W, N, KV, G, hd = 16, 256, 17, 8, 5, 128
+    unit = TransformerGenerator(
+        vocab=512, d_model=5120, n_heads=KV * G, n_kv_heads=KV, head_dim=hd,
+        n_layers=2, layer_kinds="rr", dense_layers=2, d_ff=2048,
+        qk_norm=True, tie_embeddings=False, norm_eps=1e-6,
+        rope_base=1000000.0, dtype="bfloat16", seed=0)
+    cfg = unit.cfg
+    P = R.phi_width(hd)
+
+    def on_chip(s, tree):
+        return jax.tree.map(lambda a: s(a.shape, a.dtype), tree)
+
+    def compile_of(s):
+        params = on_chip(s, jax.eval_shape(
+            lambda: unit.init_state(None)["params"]))
+        pool = on_chip(s, jax.eval_shape(
+            lambda: init_block_pool(cfg, N, 12288)))
+        return paged_forward_jit.lower(
+            params, s((B, W), jnp.int32), pool, s((B, 1), jnp.int32),
+            s((B,), jnp.int32), s((B,), jnp.int32), cfg=cfg,
+            last_only=True, fused=True).compile()
+
+    compiled = _described(one_chip, compile_of)
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= 2
+    assert len(re.findall(
+        r"output_to_operand_aliasing=\{\{1\}: \(10, \{\}\), "
+        r"\{2\}: \(11, \{\}\)\}", text)) == 2
+    assert _makers(text, (N, KV * hd, P)) <= {"parameter",
+                                              "get-tuple-element"}
+    for rows in (G * W, W):
+        for lead in ((), (KV,), (B, KV), (1, KV)):
+            assert not _makers(text, lead + (rows, P))
+    assert compiled.memory_analysis().temp_size_in_bytes < 217_549_824
 

@@ -583,7 +583,8 @@ def test_a_dense_generator_reports_no_experts_and_one_pass_a_step(
     SPINE.drain()
     assert GENPERF.document()["served_prefill"] == {
         "calls": 1, "experts_read": 0, "expert_slots": 0, "tokens": 5,
-        "rows": 1, "carried_rows": 0, "retention_state_bytes": 0}
+        "rows": 1, "carried_rows": 0, "retention_fused_rows": 0,
+        "retention_state_bytes": 0}
 
 
 def test_observe_tick_folds_the_new_counters():
@@ -602,7 +603,8 @@ def test_observe_tick_folds_the_new_counters():
     # a chunk is read back in whatever tick comes next: every kind folds it
     assert prefill == {"calls": 6, "experts_read": 4500,
                        "expert_slots": 5376, "tokens": 900, "rows": 21,
-                       "carried_rows": 9, "retention_state_bytes": 0}
+                       "carried_rows": 9, "retention_fused_rows": 0,
+                       "retention_state_bytes": 0}
     # a prefill tick's are not a decode round's
     assert (served["passes"], served["row_passes"], served["experts_read"],
             served["expert_slots"]) == (20, 60, 1400, 17408)
