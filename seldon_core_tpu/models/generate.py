@@ -56,6 +56,7 @@ from seldon_core_tpu.graph.units import Unit, UnitAux, register_unit
 from seldon_core_tpu.utils.telemetry import RECORDER
 
 _stream_counter = itertools.count()  # per-process sampled-stream key source
+from seldon_core_tpu.models.served import served
 from seldon_core_tpu.models.transformer import (
     LMConfig,
     _ffn,
@@ -1457,25 +1458,10 @@ class TransformerGenerator(Unit):
             layer_kinds=str(layer_kinds), conv_kernel=int(conv_kernel),
             dense_layers=int(dense_layers), router=str(router),
         )
-        if (set(self.cfg.layer_kinds) & set("cr")
-                and str(prefix_tokens).strip()):
-            raise ValueError(
-                "a generator with gated short-convolution or retention "
-                "layers takes no shared prefix (prefix_tokens): the "
-                "prefix's pinned blocks are shared by table reference, and "
-                "a layer's state after the prefix is one sequence's, found "
-                "by its own first block")
-        if "r" in self.cfg.layer_kinds and mesh is not None:
-            raise ValueError(
-                "a generator of retention layers is served on one chip: "
-                "nothing shards a layer's state over a mesh yet (by KV "
-                "head, beside the parameters)")
-        if self.cfg.block_length > 1 and (
-                float(temperature) > 0.0 or str(prefix_tokens).strip()):
-            raise ValueError(
-                "a generator by diffusion over blocks decodes greedily and "
-                "takes no shared prefix (block_length > 1 with "
-                "temperature > 0 or prefix_tokens)")
+        # the lanes it cannot take, in the scheduler's words (served.py)
+        served(self.cfg).refuse(
+            prefix=bool(str(prefix_tokens).strip()),
+            sampled=float(temperature) > 0.0, mesh=mesh is not None)
         self.weights_path = str(weights_path)
         self.seed = int(seed)
         self.max_new_tokens = int(max_new_tokens)

@@ -12,13 +12,12 @@ programs — the scheduler shape production TPU serving stacks use
 pool and the tables:
 
   * **Paged KV pool** — one process-wide per-layer block pool
-    (``models/generate.py init_block_pool``: ``[num_blocks, block_size,
-    KV, hd]``, heads narrower than 128 lanes several a row); sequences
-    hold block tables, the :class:`BlockAllocator`
-    does alloc/free/eviction (preempt-youngest recompute) and occupancy
-    accounting.  A shared prefix is computed once, at boot, into PINNED
-    blocks: every sequence's table references the same physical blocks
-    (a partly filled boundary block is copied per sequence, pool to pool).
+    (``models/generate.py init_block_pool``); sequences hold block
+    tables, the :class:`BlockAllocator` does alloc/free/eviction
+    (preempt-youngest recompute) and occupancy accounting.  A shared
+    prefix is computed once, at boot, into PINNED blocks: every sequence's
+    table references the same physical blocks (a partly filled boundary
+    block is copied per sequence, pool to pool).
   * **Per-step admission** — each scheduler iteration admits newly
     arrived sequences into the in-flight decode batch, runs one decode
     ROUND (``span`` single-token steps as one ``lax.scan`` — one device
@@ -38,6 +37,13 @@ pool and the tables:
   * **Composition** — int8 KV pools, shared-prefix block reuse, and
     speculative draft/verify rounds (``paged_spec_round``) all run
     through the same admission/retirement machinery.
+
+What it serves it is TOLD (``models/served.py``, one description built
+from the configuration): how a round is driven, what the pool holds, the
+lanes such a generator cannot take, which kernels serve it over the pool,
+and the counts each dispatched call is given -- the same numbers on the
+dispatching span and in the tick record.  Nothing here reads a layer's
+kind or branches on an architecture.
 
 Greedy scheduler output is token-identical to one-shot ``generate()``
 (tests/test_genserver.py pins it); sampled decoding uses per-SEQUENCE
@@ -61,6 +67,7 @@ lane (runtime/engine.py).
 
 from __future__ import annotations
 
+import collections
 import concurrent.futures
 import functools
 import hashlib
@@ -138,6 +145,13 @@ class _Phase:
 def _pow2(n: int) -> int:
     return 1 << max(n - 1, 0).bit_length() if n > 1 else 1
 
+
+# What a dispatching span says of its program's work beside ``seq``, ``rows``,
+# ``real_rows``, ``nblk`` and a round's ``inplace``: the call's counts by these
+# names (bench/lib/trace_calls.py joins them to the device's calls).
+_DECODE_SPAN = ("kv_positions", "passes", "blocks", "expert_slots")
+_PREFILL_SPAN = ("tokens", "kv_positions", "attended", "expert_slots",
+                 "carried_rows")
 
 # The most int32 entries (rows x width) a decode round's block table is
 # widened to where the in-place kernel serves.  The flattened table is the
@@ -648,65 +662,19 @@ class GenServer:
         self.span = span or _env_int("SELDON_TPU_GEN_SPAN", 8)
         self.prefill_chunk = prefill_chunk or _env_int(
             "SELDON_TPU_GEN_PREFILL_CHUNK", 128)
-        # a generator by diffusion over blocks (cfg.block_length > 1): a
-        # round is whole blocks of denoising passes, a prefill chooses no
-        # token, and a row's first round starts where its prompt's last
-        # whole block ends (_decode_round).  Blocks lie at multiples of the
-        # block length, so a round, a KV block and a prefill chunk are each
-        # a whole number of them
-        self._block = int(getattr(cfg, "block_length", 1))
-        if self._block > 1:
-            if (self.spec or prefix_ids is not None or self.temperature > 0.0
-                    or role in ("prefill", "decode")):
-                raise ValueError(
-                    "a generator by diffusion over blocks is served greedy, "
-                    "unified, without a draft model or a shared prefix")
-            for name, n in (("span", self.span),
-                            ("block_size", self.block_size),
-                            ("prefill_chunk", self.prefill_chunk)):
-                if n % self._block:
-                    raise ValueError(
-                        f"{name}={n} is no whole number of diffusion blocks "
-                        f"of {self._block}")
-        # a generator with gated short-convolution layers (cfg.layer_kinds)
-        # keeps, beside its K/V blocks, a fixed-size state a sequence and a
-        # layer, in the pool at the id of the sequence's FIRST block
-        # (models/generate.py init_block_pool): zero at position 0, carried
-        # over chunks and rounds, freed with the block, recomputed from the
-        # prompt after a preemption.  Nothing snapshots or rolls it back,
-        # so the lanes that would have to are refused by name.  A
-        # power-retention layer ("r") keeps such a state too, 34 MB a row
-        # and a layer at the published widths where a convolution's is 8
-        # KB: every layer of such a generator is one, it holds no K/V, and
-        # what the pool holds is one state entry a BLOCK
-        kinds = set(getattr(cfg, "layer_kinds", ""))
-        self._stateful = bool(kinds & set("cr"))
-        self._holds = "state" if "r" in kinds else "KV"
-        # bytes one row's states are over the retention layers (0 without):
-        # what a decode step or a prefill chunk reads and writes a row
-        self._ret_row_bytes = 0
-        if "r" in kinds:
-            self._ret_row_bytes = self._refuse_states_that_cannot_fit()
-        # layers whose FFN is dropless routed experts (not the leading
-        # dense ones): what a pass's expert slots are counted over
-        self._routed = getattr(cfg, "expert_layers", 0)
-        if self._stateful:
-            for refused, why in (
-                    (self.spec,
-                     "speculative decoding: a rejected draft would have to "
-                     "roll the layers' state back"),
-                    (prefix_ids is not None,
-                     "a shared prefix: its pinned blocks are shared by "
-                     "table reference, the state after it is one "
-                     "sequence's"),
-                    (role in ("prefill", "decode"),
-                     "the prefill / decode roles: a handoff streams K/V "
-                     "blocks, not the layers' state")):
-                if refused:
-                    raise ValueError(
-                        "a generator with gated short-convolution or "
-                        "retention layers is served unified and cannot "
-                        f"take {why}")
+        # what the scheduler is told about the generator it serves, and
+        # the lanes, sizes and pools such a generator cannot take
+        from seldon_core_tpu.models.served import served
+
+        self._served = served(cfg)
+        self._served.refuse(
+            draft=self.spec, prefix=prefix_ids is not None,
+            sampled=self.temperature > 0.0,
+            roles=role in ("prefill", "decode"), mesh=mesh is not None)
+        self._served.whole(span=self.span, block_size=self.block_size,
+                           prefill_chunk=self.prefill_chunk)
+        self._served.refuse_pool(self.num_blocks, params,
+                                 _device_memory_bytes)
         # bounded admission queue: sustained overload must fail typed
         # (retryable 503 via LoadShedError) with flat memory, never grow
         # the waiting deques without limit.  Generous by default — the
@@ -737,9 +705,7 @@ class GenServer:
         self._thread: Optional[threading.Thread] = None
         self._stopped = False
         self._pool = None
-        self._inplace = False  # decode attends over the pool in place
-        self._ret_fused = False  # ... updates retention states in place
-        self._ret_chunk = {}     # chunk width -> a prefill call does too
+        self._kernels = None    # which ones serve over the pool, once it is
         # what one decode round hands the next, on the device (_carry_ops):
         # a sequence holds a slot of it from admission to retirement
         self._carry = None
@@ -831,32 +797,16 @@ class GenServer:
         self._bubble_cause = "idle"
         self._pool_dry = False               # _admit broke on a dry pool
         self._dev_s: Dict[str, float] = {}   # phase -> booked device s
-        self._tick_rows = 0                  # padded rows dispatched
-        self._tick_real_rows = 0             # real rows dispatched
-        self._tick_dev_steps = 0             # single-token device steps
-        self._tick_inplace_steps = 0         # ... that attended in place
-        self._tick_ret_fused_steps = 0       # ... updated states in place
-        self._tick_ahead_steps = 0           # ... dispatched ahead of a read
-        self._tick_passes = 0                # passes of the model dispatched
-        self._tick_row_passes = 0            # ... times the real rows in each
-        self._tick_expert_slots = 0          # experts held x layers x passes
-        self._tick_experts_read = 0          # experts the rounds read back
-        self._tick_prefill = [0] * 7         # prefill: calls, experts read,
-        #                                      experts held x layers x calls
-        #                                      that count them, prompt tokens,
-        #                                      rows, rows that began from a
-        #                                      carried state, rows whose
-        #                                      chunk ran the retention kernel
-        self._tick_tokens = 0                # tokens emitted
-        self._tick_retired = 0               # sequences retired
+        #: what this tick's calls add up to, under the tick record's own
+        #: names: cleared at a tick's start, added to where a call is
+        #: counted, and the record's counts as they stand (``_tick``)
+        self._counts: collections.Counter = collections.Counter()
+        #: ... and what it notes a value at a time: (n_blocks, age_s) of
+        #: blocks freed; streamed requests' stages submit -> admit and
+        #: admit -> first chunk queued (/genperf ``requests``)
+        self._noted: Dict[str, list] = {
+            "kv_ages": [], "req_queue_s": [], "req_prefill_s": []}
         self._phases: Dict[str, float] = {}  # phase -> host wall s
-        self._tick_kv_pos = 0                # cache positions streamed
-        self._tick_kv_blocks = 0             # blocks the tables covered
-        self._tick_kv_ages: List[tuple] = []  # (n_blocks, age_s) freed
-        #: streamed requests' scheduler-side stages, per tick: submit ->
-        #: admit and admit -> first chunk queued (/genperf ``requests``)
-        self._tick_req_queue_s: List[float] = []
-        self._tick_req_prefill_s: List[float] = []
         # cost-ledger scratch (utils/costledger.py): per-phase tenant
         # splits of the tick's padded capacity + KV-block-seconds freed
         # this tick.  None when the ledger kill switch is off — the
@@ -1085,13 +1035,7 @@ class GenServer:
             },
             "block_size": self.block_size,
             "span": self.span,
-            # how a round decodes its span: blocks of so many positions,
-            # so many denoising passes a block (1 and 1: a token a step)
-            "round": {
-                "block_length": self._block,
-                "denoising_steps": int(
-                    getattr(self.cfg, "denoising_steps", 1)),
-            },
+            "round": self._served.round,
             "prefill_chunk": self.prefill_chunk,
             "prefill_chunk_effective": self._chunk_eff,
             "admitted_total": self.admitted_total,
@@ -1189,10 +1133,8 @@ class GenServer:
         # local tick ran (the init lock makes that safe — pool MUTATION
         # stays scheduler-thread-only afterwards)
         from seldon_core_tpu.models.generate import (
-            decode_inplace,
             init_block_pool,
             paged_forward_jit,
-            retention_fused,
         )
 
         self._pool = init_block_pool(
@@ -1207,22 +1149,19 @@ class GenServer:
             from seldon_core_tpu.runtime.servingmesh import shard_gen_pool
 
             self._pool = shard_gen_pool(self.mesh, self._pool)
-        # the Pallas kernel or the gather path: decided here, once, because
-        # only the scheduler sees the mesh its pool is sharded over
-        self._inplace = decode_inplace(
-            self._pool, self.mesh, width=self._block,
-            heads=self.cfg.n_heads, rows=_pow2(self.slots),
-            head_dim=self.cfg.hd)
-        # and likewise for a generator of retention layers: the kernel
-        # that updates a row's state where it lies, or the row-by-row step
-        self._ret_fused = retention_fused(
-            self._pool, self.mesh, heads=self.cfg.n_heads,
-            rows=_pow2(self.slots))
+        # the Pallas kernels or the jax.numpy forms: decided here, once,
+        # because only the scheduler sees the mesh its pool is sharded over
+        self._kernels = self._served.kernels(
+            self._pool, self.mesh, _pow2(self.slots),
+            self.params["embed"].dtype)
         if self.spec:
             self._draft_pool = init_block_pool(
                 self.draft_cfg, self.num_blocks, self.block_size)
             self._draft_allocator = BlockAllocator(self.num_blocks)
-        self._register_decode_costs()
+        # what a decoded token costs: utils/genperf.py prices served decode
+        # with it (``OBSERVATORY.cost_features``)
+        OBSERVATORY.record_compile(
+            "gen_decode_step", self._served.decode_costs(), None)
         _keep_out_of_program_locations()
         self._init_carry()
         self._load_programs()
@@ -1238,8 +1177,9 @@ class GenServer:
             blocks = self._allocator.alloc(self._blocks_needed(P))
             if blocks is None:
                 raise RuntimeError(
-                    f"{self._holds} pool ({self.num_blocks} blocks) smaller than "
-                    f"the shared prefix ({self._blocks_needed(P)} blocks)")
+                    f"{self._served.holds} pool ({self.num_blocks} blocks) "
+                    f"smaller than the shared prefix "
+                    f"({self._blocks_needed(P)} blocks)")
             _, self._pool = paged_forward_jit(
                 self.params, jnp.asarray(ids), self._pool,
                 jnp.asarray([blocks], jnp.int32), jnp.zeros((1,), jnp.int32),
@@ -1249,38 +1189,6 @@ class GenServer:
             full = P // self.block_size
             self._prefix_blocks = blocks[:full]
             self._prefix_tail = blocks[full] if P % self.block_size else None
-
-    def _refuse_states_that_cannot_fit(self) -> int:
-        """A generator of retention layers holds one state entry a BLOCK
-        and a layer (models/generate.py init_block_pool): refuse a pool
-        whose entries the device's memory cannot hold beside the
-        parameters -- the default block of 16 positions and pool of a
-        thousand blocks are 35 GB a layer at the published widths -- and
-        say which two settings to change, where the allocation would only
-        fail.  Returns the bytes of one row's states over the layers."""
-        import jax
-
-        from seldon_core_tpu.models.generate import init_block_pool
-
-        shapes = jax.eval_shape(
-            lambda: init_block_pool(self.cfg, 1, self.block_size))
-        row = sum(a.size * a.dtype.itemsize
-                  for a in jax.tree.leaves(shapes))
-        limit = _device_memory_bytes()
-        held = sum(a.size * a.dtype.itemsize
-                   for a in jax.tree.leaves(self.params))
-        if limit and self.num_blocks * row + held > limit:
-            raise ValueError(
-                f"a generator of retention layers keeps a state of "
-                f"{row / 1e6:.1f} MB a BLOCK of the pool, whatever the "
-                f"block holds: {self.num_blocks} blocks are "
-                f"{self.num_blocks * row / 1e9:.1f} GB beside "
-                f"{held / 1e9:.1f} GB of parameters, and the device has "
-                f"{limit / 1e9:.1f} GB.  Deploy a block a row: set "
-                "SELDON_TPU_GEN_BLOCK_SIZE to the longest row (prompt + "
-                "answer + one round, a multiple of the prefill chunk) and "
-                "SELDON_TPU_GEN_POOL_BLOCKS to the rows held at once + 1")
-        return row
 
     def _new_carry(self):
         """A zeroed carry on the device (``_carry_ops``).  Under a mesh it is
@@ -1325,8 +1233,8 @@ class GenServer:
                 # raw key data from the host: an imported row's (decode role)
                 key_data = np.asarray(key_data)
                 carry, _ = put(carry, idx, tok, seen, key_data)
-            if self._block > 1:
-                return      # a prefill of diffusion blocks picks no token
+            if not self._served.picks_first:
+                return      # no prefill of such a generator picks a token
             first(carry, np.zeros((rows, self.cfg.vocab), np.float32), idx,
                   np.zeros((rows,), bool), np.zeros((rows,), np.int32),
                   key_data, temperature=self.temperature, top_k=self.top_k,
@@ -1369,12 +1277,13 @@ class GenServer:
         if kind == "prefill":
             toks, tables, start, width = operands
             kw = {"cfg": self.cfg, "last_only": True}
-            if self._block > 1:
+            if not self._served.picks_first:
                 # no token is chosen from a prompt: no head, and the
                 # experts read in the logits' place (paged_forward)
                 kw["head"] = False
-            if self._ret_row_bytes:
-                kw["fused"] = self._chunk_fused(toks.shape[1])
+            fused = self._kernels.fused(toks.shape[1])
+            if fused is not None:
+                kw["fused"] = fused
             return (paged_forward_jit,
                     (params, toks, pool, tables, start, width), kw)
         tables, token, n_valid, active, seen, keys = operands
@@ -1384,23 +1293,7 @@ class GenServer:
                 {"span": self.span, "temperature": self.temperature,
                  "top_k": self.top_k, "top_p": self.top_p,
                  "eos_token": self.eos_token,
-                 "inplace": self._inplace or self._ret_fused})
-
-    def _chunk_fused(self, width: int):
-        """Whether a prefill call of ``width`` positions a row works on the
-        retention states where they lie in the pool (the chunk kernel of
-        ops/retention.py) or row by row in ``jax.numpy``: asked once a
-        width, here, because only the scheduler sees its mesh (as
-        ``_ret_fused`` is for the decode round).  False without such
-        layers."""
-        if width not in self._ret_chunk:
-            from seldon_core_tpu.models.generate import retention_fused
-
-            self._ret_chunk[width] = retention_fused(
-                self._pool, self.mesh, heads=self.cfg.n_heads,
-                rows=_pow2(self.slots), width=width,
-                dtype=self.params["embed"].dtype)
-        return self._ret_chunk[width]
+                 "inplace": self._kernels.inplace})
 
     def _note_program(self, kind: str, shape: tuple) -> None:
         """A tick is about to dispatch ``shape``.  One the boot did not
@@ -1445,7 +1338,7 @@ class GenServer:
             "prefill_chunk": self.prefill_chunk, "span": self.span,
             "temperature": self.temperature, "top_k": self.top_k,
             "top_p": self.top_p, "eos_token": self.eos_token,
-            "inplace": self._inplace or self._ret_fused, "role": self.role,
+            "inplace": self._kernels.inplace, "role": self.role,
         }, sort_keys=True)
 
     def _load_programs(self) -> None:
@@ -1518,8 +1411,9 @@ class GenServer:
                 # what `take` hands a round, by its own account
                 token, seen, keys = jax.eval_shape(
                     take, self._carry, S((B,)))
-                if self._block > 1:
-                    token = S((B, self._block))     # the host's (_decode_round)
+                held = self._served.held(B)
+                if held is not None:
+                    token = _abstract(held)     # the host's (_decode_round)
                 operands = (S(shape), token, S((B,)), S((B,), bool), seen,
                             _abstract(self._zero_keys[B])
                             if keys is None else keys)
@@ -1548,57 +1442,6 @@ class GenServer:
                         "%s program %s did not load ahead of its dispatch "
                         "(%s: %s)", *job, type(e).__name__, e, exc_info=True)
         return loaded
-
-    def _register_decode_costs(self) -> None:
-        """Analytic per-token cost features for the SERVED decode lane,
-        registered once at device init under ``gen_decode_step`` — the
-        read side is ``OBSERVATORY.cost_features`` in utils/genperf.py,
-        which prices served decode MFU / HBM-BW utilization against
-        REAL tokens (matmul weights at serving dtype, two KV tensors per
-        position plus int8 scales).  Never raises: accounting must not
-        block serving."""
-        try:
-            cfg = self.cfg
-            d, L = cfg.d_model, cfg.n_layers
-            ff, v = cfg.d_ff, cfg.vocab
-            kvh = getattr(cfg, "kv_heads", 0) or cfg.n_heads
-            hd = cfg.hd
-            q_out = cfg.n_heads * hd
-            qkv_out = q_out + 2 * kvh * hd
-            # a token's own work in a layer, by the layer's kind
-            # (LMConfig.kind): the mixer's matrices and the FFN's -- of an
-            # expert layer the router and the token's moe_k experts
-            mixers = {"attn": d * qkv_out + q_out * d, "conv": 4 * d * d,
-                      "ret": d * (qkv_out + kvh) + q_out * d}
-            ffns = {"gelu": 2 * d * ff, "moe": 2 * d * ff,
-                    "gated": 3 * d * ff,
-                    "experts": d * cfg.n_experts + (
-                        cfg.moe_k * 3 * d * getattr(cfg, "d_expert", 0))}
-            kinds = getattr(cfg, "kinds", (("attn", "gelu"),) * L)
-            layers = sum(mixers[m] + ffns[f] for m, f in kinds)
-            attending = sum(m == "attn" for m, _ in kinds)
-            wb = 1 if getattr(cfg, "quant", "none") == "int8" else 2
-            kv_int8 = getattr(cfg, "kv_quant", "none") == "int8"
-            kvb = 1 if kv_int8 else 2
-            OBSERVATORY.record_compile("gen_decode_step", {
-                # matmul FLOPs per generated token (attention's
-                # position-dependent term excluded)
-                "flops": float(2 * (layers + d * v)),
-                # HBM bytes ONE device step streams regardless of batch:
-                # every matmul'd weight once, the bf16 unembed once
-                "bytes_accessed": float(wb * layers + 2 * d * v),
-                "output_bytes": 0.0,
-                # HBM bytes per CACHE POSITION a step's attention reads
-                # (k + v across the layers that attend, + f32 scales when
-                # int8 KV)
-                "kv_bytes_per_position": float(
-                    attending * (2 * kvh * hd * kvb
-                                 + (8 * kvh if kv_int8 else 0))
-                ),
-            }, None)
-        except Exception:  # noqa: BLE001 - accounting must not block serving
-            logger.debug("decode cost-feature registration failed",
-                         exc_info=True)
 
     def _run(self) -> None:
         while True:
@@ -1731,16 +1574,9 @@ class GenServer:
         bubble_cause = self._bubble_cause
         self._pool_dry = False
         self._dev_s = {}
-        self._tick_rows = self._tick_real_rows = 0
-        self._tick_dev_steps = self._tick_kv_pos = self._tick_kv_blocks = 0
-        self._tick_inplace_steps = self._tick_ahead_steps = 0
-        self._tick_ret_fused_steps = 0
-        self._tick_passes = self._tick_row_passes = 0
-        self._tick_expert_slots = self._tick_experts_read = 0
-        self._tick_prefill = [0] * 7
+        self._counts.clear()
         self._tick_attr = {} if costledger_enabled() else None
         self._tick_kv_attr = []
-        self._tick_tokens = self._tick_retired = 0
         self._paced = None
         self._ensure_device()
         phases = self._phases = {}
@@ -1798,7 +1634,7 @@ class GenServer:
             self._collect(self._unread[0])
         with _Phase("GenServer._retire", phases, "retire"):
             self._retire_finished()
-        tokens, retired = self._tick_tokens, self._tick_retired
+        tokens, retired = self._counts["tokens"], self._counts["retired"]
         kind = None
         if prefilled:
             kind = "mixed" if decoded else "prefill"
@@ -1811,44 +1647,22 @@ class GenServer:
         if kind is not None:
             self.tokens_emitted_total += tokens
         wall = time.perf_counter() - t0
-        ages, self._tick_kv_ages = self._tick_kv_ages, []
         detail = {
             "wall_s": wall,
             "device_s": sum(self._dev_s.values()),
             "phases": phases,
             "device_phases": dict(self._dev_s),
-            "rows": self._tick_rows,
-            "real_rows": self._tick_real_rows,
-            "tokens": tokens,
-            "steps": self._tick_dev_steps,
-            "inplace_steps": self._tick_inplace_steps,
-            "retention_fused_steps": self._tick_ret_fused_steps,
-            "ahead_steps": self._tick_ahead_steps,
-            "passes": self._tick_passes,
-            "row_passes": self._tick_row_passes,
-            "experts_read": self._tick_experts_read,
-            "expert_slots": self._tick_expert_slots,
-            "prefill_calls": self._tick_prefill[0],
-            "prefill_experts_read": self._tick_prefill[1],
-            "prefill_expert_slots": self._tick_prefill[2],
-            "prefill_tokens": self._tick_prefill[3],
-            "prefill_rows": self._tick_prefill[4],
-            "prefill_carried_rows": self._tick_prefill[5],
-            "prefill_retention_fused_rows": self._tick_prefill[6],
-            "retention_row_bytes": self._ret_row_bytes,
-            "kv_positions": self._tick_kv_pos,
-            "kv_blocks": self._tick_kv_blocks,
-            "kv_ages": tuple(ages),
+            "retention_row_bytes": self._served.retention_row_bytes,
+            # what the tick's calls counted, each where it was dispatched
+            **self._counts,
         }
+        for name, values in self._noted.items():
+            if values:
+                detail[name] = tuple(values)
+                values.clear()
         if bubble_s > 0.0:
             detail["bubble_s"] = bubble_s
             detail["bubble_cause"] = bubble_cause
-        if self._tick_req_queue_s:
-            detail["req_queue_s"] = tuple(self._tick_req_queue_s)
-            self._tick_req_queue_s.clear()
-        if self._tick_req_prefill_s:
-            detail["req_prefill_s"] = tuple(self._tick_req_prefill_s)
-            self._tick_req_prefill_s.clear()
         if self._tick_attr is not None:
             # cost-ledger payload: per-phase tenant splits of the padded
             # capacity, KV-block-seconds freed this tick, deployment
@@ -2096,15 +1910,15 @@ class GenServer:
             # rebuild from the ORIGINAL prompt: emitted keeps growing, so
             # folding into the already-folded prompt would duplicate
             # context on a second preemption.  The last token is pending,
-            # not yet in the cache -- but for diffusion blocks, which hold
-            # no pending token: everything emitted is in the cache, and the
-            # readmitted row's next round starts where those tokens end
-            cached = seq.emitted if self._block > 1 else seq.emitted[:-1]
+            # not yet in the cache -- but where none ever is (``picks_first``):
+            # everything emitted is in the cache, and the readmitted row's
+            # next round starts where those tokens end
+            cached = seq.emitted
+            if self._served.picks_first:
+                cached, seq.pending = seq.emitted[:-1], seq.emitted[-1]
             seq.prompt = np.concatenate(
                 [seq.prompt0,
                  np.asarray(cached, np.int32)]).astype(np.int32)
-            if self._block == 1:
-                seq.pending = seq.emitted[-1]
         seq.prefill_pos = 0
         seq.n_valid = 0
         seq.inflight = 0
@@ -2138,7 +1952,7 @@ class GenServer:
             if seq.t_start > 0.0:
                 # KV residency at release — the pool-sizing histogram
                 # (seldon_tpu_gen_kv_block_age_seconds via the spine fold)
-                self._tick_kv_ages.append(
+                self._noted["kv_ages"].append(
                     (len(seq.blocks), time.time() - seq.t_start))
                 if self._tick_attr is not None:
                     # KV-block-seconds (blocks x held-time) land on the
@@ -2205,9 +2019,9 @@ class GenServer:
                     # is smaller than one request's first chunk
                     del self._waiting[idx]
                     self._finish_error(seq, RuntimeError(
-                        f"{self._holds} pool ({self.num_blocks} blocks of "
-                        f"{self.block_size}) cannot hold one prefill "
-                        "chunk (grow SELDON_TPU_GEN_POOL_BLOCKS)"))
+                        f"{self._served.holds} pool ({self.num_blocks} "
+                        f"blocks of {self.block_size}) cannot hold one "
+                        "prefill chunk (grow SELDON_TPU_GEN_POOL_BLOCKS)"))
                     continue
                 self._pool_dry = True   # bubble ledger: pool_exhaustion
                 break  # pool dry: wait for a retirement to free blocks
@@ -2248,7 +2062,8 @@ class GenServer:
                 req.t_admit = time.perf_counter()
                 RECORDER.observe_queue_wait(req.t_admit - req.t_submit)
                 if req.chunk is not None:
-                    self._tick_req_queue_s.append(req.t_admit - req.t_submit)
+                    self._noted["req_queue_s"].append(
+                        req.t_admit - req.t_submit)
         return admitted
 
     def _table(self, seq: _Sequence, nblk: int, draft: bool = False
@@ -2312,9 +2127,9 @@ class GenServer:
                         # Requeueing would livelock (admit -> prefill ->
                         # requeue at full device utilization, forever)
                         self._finish_error(seq, RuntimeError(
-                            f"{self._holds} pool ({self.num_blocks} blocks "
-                            f"of {self.block_size}) too small for prompt "
-                            f"length {len(seq.prompt)} (grow "
+                            f"{self._served.holds} pool ({self.num_blocks} "
+                            f"blocks of {self.block_size}) too small for "
+                            f"prompt length {len(seq.prompt)} (grow "
                             "SELDON_TPU_GEN_POOL_BLOCKS)"))
                         continue
                     self._waiting.appendleft(seq)
@@ -2345,9 +2160,7 @@ class GenServer:
             # the rows whose prompt ends here: their first token is picked
             # into the carry, or restored there when the host holds it (a
             # row readmitted after a preemption is never re-sampled)
-            # (a prefill of diffusion blocks chooses no token: its rows join
-            # the round with nothing pending and nothing to read back)
-            picks = self._block == 1
+            picks = self._served.picks_first
             ending = [(seq, i, picks and seq.pending is None)
                       for i, seq in enumerate(batch)
                       if seq.prefill_pos + widths[i] >= len(seq.prompt)]
@@ -2364,8 +2177,6 @@ class GenServer:
                     if key_data is not None:
                         key_data[i] = seq.key_data
             OBSERVATORY.note_padding(len(batch), B)
-            self._tick_rows += B
-            self._tick_real_rows += len(batch)
             # cost attribution: real units are this chunk's REAL prompt
             # tokens per sequence; the dispatched capacity is B x C (pad
             # rows and pad columns both burn the same device program).
@@ -2374,39 +2185,20 @@ class GenServer:
                 (s.request.tenant, s.request.tier, int(widths[i]), 0, 0)
                 for i, s in enumerate(batch)
             ])
-            self._tick_kv_blocks += sum(
-                self._blocks_needed(int(start[i]) + widths[i])
-                for i in range(len(batch)))
             # what this call is given, counted once: the tick record's
-            # share (/genperf ``served_prefill``) and the dispatching
-            # span's arguments are these same numbers
-            tokens = sum(widths)
-            # the experts a call could read, where the call counts the ones
-            # it did: a prefill that chooses no token returns that count in
-            # the logits' place, one that chooses returns its logits and no
-            # count, and slots nobody counts against would read as 0 read
-            expert_slots = (self._routed * self.cfg.n_experts
-                            if self._block > 1 else 0)
-            # rows whose layers' state is carried in from an earlier chunk
-            carried = (sum(int(start[i]) > 0 for i in range(len(batch)))
-                       if self._stateful else 0)
-            self._tick_prefill[0] += 1
-            self._tick_prefill[2] += expert_slots
-            self._tick_prefill[3] += tokens
-            self._tick_prefill[4] += len(batch)
-            self._tick_prefill[5] += carried
-            if self._chunk_fused(C):
-                self._tick_prefill[6] += len(batch)
+            # (/genperf ``served_prefill``) and the span's are these numbers
+            starts = start[:len(batch)].tolist()
+            work = self._served.prefill_counts(starts, widths)
+            self._counts.update(
+                {"prefill_" + name: n for name, n in {
+                    **work, **self._kernels.prefill_counts(C, len(batch)),
+                    "calls": 1, "rows": len(batch)}.items()},
+                rows=B, real_rows=len(batch),
+                kv_blocks=sum(self._blocks_needed(lo + w)
+                              for lo, w in zip(starts, widths)))
             self._dispatched += 1
-            work = dict(
-                seq=self._dispatched, rows=B, real_rows=len(batch),
-                nblk=nblk, tokens=tokens,
-                kv_positions=int(start.sum()) + tokens,
-                # positions the call's tokens attend to: causal, a token's
-                # own index + 1
-                attended=sum(w * int(start[i]) + w * (w + 1) // 2
-                             for i, w in enumerate(widths)),
-                expert_slots=expert_slots, carried_rows=carried)
+            work = dict(seq=self._dispatched, rows=B, real_rows=len(batch),
+                        nblk=nblk, **{k: work[k] for k in _PREFILL_SPAN})
         # fenced (depth 0): dispatch -> ready with nothing queued ahead, the
         # annotation a trace reduction sets the module event against (how
         # much of the fence is not device time).  Otherwise the dispatch is
@@ -2417,7 +2209,6 @@ class GenServer:
             t_dispatch = time.perf_counter()
             fn, args, kw = self._program(
                 "prefill", toks, tables, start, width)
-            # (diffusion blocks: the experts read, in the logits' place)
             logits, self._pool = fn(*args, **kw)
             if self.spec:
                 d_nblk = _pow2(max(
@@ -2440,7 +2231,7 @@ class GenServer:
                 first.copy_to_host_async()
             fl = _Flight("prefill", B, ending, logits, first, keys,
                          t_dispatch, attr, work["seq"])
-            if not picks and getattr(self.cfg, "d_expert", 0):
+            if not picks and self._served.counts_experts:
                 # what came in the logits' place: one int32 a chunk
                 fl.read = logits
                 fl.read.copy_to_host_async()
@@ -2485,7 +2276,7 @@ class GenServer:
                     key_data = np.asarray(fl.keys)
             if fl.read is not None:
                 counted["experts_read"] = int(np.asarray(fl.read))
-                self._tick_prefill[1] += counted["experts_read"]
+                self._counts["prefill_experts_read"] += counted["experts_read"]
         with _Phase("GenServer._prefill_tick/emit", seq=fl.seq, **counted):
             for seq, i, fresh in fl.rows:
                 # the per-sequence prefill span (admission -> prompt fully
@@ -2499,7 +2290,7 @@ class GenServer:
                     seq.inflight -= 1
                     seq.pending = int(first[i])
                     self._emit_tokens(seq, [seq.pending])
-                    self._tick_tokens += 1
+                    self._counts["tokens"] += 1
                     # one completed prefill = one request for the ledger's
                     # per-request usage normalization; the first served token
                     self._attr_note("prefill", 0, [
@@ -2557,14 +2348,14 @@ class GenServer:
             for seq in self._decodable():
                 if seq not in self._active or seq.done:
                     continue  # preempted, or finished in a drain, above
-                upto = self._round_base(seq) + self.span
+                upto = self._served.round_base(seq.n_valid) + self.span
                 if not self._ensure_capacity(seq, upto):
                     # pool exhausted even after eviction: this sequence is
                     # alone and cannot fit — surface a typed failure
                     self._active.remove(seq)
                     self._finish_error(seq, RuntimeError(
-                        f"{self._holds} pool too small for sequence length "
-                        f"{upto} (grow SELDON_TPU_GEN_POOL_BLOCKS)"))
+                        f"{self._served.holds} pool too small for sequence "
+                        f"length {upto} (grow SELDON_TPU_GEN_POOL_BLOCKS)"))
                     return None
 
         with _Phase("GenServer._decode_round/build"):
@@ -2573,8 +2364,9 @@ class GenServer:
                 return None
             B = _pow2(len(batch))
             nblk = _decode_table_width(
-                self._inplace, B,
-                max(self._blocks_needed(self._round_base(s) + self.span)
+                self._kernels.attends_inplace, B,
+                max(self._blocks_needed(
+                    self._served.round_base(s.n_valid) + self.span)
                     for s in batch),
                 self._allocator.capacity)
             self._note_program("decode", (B, nblk))
@@ -2582,12 +2374,9 @@ class GenServer:
             n_valid = np.zeros((B,), np.int32)
             active = np.zeros((B,), bool)
             idx = np.full((B,), self.slots, np.int32)   # pads: scratch
-            # diffusion blocks: the ids a row's first round finds in its
-            # first block, the prompt's remainder (no pending token rides
-            # the carry: nothing of a round depends on the one before it
-            # but the eos latch, which stays on the device)
-            held = (np.zeros((B, self._block), np.int32)
-                    if self._block > 1 else None)
+            # what the host holds in the pending token's place, where none
+            # rides the carry (models/served.py ``held``)
+            held = self._served.held(B)
             rows, skip = [], []
             for i, s in enumerate(batch):
                 tables[i] = self._table(s, nblk)
@@ -2595,8 +2384,8 @@ class GenServer:
                 active[i] = True
                 idx[i] = s.slot
                 # the round's first `off` positions are the row's own
-                # prompt again (0 but for a diffusion row's first round)
-                off = s.n_valid - self._round_base(s)
+                # prompt again (0 but for such a row's first round)
+                off = s.n_valid - self._served.round_base(s.n_valid)
                 if off:
                     held[i, :off] = s.prompt[len(s.prompt) - off:]
                 skip.append(off)
@@ -2604,59 +2393,28 @@ class GenServer:
                     self.span - off,
                     s.max_new - len(s.emitted) - s.inflight)))
             OBSERVATORY.note_padding(len(batch), B)
-            self._tick_rows += B
-            self._tick_real_rows += len(batch)
             # cost attribution: one real unit per LIVE sequence, capacity B
             # (the pow-2 row padding is the decode round's whole pad tax);
             # noted by the tick that books the round's device seconds
             attr = (B, [
                 (s.request.tenant, s.request.tier, 1, 0, 0) for s in batch])
-            self._tick_kv_blocks += sum(
-                self._blocks_needed(s.n_valid + self.span) for s in batch)
-            # cache positions the round streams (served HBM-BW accounting):
-            # each of the span steps attends over ~n_valid + step positions
-            cfg = self.cfg
-            blocks = self.span // self._block
-            if self._block > 1:
-                # every pass of a block reads the row's cache up to the
-                # block's end once, whatever the queries in it
-                passes = blocks * (cfg.denoising_steps + 1)
-                kv_positions = sum(
-                    (cfg.denoising_steps + 1)
-                    * (self._round_base(s) + (b + 1) * self._block)
-                    for s in batch for b in range(blocks))
-                # the pass that writes a block's K/V stops at its last
-                # layer's K/V: one expert layer fewer
-                skipped = blocks
-            else:
-                passes = self.span
-                kv_positions = sum(
-                    self.span * (s.n_valid + self.span // 2) for s in batch)
-                skipped = 0
-            # experts held x passes of the layers that hold experts
-            expert_slots = ((passes * self._routed - skipped)
-                            * cfg.n_experts if self._routed else 0)
-            self._tick_kv_pos += kv_positions
-            self._tick_dev_steps += self.span
-            self._tick_passes += passes
-            self._tick_row_passes += passes * len(batch)
-            self._tick_expert_slots += expert_slots
-            if self._inplace:
-                self._tick_inplace_steps += self.span
-            if self._ret_fused:
-                self._tick_ret_fused_steps += self.span
-            if self._unread:
+            # what the round is given, counted once: the tick record's
+            # (/genperf ``served_decode``) and the span's are these numbers
+            work = self._served.round_counts(
+                [s.n_valid for s in batch], self.span)
+            self._counts.update(
+                work, **self._kernels.round_counts(self.span),
+                rows=B, real_rows=len(batch), steps=self.span,
                 # queued behind a program whose results are still unread:
                 # the device goes from that one to this without the host
-                self._tick_ahead_steps += self.span
-            # the round's work as the tick record counts it above, on the
-            # dispatching span too, under the server's dispatch number
+                ahead_steps=self.span if self._unread else 0,
+                kv_blocks=sum(self._blocks_needed(s.n_valid + self.span)
+                              for s in batch))
             self._dispatched += 1
-            work = dict(
-                seq=self._dispatched, rows=B, real_rows=len(batch),
-                nblk=nblk, kv_positions=kv_positions,
-                inplace=int(bool(self._inplace)), passes=passes,
-                blocks=blocks, expert_slots=expert_slots)
+            work = dict(seq=self._dispatched, rows=B, real_rows=len(batch),
+                        nblk=nblk,
+                        inplace=int(bool(self._kernels.attends_inplace)),
+                        **{k: work[k] for k in _DECODE_SPAN})
         # fenced (depth 0): dispatch -> ready with nothing queued ahead, the
         # annotation the trace sets paged_decode_round's module event against
         # (decode_fence_slack_ms) and the guard's second half.  Otherwise
@@ -2704,13 +2462,6 @@ class GenServer:
             s.n_valid += self.span - off
         return fl
 
-    def _round_base(self, seq: _Sequence) -> int:
-        """The position ``seq``'s next decode round starts on: right after
-        what it holds, or -- diffusion blocks -- where the last whole block
-        of that ends (the rest of the prompt goes into the round's first
-        block again, and the round emits so many tokens fewer)."""
-        return seq.n_valid - seq.n_valid % self._block
-
     def _decode_collect(self, fl: _Flight) -> None:
         """Wait for a dispatched round and read its tokens back -- the one
         host sync a round needs -- then emit each row's share."""
@@ -2723,27 +2474,27 @@ class GenServer:
                 key_data = np.asarray(fl.keys)
             if fl.read is not None:
                 counted["experts_read"] = int(np.asarray(fl.read))
-                self._tick_experts_read += counted["experts_read"]
+                self._counts["experts_read"] += counted["experts_read"]
         with _Phase("GenServer._decode_round/emit", seq=fl.seq, **counted):
             for i, (s, take) in enumerate(fl.rows):
                 off = fl.skip[i]
                 s.inflight -= take
-                if self._block == 1:
+                if self._served.picks_first:
                     s.pending = int(toks[i, -1])
                 if key_data is not None:
                     s.key_data = key_data[i]
                 if s.done:
                     continue    # stopped a round ago: this one was padding
-                # one request for the cost ledger's per-request usage: a
-                # dense row's is noted with its prefill's first token; a
-                # diffusion row's prefill chose none, so its first tokens
-                # bring it (a row readmitted after a preemption has emitted)
-                first = self._block > 1 and not s.emitted
+                # one request for the cost ledger's per-request usage: noted
+                # with the prefill's first token where it picks one; where
+                # it chose none, the row's first tokens bring it (a row
+                # readmitted after a preemption has emitted)
+                first = not self._served.picks_first and not s.emitted
                 self._emit_tokens(
                     s, [int(t) for t in toks[i, off:off + take]])
                 self._seq_event(s, "decode_round", n_valid=s.n_valid,
                                 take=take)
-                self._tick_tokens += take
+                self._counts["tokens"] += take
                 if take > 0:
                     self._attr_note("decode", 0, [
                         (s.request.tenant, s.request.tier, 0, int(first),
@@ -2792,16 +2543,14 @@ class GenServer:
             n_valid[i] = s.n_valid
             active[i] = True
         OBSERVATORY.note_padding(len(batch), B)
-        self._tick_rows += B
-        self._tick_real_rows += len(batch)
         self._attr_note("decode", B, [
             (s.request.tenant, s.request.tier, 1, 0, 0) for s in batch])
-        self._tick_kv_blocks += sum(
-            self._blocks_needed(s.n_valid + W) for s in batch)
-        self._tick_kv_pos += sum(
-            W * (s.n_valid + W // 2) for s in batch)
-        # k sequential draft steps + one verify pass per round
-        self._tick_dev_steps += W
+        self._counts.update(
+            rows=B, real_rows=len(batch),
+            # k sequential draft steps + one verify pass per round
+            steps=W,
+            kv_blocks=sum(self._blocks_needed(s.n_valid + W) for s in batch),
+            kv_positions=sum(W * (s.n_valid + W // 2) for s in batch))
         td = time.perf_counter()
         new_toks, gained, corrected, self._pool, self._draft_pool = (
             paged_spec_round_jit(
@@ -2838,7 +2587,7 @@ class GenServer:
             accept_rounds += 1
         if accept_rounds:
             RECORDER.observe_accept_ratio(accept_sum / accept_rounds)
-        self._tick_tokens += emitted
+        self._counts["tokens"] += emitted
         return emitted
 
     # -- disaggregated handoff: prefill side ------------------------------
@@ -3211,7 +2960,7 @@ class GenServer:
                     # take its own stamp, before put() returns
                     req.t_first = time.perf_counter()
                     if req.t_admit is not None:
-                        self._tick_req_prefill_s.append(
+                        self._noted["req_prefill_s"].append(
                             req.t_first - req.t_admit)
                 req.queue.put(arr)
         if all(s.done for s in req.seqs):
@@ -3231,7 +2980,7 @@ class GenServer:
         for seq in [s for s in self._active if s.done]:
             self._active.remove(seq)
             self._retire(seq, seq.retire_reason or "length")
-            self._tick_retired += 1
+            self._counts["retired"] += 1
 
     def _record_seq_span(self, seq: _Sequence, name: str,
                          method: str) -> None:

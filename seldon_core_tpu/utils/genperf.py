@@ -65,6 +65,7 @@ way this module sees zero observations.
 from __future__ import annotations
 
 import bisect
+import collections
 import threading
 from typing import Any, Dict, Optional, Tuple
 
@@ -83,6 +84,37 @@ TICK_PHASES = ("admit", "prefill", "decode", "retire", "host_other")
 #: the stages a streamed request's time to first token splits into
 REQUEST_STAGES = ("lane_in", "queue", "prefill", "lane_out")
 
+#: ``served_decode``'s counts, each with the tick record's name for it (what
+#: runtime/genserver.py counts a call under, most of it given by
+#: models/served.py), folded from decode / spec / mixed ticks; docs/
+#: operations.md "reading the /genperf page" says how to read each.
+#: ``real_tokens`` emitted; ``device_steps``, the single-token steps run, of
+#: which ``inplace_steps`` attended over the block pool in place,
+#: ``retention_fused_steps`` updated the retention states where they lie
+#: and ``ahead_steps`` were dispatched before the program ahead was read;
+#: ``kv_positions`` attended over (module docstring); ``passes`` of the model
+#: (one a step, or a block's denoising passes and the K/V one) and
+#: ``row_passes``, summed over the real rows of each (real_tokens /
+#: row_passes = tokens fixed a row-pass: 1, or 4/5 for blocks of four under
+#: four denoising passes); ``experts_read`` by the expert layers (the rounds'
+#: own count) of ``expert_slots``, the experts held x layers x passes
+SERVED_DECODE = dict(
+    {"real_tokens": "tokens", "device_steps": "steps"},
+    **{name: name for name in (
+        "inplace_steps", "retention_fused_steps", "ahead_steps",
+        "kv_positions", "passes", "row_passes", "experts_read",
+        "expert_slots")})
+
+#: ``served_prefill``'s, folded from every tick (a chunk is read back a tick
+#: after it was dispatched, whatever that tick's kind): ``calls``, the
+#: ``experts_read`` (where a prefill returns the count) of ``expert_slots``,
+#: the prompt ``tokens`` and real ``rows`` given, of which ``carried_rows``
+#: began from a state an earlier chunk left and ``retention_fused_rows``
+#: worked on the retention states where they lie (the chunk kernel)
+SERVED_PREFILL = {name: "prefill_" + name for name in (
+    "calls", "experts_read", "expert_slots", "tokens", "rows",
+    "carried_rows", "retention_fused_rows")}
+
 #: fixed log-spaced edges of the TTFT histogram, 1 ms ... 60 s at a ratio
 #: of 60000 ** (1 / 79) = 1.1494 (<= 1.15): fixed, so two documents of one
 #: process always subtract bucket by bucket
@@ -99,6 +131,12 @@ def booked_device_s(t_dispatch: float, t_done: float,
     two queued programs never book the same interval twice and the sum
     over a stretch of rounds is the stretch the device was seen busy."""
     return max(t_done - max(t_dispatch, prev_done), 0.0)
+
+
+def _fold(into, names: Dict[str, str], detail: Dict[str, Any]) -> None:
+    """Add the tick record's counts ``names`` lists to a section's."""
+    for name, key in names.items():
+        into[name] += int(detail.get(key, 0) or 0)
 
 
 class GenPerf:
@@ -129,37 +167,14 @@ class GenPerf:
         self.kv_blocks_touched = 0
         # served-decode accounting (decode/spec/mixed ticks only)
         self.decode_device_s = 0.0
-        self.decode_tokens = 0       # REAL tokens emitted by decode ticks
-        self.decode_steps = 0        # single-token device steps run
-        self.decode_inplace_steps = 0  # ... that attended in place
-        self.decode_ret_fused_steps = 0  # ... that updated states in place
-        self.decode_ahead_steps = 0    # ... dispatched ahead of a readback
-        self.decode_passes = 0         # passes of the model (a token a step:
-        #                                one a step; diffusion blocks: the
-        #                                denoising passes and the K/V one)
-        self.decode_row_passes = 0     # ... times the real rows in each
-        self.decode_experts_read = 0   # experts the expert layers read
-        self.decode_expert_slots = 0   # experts held x expert layers x passes
-        # prefill programs dispatched, the experts their expert layers read
-        # (read back where a prefill returns the count) of the experts held
-        # x expert layers x calls
-        self.prefill_calls = 0
-        self.prefill_experts_read = 0
-        self.prefill_expert_slots = 0
-        self.prefill_tokens = 0        # prompt tokens the calls were given
-        self.prefill_rows = 0          # real rows the calls were given
-        self.prefill_carried_rows = 0  # ... that began from a carried state
-        self.prefill_ret_fused_rows = 0  # ... whose chunk ran the kernel
-        # a generator of retention layers (a float32 matrix state a row and
-        # a layer, read and rewritten by every token): the bytes of state
-        # read + written by each kind of call -- the layers' bytes a row x
-        # the decode steps' real rows (``decode_row_passes``) and x the
-        # prefill calls' real rows (``prefill_rows``: a row of a call is one
-        # chunk through every layer; ``prefill_carried_rows`` of them began
-        # from a carried state)
-        self.retention_decode_bytes = 0
-        self.retention_prefill_bytes = 0
-        self.decode_kv_positions = 0  # cache positions streamed per step
+        #: a section's counts by its own names (``SERVED_DECODE``,
+        #: ``SERVED_PREFILL``) and, beside them, ``retention_state_bytes``:
+        #: the bytes of float32 matrix state a generator of retention layers
+        #: read + wrote, 2 x a row's bytes over the layers x the decode
+        #: steps' real rows (``row_passes``) or the prefill calls' (``rows``:
+        #: a row of a call is one chunk through every layer)
+        self.decode: collections.Counter = collections.Counter()
+        self.prefill: collections.Counter = collections.Counter()
         self.kv_block_age = Reservoir(1024)   # seconds held at release
         self.kv_blocks_released = 0
         self.tick_errors = 0
@@ -222,42 +237,13 @@ class GenPerf:
             if kind in ("decode", "spec", "mixed"):
                 self.decode_device_s += float(
                     dev_phases.get("decode", 0.0))
-                self.decode_tokens += int(detail.get("tokens", 0) or 0)
-                self.decode_steps += int(detail.get("steps", 0) or 0)
-                self.decode_inplace_steps += int(
-                    detail.get("inplace_steps", 0) or 0)
-                self.decode_ret_fused_steps += int(
-                    detail.get("retention_fused_steps", 0) or 0)
-                self.decode_ahead_steps += int(
-                    detail.get("ahead_steps", 0) or 0)
-                self.decode_kv_positions += int(
-                    detail.get("kv_positions", 0) or 0)
-                self.decode_passes += int(detail.get("passes", 0) or 0)
-                self.decode_row_passes += int(
-                    detail.get("row_passes", 0) or 0)
-                self.decode_experts_read += int(
-                    detail.get("experts_read", 0) or 0)
-                self.decode_expert_slots += int(
-                    detail.get("expert_slots", 0) or 0)
-            # a chunk is read back a tick after it was dispatched, whatever
-            # that tick's kind
-            self.prefill_calls += int(detail.get("prefill_calls", 0) or 0)
-            self.prefill_experts_read += int(
-                detail.get("prefill_experts_read", 0) or 0)
-            self.prefill_expert_slots += int(
-                detail.get("prefill_expert_slots", 0) or 0)
-            self.prefill_tokens += int(detail.get("prefill_tokens", 0) or 0)
-            self.prefill_rows += int(detail.get("prefill_rows", 0) or 0)
-            self.prefill_carried_rows += int(
-                detail.get("prefill_carried_rows", 0) or 0)
-            self.prefill_ret_fused_rows += int(
-                detail.get("prefill_retention_fused_rows", 0) or 0)
-            state = int(detail.get("retention_row_bytes", 0) or 0)
-            if state:
-                self.retention_decode_bytes += 2 * state * int(
-                    detail.get("row_passes", 0) or 0)
-                self.retention_prefill_bytes += 2 * state * int(
-                    detail.get("prefill_rows", 0) or 0)
+                _fold(self.decode, SERVED_DECODE, detail)
+            _fold(self.prefill, SERVED_PREFILL, detail)
+            state = 2 * int(detail.get("retention_row_bytes", 0) or 0)
+            self.decode["retention_state_bytes"] += state * int(
+                detail.get("row_passes", 0) or 0)
+            self.prefill["retention_state_bytes"] += state * int(
+                detail.get("prefill_rows", 0) or 0)
             for n_blocks, age_s in kv_ages:
                 self.kv_blocks_released += int(n_blocks)
                 self.kv_block_age.observe(float(age_s))
@@ -303,53 +289,13 @@ class GenPerf:
 
         with self._lock:
             dev_s = self.decode_device_s
-            tokens = self.decode_tokens
-            steps = self.decode_steps
-            inplace_steps = self.decode_inplace_steps
-            ret_fused_steps = self.decode_ret_fused_steps
-            ahead_steps = self.decode_ahead_steps
-            kv_pos = self.decode_kv_positions
-            passes = {
-                # passes of the model a decode round made, and summed over
-                # the real rows of each (real_tokens / row_passes = tokens
-                # fixed a row-pass: 1 for a token a step, 4/5 for blocks of
-                # four under four denoising passes and the K/V one)
-                "passes": self.decode_passes,
-                "row_passes": self.decode_row_passes,
-                # experts the expert layers read (the rounds' own count,
-                # read back with their tokens) of the experts they hold
-                # x layers x passes: 0 / 0 without experts
-                "experts_read": self.decode_experts_read,
-                "expert_slots": self.decode_expert_slots,
-                # a generator of retention layers: the bytes of state
-                # that ``row_passes`` read + wrote (0 for every other
-                # generator)
-                "retention_state_bytes": self.retention_decode_bytes,
-            }
+            counts = {name: self.decode[name] for name in (
+                *SERVED_DECODE, "retention_state_bytes")}
+        tokens, steps = counts["real_tokens"], counts["device_steps"]
+        kv_pos = counts["kv_positions"]
         out: Dict[str, Any] = {
             "decode_device_s": round(dev_s, 4),
-            "real_tokens": tokens,
-            "device_steps": steps,
-            # ... of which attended over the block pool in place (the
-            # Pallas kernel, ops/paged_attention.py), not a gathered view
-            "inplace_steps": inplace_steps,
-            # ... of which updated every live row's retention states where
-            # they lie in the pool, one read and one write (the Pallas
-            # kernel of ops/retention.py), not row by row in jax.numpy; 0
-            # for a generator without such layers
-            "retention_fused_steps": ret_fused_steps,
-            # ... of which belonged to a round the scheduler put on the
-            # device's queue while an earlier program's results were still
-            # unread (runtime/genserver.py ``_tick``): the device did not
-            # wait for the host between that round and the one before
-            "ahead_steps": ahead_steps,
-            # live cache positions the single-token steps attended over,
-            # summed: the program's own count for a roofline reader.  Only
-            # the attention layers hold K/V at them: a gated short-
-            # convolution layer reads a fixed-size state a row instead,
-            # whatever the row's length (module docstring)
-            "kv_positions": kv_pos,
-            **passes,
+            **counts,
             "served_decode_mfu_pct": None,
             "served_decode_hbm_bw_util_pct": None,
             "served_decode_tok_s_device": (
@@ -469,26 +415,8 @@ class GenPerf:
                     },
                 },
             }
-            doc["served_prefill"] = {
-                "calls": self.prefill_calls,
-                "experts_read": self.prefill_experts_read,
-                "expert_slots": self.prefill_expert_slots,
-                "tokens": self.prefill_tokens,
-                # real rows of the calls, and those of them whose chunk
-                # began from a state an earlier chunk left (start > 0 in a
-                # generator with short-convolution layers; 0 without)
-                "rows": self.prefill_rows,
-                "carried_rows": self.prefill_carried_rows,
-                # ... whose chunk worked on every retention layer's state
-                # where it lies in the pool, one read and one write (the
-                # chunk kernel of ops/retention.py), not row by row in
-                # jax.numpy; 0 for a generator without such layers
-                "retention_fused_rows": self.prefill_ret_fused_rows,
-                # a generator of retention layers: a real row of a call is
-                # one chunk through every layer's state, and these are the
-                # bytes ``rows`` read + wrote (0 otherwise)
-                "retention_state_bytes": self.retention_prefill_bytes,
-            }
+            doc["served_prefill"] = {name: self.prefill[name] for name in (
+                *SERVED_PREFILL, "retention_state_bytes")}
         doc["served_decode"] = self.served_decode()
         return doc
 

@@ -857,7 +857,8 @@ def test_lanes_that_cannot_hold_the_state_refuse_by_name(model, monkeypatch):
         GenServer(**spec, **{**kw, "block_size": 16, "num_blocks": 1024})
     monkeypatch.setattr(genserver, "_device_memory_bytes", lambda: None)
     srv = GenServer(**spec, **kw)
-    assert srv._ret_row_bytes == LAYERS * 4 * (KV * HD + KV) * P
+    assert srv._served.retention_row_bytes == (
+        LAYERS * 4 * (KV * HD + KV) * P)
     srv.stop()
 
 
@@ -914,7 +915,7 @@ def test_genserver_serves_the_reference_answer_a_block_a_row_and_counts(
         monkeypatch.setattr(gen_mod, "retention_fused",
                             lambda *a, **kw: fused)
     srv = server(unit, params)
-    assert srv._ret_fused is False and not srv._inplace    # not decided yet
+    assert srv._kernels is None     # not decided yet: no pool
     try:
         cases = [(3, 6), (8, 9), (19, 7)]
         reqs = []
@@ -936,7 +937,8 @@ def test_genserver_serves_the_reference_answer_a_block_a_row_and_counts(
         assert snap["tick_errors_total"] == 0
         assert {p[-1] for kind in ("prefill", "decode")
                 for p in srv._programs[kind]} == {1}
-        assert srv._ret_fused == fused and not srv._inplace
+        assert srv._kernels.states_inplace == fused
+        assert not srv._kernels.attends_inplace
     finally:
         srv.stop()
     prefill, served = perf["served_prefill"], perf["served_decode"]

@@ -179,6 +179,11 @@ def test_batcher_lane_shared_flush_splits_by_real_rows():
     from seldon_core_tpu.runtime.batching import MicroBatcher
     from seldon_core_tpu.runtime.qos import qos_scope
 
+    # what an earlier test of this process left on the spine is not this
+    # test's to fold, and a loaded machine may stall the loop for longer
+    # than 50 ms between two of the submits below (ROADMAP C11: seen once
+    # under six workers, PR 44): the window is wide, the split is the same
+    SPINE.drain()
     LEDGER.reset()
 
     async def run():
@@ -186,8 +191,8 @@ def test_batcher_lane_shared_flush_splits_by_real_rows():
             await asyncio.sleep(0.02)
             return np.zeros((len(x), 1)), {}
 
-        mb = MicroBatcher(batch_fn, max_batch=8, max_wait_ms=100.0,
-                          pad_to_buckets=True, coalesce_ms=50.0)
+        mb = MicroBatcher(batch_fn, max_batch=8, max_wait_ms=1000.0,
+                          pad_to_buckets=True, coalesce_ms=400.0)
         mb.cost_deployment = "dep"
 
         async def one(tenant, rows):

@@ -810,3 +810,30 @@ def test_new_metric_families_registered():
         text = RECORDER.exposition().decode()
         assert "seldon_tpu_gen_bubble_seconds_total" in text
         assert "seldon_tpu_gen_served_mfu" in text
+
+
+# -- /genperf and /stats say what they said before models/served.py ------------
+
+import served_kinds  # noqa: E402, I001 - tests/served_kinds.py, beside this file
+
+
+@pytest.mark.parametrize("kind", list(served_kinds.KINDS))
+def test_a_scripted_run_reads_what_it_read_at_the_parent_commit(
+        kind, monkeypatch):
+    """One scripted run of a generator of ``kind`` (tests/served_kinds.py:
+    a fixed seed, three requests one after the other) against the fixture
+    the commit BEFORE the description was written from (PR 44: the scheduler
+    then reckoned every count itself): /genperf ``served_decode`` and
+    ``served_prefill`` and /stats ``genserver``, key for key and value for
+    value, the wall-clock fields left out by name
+    (``served_kinds.WALL_CLOCK``)."""
+    import os
+
+    path = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                        "resources", "genperf_parent.json")
+    with open(path) as f:
+        want = json.load(f)[kind]
+    got = json.loads(json.dumps(served_kinds.scripted_run(kind, monkeypatch)))
+    assert set(got) == set(want)
+    for section in want:
+        assert got[section] == want[section], section

@@ -468,7 +468,7 @@ def test_a_diffusion_generator_refuses_what_it_cannot_serve(model):
         with pytest.raises(ValueError, match=match):
             GenServer(**{**spec, "block_size": 8, "num_blocks": 16,
                          "slots": 2, "span": 8, "prefill_chunk": 16, **kw})
-    with pytest.raises(ValueError, match="greedily"):
+    with pytest.raises(ValueError, match="greedy"):
         TransformerGenerator(vocab=96, block_length=4, denoising_steps=4,
                              mask_id=MASK, temperature=0.5)
     with pytest.raises(ValueError, match="denoising_steps"):
