@@ -14,9 +14,11 @@ One KV layout, one decoder block, three programs:
     blocks through a block table; a gated short-convolution layer
     (``LMConfig.layer_kinds``) holds no K/V but a fixed-size state a
     sequence, ``[num_blocks, conv_kernel - 1, D]``, found by the row's
-    FIRST block, and a power-retention layer likewise a float32 matrix
-    state a KV head (ops/retention.py); ``_paged_block`` is the only
-    decoder block that reads or writes any of them;
+    FIRST block, a power-retention layer likewise a float32 matrix
+    state a KV head (ops/retention.py) and a Mamba-2 state-space layer its
+    convolution's taps and a float32 matrix state a head (ops/ssm.py),
+    beside the attention layers' K/V in one pool; ``_paged_block`` is the
+    only decoder block that reads or writes any of them;
   * ``paged_forward`` (a prompt, a prompt chunk or a verify pass),
     ``paged_decode_round`` (``span`` single-token steps as ONE ``lax.scan``
     inside jit — no per-token dispatch, no host round trip between steps;
@@ -290,7 +292,18 @@ def init_block_pool(cfg: LMConfig, num_blocks: int, block_size: int,
     -- so that the pool IS the table of states and holds nothing else.
     (``s`` is three-dimensional, rows of (KV head, value lane):
     bench/tools/rehearse_aot.py describes every four-dimensional float32
-    array of a pool as the chip's bfloat16 K/V.)"""
+    array of a pool as the chip's bfloat16 K/V.)
+
+    A Mamba-2 state-space layer's is {conv, h} (``_ssm``; ops/ssm.py):
+    ``conv`` ``[num_blocks, conv_kernel - 1, conv_dim]`` in the pool's
+    dtype, the convolution's input x | B | C at a sequence's last
+    positions, and ``h`` ``[num_blocks, heads, head_dim, state]``, float32
+    on every backend, both at the sequence's first block's id as a conv
+    state is: 2.1 MB a block at the published widths whatever the block
+    holds, beside the attention layers' K/V in the same pool -- so such a
+    generator is deployed with few, large blocks (runtime/genserver.py
+    refuses a pool whose state entries do not fit).  A block with no mixer
+    (an FFN alone) keeps nothing: its entry is empty."""
     from seldon_core_tpu.ops.paged_attention import heads_per_row
     from seldon_core_tpu.ops.retention import phi_width
 
@@ -308,6 +321,14 @@ def init_block_pool(cfg: LMConfig, num_blocks: int, block_size: int,
         dtype = jnp.float32
 
     def layer(mixer):
+        if mixer is None:
+            return {}
+        if mixer == "ssm":
+            return {"conv": jnp.zeros(
+                (num_blocks, cfg.conv_kernel - 1, cfg.ssm_conv_dim), dtype),
+                    "h": jnp.zeros((num_blocks, cfg.ssm_heads,
+                                    cfg.ssm_head_dim, cfg.ssm_state),
+                                   jnp.float32)}
         if mixer == "conv":
             return {"conv": jnp.zeros(
                 (num_blocks, cfg.conv_kernel - 1, cfg.d_model), dtype)}
@@ -497,23 +518,106 @@ def _short_conv(lp, x, pool_layer, tables, start, valid, cfg: LMConfig):
     with jax.named_scope("conv"):
         b, c, u = jnp.split(bcu, 3, axis=-1)
         z = b * u
-        slot = tables[:, 0]
         width = jnp.sum(jnp.broadcast_to(valid, (B, W)), axis=1)
-        old = jnp.where((start > 0)[:, None, None], state[slot], 0)
-        zz = jnp.concatenate([old.astype(z.dtype), z], axis=1)  # [B,K-1+W,D]
+        zz, state = _carried_taps(state, z, tables, start, width)
         taps = lp["conv_w"].astype(jnp.float32)
         y = sum(taps[j] * zz[:, j:j + W].astype(jnp.float32)
                 for j in range(K))
         y = c * y.astype(x.dtype)
-        # zz index of a row's valid position i is K-1+i: its last K-1 valid
-        # positions (reaching into the old state where it has fewer)
-        keep = width[:, None, None] + jnp.arange(K - 1)[None, :, None]
-        left = jnp.take_along_axis(zz, keep, axis=1)
-        state = state.at[jnp.where(width > 0, slot, 0)].set(
-            left.astype(state.dtype))
     with jax.named_scope("conv_out"):
         x = x + lm_matmul(lp, "conv_out", y, out_dtype=x.dtype)
     return x, {"conv": state}
+
+
+def _carried_taps(state, zz, tables, start, width):
+    """What a causal convolution of ``K`` taps reads before a row's first
+    position of this call, and what it leaves: ``state`` [N, K-1, C] holds
+    a sequence's input at its last K-1 positions at the id of its first
+    block.  Returns (the input with the carried positions in front,
+    [B, K-1+W, C] -- zeros for a row that starts at 0, whatever the entry
+    holds -- and ``state`` with each row's last K-1 VALID positions
+    written back, the scratch entry 0 for a row with none)."""
+    slot = tables[:, 0]
+    old = jnp.where((start > 0)[:, None, None], state[slot], 0)
+    zz = jnp.concatenate([old.astype(zz.dtype), zz], axis=1)
+    # zz index of a row's valid position i is K-1+i: its last K-1 valid
+    # positions (reaching into the old state where it has fewer)
+    keep = width[:, None, None] + jnp.arange(state.shape[1])[None, :, None]
+    left = jnp.take_along_axis(zz, keep, axis=1)
+    return zz, state.at[jnp.where(width > 0, slot, 0)].set(
+        left.astype(state.dtype))
+
+
+def _ssm(lp, x, pool_layer, tables, start, valid, cfg: LMConfig):
+    """The Mamba-2 state-space mixer on x [B, W, D] -> (x', pool layer'):
+    ``[z | xBC | dt] = in_proj(norm(x))``; ``xBC = silu(conv1d(xBC) +
+    bias)``, depthwise and causal over ``cfg.conv_kernel`` taps; ``[x | B |
+    C] = xBC`` (x as heads of ``ssm_head_dim``, B and C as ``ssm_groups``
+    groups of ``ssm_state``); ``dt = softplus(dt + dt_bias)`` and ``A =
+    -exp(A_log)``, float32; the recurrence of ops/ssm.py -- its step for a
+    call of one position a row, its chunked form otherwise; ``y =
+    norm_groups(y * silu(z))`` with a weight, an RMSNorm over each of the
+    ``ssm_groups`` groups of the inner width; ``x + out_proj(y)``.
+
+    A row's state is ``pool_layer`` at ``tables[b, 0]``: ``conv``, the
+    convolution's input at the K-1 positions before ``start[b]``, and
+    ``h``, the float32 matrix state -- both zero for a row that starts at
+    0, whatever the entry holds (a reused block needs no reset).  Pad
+    positions (``valid`` False, to the right of the valid ones) enter
+    neither a sum nor the state -- their ``dt`` is 0, which the recurrence
+    reads as no position -- and a row with no valid position (an empty
+    slot) writes the scratch entry 0."""
+    from seldon_core_tpu.ops.quant import lm_matmul
+    from seldon_core_tpu.ops.ssm import ssm_chunk, ssm_step
+
+    B, W, D = x.shape
+    H, P, G, N = (cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_groups,
+                  cfg.ssm_state)
+    inner, K = cfg.ssm_inner, cfg.conv_kernel
+    f32 = jnp.float32
+    valid = jnp.broadcast_to(valid, (B, W))
+    width = jnp.sum(valid, axis=1)
+    with jax.named_scope("ssm_in"):
+        u = lm_matmul(lp, "ssm_in", _rmsnorm(x, lp["ln1"], cfg.norm_eps),
+                      out_dtype=x.dtype)
+        z, xbc, dt = jnp.split(u, [inner, inner + cfg.ssm_conv_dim], axis=-1)
+    with jax.named_scope("ssm_conv"):
+        zz, taps_left = _carried_taps(pool_layer["conv"], xbc, tables, start,
+                                      width)
+        taps = lp["conv_w"].astype(f32)
+        xbc = sum(taps[j] * zz[:, j:j + W].astype(f32) for j in range(K))
+        xbc = jax.nn.silu(xbc + lp["conv_b"].astype(f32)).astype(x.dtype)
+    with jax.named_scope("ssm"):
+        xs, Bm, Cm = jnp.split(xbc, [inner, inner + G * N], axis=-1)
+        xs = xs.reshape(B, W, H, P)
+        Bm, Cm = Bm.reshape(B, W, G, N), Cm.reshape(B, W, G, N)
+        dt = jax.nn.softplus(dt.astype(f32) + lp["dt_bias"].astype(f32))
+        dt = jnp.where(valid[..., None], dt, 0.0)             # [B, W, H]
+        A = -jnp.exp(lp["A_log"].astype(f32))
+        skip = lp["ssm_D"].astype(f32)
+        slot = tables[:, 0]
+        h = jnp.where((start > 0)[:, None, None, None],
+                      pool_layer["h"][slot], 0.0)
+        if W == 1:
+            y, h = ssm_step(xs[:, 0], dt[:, 0], A, Bm[:, 0], Cm[:, 0], skip,
+                            h)
+            y = y[:, None]
+        else:
+            y, h = ssm_chunk(xs, dt, A, Bm, Cm, skip, h)
+        # a row's state goes back where it lies, row by row (an empty
+        # slot's to the scratch entry 0: what lands there last stays)
+        state = pool_layer["h"]
+        for b, entry in enumerate(jnp.where(width > 0, slot, 0)):
+            state = jax.lax.dynamic_update_slice(
+                state, h[b:b + 1].astype(state.dtype), (entry, 0, 0, 0))
+    with jax.named_scope("ssm_out"):
+        y = y.reshape(B, W, G, inner // G) * jax.nn.silu(
+            z.astype(f32)).reshape(B, W, G, inner // G)
+        y = y * jax.lax.rsqrt(
+            jnp.mean(y * y, axis=-1, keepdims=True) + cfg.norm_eps)
+        y = (y.reshape(B, W, inner).astype(x.dtype) * lp["ssm_norm"])
+        x = x + lm_matmul(lp, "ssm_out", y, out_dtype=x.dtype)
+    return x, {"conv": taps_left, "h": state}
 
 
 def _project_qkv(lp, x, positions, cfg: LMConfig, scope=jax.named_scope):
@@ -616,10 +720,12 @@ def _paged_block(lp, x, pool_layer, tables, start, valid, cfg: LMConfig,
 
     ``kind`` is the layer's ``(mixer, ffn)`` (``LMConfig.kind(i)``; None: an
     attention layer whose FFN is read off its weights): a "conv" layer
-    takes ``_short_conv`` in the attention's place and a "ret" layer
-    ``_retention`` -- its pool entry is the state, and what follows about
-    K/V does not concern it; ``fused`` has it work on the state where it
-    lies in the pool (the kernels of ops/retention.py -- a decode round's
+    takes ``_short_conv`` in the attention's place, a "ret" layer
+    ``_retention`` and an "ssm" layer ``_ssm`` -- its pool entry is the
+    state, and what follows about K/V does not concern it; a block of one
+    sub-layer is a mixer with no FFN (``ffn`` None) or an FFN with no mixer
+    (``mixer`` None: its pool entry is empty); ``fused`` has it work on
+    the state where it lies in the pool (the kernels of ops/retention.py -- a decode round's
     step, a prefill call's chunk -- under ``interpret`` in Pallas
     interpret mode) -- and the FFN is ``transformer._ffn``'s of that kind.
 
@@ -644,8 +750,25 @@ def _paged_block(lp, x, pool_layer, tables, start, valid, cfg: LMConfig,
     hd = cfg.hd
     q_out = cfg.n_heads * hd
     mixer, ffn = kind or ("attn", None)
+    # a block of one sub-layer (``kind`` says so; without it the FFN is
+    # read off the weights) lacks one half
+    lone = kind is not None and ffn is None
+
+    def alone(x, pool_layer, aux):
+        """What a block of one sub-layer hands on, behind a barrier: XLA's
+        TPU compiler, left to schedule fourteen such blocks as one stretch,
+        made a prefill program that never ended on the chip for block
+        tables whose ids do not ascend over the call's rows (found by
+        bisection, PERF.md section 6, PR 46: the same layers end behind a
+        barrier a block, and with the K/V rows written in ascending
+        order); behind it each block's writes are whole before the next
+        block is scheduled."""
+        x, pool_layer = jax.lax.optimization_barrier((x, pool_layer))
+        return x, pool_layer, aux
 
     def feed_forward(x):
+        if lone:
+            return x, jnp.int32(0)
         with jax.named_scope("ffn"):
             h = _rmsnorm(x, lp["ln2"], cfg.norm_eps)
             y, aux = _ffn(lp, h, cfg, mesh=None,
@@ -653,14 +776,18 @@ def _paged_block(lp, x, pool_layer, tables, start, valid, cfg: LMConfig,
                           if cfg.d_expert else None, kind=ffn)
             return x + y, aux
 
-    if mixer in ("conv", "ret"):
+    if mixer is None:
+        x, aux = feed_forward(x)
+        return alone(x, pool_layer, aux)
+    if mixer in ("conv", "ret", "ssm"):
         how = {}
         if mixer == "ret" and fused:
             how["fused"] = "interpret" if interpret else True
-        x, pool_layer = (_short_conv if mixer == "conv" else _retention)(
+        x, pool_layer = {"conv": _short_conv, "ret": _retention,
+                         "ssm": _ssm}[mixer](
             lp, x, pool_layer, tables, start, valid, cfg, **how)
         x, aux = feed_forward(x)
-        return x, pool_layer, aux
+        return alone(x, pool_layer, aux) if lone else (x, pool_layer, aux)
     # the stages below are jax.named_scope's: op metadata only (same
     # programs, same numerics), read back from a profile window's device
     # ops by bench/lib/trace_scopes.py — keep the names stable
@@ -692,7 +819,22 @@ def _paged_block(lp, x, pool_layer, tables, start, valid, cfg: LMConfig,
     with jax.named_scope("wo"):
         x = x + lm_matmul(lp, "wo", a, out_dtype=x.dtype)
     x, aux = feed_forward(x)
-    return x, pool_layer, aux
+    return alone(x, pool_layer, aux) if lone else (x, pool_layer, aux)
+
+
+def _experts_counted(read, cfg: LMConfig):
+    """What the expert layers of a program counted (``_ffn``'s aux, summed
+    over layers and passes) by the names the scheduler reads it under:
+    ``experts_read`` and, where a layer holds a share of the experts it
+    routes over (``cfg.experts_held``: the aux is a pair), the picks that
+    fell on held experts, ``expert_slots_held``.  ``read`` None gives the
+    sum's zero."""
+    if read is None:
+        return (jnp.zeros((2,), jnp.int32) if cfg.experts_held
+                else jnp.int32(0))
+    if cfg.experts_held:
+        return {"experts_read": read[0], "expert_slots_held": read[1]}
+    return {"experts_read": read}
 
 
 def _head(params, cfg: LMConfig):
@@ -750,7 +892,7 @@ def paged_forward(params, tokens, pool, tables, start, width,
         if cfg.d_expert:
             read = read + aux
     if not head:
-        return read, pool
+        return _experts_counted(read, cfg)["experts_read"], pool
     with jax.named_scope("unembed"):
         if last_only:
             idx = jnp.clip(width - 1, 0, W - 1)
@@ -900,13 +1042,13 @@ def paged_decode_round(params, pool, tables, token, n_valid, active,
 
     # the experts read ride the carry only where there are experts: a dense
     # configuration's program is the one it always was
-    read = (jnp.int32(0),) if cfg.d_expert else ()
+    read = (_experts_counted(None, cfg),) if cfg.d_expert else ()
     (pool, token, n_valid, seen_eos, keys, *read), toks = jax.lax.scan(
         step, (pool, token, n_valid, seen_eos, keys, *read), None,
         length=span
     )
     out = (toks.T, pool, token, n_valid, seen_eos, keys)
-    return out + ({"experts_read": read[0]},) if read else out
+    return out + (_experts_counted(read[0], cfg),) if read else out
 
 
 def _denoising_round(params, pool, tables, token, n_valid, active, seen_eos,
@@ -975,7 +1117,7 @@ def _denoising_round(params, pool, tables, token, n_valid, active, seen_eos,
         over the cache before ``start`` — by the kernel's ``plan``, or
         ``views``, the layers' caches gathered at the block's start; only
         the commit writes the pool."""
-        read = jnp.int32(0)
+        read = _experts_counted(None, cfg)
         with jax.named_scope("embed"):
             h = params["embed"][x]
         for i in range(cfg.n_layers):
@@ -1043,12 +1185,13 @@ def _denoising_round(params, pool, tables, token, n_valid, active, seen_eos,
         return (pool, seen_eos, read + r), (out, seen)
 
     (pool, seen_eos, read), (toks, seen) = jax.lax.scan(
-        block, (pool, seen_eos, jnp.int32(0)), jnp.arange(span // L))
+        block, (pool, seen_eos, _experts_counted(None, cfg)),
+        jnp.arange(span // L))
     toks = toks.transpose(1, 0, 2).reshape(B, span)
     n_valid = jnp.where(active, base + span, n_valid)
     out = (toks, pool, jnp.zeros((B,), jnp.int32), n_valid, seen_eos, keys)
     if cfg.d_expert:
-        out += ({"experts_read": read},)
+        out += (_experts_counted(read, cfg),)
     if trace_passes:
         out += (dict(zip(("saw", "picked", "chose"), seen)),)
     return out
@@ -1073,10 +1216,11 @@ def paged_spec_round(t_params, d_params, t_pool, d_pool, t_tables,
     (new_toks [B, k+1], gained [B], corrected [B], t_pool', d_pool'):
     row b's round output is new_toks[b, :gained[b]], its next pending
     token is corrected[b]."""
-    if set(t_cfg.layer_kinds + d_cfg.layer_kinds) & set("cr"):
+    if set(t_cfg.layer_kinds + d_cfg.layer_kinds) & set("crm"):
         raise ValueError(
-            "speculative decoding cannot serve a gated short-convolution "
-            "or retention layer: a rejected draft would have to roll the "
+            "speculative decoding cannot serve a gated short-convolution, "
+            "retention or state-space layer: a rejected draft would have to "
+            "roll the "
             "layer's state back, and the state keeps no history to roll "
             "back to")
     B = token.shape[0]
@@ -1121,7 +1265,7 @@ def paged_spec_round(t_params, d_params, t_pool, d_pool, t_tables,
 
 def paged_copy_block(pool, src, dst):
     """Copy block ``src`` onto block ``dst`` in every layer, pool to pool
-    (a short-convolution layer's state at that id with it).
+    (a short-convolution or state-space layer's state at that id with it).
     A shared prefix's full blocks are written once and SHARED by block-table
     reference across every sequence (pinned in the allocator); the partly
     filled boundary block must be private, because the sequence's own
@@ -1433,7 +1577,12 @@ class TransformerGenerator(Unit):
                  block_length: int = 1, denoising_steps: int = 1,
                  mask_id: int = -1, layer_kinds: str = "",
                  conv_kernel: int = 3, dense_layers: int = 0,
-                 router: str = "softmax"):
+                 router: str = "softmax", ssm_heads: int = 0,
+                 ssm_head_dim: int = 0, ssm_groups: int = 1,
+                 ssm_state: int = 0, expert_act: str = "silu",
+                 d_shared: int = 0, router_scale: float = 1.0,
+                 router_eps: float = 1e-6, experts_held: int = 0,
+                 experts_first: int = 0):
         # mesh (from the binding's mesh_axes, e.g. {"tp": 4}): params are
         # laid out with the LM's tp shardings and GSPMD partitions the
         # whole prefill+decode program across the mesh — one generator
@@ -1457,6 +1606,11 @@ class TransformerGenerator(Unit):
             # carries scalars, so the pattern comes as ONE string)
             layer_kinds=str(layer_kinds), conv_kernel=int(conv_kernel),
             dense_layers=int(dense_layers), router=str(router),
+            ssm_heads=int(ssm_heads), ssm_head_dim=int(ssm_head_dim),
+            ssm_groups=int(ssm_groups), ssm_state=int(ssm_state),
+            expert_act=str(expert_act), d_shared=int(d_shared),
+            router_scale=float(router_scale), router_eps=float(router_eps),
+            experts_held=int(experts_held), experts_first=int(experts_first),
         )
         # the lanes it cannot take, in the scheduler's words (served.py)
         served(self.cfg).refuse(

@@ -85,13 +85,15 @@ class Served:
     #: no layer holds K/V and the pool is one state entry a block
     holds: str
     #: some layer keeps a fixed-size state a sequence beside or in place of
-    #: K/V (a gated short convolution, power retention), in the pool at the
+    #: K/V (a gated short convolution, power retention, a Mamba-2
+    #: state-space layer), in the pool at the
     #: id of the sequence's FIRST block: zero at position 0, carried over
     #: chunks and rounds, freed with the block, recomputed from the prompt
     #: after a preemption -- and never snapshotted or rolled back
     stateful: bool
     #: layers whose FFN is dropless routed experts (not the leading dense
-    #: ones) and the experts each holds: what expert slots are counted over
+    #: ones) and the experts each holds HERE (all the router scores, or the
+    #: chip's share of them): what expert slots are counted over
     routed: int
     experts: int
     #: the programs count the experts they read (the programs' own test)
@@ -196,42 +198,59 @@ class Served:
 
     # -- what the pool holds, and the kernels over it ------------------------
 
-    @functools.cached_property
-    def retention_row_bytes(self) -> int:
-        """Bytes of one row's states over the retention layers (0 without):
-        what a decode step or a prefill chunk reads and writes a row."""
-        if self.holds != "state":
-            return 0
+    def _state_bytes(self, mixer: str) -> int:
+        """Bytes of ONE pool entry's states over the layers of ``mixer``:
+        a row's, since a row's state lives at its first block's id."""
         import jax
 
         from seldon_core_tpu.models.generate import init_block_pool
 
-        # (every layer of such a generator is one: the pool holds no other)
-        return _tree_bytes(
-            jax.eval_shape(lambda: init_block_pool(self.cfg, 1, 1)))
+        pool = jax.eval_shape(lambda: init_block_pool(self.cfg, 1, 1))
+        return _tree_bytes([pool[f"l{i}"] for i, (m, _) in enumerate(
+            self.cfg.kinds) if m == mixer])
+
+    @functools.cached_property
+    def retention_row_bytes(self) -> int:
+        """Bytes of one row's states over the retention layers (0 without):
+        what a decode step or a prefill chunk reads and writes a row."""
+        return self._state_bytes("ret")
+
+    @functools.cached_property
+    def ssm_row_bytes(self) -> int:
+        """... and over the Mamba-2 state-space layers: the float32 matrix
+        state and the convolution's taps."""
+        return self._state_bytes("ssm")
 
     def refuse_pool(self, num_blocks: int, params, limit) -> None:
-        """A generator of retention layers holds one state entry a BLOCK
-        and a layer (models/generate.py init_block_pool): refuse a pool
+        """A retention or state-space layer holds one state entry a BLOCK
+        of the pool (models/generate.py init_block_pool): refuse a pool
         whose entries a device of ``limit()`` bytes (None: it does not say)
         cannot hold beside the parameters -- the default block of 16
-        positions and pool of a thousand blocks are 35 GB a layer at the
-        published widths -- and say which two settings to change, where
-        the allocation would only fail."""
-        row = self.retention_row_bytes
+        positions and pool of a thousand blocks are 35 GB a retention layer
+        and 2.2 GB a state-space layer at the published widths -- and say
+        which two settings to change, where the allocation would only
+        fail."""
+        row = self.retention_row_bytes + self.ssm_row_bytes
         limit = limit() if row else None
         held = _tree_bytes(params)
         if limit and num_blocks * row + held > limit:
-            raise ValueError(
-                f"a generator of retention layers keeps a state of "
-                f"{row / 1e6:.1f} MB a BLOCK of the pool, whatever the "
-                f"block holds: {num_blocks} blocks are "
-                f"{num_blocks * row / 1e9:.1f} GB beside "
-                f"{held / 1e9:.1f} GB of parameters, and the device has "
-                f"{limit / 1e9:.1f} GB.  Deploy a block a row: set "
+            advice = (
+                "Deploy a block a row: set "
                 "SELDON_TPU_GEN_BLOCK_SIZE to the longest row (prompt + "
                 "answer + one round, a multiple of the prefill chunk) and "
-                "SELDON_TPU_GEN_POOL_BLOCKS to the rows held at once + 1")
+                "SELDON_TPU_GEN_POOL_BLOCKS to the rows held at once + 1"
+                if self.holds == "state" else
+                "Deploy few, large blocks: raise SELDON_TPU_GEN_BLOCK_SIZE "
+                "(the attention layers' K/V a position costs the same) and "
+                "lower SELDON_TPU_GEN_POOL_BLOCKS to the blocks the rows "
+                "held at once need")
+            raise ValueError(
+                f"a generator of retention or state-space layers keeps a "
+                f"state of {row / 1e6:.1f} MB a BLOCK of the pool, whatever "
+                f"the block holds: {num_blocks} blocks are "
+                f"{num_blocks * row / 1e9:.1f} GB beside "
+                f"{held / 1e9:.1f} GB of parameters, and the device has "
+                f"{limit / 1e9:.1f} GB.  " + advice)
 
     def kernels(self, pool, mesh, rows: int, dtype) -> Kernels:
         """The kernels that serve this generator over ``pool``, sharded
@@ -275,9 +294,16 @@ class Served:
         # matrices and the FFN's -- of an expert layer the router and the
         # token's moe_k experts
         mixers = {"attn": d * qkv_out + q_out * d, "conv": 4 * d * d,
-                  "ret": d * (qkv_out + kvh) + q_out * d}
+                  "ret": d * (qkv_out + kvh) + q_out * d,
+                  "ssm": d * (2 * cfg.ssm_inner + cfg.ssm_conv_dim
+                              + cfg.ssm_heads), None: 0}
+        # an expert is three matrices, or two where it has no gate; of a
+        # token's moe_k picks the share that falls on experts held here
+        mats = 3 if cfg.expert_act == "silu" else 2
         ffns = {"gelu": 2 * d * ff, "moe": 2 * d * ff, "gated": 3 * d * ff,
-                "experts": d * (cfg.n_experts + cfg.moe_k * 3 * cfg.d_expert)}
+                "experts": d * (cfg.n_experts + mats * (
+                    cfg.moe_k * cfg.d_expert * cfg.held / cfg.n_experts
+                    + cfg.d_shared)), None: 0}
         layers = sum(mixers[m] + ffns[f] for m, f in cfg.kinds)
         attending = sum(m == "attn" for m, _ in cfg.kinds)
         wb = 1 if cfg.quant == "int8" else 2
@@ -301,7 +327,7 @@ class Served:
 def served(cfg: LMConfig) -> Served:
     """The description of a generator of ``cfg``."""
     mixers = {mixer for mixer, _ in cfg.kinds}
-    stateful = bool(mixers & {"conv", "ret"})
+    stateful = bool(mixers & {"conv", "ret", "ssm"})
     refusals = []
     if cfg.block_length > 1:
         why = ("a generator by diffusion over blocks is served greedy, "
@@ -311,8 +337,8 @@ def served(cfg: LMConfig) -> Served:
     if stateful:
         # nothing snapshots a layer's state or rolls it back, so the lanes
         # that would have to are refused by name
-        lead = ("a generator with gated short-convolution or retention "
-                "layers is served unified and cannot take ")
+        lead = ("a generator with gated short-convolution, retention or "
+                "state-space layers is served unified and cannot take ")
         refusals += [
             ("draft", lead + "speculative decoding: a rejected draft would "
              "have to roll the layers' state back"),
@@ -320,16 +346,22 @@ def served(cfg: LMConfig) -> Served:
              "by table reference, the state after it is one sequence's"),
             ("roles", lead + "the prefill / decode roles: a handoff streams "
              "K/V blocks, not the layers' state")]
-    if "ret" in mixers:
+    if mixers & {"ret", "ssm"}:
         refusals.append((
-            "mesh", "a generator of retention layers is served on one chip: "
-            "nothing shards a layer's state over a mesh yet (by KV head, "
+            "mesh", "a generator of retention or state-space layers is "
+            "served on one chip: "
+            "nothing shards a layer's state over a mesh yet (by head, "
             "beside the parameters)"))
+    elif cfg.experts_held:
+        refusals.append((
+            "mesh", "an expert layer told which experts it holds is one "
+            "chip's share of a layer: no ``ep`` mesh exchanges the other "
+            "chips' parts yet"))
     return Served(
         cfg=cfg, quantum=int(cfg.block_length),
         block_passes=cfg.denoising_steps + 1 if cfg.block_length > 1 else 1,
         picks_first=cfg.block_length == 1,
         holds="state" if "ret" in mixers else "KV", stateful=stateful,
         routed=cfg.expert_layers,
-        experts=cfg.n_experts if cfg.expert_layers else 0,
+        experts=cfg.held if cfg.expert_layers else 0,
         counts_experts=bool(cfg.d_expert), refusals=tuple(refusals))

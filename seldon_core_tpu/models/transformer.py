@@ -23,6 +23,7 @@ step (used by ``__graft_entry__.dryrun_multichip``)."""
 
 from __future__ import annotations
 
+import math
 from dataclasses import dataclass
 from functools import partial
 from typing import Any, Dict, Optional, Tuple
@@ -48,6 +49,11 @@ __all__ = ["LMConfig", "lm_init", "lm_apply", "lm_loss", "lm_train_step",
            "save_lm_weights", "load_lm_weights",
            "lm_pipeline_params", "lm_pipeline_apply", "lm_pipeline_loss",
            "lm_pipeline_train_step"]
+
+
+#: ``LMConfig.layer_kinds`` letters of a block with one sub-layer
+_ONE_SUBLAYER = {"m": ("ssm", None), "t": ("attn", None),
+                 "e": (None, "experts")}
 
 
 @dataclass(frozen=True)
@@ -134,10 +140,41 @@ class LMConfig:
     # (``dense_layers == n_layers``).  ``router``: how an expert layer
     # scores, "softmax" or "sigmoid_bias" (parallel/moe.py).  Serving only,
     # as ``d_expert`` is.
+    # BLOCKS OF ONE SUB-LAYER (``x + sublayer(norm(x))``, one norm): "m" a
+    # Mamba-2 state-space mixer with no FFN (ops/ssm.py: ``ssm_heads`` heads
+    # of ``ssm_head_dim``, ``ssm_groups`` groups of B and C of
+    # ``ssm_state``, a depthwise causal convolution of ``conv_kernel`` taps
+    # with a bias over x | B | C; a layer that keeps those taps and a
+    # float32 matrix state ``[heads, head_dim, state]`` a sequence), "t"
+    # causal attention with no FFN, "e" an FFN with no mixer (the dropless
+    # experts of ``d_expert``).
     layer_kinds: str = ""
     conv_kernel: int = 3
     dense_layers: int = 0
     router: str = "softmax"
+    ssm_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_groups: int = 1
+    ssm_state: int = 0
+    # THE EXPERT LAYER'S FORM (parallel/moe.py ``moe_dropless``).
+    # ``expert_act``: "silu" gated experts ``w_down(silu(w_gate h) * w_up
+    # h)``, "relu2" experts of two matrices ``w_down(relu(w_up h)^2)``;
+    # ``d_shared`` > 0: a shared expert of that width and the same form on
+    # every token, added to the routed sum unweighted; ``router_scale``
+    # multiplies the routed weights; ``router_eps`` is what the
+    # "sigmoid_bias" renormalisation adds to the chosen scores' sum.
+    # ``experts_held`` > 0: THE CHIP'S SHARE of a layer divided over chips
+    # by expert parallelism -- the router keeps its ``n_experts`` outputs
+    # and its ``moe_k`` a token, the layer holds experts ``[experts_first,
+    # experts_first + experts_held)`` and computes their part of the result
+    # alone; what the absent experts would add is left out (nothing stands
+    # in for the other chips).  0 = every expert is held.
+    expert_act: str = "silu"
+    d_shared: int = 0
+    router_scale: float = 1.0
+    router_eps: float = 1e-6
+    experts_held: int = 0
+    experts_first: int = 0
 
     @property
     def hd(self) -> int:
@@ -147,14 +184,17 @@ class LMConfig:
     def is_moe_layer(self, i: int) -> bool:
         return self.moe_every > 0 and (i + 1) % self.moe_every == 0
 
-    def kind(self, i: int) -> Tuple[str, str]:
-        """Layer ``i`` as ``(mixer, ffn)``: "attn" | "conv" | "ret", and
-        "gelu" (the two-matrix FFN), "moe" (capacity-routed, ``moe_every``),
-        "gated" (a dense gated-SiLU layer) or "experts" (dropless).  The
+    def kind(self, i: int) -> Tuple[Optional[str], Optional[str]]:
+        """Layer ``i`` as ``(mixer, ffn)``: "attn" | "conv" | "ret" | "ssm",
+        and "gelu" (the two-matrix FFN), "moe" (capacity-routed,
+        ``moe_every``), "gated" (a dense gated-SiLU layer) or "experts"
+        (dropless); None for the half a block of one sub-layer lacks.  The
         one place that reads the fields above; a program traces the
         decoder block once a distinct kind."""
-        mixer = {"c": "conv", "r": "ret"}.get(self.layer_kinds[i:i + 1],
-                                              "attn")
+        letter = self.layer_kinds[i:i + 1]
+        if letter in _ONE_SUBLAYER:
+            return _ONE_SUBLAYER[letter]
+        mixer = {"c": "conv", "r": "ret"}.get(letter, "attn")
         if self.d_expert or self.dense_layers:
             return mixer, "gated" if i < self.dense_layers else "experts"
         return mixer, "moe" if self.is_moe_layer(i) else "gelu"
@@ -167,6 +207,21 @@ class LMConfig:
     def expert_layers(self) -> int:
         """Layers whose FFN is dropless routed experts."""
         return sum(ffn == "experts" for _, ffn in self.kinds)
+
+    @property
+    def held(self) -> int:
+        """Experts an expert layer holds here (``experts_held``)."""
+        return self.experts_held or self.n_experts
+
+    @property
+    def ssm_inner(self) -> int:
+        """Width of a state-space mixer's x: heads x head width."""
+        return self.ssm_heads * self.ssm_head_dim
+
+    @property
+    def ssm_conv_dim(self) -> int:
+        """Channels its convolution runs over: x | B | C."""
+        return self.ssm_inner + 2 * self.ssm_groups * self.ssm_state
 
     def __post_init__(self):
         if self.d_model % self.n_heads != 0:
@@ -202,11 +257,12 @@ class LMConfig:
             )
         if self.layer_kinds and (
                 len(self.layer_kinds) != self.n_layers
-                or set(self.layer_kinds) - set("acr")
+                or set(self.layer_kinds) - set("acrmte")
                 or self.conv_kernel < 2):
             raise ValueError(
                 f"layer_kinds={self.layer_kinds!r} is one letter a layer, "
-                f"'a', 'c' or 'r', for n_layers={self.n_layers}, and a "
+                f"'a', 'c' or 'r' (or, of one sub-layer, 'm', 't' or 'e'), "
+                f"for n_layers={self.n_layers}, and a "
                 f"convolution has at least 2 taps (got {self.conv_kernel})")
         if (not 0 <= self.dense_layers <= self.n_layers
                 or (self.dense_layers not in (0, self.n_layers)
@@ -220,10 +276,32 @@ class LMConfig:
             raise ValueError(
                 f"router={self.router!r} not supported "
                 "(softmax | sigmoid_bias)")
-        if set(self.layer_kinds) & set("cr") and (self.block_length > 1
-                                                  or self.moe_every):
+        if self.expert_act not in ("silu", "relu2"):
             raise ValueError(
-                "a gated short-convolution or retention layer rides neither "
+                f"expert_act={self.expert_act!r} not supported "
+                "(silu | relu2)")
+        if "e" in self.layer_kinds and not self.d_expert:
+            raise ValueError(
+                "an 'e' layer is dropless routed experts: it needs d_expert")
+        if not (0 <= self.experts_first
+                and self.experts_first + self.held <= self.n_experts):
+            raise ValueError(
+                f"experts_held={self.experts_held} from experts_first="
+                f"{self.experts_first} on reach past n_experts="
+                f"{self.n_experts}: the share is a range of the router's "
+                "own outputs")
+        if "m" in self.layer_kinds and not (
+                self.ssm_heads > 0 and self.ssm_head_dim > 0
+                and self.ssm_state > 0 and self.ssm_groups > 0
+                and self.ssm_heads % self.ssm_groups == 0):
+            raise ValueError(
+                "an 'm' layer (Mamba-2) needs ssm_heads, ssm_head_dim and "
+                "ssm_state, and ssm_groups that divide the heads")
+        if set(self.layer_kinds) & set("crm") and (self.block_length > 1
+                                                   or self.moe_every):
+            raise ValueError(
+                "a gated short-convolution, retention or state-space layer "
+                "rides neither "
                 "a round of denoising passes (block_length > 1: its state "
                 "would have to be rolled back a pass) nor moe_every")
         if "r" in self.layer_kinds and (
@@ -297,6 +375,45 @@ def apply_rope(x, positions, base: float = 10000.0):
     return out.astype(x.dtype)
 
 
+def _ssm_init(rng, cfg: LMConfig) -> Dict[str, Any]:
+    """One Mamba-2 mixer's weights (ops/ssm.py; models/generate.py
+    ``_ssm``): ``ssm_in`` [D, inner + conv_dim + heads] = z | x B C | dt
+    side by side, the taps ``conv_w`` [K, conv_dim] (the last one on the
+    position itself) and their bias ``conv_b``, ``ssm_norm`` [inner] the
+    gated norm's weight, ``ssm_out`` [inner, D]; and, float32, one a head:
+    ``A_log`` = log of a uniform draw from 1 .. 16 (a decay ``exp(-dt A)``
+    of 0.2 .. 0.999 a position), ``dt_bias`` the inverse softplus of a
+    log-uniform draw from 0.001 .. 0.1 (``time_step_min`` / ``_max``,
+    floored at ``time_step_floor`` 1e-4: Mamba-2's own initialisation, and
+    the published keys' values) and the skip ``ssm_D`` uniform from 0.5 ..
+    1.5.  The bias and the norm's weight are drawn too, NOT zero and one: a
+    program that drops either fails the comparison."""
+    dt = cfg.dtype
+    D, inner, heads = cfg.d_model, cfg.ssm_inner, cfg.ssm_heads
+    k = jax.random.split(rng, 8)
+
+    def dense(key, shape, fan_in):
+        return (jax.random.normal(key, shape, jnp.float32)
+                * (fan_in ** -0.5)).astype(dt)
+
+    step = jnp.maximum(jnp.exp(jax.random.uniform(
+        k[4], (heads,), jnp.float32, math.log(0.001), math.log(0.1))), 1e-4)
+    return {
+        "ssm_in": dense(k[0], (D, inner + cfg.ssm_conv_dim + heads), D),
+        "conv_w": dense(k[1], (cfg.conv_kernel, cfg.ssm_conv_dim),
+                        cfg.conv_kernel),
+        "conv_b": (0.1 * jax.random.normal(
+            k[2], (cfg.ssm_conv_dim,), jnp.float32)).astype(dt),
+        "A_log": jnp.log(jax.random.uniform(
+            k[3], (heads,), jnp.float32, 1.0, 16.0)),
+        "dt_bias": step + jnp.log(-jnp.expm1(-step)),
+        "ssm_D": jax.random.uniform(k[5], (heads,), jnp.float32, 0.5, 1.5),
+        "ssm_norm": (1.0 + 0.1 * jax.random.normal(
+            k[6], (inner,), jnp.float32)).astype(dt),
+        "ssm_out": dense(k[7], (inner, D), inner),
+    }
+
+
 def lm_init(rng, cfg: LMConfig) -> Dict[str, Any]:
     keys = jax.random.split(rng, cfg.n_layers * 4 + 1)
     dt = cfg.dtype
@@ -316,8 +433,14 @@ def lm_init(rng, cfg: LMConfig) -> Dict[str, Any]:
     for i in range(cfg.n_layers):
         k = keys[1 + 4 * i : 1 + 4 * (i + 1)]
         mixer, ffn = cfg.kind(i)
-        lp = {"ln1": jnp.ones((D,), dt), "ln2": jnp.ones((D,), dt)}
-        if mixer == "conv":
+        # a block of one sub-layer has the one norm of the half it holds
+        lp = {name: jnp.ones((D,), dt) for name, half in (
+            ("ln1", mixer), ("ln2", ffn)) if half}
+        if mixer == "ssm":
+            lp.update(_ssm_init(k[0], cfg))
+        elif mixer is None:
+            pass
+        elif mixer == "conv":
             # in_proj -> B | C | X side by side; the taps [K, D], the last
             # one on the position itself; out_proj
             lp["conv_in"] = dense(k[0], (D, 3 * D), D)
@@ -344,6 +467,8 @@ def lm_init(rng, cfg: LMConfig) -> Dict[str, Any]:
             from seldon_core_tpu.parallel.moe import dropless_init
 
             lp.update(dropless_init(k[2], cfg))
+        elif ffn is None:
+            pass
         elif ffn == "gated":
             lp["w1"] = dense(k[2], (D, cfg.d_ff), D)
             lp["w3"] = dense(jax.random.fold_in(k[2], 1), (D, cfg.d_ff), D)
@@ -574,12 +699,13 @@ def lm_apply(
     ``return_lb`` additionally returns the summed MoE load-balance loss."""
     if (cfg.d_expert or cfg.qk_norm or cfg.head_dim or cfg.block_length > 1
             or not cfg.tie_embeddings or cfg.dense_layers
-            or set(cfg.layer_kinds) & set("cr")):
+            or set(cfg.layer_kinds) & set("crmte")):
         raise ValueError(
             "the cache-free forward implements the repo's own block only; "
             "a configuration with head_dim, qk_norm, an untied head, "
-            "dropless experts, dense gated layers, short-convolution or "
-            "retention layers or block diffusion is served by the paged "
+            "dropless experts, dense gated layers, short-convolution, "
+            "retention or state-space layers, blocks of one sub-layer or "
+            "block diffusion is served by the paged "
             "programs (models/generate.py)")
     x = params["embed"][tokens]  # [B,S,D]
     lb_total = jnp.float32(0.0)

@@ -28,6 +28,8 @@ the experts held.
 
 from __future__ import annotations
 
+import functools
+
 import math
 from dataclasses import dataclass
 from typing import Any, Dict, Optional, Tuple
@@ -191,24 +193,46 @@ def moe_apply(
 
 def dropless_init(rng, cfg) -> Dict[str, Any]:
     """One layer's router and experts for ``cfg`` (an ``LMConfig`` with
-    ``d_expert`` > 0): ``router`` [D, E]; ``e_gate_up`` [E, D, 2F], each
-    expert's gate and up matrices side by side (one grouped matmul reads
-    both); ``e_down`` [E, F, D].  All in the model's dtype, drawn in it: an
+    ``d_expert`` > 0): ``router`` [D, E] over ALL ``n_experts``; the experts
+    HELD here (``cfg.held``: all of them, or the chip's share
+    ``experts_held``), ``e_gate_up`` [held, D, 2F], each expert's gate and
+    up matrices side by side (one grouped matmul reads both) -- under
+    ``cfg.expert_act`` "relu2", whose experts have no gate, ``e_up``
+    [held, F, D], each expert's up matrix stored OUTPUT-major as its down
+    matrix is input-major: a TPU keeps a dimension of whole 128-lane
+    registers minor-most, and a width like 1856 = 29 x 64 is none, so a
+    [held, D, 1856] stack lies on the chip with D minor and the grouped
+    kernel, which takes its operand in the order declared, had every
+    layer's stack copied into that order before the round (3.8 GB of
+    temporaries over six layers: PERF.md section 6, PR 46) --
+    and ``e_down`` [held, F, D]; under ``cfg.d_shared`` the
+    shared expert's ``s_gate_up`` / ``s_up`` [D, (2)Fs] and ``s_down``
+    [Fs, D].  All in the model's dtype, drawn in it: an
     expert stack is gigabytes at real widths and a float32 draw would be
     twice that beside it.  Under ``cfg.router`` "sigmoid_bias" also
     ``expert_bias`` [E] float32, drawn from the seed and NOT zero (a real
     one is what load balancing left behind): a tenth of a sigmoid's range,
     which moves most tokens' chosen set."""
     kr, kg, kd = jax.random.split(rng, 3)
+    ks = jax.random.fold_in(kg, 1)
     E, D, F, dt = cfg.n_experts, cfg.d_model, cfg.d_expert, cfg.dtype
+    held, gated = cfg.held, cfg.expert_act == "silu"
 
     def dense(key, shape, fan_in):
         return (jax.random.normal(key, shape, dt)
                 * jnp.asarray(fan_in ** -0.5, dt)).astype(dt)
 
     out = {"router": dense(kr, (D, E), D),
-           "e_gate_up": dense(kg, (E, D, 2 * F), D),
-           "e_down": dense(kd, (E, F, D), F)}
+           "e_down": dense(kd, (held, F, D), F)}
+    if gated:
+        out["e_gate_up"] = dense(kg, (held, D, 2 * F), D)
+    else:
+        out["e_up"] = dense(kg, (held, F, D), D)
+    if cfg.d_shared:
+        Fs = cfg.d_shared
+        out["s_gate_up" if gated else "s_up"] = dense(
+            ks, (D, (2 if gated else 1) * Fs), D)
+        out["s_down"] = dense(jax.random.fold_in(ks, 1), (Fs, D), Fs)
     if cfg.router == "sigmoid_bias":
         out["expert_bias"] = 0.1 * jax.random.normal(
             jax.random.fold_in(kr, 1), (E,), jnp.float32)
@@ -219,7 +243,8 @@ def dropless_init(rng, cfg) -> Dict[str, Any]:
 # against its expert's matrix, the WHOLE contraction and as much of the
 # output width as keeps one weight tile within ``_WEIGHT_TILE_BYTES`` (it is
 # double-buffered in a v5e's 16 MiB of scoped vector memory: 2048 x 1536
-# bf16 = 6.3 MB fits, a 256-row tile beside it does not), ``_ROW_TILE`` rows
+# bf16 = 6.3 MB fits, a 256-row tile beside it does not; ``_weight_tile``),
+# ``_ROW_TILE`` rows
 # at decode sizes and ``_ROW_TILE_WIDE`` from ``_WIDE_FROM`` rows up.
 # Measured on one TPU v5e (PERF.md section 6, PR 34, call P34b: 128 experts
 # of 2048 x 1536 and 768 x 2048, bf16, uniform top-8 picks; ms a matmul,
@@ -236,30 +261,58 @@ _ROW_TILE, _ROW_TILE_WIDE, _WIDE_FROM = 64, 128, 2048
 _WEIGHT_TILE_BYTES = 6_500_000
 
 
-def _ragged_dot(x, w, sizes):
-    return jax.lax.ragged_dot(x, w, sizes,
-                              preferred_element_type=jnp.float32)
+def _ragged_dot(x, w, sizes, transposed=False):
+    return jax.lax.ragged_dot(x, jnp.swapaxes(w, 1, 2) if transposed else w,
+                              sizes, preferred_element_type=jnp.float32)
 
 
-def _gmm(x, w, sizes, interpret=False):
+def _whole_tile(n: int, most: int) -> int:
+    """The widest tile of whole 128-lane registers that divides ``n`` and
+    is at most ``most`` wide; 0 where none does."""
+    return next((t for t in range(most // 128 * 128, 0, -128)
+                 if n % t == 0), 0)
+
+
+def _weight_tile(K: int, N: int, itemsize: int):
+    """``(tk, tn)``: the tile of a [K, N] expert matrix the grouped kernel
+    streams -- the WHOLE contraction and as much of the output width as
+    keeps the tile within ``_WEIGHT_TILE_BYTES``, halved while it halves
+    into whole 256s (the tiles measured above).  A width that halves no
+    further is cut into the widest whole-128 tiles that DIVIDE it; a width
+    that is no multiple of 128 at all (1856 = 29 x 64) stays whole and the
+    contraction is cut that way instead (the kernel sums its tiles in
+    float32): every tile a whole one, none reaching past the matrix."""
+    tn = N
+    while K * tn * itemsize > _WEIGHT_TILE_BYTES and tn % 256 == 0:
+        tn //= 2
+    if K * tn * itemsize <= _WEIGHT_TILE_BYTES:
+        return K, tn
+    fits = _WEIGHT_TILE_BYTES // itemsize
+    if _whole_tile(N, fits // K):
+        return K, _whole_tile(N, fits // K)
+    return _whole_tile(K, fits // N) or K, N
+
+
+def _gmm(x, w, sizes, interpret=False, transposed=False):
     from jax.experimental.pallas.ops.tpu.megablox import gmm
 
     M, K = x.shape
-    N = w.shape[2]
-    tm = _ROW_TILE_WIDE if M >= _WIDE_FROM else min(_ROW_TILE, -(-M // 16) * 16)
-    tn = N
-    while (K * tn * w.dtype.itemsize > _WEIGHT_TILE_BYTES and tn % 256 == 0):
-        tn //= 2
+    N = w.shape[1 if transposed else 2]
+    tm = (_ROW_TILE_WIDE if M >= _WIDE_FROM
+          else min(_ROW_TILE, -(-M // 16) * 16))
+    tk, tn = _weight_tile(K, N, w.dtype.itemsize)
     rows = -(-M // tm) * tm             # whole row tiles: the pad lies past
     if rows != M:                       # every group and is never computed
         x = jnp.pad(x, ((0, rows - M), (0, 0)))
     out = gmm(x, w, sizes, preferred_element_type=jnp.float32,
-              tiling=(tm, K, tn), interpret=interpret)
+              tiling=(tm, tk, tn), transpose_rhs=transposed,
+              interpret=interpret)
     return out[:M]
 
 
-def _grouped_matmul(x, w, sizes, impl=None):
-    """x [M, K], its rows sorted by group, times w [G, K, N] by
+def _grouped_matmul(x, w, sizes, impl=None, transposed=False):
+    """x [M, K], its rows sorted by group, times w [G, K, N] -- or, under
+    ``transposed``, each group's matrix stored [N, K] -- by
     ``sizes`` [G] rows a group -> [M, N] float32; rows past the last group
     hold nothing defined.  A group without rows is not visited: its matrix
     is not read.
@@ -271,25 +324,43 @@ def _grouped_matmul(x, w, sizes, impl=None):
     ``jax.lax.ragged_dot`` anywhere else (the same contract; XLA's own
     lowering).  ``"gmm_interpret"`` runs the kernel in interpret mode
     (tests on the CPU)."""
+    how = {"transposed": True} if transposed else {}
     if impl is None:
-        return jax.lax.platform_dependent(x, w, sizes, tpu=_gmm,
-                                          default=_ragged_dot)
+        return jax.lax.platform_dependent(
+            x, w, sizes, tpu=functools.partial(_gmm, **how),
+            default=functools.partial(_ragged_dot, **how))
     if impl == "ragged_dot":
-        return _ragged_dot(x, w, sizes)
-    return _gmm(x, w, sizes, interpret=impl == "gmm_interpret")
+        return _ragged_dot(x, w, sizes, **how)
+    return _gmm(x, w, sizes, interpret=impl == "gmm_interpret", **how)
+
+
+def _expert_act(u, cfg):
+    """An expert's hidden activation from its first matmul's output ``u``:
+    ``silu(gate) * up`` of the two halves side by side, or ``relu(u)^2``."""
+    if cfg.expert_act == "relu2":
+        return jnp.square(jax.nn.relu(u))
+    F = u.shape[-1] // 2
+    return jax.nn.silu(u[..., :F]) * u[..., F:]
 
 
 def moe_dropless(lp, h, valid, cfg, impl=None):
-    """Dropless top-k routed gated-SiLU experts on h [B, W, D] ->
-    (y [B, W, D], experts read: int32 scalar).
+    """Dropless top-k routed experts on h [B, W, D] -> (y [B, W, D],
+    experts read: int32 scalar -- or, where the layer holds a share of the
+    experts it routes over, int32 [2]: experts read, and the real tokens'
+    picks that fell on held experts; what they picked in all is ``moe_k`` a
+    token, which the host knows).
 
     ``g = softmax(h Wr)`` over all experts in float32; the ``moe_k``
     largest; ``w_e = g_e / sum of the chosen`` (``moe_norm_topk``);
     ``y = sum_e w_e (silu(h W_gate,e) * (h W_up,e)) W_down,e``.
     Under ``cfg.router`` "sigmoid_bias": ``g = sigmoid(h Wr)``; chosen are
     the ``moe_k`` largest of ``g + expert_bias``; the weights are the
-    UNBIASED ``g`` at the chosen, ``/ (their sum + 1e-6)`` -- the bias
-    steers who is chosen and never what a choice weighs.
+    UNBIASED ``g`` at the chosen, ``/ (their sum + cfg.router_eps)`` -- the
+    bias steers who is chosen and never what a choice weighs.  The weights
+    are then multiplied by ``cfg.router_scale``; under ``cfg.expert_act``
+    "relu2" an expert is ``relu(h W_up,e)^2 W_down,e``; under
+    ``cfg.d_shared`` a shared expert of the same form takes every real
+    token and is added unweighted.
 
     The T*k picks are sorted by expert and go through the experts as two
     grouped matmuls (``_grouped_matmul``; ``impl`` is its), which visit a
@@ -297,9 +368,16 @@ def moe_dropless(lp, h, valid, cfg, impl=None):
     chose is not read.  ``valid`` [B, W] marks the real tokens: a pad
     position or an empty slot picks nothing (its picks sort behind every
     group and lie outside all of them), so padding reads no expert and the
-    count returned -- groups with at least one row -- is of real work."""
+    count returned -- groups with at least one row -- is of real work.
+
+    Under ``cfg.experts_held`` the layer holds experts ``[experts_first,
+    experts_first + experts_held)`` of the ``n_experts`` the router scores
+    (the chip's share of a layer divided by expert parallelism): a pick of
+    an expert that is not here sorts behind every group as padding does and
+    adds nothing -- what the other chips' experts would add is left out, by
+    design -- and the experts read are counted among the held."""
     B, W, D = h.shape
-    E, k, F = cfg.n_experts, cfg.moe_k, cfg.d_expert
+    k, held, first = cfg.moe_k, cfg.held, cfg.experts_first
     x = h.reshape(B * W, D)
     live = valid.reshape(B * W)
     with jax.named_scope("router"):
@@ -312,23 +390,43 @@ def moe_dropless(lp, h, valid, cfg, impl=None):
             top_w = jnp.take_along_axis(gates, top_e, axis=-1)
             if cfg.moe_norm_topk:
                 top_w = top_w / (jnp.sum(top_w, axis=-1, keepdims=True)
-                                 + 1e-6)
+                                 + cfg.router_eps)
         else:
             gates = jax.nn.softmax(logits, axis=-1)
             top_w, top_e = jax.lax.top_k(gates, k)            # [T, k]
             if cfg.moe_norm_topk:
                 top_w = top_w / jnp.sum(top_w, axis=-1, keepdims=True)
-        pick = jnp.where(live[:, None], top_e, E).reshape(-1)  # [T*k]
+        if cfg.router_scale != 1.0:
+            top_w = top_w * cfg.router_scale
+        here = live[:, None] & (top_e >= first) & (top_e < first + held)
+        pick = jnp.where(here, top_e - first, held).reshape(-1)  # [T*k]
         order = jnp.argsort(pick, stable=True)
-        sizes = jnp.zeros((E + 1,), jnp.int32).at[pick].add(1)[:E]
+        sizes = jnp.zeros((held + 1,), jnp.int32).at[pick].add(1)[:held]
         read = jnp.count_nonzero(sizes).astype(jnp.int32)
+        if cfg.experts_held:
+            read = jnp.stack([read, jnp.sum(here, dtype=jnp.int32)])
     with jax.named_scope("experts"):
         xs = x[order // k]                                    # [T*k, D]
-        gu = _grouped_matmul(xs, lp["e_gate_up"], sizes, impl)
-        act = (jax.nn.silu(gu[:, :F]) * gu[:, F:]).astype(x.dtype)
-        ys = _grouped_matmul(act, lp["e_down"], sizes, impl)
-        # back to token order; rows past the last group hold nothing defined
+        if cfg.expert_act == "relu2":
+            up = _grouped_matmul(xs, lp["e_up"], sizes, impl,
+                                 transposed=True)
+        else:
+            up = _grouped_matmul(xs, lp["e_gate_up"], sizes, impl)
+        ys = _grouped_matmul(_expert_act(up, cfg).astype(x.dtype),
+                             lp["e_down"], sizes, impl)
+        # back to token order; rows past the last group (a pad's picks, a
+        # pick of an expert held elsewhere) hold nothing defined
         ys = ys[jnp.argsort(order)].reshape(B * W, k, D)
-        y = jnp.sum(ys * top_w[..., None], axis=1)
-        y = jnp.where(live[:, None], y, 0.0).astype(h.dtype)
-    return y.reshape(B, W, D), read
+        y = jnp.sum(jnp.where(here[..., None], ys * top_w[..., None], 0.0),
+                    axis=1)
+    if cfg.d_shared:
+        with jax.named_scope("shared_expert"):
+            from seldon_core_tpu.ops.quant import lm_matmul
+
+            gated = cfg.expert_act == "silu"
+            up = lm_matmul(lp, "s_gate_up" if gated else "s_up", x,
+                           out_dtype=jnp.float32)
+            y = y + jnp.where(live[:, None], lm_matmul(
+                lp, "s_down", _expert_act(up, cfg).astype(x.dtype),
+                out_dtype=jnp.float32), 0.0)
+    return y.astype(h.dtype).reshape(B, W, D), read

@@ -1653,6 +1653,7 @@ class GenServer:
             "phases": phases,
             "device_phases": dict(self._dev_s),
             "retention_row_bytes": self._served.retention_row_bytes,
+            "ssm_row_bytes": self._served.ssm_row_bytes,
             # what the tick's calls counted, each where it was dispatched
             **self._counts,
         }
@@ -2438,9 +2439,12 @@ class GenServer:
                          attr, work["seq"])
             fl.skip = skip
             if extra and "experts_read" in extra[0]:
-                # one int32 a round, read back beside the round's tokens
-                fl.read = extra[0]["experts_read"]
-                fl.read.copy_to_host_async()
+                # an int32 each that the round counted itself (the experts
+                # read; the picks on held experts), read back beside the
+                # round's tokens
+                fl.read = extra[0]
+                for count in fl.read.values():
+                    count.copy_to_host_async()
             self._unread.append(fl)
             if fenced:
                 jax.block_until_ready(fl.ready)
@@ -2473,8 +2477,9 @@ class GenServer:
             if fl.keys is not None:
                 key_data = np.asarray(fl.keys)
             if fl.read is not None:
-                counted["experts_read"] = int(np.asarray(fl.read))
-                self._counts["experts_read"] += counted["experts_read"]
+                counted = {name: int(np.asarray(count))
+                           for name, count in fl.read.items()}
+                self._counts.update(counted)
         with _Phase("GenServer._decode_round/emit", seq=fl.seq, **counted):
             for i, (s, take) in enumerate(fl.rows):
                 off = fl.skip[i]

@@ -97,13 +97,16 @@ REQUEST_STAGES = ("lane_in", "queue", "prefill", "lane_out")
 #: ``row_passes``, summed over the real rows of each (real_tokens /
 #: row_passes = tokens fixed a row-pass: 1, or 4/5 for blocks of four under
 #: four denoising passes); ``experts_read`` by the expert layers (the rounds'
-#: own count) of ``expert_slots``, the experts held x layers x passes
+#: own count) of ``expert_slots``, the experts held x layers x passes, and,
+#: where a layer holds a share of the experts it routes over,
+#: ``expert_slots_held``: the real rows' picks that fell on a held expert
+#: (the rounds' own count too; a row-pass picks ``moe_k`` a layer in all)
 SERVED_DECODE = dict(
     {"real_tokens": "tokens", "device_steps": "steps"},
     **{name: name for name in (
         "inplace_steps", "retention_fused_steps", "ahead_steps",
         "kv_positions", "passes", "row_passes", "experts_read",
-        "expert_slots")})
+        "expert_slots", "expert_slots_held")})
 
 #: ``served_prefill``'s, folded from every tick (a chunk is read back a tick
 #: after it was dispatched, whatever that tick's kind): ``calls``, the
@@ -114,6 +117,22 @@ SERVED_DECODE = dict(
 SERVED_PREFILL = {name: "prefill_" + name for name in (
     "calls", "experts_read", "expert_slots", "tokens", "rows",
     "carried_rows", "retention_fused_rows")}
+
+#: ``<kind>_state_bytes`` of both sections: the bytes of matrix state a
+#: generator of such layers read + wrote, 2 x a row's bytes over the layers
+#: (the tick record's ``<kind>_row_bytes``, models/served.py) x the decode
+#: steps' real rows (``row_passes``) or the prefill calls' (``rows``: a row
+#: of a call is one chunk through every layer)
+STATE_KINDS = ("retention", "ssm")
+STATE_BYTES = tuple(kind + "_state_bytes" for kind in STATE_KINDS)
+#: a count only a generator of one kind makes: a section shows it once it
+#: was made, so the documents of the other kinds read as they did
+OF_ONE_KIND = ("ssm_state_bytes",)
+
+
+def _section(counts, names) -> Dict[str, Any]:
+    return {name: counts[name] for name in names
+            if name not in OF_ONE_KIND or counts[name]}
 
 #: fixed log-spaced edges of the TTFT histogram, 1 ms ... 60 s at a ratio
 #: of 60000 ** (1 / 79) = 1.1494 (<= 1.15): fixed, so two documents of one
@@ -168,11 +187,7 @@ class GenPerf:
         # served-decode accounting (decode/spec/mixed ticks only)
         self.decode_device_s = 0.0
         #: a section's counts by its own names (``SERVED_DECODE``,
-        #: ``SERVED_PREFILL``) and, beside them, ``retention_state_bytes``:
-        #: the bytes of float32 matrix state a generator of retention layers
-        #: read + wrote, 2 x a row's bytes over the layers x the decode
-        #: steps' real rows (``row_passes``) or the prefill calls' (``rows``:
-        #: a row of a call is one chunk through every layer)
+        #: ``SERVED_PREFILL``) and, beside them, ``STATE_BYTES``
         self.decode: collections.Counter = collections.Counter()
         self.prefill: collections.Counter = collections.Counter()
         self.kv_block_age = Reservoir(1024)   # seconds held at release
@@ -239,11 +254,12 @@ class GenPerf:
                     dev_phases.get("decode", 0.0))
                 _fold(self.decode, SERVED_DECODE, detail)
             _fold(self.prefill, SERVED_PREFILL, detail)
-            state = 2 * int(detail.get("retention_row_bytes", 0) or 0)
-            self.decode["retention_state_bytes"] += state * int(
-                detail.get("row_passes", 0) or 0)
-            self.prefill["retention_state_bytes"] += state * int(
-                detail.get("prefill_rows", 0) or 0)
+            for kind in STATE_KINDS:
+                state = 2 * int(detail.get(kind + "_row_bytes", 0) or 0)
+                self.decode[kind + "_state_bytes"] += state * int(
+                    detail.get("row_passes", 0) or 0)
+                self.prefill[kind + "_state_bytes"] += state * int(
+                    detail.get("prefill_rows", 0) or 0)
             for n_blocks, age_s in kv_ages:
                 self.kv_blocks_released += int(n_blocks)
                 self.kv_block_age.observe(float(age_s))
@@ -289,8 +305,7 @@ class GenPerf:
 
         with self._lock:
             dev_s = self.decode_device_s
-            counts = {name: self.decode[name] for name in (
-                *SERVED_DECODE, "retention_state_bytes")}
+            counts = _section(self.decode, (*SERVED_DECODE, *STATE_BYTES))
         tokens, steps = counts["real_tokens"], counts["device_steps"]
         kv_pos = counts["kv_positions"]
         out: Dict[str, Any] = {
@@ -415,8 +430,8 @@ class GenPerf:
                     },
                 },
             }
-            doc["served_prefill"] = {name: self.prefill[name] for name in (
-                *SERVED_PREFILL, "retention_state_bytes")}
+            doc["served_prefill"] = _section(
+                self.prefill, (*SERVED_PREFILL, *STATE_BYTES))
         doc["served_decode"] = self.served_decode()
         return doc
 
