@@ -77,7 +77,7 @@ def _eager(x) -> bool:
 
 __all__ = ["generate", "stream_chunks", "sample_token", "mask_after_eos",
            "init_block_pool", "private_pool", "decode_inplace",
-           "retention_fused",
+           "retention_fused", "ssm_fused",
            "paged_forward", "paged_decode_round", "paged_spec_round",
            "paged_copy_block", "TransformerGenerator"]
 
@@ -548,7 +548,8 @@ def _carried_taps(state, zz, tables, start, width):
         left.astype(state.dtype))
 
 
-def _ssm(lp, x, pool_layer, tables, start, valid, cfg: LMConfig):
+def _ssm(lp, x, pool_layer, tables, start, valid, cfg: LMConfig,
+         fused=False):
     """The Mamba-2 state-space mixer on x [B, W, D] -> (x', pool layer'):
     ``[z | xBC | dt] = in_proj(norm(x))``; ``xBC = silu(conv1d(xBC) +
     bias)``, depthwise and causal over ``cfg.conv_kernel`` taps; ``[x | B |
@@ -566,9 +567,19 @@ def _ssm(lp, x, pool_layer, tables, start, valid, cfg: LMConfig):
     positions (``valid`` False, to the right of the valid ones) enter
     neither a sum nor the state -- their ``dt`` is 0, which the recurrence
     reads as no position -- and a row with no valid position (an empty
-    slot) writes the scratch entry 0."""
+    slot) writes the scratch entry 0.
+
+    ``fused`` has a call of one position a row (a decode round's step) work
+    on each live row's ``h`` where it lies in the pool: the kernel of
+    ops/ssm.py (``ssm_step_pool``; "interpret": in Pallas interpret mode),
+    which reads and writes a live row's entry once and touches no other,
+    not even the scratch entry.  Without it, and for any wider call, the
+    rows' states are gathered, ``ssm_step`` / ``ssm_chunk`` update the
+    copy and the rows are written back one by one.  It rides the call only
+    where it says so: the call of seven is what the benchmark's fault
+    injectors wrap (tests/bench/test_bench_nemotron.py)."""
     from seldon_core_tpu.ops.quant import lm_matmul
-    from seldon_core_tpu.ops.ssm import ssm_chunk, ssm_step
+    from seldon_core_tpu.ops.ssm import ssm_chunk, ssm_step, ssm_step_pool
 
     B, W, D = x.shape
     H, P, G, N = (cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_groups,
@@ -596,20 +607,25 @@ def _ssm(lp, x, pool_layer, tables, start, valid, cfg: LMConfig):
         A = -jnp.exp(lp["A_log"].astype(f32))
         skip = lp["ssm_D"].astype(f32)
         slot = tables[:, 0]
-        h = jnp.where((start > 0)[:, None, None, None],
-                      pool_layer["h"][slot], 0.0)
-        if W == 1:
-            y, h = ssm_step(xs[:, 0], dt[:, 0], A, Bm[:, 0], Cm[:, 0], skip,
-                            h)
+        state = pool_layer["h"]
+        if W == 1 and fused:
+            y, state = ssm_step_pool(
+                xs[:, 0], dt[:, 0], A, Bm[:, 0], Cm[:, 0], skip, state, slot,
+                start, width > 0, interpret=fused == "interpret")
             y = y[:, None]
         else:
-            y, h = ssm_chunk(xs, dt, A, Bm, Cm, skip, h)
-        # a row's state goes back where it lies, row by row (an empty
-        # slot's to the scratch entry 0: what lands there last stays)
-        state = pool_layer["h"]
-        for b, entry in enumerate(jnp.where(width > 0, slot, 0)):
-            state = jax.lax.dynamic_update_slice(
-                state, h[b:b + 1].astype(state.dtype), (entry, 0, 0, 0))
+            h = jnp.where((start > 0)[:, None, None, None], state[slot], 0.0)
+            if W == 1:
+                y, h = ssm_step(xs[:, 0], dt[:, 0], A, Bm[:, 0], Cm[:, 0],
+                                skip, h)
+                y = y[:, None]
+            else:
+                y, h = ssm_chunk(xs, dt, A, Bm, Cm, skip, h)
+            # a row's state goes back where it lies, row by row (an empty
+            # slot's to the scratch entry 0: what lands there last stays)
+            for b, entry in enumerate(jnp.where(width > 0, slot, 0)):
+                state = jax.lax.dynamic_update_slice(
+                    state, h[b:b + 1].astype(state.dtype), (entry, 0, 0, 0))
     with jax.named_scope("ssm_out"):
         y = y.reshape(B, W, G, inner // G) * jax.nn.silu(
             z.astype(f32)).reshape(B, W, G, inner // G)
@@ -725,9 +741,10 @@ def _paged_block(lp, x, pool_layer, tables, start, valid, cfg: LMConfig,
     state, and what follows about K/V does not concern it; a block of one
     sub-layer is a mixer with no FFN (``ffn`` None) or an FFN with no mixer
     (``mixer`` None: its pool entry is empty); ``fused`` has it work on
-    the state where it lies in the pool (the kernels of ops/retention.py -- a decode round's
-    step, a prefill call's chunk -- under ``interpret`` in Pallas
-    interpret mode) -- and the FFN is ``transformer._ffn``'s of that kind.
+    the state where it lies in the pool (the kernels of ops/retention.py,
+    a decode round's step and a prefill call's chunk, and of ops/ssm.py, a
+    decode round's step -- under ``interpret`` in Pallas interpret mode)
+    -- and the FFN is ``transformer._ffn``'s of that kind.
 
     ``plan`` (ops.paged_attention.decode_plan) selects the in-place
     formulation: attention reads the row's blocks from the pool where they
@@ -781,7 +798,7 @@ def _paged_block(lp, x, pool_layer, tables, start, valid, cfg: LMConfig,
         return alone(x, pool_layer, aux)
     if mixer in ("conv", "ret", "ssm"):
         how = {}
-        if mixer == "ret" and fused:
+        if mixer in ("ret", "ssm") and fused:
             how["fused"] = "interpret" if interpret else True
         x, pool_layer = {"conv": _short_conv, "ret": _retention,
                          "ssm": _ssm}[mixer](
@@ -959,10 +976,32 @@ def retention_fused(pool, mesh=None, heads=None, rows: int = 1,
                            **seen)
 
 
+def ssm_fused(pool, mesh=None, rows: int = 1) -> bool:
+    """Whether a decode round's step of a generator with Mamba-2
+    state-space layers updates each live row's ``h`` where it lies in
+    ``pool`` (the Pallas kernel of ops/ssm.py) or over gathered rows in
+    ``jax.numpy``: ops.ssm.step_supported over what is observable here, as
+    ``retention_fused`` asks for a retention state -- the backend, the
+    state's dtype and shapes, the caller's mesh and the padded ``rows`` of
+    the widest batch the caller will bring.  B and C are the taps' channels
+    beside x (``conv`` [.., H P + 2 G N]), which gives the groups.  False
+    for a pool without such layers."""
+    from seldon_core_tpu.ops.ssm import step_supported
+
+    entry = next((e for e in pool.values() if "h" in e), None)
+    if entry is None:
+        return False
+    _, H, P, N = entry["h"].shape
+    return step_supported(
+        backend=jax.default_backend(), state_dtype=entry["h"].dtype,
+        heads=H, head_dim=P, groups=(entry["conv"].shape[2] - H * P)
+        // (2 * N), state=N, mesh=mesh, rows=rows)
+
+
 def paged_decode_round(params, pool, tables, token, n_valid, active,
                        seen_eos, keys, cfg: LMConfig, *, span: int,
                        temperature: float, top_k: int, top_p: float,
-                       eos_token: int, inplace=None,
+                       eos_token: int, inplace=None, ssm_inplace=None,
                        trace_passes: bool = False):
     """``span`` cached decode steps for the whole in-flight batch as ONE
     lax.scan — the scheduler's unit of work between admission points.
@@ -976,7 +1015,12 @@ def paged_decode_round(params, pool, tables, token, n_valid, active,
     interpret mode (tests on the CPU).  For a generator of retention
     layers the kernel is the state's (``retention_fused`` decides, False is
     the row-by-row step of ops/retention.py): either way the answer says
-    whether the step works on the pool where it lies.
+    whether the step works on the pool where it lies.  ``ssm_inplace`` is the
+    same question of the Mamba-2 state-space layers, which stand BESIDE
+    attention layers in one generator, so their answer is apart: None
+    decides by ``ssm_fused(pool)``, True / False force the kernel of
+    ops/ssm.py / ``ssm_step`` over gathered rows, "interpret" runs the
+    kernel in Pallas interpret mode.
 
     token [B] pending tokens; n_valid [B] per-row cache length; active [B]
     masks empty slots (their writes go to scratch, their samples are
@@ -1001,6 +1045,8 @@ def paged_decode_round(params, pool, tables, token, n_valid, active,
                                   rows=n_valid.shape[0], head_dim=cfg.hd)
                    or retention_fused(pool, heads=cfg.n_heads,
                                       rows=n_valid.shape[0]))
+    if ssm_inplace is None:
+        ssm_inplace = ssm_fused(pool, rows=n_valid.shape[0])
     kv = _pool_kv(pool)     # (a plan is made only where a layer attends)
     capacity = tables.shape[1] * kv["k"].shape[1] if kv else 0
 
@@ -1012,11 +1058,13 @@ def paged_decode_round(params, pool, tables, token, n_valid, active,
         with jax.named_scope("embed"):
             x = params["embed"][token][:, None, :]
         for i in range(cfg.n_layers):
+            # a state-space layer works in place by its own answer
+            how = ssm_inplace if cfg.kind(i)[0] == "ssm" else inplace
             x, pool[f"l{i}"], aux = _paged_block(
                 params[f"l{i}"], x, pool[f"l{i}"], tables, n_valid,
                 active[:, None], cfg, plan=plan,
-                interpret=inplace == "interpret", kind=cfg.kind(i),
-                fused=bool(inplace),
+                interpret=how == "interpret", kind=cfg.kind(i),
+                fused=bool(how),
             )
             read = [r + aux for r in read]
         with jax.named_scope("unembed"):
@@ -1289,7 +1337,7 @@ paged_forward_jit = jax.jit(
 paged_decode_round_jit = jax.jit(
     paged_decode_round,
     static_argnames=("cfg", "span", "temperature", "top_k", "top_p",
-                     "eos_token", "inplace", "trace_passes"),
+                     "eos_token", "inplace", "ssm_inplace", "trace_passes"),
     donate_argnums=(1,),
 )
 paged_spec_round_jit = jax.jit(
@@ -1333,7 +1381,8 @@ def _begin(params, prompt, cfg: LMConfig, max_new_tokens: int,
     knobs = dict(temperature=temperature, top_k=top_k, top_p=top_p,
                  eos_token=eos_token,
                  inplace=decode_inplace(pool, mesh, heads=cfg.n_heads,
-                                        rows=B, head_dim=cfg.hd))
+                                        rows=B, head_dim=cfg.hd),
+                 ssm_inplace=ssm_fused(pool, mesh, rows=B))
     # prefill sees the prompt's own blocks only: its attention would
     # otherwise span (masked) the blocks the decode round has yet to fill
     logits, pool = paged_forward_jit(

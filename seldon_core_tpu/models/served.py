@@ -42,11 +42,20 @@ class Kernels:
     #: step kernel of ops/retention.py) and not row by row in jax.numpy
     states_inplace: Any
     _chunk: Any             # width -> the same of a prefill call, or None
+    #: ... updates every live row's Mamba-2 state where it lies (the step
+    #: kernel of ops/ssm.py) and not over gathered rows: an answer of its
+    #: own, because such layers stand beside attention layers
+    ssm_inplace: Any = False
 
     @property
     def inplace(self):
         """What the decode program takes as ``inplace=``."""
         return self.attends_inplace or self.states_inplace
+
+    @property
+    def round_how(self) -> Dict[str, Any]:
+        """The keywords the decode program takes because of them."""
+        return {"inplace": self.inplace, "ssm_inplace": self.ssm_inplace}
 
     def fused(self, width: int):
         """What a prefill call of ``width`` positions a row takes as
@@ -57,7 +66,8 @@ class Kernels:
     def round_counts(self, span: int) -> Dict[str, int]:
         """What a round of ``span`` steps counts because of them."""
         return {"inplace_steps": span if self.attends_inplace else 0,
-                "retention_fused_steps": span if self.states_inplace else 0}
+                "retention_fused_steps": span if self.states_inplace else 0,
+                "ssm_fused_steps": span if self.ssm_inplace else 0}
 
     def prefill_counts(self, width: int, rows: int) -> Dict[str, int]:
         """... and a prefill call of ``rows`` real rows."""
@@ -256,7 +266,8 @@ class Served:
         """The kernels that serve this generator over ``pool``, sharded
         over ``mesh`` or on one device, for batches of up to ``rows``
         padded rows and activations of ``dtype``: ``decode_inplace`` /
-        ``retention_fused`` of models/generate.py over what they observe."""
+        ``retention_fused`` / ``ssm_fused`` of models/generate.py over what
+        they observe."""
         import jax
 
         from seldon_core_tpu.models import generate as G
@@ -276,7 +287,8 @@ class Served:
             return G.retention_fused(pool, mesh, heads=cfg.n_heads,
                                      rows=rows, width=width, dtype=dtype)
 
-        return Kernels(attends, states, chunk)
+        return Kernels(attends, states, chunk,
+                       G.ssm_fused(pool, mesh, rows=rows))
 
     # -- what a token costs --------------------------------------------------
 

@@ -1292,8 +1292,7 @@ class GenServer:
                  self.cfg),
                 {"span": self.span, "temperature": self.temperature,
                  "top_k": self.top_k, "top_p": self.top_p,
-                 "eos_token": self.eos_token,
-                 "inplace": self._kernels.inplace})
+                 "eos_token": self.eos_token, **self._kernels.round_how})
 
     def _note_program(self, kind: str, shape: tuple) -> None:
         """A tick is about to dispatch ``shape``.  One the boot did not

@@ -90,7 +90,8 @@ REQUEST_STAGES = ("lane_in", "queue", "prefill", "lane_out")
 #: operations.md "reading the /genperf page" says how to read each.
 #: ``real_tokens`` emitted; ``device_steps``, the single-token steps run, of
 #: which ``inplace_steps`` attended over the block pool in place,
-#: ``retention_fused_steps`` updated the retention states where they lie
+#: ``retention_fused_steps`` updated the retention states where they lie,
+#: ``ssm_fused_steps`` the Mamba-2 states (the kernel of ops/ssm.py)
 #: and ``ahead_steps`` were dispatched before the program ahead was read;
 #: ``kv_positions`` attended over (module docstring); ``passes`` of the model
 #: (one a step, or a block's denoising passes and the K/V one) and
@@ -104,7 +105,8 @@ REQUEST_STAGES = ("lane_in", "queue", "prefill", "lane_out")
 SERVED_DECODE = dict(
     {"real_tokens": "tokens", "device_steps": "steps"},
     **{name: name for name in (
-        "inplace_steps", "retention_fused_steps", "ahead_steps",
+        "inplace_steps", "retention_fused_steps", "ssm_fused_steps",
+        "ahead_steps",
         "kv_positions", "passes", "row_passes", "experts_read",
         "expert_slots", "expert_slots_held")})
 

@@ -250,6 +250,29 @@ def test_served_decode_counts_the_steps_whose_states_the_kernel_updated():
     assert served["inplace_steps"] == 0
 
 
+def test_served_decode_counts_the_steps_whose_ssm_states_the_kernel_updated():
+    """``ssm_fused_steps`` rides the tick record beside
+    ``retention_fused_steps``: 0 for a tick record that does not say (a
+    generator without state-space layers, or the CPU's ``ssm_step`` over
+    gathered rows), equal to ``device_steps`` when every round's says so
+    (bench/layer_metrics/decode_ssm_fused_share.json divides the two), and
+    it is neither the attention kernel's count nor retention's -- such
+    layers stand beside attention layers in one generator."""
+    tick = {"wall_s": 0.02, "device_s": 0.01, "tokens": 16, "steps": 8,
+            "device_phases": {"decode": 0.01}, "phases": {"decode": 0.01}}
+    GENPERF.observe_tick("decode", {**tick, "inplace_steps": 8})
+    served = GENPERF.document()["served_decode"]
+    assert (served["device_steps"], served["ssm_fused_steps"]) == (8, 0)
+    GENPERF.reset()
+    for _ in range(3):
+        GENPERF.observe_tick("mixed", {**tick, "inplace_steps": 8,
+                                       "ssm_fused_steps": 8})
+    served = GENPERF.document()["served_decode"]
+    assert served["ssm_fused_steps"] == served["device_steps"] == 24
+    assert served["inplace_steps"] == 24
+    assert served["retention_fused_steps"] == 0
+
+
 def test_served_prefill_counts_the_rows_whose_chunk_ran_the_kernel():
     """``prefill_retention_fused_rows`` rides the tick record beside
     ``prefill_rows`` whatever the tick's kind: 0 for a record that does

@@ -139,16 +139,24 @@ def chunked(unit, params, rows, chunk, tables, pool=None, bs=4, blocks=16):
     return np.stack(out), pool
 
 
-def decode(unit, params, pool, tables, token, n_valid, active, span):
+def decode(unit, params, pool, tables, token, n_valid, active, span,
+           ssm_inplace=None):
     B = len(token)
     return paged_decode_round_jit(
         params, pool, tables, jnp.asarray(token, jnp.int32),
         jnp.asarray(n_valid, jnp.int32), jnp.asarray(active, bool),
         jnp.zeros((B,), bool), jnp.zeros((B,), jnp.uint32), unit.cfg,
-        span=span, temperature=0.0, top_k=0, top_p=0.0, eos_token=-1)
+        span=span, temperature=0.0, top_k=0, top_p=0.0, eos_token=-1,
+        ssm_inplace=ssm_inplace)
 
 
 TABLES = jnp.asarray([[1, 2, 3, 4, 5, 6], [7, 8, 9, 10, 11, 12]], jnp.int32)
+
+# a decode round's step over gathered rows in jax.numpy (what the CPU
+# decides for itself) and through the kernel of ops/ssm.py in Pallas
+# interpret mode (what a TPU decides, as far as the CPU can run it)
+BOTH_STEPS = pytest.mark.parametrize(
+    "ssm_inplace", [None, "interpret"], ids=["step", "kernel"])
 
 
 # -- the recurrence's forms against each other -------------------------------
@@ -206,6 +214,123 @@ def test_a_position_whose_dt_is_zero_is_no_position():
     np.testing.assert_array_equal(h[1], c["h"][1])
 
 
+# -- the step over the pool where it lies: the kernel -------------------------
+
+TOY, PUBLISHED = (8, 8, 16), (64, 64, 128)      # H, P, N
+NAN = float("nan")
+
+
+@pytest.mark.parametrize("shape, G, live, fresh, dirty", [
+    (TOY, 2, [1, 0, 1, 0, 1], [], 0.0),             # pads between live rows
+    (TOY, 2, [0, 1, 1], [1], NAN),      # a row at start 0 over a dirty entry
+    (TOY, 2, [0, 0, 0, 0], [0, 2], 0.0),            # nobody live
+    (TOY, 1, [1, 1, 0, 1], [3], 0.0),               # one group of 8 heads
+    (TOY, 8, [1], [], 0.0),                 # a head a group, a batch of one
+    (TOY, 2, [1] * 8, [0, 5], 0.0),                 # a batch of 8
+    (TOY, 2, [1] * 5 + [0] * 4 + [1] * 7, [2], 0.0),    # ... of 16
+    (PUBLISHED, 8, [1, 0, 1], [2], NAN),            # the published entry
+    (PUBLISHED, 1, [1, 1], [], 0.0),
+    ((4, 256, 128), 2, [0, 1], [], 0.0),    # a head of two chunks of rows
+], ids=["pads-between", "fresh-over-dirty", "nobody-live", "one-group",
+        "head-a-group-batch-1", "batch-8", "batch-16", "published",
+        "published-one-group", "wide-head"])
+def test_the_step_kernel_gives_ssm_steps_numbers_where_the_state_lies(
+        shape, G, live, fresh, dirty):
+    """``ssm_step_pool`` (the Pallas kernel in interpret mode) against
+    ``ssm_step`` over gathered rows: ``y`` of the live rows and their
+    entries within 1e-5 of values of order 1 -- float32 both, what differs
+    is the order of the read-out's sum -- and every other entry of the
+    pool, the scratch entry 0 and the entries of rows that are not live
+    among them, bit for bit what it was.  A row at ``start`` 0 reads zeros
+    whatever its entry holds (``dirty``: NaN there)."""
+    H, P, N = shape
+    B = len(live)
+    k = jax.random.split(jax.random.key(B + G), 7)
+    f32 = jnp.float32
+    x = jax.random.normal(k[0], (B, H, P), f32)
+    dt = jax.nn.softplus(jax.random.normal(k[1], (B, H), f32) - 2.0)
+    A = -jnp.exp(jax.random.uniform(k[2], (H,), f32, 0.0, 2.5))
+    Bm = jax.random.normal(k[3], (B, G, N), f32) / np.sqrt(N)
+    Cm = jax.random.normal(k[4], (B, G, N), f32)
+    D = jax.random.uniform(k[5], (H,), f32, 0.5, 1.5)
+    pool = np.array(jax.random.normal(k[6], (B + 2, H, P, N), f32))
+    slot = np.random.default_rng(B).permutation(np.arange(1, B + 2))[:B]
+    start = np.where(np.isin(np.arange(B), fresh), 0, 5).astype(np.int32)
+    if dirty != 0.0:
+        pool[slot[start == 0]] = dirty
+    live = np.asarray(live, bool)
+    y, h = ssm.ssm_step_pool(
+        x, dt, A, Bm, Cm, D, jnp.asarray(pool), jnp.asarray(slot),
+        jnp.asarray(start), jnp.asarray(live), interpret=True)
+    carried = jnp.where((start > 0)[:, None, None, None], pool[slot], 0.0)
+    want_y, want_h = ssm.ssm_step(x, dt, A, Bm, Cm, D, carried)
+    y, h = np.asarray(y), np.asarray(h)
+    np.testing.assert_allclose(y[live], np.asarray(want_y)[live], atol=1e-5,
+                               rtol=1e-5)
+    np.testing.assert_allclose(h[slot[live]], np.asarray(want_h)[live],
+                               atol=1e-5, rtol=1e-5)
+    untouched = np.setdiff1d(np.arange(B + 2), slot[live])
+    np.testing.assert_array_equal(h[untouched], pool[untouched])
+    assert 0 in untouched                           # the scratch entry
+
+
+#: what the kernel is offered at the published widths
+OFFER = dict(backend="tpu", state_dtype=jnp.float32, heads=64, head_dim=64,
+             groups=8, state=128, rows=16)
+
+
+@pytest.mark.parametrize("change, serves", [
+    ({}, True),
+    ({"rows": 1}, True),
+    ({"groups": 1}, True),
+    ({"backend": "cpu"}, False),                    # Mosaic is the TPU's
+    ({"state_dtype": jnp.bfloat16}, False),     # the tiles are float32's
+    ({"mesh": object()}, False),        # a Mosaic call does not partition
+    ({"state": 192}, False),            # N no whole 128-lane registers
+    ({"head_dim": 60}, False),          # P no whole sublane tiles
+    ({"heads": 60}, False),             # no whole groups of heads
+    ({"head_dim": 96}, False),      # 128 rows are no whole heads of 96
+    ({"state": 4096}, False),       # not an iteration's rows of it fit
+    ({"heads": 1024, "groups": 1, "head_dim": 128}, False),    # nor the x, y
+], ids=["published", "one-row", "one-group", "cpu", "bfloat16-state",
+        "mesh", "state-192", "head-60", "heads-60", "head-96", "no-tile",
+        "vmem"])
+def test_step_supported_chooses_by_what_it_can_observe(change, serves):
+    assert ssm.step_supported(**{**OFFER, **change}) is serves
+
+
+def test_the_pools_owner_asks_for_the_state_space_layers_apart(
+        model, monkeypatch):
+    """``generate.ssm_fused`` reads the question off the pool: the CPU says
+    no; a TPU backend yes for the published entry, no under a mesh and for
+    a state kept in bfloat16; a pool without such layers no.  It is not the
+    attention layers' answer, and ``Served.kernels`` keeps the two apart."""
+    doc, unit, params = model
+    pool = jax.eval_shape(lambda: init_block_pool(dataclasses.replace(
+        unit.cfg, ssm_heads=64, ssm_head_dim=64, ssm_groups=8,
+        ssm_state=128), 4, 4))
+    assert not G.ssm_fused(pool, rows=16)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert G.ssm_fused(pool, rows=16)
+    assert not G.ssm_fused(pool, object(), rows=16)
+    half = {**pool, "l0": {**pool["l0"], "h": jax.ShapeDtypeStruct(
+        pool["l0"]["h"].shape, jnp.bfloat16)}}
+    assert not G.ssm_fused(half, rows=16)
+    assert not G.ssm_fused({"l0": pool["l5"]}, rows=16)
+    # the toy's state of 16 is no whole register: its server takes the step
+    toy = init_block_pool(unit.cfg, 4, 4)
+    assert not G.ssm_fused(toy)
+    k = served(unit.cfg).kernels(toy, None, 4, jnp.float32)
+    assert not k.ssm_inplace and not k.states_inplace
+    monkeypatch.setattr(G, "ssm_fused", lambda *a, **kw: "interpret")
+    monkeypatch.setattr(G, "decode_inplace", lambda *a, **kw: False)
+    k = served(unit.cfg).kernels(toy, None, 4, jnp.float32)
+    assert k.round_how == {"inplace": False, "ssm_inplace": "interpret"}
+    assert k.round_counts(8) == {"inplace_steps": 0,
+                                 "retention_fused_steps": 0,
+                                 "ssm_fused_steps": 8}
+
+
 # -- the programs against the reference ------------------------------------
 
 
@@ -260,9 +385,10 @@ def test_whole_prefill_gives_the_references_logits_at_every_position(model):
                                atol=1e-4, rtol=0)
 
 
+@BOTH_STEPS
 @pytest.mark.parametrize("chunk", [16, 8, 4, 3, 1])
-def test_chunked_prefill_then_decode_rounds_equal_the_reference(model,
-                                                                chunk):
+def test_chunked_prefill_then_decode_rounds_equal_the_reference(
+        model, chunk, ssm_inplace):
     """The same two prompts (13 and 8 tokens: unequal, so every call but a
     whole one has pad positions or a row of width 0) in one, two and four
     chunks, and in chunks shorter than the convolution's history; then two
@@ -281,7 +407,8 @@ def test_chunked_prefill_then_decode_rounds_equal_the_reference(model,
     token = first
     for _ in range(2):
         toks, pool, token, n_valid, *_ = decode(
-            unit, params, pool, TABLES, token, n_valid, [True, True], 4)
+            unit, params, pool, TABLES, token, n_valid, [True, True], 4,
+            ssm_inplace)
         got.append(np.asarray(toks))
     got = np.concatenate(got, axis=1)
     for i, r in enumerate(rows):
@@ -299,11 +426,13 @@ def test_chunked_prefill_then_decode_rounds_equal_the_reference(model,
                                atol=1e-4, rtol=0)
 
 
+@BOTH_STEPS
 def test_an_inactive_row_writes_scratch_and_leaves_a_live_state_alone(
-        model):
+        model, ssm_inplace):
     """A decode round with an empty slot whose table is all zeros (what
     the scheduler pads with): the live row's tokens are what they are
-    alone, and the empty slot touched nothing but block 0's entries."""
+    alone, and the empty slot touched nothing but block 0's entries --
+    under the kernel not even the scratch entry's ``h``."""
     doc, unit, params = model
     rows = prompts([9, 6], seed=3)
     logits, pool = chunked(unit, params, rows, 16, TABLES)
@@ -312,10 +441,13 @@ def test_an_inactive_row_writes_scratch_and_leaves_a_live_state_alone(
     tables[1] = 0
     toks, pool, *_ = decode(
         unit, params, pool, jnp.asarray(tables),
-        [int(logits[0].argmax()), 0], [9, 0], [True, False], 4)
+        [int(logits[0].argmax()), 0], [9, 0], [True, False], 4, ssm_inplace)
     np.testing.assert_array_equal(
         np.asarray(toks)[0], reference_answer(params, rows[0], doc, 5)[1:])
     assert not np.asarray(toks)[1].any()
+    if ssm_inplace:
+        np.testing.assert_array_equal(np.asarray(pool["l0"]["h"])[0],
+                                      before["l0"]["h"][0])
     # row 1's states (at its first block, 7) are as its prefill left them
     for i, letter in enumerate(PATTERN):
         if letter == "M":
@@ -326,7 +458,8 @@ def test_an_inactive_row_writes_scratch_and_leaves_a_live_state_alone(
                 assert np.abs(before[f"l{i}"][name][7]).max() > 0
 
 
-def test_a_reused_block_needs_no_reset(model):
+@BOTH_STEPS
+def test_a_reused_block_needs_no_reset(model, ssm_inplace):
     """A sequence that starts at position 0 reads a zero state whatever its
     first block held: after another sequence's prefill and rounds over the
     same blocks, a new prompt there gives the reference's logits."""
@@ -334,7 +467,8 @@ def test_a_reused_block_needs_no_reset(model):
     old, new = prompts([11, 7], seed=4)
     logits, pool = chunked(unit, params, [old], 4, TABLES[:1])
     _, pool, *_ = decode(unit, params, pool, TABLES[:1],
-                         [int(logits[0].argmax())], [11], [True], 4)
+                         [int(logits[0].argmax())], [11], [True], 4,
+                         ssm_inplace)
     assert float(jnp.abs(pool["l0"]["conv"][1]).max()) > 0
     assert float(jnp.abs(pool["l0"]["h"][1]).max()) > 0
     logits, pool = chunked(unit, params, [new], 3, TABLES[:1], pool=pool)
@@ -695,15 +829,22 @@ def settled(tokens):
         time.sleep(0.02)
 
 
+@pytest.mark.parametrize("fused", [False, "interpret"],
+                         ids=["step", "kernel"])
 def test_genserver_serves_the_reference_answer_and_counts_its_work(
-        model, clean_genperf, recorded_spans, monkeypatch):
+        model, clean_genperf, recorded_spans, monkeypatch, fused):
     """Rows of different lengths co-scheduled, prompts of one chunk and of
     three (states carried over chunks), unary and streamed -- and what the
     server says of it: the state bytes its rows read and wrote, the picks
-    that fell on held experts beside the slots of the held experts."""
+    that fell on held experts beside the slots of the held experts, and who
+    updated the states: on the CPU ``ssm_step`` over gathered rows
+    (``ssm_fused`` says no), and the kernel in Pallas interpret mode where
+    it is made to say "interpret", as a TPU says yes."""
     doc, unit, params = model
     # the chunk stays 8: the scheduler does not probe a wider one
     monkeypatch.setenv("SELDON_TPU_GEN_PREFILL_CHUNK_MAX", "8")
+    if fused:
+        monkeypatch.setattr(G, "ssm_fused", lambda *a, **kw: fused)
     srv = server(unit, params)
     try:
         cases = [(3, 6), (8, 9), (19, 7)]
@@ -723,9 +864,15 @@ def test_genserver_serves_the_reference_answer_and_counts_its_work(
             np.stack([reference_answer(params, r, doc, 7) for r in rows]))
         perf = settled(2 * (6 + 9 + 7 + 7))
         assert srv.snapshot()["tick_errors_total"] == 0
+        assert srv._kernels.ssm_inplace == fused
+        assert not srv._kernels.attends_inplace
+        assert not srv._kernels.states_inplace
     finally:
         srv.stop()
     prefill, dec = perf["served_prefill"], perf["served_decode"]
+    assert dec["ssm_fused_steps"] == (dec["device_steps"] if fused else 0)
+    assert dec["device_steps"] > 0
+    assert dec["inplace_steps"] == dec["retention_fused_steps"] == 0
     # 19 tokens at chunk 8 are three chunks a row, the later two carried
     assert prefill["rows"] == 2 * (1 + 1 + 3 + 3)
     assert prefill["carried_rows"] == 2 * (2 + 2)

@@ -599,6 +599,92 @@ def test_retention_step_compiles_for_a_described_v5e(one_chip, B, KV, G, hd):
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
 
 
+@pytest.mark.parametrize("B,H,P,G,N", [
+    (16, 64, 64, 8, 128),   # nemotron3-nano-30b-a3b's widest round
+    (1, 64, 64, 8, 128),
+    (4, 8, 256, 2, 256),    # a head of two chunks of rows, two registers wide
+], ids=["nemotron-16", "nemotron-1", "p256-n256"])
+def test_ssm_step_compiles_for_a_described_v5e(one_chip, B, H, P, G, N):
+    """The third kernel of a decode round (ops/ssm.py ``_fused_step``, here
+    beside the others' compiles because one test file describes the chip):
+    Mosaic accepts it at the published widths -- the two transposes a
+    chunk of rows, the masked pick of a group's B and C, its tiles' vector
+    memory -- the compiled call writes the pool's entries where they lie,
+    no op but the parameters produces a state-shaped tensor, and what
+    ``jax.numpy`` makes around the call (``dt x``, the decays) is small.  A
+    compile, not a run."""
+    from seldon_core_tpu.ops import ssm
+
+    assert ssm.step_supported(backend="tpu", state_dtype=jnp.float32,
+                              heads=H, head_dim=P, groups=G, state=N, rows=B)
+    NB = 128
+    bf = jnp.bfloat16
+    compiled = _described(one_chip, lambda s: jax.jit(
+        ssm._fused_step, donate_argnums=(5,)).lower(
+            s((B, H, P), bf), s((B, H), jnp.float32), s((H,), jnp.float32),
+            s((B, G, N), bf), s((B, G, N), bf),
+            s((NB, H, P, N), jnp.float32), s((B,), jnp.int32),
+            s((B,), jnp.bool_), s((B,), jnp.int32), s((), jnp.int32),
+        ).compile())
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert "output_to_operand_aliasing={{1}: (8, {})}" in text
+    assert _makers(text, (NB, H, P, N)) <= {"parameter", "get-tuple-element"}
+    assert not _makers(text, (B, H, P, N))      # no gathered copy of rows
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+
+
+def test_decode_round_of_state_space_layers_compiles_around_the_kernel(
+        one_chip):
+    """A whole decode round of two Mamba-2 layers, an attention layer and
+    an expert layer at the published widths, 16 rows, with both kernels
+    forced as a TPU decides them: through ``_paged_block``'s inner ``jit``,
+    the layer loop, the barrier behind each one-sub-layer block and the
+    round's scan, each state-space layer's call still carries the pool
+    aliased and no op but the parameters produces a state-shaped tensor --
+    nor the ``[16, 64, 64, 128]`` copy of the rows' states that
+    ``ssm_step``'s gather made."""
+    from seldon_core_tpu.models.generate import (
+        TransformerGenerator,
+        paged_decode_round_jit,
+    )
+
+    B, NB = 16, 32
+    unit = TransformerGenerator(
+        vocab=512, d_model=2688, n_heads=32, n_kv_heads=2, head_dim=128,
+        n_layers=4, layer_kinds="mtme", conv_kernel=4, ssm_heads=64,
+        ssm_head_dim=64, ssm_groups=8, ssm_state=128, d_expert=256,
+        n_experts=8, moe_k=2, experts_held=4, router="sigmoid_bias",
+        expert_act="relu2", d_shared=256, rope=False, tie_embeddings=False,
+        dtype="bfloat16", seed=0)
+    cfg = unit.cfg
+
+    def on_chip(s, tree):
+        return jax.tree.map(lambda a: s(a.shape, a.dtype), tree)
+
+    def compile_of(s):
+        params = on_chip(s, jax.eval_shape(
+            lambda: unit.init_state(None)["params"]))
+        shapes = jax.eval_shape(lambda: init_block_pool(cfg, NB, 256))
+        # the chip keeps K/V in the model's dtype (the CPU stores float32)
+        pool = {name: {k: s(a.shape, jnp.bfloat16 if k in "kv" else a.dtype)
+                       for k, a in entry.items()}
+                for name, entry in shapes.items()}
+        return paged_decode_round_jit.lower(
+            params, pool, s((B, 8), jnp.int32), s((B,), jnp.int32),
+            s((B,), jnp.int32), s((B,), jnp.bool_), s((B,), jnp.bool_),
+            s((B,), jnp.uint32), cfg, span=8, temperature=0.0, top_k=0,
+            top_p=0.0, eos_token=-1, inplace=True,
+            ssm_inplace=True).compile()
+
+    text = _described(one_chip, compile_of).as_text()
+    assert len(re.findall(
+        r"output_to_operand_aliasing=\{\{1\}: \(8, \{\}\)\}", text)) == 2
+    assert _makers(text, (NB, 64, 64, 128)) <= {"parameter",
+                                                "get-tuple-element"}
+    assert not _makers(text, (B, 64, 64, 128))
+
+
 @pytest.mark.parametrize("B,KV,G,W,hd", [
     (16, 8, 5, 256, 128),   # brumby-14b's widest prefill call
     (1, 8, 5, 256, 128),

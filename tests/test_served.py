@@ -105,7 +105,9 @@ def test_the_kernels_answer_is_asked_once_and_counted(generator, monkeypatch):
     k = d.kernels(pool, None, 4, jnp.float32)
     assert not k.inplace
     assert k.round_counts(8) == {"inplace_steps": 0,
-                                 "retention_fused_steps": 0}
+                                 "retention_fused_steps": 0,
+                                 "ssm_fused_steps": 0}
+    assert k.round_how == {"inplace": False, "ssm_inplace": False}
     assert k.prefill_counts(8, 3) == {"retention_fused_rows": 0}
     assert k.fused(8) is (False if kind == "retention" else None)
     asked = []
@@ -123,7 +125,11 @@ def test_the_kernels_answer_is_asked_once_and_counted(generator, monkeypatch):
     assert bool(k.attends_inplace) == (kind != "retention")
     assert k.round_counts(8) == {
         "inplace_steps": 0 if kind == "retention" else 8,
-        "retention_fused_steps": 8 if kind == "retention" else 0}
+        "retention_fused_steps": 8 if kind == "retention" else 0,
+        # no kind here has a state-space layer (tests/test_nemotron_block.py
+        # has one): the attention layers' answer is not theirs
+        "ssm_fused_steps": 0}
+    assert k.round_how == {"inplace": "interpret", "ssm_inplace": False}
     for _ in range(2):
         assert k.prefill_counts(8, 3) == {
             "retention_fused_rows": 3 if kind == "retention" else 0}
