@@ -444,7 +444,19 @@ def _attend_paged(q, view, start, block_length: int = 1, limit=None):
     return _grouped_pv(p, view["v"], q.shape, q.dtype, view.get("v_s"))
 
 
-def _attend_view_and_fresh(q, view, start, k_new, v_new):
+def _fresh_mask(s, block_length):
+    """Scores ``s`` [..., W, W] of a pass's queries against its own fresh
+    keys under the block-causal mask by block: a query sees its own block
+    of ``block_length`` whole and the blocks before it.  A pass of one
+    block (``block_length`` None: all of it is one) has nothing to mask."""
+    W = s.shape[-1]
+    if W <= (block_length or W):
+        return s
+    block = jnp.arange(W) // block_length
+    return jnp.where(block[None, :] > block[:, None], -1e30, s)
+
+
+def _attend_view_and_fresh(q, view, start, k_new, v_new, block_length=None):
     """q [B, H, W, hd] — one diffusion block of a row, at positions
     ``start[b] ..`` — over a dense paged view taken BEFORE the block was
     begun, and the block's own fresh K/V ``k_new`` / ``v_new``
@@ -452,34 +464,62 @@ def _attend_view_and_fresh(q, view, start, k_new, v_new):
     (the earlier blocks; what the view holds from there on is stale) and
     the whole block, later positions too (the block-causal mask), in ONE
     softmax over both.  The fresh K/V never pass through the pool, so a
-    view gathered once serves every pass over the block."""
+    view gathered once serves every pass over the block.
+
+    A pass over SEVERAL blocks of ``block_length`` (``W`` is wider than
+    one) sees the same of the view, and of the fresh keys what
+    ``_fresh_mask`` leaves."""
     s_old = _grouped_qk(q, view["k"], view.get("k_s"))      # [B,KV,g,W,L]
     L = view["k"].shape[2]
     earlier = jnp.arange(L)[None, :] < start[:, None]       # [B, L]
     s_old = jnp.where(earlier[:, None, None, None, :], s_old, -1e30)
     p = jax.nn.softmax(
-        jnp.concatenate([s_old, _grouped_qk(q, k_new)], axis=-1), axis=-1)
+        jnp.concatenate(
+            [s_old, _fresh_mask(_grouped_qk(q, k_new), block_length)],
+            axis=-1), axis=-1)
     return (_grouped_pv(p[..., :L], view["v"], q.shape, q.dtype,
                         view.get("v_s"))
             + _grouped_pv(p[..., L:], v_new, q.shape, q.dtype))
 
 
 def _attend_pool_and_fresh(q, pool_layer, tables, plan, k_new, v_new,
-                           interpret: bool = False):
+                           interpret: bool = False, block_length=None):
     """``_attend_view_and_fresh`` with the pool read in place: the kernel
     (ops.paged_attention, ``plan`` = ``decode_plan(start, active, capacity,
     fresh=0)``) gives each query's weighted sum over the row's cache before
     ``start`` with that softmax's largest score and mass, and the block's
     own fresh keys join here, in float32: ONE softmax over both parts, by
     the larger of the two maxima.  A row whose block starts at 0 has no
-    mass in the pool and takes the fresh part alone."""
+    mass in the pool and takes the fresh part alone.
+
+    For a pass over several blocks ``plan`` is a sequence, a plan a block
+    of ``q``: the kernel serves one block's queries a call (a row that is
+    not live in a block's plan is not walked for it), the calls one after
+    another as a ``lax.map`` -- ONE place in the program that holds the
+    kernel, as a pass of one block has: a second costs a program's trace
+    and lowering a third of a second (PERF.md section 6, PR 48)."""
     from seldon_core_tpu.ops.paged_attention import paged_decode_attention
 
-    old, peak, mass = paged_decode_attention(
-        q, pool_layer["k"], pool_layer["v"], tables, *plan,
-        interpret=interpret, stats=True)             # [B, KV, g, W(, hd)]
+    def cache(q, plan):
+        return paged_decode_attention(
+            q, pool_layer["k"], pool_layer["v"], tables, *plan,
+            interpret=interpret, stats=True)             # [B, KV, g, W(, hd)]
+
+    L = block_length or q.shape[2]
+    if q.shape[2] <= L:
+        old, peak, mass = cache(q, plan)
+    else:
+        n = len(plan)
+        blocks = q.reshape(q.shape[:2] + (n, L) + q.shape[3:])
+        old, peak, mass = (
+            jnp.moveaxis(part, 0, 3).reshape(
+                part.shape[1:4] + (n * L,) + part.shape[5:])
+            for part in jax.lax.map(
+                lambda each: cache(*each),
+                (jnp.moveaxis(blocks, 2, 0),
+                 jax.tree.map(lambda *a: jnp.stack(a), *plan))))
     B, KV, g, W, hd = old.shape
-    s = _grouped_qk(q, k_new)                        # [B, KV, g, W, W]
+    s = _fresh_mask(_grouped_qk(q, k_new), block_length)  # [B, KV, g, W, W]
     top = jnp.maximum(peak, s.max(axis=-1))
     p = jnp.exp(s - top[..., None])
     mass = mass * jnp.exp(peak - top)
@@ -715,7 +755,8 @@ def _retention(lp, x, pool_layer, tables, start, valid, cfg: LMConfig,
 # lowers the block once per distinct (shapes, static arguments) -- a dense
 # program once, one whose layers are of several kinds (``kind``) once a kind,
 # a round of denoising passes three times (a pass that writes
-# nothing, the commit, the commit's last layer) -- and every other layer is a
+# nothing, the pass that writes a block's K/V, that pass's last layer) -- and
+# every other layer is a
 # cached bind that lowers to a ``call`` of one private function, so trace,
 # lowering and the module's text no longer grow with depth (PERF.md section
 # 6, PR 37).  XLA inlines the calls before it optimises.  Nothing is donated
@@ -727,8 +768,9 @@ def _retention(lp, x, pool_layer, tables, start, valid, cfg: LMConfig,
                      "fused"))
 def _paged_block(lp, x, pool_layer, tables, start, valid, cfg: LMConfig,
                  plan=None, interpret: bool = False, limit=None,
-                 kv_only: bool = False, view=None, write: bool = True,
-                 kind=None, fused: bool = False):
+                 kv_only: bool = False, view=None,
+                 write: Optional[int] = None, kind=None,
+                 fused: bool = False):
     """One decoder block over the paged pool: K/V written at per-row
     positions start[b] + i (scratch-routed where ``valid`` is False),
     attention over each row's own blocks.  x [B, W, D] -> (x', pool layer',
@@ -755,11 +797,18 @@ def _paged_block(lp, x, pool_layer, tables, start, valid, cfg: LMConfig,
     [B] is how far each row really holds positions, for the mask of
     ``cfg.block_length`` > 1 (``_attend_paged``).  ``kv_only`` stops once
     the K/V are written: the last layer of a pass whose hidden states
-    nobody reads (``_denoising_round``'s commit).  ``view`` is a dense view
-    of this layer's blocks gathered before a diffusion block was begun:
-    attention then goes over it and the fresh K/V together
-    (``_attend_view_and_fresh``) and gathers nothing; ``write`` False
-    leaves the pool as it is (a denoising pass: its K/V are not kept)."""
+    nobody reads (``_denoising_round``: the pass that writes a finished
+    block's K/V).  ``view`` is a dense view of this layer's blocks gathered
+    before a diffusion block was begun: attention then goes over it and the
+    fresh K/V together (``_attend_view_and_fresh``) and gathers nothing.
+    ``write`` is how many of the call's LEADING positions' K/V the pool
+    keeps: None all of them, 0 none (a denoising pass: its K/V are not
+    kept), a block's length of a call over two blocks (``W //
+    cfg.block_length`` says so) for the pass that writes a finished block's
+    K/V with the next block's first denoising pass riding it -- the fresh
+    keys are then masked by block (``_fresh_mask``), the view or the pool
+    serves the cache before ``start`` to both blocks, and ``plan`` is a
+    plan a block."""
     from seldon_core_tpu.ops.paged_attention import paged_decode_attention
     from seldon_core_tpu.ops.quant import lm_matmul
 
@@ -810,10 +859,14 @@ def _paged_block(lp, x, pool_layer, tables, start, valid, cfg: LMConfig,
     # ops by bench/lib/trace_scopes.py — keep the names stable
     positions = start[:, None] + jnp.arange(W)[None, :]  # [B, W] per-row
     _, q, k, v = _project_qkv(lp, x, positions, cfg)
-    if write:
+    kept = W if write is None else write
+    if kept:
         with jax.named_scope("kv_write"):
-            pool_layer = _paged_write(pool_layer, tables, positions, valid,
-                                      k, v)
+            fresh = (positions, valid, k, v)
+            if kept < W:
+                fresh = (positions[:, :kept], valid[:, :kept],
+                         k[:, :, :kept], v[:, :, :kept])
+            pool_layer = _paged_write(pool_layer, tables, *fresh)
     if kv_only:
         return x, pool_layer, jnp.int32(0)
     gathered = view is not None
@@ -822,12 +875,13 @@ def _paged_block(lp, x, pool_layer, tables, start, valid, cfg: LMConfig,
             view = _paged_view(pool_layer, tables, hd)
     with jax.named_scope("attn"):
         if gathered:
-            a = _attend_view_and_fresh(q, view, start, k, v)
+            a = _attend_view_and_fresh(q, view, start, k, v,
+                                       cfg.block_length)
         elif plan is None:
             a = _attend_paged(q, view, start, cfg.block_length, limit)
         elif cfg.block_length > 1:
             a = _attend_pool_and_fresh(q, pool_layer, tables, plan, k, v,
-                                       interpret)
+                                       interpret, cfg.block_length)
         else:
             a = paged_decode_attention(
                 q, pool_layer["k"], pool_layer["v"], tables, *plan,
@@ -1122,6 +1176,27 @@ def _denoising_round(params, pool, tables, token, n_valid, active, seen_eos,
     is what the cache keeps, it needs no logits, and its last layer stops
     at its K/V.
 
+    In a round of SEVERAL blocks the pass that writes block b's K/V and the
+    first denoising pass of block b + 1 are ONE pass of the layers over
+    ``[B, 2 L]`` (``shared``): they follow each other over the same rows,
+    the second reads what the first has just written, and every weight --
+    a dropless expert layer takes a token alone -- is position-wise, so
+    the two are one call under the block-causal mask: block b's positions
+    see the cache before b and themselves; block b + 1's, masked, see the
+    cache before b, block b's fresh K/V and themselves.  The weights, the
+    experts the two passes chose among them, are read once where they were
+    read twice: ``blocks * (steps + 1)`` passes of a block in ``blocks *
+    steps + 1`` passes of the device.  The pool keeps block b's K/V alone.
+    In the last layer block b stops at its K/V and block b + 1 goes
+    through whole, as two calls the other passes have already traced: the
+    K/V pass's last layer on block b, then a denoising pass's layer on
+    block b + 1 at its own start, which finds block b's K/V in the cache
+    (the layer's ``wqkv`` is read twice, nothing else).  The round's last
+    block has no next: its pass is the same calls with the second half
+    invalid (no expert picked, no cache walked for it, no head).  So a
+    block after the round's first comes with its first pass made and runs
+    the others: one loop from a bound the program reads, one body.
+
     A denoising pass's K/V are NOT stored: every pass, the commit too,
     attends over the cache before the block's start and its own fresh K/V
     in ONE softmax, and only the commit writes the pool.  ``inplace`` (as
@@ -1130,18 +1205,20 @@ def _denoising_round(params, pool, tables, token, n_valid, active, seen_eos,
     queries a row folded into the kernel's query group, the fresh part
     joined outside it (``_attend_pool_and_fresh``), nothing gathered; or
     on the gather path, each layer's blocks gathered into a dense view once
-    a block, before its first pass (``_attend_view_and_fresh``) — once a
-    block and not once a pass.  ``_paged_block`` stays the one block both
-    kinds of round run.
+    a block, before its first pass of its own -- after the shared pass's
+    write -- (``_attend_view_and_fresh``): once a block and not once a
+    pass.  ``_paged_block`` stays the one block both kinds of round run.
 
     Returns what ``paged_decode_round`` returns: the finished blocks
     [B, span] (a row's NEW tokens are those from its ``n_valid`` on; after
     a generated ``eos_token`` a row emits eos, the latch ``seen_eos``
     carried on), the pool, a ``[B]`` token nobody reads, ``n_valid'`` (the
     round's end), ``seen_eos'``, ``keys``; then ``{"experts_read": int32}``
-    where the configuration has experts; then, under ``trace_passes``
-    (the benchmark's driver, archs/<arch>/drive.py), what every denoising
-    pass ``saw``, ``picked`` and ``chose``, each [blocks, steps, B, L]."""
+    where the configuration has experts (a shared pass counts the experts
+    it read, once); then, under ``trace_passes`` (the benchmark's driver,
+    archs/<arch>/drive.py), what every denoising pass ``saw``, ``picked``
+    and ``chose``, each [blocks, steps, B, L] (a block's step 0 after the
+    round's first block is the shared pass's second half)."""
     from seldon_core_tpu.ops.paged_attention import decode_plan
 
     L, steps = cfg.block_length, cfg.denoising_steps
@@ -1149,7 +1226,7 @@ def _denoising_round(params, pool, tables, token, n_valid, active, seen_eos,
         raise ValueError(f"span={span} is no whole number of blocks of {L}")
     if temperature > 0.0:
         raise ValueError("a round of denoising passes decodes greedily")
-    B = n_valid.shape[0]
+    B, blocks = n_valid.shape[0], span // L
     if inplace is None:
         inplace = decode_inplace(pool, width=L, heads=cfg.n_heads, rows=B,
                                  head_dim=cfg.hd)
@@ -1160,65 +1237,95 @@ def _denoising_round(params, pool, tables, token, n_valid, active, seen_eos,
     valid = jnp.broadcast_to(active[:, None], (B, L))
     head = _head(params, cfg)
 
-    def through(pool, plan, views, x, start, commit: bool):
-        """The block's ids ``x`` [B, L] at ``start`` through every layer,
-        over the cache before ``start`` — by the kernel's ``plan``, or
-        ``views``, the layers' caches gathered at the block's start; only
-        the commit writes the pool."""
+    def layer(i, h, entry, start, valid, plan, view, write, kv_only=False):
+        """Layer ``i`` over ``h`` (``_paged_block``; one spelling of the
+        call, so that equal shapes bind one trace)."""
+        return _paged_block(
+            params[f"l{i}"], h, entry, tables, start, valid, cfg, plan=plan,
+            interpret=inplace == "interpret", view=view, write=write,
+            kv_only=kv_only, kind=cfg.kind(i))
+
+    def through(pool, plan, views, x, start, valid, write: int,
+                upto: int = cfg.n_layers):
+        """The ids ``x`` [B, W] at ``start`` through the first ``upto``
+        layers (all of them: a whole pass), over the cache before ``start``
+        — by the kernel's ``plan``, or ``views``, the layers' caches
+        gathered at the block's start; the pool keeps the K/V of the first
+        ``write`` positions, and a whole pass that keeps any stops at its
+        last layer's."""
         read = _experts_counted(None, cfg)
         with jax.named_scope("embed"):
             h = params["embed"][x]
-        for i in range(cfg.n_layers):
-            h, layer, aux = _paged_block(
-                params[f"l{i}"], h, pool[f"l{i}"], tables, start, valid,
-                cfg, plan=plan, interpret=inplace == "interpret",
-                view=views and views[i], write=commit,
-                kv_only=commit and i == cfg.n_layers - 1, kind=cfg.kind(i))
-            if commit:
-                pool[f"l{i}"] = layer
+        for i in range(upto):
+            h, entry, aux = layer(
+                i, h, pool[f"l{i}"], start, valid, plan, views and views[i],
+                write, bool(write) and i == cfg.n_layers - 1)
+            if write:
+                pool[f"l{i}"] = entry
             if cfg.d_expert:
                 read = read + aux
         return h, pool, read
 
-    def block(carry, b):
-        pool, seen_eos, read = carry
+    def begin(b):
+        """Block ``b`` of the round as its first pass finds it: its start
+        and positions, which places are masked, and its ids (the prompt's
+        remainder, which only a row's first block holds, then the mask
+        id)."""
         start = base + b * L
         pos = start[:, None] + jnp.arange(L)[None, :]
         masked = pos >= n_valid[:, None]
-        x = jnp.where(masked, jnp.int32(cfg.mask_id), token)
-        # how every pass of the block reads the cache before ``start``:
-        # the kernel's scalar operands, or each layer's view, once a block
-        plan = views = None
-        if inplace:
-            plan = decode_plan(start, active, capacity, fresh=0)
-        else:
-            with jax.named_scope("kv_gather"):
-                views = [_paged_view(pool[f"l{i}"], tables, cfg.hd)
-                         for i in range(cfg.n_layers)]
+        return (start, pos, masked,
+                jnp.where(masked, jnp.int32(cfg.mask_id), token))
 
+    # how a block's passes read the cache before its start, made once a
+    # block: the kernel's scalar operands, or each layer's view
+    def planned(start, live=active):
+        return decode_plan(start, live, capacity, fresh=0) if inplace else None
+
+    def gathered(pool, upto: int = cfg.n_layers):
+        if inplace:
+            return None
+        with jax.named_scope("kv_gather"):
+            return [_paged_view(pool[f"l{i}"], tables, cfg.hd)
+                    for i in range(upto)]
+
+    def fix(h, x, masked):
+        """A pass's hidden states ``h`` of a block ``x``: the block with
+        its surest masked places fixed, what stays masked, and what the
+        pass saw, picked and chose."""
+        with jax.named_scope("unembed"):
+            h = _rmsnorm(h, params["ln_f"], cfg.norm_eps)
+            logits = (h @ head).astype(jnp.float32)           # [B, L, V]
+        with jax.named_scope("sample"):
+            # the mask id is never an answer
+            logits = logits.at[..., cfg.mask_id].set(-jnp.inf)
+            chose = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            sure = jnp.exp(jnp.max(logits, axis=-1)
+                           - jax.nn.logsumexp(logits, axis=-1))
+            sure = jnp.where(masked, sure, -1.0)
+            rank = jnp.argsort(jnp.argsort(-sure, axis=-1), axis=-1)
+            picked = masked & (rank < L // steps)
+        return (jnp.where(picked, chose, x), masked & ~picked,
+                (x, picked, chose))
+
+    if blocks > 1:
+        # two places run it (a block's own passes, the shared pass): one
+        # trace and one lowering for them
+        fix = jax.jit(fix)
+
+    def denoising(pool, plan, views, start):
+        """One denoising pass of the block at ``start``, a scan's body."""
         def denoise(c, _):
             x, masked, read = c
             with jax.named_scope("denoise"):
-                h, _, r = through(pool, plan, views, x, start, commit=False)
-                with jax.named_scope("unembed"):
-                    h = _rmsnorm(h, params["ln_f"], cfg.norm_eps)
-                    logits = (h @ head).astype(jnp.float32)   # [B, L, V]
-                with jax.named_scope("sample"):
-                    # the mask id is never an answer
-                    logits = logits.at[..., cfg.mask_id].set(-jnp.inf)
-                    chose = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-                    sure = jnp.exp(jnp.max(logits, axis=-1)
-                                   - jax.nn.logsumexp(logits, axis=-1))
-                    sure = jnp.where(masked, sure, -1.0)
-                    rank = jnp.argsort(jnp.argsort(-sure, axis=-1), axis=-1)
-                    picked = masked & (rank < L // steps)
-            return ((jnp.where(picked, chose, x), masked & ~picked,
-                     read + r), (x, picked, chose))
+                h, _, r = through(pool, plan, views, x, start, valid, 0)
+                x, masked, saw = fix(h, x, masked)
+            return (x, masked, read + r), saw
+        return denoise
 
-        (x, _, read), seen = jax.lax.scan(
-            denoise, (x, masked, read), None, length=steps)
-        with jax.named_scope("commit"):
-            _, pool, r = through(pool, plan, views, x, start, commit=True)
+    def latch(x, pos, seen_eos):
+        """The ids ``x`` at ``pos`` as the round emits them: eos after a
+        generated eos, 0 for an empty slot; and the latch."""
         out = x
         if eos_token >= 0:
             # only a GENERATED eos stops a row: the prompt's remainder may
@@ -1229,13 +1336,87 @@ def _denoising_round(params, pool, tables, token, n_valid, active, seen_eos,
             out = jnp.where(seen_eos[:, None] | after,
                             jnp.int32(eos_token), x)
             seen_eos = seen_eos | jnp.any(hit, axis=1)
-        out = jnp.where(active[:, None], out, 0)
+        return jnp.where(active[:, None], out, 0), seen_eos
+
+    def alone(carry, b):
+        """A block whose passes are all its own: the round of one."""
+        pool, seen_eos, read = carry
+        start, pos, masked, x = begin(b)
+        plan, views = planned(start), gathered(pool)
+        (x, _, read), seen = jax.lax.scan(
+            denoising(pool, plan, views, start), (x, masked, read), None,
+            length=steps)
+        with jax.named_scope("commit"):
+            _, pool, r = through(pool, plan, views, x, start, valid, L)
+        out, seen_eos = latch(x, pos, seen_eos)
         return (pool, seen_eos, read + r), (out, seen)
 
-    (pool, seen_eos, read), (toks, seen) = jax.lax.scan(
-        block, (pool, seen_eos, _experts_counted(None, cfg)),
-        jnp.arange(span // L))
-    toks = toks.transpose(1, 0, 2).reshape(B, span)
+    def sharing(carry, b):
+        """Block ``b`` of a round of several: the denoising passes of its
+        own -- all of the round's first block's, and of a later block,
+        which comes with its first pass made, the others -- then the pass
+        that writes its K/V with the first pass of block ``b + 1`` riding
+        it."""
+        pool, read, x, masked, saw, views = carry
+        start = base + b * L
+        plan = planned(start)
+        denoise = denoising(pool, plan, views, start)
+
+        def own(i, c):
+            *c, seen = c
+            c, saw = denoise(tuple(c), None)
+            return *c, jax.tree.map(lambda all_, one: all_.at[i].set(one),
+                                    seen, saw)
+
+        # (a loop from a bound the program reads: one body for both counts)
+        x, _, read, seen = jax.lax.fori_loop(
+            jnp.minimum(b, 1), steps, own,
+            (x, masked, read, jax.tree.map(
+                lambda one: jnp.zeros((steps,) + one.shape, one.dtype
+                                      ).at[0].set(one), saw)))
+        more = b + 1 < blocks
+        live, last = active & more, cfg.n_layers - 1
+        _, _, masked, nxt = begin(b + 1)
+        with jax.named_scope("shared"):
+            # both blocks through every layer but the last, as one pass
+            h, pool, r = through(
+                pool, plan and (plan, planned(start, live)), views,
+                jnp.concatenate([x, nxt], axis=1), start,
+                jnp.concatenate([valid, valid & more], axis=1), L, upto=last)
+            # the last layer: block b stops at its K/V, and block b + 1
+            # goes through it as a denoising pass's block does, over a
+            # cache that now holds them
+            _, entry, _ = layer(last, h[:, :L], pool[f"l{last}"], start,
+                                valid, plan, views and views[last], L, True)
+            pool[f"l{last}"], view = entry, None
+            if not inplace:
+                with jax.named_scope("kv_gather"):
+                    view = _paged_view(entry, tables, cfg.hd)
+            h, _, aux = layer(last, h[:, L:], entry, start + L, valid & more,
+                              planned(start + L, live), view, 0)
+            if cfg.d_expert:
+                r = r + aux
+            # the round's last block hands nothing on: no head, no views
+            # (the last layer's is the one just gathered)
+            nxt, masked, saw, views = jax.lax.cond(
+                more, lambda: fix(h, nxt, masked) + (
+                    view and gathered(pool, last) + [view],),
+                lambda: (nxt, masked, saw, views))
+        return (pool, read + r, nxt, masked, saw, views), (x, seen)
+
+    read = _experts_counted(None, cfg)
+    if blocks == 1:
+        (pool, seen_eos, read), (toks, seen) = jax.lax.scan(
+            alone, (pool, seen_eos, read), jnp.arange(blocks))
+        toks = toks.transpose(1, 0, 2).reshape(B, span)
+    else:
+        _, _, masked, x = begin(0)
+        (pool, read, *_), (toks, seen) = jax.lax.scan(
+            sharing, (pool, read, x, masked, (x, masked, x), gathered(pool)),
+            jnp.arange(blocks))
+        toks, seen_eos = latch(
+            toks.transpose(1, 0, 2).reshape(B, span),
+            base[:, None] + jnp.arange(span)[None, :], seen_eos)
     n_valid = jnp.where(active, base + span, n_valid)
     out = (toks, pool, jnp.zeros((B,), jnp.int32), n_valid, seen_eos, keys)
     if cfg.d_expert:

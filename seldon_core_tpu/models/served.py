@@ -84,6 +84,9 @@ class Served:
     quantum: int
     #: passes of the model a round makes a block of ``quantum`` positions:
     #: 1 a token, or the denoising passes and the one that writes the K/V
+    #: -- a block's passes, of which the last shares a pass of the device
+    #: with the first of the round's next block (``round_counts``
+    #: ``shared_passes``)
     block_passes: int
     #: a prompt's last chunk picks the row's first token, which then rides
     #: from round to round PENDING (sampled, not yet in the cache), in the
@@ -160,10 +163,15 @@ class Served:
                      ) -> Dict[str, int]:
         """What a decode round of ``span`` positions over live rows holding
         ``n_valid`` is given, by its span's and the tick record's names:
-        ``passes`` of the model in ``blocks``, ``row_passes`` (x the real
-        rows), the ``kv_positions`` they attend over, and ``expert_slots``
-        (experts held x expert layers x passes: what the round's own
-        ``experts_read`` is a share of)."""
+        ``passes`` of the model in ``blocks`` -- a block's passes, whoever
+        shares them -- ``row_passes`` (x the real rows), the
+        ``kv_positions`` they attend over, ``expert_slots`` (experts held x
+        expert layers x passes: what the round's own ``experts_read`` is a
+        share of) and ``shared_passes``, the passes of the device that
+        served two of them: the one that writes a diffusion block's K/V
+        with the next block's first denoising pass riding it, one a block
+        but the round's last (``generate._denoising_round``); 0 for a token
+        a step."""
         blocks = span // self.quantum
         passes = blocks * self.block_passes
         if self.quantum > 1:
@@ -183,6 +191,7 @@ class Served:
             "passes": passes, "blocks": blocks,
             "row_passes": passes * len(n_valid),
             "kv_positions": kv_positions,
+            "shared_passes": blocks - 1 if self.quantum > 1 else 0,
             "expert_slots": ((passes * self.routed - skipped) * self.experts
                              if self.routed else 0)}
 

@@ -256,7 +256,11 @@ def dropless_init(rng, cfg) -> Dict[str, Any]:
 # XLA's own lowering of ragged_dot streams the chosen experts at 35-60% of
 # the chip's bandwidth, these tiles at 80-85%, to the same bits; row tiles
 # of 16 / 32 / 64 read within 3% of each other, narrower output tiles
-# (512, 768, 1024) within 3% of the whole width.
+# (512, 768, 1024) within 3% of the whole width.  At the seam itself
+# (bench/tools/gmm_tiles.py, PERF.md section 6, PR 48: M = 2048 sorted picks
+# of which 1152 real, what a decode round's shared pass brings at 32 rows)
+# 64-row tiles read 1.16 / 0.61 and 128-row tiles 1.19 / 0.64, and at 1024
+# picks 1.15 / 0.62 and 1.13 / 0.60: within 4% either way, so the seam stays.
 _ROW_TILE, _ROW_TILE_WIDE, _WIDE_FROM = 64, 128, 2048
 _WEIGHT_TILE_BYTES = 6_500_000
 

@@ -94,7 +94,10 @@ REQUEST_STAGES = ("lane_in", "queue", "prefill", "lane_out")
 #: ``ssm_fused_steps`` the Mamba-2 states (the kernel of ops/ssm.py)
 #: and ``ahead_steps`` were dispatched before the program ahead was read;
 #: ``kv_positions`` attended over (module docstring); ``passes`` of the model
-#: (one a step, or a block's denoising passes and the K/V one) and
+#: (one a step, or a block's denoising passes and the K/V one), of which
+#: ``shared_passes`` passes of the device served two (the K/V one of a
+#: diffusion block with the next block's first denoising pass: passes -
+#: shared_passes went over the weights) and
 #: ``row_passes``, summed over the real rows of each (real_tokens /
 #: row_passes = tokens fixed a row-pass: 1, or 4/5 for blocks of four under
 #: four denoising passes); ``experts_read`` by the expert layers (the rounds'
@@ -107,7 +110,8 @@ SERVED_DECODE = dict(
     **{name: name for name in (
         "inplace_steps", "retention_fused_steps", "ssm_fused_steps",
         "ahead_steps",
-        "kv_positions", "passes", "row_passes", "experts_read",
+        "kv_positions", "passes", "shared_passes", "row_passes",
+        "experts_read",
         "expert_slots", "expert_slots_held")})
 
 #: ``served_prefill``'s, folded from every tick (a chunk is read back a tick

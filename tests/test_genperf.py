@@ -849,7 +849,11 @@ def test_a_scripted_run_reads_what_it_read_at_the_parent_commit(
     then reckoned every count itself): /genperf ``served_decode`` and
     ``served_prefill`` and /stats ``genserver``, key for key and value for
     value, the wall-clock fields left out by name
-    (``served_kinds.WALL_CLOCK``)."""
+    (``served_kinds.WALL_CLOCK``).  Counters later PRs added stand in it
+    at what such a run counts: ``ssm_fused_steps`` 0 (PR 47);
+    ``shared_passes`` (PR 48) 0 but for the diffusion run's six rounds of
+    two blocks, 6, whose ``experts_read`` fell from 448 to 436 with them
+    -- a shared pass reads the union of two passes' picks once."""
     import os
 
     path = os.path.join(os.path.dirname(os.path.abspath(__file__)),

@@ -11,6 +11,7 @@ flips only on a tie of two float32 logits, which these seeds do not have;
 the expert layer against its loop is held to 1e-5 of values of order 1
 (summation order over the chosen experts)."""
 
+import functools
 import importlib.util
 import os
 import re
@@ -20,6 +21,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from seldon_core_tpu.models import generate as G
 from seldon_core_tpu.models.generate import (
     TransformerGenerator,
     generate,
@@ -77,11 +79,11 @@ def model(request):
 def _op_paths(lowered) -> str:
     """The scope path of every op of the compiled program, a line each --
     XLA's ``op_name``, which a trace's ``tf_op`` repeats -- with the
-    block's own ``jit`` taken out: a stage lies under the pass that runs
-    it (``.../denoise/jit(_paged_block)/attn/...``)."""
-    return "\n".join(re.findall(
-        r'op_name="([^"]*)"', lowered.compile().as_text())).replace(
-            "jit(_paged_block)/", "")
+    block's own ``jit`` taken out (and the head's, which a round of
+    several blocks binds once for its three places): a stage lies under the
+    pass that runs it (``.../denoise/jit(_paged_block)/attn/...``)."""
+    return re.sub(r"jit\((_paged_block|fix)\)/", "", "\n".join(re.findall(
+        r'op_name="([^"]*)"', lowered.compile().as_text())))
 
 
 def reference_answer(params, prompt, doc, max_new, eos=-1):
@@ -240,6 +242,197 @@ def test_a_round_in_place_equals_the_round_on_the_gather_path(model):
     assert "kv_gather" not in text and "denoise/attn" in text
     assert "kv_gather" in paged_decode_round_jit.lower(
         *args, **kw, inplace=False).as_text(debug_info=True)
+
+
+# -- (a') the pass two blocks share against each pass alone ------------------
+
+
+def plain_round(params, pool, tables, token, n_valid, active, seen_eos, keys,
+                cfg, *, span, eos_token, inplace, trace_passes=True):
+    """A round of denoising passes in the plain formulation: every block's
+    ``denoising_steps`` passes, then the pass that writes its K/V, each
+    pass alone -- the round as it was before the K/V pass of block b and
+    the first denoising pass of block b + 1 became one pass of the layers
+    (``generate._denoising_round``), kept here to hold that one to it."""
+    from seldon_core_tpu.ops.paged_attention import decode_plan
+
+    L, steps = cfg.block_length, cfg.denoising_steps
+    B = n_valid.shape[0]
+    capacity = tables.shape[1] * G._pool_kv(pool)["k"].shape[1]
+    base = n_valid - n_valid % L
+    valid = jnp.broadcast_to(active[:, None], (B, L))
+    head = G._head(params, cfg)
+
+    def through(pool, plan, views, x, start, commit: bool):
+        read = G._experts_counted(None, cfg)
+        with jax.named_scope("embed"):
+            h = params["embed"][x]
+        for i in range(cfg.n_layers):
+            h, layer, aux = G._paged_block(
+                params[f"l{i}"], h, pool[f"l{i}"], tables, start, valid,
+                cfg, plan=plan, interpret=inplace == "interpret",
+                view=views and views[i], write=L if commit else 0,
+                kv_only=commit and i == cfg.n_layers - 1, kind=cfg.kind(i))
+            if commit:
+                pool[f"l{i}"] = layer
+            if cfg.d_expert:
+                read = read + aux
+        return h, pool, read
+
+    def block(carry, b):
+        pool, seen_eos, read = carry
+        start = base + b * L
+        pos = start[:, None] + jnp.arange(L)[None, :]
+        masked = pos >= n_valid[:, None]
+        x = jnp.where(masked, jnp.int32(cfg.mask_id), token)
+        plan = views = None
+        if inplace:
+            plan = decode_plan(start, active, capacity, fresh=0)
+        else:
+            with jax.named_scope("kv_gather"):
+                views = [G._paged_view(pool[f"l{i}"], tables, cfg.hd)
+                         for i in range(cfg.n_layers)]
+
+        def denoise(c, _):
+            x, masked, read = c
+            with jax.named_scope("denoise"):
+                h, _, r = through(pool, plan, views, x, start, commit=False)
+                with jax.named_scope("unembed"):
+                    h = G._rmsnorm(h, params["ln_f"], cfg.norm_eps)
+                    logits = (h @ head).astype(jnp.float32)
+                with jax.named_scope("sample"):
+                    logits = logits.at[..., cfg.mask_id].set(-jnp.inf)
+                    chose = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+                    sure = jnp.exp(jnp.max(logits, axis=-1)
+                                   - jax.nn.logsumexp(logits, axis=-1))
+                    sure = jnp.where(masked, sure, -1.0)
+                    rank = jnp.argsort(jnp.argsort(-sure, axis=-1), axis=-1)
+                    picked = masked & (rank < L // steps)
+            return ((jnp.where(picked, chose, x), masked & ~picked,
+                     read + r), (x, picked, chose))
+
+        (x, _, read), seen = jax.lax.scan(
+            denoise, (x, masked, read), None, length=steps)
+        with jax.named_scope("commit"):
+            _, pool, r = through(pool, plan, views, x, start, commit=True)
+        out = x
+        if eos_token >= 0:
+            hit = (x == eos_token) & (pos >= n_valid[:, None])
+            hits = hit.astype(jnp.int32)
+            after = (jnp.cumsum(hits, axis=1) - hits) > 0
+            out = jnp.where(seen_eos[:, None] | after,
+                            jnp.int32(eos_token), x)
+            seen_eos = seen_eos | jnp.any(hit, axis=1)
+        out = jnp.where(active[:, None], out, 0)
+        return (pool, seen_eos, read + r), (out, seen)
+
+    (pool, seen_eos, read), (toks, seen) = jax.lax.scan(
+        block, (pool, seen_eos, G._experts_counted(None, cfg)),
+        jnp.arange(span // L))
+    toks = toks.transpose(1, 0, 2).reshape(B, span)
+    n_valid = jnp.where(active, base + span, n_valid)
+    out = (toks, pool, jnp.zeros((B,), jnp.int32), n_valid, seen_eos, keys)
+    if cfg.d_expert:
+        out += (G._experts_counted(read, cfg),)
+    if trace_passes:
+        out += (dict(zip(("saw", "picked", "chose"), seen)),)
+    return out
+
+
+def shared_round(*args, **kw):
+    return G._denoising_round(*args, temperature=0.0, **kw)
+
+
+ROUND_LENS = [3, 6, 9, 16, 0]        # remainders 3, 2, 1, 0; an empty slot
+
+
+def round_case(unit, params, own=6):
+    """Five rows prefilled over scattered blocks of 8 (``ROUND_LENS``: a
+    prompt's remainder in three first blocks, a block that starts on a
+    boundary, an inactive row) and the operands a round takes after it."""
+    lens, B, bs = ROUND_LENS, len(ROUND_LENS), 8
+    rows = prompts(lens[:4], seed=7)
+    tables = np.zeros((B, own + 2), np.int32)
+    tables[:4, :own] = 1 + np.random.default_rng(3).permutation(
+        4 * own).reshape(4, own)
+    toks = np.zeros((B, 16), np.int32)
+    held = np.zeros((B, 4), np.int32)
+    for r, row in enumerate(rows):
+        toks[r, :lens[r]] = row
+        held[r, :lens[r] % 4] = row[lens[r] - lens[r] % 4:]
+    _, pool = paged_forward_jit(
+        params, jnp.asarray(toks), init_block_pool(unit.cfg, 1 + 4 * own, bs),
+        jnp.asarray(tables[:, :own]), jnp.zeros((B,), jnp.int32),
+        jnp.asarray(lens, jnp.int32), cfg=unit.cfg)
+    live = tables[:4, :own].reshape(-1)      # not the scratch block
+    return pool, live, (
+        jnp.asarray(tables), jnp.asarray(held), jnp.asarray(lens, jnp.int32),
+        jnp.asarray(np.asarray(lens) > 0), jnp.zeros((B,), bool),
+        jnp.zeros((B,), jnp.uint32))
+
+
+@pytest.mark.parametrize("inplace", [False, "interpret"],
+                         ids=["gather", "kernel"])
+@pytest.mark.parametrize("blocks", [2, 3])
+def test_a_round_that_shares_passes_equals_each_pass_alone(
+        model, blocks, inplace):
+    """A round of two and of three blocks -- the pass that writes block b's
+    K/V and the first denoising pass of block b + 1 ONE pass over 8
+    positions a row -- against ``plain_round``, on the gather path and
+    through the kernel: the tokens, ``n_valid'``, the eos latch and what
+    every pass saw, picked and chose to the id (a prompt's remainder in a
+    first block, an inactive row, an eos that a row generates in the
+    round's first block); the K/V the pool keeps to float32 rounding (a
+    softmax summed in another order: 1e-5 of values of order 1); and never
+    more experts read."""
+    doc, unit, params = model
+    span = 4 * blocks
+    pool, live, operands = round_case(unit, params)
+
+    def run(fn, eos):
+        return jax.jit(functools.partial(
+            fn, cfg=unit.cfg, span=span, eos_token=eos, inplace=inplace,
+            trace_passes=True))(
+                params, jax.tree.map(jnp.copy, pool), *operands)
+
+    free = np.asarray(run(plain_round, -1)[0])
+    eos = int(free[1, 3])           # row 1 (remainder 2): its 2nd new token
+    want, got = run(plain_round, eos), run(shared_round, eos)
+    assert np.asarray(want[4])[1] and (np.asarray(want[0])[1, 4:] == eos).all()
+    for i in (0, 3, 4):             # tokens, n_valid', the eos latch
+        np.testing.assert_array_equal(np.asarray(got[i]), np.asarray(want[i]))
+    for name in ("saw", "picked", "chose"):
+        assert got[7][name].shape == (blocks, doc["denoising_steps"], 5, 4)
+        np.testing.assert_array_equal(
+            np.asarray(got[7][name])[:, :, :4],
+            np.asarray(want[7][name])[:, :, :4], err_msg=name)
+    for li in want[1]:
+        for name in ("k", "v"):
+            np.testing.assert_allclose(
+                np.asarray(got[1][li][name])[live],
+                np.asarray(want[1][li][name])[live], atol=1e-5, rtol=0)
+    # a shared pass reads the union of two passes' picks, once
+    assert 0 < int(got[6]["experts_read"]) <= int(want[6]["experts_read"])
+    assert int(got[6]["experts_read"]) < int(want[6]["experts_read"])
+
+
+@pytest.mark.parametrize("inplace", [False, "interpret"],
+                         ids=["gather", "kernel"])
+def test_a_round_of_one_block_is_the_program_it_was(model, inplace):
+    """``span == block_length`` has nothing to share: the same jaxpr as the
+    plain formulation, equation for equation."""
+    doc, unit, params = model
+    pool, _, operands = round_case(unit, params)
+    text = [str(jax.make_jaxpr(functools.partial(
+        fn, cfg=unit.cfg, span=4, eos_token=7, inplace=inplace,
+        trace_passes=True))(params, pool, *operands))
+        for fn in (plain_round, shared_round)]
+    assert text[0] == text[1]
+    wider = str(jax.make_jaxpr(functools.partial(
+        shared_round, cfg=unit.cfg, span=8, eos_token=7, inplace=inplace,
+        trace_passes=True))(params, pool, *operands))
+    assert wider != text[0]
+
 
 
 def test_static_lane_one_round_or_many_gives_the_reference_answer(model):
@@ -503,6 +696,8 @@ def test_genperf_counts_passes_and_experts_apart_from_tokens(model,
     passes = 2 * 2 * (steps + 1)
     assert served["device_steps"] == 16 and served["real_tokens"] == 14
     assert served["passes"] == served["row_passes"] == passes
+    # a round of two blocks: one pass of the device served two of them
+    assert served["shared_passes"] == 2
     assert served["inplace_steps"] == 0
     # the K/V-writing pass stops at its last layer's K/V: one expert layer
     # fewer a block
@@ -580,6 +775,7 @@ def test_a_dense_generator_reports_no_experts_and_one_pass_a_step(
         srv.stop()
     assert served["passes"] == served["row_passes"] == served["device_steps"]
     assert served["experts_read"] == served["expert_slots"] == 0
+    assert served["shared_passes"] == 0
     SPINE.drain()
     assert GENPERF.document()["served_prefill"] == {
         "calls": 1, "experts_read": 0, "expert_slots": 0, "tokens": 5,
@@ -610,22 +806,31 @@ def test_observe_tick_folds_the_new_counters():
             served["expert_slots"]) == (20, 60, 1400, 17408)
 
 
-def test_the_block_names_its_stages_for_the_trace(model):
+@pytest.mark.parametrize("span, writes", [(4, "commit"), (8, "shared")])
+def test_the_block_names_its_stages_for_the_trace(model, span, writes):
     """``jax.named_scope`` ``qk_norm``, ``router``, ``experts`` inside the
     block and ``denoise`` / ``commit`` around a pass: op metadata the trace
-    readers sort device time by (bench/readers/trace_stages.py)."""
+    readers sort device time by (bench/readers/trace_stages.py).  In a
+    round of several blocks the pass that writes a block's K/V is the one
+    the next block's first denoising pass rides, ``shared``, and the head
+    after it is that first pass's; a round of one block has its
+    ``commit``, which reads no logits."""
     doc, unit, params = model
     pool = init_block_pool(unit.cfg, 8, 8)
     text = _op_paths(paged_decode_round_jit.lower(
         params, pool, jnp.ones((2, 2), jnp.int32), jnp.zeros((2, 4), jnp.int32),
         jnp.asarray([5, 8], jnp.int32), jnp.ones((2,), bool),
-        jnp.zeros((2,), bool), jnp.zeros((2,), jnp.uint32), unit.cfg, span=8,
-        temperature=0.0, top_k=0, top_p=0.0, eos_token=-1))
+        jnp.zeros((2,), bool), jnp.zeros((2,), jnp.uint32), unit.cfg,
+        span=span, temperature=0.0, top_k=0, top_p=0.0, eos_token=-1))
     for scope in ("denoise/qk_norm", "denoise/ffn/router",
-                  "denoise/ffn/experts", "commit/ffn/experts",
-                  "denoise/unembed", "commit/kv_write", "denoise/attn"):
+                  "denoise/ffn/experts", writes + "/ffn/experts",
+                  "denoise/unembed", writes + "/kv_write", "denoise/attn"):
         assert scope in text, scope
     assert "commit/unembed" not in text
+    # the next block's first head lies under the shared pass (inside the
+    # ``cond`` the round's last block skips)
+    assert (re.search(r"shared/(\S*/)?unembed", text) is not None) == (
+        "commit/" not in text) == (span > 4)
 
 
 def test_a_prefill_without_its_head_returns_the_experts_read(model):

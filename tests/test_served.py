@@ -61,24 +61,45 @@ ROUND = {
     # a token a step: 8 passes, each step over ~n + 4 positions
     "attention": dict(passes=8, blocks=8, row_passes=16,
                       kv_positions=8 * (6 + 4) + 8 * (13 + 4),
-                      expert_slots=0),
+                      expert_slots=0, shared_passes=0),
     # blocks of 4 under 4 denoising passes and the K/V one: 2 blocks, 10
     # passes, each reading the cache up to its block's end (rows start at
-    # 4 and 12); the K/V pass of a block skips its last expert layer
+    # 4 and 12); the K/V pass of a block skips its last expert layer, and
+    # the first block's shares a pass of the device with the second's first
     "diffusion": dict(passes=10, blocks=2, row_passes=20,
                       kv_positions=5 * (8 + 12) + 5 * (16 + 20),
-                      expert_slots=(10 * 2 - 2) * EXPERTS),
+                      expert_slots=(10 * 2 - 2) * EXPERTS, shared_passes=1),
     # three of five layers hold experts (two leading dense ones)
     "conv": dict(passes=8, blocks=8, row_passes=16, kv_positions=216,
-                 expert_slots=8 * 3 * EXPERTS),
+                 expert_slots=8 * 3 * EXPERTS, shared_passes=0),
     "retention": dict(passes=8, blocks=8, row_passes=16, kv_positions=216,
-                      expert_slots=0),
+                      expert_slots=0, shared_passes=0),
 }
 
 
 def test_a_rounds_counts_are_the_hand_reckoned_ones(generator):
     kind, unit, d = generator
     assert d.round_counts([6, 13], 8) == ROUND[kind]
+
+
+@pytest.mark.parametrize("span", [4, 8, 12, 16])
+def test_only_a_round_of_several_diffusion_blocks_shares_passes(
+        generator, span):
+    """``shared_passes``: a block's K/V pass with the next block's first
+    denoising pass, one a block but the round's last; a generator that
+    decodes a token a step shares none, whatever its layers -- a
+    state-space one too (tests/test_nemotron_block.py's)."""
+    kind, unit, d = generator
+    counts = d.round_counts([6, 13], span)
+    assert counts["shared_passes"] == (
+        span // 4 - 1 if kind == "diffusion" else 0)
+    assert counts["passes"] == span * (5 if kind == "diffusion" else 4) // 4
+    ssm = served(TransformerGenerator(
+        vocab=96, d_model=32, n_heads=4, n_kv_heads=2, head_dim=16,
+        n_layers=3, layer_kinds="mte", ssm_heads=8, ssm_head_dim=8,
+        ssm_groups=2, ssm_state=16, conv_kernel=4, d_expert=24, n_experts=8,
+        moe_k=3, rope=False, dtype="float32").cfg)
+    assert ssm.round_counts([6, 13], span)["shared_passes"] == 0
 
 
 def test_a_prefill_calls_counts_are_the_hand_reckoned_ones(generator):
