@@ -444,19 +444,25 @@ def _attend_paged(q, view, start, block_length: int = 1, limit=None):
     return _grouped_pv(p, view["v"], q.shape, q.dtype, view.get("v_s"))
 
 
-def _fresh_mask(s, block_length):
-    """Scores ``s`` [..., W, W] of a pass's queries against its own fresh
-    keys under the block-causal mask by block: a query sees its own block
-    of ``block_length`` whole and the blocks before it.  A pass of one
-    block (``block_length`` None: all of it is one) has nothing to mask."""
+def _fresh_mask(s, block_length, valid=None):
+    """Scores ``s`` [B, KV, g, W, W] of a pass's queries against its own
+    fresh keys under the block-causal mask by block: a query sees its own
+    block of ``block_length`` whole and the blocks before it.  A pass of
+    one block (``block_length`` None: all of it is one) has nothing to
+    mask.  In a pass over several, a position that is not the row's
+    (``valid`` [B, W] False: the first block of a row that brings none,
+    ``_denoising_round``) is nobody's key: what stands there lies in the
+    row's cache."""
     W = s.shape[-1]
     if W <= (block_length or W):
         return s
     block = jnp.arange(W) // block_length
-    return jnp.where(block[None, :] > block[:, None], -1e30, s)
+    s = jnp.where(block[None, :] > block[:, None], -1e30, s)
+    return jnp.where(valid[:, None, None, None, :], s, -1e30)
 
 
-def _attend_view_and_fresh(q, view, start, k_new, v_new, block_length=None):
+def _attend_view_and_fresh(q, view, start, k_new, v_new, block_length=None,
+                           valid=None):
     """q [B, H, W, hd] — one diffusion block of a row, at positions
     ``start[b] ..`` — over a dense paged view taken BEFORE the block was
     begun, and the block's own fresh K/V ``k_new`` / ``v_new``
@@ -468,14 +474,18 @@ def _attend_view_and_fresh(q, view, start, k_new, v_new, block_length=None):
 
     A pass over SEVERAL blocks of ``block_length`` (``W`` is wider than
     one) sees the same of the view, and of the fresh keys what
-    ``_fresh_mask`` leaves."""
+    ``_fresh_mask`` leaves under the positions' ``valid`` [B, W]: a row
+    whose leading positions are not valid has them in its cache, which ends
+    where its valid positions begin."""
     s_old = _grouped_qk(q, view["k"], view.get("k_s"))      # [B,KV,g,W,L]
     L = view["k"].shape[2]
+    if q.shape[2] > (block_length or q.shape[2]):
+        start = start + jnp.argmax(valid, axis=1)
     earlier = jnp.arange(L)[None, :] < start[:, None]       # [B, L]
     s_old = jnp.where(earlier[:, None, None, None, :], s_old, -1e30)
     p = jax.nn.softmax(
         jnp.concatenate(
-            [s_old, _fresh_mask(_grouped_qk(q, k_new), block_length)],
+            [s_old, _fresh_mask(_grouped_qk(q, k_new), block_length, valid)],
             axis=-1), axis=-1)
     return (_grouped_pv(p[..., :L], view["v"], q.shape, q.dtype,
                         view.get("v_s"))
@@ -483,7 +493,8 @@ def _attend_view_and_fresh(q, view, start, k_new, v_new, block_length=None):
 
 
 def _attend_pool_and_fresh(q, pool_layer, tables, plan, k_new, v_new,
-                           interpret: bool = False, block_length=None):
+                           interpret: bool = False, block_length=None,
+                           valid=None):
     """``_attend_view_and_fresh`` with the pool read in place: the kernel
     (ops.paged_attention, ``plan`` = ``decode_plan(start, active, capacity,
     fresh=0)``) gives each query's weighted sum over the row's cache before
@@ -493,7 +504,9 @@ def _attend_pool_and_fresh(q, pool_layer, tables, plan, k_new, v_new,
     mass in the pool and takes the fresh part alone.
 
     For a pass over several blocks ``plan`` is a sequence, a plan a block
-    of ``q``: the kernel serves one block's queries a call (a row that is
+    of ``q``, each to where that block's cache ends in each row (the
+    fresh keys go by the positions' ``valid``, ``_fresh_mask``): the kernel
+    serves one block's queries a call (a row that is
     not live in a block's plan is not walked for it), the calls one after
     another as a ``lax.map`` -- ONE place in the program that holds the
     kernel, as a pass of one block has: a second costs a program's trace
@@ -519,7 +532,7 @@ def _attend_pool_and_fresh(q, pool_layer, tables, plan, k_new, v_new,
                 (jnp.moveaxis(blocks, 2, 0),
                  jax.tree.map(lambda *a: jnp.stack(a), *plan))))
     B, KV, g, W, hd = old.shape
-    s = _fresh_mask(_grouped_qk(q, k_new), block_length)  # [B, KV, g, W, W]
+    s = _fresh_mask(_grouped_qk(q, k_new), block_length, valid)  # [B,KV,g,W,W]
     top = jnp.maximum(peak, s.max(axis=-1))
     p = jnp.exp(s - top[..., None])
     mass = mass * jnp.exp(peak - top)
@@ -806,9 +819,10 @@ def _paged_block(lp, x, pool_layer, tables, start, valid, cfg: LMConfig,
     kept), a block's length of a call over two blocks (``W //
     cfg.block_length`` says so) for the pass that writes a finished block's
     K/V with the next block's first denoising pass riding it -- the fresh
-    keys are then masked by block (``_fresh_mask``), the view or the pool
-    serves the cache before ``start`` to both blocks, and ``plan`` is a
-    plan a block."""
+    keys are then masked by block and by ``valid`` (``_fresh_mask``: a row
+    that brings no finished block has that half not valid, and its cache
+    reaches up to the second), the view or the pool serves each row's
+    cache to both blocks, and ``plan`` is a plan a block."""
     from seldon_core_tpu.ops.paged_attention import paged_decode_attention
     from seldon_core_tpu.ops.quant import lm_matmul
 
@@ -876,12 +890,12 @@ def _paged_block(lp, x, pool_layer, tables, start, valid, cfg: LMConfig,
     with jax.named_scope("attn"):
         if gathered:
             a = _attend_view_and_fresh(q, view, start, k, v,
-                                       cfg.block_length)
+                                       cfg.block_length, valid)
         elif plan is None:
             a = _attend_paged(q, view, start, cfg.block_length, limit)
         elif cfg.block_length > 1:
             a = _attend_pool_and_fresh(q, pool_layer, tables, plan, k, v,
-                                       interpret, cfg.block_length)
+                                       interpret, cfg.block_length, valid)
         else:
             a = paged_decode_attention(
                 q, pool_layer["k"], pool_layer["v"], tables, *plan,
@@ -1076,7 +1090,11 @@ def paged_decode_round(params, pool, tables, token, n_valid, active,
     ops/ssm.py / ``ssm_step`` over gathered rows, "interpret" runs the
     kernel in Pallas interpret mode.
 
-    token [B] pending tokens; n_valid [B] per-row cache length; active [B]
+    token [B] pending tokens (diffusion blocks: [B, L], a row's first block
+    as the round finds it -- the prompt's remainder, or the block the round
+    before left to this one, and ``token'`` is this round's); n_valid [B]
+    per-row cache length (diffusion blocks: the positions FIXED; the pool
+    of a row that brings a block lags it by that block); active [B]
     masks empty slots (their writes go to scratch, their samples are
     forced to 0); seen_eos [B] is the device-side after-eos latch (rows
     past their stop keep riding the scan but emit eos — the generate()
@@ -1161,64 +1179,76 @@ def _denoising_round(params, pool, tables, token, n_valid, active, seen_eos,
     block_length`` blocks a row, one after another (a ``lax.scan``), greedy.
 
     The sequence is cut into blocks of ``L = cfg.block_length`` at
-    multiples of ``L``.  A row's first block starts where the last whole
-    block of its ``n_valid`` positions ends: the prompt's remainder stands
-    in it unmasked — ``token`` [B, L] holds those ids at the block's first
-    ``n_valid % L`` places (a ``[B]`` token is taken for every place: the
-    prefill chose none, and what stands at a masked place is not read) —
-    and the mask id elsewhere.  For ``cfg.denoising_steps`` passes
-    (``denoise``) the whole block goes through the model over the cache of
-    the earlier blocks under the block-causal mask; the logits at its
-    masked places give a candidate (the argmax over every id but the mask
-    id) and a confidence (that id's softmax probability), and the ``L /
-    denoising_steps`` masked places of highest confidence are fixed.  Then
-    the block goes through once more, whole (``commit``): that pass's K/V
-    is what the cache keeps, it needs no logits, and its last layer stops
-    at its K/V.
+    multiples of ``L``.  For ``cfg.denoising_steps`` passes the whole block
+    goes through the model over the cache of the earlier blocks under the
+    block-causal mask; the logits at its masked places give a candidate
+    (the argmax over every id but the mask id) and a confidence (that id's
+    softmax probability), and the ``L / denoising_steps`` masked places of
+    highest confidence are fixed (``fix``).  Then the block goes through
+    once more, whole: that pass's K/V is what the cache keeps, it needs no
+    logits, and its last layer stops at its K/V.
 
-    In a round of SEVERAL blocks the pass that writes block b's K/V and the
-    first denoising pass of block b + 1 are ONE pass of the layers over
-    ``[B, 2 L]`` (``shared``): they follow each other over the same rows,
-    the second reads what the first has just written, and every weight --
-    a dropless expert layer takes a token alone -- is position-wise, so
-    the two are one call under the block-causal mask: block b's positions
-    see the cache before b and themselves; block b + 1's, masked, see the
-    cache before b, block b's fresh K/V and themselves.  The weights, the
-    experts the two passes chose among them, are read once where they were
-    read twice: ``blocks * (steps + 1)`` passes of a block in ``blocks *
-    steps + 1`` passes of the device.  The pool keeps block b's K/V alone.
-    In the last layer block b stops at its K/V and block b + 1 goes
-    through whole, as two calls the other passes have already traced: the
-    K/V pass's last layer on block b, then a denoising pass's layer on
-    block b + 1 at its own start, which finds block b's K/V in the cache
-    (the layer's ``wqkv`` is read twice, nothing else).  The round's last
-    block has no next: its pass is the same calls with the second half
-    invalid (no expert picked, no cache walked for it, no head).  So a
-    block after the round's first comes with its first pass made and runs
-    the others: one loop from a bound the program reads, one body.
+    **That pass is never a pass of the device of its own.**  The pass that
+    writes block b's K/V and the first denoising pass of block b + 1 follow
+    each other over the same rows, the second reads what the first has just
+    written, and every weight -- a dropless expert layer takes a token
+    alone -- is position-wise, so the two are ONE pass of the layers over
+    ``[B, 2 L]`` (``shared``) under the block-causal mask: block b's
+    positions see the cache before b and themselves; block b + 1's, masked,
+    see the cache before b, block b's fresh K/V and themselves.  The
+    weights, the experts the two passes chose among them, are read once.
+    The pool keeps block b's K/V alone.  In the last layer block b stops at
+    its K/V and block b + 1 goes through whole, as two calls: the K/V
+    pass's last layer on block b, then a denoising pass's layer on block
+    b + 1 at its own start, which finds block b's K/V in the cache (the
+    layer's ``wqkv`` is read twice, nothing else).  A round boundary
+    changes nothing in this: **the round's LAST block leaves its K/V pass
+    to the next round's first pass**, so every block of every round comes
+    with its first pass made and runs ``denoising_steps - 1`` of its own --
+    ``blocks * denoising_steps`` passes of the device a round -- and a
+    row's last block, which nobody will read, gets no K/V pass at all.
 
-    A denoising pass's K/V are NOT stored: every pass, the commit too,
-    attends over the cache before the block's start and its own fresh K/V
-    in ONE softmax, and only the commit writes the pool.  ``inplace`` (as
+    **What a round takes and hands on.**  ``token`` [B, L] is a row's first
+    block as the round finds it.  A row in its first round since its
+    prefill brings the prompt's remainder: those ids (>= 0) at the block's
+    first ``n_valid % L`` places (a ``[B]`` token is taken for every place:
+    the prefill chose none, and what stands at a masked place is not
+    read); the first half of its shared pass is not valid (no expert
+    picked, no cache walked, nothing written), and its second half's cache
+    reaches up to the block.  A row that rode the round before BRINGS that
+    round's last block, fixed and not yet in the pool, as the complement
+    of its ids (``~ids``, < 0: what ``token'`` holds): ``n_valid`` counts
+    its positions (so ``n_valid % L == 0``: no row both brings a block and
+    holds a remainder) while the pool holds K/V up to ``n_valid - L``
+    only.  **The pool of a row that brings a block lags ``n_valid`` by
+    that block**; the caller hands ``token'`` on untouched (the static
+    lane, ``_denoising_lane``) or by slot (``GenServer``, models/served.py
+    ``Served.held``).
+
+    A denoising pass's K/V are NOT stored: every pass attends over the
+    cache before the block's start and its own fresh K/V in ONE softmax,
+    and only a shared pass's first half writes the pool.  ``inplace`` (as
     for ``paged_decode_round``: None decides by ``decode_inplace(pool,
     width=L)``) says how the cache is read: in place, the block's ``L``
     queries a row folded into the kernel's query group, the fresh part
     joined outside it (``_attend_pool_and_fresh``), nothing gathered; or
     on the gather path, each layer's blocks gathered into a dense view once
-    a block, before its first pass of its own -- after the shared pass's
-    write -- (``_attend_view_and_fresh``): once a block and not once a
-    pass.  ``_paged_block`` stays the one block both kinds of round run.
+    a block, after the shared pass's write (``_attend_view_and_fresh``):
+    it serves the block's own passes and the next shared pass.
+    ``_paged_block`` stays the one block both kinds of round run.
 
     Returns what ``paged_decode_round`` returns: the finished blocks
     [B, span] (a row's NEW tokens are those from its ``n_valid`` on; after
     a generated ``eos_token`` a row emits eos, the latch ``seen_eos``
-    carried on), the pool, a ``[B]`` token nobody reads, ``n_valid'`` (the
-    round's end), ``seen_eos'``, ``keys``; then ``{"experts_read": int32}``
-    where the configuration has experts (a shared pass counts the experts
-    it read, once); then, under ``trace_passes`` (the benchmark's driver,
-    archs/<arch>/drive.py), what every denoising pass ``saw``, ``picked``
-    and ``chose``, each [blocks, steps, B, L] (a block's step 0 after the
-    round's first block is the shared pass's second half)."""
+    carried on), the pool, ``token'`` [B, L] (the round's last block as
+    the next round takes it, ``~ids``: the ids the passes fixed, whatever
+    the latch makes of them; 0 for an empty slot), ``n_valid'`` (the
+    round's end), ``seen_eos'``, ``keys``; then ``{"experts_read":
+    int32}`` where the configuration has experts (a shared pass counts the
+    experts it read, once); then, under ``trace_passes`` (the benchmark's
+    driver, archs/<arch>/drive.py), what every denoising pass ``saw``,
+    ``picked`` and ``chose``, each [blocks, steps, B, L] (a block's step 0
+    is the shared pass's second half)."""
     from seldon_core_tpu.ops.paged_attention import decode_plan
 
     L, steps = cfg.block_length, cfg.denoising_steps
@@ -1233,9 +1263,11 @@ def _denoising_round(params, pool, tables, token, n_valid, active, seen_eos,
     capacity = tables.shape[1] * _pool_kv(pool)["k"].shape[1]
     if token.ndim == 1:
         token = jnp.broadcast_to(token[:, None], (B, L))
+    brings = active & (token[:, 0] < 0)
+    token = jnp.where(token < 0, ~token, token)
     base = n_valid - n_valid % L
     valid = jnp.broadcast_to(active[:, None], (B, L))
-    head = _head(params, cfg)
+    head, last = _head(params, cfg), cfg.n_layers - 1
 
     def layer(i, h, entry, start, valid, plan, view, write, kv_only=False):
         """Layer ``i`` over ``h`` (``_paged_block``; one spelling of the
@@ -1248,34 +1280,22 @@ def _denoising_round(params, pool, tables, token, n_valid, active, seen_eos,
     def through(pool, plan, views, x, start, valid, write: int,
                 upto: int = cfg.n_layers):
         """The ids ``x`` [B, W] at ``start`` through the first ``upto``
-        layers (all of them: a whole pass), over the cache before ``start``
-        — by the kernel's ``plan``, or ``views``, the layers' caches
-        gathered at the block's start; the pool keeps the K/V of the first
-        ``write`` positions, and a whole pass that keeps any stops at its
-        last layer's."""
+        layers (all of them: a whole pass), each row over its cache -- by
+        the kernel's ``plan``, or ``views``, the layers' caches gathered at
+        the block's start; the pool keeps the K/V of the first ``write``
+        positions."""
         read = _experts_counted(None, cfg)
         with jax.named_scope("embed"):
             h = params["embed"][x]
         for i in range(upto):
             h, entry, aux = layer(
                 i, h, pool[f"l{i}"], start, valid, plan, views and views[i],
-                write, bool(write) and i == cfg.n_layers - 1)
+                write)
             if write:
                 pool[f"l{i}"] = entry
             if cfg.d_expert:
                 read = read + aux
         return h, pool, read
-
-    def begin(b):
-        """Block ``b`` of the round as its first pass finds it: its start
-        and positions, which places are masked, and its ids (the prompt's
-        remainder, which only a row's first block holds, then the mask
-        id)."""
-        start = base + b * L
-        pos = start[:, None] + jnp.arange(L)[None, :]
-        masked = pos >= n_valid[:, None]
-        return (start, pos, masked,
-                jnp.where(masked, jnp.int32(cfg.mask_id), token))
 
     # how a block's passes read the cache before its start, made once a
     # block: the kernel's scalar operands, or each layer's view
@@ -1289,6 +1309,9 @@ def _denoising_round(params, pool, tables, token, n_valid, active, seen_eos,
             return [_paged_view(pool[f"l{i}"], tables, cfg.hd)
                     for i in range(upto)]
 
+    # (two places run it, the shared pass and a block's own passes: one
+    # trace and one lowering for them)
+    @jax.jit
     def fix(h, x, masked):
         """A pass's hidden states ``h`` of a block ``x``: the block with
         its surest masked places fixed, what stays masked, and what the
@@ -1308,21 +1331,6 @@ def _denoising_round(params, pool, tables, token, n_valid, active, seen_eos,
         return (jnp.where(picked, chose, x), masked & ~picked,
                 (x, picked, chose))
 
-    if blocks > 1:
-        # two places run it (a block's own passes, the shared pass): one
-        # trace and one lowering for them
-        fix = jax.jit(fix)
-
-    def denoising(pool, plan, views, start):
-        """One denoising pass of the block at ``start``, a scan's body."""
-        def denoise(c, _):
-            x, masked, read = c
-            with jax.named_scope("denoise"):
-                h, _, r = through(pool, plan, views, x, start, valid, 0)
-                x, masked, saw = fix(h, x, masked)
-            return (x, masked, read + r), saw
-        return denoise
-
     def latch(x, pos, seen_eos):
         """The ids ``x`` at ``pos`` as the round emits them: eos after a
         generated eos, 0 for an empty slot; and the latch."""
@@ -1338,87 +1346,68 @@ def _denoising_round(params, pool, tables, token, n_valid, active, seen_eos,
             seen_eos = seen_eos | jnp.any(hit, axis=1)
         return jnp.where(active[:, None], out, 0), seen_eos
 
-    def alone(carry, b):
-        """A block whose passes are all its own: the round of one."""
-        pool, seen_eos, read = carry
-        start, pos, masked, x = begin(b)
-        plan, views = planned(start), gathered(pool)
-        (x, _, read), seen = jax.lax.scan(
-            denoising(pool, plan, views, start), (x, masked, read), None,
-            length=steps)
-        with jax.named_scope("commit"):
-            _, pool, r = through(pool, plan, views, x, start, valid, L)
-        out, seen_eos = latch(x, pos, seen_eos)
-        return (pool, seen_eos, read + r), (out, seen)
-
-    def sharing(carry, b):
-        """Block ``b`` of a round of several: the denoising passes of its
-        own -- all of the round's first block's, and of a later block,
-        which comes with its first pass made, the others -- then the pass
-        that writes its K/V with the first pass of block ``b + 1`` riding
-        it."""
-        pool, read, x, masked, saw, views = carry
+    def block(carry, b):
+        """Block ``b`` of the round: the pass that writes the K/V of the
+        block ``before`` it -- the round's own or, ahead of the round's
+        first, the one a row ``came`` with -- with ``b``'s first denoising
+        pass riding it, then ``b``'s other passes."""
+        pool, read, before, came, views = carry
         start = base + b * L
-        plan = planned(start)
-        denoise = denoising(pool, plan, views, start)
-
-        def own(i, c):
-            *c, seen = c
-            c, saw = denoise(tuple(c), None)
-            return *c, jax.tree.map(lambda all_, one: all_.at[i].set(one),
-                                    seen, saw)
-
-        # (a loop from a bound the program reads: one body for both counts)
-        x, _, read, seen = jax.lax.fori_loop(
-            jnp.minimum(b, 1), steps, own,
-            (x, masked, read, jax.tree.map(
-                lambda one: jnp.zeros((steps,) + one.shape, one.dtype
-                                      ).at[0].set(one), saw)))
-        more = b + 1 < blocks
-        live, last = active & more, cfg.n_layers - 1
-        _, _, masked, nxt = begin(b + 1)
+        # the block as its first pass finds it: the prompt's remainder,
+        # which only a row's first block holds, then the mask id
+        masked = start[:, None] + jnp.arange(L)[None, :] >= n_valid[:, None]
+        x = jnp.where(masked, jnp.int32(cfg.mask_id), token)
+        kept, writing = valid & came[:, None], planned(start - L, came)
         with jax.named_scope("shared"):
-            # both blocks through every layer but the last, as one pass
+            # both blocks through every layer but the last, as one pass; a
+            # row that came with no block has the first half not valid and
+            # what lies before ``start`` in its cache
             h, pool, r = through(
-                pool, plan and (plan, planned(start, live)), views,
-                jnp.concatenate([x, nxt], axis=1), start,
-                jnp.concatenate([valid, valid & more], axis=1), L, upto=last)
-            # the last layer: block b stops at its K/V, and block b + 1
-            # goes through it as a denoising pass's block does, over a
+                pool, (writing, planned(jnp.where(came, start - L, start)))
+                if inplace else None, views,
+                jnp.concatenate([before, x], axis=1), start - L,
+                jnp.concatenate([kept, valid], axis=1), L, upto=last)
+            # the last layer: the block before stops at its K/V, and block
+            # b goes through it as a denoising pass's block does, over a
             # cache that now holds them
-            _, entry, _ = layer(last, h[:, :L], pool[f"l{last}"], start,
-                                valid, plan, views and views[last], L, True)
+            _, entry, _ = layer(
+                last, h[:, :L], pool[f"l{last}"], start - L, kept, writing,
+                views and views[last], L, True)
             pool[f"l{last}"], view = entry, None
             if not inplace:
                 with jax.named_scope("kv_gather"):
                     view = _paged_view(entry, tables, cfg.hd)
-            h, _, aux = layer(last, h[:, L:], entry, start + L, valid & more,
-                              planned(start + L, live), view, 0)
+            plan = planned(start)
+            h, _, aux = layer(last, h[:, L:], entry, start, valid, plan,
+                              view, 0)
             if cfg.d_expert:
                 r = r + aux
-            # the round's last block hands nothing on: no head, no views
-            # (the last layer's is the one just gathered)
-            nxt, masked, saw, views = jax.lax.cond(
-                more, lambda: fix(h, nxt, masked) + (
-                    view and gathered(pool, last) + [view],),
-                lambda: (nxt, masked, saw, views))
-        return (pool, read + r, nxt, masked, saw, views), (x, seen)
+            x, masked, saw = fix(h, x, masked)
+            # (the last layer's view is the one just gathered)
+            views = view and gathered(pool, last) + [view]
 
-    read = _experts_counted(None, cfg)
-    if blocks == 1:
-        (pool, seen_eos, read), (toks, seen) = jax.lax.scan(
-            alone, (pool, seen_eos, read), jnp.arange(blocks))
-        toks = toks.transpose(1, 0, 2).reshape(B, span)
-    else:
-        _, _, masked, x = begin(0)
-        (pool, read, *_), (toks, seen) = jax.lax.scan(
-            sharing, (pool, read, x, masked, (x, masked, x), gathered(pool)),
-            jnp.arange(blocks))
-        toks, seen_eos = latch(
-            toks.transpose(1, 0, 2).reshape(B, span),
-            base[:, None] + jnp.arange(span)[None, :], seen_eos)
+        def denoise(c, _):
+            x, masked, read = c
+            with jax.named_scope("denoise"):
+                h, _, r = through(pool, plan, views, x, start, valid, 0)
+                x, masked, saw = fix(h, x, masked)
+            return (x, masked, read + r), saw
+
+        (x, _, read), seen = jax.lax.scan(
+            denoise, (x, masked, read + r), None, length=steps - 1)
+        seen = jax.tree.map(lambda one, rest: jnp.concatenate(
+            [one[None], rest]), saw, seen)
+        return (pool, read, x, active, views), (x, seen)
+
+    (pool, read, x, _, _), (toks, seen) = jax.lax.scan(
+        block, (pool, _experts_counted(None, cfg), token, brings,
+                gathered(pool)), jnp.arange(blocks))
+    toks, seen_eos = latch(
+        toks.transpose(1, 0, 2).reshape(B, span),
+        base[:, None] + jnp.arange(span)[None, :], seen_eos)
     n_valid = jnp.where(active, base + span, n_valid)
-    out = (toks, pool, jnp.zeros((B,), jnp.int32), n_valid, seen_eos, keys)
+    out = (toks, pool, jnp.where(active[:, None], ~x, 0), n_valid, seen_eos,
+           keys)
     if cfg.d_expert:
         out += (_experts_counted(read, cfg),)
     if trace_passes:
@@ -1610,7 +1599,9 @@ def _denoising_lane(params, prompt, cfg: LMConfig, max_new_tokens: int,
     ONE round for everything (``chunk`` None: ``generate``) or one a client
     chunk, ``chunk`` rounded up to whole blocks (``stream_chunks``).  The
     first round takes the prompt's remainder into its first block and
-    yields so many tokens fewer.  Yields [B, n] token arrays whose
+    yields so many tokens fewer; every later one takes the block the round
+    before it handed on (``_denoising_round``: the pool lags by it).
+    Yields [B, n] token arrays whose
     concatenation is [B, max_new_tokens]; both drive the round the
     scheduler drives, so their answers are its answers."""
     B, S = prompt.shape
@@ -1632,7 +1623,8 @@ def _denoising_lane(params, prompt, cfg: LMConfig, max_new_tokens: int,
     done, skip = 0, rem
     while done < max_new_tokens:
         span = min(per, -(-(skip + max_new_tokens - done) // L) * L)
-        toks, pool, _, n_valid, seen, keys, *_ = paged_decode_round_jit(
+        # (``token'``: the round's last block, which the next one writes)
+        toks, pool, token, n_valid, seen, keys, *_ = paged_decode_round_jit(
             params, pool, tables, token, n_valid, jnp.ones((B,), bool),
             seen, keys, cfg, span=span, temperature=temperature, top_k=0,
             top_p=0.0, eos_token=eos_token, inplace=inplace)

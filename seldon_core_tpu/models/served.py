@@ -20,7 +20,11 @@ import numpy as np
 
 from seldon_core_tpu.models.transformer import LMConfig
 
-__all__ = ["Kernels", "Served", "served"]
+__all__ = ["BRINGS", "Kernels", "Served", "served"]
+
+#: what ``Served.held`` puts in every place of a row that brings a block
+#: (no id is negative)
+BRINGS = -1
 
 
 def _tree_bytes(tree) -> int:
@@ -82,17 +86,27 @@ class Served:
     #: what a round, a KV block and a prefill chunk are whole multiples of:
     #: 1, or the block a generator by diffusion denoises at once
     quantum: int
-    #: passes of the model a round makes a block of ``quantum`` positions:
-    #: 1 a token, or the denoising passes and the one that writes the K/V
-    #: -- a block's passes, of which the last shares a pass of the device
-    #: with the first of the round's next block (``round_counts``
+    #: passes of the model a block of ``quantum`` positions takes: 1 a
+    #: token, or the denoising passes and the one that writes the K/V -- of
+    #: which the last shares a pass of the device with the first of the
+    #: row's NEXT block, in this round or the next (``round_counts``
     #: ``shared_passes``)
     block_passes: int
     #: a prompt's last chunk picks the row's first token, which then rides
     #: from round to round PENDING (sampled, not yet in the cache), in the
     #: device's carry and on the host.  False for diffusion blocks: a
-    #: prefill chooses no token (no head, no ``first`` program) and nothing
-    #: of a round depends on the one before it but the eos latch
+    #: prefill chooses no token (no head, no ``first`` program), and what
+    #: rides from round to round in the carry's ``tok`` is a BLOCK, ``[S,
+    #: quantum]``: a round leaves its last block fixed and NOT YET IN THE
+    #: POOL, and the row's next round writes its K/V in its first pass
+    #: (``generate._denoising_round``).  So **the pool of a row that brings
+    #: a block lags the host's ``n_valid`` -- the positions fixed -- by that
+    #: block**, from the row's first round to its last.  Nobody reads a
+    #: lagging pool: a preempted row's tokens become prompt and its prefill
+    #: writes every block; a finished row's last block is never written;
+    #: and the lanes that would read K/V a round has just made (``roles``:
+    #: a handoff streams blocks; ``prefix``; ``draft``; ``sampled``) are
+    #: refused for such a generator (``served``)
     picks_first: bool
     #: the word for what the pool holds, in errors: "KV", or "state" where
     #: no layer holds K/V and the pool is one state entry a block
@@ -148,30 +162,49 @@ class Served:
         block again, and the round emits so many tokens fewer)."""
         return n_valid - n_valid % self.quantum
 
-    def held(self, rows: int) -> Optional[np.ndarray]:
-        """What the host holds for a round of ``rows`` in the pending
-        token's place: None where that rides the carry, else the ``[rows,
-        quantum]`` ids a row's first round finds in its first block, the
-        prompt's remainder."""
+    def held(self, rows: int, brings: Sequence[bool] = ()
+             ) -> Optional[np.ndarray]:
+        """What the host uploads for a round of ``rows`` beside the carry:
+        None where the pending token rides the carry and nothing else is to
+        say, else ``[rows, quantum]`` int32 -- for a row in its first round
+        since it was admitted the ids that round finds in its first block
+        (the caller fills the prompt's remainder in), and ``BRINGS`` in
+        every place of a row that ``brings`` the last block of the round
+        before: the host knows which by arithmetic (the row rode a round
+        since ``_admit``), and the carry's ``take`` puts the block and the
+        eos latch the device holds at its slot in that row's place -- a row
+        that brings nothing starts with neither."""
         if self.picks_first:
             return None
-        return np.zeros((rows, self.quantum), np.int32)
+        held = np.zeros((rows, self.quantum), np.int32)
+        held[:len(brings)][np.asarray(brings, bool)] = BRINGS
+        return held
 
     # -- what a call is given ------------------------------------------------
 
-    def round_counts(self, n_valid: Sequence[int], span: int
-                     ) -> Dict[str, int]:
+    def round_counts(self, n_valid: Sequence[int], span: int,
+                     brings: Sequence[bool] = ()) -> Dict[str, int]:
         """What a decode round of ``span`` positions over live rows holding
         ``n_valid`` is given, by its span's and the tick record's names:
         ``passes`` of the model in ``blocks`` -- a block's passes, whoever
-        shares them -- ``row_passes`` (x the real rows), the
-        ``kv_positions`` they attend over, ``expert_slots`` (experts held x
-        expert layers x passes: what the round's own ``experts_read`` is a
-        share of) and ``shared_passes``, the passes of the device that
-        served two of them: the one that writes a diffusion block's K/V
-        with the next block's first denoising pass riding it, one a block
-        but the round's last (``generate._denoising_round``); 0 for a token
-        a step."""
+        shares them and whichever round runs them -- the ``kv_positions``
+        they attend over and ``expert_slots`` (experts held x expert layers
+        x passes: what the round's own ``experts_read`` is a share of), all
+        three by the round's OWN blocks, which is what the benchmark takes
+        a round for (bench/archs/<arch>/needs.py; tests/bench holds
+        ``passes`` to ``block_passes`` a block); and, by what the round
+        RUNS, ``row_passes`` (passes of the model a real row, summed) and
+        ``shared_passes`` (the passes of the device that served two of
+        them); 0 shared for a token a step.
+
+        A round of diffusion blocks runs every block's denoising passes
+        and, riding the first of them, the pass that writes the K/V of the
+        block before: the round's own but for its first block, where it is
+        the block a row ``brings`` (``held``).  So a row runs ``passes - 1``
+        of them and one more if it brings a block -- its last block's K/V
+        pass is the next round's, and the last of all nobody's
+        (``generate._denoising_round``) -- and the round shares ``blocks -
+        1`` passes of the device, one more if any row brings a block."""
         blocks = span // self.quantum
         passes = blocks * self.block_passes
         if self.quantum > 1:
@@ -181,17 +214,19 @@ class Served:
                 self.block_passes
                 * (self.round_base(n) + (b + 1) * self.quantum)
                 for n in n_valid for b in range(blocks))
+            brought = sum(map(bool, brings))
+            row_passes = (passes - 1) * len(n_valid) + brought
+            shared = blocks - 1 + bool(brought)
         else:
             # each of the span steps attends over ~n_valid + step positions
             kv_positions = sum(span * (n + span // 2) for n in n_valid)
+            row_passes, shared = passes * len(n_valid), 0
         # the pass that writes a block's K/V stops at its last layer's K/V:
         # one expert layer fewer
         skipped = blocks if self.quantum > 1 else 0
         return {
-            "passes": passes, "blocks": blocks,
-            "row_passes": passes * len(n_valid),
-            "kv_positions": kv_positions,
-            "shared_passes": blocks - 1 if self.quantum > 1 else 0,
+            "passes": passes, "blocks": blocks, "row_passes": row_passes,
+            "kv_positions": kv_positions, "shared_passes": shared,
             "expert_slots": ((passes * self.routed - skipped) * self.experts
                              if self.routed else 0)}
 

@@ -26,7 +26,8 @@ pool and the tables:
     contract), and hands tokens to the per-request streams.
   * **One round ahead** — the iteration dispatches round k+1 before it
     reads round k back: which rows ride a round is arithmetic, and what
-    a round hands the next (pending token, after-eos latch, sampling
+    a round hands the next (pending token -- or, diffusion blocks, the
+    block whose K/V the next round writes -- after-eos latch, sampling
     key) stays on the device in a per-slot carry, so the device goes
     from round to round without waiting for the host (``GenServer
     ._tick``, ``_depth``, ``_carry_ops``).
@@ -279,12 +280,21 @@ def _carry_ops():
 
     ``carry``: ``{"tok": int32 [S], "seen": bool [S]}`` and, for sampled
     decoding, ``"keys": uint32 [S, K]`` key data; S = slots + 1, the last
-    entry scratch for padded rows.  Every shape but the batch's row count
-    is fixed, so each program compiles once a row count
-    (``GenServer._init_device`` loads them all).
+    entry scratch for padded rows.  For a generator whose prefill picks no
+    token (diffusion blocks, models/served.py ``picks_first``) ``tok`` is
+    ``[S, quantum]``: the block a round left fixed and not yet in the pool,
+    as the program hands it on (``generate._denoising_round``).  Every
+    shape but the batch's row count is fixed, so each program compiles
+    once a row count (``GenServer._init_device`` loads them all).
 
     * ``take(carry, idx)`` -> a round's ``token``, ``seen_eos`` and
-      ``keys`` inputs: a gather by the batch's slot indices.
+      ``keys`` inputs: a gather by the batch's slot indices.  ``take(carry,
+      idx, held)`` is the same for such a generator, ``held`` being what
+      the host uploads (``Served.held``): a row it marks as bringing a
+      block takes the block and the latch at its slot, every other row
+      its ``held`` ids and an open latch -- whatever the slot's last
+      holder left there -- so a row carries its block by slot, between
+      padded row counts too, and the host reads nothing back to say which.
     * ``put(carry, idx, tok, seen, keys)`` -> ``(carry', key data)``: the
       round's ``token'`` / ``seen_eos'`` / ``keys'`` scattered back (keys
       typed as the program returns them, or raw key data from the host).
@@ -300,9 +310,13 @@ def _carry_ops():
 
     from seldon_core_tpu.models.generate import sample_token
 
-    def take(carry, idx):
+    def take(carry, idx, held=None):
         keys = carry.get("keys")
-        return (carry["tok"][idx], carry["seen"][idx],
+        tok, seen = carry["tok"][idx], carry["seen"][idx]
+        if held is not None:
+            brings = held[:, 0] < 0     # ``Served.held``'s mark (BRINGS)
+            tok, seen = jnp.where(brings[:, None], tok, held), seen & brings
+        return (tok, seen,
                 None if keys is None
                 else jax.random.wrap_key_data(keys[idx]))
 
@@ -474,7 +488,7 @@ class _Sequence:
         "sid", "request", "row", "prompt", "prompt0", "max_new", "state",
         "n_valid", "blocks", "draft_blocks", "pending", "prefill_pos",
         "emitted", "done", "key_data", "admit_order", "retire_reason",
-        "t_start", "events", "slot", "inflight",
+        "t_start", "events", "slot", "inflight", "rode",
     )
     WAITING, PREFILL, RUNNING, DONE = range(4)
 
@@ -496,6 +510,9 @@ class _Sequence:
         self.pending: Optional[int] = None
         self.slot = -1                  # index into the device-side carry
         self.inflight = 0               # tokens dispatched, not yet read
+        #: a decode round was dispatched for it since ``_admit``: where a
+        #: round hands a block on (``Served.held``), its slot holds one
+        self.rode = False
         self.prefill_pos = 0            # prompt tokens consumed
         self.emitted: List[int] = []
         self.done = False
@@ -1197,7 +1214,9 @@ class GenServer:
         import jax
 
         width = self.slots + 1          # the last entry is scratch
-        carry = {"tok": np.zeros((width,), np.int32),
+        # a pending token a slot, or the block a round hands on
+        tok = () if self._served.picks_first else (self._served.quantum,)
+        carry = {"tok": np.zeros((width,) + tok, np.int32),
                  "seen": np.zeros((width,), bool)}
         if self.temperature > 0.0:
             carry["keys"] = np.zeros((width, self._key_width), np.uint32)
@@ -1227,7 +1246,8 @@ class GenServer:
         def load(rows: int) -> None:
             carry = self._new_carry()
             idx = np.full((rows,), self.slots, np.int32)   # scratch only
-            tok, seen, keys = take(carry, idx)
+            held = self._served.held(rows)
+            tok, seen, keys = take(carry, idx, held)
             carry, key_data = put(carry, idx, tok, seen, keys)
             if key_data is not None:
                 # raw key data from the host: an imported row's (decode role)
@@ -1408,11 +1428,10 @@ class GenServer:
                 operands = (S((B, C)), S((B, nblk)), S((B,)), S((B,)))
             else:
                 # what `take` hands a round, by its own account
-                token, seen, keys = jax.eval_shape(
-                    take, self._carry, S((B,)))
                 held = self._served.held(B)
-                if held is not None:
-                    token = _abstract(held)     # the host's (_decode_round)
+                token, seen, keys = jax.eval_shape(
+                    take, self._carry, S((B,)),
+                    None if held is None else _abstract(held))
                 operands = (S(shape), token, S((B,)), S((B,), bool), seen,
                             _abstract(self._zero_keys[B])
                             if keys is None else keys)
@@ -1911,8 +1930,10 @@ class GenServer:
             # folding into the already-folded prompt would duplicate
             # context on a second preemption.  The last token is pending,
             # not yet in the cache -- but where none ever is (``picks_first``):
-            # everything emitted is in the cache, and the readmitted row's
-            # next round starts where those tokens end
+            # everything emitted becomes prompt (the last block's K/V, which
+            # the row's next round would have written, the prefill writes:
+            # ``_admit`` clears ``rode``), and the readmitted row's next
+            # round starts where those tokens end
             cached = seq.emitted
             if self._served.picks_first:
                 cached, seq.pending = seq.emitted[:-1], seq.emitted[-1]
@@ -2044,7 +2065,7 @@ class GenServer:
                     jnp.int32(seq.blocks[0]))
             seq.n_valid = self._prefix_len
             seq.state = _Sequence.PREFILL
-            seq.slot = self._slot_free.popleft()
+            seq.slot, seq.rode = self._slot_free.popleft(), False
             seq.prefill_pos = 0
             seq.t_start = time.time()
             self._seq_event(seq, "admit", blocks=len(seq.blocks),
@@ -2331,11 +2352,13 @@ class GenServer:
         sequence as a single device program.
 
         The host uploads what it knows by arithmetic (block tables,
-        ``n_valid``, which rows are live); the values that depend on the
-        round before -- pending token, after-eos latch, sampling key --
-        are gathered from the carry on the device and scattered back
-        (``_carry_ops``), so this round can be queued while the one
-        before it is still running and unread.  Each row's share of the
+        ``n_valid``, which rows are live; where a round hands a block on,
+        which rows rode the round before and so bring one: models/served.py
+        ``held``); the values that depend on the round before -- pending
+        token or block, after-eos latch, sampling key -- are gathered from
+        the carry on the device and scattered back (``_carry_ops``), so
+        this round can be queued while the one before it is still running
+        and unread.  Each row's share of the
         round's tokens (``take``) is fixed here.  A row that sampled eos
         in a round not read yet rides this one as padding: the latch is
         set on the device, ``_emit_tokens`` drops what follows a stop,
@@ -2374,9 +2397,11 @@ class GenServer:
             n_valid = np.zeros((B,), np.int32)
             active = np.zeros((B,), bool)
             idx = np.full((B,), self.slots, np.int32)   # pads: scratch
-            # what the host holds in the pending token's place, where none
-            # rides the carry (models/served.py ``held``)
-            held = self._served.held(B)
+            batch_rode = [s.rode for s in batch]
+            # what the host says beside the carry where no pending token
+            # rides it: a row's first block, or that it brings one
+            # (models/served.py ``held``)
+            held = self._served.held(B, batch_rode)
             rows, skip = [], []
             for i, s in enumerate(batch):
                 tables[i] = self._table(s, nblk)
@@ -2401,7 +2426,7 @@ class GenServer:
             # what the round is given, counted once: the tick record's
             # (/genperf ``served_decode``) and the span's are these numbers
             work = self._served.round_counts(
-                [s.n_valid for s in batch], self.span)
+                [s.n_valid for s in batch], self.span, batch_rode)
             self._counts.update(
                 work, **self._kernels.round_counts(self.span),
                 rows=B, real_rows=len(batch), steps=self.span,
@@ -2424,10 +2449,10 @@ class GenServer:
                     + ("device" if fenced else "build"), **work):
             t_dispatch = time.perf_counter()
             take, put, _ = _carry_ops()
-            token, seen, keys = take(self._carry, idx)
+            token, seen, keys = take(self._carry, idx, held)
             fn, args, kw = self._program(
-                "decode", tables, token if held is None else held, n_valid,
-                active, seen, self._zero_keys[B] if keys is None else keys)
+                "decode", tables, token, n_valid, active, seen,
+                self._zero_keys[B] if keys is None else keys)
             toks, self._pool, token, _nv, seen, keys, *extra = fn(
                 *args, **kw)
             self._carry, key_data = put(
@@ -2463,6 +2488,7 @@ class GenServer:
         for (s, share), off in zip(rows, skip):
             s.inflight += share
             s.n_valid += self.span - off
+            s.rode = True
         return fl
 
     def _decode_collect(self, fl: _Flight) -> None:

@@ -853,7 +853,11 @@ def test_a_scripted_run_reads_what_it_read_at_the_parent_commit(
     at what such a run counts: ``ssm_fused_steps`` 0 (PR 47);
     ``shared_passes`` (PR 48) 0 but for the diffusion run's six rounds of
     two blocks, 6, whose ``experts_read`` fell from 448 to 436 with them
-    -- a shared pass reads the union of two passes' picks once."""
+    -- a shared pass reads the union of two passes' picks once; since a
+    round leaves its last block's K/V pass to the next (PR 51) that run
+    shares 9 (one in each of three first rounds, two in each of three
+    second ones), its four rows RUN 76 passes of the 80 their rounds'
+    blocks count (no row's last block is written), and it reads 419."""
     import os
 
     path = os.path.join(os.path.dirname(os.path.abspath(__file__)),

@@ -1055,6 +1055,122 @@ def test_a_round_ahead_changes_no_token_and_no_chunk(name, params,
         np.testing.assert_array_equal(a, b)
 
 
+#: what a toy generator by diffusion over blocks (``_block_generator``) served
+#: for ``_block_prompts()``, 29 tokens a row, at the commit BEFORE a round
+#: left its last block's K/V pass to the next round (49df19f: every round
+#: wrote every block it fixed; recorded there through ``GenServer``)
+BLOCK_TOKENS = np.asarray([
+    [28, 50, 28, 31, 72, 28, 52, 58, 50, 50, 67, 50, 50, 50, 50, 28, 52, 52,
+     52, 10, 10, 52, 10, 10, 10, 10, 10, 10, 10],
+    [4, 4, 10, 89, 89, 89, 89, 89, 28, 28, 28, 38, 38, 38, 13, 13, 28, 89, 9,
+     31, 40, 28, 28, 9, 9, 9, 10, 28, 93],
+    [42, 42, 1, 1, 42, 42, 28, 28, 1, 24, 24, 28, 28, 42, 42, 1, 42, 42, 42,
+     42, 42, 1, 42, 42, 42, 42, 42, 28, 28],
+    [28, 28, 31, 31, 50, 28, 27, 50, 50, 66, 14, 14, 4, 4, 24, 24, 28, 89, 3,
+     24, 1, 82, 28, 42, 95, 28, 82, 82, 95]], np.int32)
+
+
+def _block_generator():
+    from seldon_core_tpu.models.generate import TransformerGenerator
+
+    unit = TransformerGenerator(
+        vocab=96, d_model=32, n_heads=4, n_kv_heads=2, head_dim=16,
+        n_layers=2, qk_norm=True, tie_embeddings=False, d_expert=16,
+        n_experts=8, moe_k=2, moe_norm_topk=True, block_length=4,
+        denoising_steps=2, mask_id=90, dtype="float32", seed=5)
+    return unit, unit.continuous_spec(unit.init_state(None))
+
+
+def _block_prompts():
+    """Four prompts of 5, 8, 11 and 6 tokens: remainders 1, 0, 3 and 2."""
+    rng = np.random.default_rng(51)
+    return [rng.integers(0, 90, size=(1, n)) for n in (5, 8, 11, 6)]
+
+
+@pytest.mark.parametrize("how", [
+    "a-round-ahead", "fenced", "preempted-with-a-block", "leaving-by-turns",
+    "stopped-with-a-round-in-flight", "a-slot-after-an-eos"])
+def test_a_block_generator_serves_what_it_served_before_blocks_rode_the_carry(
+        how, monkeypatch):
+    """A round of a generator by diffusion over blocks hands its last block
+    on in the carry and the row's next round writes its K/V
+    (models/served.py ``picks_first``): the tokens are the ones the commit
+    before served (``BLOCK_TOKENS``), with rounds dispatched a round ahead
+    of their readback and fenced; through the eviction and readmission of
+    rows that brought a block (their tokens become prompt, the prefill
+    writes every block, ``_admit`` clears what they brought); with rows
+    leaving by turns, so that those that stay ride programs of 4, 2 and 1
+    padded rows with their block; up to a ``stop()`` with a round in
+    flight; and for the next holder of a slot whose last one stopped at an
+    eos -- it brings no block and so no latch (before, a slot's latch
+    outlived its row: the next answer was all eos)."""
+    from seldon_core_tpu.models.generate import generate
+    from seldon_core_tpu.runtime import genserver as gs
+    from seldon_core_tpu.utils.genperf import GENPERF
+    from seldon_core_tpu.utils.hotrecord import SPINE
+
+    unit, spec = _block_generator()
+    prompts = _block_prompts()
+    kw = dict(block_size=8, num_blocks=64, slots=4, span=8, prefill_chunk=16)
+    monkeypatch.setattr(gs, "_FENCE_EVERY", 10 ** 9)
+    SPINE.drain()
+    GENPERF.reset()
+    if how == "fenced":
+        monkeypatch.setattr(gs.GenServer, "_depth", lambda self: 0)
+    elif how == "preempted-with-a-block":
+        # four rows grow to 11 blocks of 4 each: 30 hold their prompts and
+        # first rounds, not their answers
+        kw.update(block_size=4, num_blocks=31, prefill_chunk=8)
+    elif how == "a-slot-after-an-eos":
+        # row 0 stops at its fifth token; row 1, which never says it,
+        # follows it into the one slot
+        assert 72 not in BLOCK_TOKENS[1]
+        kw["slots"], spec = 1, {**spec, "eos_token": 72}
+    srv = GenServer(**spec, **kw)
+    try:
+        if how == "stopped-with-a-round-in-flight":
+            it = srv.stream(prompts[0].astype(float), chunk=4, max_new=400)
+            chunks = _drain_stream(it, limit=3)
+            srv.stop()
+            with pytest.raises(RuntimeError, match="stopped"):
+                chunks += _drain_stream(it)
+            got = np.concatenate(chunks, axis=1)
+            assert 12 <= got.shape[1] < 400
+            np.testing.assert_array_equal(
+                got[:, :29], BLOCK_TOKENS[:1, :got.shape[1]])
+            np.testing.assert_array_equal(got, np.asarray(generate(
+                spec["params"], jnp.asarray(prompts[0], jnp.int32),
+                unit.cfg, max_new_tokens=got.shape[1])))
+            return
+        lengths = ([5, 13, 21, 29] if how == "leaving-by-turns"
+                   else [29] * 4)
+        reqs = [srv.submit(p.astype(float), max_new=n)
+                for p, n in zip(prompts[:2 if kw["slots"] == 1 else 4],
+                                lengths)]
+        got = [r.future.result(timeout=240)[0] for r in reqs]
+        snap = _settle(srv)
+        shapes = set(srv._programs["decode"])
+    finally:
+        srv.stop()
+    SPINE.drain()
+    served = GENPERF.document()["served_decode"]
+    GENPERF.reset()
+    for i, (row, n) in enumerate(zip(got, lengths)):
+        want = BLOCK_TOKENS[i, :n].copy()
+        if how == "a-slot-after-an-eos" and i == 0:
+            want[4:] = 72
+        np.testing.assert_array_equal(row, want)
+    assert snap["tick_errors_total"] == 0 and snap["kv_blocks"]["used"] == 0
+    if how == "fenced":
+        assert served["ahead_steps"] == 0 < served["device_steps"]
+    elif how == "a-round-ahead":
+        assert 0 < served["ahead_steps"] <= served["device_steps"]
+    elif how == "preempted-with-a-block":
+        assert snap["preempted_total"] >= 1
+    elif how == "leaving-by-turns":
+        assert {rows for rows, _ in shapes} == {4, 2, 1}
+
+
 def test_device_error_at_the_delayed_readback_fails_that_programs_requests(
         params, monkeypatch):
     """A device error now surfaces a tick late, where the round is read

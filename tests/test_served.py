@@ -12,7 +12,7 @@ import pytest
 
 import seldon_core_tpu.models.generate as G
 from seldon_core_tpu.models.generate import TransformerGenerator
-from seldon_core_tpu.models.served import served
+from seldon_core_tpu.models.served import BRINGS, served
 from seldon_core_tpu.models.transformer import lm_init
 from seldon_core_tpu.runtime import genserver
 from seldon_core_tpu.runtime.genserver import GenServer
@@ -43,6 +43,11 @@ def test_a_round_is_driven_by_what_the_description_says(generator):
         held = d.held(2)
         assert held.shape == (2, 4) and held.dtype == np.int32
         assert not held.any()
+        # ... or says which rows bring the last block of the round before
+        # (no id is negative): of four padded rows the second of three
+        assert d.held(4, [False, True, False]).tolist() == [
+            [0] * 4, [BRINGS] * 4, [0] * 4, [0] * 4]
+        assert BRINGS < 0
     else:
         assert (d.quantum, d.block_passes, d.picks_first) == (1, 1, True)
         assert d.round == {"block_length": 1, "denoising_steps": 1}
@@ -65,8 +70,10 @@ ROUND = {
     # blocks of 4 under 4 denoising passes and the K/V one: 2 blocks, 10
     # passes, each reading the cache up to its block's end (rows start at
     # 4 and 12); the K/V pass of a block skips its last expert layer, and
-    # the first block's shares a pass of the device with the second's first
-    "diffusion": dict(passes=10, blocks=2, row_passes=20,
+    # the first block's shares a pass of the device with the second's
+    # first; the second's is the next round's to run, so a row in its
+    # first round runs 9
+    "diffusion": dict(passes=10, blocks=2, row_passes=18,
                       kv_positions=5 * (8 + 12) + 5 * (16 + 20),
                       expert_slots=(10 * 2 - 2) * EXPERTS, shared_passes=1),
     # three of five layers hold experts (two leading dense ones)
@@ -82,18 +89,47 @@ def test_a_rounds_counts_are_the_hand_reckoned_ones(generator):
     assert d.round_counts([6, 13], 8) == ROUND[kind]
 
 
+#: who brings a block into a round over rows holding 8 and 16 positions ->
+#: (row_passes, shared_passes) by the round's blocks
+BRINGING = {
+    # rows in their first round: the round's last K/V pass is the next's
+    "fresh": ([False, False], lambda blocks: (2 * (5 * blocks - 1),
+                                              blocks - 1)),
+    # rows that rode the round before: its last block's K/V pass runs here
+    "brings": ([True, True], lambda blocks: (2 * 5 * blocks, blocks)),
+    # one of each: the pass of the device is shared whoever brings
+    "mixed": ([True, False], lambda blocks: (2 * 5 * blocks - 1, blocks)),
+}
+
+
+@pytest.mark.parametrize("who", list(BRINGING))
 @pytest.mark.parametrize("span", [4, 8, 12, 16])
-def test_only_a_round_of_several_diffusion_blocks_shares_passes(
-        generator, span):
-    """``shared_passes``: a block's K/V pass with the next block's first
-    denoising pass, one a block but the round's last; a generator that
-    decodes a token a step shares none, whatever its layers -- a
-    state-space one too (tests/test_nemotron_block.py's)."""
+def test_a_round_of_diffusion_blocks_counts_the_passes_it_runs(
+        generator, span, who):
+    """``passes`` and ``expert_slots`` are the round's own blocks' (what the
+    benchmark takes a round for: ``block_passes`` a block, the K/V pass an
+    expert layer short), whoever runs them; ``row_passes`` what each real
+    row RUNS -- its last block's K/V pass is the next round's, the block it
+    brings this one's -- and ``shared_passes`` the passes of the device that
+    served two: a K/V pass always rides the next block's first denoising
+    pass, in this round or the next.  A generator that decodes a token a
+    step shares none and is told nothing of blocks, whatever its layers --
+    a state-space one too (tests/test_nemotron_block.py's)."""
     kind, unit, d = generator
-    counts = d.round_counts([6, 13], span)
-    assert counts["shared_passes"] == (
-        span // 4 - 1 if kind == "diffusion" else 0)
-    assert counts["passes"] == span * (5 if kind == "diffusion" else 4) // 4
+    brings, want = BRINGING[who]
+    counts = d.round_counts([8, 16], span, brings)
+    if kind == "diffusion":
+        blocks = span // 4
+        assert (counts["row_passes"], counts["shared_passes"]) == want(blocks)
+        assert counts["passes"] == 5 * blocks
+        assert counts["expert_slots"] == (5 * 2 - 1) * blocks * EXPERTS
+        # the passes of the device: a block's denoising passes, no other
+        assert counts["passes"] - blocks == 4 * blocks
+    else:
+        assert counts == d.round_counts([8, 16], span)
+        assert (counts["row_passes"], counts["shared_passes"]) == (
+            2 * span, 0)
+        assert counts["passes"] == span
     ssm = served(TransformerGenerator(
         vocab=96, d_model=32, n_heads=4, n_kv_heads=2, head_dim=16,
         n_layers=3, layer_kinds="mte", ssm_heads=8, ssm_head_dim=8,
