@@ -134,8 +134,13 @@ class CompiledGraph:
         self.units = build_units(predictor, rng)
         rngs = unit_rngs(list(self.units), rng)
         self.states: Dict[str, Any] = {}
+        #: unit -> (start, end) of its ``init_state`` on time.monotonic():
+        #: the engine's boot timeline names them (runtime/engine.py)
+        self.init_at: Dict[str, tuple] = {}
         for name, unit in sorted(self.units.items()):
+            t0 = time.monotonic()
             st = unit.init_state(rngs[name])
+            self.init_at[name] = (t0, time.monotonic())
             if st is not None:
                 self.states[name] = st
         self._all_routers = _routers_in(predictor.graph)

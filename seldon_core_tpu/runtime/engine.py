@@ -62,6 +62,7 @@ from seldon_core_tpu.runtime.resilience import (
     maybe_deadline_scope,
     remaining_s,
 )
+from seldon_core_tpu.utils.genperf import BOOT
 from seldon_core_tpu.utils.hotrecord import SPINE
 from seldon_core_tpu.utils.metrics import MetricsRegistry
 from seldon_core_tpu.utils.perf import OBSERVATORY
@@ -121,6 +122,10 @@ class EngineService:
     ):
         from seldon_core_tpu.utils.tracing import TRACER
 
+        # this engine's spans of the boot timeline (utils/genperf.py BOOT;
+        # GET /stats ``boot``) are written under its own number, its
+        # scheduler's too: a process of several engines reads each one's
+        self._boot_owner, t_units = BOOT.server(), time.monotonic()
         self.deployment = deployment
         self.tracer = TRACER
         self.predictor: PredictorSpec = deployment.predictor(predictor_name)
@@ -301,6 +306,7 @@ class EngineService:
                 self.genserver = GenServer(
                     **cs, role=self.gen_role, coordinator=coordinator,
                 )
+                self.genserver.boot_server = self._boot_owner
                 # deployment identity for the cost ledger's per-tick
                 # attribution (utils/costledger.py)
                 self.genserver.cost_deployment = self.deployment.name
@@ -401,7 +407,14 @@ class EngineService:
             CORPUS.warm_start_autopilot()
         except Exception:  # noqa: BLE001 - corpus must never block serving
             logger.exception("perf-corpus warm start failed (serving anyway)")
-
+        for name, at in getattr(self.compiled, "init_at", {}).items():
+            BOOT.span("unit/" + name, "units", *at, self._boot_owner)
+        BOOT.span(
+            "units", None, t_units, time.monotonic(), self._boot_owner,
+            # init_state returns with its arrays' making still queued on
+            # the device (no block_until_ready here or there): what is
+            # left of it ends under the spans that follow
+            note="unit/*: init_state, dispatched, not awaited")
 
     # -- flight recorder -----------------------------------------------
 
@@ -505,6 +518,12 @@ class EngineService:
             # sequences, paged-KV-pool occupancy, admission/retirement flow
             "genserver": (
                 None if self.genserver is None else self.genserver.snapshot()
+            ),
+            # the boot timeline (utils/genperf.py BOOT): process start ->
+            # the request served now, as spans on time.monotonic()
+            "boot": (
+                BOOT.document(self._boot_owner) if self.genserver is None
+                else self.genserver.boot_document()
             ),
             "resilience": {
                 "retry_budget": self.retry_budget.snapshot(),

@@ -37,7 +37,10 @@ import argparse
 import asyncio
 import base64
 import json
+import logging
 import os
+import sys
+import time
 from typing import Optional
 
 from seldon_core_tpu.graph.defaulting import default_and_validate
@@ -94,6 +97,7 @@ async def serve(deployment: SeldonDeploymentSpec, predictor_name=None,
     from seldon_core_tpu.runtime.engine import EngineService
     from seldon_core_tpu.runtime.grpc_server import make_engine_grpc_server
     from seldon_core_tpu.runtime.rest import make_engine_app, serve_app
+    from seldon_core_tpu.utils.genperf import BOOT
 
     rest_port = rest_port or int(os.environ.get("ENGINE_SERVER_PORT", "8000"))
     grpc_port = grpc_port or int(os.environ.get("ENGINE_SERVER_GRPC_PORT", "5001"))
@@ -123,13 +127,15 @@ async def serve(deployment: SeldonDeploymentSpec, predictor_name=None,
     prewarm_raw = os.environ.get("ENGINE_PREWARM_WIDTHS", "")
     if prewarm_raw.strip():
         widths = [int(w) for w in prewarm_raw.split(",") if w.strip()]
-        t0 = asyncio.get_event_loop().time()
+        t0 = time.monotonic()
         n = engine.prewarm(widths)
+        BOOT.span("prewarm", None, t0, time.monotonic(), engine._boot_owner)
         print(
             f"prewarmed {n} batch shapes for widths {widths} "
-            f"in {asyncio.get_event_loop().time() - t0:.1f}s",
+            f"in {time.monotonic() - t0:.1f}s",
             flush=True,
         )
+    t_listen = time.monotonic()
     # data plane, fastest eligible lane first:
     #   native (C++ HTTP termination + batching, runtime/nativeplane.py)
     #   fast   (asyncio.Protocol, runtime/httpfast.py)
@@ -250,6 +256,7 @@ async def serve(deployment: SeldonDeploymentSpec, predictor_name=None,
            if engine.gen_role != "unified" else ""),
         flush=True,
     )
+    BOOT.span("listen", None, t_listen, time.monotonic(), engine._boot_owner)
 
     # engine liveness lease: when a shared gateway state file and an
     # advertise URL are configured, heartbeat this replica's row (with
@@ -353,7 +360,36 @@ async def serve(deployment: SeldonDeploymentSpec, predictor_name=None,
     print("engine stopped", flush=True)
 
 
+def _serves_in_process(deployment: SeldonDeploymentSpec,
+                       predictor_name: Optional[str]) -> bool:
+    """Whether any node of the graph runs in this process (a built-in or an
+    in-process unit): such an engine initialises the device runtime; one
+    whose every node is a remote binding never touches it."""
+    predictor = deployment.predictor(predictor_name)
+    bound = predictor.component_map()
+    return any(
+        bound.get(node.name) is None
+        or bound[node.name].runtime not in ("rest", "grpc")
+        for node in predictor.graph.walk())
+
+
+def _boot_log_to_stdout() -> None:
+    """The scheduler's boot lines (``loaded N of the record's programs``,
+    ``boot timeline: {...}``) belong in a pod's log; nothing else
+    configures logging in this process, which drops INFO."""
+    log = logging.getLogger("seldon_core_tpu.runtime.genserver")
+    if log.level == logging.NOTSET and not log.handlers:
+        handler = logging.StreamHandler(sys.stdout)
+        handler.setFormatter(logging.Formatter("%(message)s"))
+        log.addHandler(handler)
+        log.setLevel(logging.INFO)
+
+
 def main(argv=None) -> None:
+    # the boot timeline (utils/genperf.py BOOT; GET /stats ``boot``) begins
+    # here: what lies before this stamp is the interpreter and this
+    # module's own imports
+    t_main = time.monotonic()
     parser = argparse.ArgumentParser(description="seldon_core_tpu engine")
     parser.add_argument("--file", default=None, help="deployment JSON path")
     parser.add_argument("--predictor", default=None)
@@ -394,13 +430,25 @@ def main(argv=None) -> None:
              "cross-host KV-handoff receiver (env ENGINE_RELAY_TCP_PORT)",
     )
     args = parser.parse_args(argv)
+    # everything serve() will import, here and under one name: JAX, the
+    # engine and its lanes
+    import jax
+
+    import seldon_core_tpu.runtime.engine  # noqa: F401
+    import seldon_core_tpu.runtime.grpc_server  # noqa: F401
+    import seldon_core_tpu.runtime.rest  # noqa: F401
     from seldon_core_tpu.runtime.compilecache import (
         compile_cache_dir,
         enable_compile_cache,
     )
+    from seldon_core_tpu.utils.genperf import BOOT
 
+    _boot_log_to_stdout()
     if enable_compile_cache():
         print(f"compile cache: {compile_cache_dir()}", flush=True)
+    t_deployment = time.monotonic()
+    BOOT.span("process", None, BOOT.process_start, t_main)
+    BOOT.span("imports", None, t_main, t_deployment)
     deployment = load_deployment_from_env(args.file)
     node = args.node or os.environ.get("ENGINE_GRAPH_NODE", "").strip()
     if node:
@@ -412,6 +460,14 @@ def main(argv=None) -> None:
         deployment = default_and_validate(
             node_subspec(deployment, node, args.predictor)
         )
+    t_backend = time.monotonic()
+    BOOT.span("deployment", None, t_deployment, t_backend)
+    if _serves_in_process(deployment, args.predictor):
+        # the device runtime comes up on its own, under its own name,
+        # where the first unit's first array would have brought it up
+        jax.devices()
+        BOOT.span("backend", None, t_backend, time.monotonic(),
+                  platform=jax.default_backend())
     decode_peers = None
     if args.decode_peers is not None:
         from seldon_core_tpu.runtime.servingmesh import parse_decode_peers

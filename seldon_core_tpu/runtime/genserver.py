@@ -93,9 +93,14 @@ from seldon_core_tpu.runtime.compilecache import (
 )
 from seldon_core_tpu.runtime.qos import current_tier, tier_rank
 from seldon_core_tpu.utils.costledger import costledger_enabled
+from seldon_core_tpu.utils.genperf import BOOT
 from seldon_core_tpu.utils.hotrecord import SPINE
 from seldon_core_tpu.utils.perf import OBSERVATORY
-from seldon_core_tpu.utils.telemetry import RECORDER
+from seldon_core_tpu.utils.telemetry import (
+    RECORDER,
+    install_compile_cache_listener,
+    thread_cache_hits,
+)
 
 __all__ = ["BlockAllocator", "GenRequest", "GenServer"]
 
@@ -143,9 +148,41 @@ class _Phase:
         self._ann.__exit__(*exc)
 
 
+class _BootPhase(_Phase):
+    """A phase of the boot: a ``_Phase`` -- so the interval is on the
+    profiler's clock too -- whose two ``time.monotonic()`` stamps (the boot
+    timeline's clock, utils/genperf.py ``BOOT``) and the executables the
+    persistent cache handed this thread meanwhile go to ``done(start, end,
+    cache_hits)`` when it ends, raised through or not.  Opened a few dozen
+    times a boot and once a shape's first dispatch; never by a tick that
+    dispatches a shape it has dispatched before."""
+
+    __slots__ = ("_done", "_start", "_hits")
+
+    def __init__(self, name: str, done, **args: int):
+        super().__init__(name, **args)
+        self._done = done
+
+    def __enter__(self) -> "_BootPhase":
+        self._start = time.monotonic()
+        self._hits = thread_cache_hits()
+        super().__enter__()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        super().__exit__(*exc)
+        self._done(self._start, time.monotonic(),
+                   thread_cache_hits() - self._hits)
+
+
 def _pow2(n: int) -> int:
     return 1 << max(n - 1, 0).bit_length() if n > 1 else 1
 
+
+# The scheduler function that dispatches each kind of program: its spans'
+# names begin with it.
+_FUNCTIONS = {"prefill": "GenServer._prefill_tick",
+              "decode": "GenServer._decode_round"}
 
 # What a dispatching span says of its program's work beside ``seq``, ``rows``,
 # ``real_rows``, ``nblk`` and a round's ``inplace``: the call's counts by these
@@ -800,9 +837,15 @@ class GenServer:
         # the persistent compile cache (_load_programs; '' = no record is
         # kept: no cache, a mesh, a draft model)
         self._loaded: Dict[str, set] = {"prefill": set(), "decode": set()}
-        self._boot_load_s = 0.0
-        self._boot_trace_s = 0.0
         self._missed = 0            # dispatched shapes it had not loaded
+        #: this server's number in the process's boot timeline (``BOOT``):
+        #: its engine's, set by the engine like ``cost_deployment``, else
+        #: its own from ``_init_device`` on
+        self.boot_server = 0
+        # its wall seconds inside ticks and inside idle waits since,
+        # summed by the two ``_Phase``s of ``_run``
+        self._since_boot: Dict[str, float] = {}
+        self._boot_logged = False
         self._record_path = ""
         self._identity = ""
         # flight-recorder scratch (utils/genperf.py): the bubble ledger
@@ -1030,6 +1073,7 @@ class GenServer:
                         "age_s": round(now - s.t_start, 3)
                         if s.t_start else None,
                     })
+        boot_load_s, boot_trace_s = BOOT.load_seconds(self.boot_server)
         doc = {
             "mode": "speculative" if self.spec else "decode",
             # disaggregated serving mesh: this replica's generation role
@@ -1069,8 +1113,8 @@ class GenServer:
             "programs": {
                 **{k: len(v) for k, v in self._programs.items()},
                 "loaded_at_boot": sum(map(len, self._loaded.values())),
-                "boot_load_s": round(self._boot_load_s, 3),
-                "boot_trace_s": round(self._boot_trace_s, 3),
+                "boot_load_s": round(boot_load_s, 3),
+                "boot_trace_s": round(boot_trace_s, 3),
                 "missed": self._missed,
             },
             # what the wake-up before a round's end rests on (_pace): a
@@ -1117,6 +1161,13 @@ class GenServer:
             },
         }
 
+    def boot_document(self) -> Dict[str, Any]:
+        """The ``boot`` block of ``GET /stats``: the process's boot
+        timeline with this server's part of it."""
+        return BOOT.document(self.boot_server,
+                             self._since_boot.get("serving_s", 0.0),
+                             self._since_boot.get("waiting_s", 0.0))
+
     def stop(self) -> None:
         BROWNOUT.unregister_depth(self._brownout_key)
         with self._wake:
@@ -1149,63 +1200,89 @@ class GenServer:
         # handler also lands here when a KV handoff arrives before any
         # local tick ran (the init lock makes that safe — pool MUTATION
         # stays scheduler-thread-only afterwards)
-        from seldon_core_tpu.models.generate import (
-            init_block_pool,
-            paged_forward_jit,
-        )
+        if not self.boot_server:   # an engine's scheduler has the engine's
+            self.boot_server = BOOT.server()
+        with self._boot_span("device_init", None):
+            self._init_device_spans()
 
-        self._pool = init_block_pool(
-            self.cfg, self.num_blocks, self.block_size, self.mesh)
-        self._allocator = BlockAllocator(self.num_blocks)
-        if self.mesh is not None:
-            # tensor-parallel dispatch (runtime/servingmesh.py): the
-            # paged pool lays out over the unit's device mesh (KV heads
-            # over 'tp' when divisible) so the scheduler's compiled
-            # prefill/decode programs partition across chips together
-            # with the mesh-sharded params
-            from seldon_core_tpu.runtime.servingmesh import shard_gen_pool
+    def _boot_span(self, name: str, parent: Optional[str] = "device_init",
+                   **note) -> _BootPhase:
+        """One span of this server's ``_init_device`` on both clocks: the
+        profiler's (``GenServer._init_device[/<name>]``) and the boot
+        timeline's (``name`` under ``parent``, with ``note``)."""
+        return _BootPhase(
+            "GenServer._init_device" + ("" if parent is None else "/" + name),
+            lambda start, end, _: BOOT.span(
+                name, parent, start, end, self.boot_server, **note))
 
-            self._pool = shard_gen_pool(self.mesh, self._pool)
-        # the Pallas kernels or the jax.numpy forms: decided here, once,
-        # because only the scheduler sees the mesh its pool is sharded over
-        self._kernels = self._served.kernels(
-            self._pool, self.mesh, _pow2(self.slots),
-            self.params["embed"].dtype)
-        if self.spec:
-            self._draft_pool = init_block_pool(
-                self.draft_cfg, self.num_blocks, self.block_size)
-            self._draft_allocator = BlockAllocator(self.num_blocks)
-        # what a decoded token costs: utils/genperf.py prices served decode
-        # with it (``OBSERVATORY.cost_features``)
-        OBSERVATORY.record_compile(
-            "gen_decode_step", self._served.decode_costs(), None)
-        _keep_out_of_program_locations()
-        self._init_carry()
+    def _init_device_spans(self) -> None:
+        from seldon_core_tpu.models.generate import init_block_pool
+
+        # nothing here waits for the device: the pool's zeros (and, behind
+        # them, whatever of the parameters' making is still queued) finish
+        # under the spans that follow
+        with self._boot_span("pool", note="dispatched, not awaited"):
+            self._pool = init_block_pool(
+                self.cfg, self.num_blocks, self.block_size, self.mesh)
+            self._allocator = BlockAllocator(self.num_blocks)
+            if self.mesh is not None:
+                # tensor-parallel dispatch (runtime/servingmesh.py): the
+                # paged pool lays out over the unit's device mesh (KV heads
+                # over 'tp' when divisible) so the scheduler's compiled
+                # prefill/decode programs partition across chips together
+                # with the mesh-sharded params
+                from seldon_core_tpu.runtime.servingmesh import shard_gen_pool
+
+                self._pool = shard_gen_pool(self.mesh, self._pool)
+            if self.spec:
+                self._draft_pool = init_block_pool(
+                    self.draft_cfg, self.num_blocks, self.block_size)
+                self._draft_allocator = BlockAllocator(self.num_blocks)
+        with self._boot_span("kernels"):
+            # the Pallas kernels or the jax.numpy forms: decided here, once,
+            # because only the scheduler sees the mesh its pool is sharded
+            # over
+            self._kernels = self._served.kernels(
+                self._pool, self.mesh, _pow2(self.slots),
+                self.params["embed"].dtype)
+            # what a decoded token costs: utils/genperf.py prices served
+            # decode with it (``OBSERVATORY.cost_features``)
+            OBSERVATORY.record_compile(
+                "gen_decode_step", self._served.decode_costs(), None)
+            _keep_out_of_program_locations()
+        with self._boot_span("carry"):
+            self._init_carry()
         self._load_programs()
         if self.prefix_ids is not None:
-            # the shared prefix is computed ONCE, here, into pinned blocks:
-            # one row whose table is those blocks.  Its full blocks are
-            # shared by table reference; a partly filled boundary block is
-            # copied into each row's first private block at admission
-            import jax.numpy as jnp
+            with self._boot_span("prefix"):
+                self._init_prefix()
 
-            ids = np.asarray(self.prefix_ids, np.int32)[None, :]
-            P = ids.shape[1]
-            blocks = self._allocator.alloc(self._blocks_needed(P))
-            if blocks is None:
-                raise RuntimeError(
-                    f"{self._served.holds} pool ({self.num_blocks} blocks) "
-                    f"smaller than the shared prefix "
-                    f"({self._blocks_needed(P)} blocks)")
-            _, self._pool = paged_forward_jit(
-                self.params, jnp.asarray(ids), self._pool,
-                jnp.asarray([blocks], jnp.int32), jnp.zeros((1,), jnp.int32),
-                jnp.full((1,), P, jnp.int32), cfg=self.cfg, last_only=True)
-            self._allocator.pin(blocks)
-            self._prefix_len = P
-            full = P // self.block_size
-            self._prefix_blocks = blocks[:full]
-            self._prefix_tail = blocks[full] if P % self.block_size else None
+    def _init_prefix(self) -> None:
+        """The shared prefix is computed ONCE, here, into pinned blocks:
+        one row whose table is those blocks.  Its full blocks are shared
+        by table reference; a partly filled boundary block is copied into
+        each row's first private block at admission."""
+        import jax.numpy as jnp
+
+        from seldon_core_tpu.models.generate import paged_forward_jit
+
+        ids = np.asarray(self.prefix_ids, np.int32)[None, :]
+        P = ids.shape[1]
+        blocks = self._allocator.alloc(self._blocks_needed(P))
+        if blocks is None:
+            raise RuntimeError(
+                f"{self._served.holds} pool ({self.num_blocks} blocks) "
+                f"smaller than the shared prefix "
+                f"({self._blocks_needed(P)} blocks)")
+        _, self._pool = paged_forward_jit(
+            self.params, jnp.asarray(ids), self._pool,
+            jnp.asarray([blocks], jnp.int32), jnp.zeros((1,), jnp.int32),
+            jnp.full((1,), P, jnp.int32), cfg=self.cfg, last_only=True)
+        self._allocator.pin(blocks)
+        self._prefix_len = P
+        full = P // self.block_size
+        self._prefix_blocks = blocks[:full]
+        self._prefix_tail = blocks[full] if P % self.block_size else None
 
     def _new_carry(self):
         """A zeroed carry on the device (``_carry_ops``).  Under a mesh it is
@@ -1314,18 +1391,33 @@ class GenServer:
                  "top_k": self.top_k, "top_p": self.top_p,
                  "eos_token": self.eos_token, **self._kernels.round_how})
 
-    def _note_program(self, kind: str, shape: tuple) -> None:
+    def _note_program(self, kind: str, shape: tuple
+                      ) -> Optional[_BootPhase]:
         """A tick is about to dispatch ``shape``.  One the boot did not
         load is traced and loaded by the ``jit`` call of this tick --
         seconds on the scheduler thread, ``missed`` in /stats -- and
-        enters the record, so the next boot loads it ahead."""
+        enters the record, so the next boot loads it ahead.  Returns
+        None for a shape dispatched before; for a new one, the phase to
+        make the ``jit`` call under: its wall seconds are the shape's
+        ``first_dispatch`` in the boot timeline (a loaded program's:
+        the call finding its executable; a missed one's: the trace, the
+        compile or the cache's fetch, and the load)."""
         seen = self._programs[kind]
         if shape in seen:
-            return
+            return None
         seen.add(shape)
-        if shape not in self._loaded[kind]:
+        loaded = shape in self._loaded[kind]
+        if not loaded:
             self._missed += 1
             self._write_record()
+        return _BootPhase(
+            _FUNCTIONS[kind] + "/first_dispatch",
+            lambda start, end, hits: BOOT.first_dispatch(
+                self.boot_server, {
+                    "kind": kind, "shape": list(shape), "loaded": loaded,
+                    "host_s": round(end - start, 6),
+                    **({} if loaded else {"from_cache": hits > 0})}),
+            rows=shape[0], nblk=shape[-1], loaded=int(loaded))
 
     def _write_record(self) -> None:
         """The record anew: what this boot loaded and what it dispatched.
@@ -1387,14 +1479,14 @@ class GenServer:
                 for shape in sorted(listed[kind])]
         if not jobs:
             return
-        t0 = time.perf_counter()
-        for kind, shape in self._load(jobs):
-            self._loaded[kind].add(shape)
-        self._boot_load_s = time.perf_counter() - t0
+        install_compile_cache_listener()    # an entry's ``from_cache``
+        with self._boot_span("load"):
+            for kind, shape in self._load(jobs):
+                self._loaded[kind].add(shape)
         n = sum(map(len, self._loaded.values()))
         logger.info("loaded %d of the record's %d programs in %.1f s, "
                     "%.1f s of them tracing (%s)", n, len(jobs),
-                    self._boot_load_s, self._boot_trace_s, self._record_path)
+                    *BOOT.load_seconds(self.boot_server), self._record_path)
         if n < len(jobs):
             self._write_record()    # without the ones that raised
 
@@ -1410,13 +1502,17 @@ class GenServer:
         lowers, program after program (Python under the GIL: threads that
         share it only slow each other), and ``_LOAD_THREADS`` behind it
         fetch and load what it hands them.  Returns the jobs that loaded;
-        one that raised is logged."""
+        one that raised is logged.  Each job enters the boot timeline as
+        one ``programs`` entry, whole, once it has ended either way:
+        ``trace_s`` the tracer's seconds in ``.lower()``, ``load_s`` a
+        loader's in ``.compile()``, ``from_cache`` whether the persistent
+        cache handed that loader the executable, ``error`` what it raised."""
         import jax
 
         state = jax.tree_util.tree_map(_abstract, (self.params, self._pool))
         take = _carry_ops()[0]
 
-        def lower(job):
+        def lower(job, entry):
             kind, shape = job
             B = shape[0]
 
@@ -1436,29 +1532,44 @@ class GenServer:
                             _abstract(self._zero_keys[B])
                             if keys is None else keys)
             fn, args, kw = self._program(kind, *operands, state=state)
-            t0 = time.perf_counter()
-            lowered = fn.lower(*args, **kw)
             # the one tracer thread's own seconds: trace and lowering
-            self._boot_trace_s += time.perf_counter() - t0
-            return lowered
+            with _Phase("GenServer._init_device/load/trace/" + kind,
+                        entry, "trace_s", rows=B, nblk=shape[-1]):
+                return fn.lower(*args, **kw)
+
+        def compile_(lowered, entry):
+            lowered = lowered.result()
+            with _BootPhase(
+                    "GenServer._init_device/load/compile/" + entry["kind"],
+                    lambda start, end, hits: entry.update(
+                        load_s=end - start, from_cache=hits > 0)):
+                lowered.compile()
 
         loaded = []
+        entries = [{"kind": kind, "shape": list(shape), "trace_s": 0.0,
+                    "load_s": 0.0} for kind, shape in jobs]
         with concurrent.futures.ThreadPoolExecutor(
                 1, "genserver-trace") as tracer, \
                 concurrent.futures.ThreadPoolExecutor(
                     min(_LOAD_THREADS, len(jobs)),
                     "genserver-load") as loaders:
-            lowered = [tracer.submit(lower, job) for job in jobs]
-            done = [loaders.submit(lambda fut: fut.result().compile(), fut)
-                    for fut in lowered]
-            for job, fut in zip(jobs, done):
+            lowered = [tracer.submit(lower, job, entry)
+                       for job, entry in zip(jobs, entries)]
+            done = [loaders.submit(compile_, fut, entry)
+                    for fut, entry in zip(lowered, entries)]
+            for job, entry, fut in zip(jobs, entries, done):
                 try:
                     fut.result()
                     loaded.append(job)
                 except Exception as e:  # noqa: BLE001 - a hint must not stop a boot
+                    entry.pop("from_cache", None)
+                    entry["error"] = f"{type(e).__name__}: {e}"[:200]
                     logger.warning(
                         "%s program %s did not load ahead of its dispatch "
                         "(%s: %s)", *job, type(e).__name__, e, exc_info=True)
+                entry.update(trace_s=round(entry["trace_s"], 6),
+                             load_s=round(entry["load_s"], 6))
+                BOOT.program(self.boot_server, entry)
         return loaded
 
     def _run(self) -> None:
@@ -1472,7 +1583,14 @@ class GenServer:
                        # found a round late) is read before the loop parks:
                        # its completion is no later arrival's to find
                        and not self._unread):
-                    with _Phase("GenServer._run/wait"):
+                    if self._device_ready and not self._boot_logged:
+                        # first idle after the boot: the pod's log holds
+                        # the timeline without a scrape
+                        self._boot_logged = True
+                        logger.info("boot timeline: %s", json.dumps(
+                            self.boot_document(), separators=(",", ":")))
+                    with _Phase("GenServer._run/wait", self._since_boot,
+                                "waiting_s"):
                         if self._imports:
                             # an in-flight remote import holds reserved
                             # blocks: wake periodically so the TTL reaper
@@ -1484,7 +1602,12 @@ class GenServer:
                 if self._stopped:
                     break
             try:
-                with _Phase("GenServer._tick"):
+                if not self._device_ready:
+                    # the boot's seconds (``device_init`` in the boot
+                    # timeline) stay out of the first tick's wall
+                    self._ensure_device()
+                with _Phase("GenServer._tick", self._since_boot,
+                            "serving_s"):
                     progress = self._tick()
             except Exception as e:  # noqa: BLE001 - fail loudly per request
                 logger.exception("genserver tick failed")
@@ -1503,7 +1626,8 @@ class GenServer:
             if not progress:
                 # queued work that cannot run yet (pool dry, waiting on a
                 # retirement that cannot come this tick): don't spin hot
-                with self._wake, _Phase("GenServer._run/wait"):
+                with self._wake, _Phase("GenServer._run/wait",
+                                        self._since_boot, "waiting_s"):
                     self._wake.wait(0.005)
         self._fail_all(RuntimeError("generation scheduler stopped"))
 
@@ -2174,7 +2298,7 @@ class GenServer:
                 self._blocks_needed(int(start[i]) + widths[i])
                 for i in range(len(batch))
             ))
-            self._note_program("prefill", (B, C, nblk))
+            first_dispatch = self._note_program("prefill", (B, C, nblk))
             tables = np.zeros((B, nblk), np.int32)
             for i, seq in enumerate(batch):
                 tables[i] = self._table(seq, nblk)
@@ -2230,7 +2354,11 @@ class GenServer:
             t_dispatch = time.perf_counter()
             fn, args, kw = self._program(
                 "prefill", toks, tables, start, width)
-            logits, self._pool = fn(*args, **kw)
+            if first_dispatch is None:
+                logits, self._pool = fn(*args, **kw)
+            else:
+                with first_dispatch:
+                    logits, self._pool = fn(*args, **kw)
             if self.spec:
                 d_nblk = _pow2(max(
                     self._blocks_needed(seq.prefill_pos + widths[i])
@@ -2392,7 +2520,7 @@ class GenServer:
                     self._served.round_base(s.n_valid) + self.span)
                     for s in batch),
                 self._allocator.capacity)
-            self._note_program("decode", (B, nblk))
+            first_dispatch = self._note_program("decode", (B, nblk))
             tables = np.zeros((B, nblk), np.int32)
             n_valid = np.zeros((B,), np.int32)
             active = np.zeros((B,), bool)
@@ -2453,8 +2581,12 @@ class GenServer:
             fn, args, kw = self._program(
                 "decode", tables, token, n_valid, active, seen,
                 self._zero_keys[B] if keys is None else keys)
-            toks, self._pool, token, _nv, seen, keys, *extra = fn(
-                *args, **kw)
+            if first_dispatch is None:
+                out = fn(*args, **kw)
+            else:
+                with first_dispatch:
+                    out = fn(*args, **kw)
+            toks, self._pool, token, _nv, seen, keys, *extra = out
             self._carry, key_data = put(
                 self._carry, idx, token, seen,
                 keys if self.temperature > 0.0 else None)

@@ -66,13 +66,15 @@ from __future__ import annotations
 
 import bisect
 import collections
+import os
 import threading
-from typing import Any, Dict, Optional, Tuple
+import time
+from typing import Any, Dict, List, Optional, Tuple
 
 from seldon_core_tpu.utils.telemetry import RECORDER, Reservoir
 
 __all__ = ["GenPerf", "GENPERF", "BUBBLE_CAUSES", "TICK_PHASES",
-           "booked_device_s"]
+           "booked_device_s", "BootTimeline", "BOOT"]
 
 #: the bubble ledger's closed cause vocabulary (labels on
 #: seldon_tpu_gen_bubble_seconds_total)
@@ -456,3 +458,141 @@ class GenPerf:
 
 
 GENPERF = GenPerf()
+
+
+# ---------------------------------------------------------------------------
+# The boot timeline: what a process did from its start to the request it is
+# serving now, behind ``GET /stats`` ``boot`` (docs/operations.md "Watching a
+# rolling update").  Written a few dozen times a boot -- by the entry point
+# (runtime/engine_main.py), the engine's construction (runtime/engine.py) and
+# the scheduler's ``_init_device`` and first dispatches (runtime/genserver.py
+# ``_BootPhase``: the same interval is a profiler annotation) -- and never by
+# a tick that dispatches a shape it has dispatched before.
+# ---------------------------------------------------------------------------
+
+
+def _process_start() -> float:
+    """When this process started, on ``time.monotonic()``'s axis: the
+    kernel's own stamp (``/proc/self/stat`` field 22, against ``/proc/
+    uptime`` of the same filesystem, 10 ms fine) where there is one, else
+    now -- the first stamp this module can take."""
+    now = time.monotonic()
+    try:
+        with open("/proc/self/stat") as f:
+            started = int(f.read().rsplit(")", 1)[1].split()[19])
+        with open("/proc/uptime") as f:
+            age = float(f.read().split()[0]) - started / os.sysconf(
+                "SC_CLK_TCK")
+        return now - age if age >= 0.0 else now
+    except (OSError, ValueError, IndexError):
+        return now
+
+
+def _union_s(intervals: List[Tuple[float, float]]) -> float:
+    """Seconds covered by at least one of ``(start, end)``."""
+    total, reach = 0.0, float("-inf")
+    for start, end in sorted(intervals):
+        if end > reach:
+            total += end - max(start, reach)
+            reach = end
+    return total
+
+
+class BootTimeline:
+    """Spans ``{name, parent, start_s, end_s}`` in seconds since the process
+    started, on ``time.monotonic()`` (system-wide on Linux: a harness that
+    spawned the process reads them on its own clock with no offset), the
+    programs a scheduler's boot loaded ahead ``{kind, shape, trace_s, load_s,
+    from_cache | error}`` and each shape's first dispatch ``{kind, shape,
+    loaded, host_s, from_cache}``.  An entry is appended whole, under the
+    lock, when what it describes has ended.  What a scheduler writes carries
+    its ``server`` number (``server()``: one a ``GenServer._init_device``),
+    so that a process of several -- the tests' -- reads each one's own."""
+
+    def __init__(self) -> None:
+        self._lock = threading.Lock()
+        self.process_start = _process_start()
+        #: writer (None: the process itself) -> its "spans", "programs" and
+        #: "first" dispatches, each in the order they ended
+        self._kept: Dict[Optional[int], Dict[str, list]] = {}
+        self._servers = 0
+        #: entries written so far (tests: a tick over a known shape adds 0)
+        self.calls = 0
+
+    def server(self) -> int:
+        with self._lock:
+            self._servers += 1
+            return self._servers
+
+    def span(self, name: str, parent: Optional[str], start: float,
+             end: float, server: Optional[int] = None, **note: Any) -> None:
+        """``start`` and ``end`` are ``time.monotonic()`` stamps."""
+        entry = {"name": name, "parent": parent,
+                 "start_s": round(start - self.process_start, 6),
+                 "end_s": round(end - self.process_start, 6), **note}
+        self._append("spans", entry, server)
+
+    def program(self, server: int, entry: dict) -> None:
+        self._append("programs", entry, server)
+
+    def first_dispatch(self, server: int, entry: dict) -> None:
+        self._append("first", entry, server)
+
+    def _append(self, which: str, entry: dict, server: Optional[int]) -> None:
+        with self._lock:
+            self.calls += 1
+            self._kept.setdefault(server, {}).setdefault(
+                which, []).append(entry)
+
+    def _of(self, which: str, server: Optional[int]) -> List[dict]:
+        """The process's own entries with ``server``'s behind them, as a
+        new list; the lock is held."""
+        return [e for owner in dict.fromkeys((None, server))
+                for e in self._kept.get(owner, {}).get(which, ())]
+
+    def load_seconds(self, server: int) -> Tuple[float, float]:
+        """``(boot_load_s, boot_trace_s)`` of /stats ``genserver.programs``:
+        the ``load`` span's wall and the tracer thread's seconds in it."""
+        with self._lock:
+            kept = self._kept.get(server, {})
+            load = sum((e["end_s"] - e["start_s"]
+                        for e in kept.get("spans", ())
+                        if e["name"] == "load"), 0.0)
+            trace = sum((e["trace_s"] for e in kept.get("programs", ())), 0.0)
+        return load, trace
+
+    def document(self, server: Optional[int] = None, serving_s: float = 0.0,
+                 waiting_s: float = 0.0) -> Dict[str, Any]:
+        """The ``boot`` block of ``GET /stats``, built from the kept lists:
+        the process's own spans and those of scheduler ``server``, whose
+        seconds inside ticks and inside idle waits since its
+        ``device_init`` ended are ``serving_s`` and ``waiting_s``."""
+        uptime = time.monotonic() - self.process_start
+        with self._lock:
+            spans, programs, first = (
+                self._of(which, server)
+                for which in ("spans", "programs", "first"))
+        split = {}
+        for key, loaded in (("loaded", True), ("missed", False)):
+            mine = [e for e in first if e["loaded"] is loaded]
+            split[key] = {"n": len(mine),
+                          "host_s": round(
+                              sum((e["host_s"] for e in mine), 0.0), 6)}
+        split["missed"]["from_cache"] = sum(
+            1 for e in first if not e["loaded"] and e.get("from_cache"))
+        top = [(e["start_s"], e["end_s"]) for e in spans
+               if e["parent"] is None]
+        return {
+            "clock": "monotonic",
+            "process_start": round(self.process_start, 6),
+            "uptime_s": round(uptime, 6),
+            "spans": spans,
+            "programs": programs,
+            "first_dispatch": split,
+            "serving_s": round(serving_s, 6),
+            "waiting_s": round(waiting_s, 6),
+            "accounted_s": round(_union_s(top) + serving_s + waiting_s, 6),
+        }
+
+
+BOOT = BootTimeline()

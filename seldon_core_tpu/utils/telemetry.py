@@ -2222,6 +2222,17 @@ class AuditLog:
 _compile_listener_installed = False
 
 
+_thread_cache = threading.local()
+
+
+def thread_cache_hits() -> int:
+    """Executables the persistent cache has handed THIS thread so far (0
+    until ``install_compile_cache_listener()``): the difference around a
+    ``.compile()`` or a ``jit`` call says whether it was fetched or
+    compiled (runtime/genserver.py: the boot timeline's ``from_cache``)."""
+    return getattr(_thread_cache, "hits", 0)
+
+
 def install_compile_cache_listener() -> bool:
     """Map jax.monitoring compilation events onto the flight recorder:
     compilation-cache events become
@@ -2245,6 +2256,9 @@ def install_compile_cache_listener() -> bool:
                 return
             if "hit" in name:
                 RECORDER.record_compile_cache("hit")
+                # JAX fires the event in the thread that asked for the
+                # executable: whoever asked reads its own count around it
+                _thread_cache.hits = thread_cache_hits() + 1
             elif "miss" in name:
                 RECORDER.record_compile_cache("miss")
 

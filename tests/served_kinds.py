@@ -44,7 +44,7 @@ SERVER = {
 #: what a run's documents say of the clock and not of the work
 WALL_CLOCK = ("decode_device_s", "served_decode_tok_s_device",
               "served_decode_mfu_pct", "served_decode_hbm_bw_util_pct",
-              "pace", "boot_load_s", "boot_trace_s", "idle", "age_s")
+              "pace", "boot_load_s", "boot_trace_s", "boot", "idle", "age_s")
 
 
 def unit_of(kind):

@@ -685,6 +685,19 @@ PHASE_NAMES = {
 WAIT_NAMES = {"GenServer._decode_round/wait", "GenServer._prefill_tick/wait"}
 
 
+#: the boot's phases (``_BootPhase``: the boot timeline's spans on the
+#: profiler's clock), each with the phases it may open under: the device's
+#: bring-up before the first tick, and a shape's first dispatch inside the
+#: span that dispatches it
+BOOT_NAMES = {
+    "GenServer._init_device": {None},
+    **{"GenServer._init_device/" + name: {"GenServer._init_device"}
+       for name in ("pool", "kernels", "carry")},
+    **{fn + "/first_dispatch": {fn + "/build", fn + "/device"}
+       for fn in ("GenServer._prefill_tick", "GenServer._decode_round")},
+}
+
+
 @pytest.mark.parametrize("depth", [0, 1])
 def test_scheduler_opens_every_phase_annotation_properly_nested(
         params, monkeypatch, depth, recorded_spans):
@@ -729,6 +742,11 @@ def test_scheduler_opens_every_phase_annotation_properly_nested(
             assert stack and stack.pop() == name, f"{name} closed out of turn"
     # stop() can catch the scheduler parked: at most the wait is left open
     assert stack in ([], ["GenServer._run/wait"])
+    # every existing span keeps its name and its place; the boot's are
+    # beside and inside them
+    assert set(parents) >= set(BOOT_NAMES)
+    for name, under in BOOT_NAMES.items():
+        assert parents.pop(name) <= under, name
     if depth == 0:
         assert set(parents) == PHASE_NAMES
     else:
