@@ -78,6 +78,7 @@ import os
 import queue
 import threading
 import time
+import weakref
 from collections import deque
 from typing import Any, Dict, List, Optional
 
@@ -87,6 +88,7 @@ from seldon_core_tpu.messages import LoadShedError
 from seldon_core_tpu.runtime.autopilot import SHED_INFO_PREFIX
 from seldon_core_tpu.runtime.brownout import BROWNOUT, BROWNOUT_INFO_PREFIX
 from seldon_core_tpu.runtime.compilecache import (
+    ProgramStore,
     program_record_path,
     read_program_record,
     write_program_record,
@@ -272,8 +274,16 @@ _ROUND_READINGS = 8
 # configuration's 24 of 7 layers: 28.1-29.1 s inside 29.4-30.3, its decode
 # programs 2.4 s each, most of it the kernels' and the expert layer's own
 # trace and lowering): the tracer is still the longer of the two, so the
-# arrangement stands.
+# arrangement stands.  Since PR 53 a boot whose programs the store holds has
+# no tracer: the eight deserialise and load 10-24 executables in 2.9-14.9 s
+# (four read 14.2-14.3 s where eight read 14.4-14.9: PERF.md section 6,
+# PR 53), a thread's first one costing it seconds (ROADMAP A8 (k)).
 _LOAD_THREADS = 8
+
+#: the executables that JAX's persistent cache handed some compile of this
+#: process: not serialised again where the backend does not give such a one
+#: back whole (``GenServer._keep``)
+_LOADED_FROM_DISK: "weakref.WeakSet" = weakref.WeakSet()
 
 
 @functools.lru_cache(maxsize=None)
@@ -848,6 +858,15 @@ class GenServer:
         self._boot_logged = False
         self._record_path = ""
         self._identity = ""
+        # the executables of the two paged programs this server dispatches,
+        # by (kind, shape): loaded from the program store beside the record
+        # or compiled here, and then stored (_load, _bring_up).  A server
+        # that keeps no record keeps no store and no table: its ticks make
+        # the ``jit`` call
+        self._store: Optional[ProgramStore] = None
+        self._executables: Dict[tuple, Any] = {}
+        self._stored = 0            # of the loaded, how many the store held
+        self._storing: Optional[concurrent.futures.Executor] = None
         # flight-recorder scratch (utils/genperf.py): the bubble ledger
         # stamps the END of every tick and classifies the gap before the
         # NEXT one by how this one ended; the per-tick accumulators are
@@ -1106,13 +1125,15 @@ class GenServer:
             "tokens_emitted_total": self.tokens_emitted_total,
             "tick_errors_total": self.tick_errors_total,
             # distinct shapes dispatched since boot; of the record's, how
-            # many this boot loaded ahead, in how long, and how much of
-            # that its one tracer thread spent tracing and lowering (the
-            # interpreter's share of the load); dispatched shapes it had
-            # not loaded (each traced and loaded by a request)
+            # many this boot loaded ahead, how many of those the program
+            # store held (neither traced nor lowered), in how long, and how
+            # much of that its one tracer thread spent tracing and lowering
+            # (the interpreter's share of the load); dispatched shapes it
+            # had not loaded (each traced and loaded by a request)
             "programs": {
                 **{k: len(v) for k, v in self._programs.items()},
                 "loaded_at_boot": sum(map(len, self._loaded.values())),
+                "stored_at_boot": self._stored,
                 "boot_load_s": round(boot_load_s, 3),
                 "boot_trace_s": round(boot_trace_s, 3),
                 "missed": self._missed,
@@ -1176,6 +1197,9 @@ class GenServer:
         t = self._thread
         if t is not None and t.is_alive():
             t.join(timeout=10)
+        if self._storing is not None:
+            # what a request compiled is on disk before the process goes
+            self._storing.shutdown(wait=True)
         if self.coordinator is not None:
             self.coordinator.close()
 
@@ -1358,13 +1382,24 @@ class GenServer:
     # -- the programs, and the record of which ones this deployment runs ----
 
     def _program(self, kind: str, *operands, state=None):
-        """The jitted program of ``kind`` and the arguments a dispatch
-        hands it, ``operands`` being the ones a batch brings (prefill:
-        tokens, tables, start, width; decode: tables, token, n_valid,
-        active, seen_eos, keys).  The one place that states them: a tick
-        passes arrays and calls it, the boot passes their shapes (and, as
-        ``state``, the parameters' and the pool's) and lowers it, and the
-        two cannot drift into different programs."""
+        """What a dispatch of ``kind`` calls, and with which arguments:
+        ``(fn, args, kw)``, ``operands`` being the ones a batch brings
+        (prefill: tokens, tables, start, width; decode: tables, token,
+        n_valid, active, seen_eos, keys).  The one place that states them:
+        a tick passes arrays and calls ``fn(*args, **kw)``, the boot passes
+        their shapes (and, as ``state``, the parameters' and the pool's)
+        and lowers the very same, and the two cannot drift into different
+        programs.  ``args`` are the dynamic arguments and ``kw`` the static
+        ones, all of them.
+
+        For the boot, and for a server that keeps no table (``_store`` is
+        None: a mesh, a draft model, no persistent cache), ``fn`` is the
+        jitted program.  A tick of a server that keeps one is handed the
+        table's executable of the batch's shape and ``args`` alone (a
+        ``jax.stages.Compiled`` takes no static argument and converts
+        nothing: the operands arrive with the shapes and dtypes ``_load``
+        lowers them with); where the table lacks the shape, ``fn`` brings
+        it up first (``_bring_up``)."""
         from seldon_core_tpu.models.generate import (
             paged_decode_round_jit,
             paged_forward_jit,
@@ -1373,6 +1408,8 @@ class GenServer:
         params, pool = state or (self.params, self._pool)
         if kind == "prefill":
             toks, tables, start, width = operands
+            fn, shape = paged_forward_jit, toks.shape + tables.shape[1:]
+            args = (params, toks, pool, tables, start, width)
             kw = {"cfg": self.cfg, "last_only": True}
             if not self._served.picks_first:
                 # no token is chosen from a prompt: no head, and the
@@ -1381,15 +1418,64 @@ class GenServer:
             fused = self._kernels.fused(toks.shape[1])
             if fused is not None:
                 kw["fused"] = fused
-            return (paged_forward_jit,
-                    (params, toks, pool, tables, start, width), kw)
-        tables, token, n_valid, active, seen, keys = operands
-        return (paged_decode_round_jit,
-                (params, pool, tables, token, n_valid, active, seen, keys,
-                 self.cfg),
-                {"span": self.span, "temperature": self.temperature,
-                 "top_k": self.top_k, "top_p": self.top_p,
-                 "eos_token": self.eos_token, **self._kernels.round_how})
+        else:
+            tables, token, n_valid, active, seen, keys = operands
+            fn, shape = paged_decode_round_jit, tables.shape
+            args = (params, pool, tables, token, n_valid, active, seen, keys)
+            kw = {"cfg": self.cfg, "span": self.span,
+                  "temperature": self.temperature, "top_k": self.top_k,
+                  "top_p": self.top_p, "eos_token": self.eos_token,
+                  **self._kernels.round_how}
+        if state is not None or self._store is None:
+            return fn, args, kw
+        compiled = self._executables.get((kind, shape))
+        if compiled is None:
+            return functools.partial(self._bring_up, kind, shape, fn), args, kw
+        return compiled, args, {}
+
+    def _bring_up(self, kind: str, shape: tuple, fn, *args, **kw):
+        """The first dispatch of a shape the table lacks (a cold boot, a
+        shape the record never saw): traced, lowered and compiled here, on
+        the scheduler thread and under the tick's ``first_dispatch`` phase
+        -- the work the ``jit`` call's first trace did, ``missed`` in /stats
+        -- then entered in the table, handed to the store, and called.  So
+        a cold boot stores everything it compiles, and the first warm boot
+        after it traces nothing."""
+        hits = thread_cache_hits()
+        compiled = fn.lower(*args, **kw).compile()
+        self._executables[kind, shape] = compiled
+        self._keep(kind, shape, kw, compiled, hits < thread_cache_hits())
+        return compiled(*args)
+
+    def _keep(self, kind: str, shape: tuple, kw: dict, compiled,
+              from_cache: bool) -> None:
+        """Hand ``compiled`` to the store, off the calling thread: the
+        serialising and the write (tens of megabytes a program) are one
+        worker's, one program after another.  Whatever the server obtained,
+        compiled here or handed over by JAX's persistent cache
+        (``from_cache``) -- so the boot after a package upgrade, whose
+        lowered modules are mostly the old ones, traces once and is stored
+        again -- except on a backend that does not give back whole an
+        executable it loaded from a file (``ProgramStore.reserialises``):
+        there such a shape takes the traced path for as long as its cache
+        entry hits."""
+        # (one ``MeshExecutable`` serves every lowering of one program in
+        # a process -- a second server of this deployment is handed the
+        # first one's without any event -- so the set is the process's)
+        if from_cache:
+            _LOADED_FROM_DISK.add(compiled._executable)
+        if (compiled._executable in _LOADED_FROM_DISK
+                and not self._store.reserialises):
+            return
+        if self._storing is None:
+            self._storing = concurrent.futures.ThreadPoolExecutor(
+                1, "genserver-store")
+        try:
+            # (``save`` raises nothing: it warns and returns False)
+            self._storing.submit(
+                self._store.save, self._store.path(kind, shape, kw), compiled)
+        except RuntimeError:    # ``stop`` shut the worker down meanwhile
+            pass
 
     def _note_program(self, kind: str, shape: tuple
                       ) -> Optional[_BootPhase]:
@@ -1460,12 +1546,22 @@ class GenServer:
         whose programs this cannot state exactly -- partitioned over a
         mesh, with a draft model -- keeps no record and loads nothing, as
         does one without a persistent cache."""
+        import jax
+
         if self.mesh is not None or self.spec:
             return
         self._identity = self._deployment_identity()
         self._record_path = program_record_path(self._identity)
         if not self._record_path:
             return
+        # an entry's ``from_cache``, and what may be stored (_keep)
+        install_compile_cache_listener()
+        # from here on the ticks dispatch from the table (_program)
+        self._store = ProgramStore(
+            os.path.dirname(self._record_path), self._identity,
+            sorted(jax.tree_util.tree_leaves(self._pool)[0].devices(),
+                   key=lambda d: d.id))
+        self._store.sweep()     # another package's files serve no boot
         listed = read_program_record(self._record_path, self._identity)
         # a prefill before a round, as a tick first needs them: where both
         # programs hold a Pallas kernel (retention layers: the chunk's and
@@ -1479,40 +1575,47 @@ class GenServer:
                 for shape in sorted(listed[kind])]
         if not jobs:
             return
-        install_compile_cache_listener()    # an entry's ``from_cache``
         with self._boot_span("load"):
             for kind, shape in self._load(jobs):
                 self._loaded[kind].add(shape)
         n = sum(map(len, self._loaded.values()))
         logger.info("loaded %d of the record's %d programs in %.1f s, "
-                    "%.1f s of them tracing (%s)", n, len(jobs),
-                    *BOOT.load_seconds(self.boot_server), self._record_path)
+                    "%.1f s of them tracing, %d from the program store (%s)",
+                    n, len(jobs), *BOOT.load_seconds(self.boot_server),
+                    self._stored, self._record_path)
         if n < len(jobs):
             self._write_record()    # without the ones that raised
 
     def _load(self, jobs: list) -> list:
-        """Bring each ``(kind, shape)`` to the state its first dispatch
-        needs -- traced, lowered, fetched from the persistent cache (or
-        compiled, cold) and loaded -- from abstract arguments: nothing
-        runs, the pool is not donated.  ``.lower().compile()`` fills the
-        very caches the ``jit`` call reads, so the tick finds the program
-        ready and dispatches as ever (and the persistent cache's key is
-        the one a tick's own trace would give:
-        ``_keep_out_of_program_locations``).  One worker traces and
-        lowers, program after program (Python under the GIL: threads that
-        share it only slow each other), and ``_LOAD_THREADS`` behind it
-        fetch and load what it hands them.  Returns the jobs that loaded;
-        one that raised is logged.  Each job enters the boot timeline as
-        one ``programs`` entry, whole, once it has ended either way:
-        ``trace_s`` the tracer's seconds in ``.lower()``, ``load_s`` a
-        loader's in ``.compile()``, ``from_cache`` whether the persistent
-        cache handed that loader the executable, ``error`` what it raised."""
+        """Bring each ``(kind, shape)`` into the table its first dispatch
+        reads (``_program``), from abstract arguments: nothing runs, the
+        pool is not donated.  A shape the program store holds under this
+        boot's key (runtime/compilecache.py ``ProgramStore``: no trace
+        goes into it) is deserialised and loaded by one of the
+        ``_LOAD_THREADS`` loaders, and that is all.  Any other -- no file,
+        or one that does not load -- takes the traced path: ONE worker
+        traces and lowers, program after program in the jobs' order
+        (Python under the GIL: threads that share it only slow each other;
+        and the order decides the persistent cache's key where both
+        programs hold a kernel, ``_load_programs``), a loader behind it
+        fetches the executable from JAX's persistent cache (its key is the
+        one a tick's own lowering gives: ``_keep_out_of_program_locations``)
+        or, cold, compiles it, and the store is handed the result.  Returns
+        the jobs that loaded; one that raised is logged.  Each job enters
+        the boot timeline as one ``programs`` entry, whole, once it has
+        ended either way: ``trace_s`` the tracer's seconds in ``.lower()``
+        (0.0 for a stored program), ``load_s`` a loader's in the
+        deserialising or in ``.compile()``, ``from_cache`` whether the disk
+        handed that loader the executable (the store, or the persistent
+        cache), ``stored`` whether it was the store, ``error`` what it
+        raised."""
         import jax
 
         state = jax.tree_util.tree_map(_abstract, (self.params, self._pool))
         take = _carry_ops()[0]
 
-        def lower(job, entry):
+        def stated(job):
+            """The program of ``job`` over abstract operands."""
             kind, shape = job
             B = shape[0]
 
@@ -1531,38 +1634,77 @@ class GenServer:
                 operands = (S(shape), token, S((B,)), S((B,), bool), seen,
                             _abstract(self._zero_keys[B])
                             if keys is None else keys)
-            fn, args, kw = self._program(kind, *operands, state=state)
+            return self._program(kind, *operands, state=state)
+
+        def lower(program, entry):
+            fn, args, kw = program
             # the one tracer thread's own seconds: trace and lowering
-            with _Phase("GenServer._init_device/load/trace/" + kind,
-                        entry, "trace_s", rows=B, nblk=shape[-1]):
+            with _Phase("GenServer._init_device/load/trace/" + entry["kind"],
+                        entry, "trace_s", rows=entry["shape"][0],
+                        nblk=entry["shape"][-1]):
                 return fn.lower(*args, **kw)
 
-        def compile_(lowered, entry):
+        def loading(entry, stored):
+            return _BootPhase(
+                "GenServer._init_device/load/compile/" + entry["kind"],
+                lambda start, end, hits: entry.update(
+                    load_s=end - start, from_cache=stored or hits > 0,
+                    stored=stored), stored=int(stored))
+
+        def compile_(job, entry, program, lowered):
             lowered = lowered.result()
-            with _BootPhase(
-                    "GenServer._init_device/load/compile/" + entry["kind"],
-                    lambda start, end, hits: entry.update(
-                        load_s=end - start, from_cache=hits > 0)):
-                lowered.compile()
+            with loading(entry, False):
+                compiled = lowered.compile()
+            self._keep(*job, program[2], compiled, entry["from_cache"])
+            return compiled
+
+        def fetch(job, entry, program, path):
+            with loading(entry, True):
+                compiled = self._store.load(path)
+            if compiled is None:
+                # a file that did not load: the traced path, behind the rest
+                compiled = compile_(job, entry, program,
+                                    tracer.submit(lower, program, entry))
+            return compiled
 
         loaded = []
         entries = [{"kind": kind, "shape": list(shape), "trace_s": 0.0,
                     "load_s": 0.0} for kind, shape in jobs]
+        programs, paths = [], []
+        for job in jobs:
+            try:
+                programs.append(stated(job))
+                paths.append(self._store.path(*job, programs[-1][2]))
+            except Exception as e:  # noqa: BLE001 - this job's alone
+                programs.append(e)
+                paths.append("")
+        held = {path for path in paths if os.path.exists(path)}
         with concurrent.futures.ThreadPoolExecutor(
                 1, "genserver-trace") as tracer, \
                 concurrent.futures.ThreadPoolExecutor(
                     min(_LOAD_THREADS, len(jobs)),
                     "genserver-load") as loaders:
-            lowered = [tracer.submit(lower, job, entry)
-                       for job, entry in zip(jobs, entries)]
-            done = [loaders.submit(compile_, fut, entry)
-                    for fut, entry in zip(lowered, entries)]
+            done = []
+            for job, entry, program, path in zip(jobs, entries, programs,
+                                                 paths):
+                if isinstance(program, Exception):
+                    done.append(concurrent.futures.Future())
+                    done[-1].set_exception(program)
+                elif path in held:
+                    done.append(loaders.submit(
+                        fetch, job, entry, program, path))
+                else:
+                    done.append(loaders.submit(
+                        compile_, job, entry, program,
+                        tracer.submit(lower, program, entry)))
             for job, entry, fut in zip(jobs, entries, done):
                 try:
-                    fut.result()
+                    self._executables[job] = fut.result()
                     loaded.append(job)
+                    self._stored += entry["stored"]
                 except Exception as e:  # noqa: BLE001 - a hint must not stop a boot
-                    entry.pop("from_cache", None)
+                    for key in ("from_cache", "stored"):
+                        entry.pop(key, None)
                     entry["error"] = f"{type(e).__name__}: {e}"[:200]
                     logger.warning(
                         "%s program %s did not load ahead of its dispatch "

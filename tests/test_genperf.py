@@ -875,7 +875,8 @@ def test_a_scripted_run_reads_what_it_read_at_the_parent_commit(
     round leaves its last block's K/V pass to the next (PR 51) that run
     shares 9 (one in each of three first rounds, two in each of three
     second ones), its four rows RUN 76 passes of the 80 their rounds'
-    blocks count (no row's last block is written), and it reads 419."""
+    blocks count (no row's last block is written), and it reads 419;
+    ``programs.stored_at_boot`` (PR 53) 0: such a run keeps no store."""
     import os
 
     path = os.path.join(os.path.dirname(os.path.abspath(__file__)),

@@ -1587,14 +1587,24 @@ def test_program_record_round_trips(tmp_path, caplog):
         str(tmp_path / "absent" / "record.json"), "dep", programs)
 
 
+def _stored(cache_dir):
+    return sorted(cache_dir.glob("genserver-program-*"))
+
+
 def test_a_second_boot_loads_what_the_first_dispatched(
         params, cache_dir, cache_keeps_every_program, jax_events,
         monkeypatch):
-    """The first server of a deployment traces and loads each shape when
-    a request first needs it and writes the record once a new shape; the
-    second loads them all inside ``_init_device``, then serves the same
-    requests with nothing traced, lowered or compiled, nothing missed,
-    nothing written, and the same tokens and chunks."""
+    """The first server of a deployment traces and compiles each shape when
+    a request first needs it, writes the record once a new shape and hands
+    the program store each executable; the second loads them all inside
+    ``_init_device`` from the store, then serves the same requests: nothing
+    traced, lowered or compiled in the boot or after it, nothing missed,
+    nothing written, and the same tokens and chunks.  A third boot, the
+    store's files gone, takes the traced path: it lowers every listed
+    shape and fetches each under the key the first server's TICK wrote it
+    under -- and stores none of them: XLA:CPU does not give back whole an
+    executable that it loaded from a file (a backend that does stores them
+    again: the next test)."""
     from seldon_core_tpu.runtime import genserver as gs_mod
 
     jax.clear_caches()      # what an earlier test compiled is in no file
@@ -1607,6 +1617,7 @@ def test_a_second_boot_loads_what_the_first_dispatched(
     try:
         first._ensure_device()
         assert not _records(cache_dir)          # nothing dispatched yet
+        assert not _stored(cache_dir)
         want = _serve_recorded(first)
         shapes = _shapes(first)
         progs = first.snapshot()["programs"]
@@ -1616,12 +1627,13 @@ def test_a_second_boot_loads_what_the_first_dispatched(
     assert n >= 4 and len(writes) == n          # once a new shape
     assert progs == {"prefill": len(shapes["prefill"]),
                      "decode": len(shapes["decode"]),
-                     "loaded_at_boot": 0, "boot_load_s": 0.0,
-                     "boot_trace_s": 0.0, "missed": n}
+                     "loaded_at_boot": 0, "stored_at_boot": 0,
+                     "boot_load_s": 0.0, "boot_trace_s": 0.0, "missed": n}
     (record,) = _records(cache_dir)
     doc = json.loads(record.read_text())
     assert {k: {tuple(x) for x in doc[k]} for k in shapes} == shapes
     assert "LMConfig(" in doc["identity"]       # names what it belongs to
+    assert len(_stored(cache_dir)) == n         # ``stop`` waited for them
 
     jax.clear_caches()                          # as a new process would be
     del writes[:]
@@ -1629,13 +1641,12 @@ def test_a_second_boot_loads_what_the_first_dispatched(
     try:
         del jax_events[:]
         second._ensure_device()
-        # the boot did the lowering, once a listed shape
-        assert jax_events.count("jaxpr_to_mlir_module_duration") >= n
         assert second._loaded == shapes
-        # ... and fetched every one under the key the first server's TICK
-        # had written it under: one program under one key, whoever traces
-        assert jax_events.count("cache_hits on genserver-load") == n
-        assert "cache_misses on genserver-load" not in jax_events
+        # the boot lowered no program (the carry's helpers are the boot
+        # thread's) and asked JAX's cache for none
+        assert not [e for e in jax_events
+                    if e.startswith("lowered on genserver-trace")
+                    or e.endswith(" on genserver-load")]
         del jax_events[:]
         got = _serve_recorded(second)
         assert not [e for e in jax_events if e in _LOWERED_OR_COMPILED]
@@ -1643,12 +1654,85 @@ def test_a_second_boot_loads_what_the_first_dispatched(
     finally:
         second.stop()
     assert got == want
-    assert progs["loaded_at_boot"] == n and progs["missed"] == 0
-    # the tracer thread's seconds inside ``fn.lower``, within the load's wall
-    assert 0.0 < progs["boot_trace_s"] <= progs["boot_load_s"]
+    assert progs["loaded_at_boot"] == progs["stored_at_boot"] == n
+    assert progs["missed"] == 0
+    assert 0.0 == progs["boot_trace_s"] < progs["boot_load_s"]
     assert (progs["prefill"], progs["decode"]) == (
         len(shapes["prefill"]), len(shapes["decode"]))
     assert not writes                           # no set grew past the record
+
+    for path in _stored(cache_dir):
+        path.unlink()
+    jax.clear_caches()
+    third = _server(params)
+    try:
+        del jax_events[:]
+        third._ensure_device()
+        # the boot did the lowering, once a listed shape
+        assert jax_events.count("jaxpr_to_mlir_module_duration") >= n
+        assert third._loaded == shapes
+        # ... and fetched every one under the key the first server's TICK
+        # had written it under: one program under one key, whoever traces
+        assert jax_events.count("cache_hits on genserver-load") == n
+        assert "cache_misses on genserver-load" not in jax_events
+        del jax_events[:]
+        # the executables were lowered from abstract arguments; the ticks
+        # call them with their own arrays
+        got = _serve_recorded(third)
+        assert not [e for e in jax_events if e in _LOWERED_OR_COMPILED]
+        progs = third.snapshot()["programs"]
+    finally:
+        third.stop()
+    assert got == want
+    assert (progs["loaded_at_boot"], progs["stored_at_boot"]) == (n, 0)
+    assert progs["missed"] == 0
+    # the tracer thread's seconds inside ``fn.lower``, within the load's wall
+    assert 0.0 < progs["boot_trace_s"] <= progs["boot_load_s"]
+    assert not _stored(cache_dir) and not writes
+
+
+def test_a_backend_that_reserialises_stores_what_the_cache_hands_over(
+        params, cache_dir, cache_keeps_every_program, monkeypatch):
+    """The boot after a package upgrade: the store holds nothing under the
+    new key while JAX's own entries -- keyed by the lowered module, which
+    the upgrade left alone -- still hit.  Where the backend gives a loaded
+    executable back whole (``ProgramStore.reserialises``: a TPU) that boot
+    traces ONCE and stores what it was handed, so the one after it traces
+    nothing; and the old package's files go at boot, before any write."""
+    from seldon_core_tpu.runtime import compilecache as cc_mod
+
+    jax.clear_caches()
+    first = _server(params)
+    try:
+        _serve_recorded(first)
+        n = sum(map(len, _shapes(first).values()))
+    finally:
+        first.stop()
+    mine = _stored(cache_dir)
+    assert len(mine) == n >= 4
+    # as another package wrote them: no boot of this one can load them
+    old = [path.rename(path.with_name(path.name.replace(
+        cc_mod.package_digest(), "0" * 16))) for path in mine]
+    init = cc_mod.ProgramStore.__init__
+
+    def on_a_tpu(self, *a, **kw):
+        init(self, *a, **kw)
+        self.reserialises = True
+
+    monkeypatch.setattr(cc_mod.ProgramStore, "__init__", on_a_tpu)
+    jax.clear_caches()
+    second = _server(params)
+    try:
+        second._ensure_device()
+        assert not [path for path in old if path.exists()]
+        progs = second.snapshot()["programs"]
+        assert (progs["loaded_at_boot"], progs["stored_at_boot"]) == (n, 0)
+        assert progs["boot_trace_s"] > 0.0
+        assert all(e["from_cache"] and not e["stored"]
+                   for e in second.boot_document()["programs"])
+    finally:
+        second.stop()           # waits for the store's worker
+    assert _stored(cache_dir) == mine
 
 
 def test_the_boot_loads_the_prefill_programs_before_the_rounds(
@@ -1853,4 +1937,5 @@ def test_servers_that_keep_no_record_write_and_load_nothing(
         assert progs["loaded_at_boot"] == 0 and progs["boot_load_s"] == 0.0
         assert progs["boot_trace_s"] == 0.0
         assert progs["missed"] == progs["prefill"] + progs["decode"] > 0
-        assert not _records(cache_dir)
+        assert progs["stored_at_boot"] == 0
+        assert not _records(cache_dir) and not _stored(cache_dir)
