@@ -77,7 +77,7 @@ def _eager(x) -> bool:
 
 __all__ = ["generate", "stream_chunks", "sample_token", "mask_after_eos",
            "init_block_pool", "private_pool", "decode_inplace",
-           "retention_fused", "ssm_fused",
+           "retention_fused", "ssm_fused", "experts_fused",
            "paged_forward", "paged_decode_round", "paged_spec_round",
            "paged_copy_block", "TransformerGenerator"]
 
@@ -778,12 +778,12 @@ def _retention(lp, x, pool_layer, tables, start, valid, cfg: LMConfig,
 @functools.partial(
     jax.jit,
     static_argnames=("cfg", "interpret", "kv_only", "write", "kind",
-                     "fused"))
+                     "fused", "experts"))
 def _paged_block(lp, x, pool_layer, tables, start, valid, cfg: LMConfig,
                  plan=None, interpret: bool = False, limit=None,
                  kv_only: bool = False, view=None,
                  write: Optional[int] = None, kind=None,
-                 fused: bool = False):
+                 fused: bool = False, experts: Optional[str] = None):
     """One decoder block over the paged pool: K/V written at per-row
     positions start[b] + i (scratch-routed where ``valid`` is False),
     attention over each row's own blocks.  x [B, W, D] -> (x', pool layer',
@@ -799,7 +799,9 @@ def _paged_block(lp, x, pool_layer, tables, start, valid, cfg: LMConfig,
     the state where it lies in the pool (the kernels of ops/retention.py,
     a decode round's step and a prefill call's chunk, and of ops/ssm.py, a
     decode round's step -- under ``interpret`` in Pallas interpret mode)
-    -- and the FFN is ``transformer._ffn``'s of that kind.
+    -- and the FFN is ``transformer._ffn``'s of that kind, a layer of
+    dropless experts by ``experts`` (``moe_dropless``'s ``impl``: what
+    ``_experts_impl`` makes of a program's ``experts_fused``).
 
     ``plan`` (ops.paged_attention.decode_plan) selects the in-place
     formulation: attention reads the row's blocks from the pool where they
@@ -853,7 +855,8 @@ def _paged_block(lp, x, pool_layer, tables, start, valid, cfg: LMConfig,
             h = _rmsnorm(x, lp["ln2"], cfg.norm_eps)
             y, aux = _ffn(lp, h, cfg, mesh=None,
                           valid=jnp.broadcast_to(valid, (B, W))
-                          if cfg.d_expert else None, kind=ffn)
+                          if cfg.d_expert else None, kind=ffn,
+                          experts=experts)
             return x + y, aux
 
     if mixer is None:
@@ -930,7 +933,7 @@ def _head(params, cfg: LMConfig):
 
 def paged_forward(params, tokens, pool, tables, start, width,
                   cfg: LMConfig, last_only: bool = True, head: bool = True,
-                  fused=None):
+                  fused=None, experts_fused=None):
     """Forward W tokens per row at per-row offsets over the paged pool —
     chunked prefill (one prompt chunk at a time, decode never stalls for
     the whole prompt) and the speculative verify pass share this program.
@@ -955,8 +958,12 @@ def paged_forward(params, tokens, pool, tables, start, width,
     ``paged_decode_round(inplace=None)`` does (a caller with a mesh passes
     its own answer); True / False force the kernel / ``jax.numpy`` row by
     row; "interpret" runs the kernel in Pallas interpret mode (tests on
-    the CPU)."""
+    the CPU).  ``experts_fused`` concerns layers of dropless experts, with
+    the same four answers: whether an expert's whole feed-forward is one
+    kernel (parallel/moe.py ``_experts_fused``) or two grouped matmuls;
+    None decides by ``experts_fused(cfg, dtype=...)``."""
     B, W = tokens.shape
+    experts = _experts_impl(experts_fused, params, cfg)
     if fused is None:
         fused = retention_fused(pool, heads=cfg.n_heads, rows=B, width=W,
                                 dtype=params["embed"].dtype)
@@ -972,7 +979,7 @@ def paged_forward(params, tokens, pool, tables, start, width,
         x, pool[f"l{i}"], aux = _paged_block(
             params[f"l{i}"], x, pool[f"l{i}"], tables, start, valid, cfg,
             limit=limit, kind=cfg.kind(i), fused=bool(fused),
-            interpret=fused == "interpret",
+            interpret=fused == "interpret", experts=experts,
         )
         if cfg.d_expert:
             read = read + aux
@@ -1066,11 +1073,42 @@ def ssm_fused(pool, mesh=None, rows: int = 1) -> bool:
         // (2 * N), state=N, mesh=mesh, rows=rows)
 
 
+def experts_fused(cfg: LMConfig, mesh=None, dtype=None) -> bool:
+    """Whether the layers of dropless experts of a generator of ``cfg``
+    take an expert's whole feed-forward as one kernel (parallel/moe.py
+    ``_experts_fused``) or as two grouped matmuls with the activation
+    between them: parallel.moe.fused_supported over what is observable
+    here, as ``ssm_fused`` asks for a state-space layer -- the backend, the
+    caller's mesh, the experts' ``dtype`` (the configuration's where the
+    caller does not say), the model's and an expert's width and whether an
+    expert has a gate.  False for a generator without such layers."""
+    from seldon_core_tpu.parallel.moe import fused_supported
+
+    if not cfg.d_expert:
+        return False
+    return fused_supported(
+        backend=jax.default_backend(), dtype=dtype or cfg.dtype, mesh=mesh,
+        d_model=cfg.d_model, d_expert=cfg.d_expert,
+        gated=cfg.expert_act == "silu")
+
+
+def _experts_impl(answer, params, cfg: LMConfig) -> Optional[str]:
+    """``moe_dropless``'s ``impl`` for a program's ``experts_fused``: None
+    asks ``experts_fused`` for this program's parameters, "interpret" runs
+    the kernel in Pallas interpret mode (tests on the CPU), True forces it,
+    False leaves the two grouped matmuls to the platform."""
+    if answer is None:
+        answer = experts_fused(cfg, dtype=params["embed"].dtype)
+    if not answer:
+        return None
+    return "fused_interpret" if answer == "interpret" else "fused"
+
+
 def paged_decode_round(params, pool, tables, token, n_valid, active,
                        seen_eos, keys, cfg: LMConfig, *, span: int,
                        temperature: float, top_k: int, top_p: float,
                        eos_token: int, inplace=None, ssm_inplace=None,
-                       trace_passes: bool = False):
+                       experts_fused=None, trace_passes: bool = False):
     """``span`` cached decode steps for the whole in-flight batch as ONE
     lax.scan — the scheduler's unit of work between admission points.
     (``cfg.block_length`` > 1: ``span`` positions as blocks of denoising
@@ -1088,7 +1126,10 @@ def paged_decode_round(params, pool, tables, token, n_valid, active,
     attention layers in one generator, so their answer is apart: None
     decides by ``ssm_fused(pool)``, True / False force the kernel of
     ops/ssm.py / ``ssm_step`` over gathered rows, "interpret" runs the
-    kernel in Pallas interpret mode.
+    kernel in Pallas interpret mode.  ``experts_fused`` is the same
+    question of the layers of dropless experts (one kernel an expert's
+    feed-forward, or two grouped matmuls): None decides by
+    ``experts_fused(cfg, dtype=...)``.
 
     token [B] pending tokens (diffusion blocks: [B, L], a row's first block
     as the round finds it -- the prompt's remainder, or the block the round
@@ -1111,7 +1152,8 @@ def paged_decode_round(params, pool, tables, token, n_valid, active,
         return _denoising_round(
             params, pool, tables, token, n_valid, active, seen_eos, keys,
             cfg, span=span, temperature=temperature, eos_token=eos_token,
-            inplace=inplace, trace_passes=trace_passes)
+            inplace=inplace, experts_fused=experts_fused,
+            trace_passes=trace_passes)
     if inplace is None:
         inplace = (decode_inplace(pool, heads=cfg.n_heads,
                                   rows=n_valid.shape[0], head_dim=cfg.hd)
@@ -1119,6 +1161,7 @@ def paged_decode_round(params, pool, tables, token, n_valid, active,
                                       rows=n_valid.shape[0]))
     if ssm_inplace is None:
         ssm_inplace = ssm_fused(pool, rows=n_valid.shape[0])
+    experts = _experts_impl(experts_fused, params, cfg)
     kv = _pool_kv(pool)     # (a plan is made only where a layer attends)
     capacity = tables.shape[1] * kv["k"].shape[1] if kv else 0
 
@@ -1136,7 +1179,7 @@ def paged_decode_round(params, pool, tables, token, n_valid, active,
                 params[f"l{i}"], x, pool[f"l{i}"], tables, n_valid,
                 active[:, None], cfg, plan=plan,
                 interpret=how == "interpret", kind=cfg.kind(i),
-                fused=bool(how),
+                fused=bool(how), experts=experts,
             )
             read = [r + aux for r in read]
         with jax.named_scope("unembed"):
@@ -1173,7 +1216,7 @@ def paged_decode_round(params, pool, tables, token, n_valid, active,
 
 def _denoising_round(params, pool, tables, token, n_valid, active, seen_eos,
                      keys, cfg: LMConfig, *, span: int, temperature: float,
-                     eos_token: int, inplace=None,
+                     eos_token: int, inplace=None, experts_fused=None,
                      trace_passes: bool = False):
     """A decode round of a generator by diffusion over blocks: ``span /
     block_length`` blocks a row, one after another (a ``lax.scan``), greedy.
@@ -1260,6 +1303,7 @@ def _denoising_round(params, pool, tables, token, n_valid, active, seen_eos,
     if inplace is None:
         inplace = decode_inplace(pool, width=L, heads=cfg.n_heads, rows=B,
                                  head_dim=cfg.hd)
+    experts = _experts_impl(experts_fused, params, cfg)
     capacity = tables.shape[1] * _pool_kv(pool)["k"].shape[1]
     if token.ndim == 1:
         token = jnp.broadcast_to(token[:, None], (B, L))
@@ -1275,7 +1319,7 @@ def _denoising_round(params, pool, tables, token, n_valid, active, seen_eos,
         return _paged_block(
             params[f"l{i}"], h, entry, tables, start, valid, cfg, plan=plan,
             interpret=inplace == "interpret", view=view, write=write,
-            kv_only=kv_only, kind=cfg.kind(i))
+            kv_only=kv_only, kind=cfg.kind(i), experts=experts)
 
     def through(pool, plan, views, x, start, valid, write: int,
                 upto: int = cfg.n_layers):
@@ -1462,9 +1506,11 @@ def paged_spec_round(t_params, d_params, t_pool, d_pool, t_tables,
     )
     seg = seg.transpose(1, 0)  # [B, W] = [pending, d1 .. dk]
     widths = jnp.where(active, jnp.int32(W), jnp.int32(0))
+    # (expert layers as the draft's steps above run them: this round is
+    # given no answer of the pool's owner, who may hold a mesh)
     t_logits, t_pool = paged_forward(
         t_params, seg, t_pool, t_tables, n_valid, widths, t_cfg,
-        last_only=False,
+        last_only=False, experts_fused=False,
     )
     t_argmax = jnp.argmax(t_logits, axis=-1).astype(jnp.int32)  # [B, W]
     draft = seg[:, 1:]  # [B, k]
@@ -1501,13 +1547,15 @@ def paged_copy_block(pool, src, dst):
 # one live pool pytree per model and rebinds it after each dispatch, so XLA
 # mutates the blocks in place instead of copying the whole pool per step
 paged_forward_jit = jax.jit(
-    paged_forward, static_argnames=("cfg", "last_only", "head", "fused"),
+    paged_forward,
+    static_argnames=("cfg", "last_only", "head", "fused", "experts_fused"),
     donate_argnums=(2,)
 )
 paged_decode_round_jit = jax.jit(
     paged_decode_round,
     static_argnames=("cfg", "span", "temperature", "top_k", "top_p",
-                     "eos_token", "inplace", "ssm_inplace", "trace_passes"),
+                     "eos_token", "inplace", "ssm_inplace", "experts_fused",
+                     "trace_passes"),
     donate_argnums=(1,),
 )
 paged_spec_round_jit = jax.jit(
@@ -1552,13 +1600,15 @@ def _begin(params, prompt, cfg: LMConfig, max_new_tokens: int,
                  eos_token=eos_token,
                  inplace=decode_inplace(pool, mesh, heads=cfg.n_heads,
                                         rows=B, head_dim=cfg.hd),
-                 ssm_inplace=ssm_fused(pool, mesh, rows=B))
+                 ssm_inplace=ssm_fused(pool, mesh, rows=B),
+                 experts_fused=experts_fused(cfg, mesh,
+                                             params["embed"].dtype))
     # prefill sees the prompt's own blocks only: its attention would
     # otherwise span (masked) the blocks the decode round has yet to fill
     logits, pool = paged_forward_jit(
         params, prompt, pool, tables[:, :-(-S // BLOCK_SIZE)],
         jnp.zeros((B,), jnp.int32), jnp.full((B,), S, jnp.int32), cfg=cfg,
-        last_only=True)
+        last_only=True, experts_fused=knobs["experts_fused"])
     # per-ROW keys, as the round draws: a row's stream must not depend on
     # the rows it happens to be stacked with
     keys = jax.random.split(
@@ -1611,10 +1661,11 @@ def _denoising_lane(params, prompt, cfg: LMConfig, max_new_tokens: int,
     pool, tables = private_pool(cfg, B, S - rem + whole, mesh)
     inplace = decode_inplace(pool, mesh, width=L, heads=cfg.n_heads, rows=B,
                              head_dim=cfg.hd)
+    fused = experts_fused(cfg, mesh, params["embed"].dtype)
     _, pool = paged_forward_jit(
         params, prompt, pool, tables[:, :-(-S // BLOCK_SIZE)],
         jnp.zeros((B,), jnp.int32), jnp.full((B,), S, jnp.int32), cfg=cfg,
-        last_only=True)
+        last_only=True, experts_fused=fused)
     token = jnp.zeros((B, L), jnp.int32).at[:, :rem].set(prompt[:, S - rem:])
     n_valid = jnp.full((B,), S, jnp.int32)
     seen = jnp.zeros((B,), bool)
@@ -1627,7 +1678,8 @@ def _denoising_lane(params, prompt, cfg: LMConfig, max_new_tokens: int,
         toks, pool, token, n_valid, seen, keys, *_ = paged_decode_round_jit(
             params, pool, tables, token, n_valid, jnp.ones((B,), bool),
             seen, keys, cfg, span=span, temperature=temperature, top_k=0,
-            top_p=0.0, eos_token=eos_token, inplace=inplace)
+            top_p=0.0, eos_token=eos_token, inplace=inplace,
+            experts_fused=fused)
         new = toks[:, skip:skip + max_new_tokens - done]
         yield new
         done, skip = done + new.shape[1], 0

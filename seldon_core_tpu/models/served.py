@@ -50,6 +50,11 @@ class Kernels:
     #: kernel of ops/ssm.py) and not over gathered rows: an answer of its
     #: own, because such layers stand beside attention layers
     ssm_inplace: Any = False
+    #: a layer of dropless experts takes an expert's whole feed-forward as
+    #: one kernel (parallel/moe.py ``_experts_fused``) and not as two
+    #: grouped matmuls, in both programs; None without such layers: the
+    #: argument is not given
+    experts_fused: Any = None
 
     @property
     def inplace(self):
@@ -57,9 +62,16 @@ class Kernels:
         return self.attends_inplace or self.states_inplace
 
     @property
+    def experts_how(self) -> Dict[str, Any]:
+        """The keyword either program takes because of its expert layers."""
+        return ({} if self.experts_fused is None
+                else {"experts_fused": self.experts_fused})
+
+    @property
     def round_how(self) -> Dict[str, Any]:
         """The keywords the decode program takes because of them."""
-        return {"inplace": self.inplace, "ssm_inplace": self.ssm_inplace}
+        return {"inplace": self.inplace, "ssm_inplace": self.ssm_inplace,
+                **self.experts_how}
 
     def fused(self, width: int):
         """What a prefill call of ``width`` positions a row takes as
@@ -67,15 +79,18 @@ class Kernels:
         width; None without retention layers: the argument is not given."""
         return self._chunk(width)
 
-    def round_counts(self, span: int) -> Dict[str, int]:
-        """What a round of ``span`` steps counts because of them."""
+    def round_counts(self, span: int, passes: int) -> Dict[str, int]:
+        """What a round of ``span`` steps, ``passes`` of the model, counts
+        because of them."""
         return {"inplace_steps": span if self.attends_inplace else 0,
                 "retention_fused_steps": span if self.states_inplace else 0,
-                "ssm_fused_steps": span if self.ssm_inplace else 0}
+                "ssm_fused_steps": span if self.ssm_inplace else 0,
+                "experts_fused_passes": passes if self.experts_fused else 0}
 
     def prefill_counts(self, width: int, rows: int) -> Dict[str, int]:
         """... and a prefill call of ``rows`` real rows."""
-        return {"retention_fused_rows": rows if self.fused(width) else 0}
+        return {"retention_fused_rows": rows if self.fused(width) else 0,
+                "experts_fused_calls": int(bool(self.experts_fused))}
 
 
 @dataclasses.dataclass(frozen=True)
@@ -310,8 +325,8 @@ class Served:
         """The kernels that serve this generator over ``pool``, sharded
         over ``mesh`` or on one device, for batches of up to ``rows``
         padded rows and activations of ``dtype``: ``decode_inplace`` /
-        ``retention_fused`` / ``ssm_fused`` of models/generate.py over what
-        they observe."""
+        ``retention_fused`` / ``ssm_fused`` / ``experts_fused`` of
+        models/generate.py over what they observe."""
         import jax
 
         from seldon_core_tpu.models import generate as G
@@ -332,7 +347,9 @@ class Served:
                                      rows=rows, width=width, dtype=dtype)
 
         return Kernels(attends, states, chunk,
-                       G.ssm_fused(pool, mesh, rows=rows))
+                       G.ssm_fused(pool, mesh, rows=rows),
+                       G.experts_fused(cfg, mesh, dtype) if self.routed
+                       else None)
 
     # -- what a token costs --------------------------------------------------
 

@@ -654,15 +654,16 @@ def _block(lp, x, cfg: LMConfig, mesh: Optional[Mesh], causal: bool,
 
 
 def _ffn(lp, h, cfg: LMConfig, mesh: Optional[Mesh], valid=None,
-         kind: Optional[str] = None):
+         kind: Optional[str] = None, experts: Optional[str] = None):
     """Feed-forward on h [B,S,D] -> (y, aux), by the layer's ``kind``
     (``LMConfig.kind``'s second; None reads it off the layer's weights):
     "gelu" the dense two-matrix tanh-GELU FFN (aux 0), "moe" the
     capacity-routed experts of ``moe_every`` (aux the load-balance loss),
     "experts" the dropless routed gated-SiLU experts of ``cfg.d_expert``
     (aux the number of experts read; ``valid`` [B,S] keeps pad positions
-    from picking any), "gated" a leading dense layer of such a
-    configuration, ``w2(silu(w1 h) * w3 h)`` (aux 0)."""
+    from picking any; ``experts`` is ``moe_dropless``'s ``impl``), "gated"
+    a leading dense layer of such a configuration, ``w2(silu(w1 h) * w3
+    h)`` (aux 0)."""
     from seldon_core_tpu.ops.quant import lm_matmul
 
     if kind is None:
@@ -673,7 +674,9 @@ def _ffn(lp, h, cfg: LMConfig, mesh: Optional[Mesh], valid=None,
 
         if valid is None:
             valid = jnp.ones(h.shape[:2], bool)
-        return moe_dropless(lp, h, valid, cfg)
+        # (named only where one is forced: the platform decides otherwise)
+        return moe_dropless(lp, h, valid, cfg,
+                            **({"impl": experts} if experts else {}))
     if kind == "gated":
         u = (jax.nn.silu(lm_matmul(lp, "w1", h, out_dtype=h.dtype))
              * lm_matmul(lp, "w3", h, out_dtype=h.dtype))

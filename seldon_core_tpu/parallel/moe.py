@@ -19,11 +19,12 @@ only its experts' blocks.  Its capacity couples the rows of a batch.
 
 ``moe_dropless`` (serving, the paged programs; ``LMConfig.d_expert``) drops
 nothing: a float32 softmax router, the top ``k`` a token, the picks sorted
-by expert and two grouped matmuls (``_grouped_matmul``) over the experts
-held.  A token's result depends on that token alone, so a row's answer is
-the same whoever shares its batch -- and the grouped matmul visits only the
-experts a pass chose, so the weights read grow with the experts CHOSEN, not
-the experts held.
+by expert and, over the experts held, two grouped matmuls
+(``_grouped_matmul``) -- on one TPU ONE kernel for an expert's whole
+feed-forward (``_experts_fused``).  A token's result depends on that token
+alone, so a row's answer is the same whoever shares its batch -- and either
+form visits only the experts a pass chose, so the weights read grow with
+the experts CHOSEN, not the experts held.
 """
 
 from __future__ import annotations
@@ -39,7 +40,8 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 __all__ = ["MoEConfig", "moe_init", "moe_apply", "moe_param_shardings",
-           "moe_leaf_spec", "dropless_init", "moe_dropless"]
+           "moe_leaf_spec", "dropless_init", "moe_dropless",
+           "fused_supported"]
 
 
 @dataclass(frozen=True)
@@ -239,28 +241,32 @@ def dropless_init(rng, cfg) -> Dict[str, Any]:
     return out
 
 
-# The grouped matmul's tiles on the TPU (megablox ``gmm``): a group's rows
-# against its expert's matrix, the WHOLE contraction and as much of the
-# output width as keeps one weight tile within ``_WEIGHT_TILE_BYTES`` (it is
-# double-buffered in a v5e's 16 MiB of scoped vector memory: 2048 x 1536
-# bf16 = 6.3 MB fits, a 256-row tile beside it does not; ``_weight_tile``),
-# ``_ROW_TILE`` rows
-# at decode sizes and ``_ROW_TILE_WIDE`` from ``_WIDE_FROM`` rows up.
-# Measured on one TPU v5e (PERF.md section 6, PR 34, call P34b: 128 experts
-# of 2048 x 1536 and 768 x 2048, bf16, uniform top-8 picks; ms a matmul,
-# gate|up / down, beside the least time for the bytes of the experts hit):
+# The grouped matmul's tiles on the TPU (megablox ``gmm``), where the pair
+# of them serves -- a TPU under a mesh and the widths ``fused_supported``
+# refuses; one chip takes ``_experts_fused`` below, whose table holds the
+# pair's times at these tiles beside its own: a group's rows against its
+# expert's matrix, the WHOLE contraction and as much of the output width as
+# keeps one weight tile within ``_WEIGHT_TILE_BYTES`` (it is double-buffered
+# in the 16 MiB of vector memory the compiler gives a kernel by default:
+# 2048 x 1536 bf16 = 6.3 MB fits, a 256-row tile beside it does not;
+# ``_weight_tile``), ``_ROW_TILE`` rows at decode sizes and
+# ``_ROW_TILE_WIDE`` from ``_WIDE_FROM`` rows up.  Measured on one TPU v5e
+# (PERF.md section 6, PR 34, call P34b: 128 experts of 2048 x 1536 and 768 x
+# 2048, bf16, uniform top-8 picks; ms a matmul, gate|up / down, beside the
+# least time for the bytes of the experts hit):
 #   tokens (experts hit)   least        jax.lax.ragged_dot   gmm, these tiles
 #   16    (84)          0.65 / 0.32      0.99 / 0.67         0.76 / 0.40
 #   64    (125)         0.96 / 0.48      2.68 / 1.59         1.13 / 0.61
 #   1024  (128)         0.98 / 0.49      3.14 / 1.92         1.51 / 0.88
 # XLA's own lowering of ragged_dot streams the chosen experts at 35-60% of
-# the chip's bandwidth, these tiles at 80-85%, to the same bits; row tiles
+# the chip's bandwidth, these tiles at 80-89%, to the same bits; row tiles
 # of 16 / 32 / 64 read within 3% of each other, narrower output tiles
 # (512, 768, 1024) within 3% of the whole width.  At the seam itself
-# (bench/tools/gmm_tiles.py, PERF.md section 6, PR 48: M = 2048 sorted picks
-# of which 1152 real, what a decode round's shared pass brings at 32 rows)
-# 64-row tiles read 1.16 / 0.61 and 128-row tiles 1.19 / 0.64, and at 1024
-# picks 1.15 / 0.62 and 1.13 / 0.60: within 4% either way, so the seam stays.
+# (bench/tools/gmm_tiles.py; PERF.md section 6, PR 48 and again PR 56, call
+# P56a: M = 2048 sorted picks of which 1152 real, what a decode round's
+# shared pass brings at 32 rows) 64-row tiles read 1.17 / 0.62 and 128-row
+# tiles 1.19 / 0.64, and at 1024 picks 1.16 / 0.62 and 1.14 / 0.60: within
+# 4% either way, so the seam stays.
 _ROW_TILE, _ROW_TILE_WIDE, _WIDE_FROM = 64, 128, 2048
 _WEIGHT_TILE_BYTES = 6_500_000
 
@@ -341,10 +347,233 @@ def _grouped_matmul(x, w, sizes, impl=None, transposed=False):
 def _expert_act(u, cfg):
     """An expert's hidden activation from its first matmul's output ``u``:
     ``silu(gate) * up`` of the two halves side by side, or ``relu(u)^2``."""
-    if cfg.expert_act == "relu2":
+    return _act(u, cfg.expert_act == "silu")
+
+
+def _act(u, gated: bool):
+    if not gated:
         return jnp.square(jax.nn.relu(u))
     F = u.shape[-1] // 2
     return jax.nn.silu(u[..., :F]) * u[..., F:]
+
+
+# ---------------------------------------------------------------------------
+# An expert's whole feed-forward as ONE kernel
+# ---------------------------------------------------------------------------
+
+# The pair of grouped matmuls with the activation between them leaves the
+# first matmul's output in HBM in float32, visits every expert twice, and
+# -- megablox's pipeline holds ONE weight tile on its way in, sent for a
+# step ahead -- lets the copy queue run dry wherever a row tile straddles two
+# experts.  ``_experts_fused`` keeps that output and the hidden in vector
+# memory, brings an expert's matrices WHOLE by its own copies (the next
+# expert's sent for before this one's are waited for) and reads an expert
+# once however many tiles it straddles.  One TPU v5e, bfloat16, each token's
+# picks distinct experts drawn uniformly, ms a layer (PERF.md section 6,
+# PR 56, call P56a: scripts/experts_fused_time.py; the pair is ``_gmm`` ->
+# ``_act`` -> ``_gmm`` at the tiles above, "least" the bytes of the experts
+# hit at the chip's 819 GB/s):
+#   experts of          picks (real)  hit  least  pair   fused at 64 / 128
+#   128 x 2048 x 768,   2048 (1152)   128  1.475  1.734  1.662 / 1.694
+#   silu gate|up        2048 (2048)   128  1.475  1.798  1.678 / 1.710
+#   (sdar-30b-a3b)      1024  (576)   127  1.463  1.684  1.670 / 1.656
+#                       1024 (1024)   128  1.475  1.725  1.694 / 1.674
+#                        512  (512)   123  1.417  1.612  1.601 / 1.592
+#                        128  (128)    86  0.991  1.109  1.116 / 1.108
+#   32 x 2048 x 1792,    128  (128)    32  0.860  0.969  0.971 / 0.967
+#   silu (lfm2-8b-a1b)    64   (64)    30  0.807  0.905  0.904 / 0.909
+#                       1024 (1024)    32  0.860  1.111  0.987 / 0.990
+#                       2048 (2048)    32  0.860  1.299  1.023 / 1.011
+#   64 of 128 x 2688 x    96   (49)    34  0.828  0.931  0.937 / 0.934
+#   1856, relu2 (nemo-    48   (22)    19  0.463  0.532  0.534 / 0.539
+#   tron3-nano-30b-a3b) 1536  (798)    64  1.559  1.925  1.746 / 1.757
+# (the pair at the better of its row tiles).  The fused call streams the
+# experts hit at 87-89% of the published bandwidth whatever the picks -- all
+# its bytes counted, 742 GB/s, where the dense cell's matmuls read 753: what
+# this chip gives -- so it is 2-7% under the pair at a diffusion round's
+# sizes, 9-22% under it at a prefill call's, and level with it at 128 picks
+# and fewer, where the pair moves next to no activations and streams at 89%
+# already (inside the programs the pair costs more than alone, and the
+# rounds of all three cells gain: PERF.md section 6, PR 56).  It is never
+# slower: no seam by size.  Its row tile: 128 up to
+# 1024 picks (fewer visits), 64 above (two visits of 128 rows on one expert
+# outlast its successor's copy).
+_FUSED_ROW_TILE, _FUSED_NARROW_FROM, _FUSED_ROW_TILE_NARROW = 128, 1025, 64
+# What the call may ask of a TPU's vector memory (a v5e core has 128 MiB;
+# the compiler's default for a kernel is 16 MiB, and two experts' matrices
+# -- one computed on, one on its way in -- are 18.9 MB at sdar-30b-a3b's
+# widths, 44 MB at lfm2-8b-a1b's, 40 MB at nemotron3-nano-30b-a3b's): the
+# call states its own need (``_fused_vmem``) as its limit, and
+# ``fused_supported`` refuses widths whose need is above this.
+_FUSED_VMEM_BYTES = 96 * 2 ** 20
+_FUSED_SLOTS = 2
+
+
+def _fused_rows(M: int) -> int:
+    """The row tile of the fused call for ``M`` sorted picks (the table
+    above), no taller than ``M`` in whole sublane tiles."""
+    tm = (_FUSED_ROW_TILE if M < _FUSED_NARROW_FROM
+          else _FUSED_ROW_TILE_NARROW)
+    return min(tm, -(-M // 16) * 16)
+
+
+def _fused_vmem(tm: int, D: int, F: int, gated: bool, itemsize: int) -> int:
+    """Bytes of vector memory the fused call holds at a row tile of ``tm``:
+    the slots of whole experts, the row tile in and out (double-buffered
+    by the pipeline) and the tile's intermediates (the first matmul's
+    float32 output, the hidden in float32 and in the activations' dtype,
+    the second's float32 output beside the tile it is merged into)."""
+    ups = 2 if gated else 1
+    experts = _FUSED_SLOTS * (ups + 1) * D * F * itemsize
+    tiles = 2 * tm * D * (itemsize + 4)
+    between = tm * (ups * F * 4 + F * (4 + itemsize) + 2 * D * 4)
+    return experts + tiles + between
+
+
+def fused_supported(*, backend: str, dtype: Any, mesh: Optional[Any],
+                    d_model: int, d_expert: int, gated: bool) -> bool:
+    """True where an expert layer takes an expert's whole feed-forward as
+    one kernel (``_experts_fused``), False where the two grouped matmuls
+    with the activation between them serve.  Decided from what the caller
+    can observe, as ops.ssm.step_supported decides: a TPU backend (Mosaic),
+    no mesh (a Mosaic call does not partition under GSPMD), bfloat16 or
+    float32 (the row tiles are whole sublane tiles of either), a model
+    width of whole 128-lane registers and an expert width the two halves of
+    a gated first matmul split at one (768, 1792) or, without a gate, of
+    whole sublane tiles (1856 = 116 x 16: the matrix is stored [F, D]), and
+    two whole experts beside the tallest row tile within
+    ``_FUSED_VMEM_BYTES``.  The picks a call brings do not enter: the table
+    above has no size at which the pair is faster."""
+    if backend != "tpu" or mesh is not None:
+        return False
+    if jnp.dtype(dtype) not in (jnp.dtype(jnp.bfloat16),
+                                jnp.dtype(jnp.float32)):
+        return False
+    if d_model % 128 or d_expert % (128 if gated else 16):
+        return False
+    return (_fused_vmem(_FUSED_ROW_TILE, d_model, d_expert, gated,
+                        jnp.dtype(dtype).itemsize) <= _FUSED_VMEM_BYTES)
+
+
+def _fused_kernel(offsets, groups, tiles, hits, rank, count, x_ref, up_hbm,
+                  down_hbm, out_ref, up_buf, down_buf, sems, *, gated: bool):
+    """Visit ``v`` of the walk megablox's metadata lays out -- a row tile
+    ``tiles[v]`` against the expert ``groups[v]`` that has rows in it, the
+    experts hit in order, a tile that straddles experts visited once an
+    expert -- computes ``act(x W_up) W_down`` of the tile's rows and keeps
+    the rows that are the expert's.  The experts' matrices stay in HBM and
+    come whole, the ``j``-th expert hit into slot ``j % 2``: at an expert's
+    FIRST visit the next expert's two matrices are sent for (their slot is
+    the one the expert before has finished with) before this one's are
+    waited for, the down matrix only after the first matmul -- so the next
+    copy is queued while this one still runs, an expert's matrices are read
+    once however many tiles it straddles, and an expert nobody chose is
+    never sent for."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    v = pl.program_id(0)
+    g = groups[v]
+    j = rank[g]
+    slot = jax.lax.rem(j, _FUSED_SLOTS)
+    first = jnp.logical_or(v == 0, groups[jnp.maximum(v - 1, 0)] != g)
+
+    def copy(j, down: bool):
+        e, s = hits[j], jax.lax.rem(j, _FUSED_SLOTS)
+        if down:
+            return pltpu.make_async_copy(down_hbm.at[e], down_buf.at[s],
+                                         sems.at[1, s])
+        return pltpu.make_async_copy(up_hbm.at[e], up_buf.at[s],
+                                     sems.at[0, s])
+
+    @pl.when(v == 0)
+    def _():
+        copy(0, False).start()
+        copy(0, True).start()
+
+    @pl.when(first)
+    def _():
+        @pl.when(j + 1 < count[0])
+        def _():
+            copy(j + 1, False).start()
+            copy(j + 1, True).start()
+
+        copy(j, False).wait()
+
+    x = x_ref[...]
+    u = jax.lax.dot_general(
+        x, up_buf[slot], (((1,), (0 if gated else 1,)), ((), ())),
+        preferred_element_type=jnp.float32)
+    h = _act(u, gated).astype(x.dtype)
+
+    @pl.when(first)
+    def _():
+        copy(j, True).wait()
+
+    y = jnp.dot(h, down_buf[slot], preferred_element_type=jnp.float32)
+    tm = x.shape[0]
+    row = tiles[v] * tm + jax.lax.broadcasted_iota(jnp.int32, y.shape, 0)
+    mine = jnp.logical_and(row >= offsets[g], row < offsets[g + 1])
+    out_ref[...] = jnp.where(mine, y, out_ref[...])
+
+
+def _experts_fused(x, w_up, w_down, sizes, *, gated: bool,
+                   interpret: bool = False):
+    """x [M, D], its rows sorted by group, through each group's expert --
+    ``act(x W_up[g]) W_down[g]``, ``W_up`` [G, D, 2F] gate and up side by
+    side (``gated``) or [G, F, D] -- by ``sizes`` [G] rows a group -> [M,
+    D] float32, as ONE Pallas call: ``_grouped_matmul``'s contract (a group
+    without rows is not visited and its matrices are not read; rows past
+    the last group hold nothing defined; the row tile may straddle groups)
+    and its rounding points (float32 accumulation in both matmuls, the
+    activation in float32, one cast of the hidden to ``x``'s dtype), with
+    the first matmul's output and the hidden in vector memory only."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    from jax.experimental.pallas.ops.tpu.megablox.gmm import (
+        make_group_metadata,
+    )
+
+    M, D = x.shape
+    G, F = w_down.shape[:2]
+    tm = _fused_rows(M)
+    rows = -(-M // tm) * tm             # whole row tiles: the pad lies past
+    if rows != M:                       # every group and is never computed
+        x = jnp.pad(x, ((0, rows - M), (0, 0)))
+    (offsets, groups, tiles), visits = make_group_metadata(
+        group_sizes=sizes, m=rows, tm=tm, start_group=jnp.int32(0),
+        num_nonzero_groups=G, visit_empty_groups=False)
+    hit = sizes > 0
+    rank = jnp.cumsum(hit, dtype=jnp.int32) - 1
+    hits = jnp.nonzero(hit, size=G, fill_value=0)[0].astype(jnp.int32)
+    count = jnp.sum(hit, dtype=jnp.int32).reshape(1)
+
+    def tile(v, offsets, groups, tiles, *_):
+        return tiles[v], 0
+
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    need = _fused_vmem(tm, D, F, gated, x.dtype.itemsize)
+    out = pl.pallas_call(
+        functools.partial(_fused_kernel, gated=gated),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=6,
+            grid=(visits,),
+            in_specs=[pl.BlockSpec((tm, D), tile), hbm, hbm],
+            out_specs=pl.BlockSpec((tm, D), tile),
+            scratch_shapes=[
+                pltpu.VMEM((_FUSED_SLOTS,) + w_up.shape[1:], w_up.dtype),
+                pltpu.VMEM((_FUSED_SLOTS, F, D), w_down.dtype),
+                pltpu.SemaphoreType.DMA((2, _FUSED_SLOTS)),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((rows, D), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=max(need + need // 4, 16 * 2 ** 20)),
+        interpret=interpret,
+        name="experts_fused",
+    )(offsets, groups, tiles, hits, rank, count, x, w_up, w_down)
+    return out[:M]
 
 
 def moe_dropless(lp, h, valid, cfg, impl=None):
@@ -367,8 +596,11 @@ def moe_dropless(lp, h, valid, cfg, impl=None):
     token and is added unweighted.
 
     The T*k picks are sorted by expert and go through the experts as two
-    grouped matmuls (``_grouped_matmul``; ``impl`` is its), which visit a
-    group's weight tiles only where the group has rows: an expert nobody
+    grouped matmuls (``_grouped_matmul``; ``impl`` is its) or, under
+    ``impl`` "fused" (a caller on one TPU whom ``fused_supported`` told so;
+    "fused_interpret": the same kernel in Pallas interpret mode, tests on
+    the CPU), as ONE kernel (``_experts_fused``); both visit a
+    group's weights only where the group has rows: an expert nobody
     chose is not read.  ``valid`` [B, W] marks the real tokens: a pad
     position or an empty slot picks nothing (its picks sort behind every
     group and lie outside all of them), so padding reads no expert and the
@@ -411,13 +643,15 @@ def moe_dropless(lp, h, valid, cfg, impl=None):
             read = jnp.stack([read, jnp.sum(here, dtype=jnp.int32)])
     with jax.named_scope("experts"):
         xs = x[order // k]                                    # [T*k, D]
-        if cfg.expert_act == "relu2":
-            up = _grouped_matmul(xs, lp["e_up"], sizes, impl,
-                                 transposed=True)
+        gated = cfg.expert_act == "silu"
+        w_up = lp["e_gate_up" if gated else "e_up"]
+        if impl in ("fused", "fused_interpret"):
+            ys = _experts_fused(xs, w_up, lp["e_down"], sizes, gated=gated,
+                                interpret=impl == "fused_interpret")
         else:
-            up = _grouped_matmul(xs, lp["e_gate_up"], sizes, impl)
-        ys = _grouped_matmul(_expert_act(up, cfg).astype(x.dtype),
-                             lp["e_down"], sizes, impl)
+            up = _grouped_matmul(xs, w_up, sizes, impl, transposed=not gated)
+            ys = _grouped_matmul(_expert_act(up, cfg).astype(x.dtype),
+                                 lp["e_down"], sizes, impl)
         # back to token order; rows past the last group (a pad's picks, a
         # pick of an expert held elsewhere) hold nothing defined
         ys = ys[jnp.argsort(order)].reshape(B * W, k, D)

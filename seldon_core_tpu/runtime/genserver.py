@@ -1301,7 +1301,8 @@ class GenServer:
         _, self._pool = paged_forward_jit(
             self.params, jnp.asarray(ids), self._pool,
             jnp.asarray([blocks], jnp.int32), jnp.zeros((1,), jnp.int32),
-            jnp.full((1,), P, jnp.int32), cfg=self.cfg, last_only=True)
+            jnp.full((1,), P, jnp.int32), cfg=self.cfg, last_only=True,
+            **self._kernels.experts_how)
         self._allocator.pin(blocks)
         self._prefix_len = P
         full = P // self.block_size
@@ -1418,6 +1419,7 @@ class GenServer:
             fused = self._kernels.fused(toks.shape[1])
             if fused is not None:
                 kw["fused"] = fused
+            kw.update(self._kernels.experts_how)
         else:
             tables, token, n_valid, active, seen, keys = operands
             fn, shape = paged_decode_round_jit, tables.shape
@@ -2698,7 +2700,8 @@ class GenServer:
             work = self._served.round_counts(
                 [s.n_valid for s in batch], self.span, batch_rode)
             self._counts.update(
-                work, **self._kernels.round_counts(self.span),
+                work, **self._kernels.round_counts(self.span,
+                                                   work["passes"]),
                 rows=B, real_rows=len(batch), steps=self.span,
                 # queued behind a program whose results are still unread:
                 # the device goes from that one to this without the host

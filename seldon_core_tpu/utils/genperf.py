@@ -99,7 +99,8 @@ REQUEST_STAGES = ("lane_in", "queue", "prefill", "lane_out")
 #: (one a step, or a block's denoising passes and the K/V one), of which
 #: ``shared_passes`` passes of the device served two (the K/V one of a
 #: diffusion block with the next block's first denoising pass: passes -
-#: shared_passes went over the weights) and
+#: shared_passes went over the weights), ``experts_fused_passes`` took each
+#: expert's feed-forward as one kernel (parallel/moe.py) and
 #: ``row_passes``, summed over the real rows of each (real_tokens /
 #: row_passes = tokens fixed a row-pass: 1, or 4/5 for blocks of four under
 #: four denoising passes); ``experts_read`` by the expert layers (the rounds'
@@ -112,8 +113,8 @@ SERVED_DECODE = dict(
     **{name: name for name in (
         "inplace_steps", "retention_fused_steps", "ssm_fused_steps",
         "ahead_steps",
-        "kv_positions", "passes", "shared_passes", "row_passes",
-        "experts_read",
+        "kv_positions", "passes", "shared_passes", "experts_fused_passes",
+        "row_passes", "experts_read",
         "expert_slots", "expert_slots_held")})
 
 #: ``served_prefill``'s, folded from every tick (a chunk is read back a tick
@@ -121,10 +122,11 @@ SERVED_DECODE = dict(
 #: ``experts_read`` (where a prefill returns the count) of ``expert_slots``,
 #: the prompt ``tokens`` and real ``rows`` given, of which ``carried_rows``
 #: began from a state an earlier chunk left and ``retention_fused_rows``
-#: worked on the retention states where they lie (the chunk kernel)
+#: worked on the retention states where they lie (the chunk kernel), and
+#: the ``experts_fused_calls`` whose expert layers ran that one kernel
 SERVED_PREFILL = {name: "prefill_" + name for name in (
-    "calls", "experts_read", "expert_slots", "tokens", "rows",
-    "carried_rows", "retention_fused_rows")}
+    "calls", "experts_fused_calls", "experts_read", "expert_slots", "tokens",
+    "rows", "carried_rows", "retention_fused_rows")}
 
 #: ``<kind>_state_bytes`` of both sections: the bytes of matrix state a
 #: generator of such layers read + wrote, 2 x a row's bytes over the layers

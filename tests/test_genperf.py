@@ -273,6 +273,34 @@ def test_served_decode_counts_the_steps_whose_ssm_states_the_kernel_updated():
     assert served["retention_fused_steps"] == 0
 
 
+def test_served_counts_the_passes_and_calls_whose_experts_ran_one_kernel():
+    """``experts_fused_passes`` rides the tick record beside ``passes``
+    and ``prefill_experts_fused_calls`` beside ``prefill_calls``: 0 for a
+    record that does not say (a generator without expert layers, or the
+    two grouped matmuls), equal to ``passes`` / ``calls`` when every
+    round's and every call's expert layers took an expert's feed-forward
+    as one kernel (bench/layer_metrics/decode_experts_fused_share.json
+    divides the first two)."""
+    tick = {"wall_s": 0.02, "device_s": 0.01, "tokens": 16, "steps": 8,
+            "passes": 10, "prefill_calls": 1, "prefill_rows": 3,
+            "device_phases": {"decode": 0.01}, "phases": {"decode": 0.01}}
+    GENPERF.observe_tick("decode", tick)
+    doc = GENPERF.document()
+    assert (doc["served_decode"]["passes"],
+            doc["served_decode"]["experts_fused_passes"]) == (10, 0)
+    assert (doc["served_prefill"]["calls"],
+            doc["served_prefill"]["experts_fused_calls"]) == (1, 0)
+    GENPERF.reset()
+    for kind in ("decode", "mixed"):
+        GENPERF.observe_tick(kind, {**tick, "experts_fused_passes": 10,
+                                    "prefill_experts_fused_calls": 1})
+    doc = GENPERF.document()
+    assert (doc["served_decode"]["experts_fused_passes"]
+            == doc["served_decode"]["passes"] == 20)
+    assert (doc["served_prefill"]["experts_fused_calls"]
+            == doc["served_prefill"]["calls"] == 2)
+
+
 def test_served_prefill_counts_the_rows_whose_chunk_ran_the_kernel():
     """``prefill_retention_fused_rows`` rides the tick record beside
     ``prefill_rows`` whatever the tick's kind: 0 for a record that does
@@ -876,7 +904,9 @@ def test_a_scripted_run_reads_what_it_read_at_the_parent_commit(
     shares 9 (one in each of three first rounds, two in each of three
     second ones), its four rows RUN 76 passes of the 80 their rounds'
     blocks count (no row's last block is written), and it reads 419;
-    ``programs.stored_at_boot`` (PR 53) 0: such a run keeps no store."""
+    ``programs.stored_at_boot`` (PR 53) 0: such a run keeps no store;
+    ``experts_fused_passes`` / ``experts_fused_calls`` (PR 56) 0: on the
+    CPU the two grouped matmuls serve."""
     import os
 
     path = os.path.join(os.path.dirname(os.path.abspath(__file__)),

@@ -101,7 +101,8 @@ def reference_answer(params, prompt, doc, max_new):
     return np.asarray(seq[len(prompt):], np.int32)
 
 
-def chunked(unit, params, rows, chunk, tables, pool=None, bs=4, blocks=16):
+def chunked(unit, params, rows, chunk, tables, pool=None, bs=4, blocks=16,
+            how=None):
     """``rows`` prefilled ``chunk`` tokens a call as the scheduler does:
     rows of unequal length in one call, the shorter ones right-padded, a
     row that is through riding along with width 0.  Returns each row's
@@ -120,20 +121,22 @@ def chunked(unit, params, rows, chunk, tables, pool=None, bs=4, blocks=16):
             width[i], start[i] = w, min(lo, lens[i])
         logits, pool = paged_forward_jit(
             params, jnp.asarray(toks), pool, tables, jnp.asarray(start),
-            jnp.asarray(width), cfg=unit.cfg, last_only=True)
+            jnp.asarray(width), cfg=unit.cfg, last_only=True, **(how or {}))
         for i in range(len(rows)):
             if width[i] and lo + width[i] == lens[i]:
                 out[i] = np.asarray(logits[i])
     return np.stack(out), pool
 
 
-def decode(unit, params, pool, tables, token, n_valid, active, span):
+def decode(unit, params, pool, tables, token, n_valid, active, span,
+           how=None):
     B = len(token)
     return paged_decode_round_jit(
         params, pool, tables, jnp.asarray(token, jnp.int32),
         jnp.asarray(n_valid, jnp.int32), jnp.asarray(active, bool),
         jnp.zeros((B,), bool), jnp.zeros((B,), jnp.uint32), unit.cfg,
-        span=span, temperature=0.0, top_k=0, top_p=0.0, eos_token=-1)
+        span=span, temperature=0.0, top_k=0, top_p=0.0, eos_token=-1,
+        **(how or {}))
 
 
 TABLES = jnp.asarray([[1, 2, 3, 4, 5, 6], [7, 8, 9, 10, 11, 12]], jnp.int32)
@@ -175,17 +178,22 @@ def test_whole_prefill_gives_the_references_logits_at_every_position(model):
                                atol=1e-4, rtol=0)
 
 
-@pytest.mark.parametrize("chunk", [1, 2, 3, 5, 16])
-def test_chunked_prefill_then_decode_rounds_equal_the_reference(model,
-                                                                chunk):
+@pytest.mark.parametrize("chunk, experts_fused", [
+    (1, None), (2, None), (3, None), (5, None), (16, None),
+    (5, "interpret")], ids=["1", "2", "3", "5", "16", "5-experts-fused"])
+def test_chunked_prefill_then_decode_rounds_equal_the_reference(
+        model, chunk, experts_fused):
     """The same two prompts (13 and 8 tokens: unequal, so every call but a
     whole one has pad positions or a row of width 0) in chunks shorter
     than, equal to and longer than the convolution's history; then two
     decode rounds through the cache, teacher-checked: every token is the
-    argmax of the reference's whole forward pass over the row so far."""
+    argmax of the reference's whole forward pass over the row so far.
+    Once with an expert's feed-forward as the ONE Pallas call the chip
+    runs, in interpret mode, in both programs."""
     doc, unit, params = model
+    how = {"experts_fused": experts_fused} if experts_fused else None
     rows = prompts([13, 8], seed=2)
-    logits, pool = chunked(unit, params, rows, chunk, TABLES)
+    logits, pool = chunked(unit, params, rows, chunk, TABLES, how=how)
     for i, r in enumerate(rows):
         np.testing.assert_allclose(
             logits[i], reference_logits(params, r, doc)[-1], atol=1e-4,
@@ -196,7 +204,8 @@ def test_chunked_prefill_then_decode_rounds_equal_the_reference(model,
     token = first
     for _ in range(2):
         toks, pool, token, n_valid, *_ = decode(
-            unit, params, pool, TABLES, token, n_valid, [True, True], 4)
+            unit, params, pool, TABLES, token, n_valid, [True, True], 4,
+            how=how)
         got.append(np.asarray(toks))
     got = np.concatenate(got, axis=1)
     for i, r in enumerate(rows):
@@ -208,7 +217,7 @@ def test_chunked_prefill_then_decode_rounds_equal_the_reference(model,
     nxt, _ = paged_forward_jit(
         params, jnp.asarray(seq[None, -1:]), pool, TABLES[:1],
         jnp.asarray([len(seq) - 1], jnp.int32), jnp.asarray([1], jnp.int32),
-        cfg=unit.cfg, last_only=True)
+        cfg=unit.cfg, last_only=True, **(how or {}))
     np.testing.assert_allclose(np.asarray(nxt[0]),
                                reference_logits(params, seq, doc)[-1],
                                atol=1e-4, rtol=0)

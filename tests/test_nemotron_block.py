@@ -113,7 +113,8 @@ def reference_answer(params, prompt, doc, max_new):
     return np.asarray(seq[len(prompt):], np.int32)
 
 
-def chunked(unit, params, rows, chunk, tables, pool=None, bs=4, blocks=16):
+def chunked(unit, params, rows, chunk, tables, pool=None, bs=4, blocks=16,
+            how=None):
     """``rows`` prefilled ``chunk`` tokens a call as the scheduler does:
     rows of unequal length in one call, the shorter ones right-padded, a
     row that is through riding along with width 0.  Returns each row's
@@ -132,7 +133,7 @@ def chunked(unit, params, rows, chunk, tables, pool=None, bs=4, blocks=16):
             width[i], start[i] = w, min(lo, lens[i])
         logits, pool = paged_forward_jit(
             params, jnp.asarray(toks), pool, tables, jnp.asarray(start),
-            jnp.asarray(width), cfg=unit.cfg, last_only=True)
+            jnp.asarray(width), cfg=unit.cfg, last_only=True, **(how or {}))
         for i in range(len(rows)):
             if width[i] and lo + width[i] == lens[i]:
                 out[i] = np.asarray(logits[i])
@@ -140,14 +141,14 @@ def chunked(unit, params, rows, chunk, tables, pool=None, bs=4, blocks=16):
 
 
 def decode(unit, params, pool, tables, token, n_valid, active, span,
-           ssm_inplace=None):
+           ssm_inplace=None, how=None):
     B = len(token)
     return paged_decode_round_jit(
         params, pool, tables, jnp.asarray(token, jnp.int32),
         jnp.asarray(n_valid, jnp.int32), jnp.asarray(active, bool),
         jnp.zeros((B,), bool), jnp.zeros((B,), jnp.uint32), unit.cfg,
         span=span, temperature=0.0, top_k=0, top_p=0.0, eos_token=-1,
-        ssm_inplace=ssm_inplace)
+        ssm_inplace=ssm_inplace, **(how or {}))
 
 
 TABLES = jnp.asarray([[1, 2, 3, 4, 5, 6], [7, 8, 9, 10, 11, 12]], jnp.int32)
@@ -325,10 +326,17 @@ def test_the_pools_owner_asks_for_the_state_space_layers_apart(
     monkeypatch.setattr(G, "ssm_fused", lambda *a, **kw: "interpret")
     monkeypatch.setattr(G, "decode_inplace", lambda *a, **kw: False)
     k = served(unit.cfg).kernels(toy, None, 4, jnp.float32)
-    assert k.round_how == {"inplace": False, "ssm_inplace": "interpret"}
-    assert k.round_counts(8) == {"inplace_steps": 0,
-                                 "retention_fused_steps": 0,
-                                 "ssm_fused_steps": 8}
+    # (the backend says "tpu" here: the expert layers' answer is asked of
+    # the toy's widths, no whole registers, and of the published ones)
+    assert k.round_how == {"inplace": False, "ssm_inplace": "interpret",
+                           "experts_fused": False}
+    assert k.round_counts(8, 8) == {"inplace_steps": 0,
+                                    "retention_fused_steps": 0,
+                                    "ssm_fused_steps": 8,
+                                    "experts_fused_passes": 0}
+    wide = dataclasses.replace(unit.cfg, d_model=2688, d_expert=1856)
+    assert G.experts_fused(wide, None, jnp.bfloat16)
+    assert not G.experts_fused(wide, object(), jnp.bfloat16)
 
 
 # -- the programs against the reference ------------------------------------
@@ -394,9 +402,23 @@ def test_chunked_prefill_then_decode_rounds_equal_the_reference(
     chunks, and in chunks shorter than the convolution's history; then two
     decode rounds through the pool, teacher-checked: every token is the
     argmax of the reference's whole forward pass over the row so far."""
+    chunks_then_rounds(model, chunk, ssm_inplace)
+
+
+def test_the_fused_expert_call_serves_the_chunks_and_the_rounds(model):
+    """The same, as far as the CPU can run what the chip decides: both
+    programs with an expert's feed-forward as ONE Pallas call in interpret
+    mode (``experts_fused="interpret"``: relu^2 over the transposed up
+    matrix, the held range, the shared expert beside it), the rounds with
+    the state-space kernel too."""
+    chunks_then_rounds(model, 4, "interpret",
+                       {"experts_fused": "interpret"})
+
+
+def chunks_then_rounds(model, chunk, ssm_inplace, how=None):
     doc, unit, params = model
     rows = prompts([13, 8], seed=2)
-    logits, pool = chunked(unit, params, rows, chunk, TABLES)
+    logits, pool = chunked(unit, params, rows, chunk, TABLES, how=how)
     for i, r in enumerate(rows):
         np.testing.assert_allclose(
             logits[i], reference_logits(params, r, doc)[-1], atol=1e-4,
@@ -408,7 +430,7 @@ def test_chunked_prefill_then_decode_rounds_equal_the_reference(
     for _ in range(2):
         toks, pool, token, n_valid, *_ = decode(
             unit, params, pool, TABLES, token, n_valid, [True, True], 4,
-            ssm_inplace)
+            ssm_inplace, how)
         got.append(np.asarray(toks))
     got = np.concatenate(got, axis=1)
     for i, r in enumerate(rows):
@@ -420,7 +442,7 @@ def test_chunked_prefill_then_decode_rounds_equal_the_reference(
     nxt, _ = paged_forward_jit(
         params, jnp.asarray(seq[None, -1:]), pool, TABLES[:1],
         jnp.asarray([len(seq) - 1], jnp.int32), jnp.asarray([1], jnp.int32),
-        cfg=unit.cfg, last_only=True)
+        cfg=unit.cfg, last_only=True, **(how or {}))
     np.testing.assert_allclose(np.asarray(nxt[0]),
                                reference_logits(params, seq, doc)[-1],
                                atol=1e-4, rtol=0)

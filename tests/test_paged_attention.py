@@ -7,6 +7,7 @@ compiled for a described v5e at the benchmark cells' shapes.  A head
 narrower than a 128-lane row is a further case of each: the pool is then
 made as ``init_block_pool`` makes it, several KV heads a row."""
 
+import functools
 import itertools
 import re
 
@@ -777,3 +778,37 @@ def test_paged_forward_of_retention_layers_compiles_around_the_kernel(
             assert not _makers(text, lead + (rows, P))
     assert compiled.memory_analysis().temp_size_in_bytes < 217_549_824
 
+
+
+@pytest.mark.parametrize("held,D,F,gated,picks", [
+    (128, 2048, 768, True, 1024),   # sdar-30b-a3b: a denoising pass at 32 rows
+    (128, 2048, 768, True, 2048),   # ... the pass two blocks share
+    (128, 2048, 768, True, 2120),   # ... a prefill call of 265 tokens
+    (32, 2048, 1792, True, 128),    # lfm2-8b-a1b: a step at 32 rows
+    (64, 2688, 1856, False, 96),    # nemotron3-nano-30b-a3b: 16 rows, 29 x 64
+    (64, 2688, 1856, False, 1536),
+])
+def test_fused_expert_call_compiles_for_a_described_v5e(one_chip, held, D, F,
+                                                        gated, picks):
+    """The expert layer's ONE Pallas call (parallel/moe.py
+    ``_experts_fused``) at the three expert configurations' widths, in
+    bfloat16, for the chip the benchmark runs on: two whole experts in
+    vector memory under the limit the call states for itself (18.9, 44 and
+    40 MB: over the compiler's default 16 MiB), the halves of a gated first
+    matmul split at a lane boundary, a width of 29 x 64 whole -- what
+    interpret mode cannot refuse."""
+    from seldon_core_tpu.parallel import moe
+
+    assert moe.fused_supported(backend="tpu", dtype=jnp.bfloat16, mesh=None,
+                               d_model=D, d_expert=F, gated=gated)
+    bf16 = jnp.bfloat16
+
+    def compile_of(s):
+        return jax.jit(functools.partial(
+            moe._experts_fused, gated=gated)).lower(
+            s((picks, D), bf16),
+            s((held, D, 2 * F) if gated else (held, F, D), bf16),
+            s((held, F, D), bf16), s((held,), jnp.int32)).compile()
+
+    text = _described(one_chip, compile_of).as_text()
+    assert text.count("tpu_custom_call") == 1

@@ -130,12 +130,18 @@ def prompts(lens, seed=0):
 # -- (a) the paged programs against the reference ---------------------------
 
 
-def test_chunked_prefill_then_a_round_equal_the_reference(model):
+@pytest.mark.parametrize("experts_fused", [None, "interpret"],
+                         ids=["grouped-matmuls", "experts-fused"])
+def test_chunked_prefill_then_a_round_equal_the_reference(model,
+                                                          experts_fused):
     """``paged_forward`` chunk by chunk (chunks of 8 = two blocks) and one
     ``paged_decode_round`` of two blocks over rows whose prompts are and are
     not a whole number of blocks: the prefill's logits to rounding, the
-    round's tokens to the id."""
+    round's tokens to the id -- with the expert layers as the CPU runs them
+    and with an expert's feed-forward as the ONE Pallas call the chip runs
+    (``experts_fused="interpret"``, both programs)."""
     doc, unit, params = model
+    how = {"experts_fused": experts_fused} if experts_fused else {}
     lens = [5, 8, 13, 16]
     rows = prompts(lens)
     bs, C, span = 8, 8, 8
@@ -151,7 +157,8 @@ def test_chunked_prefill_then_a_round_equal_the_reference(model):
             toks[r, :w], width[r] = row[pos[r]:pos[r] + w], w
         logits, pool = paged_forward_jit(
             params, jnp.asarray(toks), pool, tables,
-            jnp.asarray(pos, jnp.int32), jnp.asarray(width), cfg=unit.cfg)
+            jnp.asarray(pos, jnp.int32), jnp.asarray(width), cfg=unit.cfg,
+            **how)
         for r in range(4):
             if width[r] and pos[r] + width[r] == lens[r]:
                 last[r] = np.asarray(logits[r])
@@ -168,7 +175,7 @@ def test_chunked_prefill_then_a_round_equal_the_reference(model):
         params, pool, tables, jnp.asarray(held), jnp.asarray(lens, jnp.int32),
         jnp.ones((4,), bool), jnp.zeros((4,), bool),
         jnp.zeros((4,), jnp.uint32), unit.cfg, span=span, temperature=0.0,
-        top_k=0, top_p=0.0, eos_token=-1)
+        top_k=0, top_p=0.0, eos_token=-1, **how)
     toks = np.asarray(toks)
     for r, row in enumerate(rows):
         rem = lens[r] % 4
@@ -990,9 +997,9 @@ def test_a_dense_generator_reports_no_experts_and_one_pass_a_step(
     assert served["shared_passes"] == 0
     SPINE.drain()
     assert GENPERF.document()["served_prefill"] == {
-        "calls": 1, "experts_read": 0, "expert_slots": 0, "tokens": 5,
-        "rows": 1, "carried_rows": 0, "retention_fused_rows": 0,
-        "retention_state_bytes": 0}
+        "calls": 1, "experts_fused_calls": 0, "experts_read": 0,
+        "expert_slots": 0, "tokens": 5, "rows": 1, "carried_rows": 0,
+        "retention_fused_rows": 0, "retention_state_bytes": 0}
 
 
 def test_observe_tick_folds_the_new_counters():
@@ -1009,7 +1016,8 @@ def test_observe_tick_folds_the_new_counters():
     prefill = GENPERF.document()["served_prefill"]
     GENPERF.reset()
     # a chunk is read back in whatever tick comes next: every kind folds it
-    assert prefill == {"calls": 6, "experts_read": 4500,
+    assert prefill == {"calls": 6, "experts_fused_calls": 0,
+                       "experts_read": 4500,
                        "expert_slots": 5376, "tokens": 900, "rows": 21,
                        "carried_rows": 9, "retention_fused_rows": 0,
                        "retention_state_bytes": 0}

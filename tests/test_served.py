@@ -154,18 +154,25 @@ def test_a_prefill_calls_counts_are_the_hand_reckoned_ones(generator):
 
 def test_the_kernels_answer_is_asked_once_and_counted(generator, monkeypatch):
     """On the CPU no kernel serves and nothing is counted; where
-    ``decode_inplace`` / ``retention_fused`` say "interpret" (as a TPU says
-    yes) the decode program takes it as ``inplace=``, a prefill call of a
-    width as ``fused=`` -- asked once a width -- and the counts follow."""
+    ``decode_inplace`` / ``retention_fused`` / ``experts_fused`` say
+    "interpret" (as a TPU says yes) the decode program takes it as
+    ``inplace=``, a prefill call of a width as ``fused=`` -- asked once a
+    width -- both as ``experts_fused=`` where the generator has expert
+    layers (no other is given the argument), and the counts follow."""
     kind, unit, d = generator
+    routed = kind in ("diffusion", "conv")
     pool = G.init_block_pool(unit.cfg, 4, 8)
     k = d.kernels(pool, None, 4, jnp.float32)
     assert not k.inplace
-    assert k.round_counts(8) == {"inplace_steps": 0,
-                                 "retention_fused_steps": 0,
-                                 "ssm_fused_steps": 0}
-    assert k.round_how == {"inplace": False, "ssm_inplace": False}
-    assert k.prefill_counts(8, 3) == {"retention_fused_rows": 0}
+    assert k.round_counts(8, 10) == {"inplace_steps": 0,
+                                     "retention_fused_steps": 0,
+                                     "ssm_fused_steps": 0,
+                                     "experts_fused_passes": 0}
+    assert k.experts_how == ({"experts_fused": False} if routed else {})
+    assert k.round_how == {"inplace": False, "ssm_inplace": False,
+                           **k.experts_how}
+    assert k.prefill_counts(8, 3) == {"retention_fused_rows": 0,
+                                      "experts_fused_calls": 0}
     assert k.fused(8) is (False if kind == "retention" else None)
     asked = []
 
@@ -177,19 +184,27 @@ def test_the_kernels_answer_is_asked_once_and_counted(generator, monkeypatch):
     monkeypatch.setattr(
         G, "decode_inplace",
         lambda pool, *a, **kw: kind != "retention" and "interpret")
+    monkeypatch.setattr(G, "experts_fused",
+                        lambda cfg, mesh=None, dtype=None: "interpret")
     k = d.kernels(pool, None, 4, jnp.float32)
     assert k.inplace == "interpret"
     assert bool(k.attends_inplace) == (kind != "retention")
-    assert k.round_counts(8) == {
+    assert k.round_counts(8, 10) == {
         "inplace_steps": 0 if kind == "retention" else 8,
         "retention_fused_steps": 8 if kind == "retention" else 0,
         # no kind here has a state-space layer (tests/test_nemotron_block.py
         # has one): the attention layers' answer is not theirs
-        "ssm_fused_steps": 0}
-    assert k.round_how == {"inplace": "interpret", "ssm_inplace": False}
+        "ssm_fused_steps": 0,
+        # the round's passes of the model, not its steps
+        "experts_fused_passes": 10 if routed else 0}
+    assert k.experts_how == ({"experts_fused": "interpret"} if routed
+                             else {})
+    assert k.round_how == {"inplace": "interpret", "ssm_inplace": False,
+                           **k.experts_how}
     for _ in range(2):
         assert k.prefill_counts(8, 3) == {
-            "retention_fused_rows": 3 if kind == "retention" else 0}
+            "retention_fused_rows": 3 if kind == "retention" else 0,
+            "experts_fused_calls": int(routed)}
     assert k.fused(8) == ("interpret" if kind == "retention" else None)
     assert asked == ([1, 8] if kind == "retention" else [1])
 
